@@ -1,0 +1,766 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — does the serving path still start on the TPU?
+
+Drives HTTP -> ModelManager -> Engine once through the real CLI at the
+published widths of `mistral-7b` (full depth, random int8 weights from a
+seed), and compiles every Pallas kernel in `ops/` with Mosaic at the served
+shapes against its XLA oracle. It is a smoke test: its timings are labelled
+"smoke" and are never benchmark numbers; it claims nothing.
+
+    python chip_smoke.py                      # on a machine with a TPU
+    python chip_smoke.py --cpu-rehearsal      # tiny widths on the CPU; says so
+    python chip_smoke.py --phases four_chip   # only the named phases
+
+The parent process never imports jax: a chip belongs to one process at a
+time, so the parent only starts children, one after another, and reads what
+they print. Without a TPU the bare command fails and prints no result.
+
+Standard output holds two lines: the detailed summary (versions, compile
+cache, every phase with its smoke timings, `"claim": null`; also written to
+`chiprun_out/chip_smoke/summary.json`), then, last, the verdict
+`{"ok": ..., "device": {"platform", "kind", "count"}}` with exactly those keys.
+
+Phases (each passes or fails on its own; any failure makes the exit code 1):
+  kernels    every Pallas kernel, jitted through its dispatcher with
+             impl="auto": the lowered module must hold a Mosaic custom call,
+             and the result must agree with the impl="xla" oracle.
+  serve      `python -m localai_tpu run` serving mistral-7b int8 twice over —
+             a dense-cache YAML, then a paged one (loading it evicts the
+             first): a lone request, 8 concurrent streams, a ~1,500-token
+             prompt repeated until the prefix-cached admission lands, and
+             (paged) an n=2 request that forks a slot. Checked from outside:
+             status 200, [DONE], one SSE content chunk per completion token,
+             /system says tpu, engine counters, and a server log with no
+             traceback and no ERROR record.
+  restart    the server again, same lone request: the compile cache must
+             gain no entry.
+  four_chip  with >= 4 devices: the serve phase in bf16 at tensor_parallel 4
+             (plan, shard placement and per-device HBM asserted from
+             /system), then four tp=1 cluster replicas (one device each).
+"""
+
+from __future__ import annotations
+
+import argparse
+import http.client
+import json
+import os
+import re
+import shutil
+import signal
+import socket
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+PHASES = ("kernels", "serve", "restart", "four_chip")
+
+# Served shapes. "full" is mistral-7b as published (models/config.py); "tiny"
+# exists only for the CPU rehearsal of this script's own control flow.
+SIZES = {
+    "full": dict(
+        arch="mistral-7b", slots=8, context=2048, page=128,
+        short_prompt=100, long_prompt=1450, max_tokens=96,
+        # kernel phase: B slots, K kv heads, G q heads per kv head, head dim
+        B=8, K=8, G=4, D=128, hidden=4096, ffn=14336, vocab=32000,
+        chunk=512, verify=6, lora_rank=16, flash_lens=(32, 512, 2048), tp=4,
+    ),
+    "tiny": dict(
+        arch="tiny", slots=4, context=512, page=16,
+        short_prompt=40, long_prompt=300, max_tokens=12,
+        B=4, K=2, G=2, D=16, hidden=64, ffn=128, vocab=512,
+        chunk=32, verify=3, lora_rank=4, flash_lens=(32,),
+        tp=2,  # the tiny preset has two kv heads
+    ),
+}
+
+
+def log(msg: str) -> None:
+    print(f"[chip_smoke] {msg}", file=sys.stderr, flush=True)
+
+
+# --------------------------------------------------------------------------- #
+# Children — the only code here that imports jax
+# --------------------------------------------------------------------------- #
+
+
+def child_probe() -> dict:
+    """What jax sees, and where the program keeps its compile cache."""
+    import jax
+    import jaxlib
+
+    from localai_tpu.utils.compile_cache import configure_compile_cache
+
+    try:
+        from importlib.metadata import version
+
+        libtpu = version("libtpu")
+    except Exception:  # noqa: BLE001 — not installed on CPU-only hosts
+        libtpu = None
+    devs = jax.devices()
+    arr = jax.numpy.zeros((8, 128))
+    return {
+        "device": {"platform": devs[0].platform, "kind": devs[0].device_kind,
+                   "count": len(devs)},
+        "default_backend": jax.default_backend(),
+        "versions": {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                     "libtpu": libtpu},
+        "cache_dir": configure_compile_cache(),
+        # engine.py calls both without a guard (PR 21)
+        "array_has_is_ready": hasattr(arr, "is_ready"),
+        "array_has_copy_to_host_async": hasattr(arr, "copy_to_host_async"),
+    }
+
+
+def child_kernels(size: str, rehearsal: bool) -> dict:
+    """Compile each Pallas kernel through its dispatcher and compare it with
+    its XLA oracle. Returns {"cases": {name: {...}}, "failed": [...]}."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from localai_tpu.models import quant as Q
+    from localai_tpu.ops import attention as A
+    from localai_tpu.ops import lora_matmul as LM
+    from localai_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+    s = SIZES[size]
+    B, K, G, D, page = s["B"], s["K"], s["G"], s["D"], s["page"]
+    H = K * G
+    keys = iter(jax.random.split(jax.random.key(21), 64))
+    cases: dict[str, dict] = {}
+
+    def rnd(shape, dtype=jnp.bfloat16, scale=1.0):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(dtype)
+
+    def rel_err(got, want) -> float:
+        got = np.asarray(got, np.float32)
+        want = np.asarray(want, np.float32)
+        if got.shape != want.shape or not np.isfinite(got).all():
+            return float("inf")
+        return float(np.max(np.abs(got - want))
+                     / (np.max(np.abs(want)) + 1e-6))
+
+    def case(name, fn_auto, fn_oracle, args, tol):
+        """tol bounds max|got-want| / max|want| over every output."""
+        t0 = time.time()
+        rec: dict = {"tol": tol}
+        try:
+            jitted = jax.jit(fn_auto)
+            rec["mosaic"] = "tpu_custom_call" in jitted.lower(*args).as_text()
+            got = jax.block_until_ready(jitted(*args))
+            want = jax.block_until_ready(jax.jit(fn_oracle)(*args))
+            errs = [rel_err(g, w) for g, w in
+                    zip(jax.tree.leaves(got), jax.tree.leaves(want))]
+            rec["err"] = max(errs)
+            rec["ok"] = bool(rec["err"] <= tol
+                             and (rec["mosaic"] or rehearsal))
+        except Exception as e:  # noqa: BLE001 — one refused kernel must not hide the rest
+            rec["ok"] = False
+            rec["error"] = f"{type(e).__name__}: {str(e)[:600]}"
+        rec["smoke_s"] = round(time.time() - t0, 2)
+        cases[name] = rec
+        log(f"kernel {name}: {rec}")
+
+    # -- flash prefill (ops/flash.py) vs dense causal attention --------------
+    # bf16 in/out, f32 accumulation on both sides: one bf16 ulp of the
+    # largest output (2^-8) plus matmul-pass differences -> 2e-2.
+    for S in s["flash_lens"]:
+        lens = jnp.array([S, max(1, S - 7)], jnp.int32)
+        q, k, v = rnd((2, S, H, D)), rnd((2, S, K, D)), rnd((2, S, K, D))
+
+        def valid(lens, S=S):
+            return jnp.arange(S)[None, :] < lens[:, None]
+
+        def on_valid_rows(out, mask):
+            # Compared on the query rows inside each length: flash zeroes
+            # the padded rows, the dense form leaves them unspecified.
+            return jnp.where(mask[:, :, None, None], out, 0)
+
+        def flash(q, k, v, lens):
+            return on_valid_rows(
+                A.prefill_attention(q, k, v, None, lengths=lens), valid(lens))
+
+        def dense(q, k, v, lens):
+            mask = valid(lens)
+            return on_valid_rows(
+                A.causal_prefill_attention(q, k, v, mask), mask)
+
+        case(f"flash_prefill_S{S}", flash, dense, (q, k, v, lens), 2e-2)
+
+    # -- ragged paged attention (ops/paged_flash.py) vs the XLA page walk -----
+    # f32 partials (acc, m, l) from a bf16 pool. Both sides feed the MXU
+    # f32 operands at default precision (softmax weights rounded to bf16,
+    # 2^-8 relative); the decode oracle folds 8 pages per step where the
+    # kernel folds one, so the roundings land differently -> 5e-3. (The
+    # multi-query oracle walks page by page like the kernel and agrees to
+    # the last bit on the v5e.)
+    max_pages = s["context"] // page
+    n_pool = B * max_pages + 1
+    k_pool, v_pool = rnd((n_pool, page, K, D)), rnd((n_pool, page, K, D))
+    perm = jax.random.permutation(next(keys), n_pool - 1)[: B * max_pages] + 1
+    table = perm.reshape(B, max_pages).astype(jnp.int32)
+    limits = jnp.array(
+        [(i * 37 + 11) % (max_pages * page - page) + 1 for i in range(B)],
+        jnp.int32).at[0].set(0).at[1].set(max_pages * page - 1)
+
+    def settled(partials):
+        """(acc, m, l) -> (acc / l, m, l) with the rows of an idle slot
+        (l == 0, m == -1e30) zeroed, so one sentinel cannot set the scale
+        every other entry is compared on."""
+        acc, m, l = partials
+        live = l > 0
+        return (jnp.where(live, acc / jnp.where(live, l, 1.0), 0.0),
+                jnp.where(live, m, 0.0), l)
+
+    def paged(impl):
+        return lambda q, kp, vp, t, lim: settled(A.paged_partials(
+            q, kp, vp, t, lim, impl=impl))
+
+    case("paged_decode", paged("auto"), paged("xla"),
+         (rnd((B, H, D)), k_pool, v_pool, table, limits), 5e-3)
+
+    T = s["verify"]
+    qpos = limits[:, None] + jnp.arange(T)[None, :]
+
+    def paged_mq(impl):
+        return lambda q, kp, vp, t, lim, qp: settled(A.paged_partials_mq(
+            q, kp, vp, t, lim, q_pos=qp, impl=impl))
+
+    case("paged_verify_chunk", paged_mq("auto"), paged_mq("xla"),
+         (rnd((B, T, H, D)), k_pool, v_pool, table, limits, qpos), 5e-3)
+
+    C = s["chunk"]
+    lim1 = jnp.array([max_pages * page - C - 3], jnp.int32)
+    qpos1 = lim1[:, None] + jnp.arange(C)[None, :]
+
+    def paged_chunk(impl):
+        return lambda q, kp, vp, t, lim, qp: settled(A.paged_prefill_partials(
+            q, kp, vp, t, lim, q_pos=qp, impl=impl))
+
+    case("paged_prefill_chunk", paged_chunk("auto"), paged_chunk("xla"),
+         (rnd((1, C, H, D)), k_pool, v_pool, table[:1], lim1, qpos1), 5e-3)
+
+    # -- dequant-matmul (ops/quant_matmul.py) vs models/quant's XLA forms ----
+    # bf16 x and bf16 result over a 4096-long f32 reduction: the kernel
+    # dequantizes to f32 where XLA dequantizes to bf16 -> 2e-2.
+    hid = s["hidden"]
+    x = rnd((B, hid))
+    for out_dim, tag in ((s["ffn"], "ffn"), (s["vocab"], "vocab")):
+        w = rnd((hid, out_dim), jnp.float32, 0.02)
+        gq = jnp.clip(jnp.round(w.reshape(hid // 32, 32, out_dim) / 5e-4),
+                      -127, 127).astype(jnp.int8)
+        forms = {
+            "int8_channel": Q.quantize_tensor(w),
+            "int8_grouped": {"gq": gq, "gs": jnp.full(
+                (hid // 32, 1, out_dim), 5e-4, jnp.float32)},
+            "int4_packed": Q.quantize_tensor_g4(w),
+        }
+        for form, wq in forms.items():
+            case(f"quant_{form}_{tag}",
+                 lambda x, wq: Q.matmul(x, wq, impl="auto"),
+                 lambda x, wq: Q.matmul(x, wq, impl="xla"), (x, wq), 2e-2)
+    head = rnd((s["vocab"], hid), jnp.float32, 0.02)
+    hs = jnp.maximum(jnp.max(jnp.abs(head), axis=-1, keepdims=True) / 127.0,
+                     1e-9)
+    hq = {"q": jnp.clip(jnp.round(head / hs), -127, 127).astype(jnp.int8),
+          "s": hs}
+    case("quant_unembed", lambda h, w: Q.unembed_matmul(h, w, impl="auto"),
+         lambda h, w: Q.unembed_matmul(h, w, impl="xla"), (x, hq), 2e-2)
+
+    # -- ragged LoRA delta (ops/lora_matmul.py) vs the XLA gather form --------
+    # bf16 factors, f32 accumulation; the oracle rounds the rank-r
+    # intermediate to bf16 and the kernel keeps it f32 -> 2e-2.
+    r = s["lora_rank"]
+    fac = {"a": rnd((4, hid, r), scale=0.05), "b": rnd((4, r, hid), scale=0.05)}
+    ids = jnp.arange(B, dtype=jnp.int32) % 4  # the batch mixes all four
+    case("lora_delta",
+         lambda x, f, i: LM.lora_delta(x, f, i, impl="auto"),
+         lambda x, f, i: LM.lora_delta_xla(x, f["a"], f["b"], i),
+         (x, fac, ids), 2e-2)
+
+    failed = sorted(n for n, c in cases.items() if not c["ok"])
+    return {"cases": cases, "failed": failed}
+
+
+def run_child(mode: str, size: str, rehearsal: bool, timeout: float) -> dict:
+    """Run one jax child to its end and parse the JSON line it prints last."""
+    cmd = [sys.executable, os.path.abspath(__file__), "--child", mode,
+           "--size", size] + (["--cpu-rehearsal"] if rehearsal else [])
+    proc = subprocess.run(cmd, cwd=HERE, capture_output=True, text=True,
+                          timeout=timeout)
+    sys.stderr.write(proc.stderr[-6000:])
+    lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
+    if proc.returncode != 0 or not lines:
+        raise RuntimeError(
+            f"child {mode!r} exited {proc.returncode}: "
+            f"{proc.stderr.strip().splitlines()[-1:] or 'no output'}")
+    return json.loads(lines[-1])
+
+
+# --------------------------------------------------------------------------- #
+# Parent — HTTP client and server supervision, no jax
+# --------------------------------------------------------------------------- #
+
+
+class PhaseFailed(Exception):
+    pass
+
+
+def need(cond: bool, msg: str) -> None:
+    if not cond:
+        raise PhaseFailed(msg)
+
+
+def free_port() -> int:
+    # `run --port 0` is dropped by the CLI (`if args.port:`), so the port is
+    # chosen here.
+    with socket.socket() as sk:
+        sk.bind(("127.0.0.1", 0))
+        return sk.getsockname()[1]
+
+
+def cache_entries(path: str) -> int:
+    if not os.path.isdir(path):
+        return 0
+    return sum(len(files) for _, _, files in os.walk(path))
+
+
+class Server:
+    """One `python -m localai_tpu run` child and its log."""
+
+    def __init__(self, models_dir: str, log_path: str, extra_args=()):
+        self.port = free_port()
+        self.log_path = log_path
+        self.t_spawn = time.time()
+        self._log = open(log_path, "w")
+        self.proc = subprocess.Popen(
+            [sys.executable, "-m", "localai_tpu", "run",
+             "--models-path", models_dir, "--port", str(self.port),
+             "--address", "127.0.0.1", "--max-active-models", "1",
+             *extra_args],
+            cwd=HERE, stdout=self._log, stderr=subprocess.STDOUT,
+            start_new_session=True,
+        )
+
+    def get(self, path: str, timeout: float = 30.0):
+        conn = http.client.HTTPConnection("127.0.0.1", self.port,
+                                          timeout=timeout)
+        try:
+            conn.request("GET", path)
+            resp = conn.getresponse()
+            return resp.status, json.loads(resp.read() or b"null")
+        finally:
+            conn.close()
+
+    def wait_ready(self, timeout: float = 180.0) -> float:
+        deadline = time.time() + timeout
+        while time.time() < deadline:
+            if self.proc.poll() is not None:
+                raise PhaseFailed(
+                    f"server exited {self.proc.returncode} before /readyz; "
+                    f"log tail: {self.log_tail()}")
+            try:
+                status, _ = self.get("/readyz", timeout=2.0)
+                if status == 200:
+                    return time.time() - self.t_spawn
+            except OSError:
+                time.sleep(0.5)
+        raise PhaseFailed(f"/readyz not answering after {timeout:.0f}s")
+
+    def log_tail(self, n: int = 2000) -> str:
+        self._log.flush()
+        with open(self.log_path, errors="replace") as f:
+            return f.read()[-n:]
+
+    def log_problems(self) -> list[str]:
+        """Tracebacks and ERROR/CRITICAL records: the engine's containment
+        paths keep serving after a failed compile and only log it."""
+        self._log.flush()
+        bad = []
+        with open(self.log_path, errors="replace") as f:
+            for line in f:
+                if ("Traceback (most recent call last)" in line
+                        or re.match(r"^\S+ \S+ (ERROR|CRITICAL) ", line)):
+                    bad.append(line.strip()[:300])
+        return bad
+
+    def stop(self) -> None:
+        if self.proc.poll() is None:
+            try:
+                os.killpg(self.proc.pid, signal.SIGTERM)
+                self.proc.wait(timeout=60)
+            except (subprocess.TimeoutExpired, ProcessLookupError):
+                pass
+        if self.proc.poll() is None:
+            os.killpg(self.proc.pid, signal.SIGKILL)
+            self.proc.wait(timeout=30)
+        self._log.close()
+
+
+def stream_chat(port: int, model: str, prompt: str, max_tokens: int,
+                n: int = 1, timeout: float = 900.0) -> dict:
+    """One streamed /v1/chat/completions. Raises unless the response is 200,
+    ends in [DONE] and carries one content chunk per completion token (the
+    contract bench.py's HTTP row enforces)."""
+    body = {"model": model, "stream": True, "ignore_eos": True, "n": n,
+            "max_tokens": max_tokens, "temperature": 0.0,
+            "messages": [{"role": "user", "content": prompt}]}
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    t0 = time.time()
+    try:
+        conn.request("POST", "/v1/chat/completions", body=json.dumps(body),
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        if resp.status != 200:
+            raise PhaseFailed(f"HTTP {resp.status}: {resp.read()[:300]!r}")
+        chunks, usage, ttft, done = 0, None, None, False
+        for raw in resp:
+            line = raw.strip()
+            if not line.startswith(b"data:"):
+                continue
+            data = line[5:].strip()
+            if data == b"[DONE]":
+                done = True
+                resp.read()  # drain, so close() is a FIN and not a reset
+                break
+            ev = json.loads(data)
+            if "error" in ev:
+                raise PhaseFailed(f"stream error event: {ev['error']}")
+            usage = ev.get("usage") or usage
+            for ch in ev.get("choices") or ():
+                delta = ch.get("delta") or {}
+                if "content" in delta and "role" not in delta:
+                    ttft = ttft if ttft is not None else time.time() - t0
+                    chunks += 1
+    finally:
+        conn.close()
+    need(done, "stream closed before [DONE]")
+    need(usage is not None, "no usage in the stream")
+    need(chunks == usage["completion_tokens"] == n * max_tokens,
+         f"SSE content chunks {chunks} != usage.completion_tokens "
+         f"{usage['completion_tokens']} (asked {n}x{max_tokens})")
+    return {"chunks": chunks, "prompt_tokens": usage["prompt_tokens"],
+            "smoke_first_content_s": round(ttft or 0.0, 3),
+            "smoke_wall_s": round(time.time() - t0, 3)}
+
+
+def burst(port: int, model: str, letters: str, length: int,
+          max_tokens: int) -> list[dict]:
+    """One concurrent stream per letter. Each prompt repeats its own letter,
+    so no two share a cacheable prefix and the burst goes through batched
+    admission, not through the prefix cache."""
+    out: list = [None] * len(letters)
+
+    def one(i: int) -> None:
+        try:
+            out[i] = stream_chat(port, model, letters[i] * length, max_tokens)
+        except Exception as e:  # noqa: BLE001 — reported below, per request
+            out[i] = e
+
+    threads = [threading.Thread(target=one, args=(i,))
+               for i in range(len(letters))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    errs = [f"req {i}: {e}" for i, e in enumerate(out)
+            if isinstance(e, Exception)]
+    need(not errs, "; ".join(errs[:3]))
+    return out
+
+
+def write_models(models_dir: str, s: dict, quant: str, tp: int) -> dict:
+    """The dense and the paged YAML of the serve phase; returns name->cfg."""
+    import yaml
+
+    base = {
+        "model": s["arch"], "tokenizer": "synthetic-bytes",
+        "max_slots": s["slots"], "context_size": s["context"],
+        "temperature": 0.0, "template": {"family": "chatml"},
+    }
+    if quant:
+        base["quantization"] = quant
+    if tp:
+        base["tensor_parallel"] = tp
+    models = {
+        "smoke-dense": dict(base),
+        # a pool covering the same tokens as the dense cache
+        "smoke-paged": dict(
+            base, kv_pages=s["slots"] * s["context"] // s["page"],
+            kv_page_size=s["page"]),
+    }
+    for name, cfg in models.items():
+        with open(os.path.join(models_dir, f"{name}.yaml"), "w") as f:
+            yaml.safe_dump({"name": name, **cfg}, f)
+    return models
+
+
+def drive_model(srv: Server, name: str, s: dict, paged: bool,
+                platform: str) -> dict:
+    """The traffic one model sees, and what /system must say afterwards."""
+    rec: dict = {}
+    short = "x" * s["short_prompt"]
+    letters = "abcdefghijklmnopqrstuvw"
+    t0 = time.time()
+    first = stream_chat(srv.port, name, short, s["max_tokens"])
+    rec["smoke_setup_s"] = round(time.time() - t0, 1)  # load + first compiles
+    rec["prompt_tokens_short"] = first["prompt_tokens"]
+    for tag, lo in (("cold", 0), ("warm", s["slots"])):
+        t1 = time.time()
+        burst(srv.port, name, letters[lo:lo + s["slots"]],
+              s["short_prompt"], s["max_tokens"])
+        rec[f"smoke_burst_{tag}_s"] = round(time.time() - t1, 1)
+
+    def prefix_hits() -> float:
+        _, system = srv.get("/system")
+        return system["backends"][name].get("prefix_cache_hits", 0.0)
+
+    # Long prompt: the first send fills the prefix cache, the second finds
+    # it and starts the cached-admission compile on a background thread
+    # (serving that request through full admission), a later one runs it.
+    long_prompt = "y" * s["long_prompt"]
+    hits0, sends = prefix_hits(), 0
+    deadline = time.time() + 300
+    while True:
+        r = stream_chat(srv.port, name, long_prompt, 8)
+        sends += 1
+        rec["prompt_tokens_long"] = r["prompt_tokens"]
+        long_hits = prefix_hits() - hits0
+        if long_hits >= 1 or time.time() > deadline:
+            break
+        if sends >= 2:
+            time.sleep(2.0)
+    rec["long_prompt_sends"] = sends
+    if paged:
+        stream_chat(srv.port, name, short + " fork", 16, n=2)
+
+    _, system = srv.get("/system")
+    m = system["backends"][name]
+    info = system["sysinfo"]
+    rec["metrics"] = {k: m.get(k) for k in (
+        "loop_dead", "prefix_cache_hits", "fork_branches",
+        "fork_clone_fallbacks", "kv_pages_peak", "tokens_generated",
+        "prompt_tokens_processed", "peak_active_slots")}
+    rec["placement"] = system["placement"][name]
+    rec["hbm_in_use_bytes"] = [d.get("hbm_in_use_bytes")
+                               for d in info["devices"]]
+    need(info["platform"] == platform,
+         f"/system platform {info['platform']!r}, expected {platform!r}")
+    need(bool(info["devices"][0].get("kind")), "/system has no device kind")
+    need(m["loop_dead"] == 0, "engine loop died")
+    need(m.get("fork_clone_fallbacks", 0) == 0,
+         f"{m.get('fork_clone_fallbacks')} fork branch(es) fell back to clone")
+    need(long_hits >= 1,
+         f"no prefix-cached admission ran in {sends} sends of the long prompt")
+    need(m["peak_active_slots"] >= 2, "the burst never batched")
+    if paged:
+        need(m.get("kv_pages_peak", 0) > 0, "paged engine used no pages")
+        need(m.get("fork_branches", 0) >= 1, "n=2 did not fork a slot")
+    problems = srv.log_problems()
+    need(not problems, f"server log: {problems[:3]}")
+    return rec
+
+
+def phase_serve(s: dict, out_dir: str, platform: str, tag: str,
+                quant: str, tp: int = 0) -> dict:
+    rec: dict = {"models": {}}
+    models_dir = tempfile.mkdtemp(prefix=f"chip_smoke_{tag}_")
+    models = write_models(models_dir, s, quant, tp)
+    srv = Server(models_dir, os.path.join(out_dir, f"server_{tag}.log"))
+    try:
+        rec["smoke_ready_s"] = round(srv.wait_ready(), 1)
+        for name in models:
+            log(f"{tag}: driving {name}")
+            rec["models"][name] = drive_model(
+                srv, name, s, paged="paged" in name, platform=platform)
+            if tp:
+                p = rec["models"][name]["placement"]
+                need(p["plan"]["tp"] == tp, f"plan {p['plan']}, asked tp={tp}")
+                for what in ("param_devices", "kv_devices"):
+                    need(len(set(p[what])) == tp,
+                         f"{what} on {p[what]}, expected {tp} devices")
+                hbm = rec["models"][name]["hbm_in_use_bytes"][:tp]
+                # CPU devices report no memory stats (rehearsal)
+                need(platform != "tpu"
+                     or (all(hbm) and max(hbm) <= 1.25 * min(hbm)),
+                     f"uneven HBM use across the tp devices: {hbm}")
+    finally:
+        srv.stop()
+        shutil.rmtree(models_dir, ignore_errors=True)
+    return rec
+
+
+def phase_restart(s: dict, out_dir: str, cache_dir: str) -> dict:
+    """Second start of the same server: the lone request of the serve phase
+    again. Every program it needs was cached by the first start."""
+    before = cache_entries(cache_dir)
+    models_dir = tempfile.mkdtemp(prefix="chip_smoke_restart_")
+    write_models(models_dir, s, "int8", 0)
+    srv = Server(models_dir, os.path.join(out_dir, "server_restart.log"))
+    try:
+        srv.wait_ready()
+        t0 = time.time()
+        stream_chat(srv.port, "smoke-paged", "x" * s["short_prompt"],
+                    s["max_tokens"])
+        setup = round(time.time() - t0, 1)
+        problems = srv.log_problems()
+    finally:
+        srv.stop()
+        shutil.rmtree(models_dir, ignore_errors=True)
+    after = cache_entries(cache_dir)
+    rec = {"smoke_setup_s": setup, "cache_entries_before": before,
+           "cache_entries_after": after}
+    need(not problems, f"server log: {problems[:3]}")
+    need(before > 0, f"the first start left nothing in {cache_dir}")
+    need(after == before,
+         f"second start added {after - before} cache entries")
+    return rec
+
+
+def phase_replicas(s: dict, out_dir: str, n: int) -> dict:
+    """Four tp=1 same-host cluster replicas: each on its own device?"""
+    models_dir = tempfile.mkdtemp(prefix="chip_smoke_replicas_")
+    write_models(models_dir, s, "int8", 1)
+    os.remove(os.path.join(models_dir, "smoke-dense.yaml"))
+    srv = Server(models_dir, os.path.join(out_dir, "server_replicas.log"),
+                 extra_args=("--cluster-replicas", str(n)))
+    try:
+        srv.wait_ready()
+        burst(srv.port, "smoke-paged", "abcdefghijklmnop"[:2 * n],
+              s["short_prompt"], s["max_tokens"])
+        _, system = srv.get("/system")
+        problems = srv.log_problems()
+    finally:
+        srv.stop()
+        shutil.rmtree(models_dir, ignore_errors=True)
+    reps = system["placement"]["smoke-paged"]["replicas"]
+    where = {r: p["param_devices"] for r, p in reps.items()}
+    need(not problems, f"server log: {problems[:3]}")
+    need(len(where) == n, f"{len(where)} replicas, asked {n}")
+    need(len({tuple(v) for v in where.values()}) == n,
+         f"replicas share devices: {where}")
+    return {"replica_param_devices": where}
+
+
+def phase_kernels(size: str, rehearsal: bool) -> dict:
+    res = run_child("kernels", size, rehearsal, timeout=900)
+    need(not res["failed"], f"kernels failed: {res['failed']}: " + "; ".join(
+        f"{n}: {res['cases'][n].get('error', res['cases'][n])}"
+        for n in res["failed"][:3]))
+    return {"cases": {n: {k: c[k] for k in ("mosaic", "err", "tol") if k in c}
+                      for n, c in res["cases"].items()}}
+
+
+def phase_four_chip(s: dict, out_dir: str, device: dict) -> dict:
+    if device["count"] < 4:
+        return {"status": f"skipped: {device['count']} device(s)"}
+    rec = phase_serve(s, out_dir, device["platform"], "tp", "", tp=s["tp"])
+    rec["replicas"] = phase_replicas(s, out_dir, 4)
+    return rec
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--cpu-rehearsal", action="store_true",
+                    help="tiny widths on the CPU backend; not a chip result")
+    ap.add_argument("--phases", default=",".join(PHASES),
+                    help=f"comma list out of {PHASES}")
+    ap.add_argument("--child", choices=("probe", "kernels"),
+                    help=argparse.SUPPRESS)
+    ap.add_argument("--size", choices=tuple(SIZES), default="full",
+                    help=argparse.SUPPRESS)
+    args = ap.parse_args()
+
+    if not os.path.isdir(os.path.join(HERE, "localai_tpu")):
+        print("chip_smoke.py: no localai_tpu/ beside this script — it drives "
+              "the repository's server and must run from a checkout",
+              file=sys.stderr)
+        return 2
+    if args.child:
+        sys.path.insert(0, HERE)
+        res = (child_probe() if args.child == "probe"
+               else child_kernels(args.size, args.cpu_rehearsal))
+        print(json.dumps(res))
+        return 0
+
+    phases = [p for p in args.phases.split(",") if p]
+    unknown = sorted(set(phases) - set(PHASES))
+    if unknown:
+        ap.error(f"unknown phase(s) {unknown}")
+    rehearsal = args.cpu_rehearsal
+    size = "tiny" if rehearsal else "full"
+    s = SIZES[size]
+    if rehearsal:
+        os.environ["JAX_PLATFORMS"] = "cpu"  # children inherit it
+        log("CPU REHEARSAL at tiny widths — this is not a chip result")
+
+    probe = run_child("probe", size, rehearsal, timeout=300)
+    device = probe["device"]
+    if device["platform"] != "tpu" and not rehearsal:
+        print(f"chip_smoke.py: jax found no TPU (devices: {device}); the "
+              "smoke only means something on the chip. --cpu-rehearsal "
+              "walks the same script at tiny widths.", file=sys.stderr)
+        return 1
+    array_api_ok = all(probe[k] for k in ("array_has_is_ready",
+                                          "array_has_copy_to_host_async"))
+    cache_dir = probe["cache_dir"]
+    out_dir = os.path.join(HERE, "chiprun_out", "chip_smoke")
+    os.makedirs(out_dir, exist_ok=True)
+    summary: dict = {
+        "ok": False, "device": device, "rehearsal": rehearsal,
+        "default_backend": probe["default_backend"],
+        "versions": probe["versions"],
+        "compile_cache": {"dir": cache_dir,
+                          "entries_before": cache_entries(cache_dir)},
+        "phases": {},
+    }
+
+    def run_phase(name: str, fn) -> None:
+        t0 = time.time()
+        rec: dict = {}
+        try:
+            rec.update(fn() or {})
+            rec["status"] = rec.get("status", "passed")
+        except (PhaseFailed, RuntimeError, OSError, KeyError,
+                subprocess.TimeoutExpired, json.JSONDecodeError) as e:
+            rec.update(status="failed", error=f"{type(e).__name__}: {e}"[:800])
+        rec["smoke_wall_s"] = round(time.time() - t0, 1)
+        summary["phases"][name] = rec
+        log(f"phase {name}: {rec['status']} in {rec['smoke_wall_s']}s"
+            + (f" — {rec['error']}" if "error" in rec else ""))
+
+    table = {
+        "kernels": lambda: phase_kernels(size, rehearsal),
+        "serve": lambda: phase_serve(s, out_dir, device["platform"],
+                                     "int8", "int8"),
+        "restart": lambda: phase_restart(s, out_dir, cache_dir),
+        "four_chip": lambda: phase_four_chip(s, out_dir, device),
+    }
+    for name in PHASES:
+        if name in phases:
+            run_phase(name, table[name])
+
+    summary["compile_cache"]["entries_after"] = cache_entries(cache_dir)
+    statuses = [p["status"] for p in summary["phases"].values()]
+    summary["ok"] = bool(array_api_ok and statuses and all(
+        st == "passed" or st.startswith("skipped") for st in statuses))
+    summary["claim"] = None
+    # The detailed summary first, and on disk; the last stdout line is the
+    # driver's verdict and holds exactly "ok" and "device".
+    with open(os.path.join(out_dir, "summary.json"), "w") as f:
+        json.dump(summary, f, indent=1)
+    print(json.dumps(summary))
+    print(json.dumps({"ok": summary["ok"], "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}}), flush=True)
+    return 0 if summary["ok"] else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -54,10 +54,8 @@ def _head_shard_map(fn, mesh, in_specs, out_specs):
     shard) and runs the unmodified kernel on local shapes; no collective is
     introduced — the psum stays at the o-projection where GSPMD already puts
     it (row-parallel wo, parallel/sharding.py)."""
-    from localai_tpu.parallel.mesh import shard_map as _shard_map
-
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def prefill_attention(
@@ -325,9 +323,7 @@ def _sp_cache_partials(q, k_cache, v_cache, limits, mesh,
     # replicated operand (closure capture of tracers is not valid under
     # shard_map).
     sl_in = sliding if sliding is not None else jnp.zeros((), bool)
-    from localai_tpu.parallel.mesh import shard_map as _shard_map
-
-    fn = _shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

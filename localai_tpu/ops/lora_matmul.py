@@ -108,7 +108,7 @@ def _lora_kernel(ids_ref, x_ref, a_ref, b_ref, o_ref):
     the grid pipeline via the scalar-prefetched ids (see _lora_call); the
     rank-r intermediate lives only in registers."""
     del ids_ref  # consumed by the BlockSpec index maps, not the body
-    x = x_ref[...].astype(jnp.float32)  # [1, IN]
+    x = x_ref[0].astype(jnp.float32)  # [1, IN]
     a = a_ref[0].astype(jnp.float32)  # [IN, R]
     b = b_ref[0].astype(jnp.float32)  # [R, bo]
     t = jax.lax.dot_general(
@@ -117,7 +117,7 @@ def _lora_kernel(ids_ref, x_ref, a_ref, b_ref, o_ref):
     y = jax.lax.dot_general(
         t, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # [1, bo]
-    o_ref[...] = y.astype(o_ref.dtype)
+    o_ref[0] = y.astype(o_ref.dtype)
 
 
 def _lora_call(x2, a, b, ids):
@@ -125,7 +125,10 @@ def _lora_call(x2, a, b, ids):
 
     x2 [N, IN] float; a [NA, IN, R]; b [NA, R, OUT]; ids [N] int32.
     Returns [N, OUT] in x2.dtype. Grid (N, out-tiles); the adapter ids ride
-    scalar prefetch so the factor BlockSpecs gather per-row segments."""
+    scalar prefetch so the factor BlockSpecs gather per-row segments. x and
+    the output ship as [N, 1, ·] so a one-row block spans the array's whole
+    second-minor dim (Mosaic's block rule: a multiple of 8 or the full
+    dim)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -140,15 +143,15 @@ def _lora_call(x2, a, b, ids):
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, k_in), lambda i, j, ids: (i, 0)),
+                pl.BlockSpec((1, 1, k_in), lambda i, j, ids: (i, 0, 0)),
                 pl.BlockSpec((1, k_in, r), lambda i, j, ids: (ids[i], 0, 0)),
                 pl.BlockSpec((1, r, bo), lambda i, j, ids: (ids[i], 0, j)),
             ],
-            out_specs=pl.BlockSpec((1, bo), lambda i, j, ids: (i, j)),
+            out_specs=pl.BlockSpec((1, 1, bo), lambda i, j, ids: (i, 0, j)),
         ),
-        out_shape=jax.ShapeDtypeStruct((n, out), x2.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, 1, out), x2.dtype),
         interpret=_interpret(),
-    )(ids, x2, a, b)
+    )(ids, x2[:, None, :], a, b)[:, 0, :]
 
 
 # --------------------------------------------------------------------------- #
@@ -184,8 +187,6 @@ def _sharded_lora_delta(x, a, b, ids, mesh, part: str):
     over "tp" here (the declared ICI boundary — see COLLECTIVE_BOUNDARY)."""
     from jax.sharding import PartitionSpec as P
 
-    from localai_tpu.parallel.mesh import shard_map as _shard_map
-
     row = part == "row"
     fspecs = lora_factor_specs(part)
     # The engine's stacked factors carry a leading L axis the per-layer
@@ -201,7 +202,7 @@ def _sharded_lora_delta(x, a, b, ids, mesh, part: str):
             y = jax.lax.psum(y, "tp")
         return y
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, a_spec, b_spec, P(None)),
         out_specs=o_spec,

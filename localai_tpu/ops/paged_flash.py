@@ -25,7 +25,10 @@ Shapes (matching the XLA reference):
   are computed from it before the body runs).
 - limits     [B] int32 — rows with global index >= limits[b] are masked;
   the page walk is bounded by ceil(limits[b]/page).
-- qpos       [B, QR] int32 query positions (sliding-window distance).
+- qpos       [B, QR] int32 query positions (sliding-window distance);
+  shipped to the kernel as [B, QR, 1] so a slot's block spans the array's
+  whole last two dims (Mosaic's block rule) and lands row-per-sublane,
+  the orientation the [QR, page] masks broadcast from.
 - sliding    [1] int32 — traced per-layer flag (gemma-2 alternates
   sliding/global layers inside a scanned stack, so it cannot be static).
 
@@ -95,7 +98,7 @@ def _ragged_paged_kernel(
         budget.
 
     Then: limits_ref [B] i32, sliding_ref [1] i32 (both prefetch), and the
-    regular operands q_ref [1, K, QR, Dk] f32, qpos_ref [1, QR] i32,
+    regular operands q_ref [1, K, QR, Dk] f32, qpos_ref [1, QR, 1] i32,
     kvs_ref [2, K] f32 SMEM, k_hbm/v_hbm pools (ANY), outputs acc/m/l, VMEM
     scratch kbuf/vbuf/acc_s/m_s/l_s and the DMA semaphores.
 
@@ -121,7 +124,7 @@ def _ragged_paged_kernel(
         limits_ref,  # scalar-prefetch [B] i32
         sliding_ref,  # scalar-prefetch [1] i32
         q_ref,  # [1, K, QR, Dk] f32 (scale applied)
-        qpos_ref,  # [1, QR] i32
+        qpos_ref,  # [1, QR, 1] i32
         kvs_ref,  # [2, K] f32 SMEM — per-head (k, v) dequant scales (fp8
         # KV); ones when the pool is unscaled (multiply is exact identity)
         k_hbm,  # [P, page, K, Dk] pool dtype, memory_space=ANY
@@ -203,13 +206,11 @@ def _ragged_paged_kernel(
         )
         valid = gpos < lim
         if window:
-            qp = qpos_ref[0]  # [QR]
             sl = sliding_ref[0] > 0
-            dist = qp[:, None] - gpos
+            dist = qpos_ref[0] - gpos  # [QR, 1] - [QR, page]
             valid = valid & (~sl | (dist < window))
         if swin:
-            qp = qpos_ref[0]  # [QR]
-            dist = qp[:, None] - gpos
+            dist = qpos_ref[0] - gpos
             valid = valid & ((gpos < sink) | (dist < swin))
 
         for kh in range(num_kv):  # static unroll — one MXU pass per kv head
@@ -293,10 +294,10 @@ def _paged_partials_rows(
             grid=(B,),
             in_specs=[
                 pl.BlockSpec((1, K, QR, Dk), lambda b, *_: (b, 0, 0, 0)),
-                pl.BlockSpec((1, QR), lambda b, *_: (b, 0)),
+                pl.BlockSpec((1, QR, 1), lambda b, *_: (b, 0, 0)),
                 pl.BlockSpec(memory_space=pltpu.SMEM),  # [2, K] kv scales
-                pl.BlockSpec(memory_space=pltpu.ANY),  # pool stays in HBM
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),  # pool stays in HBM
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=[
                 pl.BlockSpec((1, K, QR, Dv), lambda b, *_: (b, 0, 0, 0)),
@@ -320,7 +321,7 @@ def _paged_partials_rows(
         interpret=interpret,
     )(
         *tbl_args, limits.astype(jnp.int32), sl_arr,
-        qr, qpos_rows.astype(jnp.int32), kvs, k_pool, v_pool,
+        qr, qpos_rows.astype(jnp.int32)[..., None], kvs, k_pool, v_pool,
     )
     return acc, m[..., :1], l[..., :1]
 

@@ -113,7 +113,8 @@ def _qmm_kernel(x_ref, w_ref, s_ref, *rest, gs: int, gc: int, packed: bool):
     f32, optional z (1, gc, bo) f32, out (1, N, bo), acc scratch (N, bo)
     f32. gs == 0 means the flat per-channel form (scale applied once at the
     final write); packed means two nibbles per weight byte along the
-    in-group axis (low nibble = first gs/2 elements — models/quant.py).
+    in-group axis (low nibble = first gs/2 elements — models/quant.py),
+    shipped bitcast to int8.
     """
     import jax.experimental.pallas as pl
 
@@ -127,16 +128,21 @@ def _qmm_kernel(x_ref, w_ref, s_ref, *rest, gs: int, gc: int, packed: bool):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     xb = x_ref[0].astype(jnp.float32)  # [N, kc]
-    wb = w_ref[0]  # [kc(,/2), bo] int8/uint8
+    wb = w_ref[0]  # [kc(,/2), bo] int8 (packed: two nibbles per byte)
     bo = wb.shape[-1]
     if packed:
+        # Widen to 32 bits BEFORE any reshape/bit op: Mosaic has no
+        # uint8→f32 convert and no 8-bit shifts, and the (gc, gs/2, bo)
+        # regroup only lands on whole sublane tiles at 32-bit width (the
+        # int8 tile is 32 rows, the half-group is 16). The wrapper bitcasts
+        # the uint8 bytes to int8, so the sign-extended arithmetic shift is
+        # masked back to the nibble.
         half = gs // 2
-        wp = wb.reshape(gc, half, bo)
-        nib = jnp.concatenate([wp & jnp.uint8(0xF), wp >> jnp.uint8(4)],
-                              axis=1)  # [gc, gs, bo]
-        wf = nib.astype(jnp.float32)
+        wi = wb.astype(jnp.int32).reshape(gc, half, bo)
+        nib = jnp.concatenate([wi & 0xF, (wi >> 4) & 0xF], axis=1)
+        wf = nib.astype(jnp.float32)  # [gc, gs, bo]
     elif gs:
-        wf = wb.reshape(gc, gs, bo).astype(jnp.float32)
+        wf = wb.astype(jnp.float32).reshape(gc, gs, bo)
     else:
         wf = wb.astype(jnp.float32)  # flat: [kc, bo]
     if gs:
@@ -149,9 +155,18 @@ def _qmm_kernel(x_ref, w_ref, s_ref, *rest, gs: int, gc: int, packed: bool):
         preferred_element_type=jnp.float32,
     )
     if z_ref is not None:
-        # Affine zero point: −Σᵢ x_{g,i} · z_{g,o} per group.
+        # Affine zero point: −Σᵢ x_{g,i} · z_{g,o} per group. The per-group
+        # x sums ride the MXU against a 0/1 group-membership matrix — a
+        # lane-splitting reshape of x is not something Mosaic lowers.
         zb = z_ref[0].astype(jnp.float32)  # [gc, bo]
-        xs = xb.reshape(xb.shape[0], gc, gs).sum(axis=-1)  # [N, gc]
+        row = jax.lax.broadcasted_iota(jnp.int32, (gc * gs, gc), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (gc * gs, gc), 1)
+        member = ((row >= col * gs) & (row < (col + 1) * gs)).astype(
+            jnp.float32)
+        xs = jax.lax.dot_general(
+            xb, member, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [N, gc]
         acc_ref[...] -= jax.lax.dot_general(
             xs, zb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -212,9 +227,13 @@ def _qmm_call(x3, wq, s3, z3, *, gs: int, packed: bool, out_dtype,
 
     E, kin_w, out = wq.shape
     _, N, kin = x3.shape
+    if packed:  # same bytes; the kernel masks the nibbles out of int32
+        wq = jax.lax.bitcast_convert_type(wq, jnp.int8)
     if gs:
         g = kin // gs
-        gc = _tile(g, (16, 8, 4, 2))
+        # Scale/zero blocks are (1, gc, bo): gc must be a whole number of
+        # 8-row sublane tiles or the full group axis.
+        gc = _tile(g, (16, 8))
         kc = gc * gs
         kc_w = kc // 2 if packed else kc
     else:
@@ -369,8 +388,6 @@ def _sharded_quant_matmul(x, w, mesh, part: str, moe_sub=None):
     "tp" here (the declared ICI boundary — see COLLECTIVE_BOUNDARY)."""
     from jax.sharding import PartitionSpec as P
 
-    from localai_tpu.parallel.mesh import shard_map as _shard_map
-
     row = part == "row"
     x_ax = [None] * x.ndim
     if row:
@@ -398,7 +415,7 @@ def _sharded_quant_matmul(x, w, mesh, part: str, moe_sub=None):
 
     leaf = w.get("q", w.get("gq", w.get("g4")))
     moe = leaf.ndim == (3 if "q" in w else 4)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(*x_ax), _w_specs(w, part, moe=moe)),
         out_specs=P(*o_ax),

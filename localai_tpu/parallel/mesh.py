@@ -53,18 +53,3 @@ def plan_for_devices(n: int, tp: Optional[int] = None) -> MeshPlan:
     if n % tp != 0:
         raise ValueError(f"{n} devices not divisible by tp={tp}")
     return MeshPlan(dp=n // tp, tp=tp)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """`jax.shard_map` across jax versions: the top-level binding (and its
-    `check_vma` kwarg) landed after the 0.4.x series; older releases ship it
-    as `jax.experimental.shard_map` with the same semantics under
-    `check_rep`. Every shard_map in this repo routes through here so the sp
-    matrix runs on both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)

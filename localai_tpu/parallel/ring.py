@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from localai_tpu.parallel.mesh import shard_map as _shard_map
-
 NEG_INF = -1e30
 
 # Declared ICI-collective boundary (lint: sharding-consistency): the ring
@@ -106,7 +104,7 @@ def ring_prefill_attention(
     n = mesh.shape[axis]
     seq_spec = P(None, axis, None, None)
     if sliding is None:
-        fn = _shard_map(
+        fn = jax.shard_map(
             partial(_local_ring, axis=axis, n_shards=n, softcap=softcap),
             mesh=mesh,
             in_specs=(seq_spec, seq_spec, seq_spec, P(None)),
@@ -116,7 +114,7 @@ def ring_prefill_attention(
         return fn(q, k, v, lengths)
     # `sliding` is a traced bool scalar (layer alternation) — it rides as a
     # replicated operand so one shard_map serves both layer kinds.
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda q_, k_, v_, l_, sl_: _local_ring(
             q_, k_, v_, l_, axis=axis, n_shards=n, softcap=softcap,
             window=window, sliding=sl_,
@@ -251,7 +249,7 @@ def ring_chunk_paged_attention(
            else kv_scale.astype(jnp.float32))
     sl_in = sliding if sliding is not None else jnp.zeros((), bool)
     tbl_spec = _pt.shard_spec(table, P(None, None), P(None, None))
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(
             _local_ring_chunk, axis=axis, n_shards=n, softcap=softcap,
             window=window, has_sliding=sliding is not None, sink=sink,

@@ -22,6 +22,7 @@ from localai_tpu.engine.tokenizer import load_tokenizer
 from localai_tpu.parallel.mesh import MeshPlan
 from localai_tpu.templates import Evaluator
 from localai_tpu.testing import faults
+from localai_tpu.utils.compile_cache import configure_compile_cache
 
 log = logging.getLogger("localai_tpu.manager")
 
@@ -140,6 +141,9 @@ class ModelManager:
         self._quarantined_until: dict[str, float] = {}
         self._quarantine_total: dict[str, int] = {}
         faults.ensure_env_installed()
+        # Every engine kind this manager loads (LLM, bert, image, audio)
+        # compiles into one persistent cache, placed before the first load.
+        configure_compile_cache()
         # Multi-host serving bootstrap (ISSUE 13): wire this process into
         # the global device mesh BEFORE any engine touches jax. Idempotent
         # — a no-op for single-process deployments and for entrypoints
@@ -291,6 +295,17 @@ class ModelManager:
             cfg = self.configs.get(name)
             if cfg is None:
                 raise KeyError(f"model {name!r} not found")
+            # Make room BEFORE loading: a model that fills more than half
+            # the HBM (7B int8 on a 16 GB chip) cannot load beside the one
+            # it replaces. Idle LRU victims only; when everything is busy
+            # the load proceeds over budget and the post-load pass below
+            # evicts once a victim goes idle.
+            with self._lock:
+                evicting = self._evict_lru_locked(incoming=1)
+            for t in evicting:
+                t.join()
+            if evicting:
+                gc.collect()  # an engine's jit closures cycle back to it
             try:
                 lm = self._load(cfg)
             except (KeyError, RuntimeError):
@@ -525,28 +540,36 @@ class ModelManager:
         lm.engine.cache = None
         gc.collect()
 
-    def _evict_lru_locked(self, protect: str = "") -> None:
+    def _evict_lru_locked(self, protect: str = "",
+                          incoming: int = 0) -> list[threading.Thread]:
         """Reference: watchdog.go:135-195 LRU to MaxActiveBackends.
 
         `protect` is the model a get() is about to hand to its caller — never
-        evict it, even though its lease hasn't been acquired yet."""
+        evict it, even though its lease hasn't been acquired yet. `incoming`
+        counts models about to load: the budget is met as if they already
+        had. Returns the teardown threads it started, for a caller that
+        needs the HBM back before it goes on."""
+        started: list[threading.Thread] = []
         budget = self.app_cfg.max_active_models
         if budget <= 0:
-            return  # unlimited — HBM is the only budget (reference default)
-        while len(self._loaded) > budget:
+            return started  # unlimited — HBM is the only budget (reference default)
+        while len(self._loaded) + incoming > budget:
             idle = [
                 (lm.last_used, n)
                 for n, lm in self._loaded.items()
                 if lm.in_flight == 0 and n != protect
             ]
             if not idle:
-                return  # everything busy; let the next call retry
+                break  # everything busy; let the next call retry
             _, victim = min(idle)
             lm = self._loaded.pop(victim)
-            threading.Thread(
+            t = threading.Thread(
                 target=self._drain_and_teardown, args=(lm, 30.0), daemon=True,
                 name="unload-drain",
-            ).start()
+            )
+            t.start()
+            started.append(t)
+        return started
 
     def _resolve_ckpt_dir(self, model: str) -> str:
         import os
@@ -740,6 +763,19 @@ class ModelManager:
             params = init_params_quantized(
                 arch, jax.random.key(0), mode=cfg.quantization
             )
+        elif plan.total > 1:
+            # Each chip generates its own shard: a whole-tree init on the
+            # default device needs the full bf16 model in ONE chip's HBM
+            # (mistral-7b is 14.5 GB of a 16 GB v5e) before the engine
+            # could spread it.
+            from localai_tpu.parallel.mesh import build_mesh
+            from localai_tpu.parallel.sharding import param_shardings
+
+            params = jax.jit(
+                lambda k: init_params(arch, k),
+                out_shardings=param_shardings(
+                    arch, build_mesh(plan, engine_devices)),
+            )(jax.random.key(0))
         else:
             params = jax.jit(lambda k: init_params(arch, k))(jax.random.key(0))
 
@@ -835,10 +871,19 @@ class ModelManager:
             n_local = max(1, n_replicas)
             roles = parse_roles(n_local, self.app_cfg.cluster_role)
             replicas = [LocalReplica("r0", engine, role=roles[0])]
+            # Replica i takes the i-th slice of plan.total devices when the
+            # host has one for each (r0 already sits on the first slice —
+            # build_mesh takes the leading devices); a host with fewer
+            # devices stacks them on the first slice, sharing the weights.
+            width = engine.plan.total
+            host_devs = jax.devices()
+            spread = (engine_devices is None
+                      and n_local * width <= len(host_devs))
             for i in range(1, n_local):
                 extra = Engine(
                     arch, params, tokenizer, mesh_plan=plan,
-                    devices=engine_devices,
+                    devices=(host_devs[i * width:(i + 1) * width] if spread
+                             else engine_devices),
                     engine_cfg=engine.ecfg, quantization=cfg.quantization,
                 )
                 extra.start()

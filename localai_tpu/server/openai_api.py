@@ -1040,16 +1040,23 @@ class OpenAIApi:
     def system(self, req: Request) -> Response:
         import jax
 
-        from localai_tpu.utils.sysinfo import device_info, recommend_mesh
+        from localai_tpu.utils.sysinfo import (
+            device_info,
+            engine_placement,
+            recommend_mesh,
+        )
 
         loaded = self.manager.loaded_names()
         backends = {}
+        placement = {}
         for n in loaded:
             lm = self.manager.peek(n)  # never trigger a load from a monitoring poll
             if lm is not None:
                 backends[n] = lm.engine.metrics()
+                placement[n] = engine_placement(lm.engine)
         return Response(body={
             "backends": backends,
+            "placement": placement,
             "loaded_models": loaded,
             "configured_models": self.manager.configs.names(),
             "devices": [str(d) for d in jax.devices()],

@@ -52,6 +52,39 @@ def device_info() -> dict[str, Any]:
     return out
 
 
+def engine_placement(engine) -> Optional[dict[str, Any]]:
+    """Where one loaded engine actually sits: the mesh plan it ended up
+    with (after any tensor_parallel clamp or max_valid_tp degrade) and the
+    ids of the devices that hold its parameter and KV shards. A cluster
+    facade reports each same-host replica. None for engines without a mesh
+    (bert, image, audio)."""
+    import jax
+
+    replicas = getattr(engine, "local_replicas", None)
+    if replicas is not None:
+        return {"replicas": {r.name: engine_placement(r.engine)
+                             for r in replicas}}
+    plan = getattr(engine, "plan", None)
+    mesh = getattr(engine, "mesh", None)
+    if plan is None or mesh is None:
+        return None
+
+    def device_ids(tree) -> list[int]:
+        # .sharding survives buffer donation; .devices() on a donated
+        # array does not.
+        return sorted({
+            d.id for leaf in jax.tree.leaves(tree)
+            for d in leaf.sharding.device_set
+        })
+
+    return {
+        "plan": {"dp": plan.dp, "tp": plan.tp, "ep": plan.ep, "sp": plan.sp},
+        "mesh_devices": [int(d.id) for d in mesh.devices.flat],
+        "param_devices": device_ids(engine.params),
+        "kv_devices": device_ids(engine.cache),
+    }
+
+
 def recommend_mesh(n_devices: Optional[int] = None) -> dict[str, int]:
     """Default mesh sizes: all devices on tp (fastest interconnect gets the
     fastest-varying parallelism — the scaling-book recipe used by

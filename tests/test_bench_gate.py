@@ -147,12 +147,25 @@ def test_main_exit_codes(tmp_path, capsys):
     assert payload["regressions"][0]["key"] == "decode_tps"
 
 
-def test_gate_on_real_rounds_if_present():
-    """The shipped BENCH_r04 payload parses (r05 crashed — rc=124 — and
-    carries no parsed metrics; the gate's job starts at the next clean
-    TPU round)."""
-    p = os.path.join(REPO, "BENCH_r04.json")
+def test_gate_on_real_rounds_if_present(tmp_path):
+    """A payload of the wrapped shape the round records had ({n, cmd, rc,
+    tail, parsed}) parses, string and boolean fields and all. The records
+    themselves (BENCH_r01-r05) were deleted in PR 21; the figures below are
+    made up."""
+    p = _write(tmp_path, "round.json", {
+        "n": 4, "cmd": "python bench.py", "rc": 0,
+        "tail": "bench devices: [...]\n{\"metric\": ...}",
+        "parsed": {
+            "metric": "decode_tokens_per_sec_http_bs8", "unit": "tok/s",
+            "value": 600.0, "vs_baseline": None,
+            "decode_tokens_per_sec_paged": 1300.0,
+            "decode_tokens_per_sec_int8": 2700.0,
+            "paged_vs_dense_tps": 0.7, "long_ctx_paged": True,
+            "long_ctx_prefill_ms": 3500.0, "p50_ttft_ms": 100.0,
+        },
+    })
     m = load_metrics(p)
     assert "decode_tokens_per_sec_paged" in m
+    assert "long_ctx_paged" not in m and "unit" not in m
     r = compare(m, m)
     assert r["regressions"] == [] and r["improvements"] == []

@@ -120,3 +120,48 @@ def test_finetune_chain_semantics():
     plain = ModelConfig.from_dict({"name": "h", "model": "tiny"})
     assert not needs_finetune(plain)
     assert finetune(plain, "p", "x") == "x"
+
+
+def test_compile_cache_placement_rule(tmp_path, monkeypatch):
+    """PR 21: JAX_COMPILATION_CACHE_DIR set -> jax keeps the value it read
+    from the variable (the helper sets no directory in code); unset -> one
+    fixed directory inside the checkout, whatever the working directory."""
+    import subprocess
+    import sys
+
+    import jax
+
+    from localai_tpu.utils import compile_cache as cc
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    fixed = os.path.join(repo, ".jax_cache")
+    knobs = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in knobs}
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert cc.configure_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        monkeypatch.chdir(tmp_path)  # a second working directory
+        assert cc.configure_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+
+    # Placed from outside: a fresh interpreter, because jax reads the
+    # variable at import.
+    placed = str(tmp_path / "placed-from-outside")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax\n"
+         "from localai_tpu.utils.compile_cache import configure_compile_cache\n"
+         "print(configure_compile_cache())\n"
+         "print(jax.config.jax_compilation_cache_dir)\n"],
+        cwd=str(tmp_path), text=True, capture_output=True, timeout=120,
+        check=True,
+        env={**os.environ, "PYTHONPATH": repo,
+             "JAX_COMPILATION_CACHE_DIR": placed},
+    ).stdout.split()
+    assert out == [placed, placed]
+    assert os.path.isdir(placed)  # a bad directory would have raised
