@@ -49,18 +49,22 @@ import queue
 import threading
 import time
 from collections import OrderedDict, deque
-from functools import partial
 from typing import Any, Iterator, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 import numpy as np
 
 from localai_tpu.models import llama
 from localai_tpu.engine import speclookup
-from localai_tpu.engine.runtime import ControlStager, DeadlineIndex, LoopPhases
+from localai_tpu.engine.runtime import (
+    SPAN_SLICE_S,
+    ControlStager,
+    DeadlineIndex,
+    LoopPhases,
+)
 from localai_tpu.models.config import ArchConfig
-from localai_tpu.observe import fence as ofence
 from localai_tpu.observe import postmortem as opostmortem
 from localai_tpu.observe import trace as otrace
 from localai_tpu.observe.journal import EventJournal
@@ -312,13 +316,6 @@ class EngineConfig:
     # timeline and the postmortem journal tail). LOCALAI_TRACE_JOURNAL
     # env var overrides.
     trace_journal_events: int = 4096
-    # Fenced per-dispatch device timing (debug): when true, every decode-
-    # block dispatch blocks until the device finishes and the journal's
-    # loop_iter record carries the fenced device time — this SERIALIZES
-    # the pipeline (pipeline_depth effectively 1), so it is a measurement
-    # mode, never a serving default. LOCALAI_TRACE_FENCE env var
-    # overrides ("1" enables).
-    trace_fence: bool = False
     # Flight-recorder output directory (ISSUE 11): where the engine dumps
     # its postmortem JSON (journal tail + state snapshot) when the loop
     # dies. "" = a stable tempdir child (observe/postmortem.default_dir).
@@ -588,6 +585,10 @@ class RequestHandle:
         # Admission-dispatch stamp (_note_admitted): terminal events derive
         # timing_queue_wait from it.
         self.t_admit: float = 0.0
+        # Decode blocks in flight when the request was admitted, from its
+        # admission until its first token out of a decode block is posted
+        # (the `decode_first` journal event): -1 before, -2 after.
+        self.join_blocks: int = -1
 
     def __iter__(self) -> Iterator[TokenEvent]:
         while True:
@@ -648,6 +649,26 @@ def _parse_buckets_env(val: str) -> tuple[int, ...]:
     return tuple(
         int(x) for x in val.replace("|", ",").split(",") if x.strip()
     )
+
+
+# Every program the engine jits, by kind. The compiled module is named
+# `jit_<kind>` (shapes and flags stay in the fingerprint), so a profiler
+# trace, a compile log and the benchmark's readers tell the decode block
+# from an admission by name (docs/OBSERVABILITY.md).
+PROGRAM_NAMES = frozenset({
+    "decode_block", "spec_block", "admit", "admit_spec", "admit_cached",
+    "admit_cached_paged", "prefill_chunk", "prefill_chunk_final", "chunk_pin",
+    "span_copy", "fork", "page_copy", "ctrl_copy", "snapshot", "pages_gather",
+    "swap_in", "resume_restore", "rng_set", "sd_sync", "sd_sync_paged",
+    "prefill", "embed", "score", "quantize_params",
+})
+
+
+def _named_jit(fn, name: str, **kw):
+    """`jax.jit(fn, **kw)` under a stable program name (compile-time only)."""
+    assert name in PROGRAM_NAMES, name
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **kw)
 
 
 def _host_copy_async(arr: Any) -> None:
@@ -760,7 +781,6 @@ class Engine:
             "LOCALAI_LORA_KERNEL": ("lora_kernel", str),
             "LOCALAI_ADAPTER_CACHE_BYTES": ("adapter_cache_bytes", int),
             "LOCALAI_TRACE_JOURNAL": ("trace_journal_events", int),
-            "LOCALAI_TRACE_FENCE": ("trace_fence", _parse_flag_env),
             "LOCALAI_POSTMORTEM_DIR": ("postmortem_dir", str),
             "LOCALAI_SPEC_MODE": ("spec_mode", str),
             "LOCALAI_SELF_DRAFT_LAYERS": ("self_draft_layers", int),
@@ -1053,8 +1073,9 @@ class Engine:
                 # the weight shardings (models/quant.py). Checkpoints too big
                 # for HBM in bf16 arrive pre-quantized from the loader
                 # instead (load_hf_checkpoint quantize=).
-                self.params = jax.jit(
-                    lambda p: quantize_params(cfg, p, quantization)
+                self.params = _named_jit(
+                    lambda p: quantize_params(cfg, p, quantization),
+                    "quantize_params",
                 )(self.params)
             if self.ecfg.kv_pages > 0:
                 # Paged pool [L, P, page, K, Hd]: kv-heads shard over tp;
@@ -1456,15 +1477,14 @@ class Engine:
         self.m_adapter_promotes = 0
         self.m_adapter_evictions = 0
         # Request-lifecycle observability (ISSUE 11, docs/OBSERVABILITY.md):
-        # the loop-owned event journal (None = disabled), the fenced-timing
-        # debug flag, a submit-side id counter for requests that carry no
-        # caller request_id, and the path of the last flight-recorder dump
+        # the loop-owned event journal (None = disabled), a submit-side id
+        # counter for requests that carry no caller request_id, and the
+        # path of the last flight-recorder dump
         # (surfaced via the loop_dead gauge labels + manager log).
         self._journal = (
             EventJournal(self.ecfg.trace_journal_events)
             if self.ecfg.trace_journal_events > 0 else None
         )
-        self._trace_fence = bool(self.ecfg.trace_fence)
         self._postmortem_path = ""
         # Pipelined loop runtime (ISSUE 17, docs/ENGINE_RUNTIME.md).
         # thread: single-writer engine-loop — the control stager's cache
@@ -1491,9 +1511,14 @@ class Engine:
         # gen)], flushed on ticks and before the owning slot finishes.
         self._hk_last = 0.0
         self._deferred_saves: list[tuple] = []
-        self._last_fence_ms = 0.0
         self.m_loop_host_ms = 0.0
+        self.m_loop_blocked_ms = 0.0  # the `pull` phase: blocked on the device
         self.m_loop_blocks = 0
+        # Decode rows (steps x compiled batch rows), see _count_rows.
+        self.m_rows_dispatched = 0
+        self.m_rows_posted = 0
+        self.m_rows_overshoot = 0
+        self.m_rows_empty = 0
         self._build_programs()
 
     # ------------------------------------------------------------------ #
@@ -2161,7 +2186,7 @@ class Engine:
             def gather(k, v, pages):
                 return k[:, pages], v[:, pages]
 
-            fn = jax.jit(gather)
+            fn = _named_jit(gather, "pages_gather")
             self._block_cache[key] = fn
         return fn
 
@@ -2174,7 +2199,7 @@ class Engine:
                 v = cache.v.at[:, pages].set(hv.astype(cache.v.dtype))
                 return llama.KVCache(k=k, v=v)
 
-            fn = jax.jit(swap_in, donate_argnums=(0,))
+            fn = _named_jit(swap_in, "swap_in", donate_argnums=(0,))
             self._block_cache[key] = fn
         return fn
 
@@ -2191,7 +2216,8 @@ class Engine:
                 d_positions = d_positions.at[slot].set(pos)
                 return counts, rngs, bias, d_tokens, d_positions
 
-            fn = jax.jit(restore, donate_argnums=(0, 1, 2, 3, 4))
+            fn = _named_jit(restore, "resume_restore",
+                            donate_argnums=(0, 1, 2, 3, 4))
             self._block_cache[("resume-restore",)] = fn
         return fn
 
@@ -2201,7 +2227,7 @@ class Engine:
             def setrng(rngs, slot, rngd):
                 return rngs.at[slot].set(jax.random.wrap_key_data(rngd))
 
-            fn = jax.jit(setrng, donate_argnums=(0,))
+            fn = _named_jit(setrng, "rng_set", donate_argnums=(0,))
             self._block_cache[("rng-set",)] = fn
         return fn
 
@@ -2802,24 +2828,21 @@ class Engine:
         )
         op_mesh = self._op_mesh
 
-        @partial(jax.jit, static_argnames=())
         def _prefill(params, tokens, lengths):
             return llama.prefill(cfg, params, tokens, lengths, mesh=op_mesh, ep=self.plan.ep)
 
-        @partial(jax.jit)
         def _embed(params, tokens, lengths):
             return llama.encode(cfg, params, tokens, lengths, mesh=op_mesh, ep=self.plan.ep)
 
-        @partial(jax.jit)
         def _score(params, tokens, lengths, cond_lengths):
             return llama.sequence_logprob(
                 cfg, params, tokens, lengths, cond_lengths, mesh=op_mesh,
                 ep=self.plan.ep,
             )
 
-        self._prefill_fn = _prefill
-        self._embed_fn = _embed
-        self._score_fn = _score
+        self._prefill_fn = _named_jit(_prefill, "prefill")
+        self._embed_fn = _named_jit(_embed, "embed")
+        self._score_fn = _named_jit(_score, "score")
 
     def _get_block(self, variant: str, n: int, with_lp: bool = False,
                    with_dfa: bool = False, kv_win: Optional[int] = None,
@@ -2997,7 +3020,7 @@ class Engine:
         # Positional wrapper: [8 base] [rope_delta?] [ptable?] [dfa: mask,
         # trans, cls, gstate] [lora: stacks, ids] — mirrors
         # _dispatch_block's argument assembly.
-        def wrapped(*args):
+        def program(*args):
             i = 8
             rope_delta = None
             if mrope:
@@ -3019,7 +3042,7 @@ class Engine:
         donate = (1, 2, 3, 5, 6)
         if with_dfa:
             donate = donate + (8 + (1 if mrope else 0) + (1 if paged else 0) + 3,)
-        fn = jax.jit(wrapped, donate_argnums=donate)
+        fn = _named_jit(program, "decode_block", donate_argnums=donate)
         self._block_cache[key] = fn
         return fn
 
@@ -3135,7 +3158,7 @@ class Engine:
             # [img 2?] [mrope?] [dfa 4?] [ptable?] [lora 2?] — mirrors
             # _dispatch_admit's arg assembly so every flag combination
             # shares one code path.
-            def wrapped(*args):
+            def program(*args):
                 i = 7
                 params, cache, counts, rngs, bias, d_tokens, d_positions = args[:7]
                 d_gstate = None
@@ -3170,7 +3193,7 @@ class Engine:
                              d_gstate=d_gstate, ptable=ptable, lora=lora)
 
             donate = (1, 2, 3, 4, 5, 6) + ((7,) if with_dfa else ())
-            fn = jax.jit(wrapped, donate_argnums=donate)
+            fn = _named_jit(program, "admit", donate_argnums=donate)
         else:
             dcfg = self.draft_cfg
 
@@ -3210,7 +3233,7 @@ class Engine:
                 # d_gstate is the LAST positional arg (after the 13 fixed,
                 # the 4 dfa tables, and the optional ptable).
                 donate = donate + (13 + 4 + (1 if paged else 0),)
-            fn = jax.jit(admit_spec, donate_argnums=donate)
+            fn = _named_jit(admit_spec, "admit_spec", donate_argnums=donate)
         self._admit_cache[key] = fn
         return fn
 
@@ -3307,7 +3330,7 @@ class Engine:
 
         dcfg = self.draft_cfg
 
-        def wrapped(*args):
+        def program(*args):
             # Positional assembly mirrors _dispatch_admit_cached: [7 state]
             # [d_gstate?] [dparams, dcache?] [pk, pv] [tail, full, aux,
             # samp] [bias_rows?] [dfa 4?].
@@ -3351,7 +3374,7 @@ class Engine:
             donate = donate + (7,)
         if draft:
             donate = donate + (7 + (1 if with_dfa else 0) + 1,)  # dcache
-        fn = jax.jit(wrapped, donate_argnums=donate)
+        fn = _named_jit(program, "admit_cached", donate_argnums=donate)
         if not build_only:
             self._admit_cache[key] = fn
         return fn
@@ -3439,7 +3462,7 @@ class Engine:
 
         dcfg = self.draft_cfg
 
-        def wrapped(*args):
+        def program(*args):
             # Same positional assembly as _get_admit_cached, with the span
             # operands (pages, table_row) in place of (pk, pv).
             i = 7
@@ -3483,7 +3506,8 @@ class Engine:
             donate = donate + (7,)
         if draft:
             donate = donate + (7 + (1 if with_dfa else 0) + 1,)  # dcache
-        fn = jax.jit(wrapped, donate_argnums=donate)
+        fn = _named_jit(program, "admit_cached_paged",
+                        donate_argnums=donate)
         if not build_only:
             self._admit_cache[key] = fn
         return fn
@@ -3617,7 +3641,7 @@ class Engine:
                 d_positions = d_positions.at[slot].set(S - 1)
                 return cache, d_positions, aux
 
-        fn = jax.jit(chunk, donate_argnums=(1, 2))
+        fn = _named_jit(chunk, "prefill_chunk", donate_argnums=(1, 2))
         self._block_cache[key] = fn
         return fn
 
@@ -3634,7 +3658,7 @@ class Engine:
             def pin(d_positions, slot):
                 return d_positions.at[slot].set(S - 1)
 
-            fn = jax.jit(pin, donate_argnums=(0,))
+            fn = _named_jit(pin, "chunk_pin", donate_argnums=(0,))
             self._block_cache[("chunk-pin",)] = fn
         return fn
 
@@ -3652,7 +3676,7 @@ class Engine:
                     cache.v, pv.astype(cache.v.dtype), (0, slot, 0, 0, 0))
                 return llama.KVCache(k=k, v=v)
 
-            fn = jax.jit(copy, donate_argnums=(0,))
+            fn = _named_jit(copy, "span_copy", donate_argnums=(0,))
             self._block_cache[key] = fn
         return fn
 
@@ -3731,7 +3755,7 @@ class Engine:
 
         dcfg = self.draft_cfg
 
-        def wrapped(*args):
+        def program(*args):
             # Positional assembly mirrors _get_admit_cached_paged with
             # (table_row,) in place of (pages, table_row): [7 state]
             # [d_gstate?] [dparams, dcache?] [table_row, tail, full, aux,
@@ -3779,7 +3803,8 @@ class Engine:
             donate = donate + (7,)
         if draft:
             donate = donate + (7 + (1 if with_dfa else 0) + 1,)  # dcache
-        fn = jax.jit(wrapped, donate_argnums=donate)
+        fn = _named_jit(program, "prefill_chunk_final",
+                        donate_argnums=donate)
         self._admit_cache[key] = fn
         return fn
 
@@ -4165,7 +4190,7 @@ class Engine:
             return out
 
         donate = (0, 1, 2, 3, 4) + ((12,) if with_dfa else ())
-        fn = jax.jit(fork_fn, donate_argnums=donate)
+        fn = _named_jit(fork_fn, "fork", donate_argnums=donate)
         self._admit_cache[key] = fn
         return fn
 
@@ -4185,7 +4210,7 @@ class Engine:
             v = cache.v.at[:, dstp].set(cache.v[:, srcp])
             return llama.KVCache(k=k, v=v)
 
-        fn = jax.jit(copy_page, donate_argnums=(0,))
+        fn = _named_jit(copy_page, "page_copy", donate_argnums=(0,))
         self._admit_cache[key] = fn
         return fn
 
@@ -4215,7 +4240,7 @@ class Engine:
             return out
 
         donate = (0, 1, 2, 3, 4) + ((6,) if with_dfa else ())
-        fn = jax.jit(ctrl_copy, donate_argnums=donate)
+        fn = _named_jit(ctrl_copy, "ctrl_copy", donate_argnums=donate)
         self._admit_cache[key] = fn
         return fn
 
@@ -4804,7 +4829,7 @@ class Engine:
                     cache.v, (0, slot, 0, 0, 0), (L, 1, pb, K, vd))
                 return k, v
 
-            fn = jax.jit(snap)
+            fn = _named_jit(snap, "snapshot")
             self._snap_cache[pb] = fn
         return fn
 
@@ -5648,7 +5673,7 @@ class Engine:
         has_dstate = mode in ("draft_model", "self_draft")
         nhead = 4 if has_dstate else 2  # params [dparams] cache [dcache]
 
-        def wrapped(*args):
+        def program(*args):
             if mode == "draft_model":
                 params, dparams, cache, dcache = args[:4]
             elif mode == "self_draft":
@@ -5693,7 +5718,7 @@ class Engine:
             base = 8 + 1  # + drafts operand
         if with_dfa:
             donate = donate + (base + (1 if paged else 0) + 3,)
-        fn = jax.jit(wrapped, donate_argnums=donate)
+        fn = _named_jit(program, "spec_block", donate_argnums=donate)
         self._block_cache[key] = fn
         return fn
 
@@ -5922,6 +5947,9 @@ class Engine:
     def _note_admitted(self, handle: RequestHandle) -> None:
         """Record one request's queue wait into the admission-latency EWMA
         (loop thread only; handles built outside submit() carry no stamp)."""
+        if handle.join_blocks == -1:
+            handle.join_blocks = sum(
+                1 for e in self._inflight if e.kind in ("block", "spec"))
         if handle.t_submit <= 0.0:
             return
         handle.t_admit = time.monotonic()
@@ -6083,6 +6111,10 @@ class Engine:
             out["adapter_promotes"] = float(self.m_adapter_promotes)
             out["adapter_evictions"] = float(self.m_adapter_evictions)
         out["peak_active_slots"] = float(self.m_peak_active)
+        out["decode_rows_dispatched"] = float(self.m_rows_dispatched)
+        out["decode_rows_posted"] = float(self.m_rows_posted)
+        out["decode_rows_overshoot"] = float(self.m_rows_overshoot)
+        out["decode_rows_empty"] = float(self.m_rows_empty)
         if self.m_forks or self.m_fork_clone_fallbacks:
             # Tree-batched fork sampling (ISSUE 18): branches admitted by
             # slot fork vs degraded to the N-clone path (fault/pressure).
@@ -6094,6 +6126,7 @@ class Engine:
             # transfer economy (skips = commits served from cache).
             out["loop_blocks"] = float(self.m_loop_blocks)
             out["loop_host_ms_total"] = float(self.m_loop_host_ms)
+            out["loop_blocked_ms_total"] = float(self.m_loop_blocked_ms)
             out["loop_host_overhead_per_block_ms"] = float(
                 self.m_loop_host_ms / self.m_loop_blocks
             )
@@ -6625,23 +6658,31 @@ class Engine:
             done.set()
 
     def _loop(self) -> None:
-        trace = os.environ.get("LOCALAI_ENGINE_TRACE", "0") == "1"
         self._charge_last = time.monotonic()
         self._charge_was_active = False
+        try:
+            self._loop_body()
+        finally:
+            self._phases.end()  # closes the open loop/<phase> span
+
+    def _loop_body(self) -> None:
+        # Every moment of the loop lies in one phase (LoopPhases): a phase
+        # begins where its work does and runs until the next one begins, so
+        # an iteration that finds nothing to do stays in `wait` and a
+        # waiting loop is one `loop/wait` span, not one per spin.
         ph = self._phases
         pipelined = bool(self.ecfg.loop_prepare_ahead)
         while not self._shutdown.is_set():
             faults.fire("engine_loop")  # injected loop death (ISSUE 4)
             self._charge()
-            ph.mark()
             ph.iters += 1
             did = processed = False
             jr = self._journal
-            if jr is not None:
+            if jr is not None and jr.staged():
                 # Move cross-thread events (queued, span export) into the
                 # single-writer ring in order.
+                ph.begin("drain")
                 jr.drain_staged()
-            ph.lap("drain")
             if pipelined:
                 # Budgeted sidecar (ISSUE 17): purge/deadline sweeps run on
                 # a DUE tick — the deadline heap says something expired, or
@@ -6649,12 +6690,12 @@ class Engine:
                 # pending request every iteration.
                 now = time.monotonic()
                 if self._hk_due(now):
+                    ph.begin("housekeeping")
                     self._housekeeping(now)
-                ph.lap("housekeeping")
             else:
+                ph.begin("purge")
                 self._purge_pending()
                 self._enforce_deadlines()
-                ph.lap("purge")
             self._drain_span_inbox()
 
             if self._growth_blocked and not self.h_active.any():
@@ -6662,6 +6703,8 @@ class Engine:
                 # during the drain) — nothing is waiting on pages anymore,
                 # so admission must unblock or the queue starves.
                 self._growth_blocked = False
+            if self._pending or self._fork_requests or self._admit_hold_start:
+                ph.begin("admit")
             if self._fork_requests:
                 # Mid-stream forks (Engine.fork) execute at a quiesce point;
                 # while any are staged, hold new admissions and blocks so
@@ -6669,7 +6712,6 @@ class Engine:
                 self._service_forks()
             admitted = (False if self._fork_requests
                         else self._admit_pending())
-            ph.lap("admit")
             # Only host-walk grammars force single-step, serialized blocks;
             # DFA-constrained slots pipeline at full depth like everyone else.
             grammar = self._legacy_grammar_active()
@@ -6699,6 +6741,7 @@ class Engine:
             if dispatchable and not hold:
                 t0 = time.monotonic()
                 try:
+                    # begins the prep, commit and dispatch phases itself
                     did = self._dispatch_block(grammar)
                 except Exception as e:  # noqa: BLE001 — fail requests, not the loop
                     self._fail_block(e)
@@ -6706,17 +6749,8 @@ class Engine:
                     continue
                 if did:
                     dispatch_ms = (time.monotonic() - t0) * 1000.0
-                    ent = self._inflight[-1]
-                    # Optional fenced device time (LOCALAI_TRACE_FENCE):
-                    # the fence module is the declared sync point — this
-                    # serializes the pipeline and is debug-only.
-                    self._last_fence_ms = (ofence.fenced_wait_ms(ent.toks)
-                                           if self._trace_fence else 0.0)
-                    self._jnote("decode_block", slot=-1, a=float(ent.n),
-                                b=dispatch_ms)
-                    if trace:
-                        print(f"[eng {time.monotonic():.3f}] dispatch block n={self._inflight[-1].n} "
-                              f"took {(time.monotonic()-t0)*1000:.1f}ms inflight={len(self._inflight)}")
+                    self._jnote("decode_block", slot=-1,
+                                a=float(self._inflight[-1].n), b=dispatch_ms)
                     nblocks += 1
                 elif not self._inflight:
                     # Pool exhausted mid-decode and every in-flight dispatch
@@ -6729,28 +6763,24 @@ class Engine:
             # chunk in flight at a time, so the device alternates decode
             # blocks and prefill chunks instead of stalling every live slot
             # behind a monolithic long-prompt prefill.
-            self._advance_chunked()
+            if self._chunkings:
+                ph.begin("dispatch")
+                self._advance_chunked()
 
             if not pipelined:
                 # Cold-page spill tick (ISSUE 14): pages that fell out of
                 # every live query's sink+window move to the host tier,
                 # bounded per iteration so the copy never stalls dispatch.
                 # Pipelined loops run this from the budgeted sidecar.
+                ph.begin("dispatch")
                 self._spill_cold_pages()
-            ph.lap("dispatch")
 
             if self._inflight:
                 front = self._inflight[0]
-                fr = front.ready()
-                if fr or nblocks >= depth or not active:
-                    t0 = time.monotonic()
-                    e = self._inflight.popleft()
-                    self._process_entry(e)
+                if front.ready() or nblocks >= depth or not active:
+                    # begins the pull and process phases itself
+                    self._process_entry(self._inflight.popleft())
                     processed = True
-                    ph.lap("process")
-                    if trace:
-                        print(f"[eng {time.monotonic():.3f}] process {e.kind} n={e.n} ready={fr} "
-                              f"took {(time.monotonic()-t0)*1000:.1f}ms inflight={len(self._inflight)}")
                 else:
                     # The loop would otherwise wait on the in-flight block:
                     # prepare the NEXT block's control plan (so the post-
@@ -6759,41 +6789,41 @@ class Engine:
                     staged = False
                     if pipelined and not grammar:
                         try:
+                            # begins the prep phase when it builds a plan
                             staged = self._stage_plan()
                         except Exception as e:  # noqa: BLE001 — same containment as dispatch
                             self._fail_block(e)
                             self._flush_loop_iter(False, False)
                             continue
-                    ph.lap("prep")
                     if pipelined:
                         now = time.monotonic()
                         if self._hk_due(now, idle=True):
+                            ph.begin("housekeeping")
                             self._housekeeping(now)
-                        ph.lap("housekeeping")
                     if not staged:
                         # Nothing ready, nothing to prepare (e.g. grammar
                         # mode waiting on an in-flight admit): don't
                         # busy-spin.
+                        ph.begin("wait")
                         if pipelined:
                             self._wake.wait(timeout=0.001)
                             self._wake.clear()
                         else:
                             time.sleep(0.001)
-                    ph.lap("wait")
             elif not active and not admitted:
                 if pipelined:
                     now = time.monotonic()
                     if self._hk_due(now, idle=True):
+                        ph.begin("housekeeping")
                         self._housekeeping(now)
-                    ph.lap("housekeeping")
+                ph.begin("wait")
                 self._wake.wait(timeout=0.05)
                 self._wake.clear()
-                ph.lap("wait")
             elif hold and not did:
                 # Held dispatch with nothing in flight to process: brief
                 # pause (chunk progress and spill above already ran).
+                ph.begin("wait")
                 time.sleep(0.0005)
-                ph.lap("wait")
             self._flush_loop_iter(did, processed)
 
     # thread: engine-loop-only
@@ -6901,6 +6931,7 @@ class Engine:
         iterations instead would flood the 4096-event ring and evict the
         lifecycle events a postmortem needs."""
         ph = self._phases
+        ph.sync()
         host_ms = ph.total()  # excludes the wait phase
         if not (did or processed) and host_ms < 25.0:
             if ph.ms["wait"] >= 1000.0:
@@ -6910,13 +6941,12 @@ class Engine:
                 ph.reset()
             return
         self.m_loop_host_ms += host_ms
+        self.m_loop_blocked_ms += ph.ms["pull"]
         if did:
             self.m_loop_blocks += 1
         self._jnote(
             "loop_iter", slot=-1, a=float(int(self.h_active.sum())),
-            b=(self._last_fence_ms if (self._trace_fence and did)
-               else host_ms),
-            phases=ph.vector(),
+            b=host_ms, phases=ph.vector(),
         )
         ph.reset()
 
@@ -7387,13 +7417,10 @@ class Engine:
         # lora-enabled program (id 0 rows ride the exact-zero null adapter)
         # so mixed-tenant and adapter-less admissions share one compile.
         with_lora = self._lora_tree is not None
-        trace = os.environ.get("LOCALAI_ENGINE_TRACE", "0") == "1"
-        t_a = time.monotonic()
         with_dfa = self._dfa_mode_of(dfa_tables)
         fn = self._get_admit(m, bucket, has_bias, with_topk, with_lp, n_img,
                              with_dfa=with_dfa, with_mrope=with_mrope,
                              with_lora=with_lora, with_logits=with_logits)
-        t_b = time.monotonic()
         args_in = (
             jnp.asarray(prompt_toks), jnp.asarray(aux), jnp.asarray(samp_pack),
             # lint: ignore[trace-safety] admit programs are compiled per (m, bucket) by design and warmed (warmup()); m is the admission group size, already bucketed by the batching loop
@@ -7460,23 +7487,25 @@ class Engine:
             args_in = args_in + (
                 self._lora_tree, jnp.asarray(adapter_rows, dtype=jnp.int32),
             )
-        t_c = time.monotonic()
         try:
-            if self.draft_cfg is None:
-                pre = (self.params, self.cache, self.counts, self.rngs, self.bias,
-                       self.d_tokens, self.d_positions)
-                if with_dfa:
-                    pre = pre + (self.d_gstate,)
-                out = fn(*pre, *args_in)
-            else:
-                pre = (self.params, self.cache, self.counts, self.rngs, self.bias,
-                       self.d_tokens, self.d_positions, self.draft_params,
-                       self.d_cache)
-                if with_dfa:
-                    # admit_spec takes the dfa inputs after bias_rows, d_gstate last.
-                    out = fn(*pre, *args_in, self.d_gstate)
-                else:
+            # The span a trace matches the admission's device execution to.
+            with TraceAnnotation("dispatch/admit", m=m, bucket=bucket,
+                                 live=int(self.h_active.sum())):
+                if self.draft_cfg is None:
+                    pre = (self.params, self.cache, self.counts, self.rngs,
+                           self.bias, self.d_tokens, self.d_positions)
+                    if with_dfa:
+                        pre = pre + (self.d_gstate,)
                     out = fn(*pre, *args_in)
+                else:
+                    pre = (self.params, self.cache, self.counts, self.rngs,
+                           self.bias, self.d_tokens, self.d_positions,
+                           self.draft_params, self.d_cache)
+                    if with_dfa:
+                        # admit_spec takes the dfa inputs after bias_rows, d_gstate last.
+                        out = fn(*pre, *args_in, self.d_gstate)
+                    else:
+                        out = fn(*pre, *args_in)
         except Exception:
             # Slots were never claimed, so _release won't run — return the
             # reserved pages and adapter pins before surfacing the error.
@@ -7497,11 +7526,7 @@ class Engine:
             self.d_cache = rest[0]
         if with_logits:
             self._fork_logits = out[-1]
-        t_d = time.monotonic()
         _host_copy_async(toks)
-        if trace:
-            print(f"[eng {time.monotonic():.3f}] dispatch admit m={m} bucket={bucket} "
-                  f"get={1e3*(t_b-t_a):.1f} h2d={1e3*(t_c-t_b):.1f} call={1e3*(t_d-t_c):.1f}ms")
         # Claim slots only after a successful dispatch so a failed admission
         # (e.g. compile error) never leaks slot state.
         for j, ((r, handle), slot_idx) in enumerate(zip(chunk, slot_ids)):
@@ -7611,6 +7636,7 @@ class Engine:
         self._staged_plan = None
         if not self.h_active.any() or not self._has_unscheduled():
             return False
+        self._phases.begin("prep")
         plan = self._plan_block(False)
         if isinstance(plan, _BlockPlan):
             self._staged_plan = plan
@@ -7745,11 +7771,12 @@ class Engine:
         if (not isinstance(plan, _BlockPlan) or plan.epoch != self._ctrl_epoch
                 or plan.grammar != grammar
                 or not self.ecfg.loop_prepare_ahead):
+            self._phases.begin("prep")
             plan = self._plan_block(grammar)
-        self._phases.lap("prep")
         if plan is None or isinstance(plan, str):
             return False
         if plan.spec is not None:
+            self._phases.begin("dispatch")
             smode, sp = plan.spec
             self._dispatch_spec_block(smode, sp[0], sp[1], sp[2],
                                       plan.with_dfa)
@@ -7808,6 +7835,7 @@ class Engine:
         is exactly this method (ISSUE 17)."""
         n = p.n
         active_snapshot = p.active
+        self._phases.begin("commit")
         fn = self._get_block(p.variant, n, p.with_lp, p.with_dfa, p.kv_win,
                              p.with_lora)
         d_pack, d_rope, d_adapter = self._commit_ctrl(p)
@@ -7820,20 +7848,27 @@ class Engine:
         if self._paged:
             args = args + (self._ptable_device(),)
         lora_args = ((self._lora_tree, d_adapter) if p.with_lora else ())
-        self._phases.lap("commit")
+        self._phases.begin("dispatch")
         if p.with_dfa:
             d = self._dfa
+            args = args + (d["mask_bits"], self._dfa_table(d, p.with_dfa),
+                           d["tok_cls"], self.d_gstate)
+        # The span a trace matches the block's device execution to: its
+        # steps and the rows that were live when it was dispatched.
+        with TraceAnnotation("dispatch/decode_block", n=n,
+                             live=int(active_snapshot.sum())):
+            out = fn(*args, *lora_args)
+        if p.with_dfa:
             (
                 self.cache, self.counts, self.rngs, self.d_tokens,
                 self.d_positions, toks_block, tk_block, lp_block, self.d_gstate,
-            ) = fn(*args, d["mask_bits"], self._dfa_table(d, p.with_dfa),
-                   d["tok_cls"], self.d_gstate, *lora_args)
+            ) = out
             self.m_dfa_tokens += n * int((self.h_gmask * active_snapshot).sum())
         else:
             (
                 self.cache, self.counts, self.rngs, self.d_tokens, self.d_positions,
                 toks_block, tk_block, lp_block,
-            ) = fn(*args, *lora_args)
+            ) = out
         _host_copy_async(toks_block)
         if tk_block is not None:
             _host_copy_async(tk_block)
@@ -7978,7 +8013,7 @@ class Engine:
                 v=sd.v.at[:, slot].set(cache.v[:kl, slot].astype(sd.v.dtype)),
             )
 
-        fn = jax.jit(sync, donate_argnums=(0,))
+        fn = _named_jit(sync, "sd_sync", donate_argnums=(0,))
         self._block_cache[("sd-sync",)] = fn
         return fn
 
@@ -8010,7 +8045,7 @@ class Engine:
                 v=sd.v.at[:, slot, :W].set(gv.astype(sd.v.dtype)),
             )
 
-        fn = jax.jit(sync, donate_argnums=(0,))
+        fn = _named_jit(sync, "sd_sync_paged", donate_argnums=(0,))
         self._block_cache[key] = fn
         return fn
 
@@ -8055,7 +8090,9 @@ class Engine:
                            d["tok_cls"], self.d_gstate)
         if with_lora:
             args = args + (self._lora_tree, jnp.asarray(self.h_adapter))
-        out = fn(*args)
+        with TraceAnnotation("dispatch/spec_block", n=kb + 1,
+                             live=int(active_snapshot.sum())):
+            out = fn(*args)
         if mode == "draft_model":
             self.cache, self.d_cache = out[0], out[1]
             rest = out[2:]
@@ -8112,14 +8149,24 @@ class Engine:
         self._charge_was_active = active
 
     def _process_entry(self, e: _Entry) -> None:
+        # `pull` is the time blocked on the device result (nothing when the
+        # drainer already has it); `process` is posting it.
+        self._phases.begin("pull")
         if isinstance(e.host, Exception):
             raise e.host
         if e.host is not None:
             toks, tk, lp = e.host  # pre-pulled by the drainer thread
         else:
             # Forced processing (depth pressure) before the drainer got
-            # there: pull inline. np.asarray is idempotent, so the drainer
+            # there: wait for the result in slices, so that the blocked
+            # time is `loop/pull` spans a capture can hold (LoopPhases);
+            # the drainer sets _wake the moment its own copy is done. Then
+            # pull inline. np.asarray is idempotent, so the drainer
             # finishing its own copy later is harmless.
+            while not e.ready():
+                self._wake.wait(timeout=SPAN_SLICE_S)
+                self._wake.clear()
+                self._phases.begin("pull")
             # lint: ignore[trace-safety] deliberate sync point: the drainer thread usually completed the copy (this is a cheap wait, not a walk), and when it has not, the loop NEEDS these results to schedule the next block
             toks = np.asarray(e.toks)
             # lint: ignore[trace-safety] same drainer-backed pull as toks above
@@ -8127,6 +8174,7 @@ class Engine:
             lp = (
                 tuple(np.asarray(a) for a in e.lp) if e.lp is not None else None
             )  # (tok_lp, lp_ids, lp_vals)
+        self._phases.begin("process")
         # Charge the just-completed block's interval BEFORE any done events
         # post: a caller reading the throughput counters right after
         # result() returns must see this block's time in the denominator.
@@ -8143,6 +8191,7 @@ class Engine:
             consumed = 0
             emitted_per = np.zeros((self.ecfg.max_slots,), np.int64)
             for step in range(e.n):
+                self._phases.begin("process")  # slices a long phase's span
                 for i in range(self.ecfg.max_slots):
                     if not e.active[i] or self._slot_gen[i] != e.gen[i]:
                         continue
@@ -8153,10 +8202,12 @@ class Engine:
                         continue
                     consumed += 1
                     emitted_per[i] += 1
+                    self._note_decode_first(i)
                     self._post_token(i, tok)
             self.m_spec_rounds += int((emitted_per > 0).sum())
             self.m_spec_accepted += consumed
             self._decode_tokens += consumed
+            self._count_rows(e, consumed)
             # Acceptance-aware scheduling (ISSUE 12): fold each slot's
             # accepted/drafted ratio into its EWMA — the NEXT round's draft
             # length comes from it. A round always emits one non-draft
@@ -8218,6 +8269,7 @@ class Engine:
 
         consumed = 0
         for step in range(e.n):
+            self._phases.begin("process")  # slices a long phase's span
             for i in range(self.ecfg.max_slots):
                 if not e.active[i] or self._slot_gen[i] != e.gen[i]:
                     continue
@@ -8241,8 +8293,45 @@ class Engine:
                     tok = chosen
                 consumed += 1
                 lpi = (lp[0][step, i], lp[1][step, i], lp[2][step, i]) if lp is not None else None
+                self._note_decode_first(i)
                 self._post_token(i, tok, lpi)
         self._decode_tokens += consumed
+        self._count_rows(e, consumed)
+
+    # thread: engine-loop-only
+    def _count_rows(self, e: _Entry, posted: int) -> None:
+        """Account one decode or spec block's rows: steps x compiled batch
+        rows were computed; `posted` of them carried a token that a handle
+        received; rows not live at dispatch were `empty`; the rest were
+        live at dispatch and lost before their step (the request ended
+        inside the block, its slot changed generation, or a verify round
+        rejected the draft) — `overshoot`. dispatched = posted + overshoot
+        + empty, exactly."""
+        rows = e.n * self.ecfg.max_slots
+        live = e.n * int(e.active.sum())
+        self.m_rows_dispatched += rows
+        self.m_rows_posted += posted
+        self.m_rows_empty += rows - live
+        self.m_rows_overshoot += live - posted
+        # The same account per block, for a reader that needs it over an
+        # exact span of time rather than between two scrapes.
+        self._jnote("decode_rows", a=float(rows), b=float(posted))
+        self._jnote("decode_rows_lost", a=float(live - posted),
+                    b=float(rows - live))
+
+    # thread: engine-loop-only
+    def _note_decode_first(self, slot_idx: int) -> None:
+        """Journal, once per request, the first token it gets from a decode
+        block: `first_token` -> `decode_first` is its wait to join the
+        decode stream (blocks dispatched before its admission run first)."""
+        h = self.slots[slot_idx].handle
+        if h.join_blocks < 0:
+            return
+        self._jnote("decode_first", rid=h.rid, slot=slot_idx,
+                    a=float(h.join_blocks))
+        if h.trace is not None:
+            h.trace.note("decode_first")
+        h.join_blocks = -2
 
     # ------------------------------------------------------------------ #
     # Grammar-constrained decoding

@@ -15,10 +15,11 @@ touching program semantics:
   by construction: every cached operand is a NON-donated argument of the
   decode/spec programs (the donation-safety lint pins that), so reusing
   the same device array across dispatches is sound.
-- `LoopPhases` — a per-iteration monotonic phase accumulator
-  (drain/purge/admit/prep/commit/dispatch/process/housekeeping/wait)
+- `LoopPhases` — a monotonic phase accumulator
+  (drain/purge/admit/prep/commit/dispatch/pull/process/housekeeping/wait)
   whose vector rides the `loop_iter` journal event, so loop overhead per
-  block is attributable from the journal alone.
+  block is attributable from the journal alone; while a profiler capture
+  runs, each phase is also a `loop/<phase>` span on the capture's clock.
 - `DeadlineIndex` — a lazy-deletion min-heap of absolute monotonic
   deadlines. Submit pushes each request's deadline / queue-timeout
   expiry; the loop's housekeeping tick asks "is anything due?" in O(1)
@@ -47,10 +48,17 @@ LOOP_PHASES = (
     "prep",          # control-plan build (pack/variant/growth/spec plan)
     "commit",        # H2D control commit (the one batched transfer)
     "dispatch",      # decode/spec block dispatch + chunk advance
+    "pull",          # blocked on the device: an in-flight result's D2H pull
     "process",       # in-flight result processing (token posting)
     "housekeeping",  # budgeted sidecar tick (spill, deferred saves)
     "wait",          # idle / waiting on an in-flight block
 )
+
+
+# The longest a `loop/<phase>` span stays open before it is closed and opened
+# again (LoopPhases). It is also the slice in which the loop waits for a
+# device result, so that its `pull` phase is spans of this length.
+SPAN_SLICE_S = 0.01
 
 
 class _CtrlEntry:
@@ -127,16 +135,34 @@ class ControlStager:
 
 
 class LoopPhases:
-    """Accumulates per-phase host milliseconds across loop iterations.
+    """Per-phase host milliseconds of the engine loop, and the same phases
+    as spans on the profiler's clock.
 
-    The loop calls `mark()` at the top of an iteration and `lap(name)`
-    after each phase; `vector()`/`total()` feed the coalesced `loop_iter`
-    journal emission, after which `reset()` starts the next window.
+    The loop calls `begin(name)` where a phase starts; the phase runs until
+    the next `begin` (or `end()`). Each phase is accumulated in `ms` (the
+    vector that rides the coalesced `loop_iter` journal event) and, while a
+    profiler capture is running, is one `loop/<name>` TraceAnnotation on the
+    loop thread, so a device idle gap in the trace lies under the phase that
+    caused it. Beginning the phase that is already running keeps its span:
+    the loop spins at about 1 kHz while it waits, and consecutive `wait`s
+    are one span, not a thousand. Only a span older than SPAN_SLICE_S is
+    closed and opened again, because the profiler records a span when it
+    ENDS: one that is open when a capture stops is lost whole, and one that
+    began before the capture has no start. Slices bound both losses. With no
+    capture running `begin` costs one clock read and `is_enabled()`; no
+    annotation object is made.
+
+    `sync()` settles the running phase's time into `ms` without closing
+    its span; `vector()`/`total()` read `ms`, `reset()` starts the next
+    window.
     """
 
-    __slots__ = ("names", "ms", "iters", "_mark")
+    __slots__ = ("names", "ms", "iters", "_mark", "_cur", "_span",
+                 "_span_t0", "_annotate", "_enabled")
 
-    def __init__(self, names=LOOP_PHASES):
+    def __init__(self, names=LOOP_PHASES, annotate=None):
+        if annotate is None:
+            from jax.profiler import TraceAnnotation as annotate
         # thread: instance-owned — loop-thread state, read best-effort by
         # metrics/bench after generation completes.
         self.names = tuple(names)
@@ -147,14 +173,55 @@ class LoopPhases:
         self.iters = 0
         # thread: instance-owned — see above.
         self._mark = 0.0
+        # thread: instance-owned — the running phase and its open span.
+        self._cur = None
+        # thread: instance-owned — see above.
+        self._span = None
+        # thread: instance-owned — see above.
+        self._span_t0 = 0.0
+        self._annotate = annotate
+        self._enabled = annotate.is_enabled
 
-    def mark(self) -> None:
-        self._mark = time.monotonic()
+    def begin(self, name: str) -> None:
+        if name == self._cur:
+            # Keep the span, unless it is a slice old; open one if a capture
+            # started in the middle of the phase.
+            if (self._span is None
+                    or time.monotonic() - self._span_t0 >= SPAN_SLICE_S):
+                self._close_span()
+                self._open_span()
+            return
+        self.end()
+        self._cur = name
+        self._open_span()
 
-    def lap(self, name: str) -> None:
+    def _open_span(self) -> None:
+        if self._enabled():
+            self._span = self._annotate("loop/" + self._cur)
+            self._span.__enter__()
+            self._span_t0 = time.monotonic()
+
+    def _close_span(self) -> None:
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+
+    def _settle(self) -> None:
         now = time.monotonic()
-        self.ms[name] += (now - self._mark) * 1000.0
+        if self._cur is not None:
+            self.ms[self._cur] += (now - self._mark) * 1000.0
         self._mark = now
+
+    def end(self) -> None:
+        """Close the running phase (loop exit, or before a new one)."""
+        self._settle()
+        self._cur = None
+        self._close_span()
+
+    def sync(self) -> None:
+        self._settle()
+        if self._cur is not None:
+            self.begin(self._cur)  # slices the span, or opens it late
 
     def total(self, exclude: tuple = ("wait",)) -> float:
         return sum(v for n, v in self.ms.items() if n not in exclude)

@@ -179,18 +179,38 @@ def _scan_layers(cfg: ArchConfig, params: Params, h, layer_fn, extras=()):
     L = cfg.num_layers
     kd = cfg.first_k_dense if ("dense_layers" in params) else 0
     if kd == 0:
-        return jax.lax.scan(
-            layer_fn, h, (params["layers"], jnp.arange(L)) + tuple(extras)
-        )
+        return _scan_stack(layer_fn, h, params["layers"], 0, L, extras)
     head = tuple(e[:kd] for e in extras)
     tail = tuple(e[kd:] for e in extras)
-    h, out_d = jax.lax.scan(
-        layer_fn, h, (params["dense_layers"], jnp.arange(kd)) + head
-    )
-    h, out_m = jax.lax.scan(
-        layer_fn, h, (params["layers"], jnp.arange(kd, L)) + tail
-    )
+    h, out_d = _scan_stack(layer_fn, h, params["dense_layers"], 0, kd, head)
+    h, out_m = _scan_stack(layer_fn, h, params["layers"], kd, L, tail)
     out = jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=0), out_d, out_m)
+    return h, out
+
+
+def _scan_stack(layer_fn, h, stack, lo: int, hi: int, extras):
+    """`lax.scan` of `layer_fn` over layers lo..hi of one stack. The body
+    takes its own slice of the stacked weights and of each per-layer extra
+    (what scan does with `xs`), so that the two slices carry a name into the
+    compiled program: `layer_weights` and `layer_kv_pool` are what a profile
+    shows as per-layer copies out of the stacked arrays (PERF.md §5)."""
+
+    def index(i):
+        return lambda a: jax.lax.dynamic_index_in_dim(
+            a, i, 0, keepdims=False, allow_negative_indices=False)
+
+    def body(carry, _):
+        # `i` is carried beside h, not sliced out of an arange: the index of
+        # every slice below is then a loop counter, as scan's own is.
+        h, i = carry
+        with jax.named_scope("layer_weights"):
+            lp = jax.tree.map(index(i), stack)
+        with jax.named_scope("layer_kv_pool"):
+            ex = jax.tree.map(index(i), tuple(extras))
+        h, out = layer_fn(h, (lp, i + lo if lo else i) + ex)
+        return (h, i + 1), out
+
+    (h, _), out = jax.lax.scan(body, (h, jnp.int32(0)), None, length=hi - lo)
     return h, out
 
 
@@ -475,6 +495,7 @@ def _mlp(cfg: ArchConfig, lp: Params, x: jnp.ndarray, ep: int = 1,
     return y
 
 
+@jax.named_scope("attention")
 def _attn_out(cfg: ArchConfig, lp: Params, attn_flat: jnp.ndarray,
               mesh=None, lora=None) -> jnp.ndarray:
     """Output projection + optional gemma-2 post-attention sandwich norm.
@@ -489,6 +510,7 @@ def _attn_out(cfg: ArchConfig, lp: Params, attn_flat: jnp.ndarray,
     return a
 
 
+@jax.named_scope("mlp")
 def _mlp_out(cfg: ArchConfig, lp: Params, x: jnp.ndarray, ep: int = 1,
              mesh=None, lora=None) -> jnp.ndarray:
     """MLP + optional gemma-2 post-feedforward sandwich norm."""
@@ -518,6 +540,7 @@ def _layer_inv_freq(cfg: ArchConfig, inv_global, inv_local, li):
     return jnp.where(sliding, inv_local, inv_global)
 
 
+@jax.named_scope("attention")
 def _attn_proj_qkv(cfg: ArchConfig, lp: Params, x: jnp.ndarray, mesh=None,
                    lora=None):
     """x: [..., D] -> q [..., H, Hd], k/v [..., K, Hd]."""
@@ -657,6 +680,7 @@ def _act(cfg: ArchConfig, x: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.silu(x)
 
 
+@jax.named_scope("lm_head")
 def _unembed(cfg: ArchConfig, params: Params, h: jnp.ndarray,
              mesh=None) -> jnp.ndarray:
     # bf16 (or int8-dequant) operands with f32 MXU accumulation: casting the
@@ -1025,34 +1049,35 @@ def decode_step_windowed(
             h = h + _mlp_out(cfg, lp, x, ep, mesh)
             return h, (rows, rows[..., :0])
         q, k, v = _attn_proj_qkv(cfg, lp, x, mesh, lora=llora)
-        q = apply_rope(q[:, None], rope_pos[:, None], inv)[:, 0]
-        k = apply_rope(k[:, None], rope_pos[:, None], inv)[:, 0]
-        if ptable is not None:
-            from localai_tpu.ops.attention import decode_attention_windowed_paged
+        with jax.named_scope("attention"):
+            q = apply_rope(q[:, None], rope_pos[:, None], inv)[:, 0]
+            k = apply_rope(k[:, None], rope_pos[:, None], inv)[:, 0]
+            if ptable is not None:
+                from localai_tpu.ops.attention import decode_attention_windowed_paged
 
-            attn = decode_attention_windowed_paged(
-                q, kc, vc, ptable, lk, lv, k, v, positions, step,
-                softcap=cfg.attn_softcap, window=cfg.sliding_window,
-                sliding=_layer_sliding(cfg, li), impl=paged_impl, mesh=mesh,
-                kv_scale=kv_scale, sink=cfg.attention_sink,
-                swin=cfg.attention_window,
-            )
-        elif use_sp:
-            from localai_tpu.ops.attention import decode_attention_windowed_sp
+                attn = decode_attention_windowed_paged(
+                    q, kc, vc, ptable, lk, lv, k, v, positions, step,
+                    softcap=cfg.attn_softcap, window=cfg.sliding_window,
+                    sliding=_layer_sliding(cfg, li), impl=paged_impl, mesh=mesh,
+                    kv_scale=kv_scale, sink=cfg.attention_sink,
+                    swin=cfg.attention_window,
+                )
+            elif use_sp:
+                from localai_tpu.ops.attention import decode_attention_windowed_sp
 
-            attn = decode_attention_windowed_sp(
-                q, kc, vc, lk, lv, k, v, positions, step, mesh,
-                softcap=cfg.attn_softcap, window=cfg.sliding_window,
-                sliding=_layer_sliding(cfg, li), sink=cfg.attention_sink,
-                swin=cfg.attention_window,
-            )
-        else:
-            attn = decode_attention_windowed(
-                q, kc, vc, lk, lv, k, v, positions, step,
-                softcap=cfg.attn_softcap, window=cfg.sliding_window,
-                sliding=_layer_sliding(cfg, li), sink=cfg.attention_sink,
-                swin=cfg.attention_window,
-            )
+                attn = decode_attention_windowed_sp(
+                    q, kc, vc, lk, lv, k, v, positions, step, mesh,
+                    softcap=cfg.attn_softcap, window=cfg.sliding_window,
+                    sliding=_layer_sliding(cfg, li), sink=cfg.attention_sink,
+                    swin=cfg.attention_window,
+                )
+            else:
+                attn = decode_attention_windowed(
+                    q, kc, vc, lk, lv, k, v, positions, step,
+                    softcap=cfg.attn_softcap, window=cfg.sliding_window,
+                    sliding=_layer_sliding(cfg, li), sink=cfg.attention_sink,
+                    swin=cfg.attention_window,
+                )
         h = h + _attn_out(cfg, lp, attn.reshape(B, -1), mesh, lora=llora)
         x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
         h = h + _mlp_out(cfg, lp, x, ep, mesh, lora=llora)

@@ -20,9 +20,11 @@ minutes in). This package makes the lifecycle observable in four layers:
   journal events + an engine state snapshot dump to a JSON file whose path
   rides the `loop_dead` gauge labels and the manager log.
 
-`fence` and `profile` are DECLARED sync points (LOCALAI_TRACE_FENCE /
-LOCALAI_PROFILE debug paths) and are deliberately excluded from the
-trace-safety lint targets, exactly like the engine drainer thread.
+`profile` is the DECLARED measurement point (the LOCALAI_PROFILE debug
+path) and is deliberately excluded from the trace-safety lint targets,
+exactly like the engine drainer thread. What a capture shows of the engine
+— program and kernel names, `loop/<phase>` and `dispatch/<program>` spans —
+is listed in docs/OBSERVABILITY.md.
 """
 
 from localai_tpu.observe.journal import EventJournal  # noqa: F401

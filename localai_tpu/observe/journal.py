@@ -39,9 +39,8 @@ BASE_EVENTS = (
     "first_token",   # admission result produced the first token (slot)
     "decode_block",  # decode/spec block dispatched (a=block size, b=dispatch ms)
     "loop_iter",     # coalesced loop-iteration window (a=occupancy, b=host ms
-    #                  spent this window — or fenced device ms under
-    #                  trace_fence when the window dispatched; the per-phase
-    #                  host-ms breakdown rides the `phases` vector, ISSUE 17)
+    #                  spent this window outside the wait phase; the
+    #                  per-phase host-ms breakdown rides the `phases` vector)
     "preempt",       # slot preempted for pool pressure (slot, a=ctx rows)
     "swap_out",      # preempt-swap image written to the host tier (a=bytes)
     "swap_in",       # swap resume restored pool pages (slot, a=bytes)
@@ -80,6 +79,16 @@ BASE_EVENTS = (
     "affinity_handoff",  # a draining/dead replica's span affinity moved to
     #                  a survivor instead of being dropped (staged; rid=
     #                  source replica, a=digests moved)
+    "decode_first",  # the request's first token from a decode block was
+    #                  posted (rid, slot; a=blocks in flight when it was
+    #                  admitted): first_token -> decode_first is the wait
+    #                  to join the decode stream
+    "decode_rows",   # a decode or spec block's results were processed
+    #                  (a=rows dispatched: steps x compiled batch rows,
+    #                  b=rows that carried a token a handle received)
+    "decode_rows_lost",  # the same block's other rows (a=overshoot: live at
+    #                  dispatch, no token; b=empty: not live at dispatch);
+    #                  a + b + decode_rows.b = decode_rows.a
 )
 
 # One journal event type per fault-injection site (faults.SITES), checked
@@ -113,8 +122,8 @@ CODES = {name: i for i, name in enumerate(EVENTS)}
 # a unit test pins the two tuples equal). The per-event `ph` vector stores
 # milliseconds per phase in this order.
 LOOP_PHASES = (
-    "drain", "purge", "admit", "prep", "commit", "dispatch", "process",
-    "housekeeping", "wait",
+    "drain", "purge", "admit", "prep", "commit", "dispatch", "pull",
+    "process", "housekeeping", "wait",
 )
 
 _DTYPE = np.dtype([
@@ -186,6 +195,11 @@ class EventJournal:
             self._staged.append(rec)
 
     # thread: engine-loop-only
+    def staged(self) -> bool:
+        """Anything waiting for `drain_staged`? Unlocked peek (len() is
+        atomic in CPython); the loop asks every iteration."""
+        return bool(self._staged)
+
     def drain_staged(self) -> None:
         """Writer thread: move staged events into the ring (original
         timestamps preserved)."""

@@ -3,8 +3,8 @@
 Gated behind LOCALAI_PROFILE (the capture output directory): profiling
 allocates device trace buffers and perturbs serving, so it must be an
 explicit operator opt-in, not a reachable default. One capture at a time —
-jax.profiler keeps process-global state. Like `fence`, this module is a
-declared sync/measurement point outside the trace-safety lint targets.
+jax.profiler keeps process-global state. This module is the declared
+measurement point outside the trace-safety lint targets.
 """
 
 from __future__ import annotations
@@ -17,6 +17,21 @@ _capture_lock = threading.Lock()
 MAX_SECONDS = 30.0
 
 
+def trace_options(jax):
+    """The tracer levels every capture of this repo uses (an operator's
+    /debug/profile and the benchmark's traced run alike): host TraceMe
+    annotations on, so the engine's `loop/<phase>` and `dispatch/<program>`
+    spans are in the capture; the Python tracer off, which would record
+    every call of the serving threads. {} on a jax without ProfileOptions."""
+    try:
+        opts = jax.profiler.ProfileOptions()
+    except AttributeError:
+        return {}
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    return {"profiler_options": opts}
+
+
 def capture(dirpath: str, seconds: float) -> dict:
     """Run one profiler capture window (blocking). Raises RuntimeError
     when a capture is already in flight or the profiler fails."""
@@ -27,7 +42,7 @@ def capture(dirpath: str, seconds: float) -> dict:
         import jax
 
         t0 = time.monotonic()
-        jax.profiler.start_trace(dirpath)
+        jax.profiler.start_trace(dirpath, **trace_options(jax))
         try:
             time.sleep(seconds)
         finally:

@@ -8,8 +8,8 @@ Mapping:
 - `tid` is the engine slot (engine-wide events ride tid 0 labeled
   "engine-loop");
 - events that carry a duration (`decode_block` dispatch wall, `loop_iter`
-  fenced device time, `chunk`) become complete ("X") events ending at
-  their journal timestamp; everything else is an instant ("i");
+  host time outside the wait phase, `chunk`) become complete ("X") events
+  ending at their journal timestamp; everything else is an instant ("i");
 - timestamps are microseconds relative to the earliest journal anchor, so
   multi-journal exports (cluster replicas) share one timeline.
 """

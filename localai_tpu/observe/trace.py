@@ -43,7 +43,8 @@ PHASE_OF = {
     "queued": "queue",
     "admitted": "admit",
     "swap_in": "admit",
-    "first_token": "decode",
+    "first_token": "join",     # waiting for the first decode block that
+    "decode_first": "decode",  # includes this slot to be delivered
     "resumed": "decode",
     "preempt": "preempted",
 }

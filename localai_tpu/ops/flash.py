@@ -163,5 +163,6 @@ def flash_prefill_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
         interpret=interpret,
+        name="flash_prefill",
     )(lengths.astype(jnp.int32), qh, kh, vh)
     return out.transpose(0, 2, 1, 3)  # [B, S, H, D]

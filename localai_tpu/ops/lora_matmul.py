@@ -151,6 +151,7 @@ def _lora_call(x2, a, b, ids):
         ),
         out_shape=jax.ShapeDtypeStruct((n, 1, out), x2.dtype),
         interpret=_interpret(),
+        name="lora_matmul",
     )(ids, x2[:, None, :], a, b)[:, 0, :]
 
 

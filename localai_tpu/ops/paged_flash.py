@@ -319,6 +319,7 @@ def _paged_partials_rows(
             jax.ShapeDtypeStruct((B, K, QR, STAT_LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="paged_attention",
     )(
         *tbl_args, limits.astype(jnp.int32), sl_arr,
         qr, qpos_rows.astype(jnp.int32)[..., None], kvs, k_pool, v_pool,

@@ -270,6 +270,7 @@ def _qmm_call(x3, wq, s3, z3, *, gs: int, packed: bool, out_dtype,
         out_shape=jax.ShapeDtypeStruct((E, N, out), out_dtype),
         scratch_shapes=[pltpu.VMEM((N, bo), jnp.float32)],
         interpret=_interpret(),
+        name="int4_matmul" if packed else "int8_matmul",
     )(*args)
 
 
@@ -354,6 +355,7 @@ def _plain_unembed(h: jnp.ndarray, w: dict) -> jnp.ndarray:
         out_shape=jax.ShapeDtypeStruct((n, v), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, bv), jnp.float32)],
         interpret=_interpret(),
+        name="int8_unembed",
     )(h2, w["q"], w["s"].astype(jnp.float32))
     return out.reshape(*lead, v)
 

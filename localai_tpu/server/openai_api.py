@@ -453,6 +453,7 @@ class OpenAIApi:
         if extra:
             u["timing_prompt_processing"] = sum(f.timing_prompt_processing for f in finals)
             u["timing_token_generation"] = sum(f.timing_token_generation for f in finals)
+            u["timing_queue_wait"] = sum(f.timing_queue_wait for f in finals)
         return u
 
     def _chat_logprobs(self, body: dict[str, Any]) -> int:
@@ -502,6 +503,7 @@ class OpenAIApi:
             # (chat.go:47-50; proto Reply timing fields).
             u["timing_prompt_processing"] = final.timing_prompt_processing
             u["timing_token_generation"] = final.timing_token_generation
+            u["timing_queue_wait"] = final.timing_queue_wait
         return u
 
     # ------------------------------------------------------------------ #

@@ -85,7 +85,7 @@ def _pair_sweep(tiny, reqs, **cfg):
 
 def test_loop_phases_pinned():
     assert runtime.LOOP_PHASES == jmod.LOOP_PHASES
-    assert len(runtime.LOOP_PHASES) == 9
+    assert len(runtime.LOOP_PHASES) == 10
     assert runtime.LOOP_PHASES[-1] == "wait"
 
 

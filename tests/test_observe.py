@@ -163,6 +163,7 @@ def test_trace_inherits_traceparent_and_tiles_phases():
     tr.note("queued")
     tr.note("admitted")
     tr.note("first_token")
+    tr.note("decode_first")
     tr.note("preempt")
     tr.note("resumed")
 
@@ -177,7 +178,7 @@ def test_trace_inherits_traceparent_and_tiles_phases():
     j = tr.to_json()
     assert j["terminal_events"] == 1
     names = [s["name"] for s in j["spans"]]
-    assert names == ["queue", "admit", "decode", "preempted", "decode"]
+    assert names == ["queue", "admit", "join", "decode", "preempted", "decode"]
     # to_json rounds span durations to µs precision — tolerate that.
     assert abs(sum(s["duration_ms"] for s in j["spans"]) - j["wall_ms"]) < 0.05
 
@@ -643,8 +644,8 @@ def test_http_trace_and_timeline(api):
     assert data["trace_ids"] == [otrace.parse_traceparent(tp)[0]]
     leg = data["legs"][-1]
     assert leg["complete"] and leg["terminal_events"] == 1
-    assert [s["name"] for s in leg["spans"]][:3] == ["queue", "admit",
-                                                     "decode"]
+    assert [s["name"] for s in leg["spans"]][:4] == ["queue", "admit",
+                                                     "join", "decode"]
     # Unknown request → 404.
     with pytest.raises(urllib.error.HTTPError) as e:
         _get(base, "/debug/trace/no-such-request")
@@ -686,3 +687,28 @@ def test_http_lifecycle_histograms_render(api):
     # api_call histogram unchanged, engine journal gauges exported.
     assert "localai_api_call_bucket" in body
     assert 'localai_engine_journal_events{model="tiny-obs"}' in body
+
+
+@pytest.mark.parametrize("stream", [False, True], ids=["unstreamed", "streamed"])
+def test_usage_carries_queue_wait_beside_prompt_processing(api, stream):
+    """With the Extra-Usage header `usage` holds the three timings of the
+    final TokenEvent; the queue wait is what /debug/trace calls `queue`."""
+    base, _mgr = api
+    req = urllib.request.Request(
+        base + "/v1/chat/completions",
+        data=json.dumps({
+            "model": "tiny-obs", "max_tokens": 4, "stream": stream,
+            "messages": [{"role": "user", "content": "hello"}],
+        }).encode(),
+        headers={"Content-Type": "application/json", "Extra-Usage": "1"})
+    with urllib.request.urlopen(req, timeout=120) as r:
+        body = r.read().decode()
+    if stream:
+        frames = [json.loads(line[6:]) for line in body.splitlines()
+                  if line.startswith("data: ") and line != "data: [DONE]"]
+        usage = next(f["usage"] for f in reversed(frames) if f.get("usage"))
+    else:
+        usage = json.loads(body)["usage"]
+    for key in ("timing_queue_wait", "timing_prompt_processing",
+                "timing_token_generation"):
+        assert usage[key] >= 0.0, (key, usage)

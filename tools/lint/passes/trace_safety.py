@@ -51,9 +51,8 @@ TRACED_MODULE_GLOBS = [
     "localai_tpu/parallel/*.py",
     # The observability layer (ISSUE 11) rides the engine loop between
     # every dispatch: journal appends, trace notes, timeline/postmortem
-    # reads must never sync the device. observe/fence.py and
-    # observe/profile.py are EXCLUDED by design — they are the declared
-    # sync/measurement points (LOCALAI_TRACE_FENCE / LOCALAI_PROFILE),
+    # reads must never sync the device. observe/profile.py is EXCLUDED by
+    # design — it is the declared measurement point (LOCALAI_PROFILE),
     # exactly like the engine drainer thread is excluded from HOT_METHODS.
     "localai_tpu/observe/journal.py",
     "localai_tpu/observe/trace.py",
