@@ -265,6 +265,17 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
             case(f"quant_{form}_{tag}",
                  lambda x, wq: Q.matmul(x, wq, impl="auto"),
                  lambda x, wq: Q.matmul(x, wq, impl="xla"), (x, wq), 2e-2)
+    # The decode block's form: N = 32 rows, the weights still stacked over
+    # layers, the layer picked by the scalar-prefetched index in the kernel's
+    # index maps (first and last layer of four).
+    stack = Q.quantize_tensor(rnd((4, hid, s["ffn"]), jnp.float32, 0.02))
+
+    def stacked(impl):
+        return lambda x, wq, first, last: tuple(
+            Q.matmul(x, Q.StackedLayer(wq, i), impl=impl) for i in (first, last))
+
+    case("quant_int8_channel_ffn_stacked", stacked("auto"), stacked("xla"),
+         (rnd((32, hid)), stack, jnp.int32(0), jnp.int32(3)), 2e-2)
     head = rnd((s["vocab"], hid), jnp.float32, 0.02)
     hs = jnp.maximum(jnp.max(jnp.abs(head), axis=-1, keepdims=True) / 127.0,
                      1e-9)

@@ -68,6 +68,7 @@ from localai_tpu.models.config import ArchConfig
 from localai_tpu.observe import postmortem as opostmortem
 from localai_tpu.observe import trace as otrace
 from localai_tpu.observe.journal import EventJournal
+from localai_tpu.ops.quant_matmul import SiteCounts
 from localai_tpu.ops.sampling import (
     NEG_INF,
     SamplingParams,
@@ -664,9 +665,18 @@ PROGRAM_NAMES = frozenset({
 })
 
 
-def _named_jit(fn, name: str, **kw):
-    """`jax.jit(fn, **kw)` under a stable program name (compile-time only)."""
+def _named_jit(fn, name: str, sites: Optional[SiteCounts] = None, **kw):
+    """`jax.jit(fn, **kw)` under a stable program name (compile-time only).
+    With `sites`, each trace of the program counts into it the quantized
+    matmul call sites it holds (ops/quant_matmul.SiteCounts)."""
     assert name in PROGRAM_NAMES, name
+    if sites is not None:
+        body = fn
+
+        def fn(*args, **kwargs):
+            with sites.tracing(name):
+                return body(*args, **kwargs)
+
     fn.__name__ = fn.__qualname__ = name
     return jax.jit(fn, **kw)
 
@@ -761,6 +771,9 @@ class Engine:
         quantization: str = "",
     ) -> None:
         self.cfg = cfg
+        # Per program kind: traces, and the quantized matmul sites in them
+        # that took the layer stack or a slice (metrics(), OBSERVABILITY.md).
+        self.quant_sites = SiteCounts()
         self.tokenizer = tokenizer
         self.ecfg = engine_cfg or EngineConfig()
         env_chunk = os.environ.get("LOCALAI_PREFILL_CHUNK")
@@ -1073,7 +1086,7 @@ class Engine:
                 # the weight shardings (models/quant.py). Checkpoints too big
                 # for HBM in bf16 arrive pre-quantized from the loader
                 # instead (load_hf_checkpoint quantize=).
-                self.params = _named_jit(
+                self.params = self._jit(
                     lambda p: quantize_params(cfg, p, quantization),
                     "quantize_params",
                 )(self.params)
@@ -2186,7 +2199,7 @@ class Engine:
             def gather(k, v, pages):
                 return k[:, pages], v[:, pages]
 
-            fn = _named_jit(gather, "pages_gather")
+            fn = self._jit(gather, "pages_gather")
             self._block_cache[key] = fn
         return fn
 
@@ -2199,7 +2212,7 @@ class Engine:
                 v = cache.v.at[:, pages].set(hv.astype(cache.v.dtype))
                 return llama.KVCache(k=k, v=v)
 
-            fn = _named_jit(swap_in, "swap_in", donate_argnums=(0,))
+            fn = self._jit(swap_in, "swap_in", donate_argnums=(0,))
             self._block_cache[key] = fn
         return fn
 
@@ -2216,7 +2229,7 @@ class Engine:
                 d_positions = d_positions.at[slot].set(pos)
                 return counts, rngs, bias, d_tokens, d_positions
 
-            fn = _named_jit(restore, "resume_restore",
+            fn = self._jit(restore, "resume_restore",
                             donate_argnums=(0, 1, 2, 3, 4))
             self._block_cache[("resume-restore",)] = fn
         return fn
@@ -2227,7 +2240,7 @@ class Engine:
             def setrng(rngs, slot, rngd):
                 return rngs.at[slot].set(jax.random.wrap_key_data(rngd))
 
-            fn = _named_jit(setrng, "rng_set", donate_argnums=(0,))
+            fn = self._jit(setrng, "rng_set", donate_argnums=(0,))
             self._block_cache[("rng-set",)] = fn
         return fn
 
@@ -2840,9 +2853,9 @@ class Engine:
                 ep=self.plan.ep,
             )
 
-        self._prefill_fn = _named_jit(_prefill, "prefill")
-        self._embed_fn = _named_jit(_embed, "embed")
-        self._score_fn = _named_jit(_score, "score")
+        self._prefill_fn = self._jit(_prefill, "prefill")
+        self._embed_fn = self._jit(_embed, "embed")
+        self._score_fn = self._jit(_score, "score")
 
     def _get_block(self, variant: str, n: int, with_lp: bool = False,
                    with_dfa: bool = False, kv_win: Optional[int] = None,
@@ -3042,7 +3055,7 @@ class Engine:
         donate = (1, 2, 3, 5, 6)
         if with_dfa:
             donate = donate + (8 + (1 if mrope else 0) + (1 if paged else 0) + 3,)
-        fn = _named_jit(program, "decode_block", donate_argnums=donate)
+        fn = self._jit(program, "decode_block", donate_argnums=donate)
         self._block_cache[key] = fn
         return fn
 
@@ -3193,7 +3206,7 @@ class Engine:
                              d_gstate=d_gstate, ptable=ptable, lora=lora)
 
             donate = (1, 2, 3, 4, 5, 6) + ((7,) if with_dfa else ())
-            fn = _named_jit(program, "admit", donate_argnums=donate)
+            fn = self._jit(program, "admit", donate_argnums=donate)
         else:
             dcfg = self.draft_cfg
 
@@ -3233,7 +3246,7 @@ class Engine:
                 # d_gstate is the LAST positional arg (after the 13 fixed,
                 # the 4 dfa tables, and the optional ptable).
                 donate = donate + (13 + 4 + (1 if paged else 0),)
-            fn = _named_jit(admit_spec, "admit_spec", donate_argnums=donate)
+            fn = self._jit(admit_spec, "admit_spec", donate_argnums=donate)
         self._admit_cache[key] = fn
         return fn
 
@@ -3374,7 +3387,7 @@ class Engine:
             donate = donate + (7,)
         if draft:
             donate = donate + (7 + (1 if with_dfa else 0) + 1,)  # dcache
-        fn = _named_jit(program, "admit_cached", donate_argnums=donate)
+        fn = self._jit(program, "admit_cached", donate_argnums=donate)
         if not build_only:
             self._admit_cache[key] = fn
         return fn
@@ -3506,7 +3519,7 @@ class Engine:
             donate = donate + (7,)
         if draft:
             donate = donate + (7 + (1 if with_dfa else 0) + 1,)  # dcache
-        fn = _named_jit(program, "admit_cached_paged",
+        fn = self._jit(program, "admit_cached_paged",
                         donate_argnums=donate)
         if not build_only:
             self._admit_cache[key] = fn
@@ -3641,7 +3654,7 @@ class Engine:
                 d_positions = d_positions.at[slot].set(S - 1)
                 return cache, d_positions, aux
 
-        fn = _named_jit(chunk, "prefill_chunk", donate_argnums=(1, 2))
+        fn = self._jit(chunk, "prefill_chunk", donate_argnums=(1, 2))
         self._block_cache[key] = fn
         return fn
 
@@ -3658,7 +3671,7 @@ class Engine:
             def pin(d_positions, slot):
                 return d_positions.at[slot].set(S - 1)
 
-            fn = _named_jit(pin, "chunk_pin", donate_argnums=(0,))
+            fn = self._jit(pin, "chunk_pin", donate_argnums=(0,))
             self._block_cache[("chunk-pin",)] = fn
         return fn
 
@@ -3676,7 +3689,7 @@ class Engine:
                     cache.v, pv.astype(cache.v.dtype), (0, slot, 0, 0, 0))
                 return llama.KVCache(k=k, v=v)
 
-            fn = _named_jit(copy, "span_copy", donate_argnums=(0,))
+            fn = self._jit(copy, "span_copy", donate_argnums=(0,))
             self._block_cache[key] = fn
         return fn
 
@@ -3803,7 +3816,7 @@ class Engine:
             donate = donate + (7,)
         if draft:
             donate = donate + (7 + (1 if with_dfa else 0) + 1,)  # dcache
-        fn = _named_jit(program, "prefill_chunk_final",
+        fn = self._jit(program, "prefill_chunk_final",
                         donate_argnums=donate)
         self._admit_cache[key] = fn
         return fn
@@ -4190,7 +4203,7 @@ class Engine:
             return out
 
         donate = (0, 1, 2, 3, 4) + ((12,) if with_dfa else ())
-        fn = _named_jit(fork_fn, "fork", donate_argnums=donate)
+        fn = self._jit(fork_fn, "fork", donate_argnums=donate)
         self._admit_cache[key] = fn
         return fn
 
@@ -4210,7 +4223,7 @@ class Engine:
             v = cache.v.at[:, dstp].set(cache.v[:, srcp])
             return llama.KVCache(k=k, v=v)
 
-        fn = _named_jit(copy_page, "page_copy", donate_argnums=(0,))
+        fn = self._jit(copy_page, "page_copy", donate_argnums=(0,))
         self._admit_cache[key] = fn
         return fn
 
@@ -4240,7 +4253,7 @@ class Engine:
             return out
 
         donate = (0, 1, 2, 3, 4) + ((6,) if with_dfa else ())
-        fn = _named_jit(ctrl_copy, "ctrl_copy", donate_argnums=donate)
+        fn = self._jit(ctrl_copy, "ctrl_copy", donate_argnums=donate)
         self._admit_cache[key] = fn
         return fn
 
@@ -4829,7 +4842,7 @@ class Engine:
                     cache.v, (0, slot, 0, 0, 0), (L, 1, pb, K, vd))
                 return k, v
 
-            fn = _named_jit(snap, "snapshot")
+            fn = self._jit(snap, "snapshot")
             self._snap_cache[pb] = fn
         return fn
 
@@ -5718,7 +5731,7 @@ class Engine:
             base = 8 + 1  # + drafts operand
         if with_dfa:
             donate = donate + (base + (1 if paged else 0) + 3,)
-        fn = _named_jit(program, "spec_block", donate_argnums=donate)
+        fn = self._jit(program, "spec_block", donate_argnums=donate)
         self._block_cache[key] = fn
         return fn
 
@@ -6043,6 +6056,9 @@ class Engine:
             lens[i] = len(r)
         return np.asarray(self._score_fn(self.params, toks, lens, conds))
 
+    def _jit(self, fn, name: str, **kw):
+        return _named_jit(fn, name, sites=self.quant_sites, **kw)
+
     def metrics(self) -> dict[str, float]:
         tps = self._decode_tokens / self._decode_time if self._decode_time > 0 else 0.0
         out = {
@@ -6115,6 +6131,13 @@ class Engine:
         out["decode_rows_posted"] = float(self.m_rows_posted)
         out["decode_rows_overshoot"] = float(self.m_rows_overshoot)
         out["decode_rows_empty"] = float(self.m_rows_empty)
+        sites = self.quant_sites.totals()
+        if sites["stacked"] or sites["sliced"]:
+            # Quantized layer matmuls over every program traced so far: the
+            # Pallas kernel read its layer out of the stacked weights, or
+            # the layer was sliced out first (ops/quant_matmul.SiteCounts).
+            out["quant_matmul_stacked_sites"] = float(sites["stacked"])
+            out["quant_matmul_sliced_sites"] = float(sites["sliced"])
         if self.m_forks or self.m_fork_clone_fallbacks:
             # Tree-batched fork sampling (ISSUE 18): branches admitted by
             # slot fork vs degraded to the N-clone path (fault/pressure).
@@ -8013,7 +8036,7 @@ class Engine:
                 v=sd.v.at[:, slot].set(cache.v[:kl, slot].astype(sd.v.dtype)),
             )
 
-        fn = _named_jit(sync, "sd_sync", donate_argnums=(0,))
+        fn = self._jit(sync, "sd_sync", donate_argnums=(0,))
         self._block_cache[("sd-sync",)] = fn
         return fn
 
@@ -8045,7 +8068,7 @@ class Engine:
                 v=sd.v.at[:, slot, :W].set(gv.astype(sd.v.dtype)),
             )
 
-        fn = _named_jit(sync, "sd_sync_paged", donate_argnums=(0,))
+        fn = self._jit(sync, "sd_sync_paged", donate_argnums=(0,))
         self._block_cache[key] = fn
         return fn
 

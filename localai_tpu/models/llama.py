@@ -25,6 +25,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from localai_tpu.models import quant
 from localai_tpu.models.config import ArchConfig
 from localai_tpu.models.quant import matmul, unembed_matmul
 from localai_tpu.ops.attention import (
@@ -193,18 +194,29 @@ def _scan_stack(layer_fn, h, stack, lo: int, hi: int, extras):
     takes its own slice of the stacked weights and of each per-layer extra
     (what scan does with `xs`), so that the two slices carry a name into the
     compiled program: `layer_weights` and `layer_kv_pool` are what a profile
-    shows as per-layer copies out of the stacked arrays (PERF.md §5)."""
+    shows as per-layer copies out of the stacked arrays (PERF.md §5).
+
+    Quantized weights are not sliced here: the body gets a
+    `quant.StackedLayer`, the whole stack plus `i`, and the consumer
+    (`quant.matmul`, `_moe_mm`) either hands both to the Pallas kernel, which
+    then reads layer `i` in place, or slices at its own call site
+    (`quant.layer_slice`) in front of the XLA form."""
 
     def index(i):
-        return lambda a: jax.lax.dynamic_index_in_dim(
-            a, i, 0, keepdims=False, allow_negative_indices=False)
+        def take(a):
+            if quant.is_quantized(a):
+                return quant.StackedLayer(a, i)
+            return jax.lax.dynamic_index_in_dim(
+                a, i, 0, keepdims=False, allow_negative_indices=False)
+
+        return take
 
     def body(carry, _):
         # `i` is carried beside h, not sliced out of an arange: the index of
         # every slice below is then a loop counter, as scan's own is.
         h, i = carry
         with jax.named_scope("layer_weights"):
-            lp = jax.tree.map(index(i), stack)
+            lp = jax.tree.map(index(i), stack, is_leaf=quant.is_quantized)
         with jax.named_scope("layer_kv_pool"):
             ex = jax.tree.map(index(i), tuple(extras))
         h, out = layer_fn(h, (lp, i + lo if lo else i) + ex)
@@ -222,9 +234,11 @@ def _moe_mm(x: jnp.ndarray, w, sub: str, impl: str = "auto",
     if isinstance(w, dict):
         from localai_tpu.ops.quant_matmul import dispatch_moe_mm
 
-        y = dispatch_moe_mm(x, w, sub, impl=impl, mesh=mesh)
+        y = dispatch_moe_mm(x, dict(w), sub, impl=impl, mesh=mesh,
+                            layer=quant.layer_of(w))
         if y is not None:
             return y
+        w = quant.layer_slice(w)
         if "q" in w:
             out = jnp.einsum(sub, x, w["q"].astype(x.dtype))
             return out * w["s"].astype(x.dtype)[..., 0, :]

@@ -132,9 +132,11 @@ def matmul(x: jnp.ndarray, w, impl: str = "auto", mesh=None,
     if isinstance(w, dict):
         from localai_tpu.ops.quant_matmul import dispatch_matmul
 
-        y = dispatch_matmul(x, w, impl=impl, mesh=mesh, part=part)
+        y = dispatch_matmul(x, dict(w), impl=impl, mesh=mesh, part=part,
+                            layer=layer_of(w))
         if y is not None:
             return y
+        w = layer_slice(w)
         if "q" in w:
             return (x @ w["q"].astype(x.dtype)) * w["s"].astype(x.dtype)[..., 0, :]
         return grouped_matmul(x, w)
@@ -143,6 +145,39 @@ def matmul(x: jnp.ndarray, w, impl: str = "auto", mesh=None,
 
 def is_quantized(w) -> bool:
     return isinstance(w, dict) and ("q" in w or "gq" in w or "g4" in w)
+
+
+class StackedLayer(dict):
+    """One layer of a quantized weight that is still stacked over layers:
+    the dict's leaves keep their leading [L] axis and `layer` (a traced
+    int32 scalar) says which one is meant. llama._scan_stack hands these to
+    the layer body in place of a sliced dict, so that the Pallas
+    dequant-matmul can read its layer straight out of the stack (a custom
+    call's operand is a buffer: a slice in front of it is a copy of the
+    whole matrix, every layer of every step). Only `matmul` and
+    llama._moe_mm look inside; whoever needs the plain per-layer dict calls
+    `layer_slice`."""
+
+    def __init__(self, stack: dict, layer):
+        super().__init__(stack)
+        self.layer = layer
+
+
+def layer_of(w):
+    """The layer index a StackedLayer carries; None for a plain dict."""
+    return getattr(w, "layer", None)
+
+
+def layer_slice(w: dict) -> dict:
+    """The plain per-layer dict of a StackedLayer (any other dict as it is):
+    the one place a quantized layer is sliced out of its stack. In front of
+    an XLA dot the slice fuses into the operand load."""
+    if layer_of(w) is None:
+        return w
+    with jax.named_scope("layer_weights"):
+        return {k: jax.lax.dynamic_index_in_dim(
+            v, w.layer, 0, keepdims=False, allow_negative_indices=False)
+            for k, v in w.items()}
 
 
 def is_grouped(w) -> bool:

@@ -27,13 +27,31 @@ Forms served (matching models/quant.py representations):
   _moe_dense einsum shapes (shared-x and per-expert-x)
 - unembed        {"q": [V, D] i8, "s": [V, 1] f32} used transposed (h @ qᵀ·s)
 
+Who slices what (ISSUE 25). A layer's weights live stacked over layers
+([L, ...] leaves), and a pallas_call's operand has to be a buffer: a slice in
+front of it is a copy of the whole matrix, every layer of every step (it was
+a third of the int8 decode step). So llama._scan_stack does not slice
+quantized leaves; it hands the layer body a quant.StackedLayer (the stack
+and the layer index), and the dispatchers here take `layer=`: engaged, the
+kernel gets the stack with every leading axis merged into one block axis
+and the index as a scalar-prefetch operand, and its BlockSpec index maps
+read block layer·E + e. Same blocks, grid order and arithmetic as on a
+slice, so the result is bit-identical. Not engaged (prefill-scale rows,
+impl xla, CPU auto, a shape _shardable refuses) the dispatcher returns None
+and the caller slices at its own call site (quant.layer_slice) in front of
+the XLA form. SiteCounts tallies the choice per traced program. The
+grouped forms' [L, G, 1, out] scales and zeros are re-laid to [L, G, out]
+for the kernel, which XLA hoists out of the layer loop (one pass over them
+per program run, held as a temporary; PERF.md §7).
+
 Sharding (ISSUE 7 shard_map wrapping): pallas_call is opaque to GSPMD, so
 under a tp>1 mesh the kernels run inside shard_map with the weight specs
 parallel/sharding.py already assigns to the q/s/g4 forms — column-parallel
 weights shard their out axis ("tp" on the last dim of every leaf),
 row-parallel weights shard the group/in axis, and the row-parallel partial
 sums psum over "tp" inside the declared boundary below (the same ICI
-boundary GSPMD would have placed at the o/down projection).
+boundary GSPMD would have placed at the o/down projection). A stacked
+weight keeps its layer axis whole on every shard; the index is replicated.
 
 Dispatch: models/quant.matmul / unembed_matmul and models/llama._moe_mm call
 the dispatch_* helpers here; a None return means "not engaged" and the
@@ -44,8 +62,11 @@ it, exactly like ops/paged_flash vs the XLA page walk).
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -95,6 +116,11 @@ def _rows(x: jnp.ndarray, tail: int = 1) -> int:
     return r
 
 
+def _leaf(w: dict):
+    """The weight array of a quantized dict (None if it is not one)."""
+    return w.get("q", w.get("gq", w.get("g4")))
+
+
 def _tp_degree(mesh) -> int:
     if mesh is None:
         return 1
@@ -106,8 +132,12 @@ def _tp_degree(mesh) -> int:
 # --------------------------------------------------------------------------- #
 
 
-def _qmm_kernel(x_ref, w_ref, s_ref, *rest, gs: int, gc: int, packed: bool):
+def _qmm_kernel(_layer_ref, x_ref, w_ref, s_ref, *rest, gs: int, gc: int,
+                packed: bool):
     """One (expert, out-tile, k-chunk) grid step of the dequant-matmul.
+
+    `_layer_ref` is the scalar-prefetched layer index: only the BlockSpec
+    index maps read it (they pick this layer's blocks out of the stack).
 
     Blocks: x (1, N, kc) float, w (1, kc[/2], bo) i8/u8, s (1, gc|1, bo)
     f32, optional z (1, gc, bo) f32, out (1, N, bo), acc scratch (N, bo)
@@ -216,16 +246,22 @@ def _interpret() -> bool:
 
 
 def _qmm_call(x3, wq, s3, z3, *, gs: int, packed: bool, out_dtype,
-              x_per_expert: bool):
-    """Grid launch over (E, out-tiles, k-chunks).
+              x_per_expert: bool, experts: int = 1, layer=None):
+    """Grid launch over (E, out-tiles, k-chunks), E = `experts`.
 
-    x3 [Ex, N, Kin] float (Ex = E when per-expert, else 1); wq [E, Kin(/2),
-    out] int; s3 [E, G|1, out] f32; z3 [E, G, out] f32 or None. Returns
-    [E, N, out] in out_dtype.
+    x3 [Ex, N, Kin] float (Ex = E when per-expert, else 1); wq [B, Kin(/2),
+    out] int; s3 [B, G|1, out] f32; z3 [B, G, out] f32 or None. B is E, or
+    L·E for weights still stacked over L layers: `layer` (traced int32
+    scalar) rides scalar prefetch and the weight/scale/zero index maps read
+    block `layer·E + e`, so the kernel's DMA takes this layer's blocks
+    straight out of the stack and no per-layer copy of it exists. Without
+    `layer` the index is 0. Returns [E, N, out] in out_dtype.
     """
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    E, kin_w, out = wq.shape
+    E = experts
+    _, kin_w, out = wq.shape
     _, N, kin = x3.shape
     if packed:  # same bytes; the kernel masks the nibbles out of int32
         wq = jax.lax.bitcast_convert_type(wq, jnp.int8)
@@ -244,62 +280,79 @@ def _qmm_call(x3, wq, s3, z3, *, gs: int, packed: bool, out_dtype,
     nk = kin // kc
     grid = (E, out // bo, nk)
 
-    def xi(e, j, k):
+    def xi(e, j, k, li):
         return ((e, 0, k) if x_per_expert else (0, 0, k))
+
+    def wi(e, j, k, li):
+        return (li[0] * E + e, k, j)
+
+    def si(e, j, k, li):  # flat: one scale row per out channel
+        return (li[0] * E + e, k if gs else 0, j)
 
     in_specs = [
         pl.BlockSpec((1, N, kc), xi),
-        pl.BlockSpec((1, kc_w, bo), lambda e, j, k: (e, k, j)),
-        pl.BlockSpec(
-            (1, gc if gs else 1, bo),
-            (lambda e, j, k: (e, k, j)) if gs else (lambda e, j, k: (e, 0, j)),
-        ),
+        pl.BlockSpec((1, kc_w, bo), wi),
+        pl.BlockSpec((1, gc if gs else 1, bo), si),
     ]
     args = [x3, wq, s3]
     if z3 is not None:
-        in_specs.append(pl.BlockSpec((1, gc, bo), lambda e, j, k: (e, k, j)))
+        in_specs.append(pl.BlockSpec((1, gc, bo), si))
         args.append(z3)
-    from jax.experimental.pallas import tpu as pltpu
-
+    li = jnp.zeros((1,), jnp.int32) if layer is None else (
+        jnp.asarray(layer, jnp.int32).reshape(1))
     kernel = functools.partial(_qmm_kernel, gs=gs, gc=gc, packed=packed)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, N, bo), lambda e, j, k: (e, 0, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, N, bo), lambda e, j, k, li: (e, 0, j)),
+            scratch_shapes=[pltpu.VMEM((N, bo), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((E, N, out), out_dtype),
-        scratch_shapes=[pltpu.VMEM((N, bo), jnp.float32)],
         interpret=_interpret(),
         name="int4_matmul" if packed else "int8_matmul",
-    )(*args)
+    )(li, *args)
 
 
-def _plain_matmul(x: jnp.ndarray, w: dict) -> jnp.ndarray:
-    """Non-MoE quantized x @ w on local (possibly shard-local) shapes."""
-    lead = x.shape[:-1]
-    n = _rows(x)
-    x3 = x.reshape(1, n, x.shape[-1])
+def _operands(w: dict, lead: int):
+    """A quantized dict's leaves as the kernel takes them: every leading
+    axis (layer stack, experts; `lead` of them) merged into one block axis.
+    Returns (wq [B, Kin(/2), out], s3 [B, G|1, out], z3 | None, group width
+    or 0 for the flat form, packed)."""
     if "q" in w:
-        out = _qmm_call(
-            x3, w["q"][None], w["s"].reshape(1, 1, -1), None,
-            gs=0, packed=False, out_dtype=x.dtype, x_per_expert=False,
-        )
-        return out.reshape(*lead, -1)
+        q, s = w["q"], w["s"]
+        return (q.reshape(-1, *q.shape[lead:]), s.reshape(-1, *s.shape[lead:]),
+                None, 0, False)
     packed = "g4" in w
-    wq = (w["g4"] if packed else w["gq"])  # [G, gs(/2), out]
-    g, gsw, out_dim = wq.shape
-    gs_width = gsw * (2 if packed else 1)
-    s3 = w["gs"][..., 0, :][None]  # [1, G, out]
-    z3 = w["gz"][..., 0, :][None] if "gz" in w else None
+    wq = w["g4"] if packed else w["gq"]  # [.., G, gs(/2), out]
+    g, gsw, out = wq.shape[lead:]
+
+    def per_group(a):  # [.., G, 1, out] → [B, G, out]
+        return a.reshape(-1, g, out)
+
+    return (wq.reshape(-1, g * gsw, out), per_group(w["gs"]),
+            per_group(w["gz"]) if "gz" in w else None,
+            gsw * (2 if packed else 1), packed)
+
+
+def _plain_matmul(x: jnp.ndarray, w: dict, layer=None) -> jnp.ndarray:
+    """Non-MoE quantized x @ w on local (possibly shard-local) shapes; with
+    `layer`, w's leaves are the stack over layers."""
+    lead = x.shape[:-1]
+    x3 = x.reshape(1, _rows(x), x.shape[-1])
+    wq, s3, z3, gs, packed = _operands(w, 0 if layer is None else 1)
     out = _qmm_call(
-        x3, wq.reshape(1, g * gsw, out_dim), s3, z3,
-        gs=gs_width, packed=packed, out_dtype=x.dtype, x_per_expert=False,
+        x3, wq, s3, z3, gs=gs, packed=packed, out_dtype=x.dtype,
+        x_per_expert=False, layer=layer,
     )
     return out.reshape(*lead, -1)
 
 
-def _plain_moe_mm(x: jnp.ndarray, w: dict, sub: str) -> jnp.ndarray:
-    """MoE dequant-matmul for the two _moe_dense einsum shapes."""
+def _plain_moe_mm(x: jnp.ndarray, w: dict, sub: str, layer=None) -> jnp.ndarray:
+    """MoE dequant-matmul for the two _moe_dense einsum shapes; with
+    `layer`, w's leaves are [L, E, ...]."""
     per_expert = sub == "...ef,efd->...ed"
     if per_expert:
         lead = x.shape[:-2]
@@ -311,22 +364,13 @@ def _plain_moe_mm(x: jnp.ndarray, w: dict, sub: str) -> jnp.ndarray:
         lead = x.shape[:-1]
         n = _rows(x)
         x3 = x.reshape(1, n, x.shape[-1])
-    if "q" in w:
-        out = _qmm_call(
-            x3, w["q"], w["s"], None,  # s already [E, 1, out]
-            gs=0, packed=False, out_dtype=x.dtype, x_per_expert=per_expert,
-        )
-    else:
-        packed = "g4" in w
-        wq3 = w["g4"] if packed else w["gq"]  # [E, G, gs(/2), out]
-        e_, g, gsw, out_dim = wq3.shape
-        gs_width = gsw * (2 if packed else 1)
-        out = _qmm_call(
-            x3, wq3.reshape(e_, g * gsw, out_dim),
-            w["gs"][..., 0, :], w["gz"][..., 0, :] if "gz" in w else None,
-            gs=gs_width, packed=packed, out_dtype=x.dtype,
-            x_per_expert=per_expert,
-        )
+    axes = 1 if layer is None else 2  # leaves [E, ...] or [L, E, ...]
+    wq, s3, z3, gs, packed = _operands(w, axes)
+    out = _qmm_call(
+        x3, wq, s3, z3, gs=gs, packed=packed, out_dtype=x.dtype,
+        x_per_expert=per_expert, experts=_leaf(w).shape[axes - 1],
+        layer=layer,
+    )
     # out [E, N, F|D] → [.., E, F|D]
     y = jnp.moveaxis(out, 0, 1)  # [N, E, F|D]
     return y.reshape(*lead, y.shape[1], y.shape[2])
@@ -365,14 +409,14 @@ def _plain_unembed(h: jnp.ndarray, w: dict) -> jnp.ndarray:
 # --------------------------------------------------------------------------- #
 
 
-def _w_specs(w: dict, part: str, moe: bool):
+def _w_specs(w: dict, part: str, lead: int):
     """PartitionSpecs for a quantized dict's leaves, mirroring
     parallel/sharding.param_shardings_for: col shards every leaf's out
-    (last) axis; row shards the group/in axis (the flat scale is per-out
-    and stays replicated)."""
+    (last) axis; row shards the group/in axis, which sits behind `lead`
+    leading axes (layer stack, experts); the flat scale is per-out and
+    stays replicated."""
     from jax.sharding import PartitionSpec as P
 
-    off = 1 if moe else 0
     specs = {}
     for key, leaf in w.items():
         ax = [None] * leaf.ndim
@@ -380,50 +424,46 @@ def _w_specs(w: dict, part: str, moe: bool):
             # unembed's out axis is the leading V axis of [V, D]/[V, 1].
             ax[0 if part == "unembed" else -1] = "tp"
         elif key != "s":  # row: q in-axis / grouped G-axis; flat s replicated
-            ax[off] = "tp"
+            ax[lead] = "tp"
         specs[key] = P(*ax)
     return specs
 
 
-def _sharded_quant_matmul(x, w, mesh, part: str, moe_sub=None):
+def _sharded_quant_matmul(x, w, mesh, part: str, moe_sub=None, layer=None):
     """Run the local kernel per tp shard; row-parallel partials psum over
-    "tp" here (the declared ICI boundary — see COLLECTIVE_BOUNDARY)."""
+    "tp" here (the declared ICI boundary — see COLLECTIVE_BOUNDARY). With
+    `layer`, w's leaves keep their leading layer axis, unsharded, and the
+    index is replicated."""
     from jax.sharding import PartitionSpec as P
 
     row = part == "row"
     x_ax = [None] * x.ndim
     if row:
         x_ax[-1] = "tp"
-    if part == "unembed":
-        out_ndim = x.ndim
-    elif moe_sub == "...d,edf->...ef":
-        out_ndim = x.ndim + 1
-    else:
-        out_ndim = x.ndim
+    out_ndim = x.ndim + 1 if moe_sub == "...d,edf->...ef" else x.ndim
     o_ax = [None] * out_ndim
     if not row:
         o_ax[-1] = "tp"
 
-    def local(xl, wl):
+    def local(xl, wl, li):
         if part == "unembed":
             y = _plain_unembed(xl, wl)
         elif moe_sub is not None:
-            y = _plain_moe_mm(xl, wl, moe_sub)
+            y = _plain_moe_mm(xl, wl, moe_sub, layer=li)
         else:
-            y = _plain_matmul(xl, wl)
+            y = _plain_matmul(xl, wl, layer=li)
         if row:
             y = jax.lax.psum(y, "tp")
         return y
 
-    leaf = w.get("q", w.get("gq", w.get("g4")))
-    moe = leaf.ndim == (3 if "q" in w else 4)
+    lead = (moe_sub is not None) + (layer is not None)
     fn = jax.shard_map(
         local, mesh=mesh,
-        in_specs=(P(*x_ax), _w_specs(w, part, moe=moe)),
+        in_specs=(P(*x_ax), _w_specs(w, part, lead), None if layer is None else P()),
         out_specs=P(*o_ax),
         check_vma=False,
     )
-    return fn(x, w)
+    return fn(x, w, layer)
 
 
 # --------------------------------------------------------------------------- #
@@ -440,39 +480,98 @@ def _engaged(x, impl: str, tail: int = 1) -> bool:
     )
 
 
-def _shardable(x, w: dict, part: str, tp: int, moe_off: int = 0) -> bool:
+def _shardable(x, w: dict, part: str, tp: int, lead: int = 0) -> bool:
     """Every axis a tp shard_map would split must divide by tp — otherwise
     fall back to the XLA path (which GSPMD partitions or replicates as it
     can). col splits the out axis; row splits x's reduction axis and the
-    weight's in/group axis."""
-    leaf = w.get("q", w.get("gq", w.get("g4")))
+    weight's in/group axis, behind `lead` leading axes."""
+    leaf = _leaf(w)
     if part in ("col", "unembed"):
         out_ax = 0 if part == "unembed" else leaf.ndim - 1
         return leaf.shape[out_ax] % tp == 0
     return (x.shape[-1] % tp == 0
-            and leaf.shape[moe_off] % tp == 0)
+            and leaf.shape[lead] % tp == 0)
 
 
-def dispatch_matmul(x, w: dict, impl: str = "auto", mesh=None, part=None):
-    """Fused x @ w for the non-MoE quantized forms, or None to fall back."""
-    leaf = w.get("q", w.get("gq", w.get("g4")))
-    if leaf is None or leaf.ndim != (2 if "q" in w else 3):
+class SiteCounts:
+    """How many quantized layer-matmul call sites a program's trace held,
+    by what the site handed on: "stacked" (the Pallas kernel took the whole
+    layer stack and the index) or "sliced" (the layer was sliced out first,
+    for the XLA form or an unstacked kernel call). The choice is static, so
+    it is counted where it is made, once per trace. An engine owns one and
+    traces its programs under `tracing(<program>)`."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.by_program: dict[str, dict[str, int]] = {}
+
+    @contextlib.contextmanager
+    def tracing(self, program: str):
+        tally = {"traces": 1, "stacked": 0, "sliced": 0}
+        token = _TALLY.set(tally)
+        try:
+            yield
+        finally:
+            _TALLY.reset(token)
+            with self._lock:
+                have = self.by_program.setdefault(program, dict.fromkeys(tally, 0))
+                for k, v in tally.items():
+                    have[k] += v
+
+    def totals(self) -> dict[str, int]:
+        with self._lock:
+            progs = list(self.by_program.values())
+        return {k: sum(p[k] for p in progs) for k in ("stacked", "sliced")}
+
+
+_TALLY: contextvars.ContextVar = contextvars.ContextVar(
+    "quant_matmul_site_tally", default=None)
+
+
+def _note_site(stacked: bool) -> None:
+    tally = _TALLY.get()
+    if tally is not None:
+        tally["stacked" if stacked else "sliced"] += 1
+
+
+def dispatch_matmul(x, w: dict, impl: str = "auto", mesh=None, part=None,
+                    layer=None):
+    """Fused x @ w for the non-MoE quantized forms, or None to fall back.
+    With `layer`, w's leaves are stacked over layers and the kernel reads
+    that layer in place; a None return leaves the slicing to the caller."""
+    y = _dispatch_matmul(x, w, impl, mesh, part, layer)
+    _note_site(stacked=y is not None and layer is not None)
+    return y
+
+
+def _dispatch_matmul(x, w, impl, mesh, part, layer):
+    leaf = _leaf(w)
+    stacked = layer is not None
+    if leaf is None or leaf.ndim != (2 if "q" in w else 3) + stacked:
         return None
     if not _engaged(x, impl):
         return None
     tp = _tp_degree(mesh)
     if tp > 1 and part in ("col", "row"):
-        if not _shardable(x, w, part, tp):
+        if not _shardable(x, w, part, tp, lead=int(stacked)):
             return None
-        return _sharded_quant_matmul(x, w, mesh, part)
-    return _plain_matmul(x, w)
+        return _sharded_quant_matmul(x, w, mesh, part, layer=layer)
+    return _plain_matmul(x, w, layer=layer)
 
 
-def dispatch_moe_mm(x, w: dict, sub: str, impl: str = "auto", mesh=None):
+def dispatch_moe_mm(x, w: dict, sub: str, impl: str = "auto", mesh=None,
+                    layer=None):
     """Fused MoE dequant-matmul for _moe_dense's two einsum shapes, or
-    None to fall back. Part is implied by the shape: edf projects OUT to
-    the tp-sharded F axis (col), efd contracts the sharded F axis (row).
-    Expert-parallel (ep>1) meshes fall back to the XLA path."""
+    None to fall back; `layer` as in dispatch_matmul. Part is implied by
+    the shape: edf projects OUT to the tp-sharded F axis (col), efd
+    contracts the sharded F axis (row). Expert-parallel (ep>1) meshes fall
+    back to the XLA path."""
+    y = _dispatch_moe_mm(x, w, sub, impl, mesh, layer)
+    _note_site(stacked=y is not None and layer is not None)
+    return y
+
+
+def _dispatch_moe_mm(x, w, sub, impl, mesh, layer):
     if sub not in ("...d,edf->...ef", "...ef,efd->...ed"):
         return None
     per_expert = sub == "...ef,efd->...ed"
@@ -483,10 +582,10 @@ def dispatch_moe_mm(x, w: dict, sub: str, impl: str = "auto", mesh=None):
         part = "row" if per_expert else "col"
         if int(mesh.shape.get("ep", 1)) > 1:
             return None
-        if not _shardable(x, w, part, tp, moe_off=1):
+        if not _shardable(x, w, part, tp, lead=1 + (layer is not None)):
             return None
-        return _sharded_quant_matmul(x, w, mesh, part, moe_sub=sub)
-    return _plain_moe_mm(x, w, sub)
+        return _sharded_quant_matmul(x, w, mesh, part, moe_sub=sub, layer=layer)
+    return _plain_moe_mm(x, w, sub, layer=layer)
 
 
 def dispatch_unembed(h, w: dict, impl: str = "auto", mesh=None):

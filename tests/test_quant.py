@@ -416,6 +416,187 @@ def test_pallas_disengages_at_prefill_rows():
     )
 
 
+def _quantize_form(w, form):
+    from localai_tpu.models.quant import quantize_tensor_g4
+
+    if form == "flat_int8":
+        return quantize_tensor(w)
+    if form == "grouped_int8":
+        lead = w.shape[:-2]
+        q = jax.vmap(_grouped_int8)(w.reshape(-1, *w.shape[-2:]))
+        return {k: v.reshape(*lead, *v.shape[1:]) for k, v in q.items()}
+    return quantize_tensor_g4(w)
+
+
+def _layer(q, l):
+    return {k: v[l] for k, v in q.items()}
+
+
+_SHARED_X, _PER_EXPERT_X = "...d,edf->...ef", "...ef,efd->...ed"
+
+
+@pytest.mark.parametrize("shape", ["plain", "moe_shared_x", "moe_per_expert_x"])
+@pytest.mark.parametrize("form", ["flat_int8", "grouped_int8", "packed_int4"])
+def test_stacked_kernel_is_bit_identical_to_sliced(form, shape):
+    """The kernel reading layer l out of the stacked weights (scalar-prefetch
+    index) runs the sliced call's blocks in its order: equal bit for bit at
+    the first, a middle and the last layer, and close to the XLA oracle."""
+    from localai_tpu.models.llama import _moe_mm
+    from localai_tpu.models.quant import StackedLayer
+
+    L, E = 4, 3
+    moe = shape != "plain"
+    w = jax.random.normal(
+        jax.random.key(20), (L, E, 64, 96) if moe else (L, 64, 96)) * 0.1
+    q = _quantize_form(w, form)
+    x = jax.random.normal(
+        jax.random.key(21), (5, E, 64) if shape == "moe_per_expert_x" else (5, 64))
+    sub = _PER_EXPERT_X if shape == "moe_per_expert_x" else _SHARED_X
+
+    def mm(w, impl):
+        return _moe_mm(x, w, sub, impl=impl) if moe else matmul(x, w, impl=impl)
+
+    for l in (0, 2, L - 1):
+        stacked = mm(StackedLayer(q, jnp.int32(l)), "pallas")
+        np.testing.assert_array_equal(
+            np.asarray(stacked), np.asarray(mm(_layer(q, l), "pallas")))
+        np.testing.assert_allclose(
+            np.asarray(stacked), np.asarray(mm(_layer(q, l), "xla")),
+            rtol=2e-4, atol=2e-4)
+        # ... and sliced at the use site, the view is the plain layer
+        np.testing.assert_array_equal(
+            np.asarray(mm(StackedLayer(q, jnp.int32(l)), "xla")),
+            np.asarray(mm(_layer(q, l), "xla")))
+
+
+@pytest.mark.parametrize("form", ["flat_int8", "packed_int4"])
+def test_stacked_kernel_under_scan_with_a_traced_index(form):
+    """llama._scan_stack hands the body the stack and its loop counter; the
+    quantized leaves reach the kernel unsliced, the plain ones sliced."""
+    from localai_tpu.models.llama import _scan_stack
+    from localai_tpu.models.quant import StackedLayer
+
+    L = 3
+    w = jax.random.normal(jax.random.key(22), (L, 64, 64)) * 0.1
+    stack = {"w": _quantize_form(w, form), "b": jnp.arange(L, dtype=jnp.float32)}
+    x = jax.random.normal(jax.random.key(23), (4, 64))
+
+    def run(impl):
+        def layer(h, xs):
+            lp, i = xs
+            assert isinstance(lp["w"], StackedLayer) and lp["b"].shape == ()
+            return matmul(h, lp["w"], impl=impl) + lp["b"], i
+
+        return jax.jit(lambda h, st: _scan_stack(layer, h, st, 1, L + 1, ()))(x, stack)
+
+    (got, idx), (want, _) = run("pallas"), run("xla")
+    assert idx.tolist() == [1, 2, 3]  # the body's layer number counts from lo
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
+def _pallas_calls(jaxpr, name):
+    """Every pallas_call equation named `name`, through all sub-jaxprs."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" and (
+                eqn.params["name"] == name):
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_pallas_calls(sub, name))
+    return found
+
+
+def _int8_decode_step(B, impl):
+    import dataclasses
+
+    from localai_tpu.models import llama
+
+    cfg = dataclasses.replace(get_arch("tiny"), quant_kernel=impl)
+    params = quantize_params(cfg, init_params(cfg, jax.random.key(0)), "int8")
+    n, kv = 4, (cfg.num_kv_heads, cfg.head_dim_)
+    cache = llama.KVCache(
+        k=jnp.zeros((cfg.num_layers, B, 32, *kv), jnp.bfloat16),
+        v=jnp.zeros((cfg.num_layers, B, 32, *kv), jnp.bfloat16))
+    local = jnp.zeros((cfg.num_layers, B, n, *kv), jnp.bfloat16)
+    tok = jnp.arange(B, dtype=jnp.int32) % cfg.vocab_size
+    fn = lambda p, t, pos, c, lk, lv, s: llama.decode_step_windowed(  # noqa: E731
+        cfg, p, t, pos, c, lk, lv, s)
+    return cfg, fn, (params, tok, tok % 8, cache, local, local, jnp.int32(0))
+
+
+def test_decode_step_hands_the_kernels_the_stack_and_counts_it():
+    """In the decode step's jaxpr every int8_matmul takes a weight whose
+    leading dimension is the layer count, none a [1, in, out] copy, and the
+    site counter saw the same seven."""
+    from localai_tpu.ops.quant_matmul import SiteCounts
+
+    cfg, fn, args = _int8_decode_step(2, "pallas")
+    sites = SiteCounts()
+    with sites.tracing("decode_block"):
+        jaxpr = jax.make_jaxpr(fn)(*args)
+    calls = _pallas_calls(jaxpr.jaxpr, "int8_matmul")
+    assert len(calls) == 7  # q, k, v, o, gate, up, down: once in the layer scan
+    for eqn in calls:
+        weights = [v.aval for v in eqn.invars if v.aval.dtype == jnp.int8]
+        assert [w.ndim for w in weights] == [3]
+        assert weights[0].shape[0] == cfg.num_layers > 1
+    assert sites.by_program == {
+        "decode_block": {"traces": 1, "stacked": 7, "sliced": 0}}
+    assert sites.totals() == {"stacked": 7, "sliced": 0}
+
+
+def test_decode_step_above_the_row_limit_slices_at_the_use_site():
+    """Rows above QUANT_PALLAS_MAX_ROWS: no kernel in the jaxpr, every site
+    counts as sliced, and the numbers are the XLA path's."""
+    from localai_tpu.ops.quant_matmul import QUANT_PALLAS_MAX_ROWS, SiteCounts
+
+    B = QUANT_PALLAS_MAX_ROWS + 1
+    _, fn, args = _int8_decode_step(B, "pallas")
+    sites = SiteCounts()
+    with sites.tracing("admit"):
+        jaxpr = jax.make_jaxpr(fn)(*args)
+    assert not _pallas_calls(jaxpr.jaxpr, "int8_matmul")
+    assert not _pallas_calls(jaxpr.jaxpr, "int8_unembed")
+    assert sites.by_program["admit"] == {"traces": 1, "stacked": 0, "sliced": 7}
+    _, fn_xla, _ = _int8_decode_step(B, "xla")
+    got, want = jax.jit(fn)(*args)[0], jax.jit(fn_xla)(*args)[0]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_engine_gauges_count_stacked_and_sliced_sites():
+    """Engine.metrics() totals the sites of every program traced; the decode
+    block (2 rows) takes the stack at all seven, no kernel call is handed a
+    slice, and what the XLA engine slices it says too."""
+    cfg = get_arch("tiny")
+    params = init_params(cfg, jax.random.key(0))
+    seen = {}
+    for impl in ("pallas", "xla"):
+        eng = Engine(
+            cfg, params, ByteTokenizer(cfg.vocab_size),
+            engine_cfg=EngineConfig(max_slots=2, max_seq=128,
+                                    min_prefill_bucket=16, quant_kernel=impl),
+            quantization="int8",
+        )
+        try:
+            _, ev = eng.generate(list(range(1, 20)), max_new_tokens=6,
+                                 ignore_eos=True)
+            assert ev.kind == "done"
+            seen[impl] = (dict(eng.quant_sites.by_program), eng.metrics())
+        finally:
+            eng.stop()
+    by_program, metrics = seen["pallas"]
+    block = by_program["decode_block"]
+    assert block["stacked"] == 7 * block["traces"] and block["sliced"] == 0
+    assert metrics["quant_matmul_stacked_sites"] == sum(
+        p["stacked"] for p in by_program.values())
+    assert metrics["quant_matmul_sliced_sites"] == 0
+    by_program, metrics = seen["xla"]
+    assert metrics["quant_matmul_stacked_sites"] == 0
+    assert metrics["quant_matmul_sliced_sites"] == sum(
+        p["sliced"] for p in by_program.values()) >= 7
+
+
 @pytest.mark.multichip
 def test_pallas_matmul_sharded_tp2(multichip):
     """tp=2 shard_map dispatch: col (out axis), row (group axis + psum at
@@ -469,13 +650,59 @@ def test_pallas_matmul_sharded_tp2(multichip):
             )
 
 
-@pytest.mark.parametrize("mode", ["int4"])
-def test_quant_engine_pallas_matches_xla(mode):
+@pytest.mark.multichip
+def test_pallas_matmul_stacked_sharded_tp2(multichip):
+    """tp=2 shard_map with the weights still stacked: the layer axis stays
+    whole on every shard, the index is replicated; col, row (+psum) and both
+    MoE shapes equal the sharded call on the sliced layer bit for bit."""
+    if multichip is True:
+        return  # verdict delivered by the subprocess re-run
+    from localai_tpu.models.llama import _moe_mm
+    from localai_tpu.models.quant import StackedLayer
+    from localai_tpu.parallel.mesh import MeshPlan as MP_, build_mesh
+
+    mesh = build_mesh(MP_(tp=2))
+    L, E, l = 3, 4, 2
+    w = jax.random.normal(jax.random.key(24), (L, 64, 96), jnp.float32) * 0.1
+    wm = jax.random.normal(jax.random.key(25), (L, E, 64, 64), jnp.float32) * 0.1
+    x = jax.random.normal(jax.random.key(26), (5, 64), jnp.float32)
+    xe = jax.random.normal(jax.random.key(27), (5, E, 64), jnp.float32)
+
+    def both(fn, xx, q):
+        run = jax.jit(lambda xx, q, i: (fn(xx, StackedLayer(q, i)),
+                                        fn(xx, _layer(q, l))))
+        return run(xx, q, jnp.int32(l))
+
+    with mesh:
+        for form in ("flat_int8", "packed_int4"):
+            q, qm = _quantize_form(w, form), _quantize_form(wm, form)
+            cases = [
+                (x, q, lambda xx, ww, part=part: matmul(
+                    xx, ww, impl="pallas", mesh=mesh, part=part))
+                for part in ("col", "row")
+            ] + [
+                (xx, qm, lambda xx, ww, sub=sub: _moe_mm(
+                    xx, ww, sub, impl="pallas", mesh=mesh))
+                for xx, sub in ((x, _SHARED_X), (xe, _PER_EXPERT_X))
+            ]
+            for xx, qq, fn in cases:
+                stacked, sliced = both(fn, xx, qq)
+                np.testing.assert_array_equal(np.asarray(stacked),
+                                              np.asarray(sliced))
+
+
+@pytest.mark.parametrize("arch,mode", [
+    ("tiny", "int4"),
+    # DeepSeek layout: two stacks (the MoE stack starts at layer 1, lo > 0),
+    # MLA's extra projections, quantized experts and the shared expert.
+    ("tiny-mla", "int8"),
+])
+def test_quant_engine_pallas_matches_xla(arch, mode):
     """End-to-end: a quantized engine forced onto the Pallas dequant-matmul
     kernels (interpret mode on CPU) decodes the same greedy tokens as the
     XLA dequant path — quant_kernel is the dispatch knob, exactly like
     paged_kernel for the attention kernel."""
-    cfg = get_arch("tiny")
+    cfg = get_arch(arch)
     params = init_params(cfg, jax.random.key(0))
     prompt = list(range(1, 20))
     texts = {}
