@@ -67,6 +67,8 @@ SIZES = {
         # kernel phase: B slots, K kv heads, G q heads per kv head, head dim
         B=8, K=8, G=4, D=128, hidden=4096, ffn=14336, vocab=32000,
         chunk=512, verify=6, lora_rank=16, flash_lens=(32, 512, 2048), tp=4,
+        # olmoe-1b-7b's expert stack as published: layers, experts, widths
+        moe=dict(layers=16, experts=64, hidden=2048, ffn=1024),
     ),
     "tiny": dict(
         arch="tiny", slots=4, context=512, page=16,
@@ -74,6 +76,7 @@ SIZES = {
         B=4, K=2, G=2, D=16, hidden=64, ffn=128, vocab=512,
         chunk=32, verify=3, lora_rank=4, flash_lens=(32,),
         tp=2,  # the tiny preset has two kv heads
+        moe=dict(layers=2, experts=4, hidden=64, ffn=32),
     ),
 }
 
@@ -276,6 +279,35 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
 
     case("quant_int8_channel_ffn_stacked", stacked("auto"), stacked("xla"),
          (rnd((32, hid)), stack, jnp.int32(0), jnp.int32(3)), 2e-2)
+    # The MoE decode block's form at olmoe-1b-7b's published widths: 32 rows,
+    # the int8 experts still stacked over layers AND experts ([16·64, in, out]
+    # blocks, block layer·E + e by scalar prefetch), both einsum shapes, two
+    # non-zero layers. Drawn a layer at a time, as models/quant.py does. Same
+    # arithmetic as the dense case (bf16 rows, f32 accumulation) -> 2e-2.
+    from localai_tpu.models import llama as LL
+
+    mo = s["moe"]
+
+    def expert_stack(kin, kout):
+        return jax.jit(lambda kk: jax.lax.map(
+            lambda k1: Q.quantize_tensor(jax.random.normal(
+                k1, (mo["experts"], kin, kout), jnp.float32) * 0.02),
+            jax.random.split(kk, mo["layers"])))(next(keys))
+
+    def experts(impl):
+        def fn(x, up, down, a, b):
+            out = []
+            for i in (a, b):
+                h = LL._moe_mm(x, Q.StackedLayer(up, i), "...d,edf->...ef", impl)
+                out += [h, LL._moe_mm(h, Q.StackedLayer(down, i),
+                                      "...ef,efd->...ed", impl)]
+            return tuple(out)
+        return fn
+
+    case("moe_int8_experts_stacked", experts("auto"), experts("xla"),
+         (rnd((32, mo["hidden"])), expert_stack(mo["hidden"], mo["ffn"]),
+          expert_stack(mo["ffn"], mo["hidden"]),
+          jnp.int32(mo["layers"] // 3), jnp.int32(mo["layers"] - 1)), 2e-2)
     head = rnd((s["vocab"], hid), jnp.float32, 0.02)
     hs = jnp.maximum(jnp.max(jnp.abs(head), axis=-1, keepdims=True) / 127.0,
                      1e-9)

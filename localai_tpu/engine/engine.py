@@ -702,7 +702,11 @@ class _Entry:
     # Spec rounds (ISSUE 12): per-slot draft lengths chosen at dispatch —
     # the acceptance-EWMA update needs the denominator per slot.
     dlens: Optional[np.ndarray] = None
-    # Host-side results pulled by the drainer thread (toks, tk, lp as numpy).
+    # Decode block of a MoE model: device [2] i32, the block's routing sums
+    # (experts that got a row, busiest expert's rows); see _count_routing.
+    moe: Any = None
+    # Host-side results pulled by the drainer thread (toks, tk, lp, moe as
+    # numpy).
     host: Optional[tuple] = None
     host_done: bool = False
 
@@ -1532,6 +1536,11 @@ class Engine:
         self.m_rows_posted = 0
         self.m_rows_overshoot = 0
         self.m_rows_empty = 0
+        # Decode-block routing of a MoE model, see _count_routing.
+        self.m_moe_slots = 0
+        self.m_moe_slots_hit = 0
+        self.m_moe_rows_busiest = 0
+        self.m_moe_rows_mean = 0.0
         self._build_programs()
 
     # ------------------------------------------------------------------ #
@@ -2957,19 +2966,20 @@ class Engine:
                     # table forever. Their compute is discarded anyway, so
                     # pin them to 0 for this step's attention.
                     pos_eff = jnp.where(active, positions, 0)
-                    logits, lk, lv = llama.decode_step_windowed(
+                    logits, lk, lv, *routed = llama.decode_step_windowed(
                         cfg, params, tokens, pos_eff, cache, lk, lv, step,
                         ep=self.plan.ep, ptable=ptable,
                         paged_impl=self.ecfg.paged_kernel,
                         kv_scale=self._kv_scales,
                         rope_delta=rope_delta, mesh=self._op_mesh,
-                        lora=lora,
+                        lora=lora, expert_rows=cfg.is_moe,
                     )
                 else:
-                    logits, lk, lv = llama.decode_step_windowed(
+                    logits, lk, lv, *routed = llama.decode_step_windowed(
                         cfg, params, tokens, positions, read_cache, lk, lv, step,
                         ep=self.plan.ep, mesh=self._op_mesh,
                         rope_delta=rope_delta, lora=lora,
+                        expert_rows=cfg.is_moe,
                     )
                 split = jax.vmap(lambda k: jax.random.split(k, 2))(rngs)
                 rngs, draw = split[:, 0], split[:, 1]
@@ -3005,13 +3015,18 @@ class Engine:
                     lp_vals, lp_ids = jax.lax.top_k(logp, LK)
                     tok_lp = jnp.take_along_axis(logp, nxt[:, None], axis=-1)[:, 0]
                     out = out + (tok_lp, lp_ids, lp_vals)
+                # Routing of this step, [L, E] rows per expert → experts that
+                # got a row, and the busiest expert's rows summed over layers.
+                per = routed[0] if routed else None
+                moe = (None if per is None else
+                       jnp.stack([(per > 0).sum(), per.max(-1).sum()]))
                 # Clamp so idle/overshooting slots keep writing inside their
                 # own cache row instead of out-of-bounds.
                 positions = jnp.minimum(positions + 1, S - 1)
-                return (nxt, positions, counts, rngs, lk, lv, gs), out
+                return (nxt, positions, counts, rngs, lk, lv, gs), (out, moe)
 
             gs0 = gstate if with_dfa else jnp.zeros((B,), jnp.int32)
-            (tokens, positions, counts, rngs, local_k, local_v, gs), outs = jax.lax.scan(
+            (tokens, positions, counts, rngs, local_k, local_v, gs), (outs, moe) = jax.lax.scan(
                 body, (tokens, positions, counts, rngs, local_k, local_v, gs0),
                 jnp.arange(n),
             )
@@ -3025,7 +3040,10 @@ class Engine:
             toks_block = outs[0]  # [n, B]
             tk_block = outs[1] if variant == "grammar" else None
             lp_block = tuple(outs[-3:]) if with_lp else None  # ([n,B],[n,B,LK],[n,B,LK])
-            out = (cache, counts, rngs, tokens, positions, toks_block, tk_block, lp_block)
+            # [2] i32 over the block's steps, MoE models only (_count_routing)
+            moe_block = None if moe is None else moe.sum(0)
+            out = (cache, counts, rngs, tokens, positions, toks_block, tk_block,
+                   lp_block, moe_block)
             if with_dfa:
                 out = out + (gs,)
             return out
@@ -5771,7 +5789,8 @@ class Engine:
                 tk = np.asarray(e.tk) if e.tk is not None else None
                 lp = (tuple(np.asarray(a) for a in e.lp)
                       if e.lp is not None else None)
-                e.host = (toks, tk, lp)
+                moe = np.asarray(e.moe) if e.moe is not None else None
+                e.host = (toks, tk, lp, moe)
             except Exception as ex:  # noqa: BLE001 — surface via processing
                 e.host = ex
             e.host_done = True
@@ -6131,6 +6150,12 @@ class Engine:
         out["decode_rows_posted"] = float(self.m_rows_posted)
         out["decode_rows_overshoot"] = float(self.m_rows_overshoot)
         out["decode_rows_empty"] = float(self.m_rows_empty)
+        if self.cfg.is_moe:
+            # Routing of the decode blocks processed, see _count_routing.
+            out["moe_expert_slots"] = float(self.m_moe_slots)
+            out["moe_expert_slots_hit"] = float(self.m_moe_slots_hit)
+            out["moe_rows_busiest"] = float(self.m_moe_rows_busiest)
+            out["moe_rows_mean"] = float(self.m_moe_rows_mean)
         sites = self.quant_sites.totals()
         if sites["stacked"] or sites["sliced"]:
             # Quantized layer matmuls over every program traced so far: the
@@ -6293,7 +6318,7 @@ class Engine:
             args = args + (self._ptable_device(),)
         (
             self.cache, self.counts, self.rngs, self.d_tokens, self.d_positions,
-            toks, _tk, _lp,
+            toks, _tk, _lp, _moe,
         ) = fn(*args)
         jax.block_until_ready(toks)
 
@@ -7884,13 +7909,14 @@ class Engine:
         if p.with_dfa:
             (
                 self.cache, self.counts, self.rngs, self.d_tokens,
-                self.d_positions, toks_block, tk_block, lp_block, self.d_gstate,
+                self.d_positions, toks_block, tk_block, lp_block, moe_block,
+                self.d_gstate,
             ) = out
             self.m_dfa_tokens += n * int((self.h_gmask * active_snapshot).sum())
         else:
             (
                 self.cache, self.counts, self.rngs, self.d_tokens, self.d_positions,
-                toks_block, tk_block, lp_block,
+                toks_block, tk_block, lp_block, moe_block,
             ) = out
         _host_copy_async(toks_block)
         if tk_block is not None:
@@ -7904,6 +7930,7 @@ class Engine:
             _Entry(
                 kind="block", toks=toks_block, tk=tk_block, lp=lp_block,
                 gen=list(self._slot_gen), active=active_snapshot, n=n,
+                moe=moe_block,
             )
         )
         return True
@@ -8178,7 +8205,7 @@ class Engine:
         if isinstance(e.host, Exception):
             raise e.host
         if e.host is not None:
-            toks, tk, lp = e.host  # pre-pulled by the drainer thread
+            toks, tk, lp, moe = e.host  # pre-pulled by the drainer thread
         else:
             # Forced processing (depth pressure) before the drainer got
             # there: wait for the result in slices, so that the blocked
@@ -8197,6 +8224,7 @@ class Engine:
             lp = (
                 tuple(np.asarray(a) for a in e.lp) if e.lp is not None else None
             )  # (tok_lp, lp_ids, lp_vals)
+            moe = np.asarray(e.moe) if e.moe is not None else None
         self._phases.begin("process")
         # Charge the just-completed block's interval BEFORE any done events
         # post: a caller reading the throughput counters right after
@@ -8320,6 +8348,28 @@ class Engine:
                 self._post_token(i, tok, lpi)
         self._decode_tokens += consumed
         self._count_rows(e, consumed)
+        if moe is not None:
+            self._count_routing(e, moe)
+
+    # thread: engine-loop-only
+    def _count_routing(self, e: _Entry, sums: np.ndarray) -> None:
+        """Account one decode block's routing, summed on the device over its
+        steps and MoE layers: of the expert slots offered (steps x MoE layers
+        x experts) how many got at least one of the compiled batch rows, and
+        the busiest expert's rows against the mean rows per expert (rows x
+        top-k / experts). Every compiled row counts, live or not: the
+        all-experts kernel computes them all."""
+        cfg = self.cfg
+        layers = cfg.num_layers - cfg.first_k_dense
+        slots = e.n * layers * cfg.num_experts
+        mean = (e.n * layers * self.ecfg.max_slots
+                * cfg.num_experts_per_token / cfg.num_experts)
+        self.m_moe_slots += slots
+        self.m_moe_slots_hit += int(sums[0])
+        self.m_moe_rows_busiest += int(sums[1])
+        self.m_moe_rows_mean += mean
+        self._jnote("moe_experts", a=float(slots), b=float(sums[0]))
+        self._jnote("moe_load", a=float(sums[1]), b=float(mean))
 
     # thread: engine-loop-only
     def _count_rows(self, e: _Entry, posted: int) -> None:

@@ -49,6 +49,20 @@ _MOE_LAYER_MAP = {
     "w_down": ("block_sparse_moe.experts.{e}.w2.weight", True),
 }
 
+# OLMoE keeps its MoE block under `mlp.` (as the deepseek loader's names do).
+_OLMOE_LAYER_MAP = {
+    "router": ("mlp.gate.weight", True),
+    "w_gate": ("mlp.experts.{e}.gate_proj.weight", True),
+    "w_up": ("mlp.experts.{e}.up_proj.weight", True),
+    "w_down": ("mlp.experts.{e}.down_proj.weight", True),
+}
+
+
+def _moe_layer_map(cfg: ArchConfig) -> dict:
+    """HF tensor names of a non-MLA MoE block: Mixtral's, or OLMoE's for the
+    family that scores all experts before it selects."""
+    return _OLMOE_LAYER_MAP if cfg.moe_family == "deepseek" else _MOE_LAYER_MAP
+
 
 def _index(ckpt_dir: str) -> dict[str, str]:
     """tensor name -> safetensors shard filename."""
@@ -326,9 +340,9 @@ def load_hf_checkpoint(
         layer_map["mlp_norm"] = ("pre_feedforward_layernorm.weight", False)
         layer_map["post_attn_norm"] = ("post_attention_layernorm.weight", False)
         layer_map["post_ffw_norm"] = ("post_feedforward_layernorm.weight", False)
-    if cfg.qk_norm:
+    if cfg.qk_norm or cfg.qk_norm_full:
         # Gemma-3 per-head q/k norms ((1+w) fold applies — they end in
-        # "norm.weight").
+        # "norm.weight"); OLMoE's span the whole projection, same names.
         layer_map["q_norm"] = ("self_attn.q_norm.weight", False)
         layer_map["k_norm"] = ("self_attn.k_norm.weight", False)
     for our, (suffix, transpose) in layer_map.items():
@@ -341,11 +355,12 @@ def load_hf_checkpoint(
         )
 
     if cfg.is_moe:
+        moe_map = _moe_layer_map(cfg)
         layers["router"] = put(
-            "layers/router", stack_layers("router", _MOE_LAYER_MAP["router"][0], True)
+            "layers/router", stack_layers("router", moe_map["router"][0], True)
         )
         for our in ("w_gate", "w_up", "w_down"):
-            suffix, transpose = _MOE_LAYER_MAP[our]
+            suffix, transpose = moe_map[our]
             per_layer = []
             for i in range(cfg.num_layers):
                 experts = [
@@ -916,7 +931,7 @@ def save_hf_checkpoint(cfg: ArchConfig, params: Params, ckpt_dir: str) -> None:
         layer_map["mlp_norm"] = ("pre_feedforward_layernorm.weight", False)
         layer_map["post_attn_norm"] = ("post_attention_layernorm.weight", False)
         layer_map["post_ffw_norm"] = ("post_feedforward_layernorm.weight", False)
-    if cfg.qk_norm:
+    if cfg.qk_norm or cfg.qk_norm_full:
         layer_map["q_norm"] = ("self_attn.q_norm.weight", False)
         layer_map["k_norm"] = ("self_attn.k_norm.weight", False)
     for our, (suffix, transpose) in layer_map.items():
@@ -925,10 +940,11 @@ def save_hf_checkpoint(cfg: ArchConfig, params: Params, ckpt_dir: str) -> None:
         for i in range(cfg.num_layers):
             emit(f"model.layers.{i}.{suffix}", layers[our][i], transpose)
     if cfg.is_moe:
+        moe_map = _moe_layer_map(cfg)
         for i in range(cfg.num_layers):
-            emit(f"model.layers.{i}.{_MOE_LAYER_MAP['router'][0]}", layers["router"][i], True)
+            emit(f"model.layers.{i}.{moe_map['router'][0]}", layers["router"][i], True)
             for our in ("w_gate", "w_up", "w_down"):
-                suffix, transpose = _MOE_LAYER_MAP[our]
+                suffix, transpose = moe_map[our]
                 for e in range(cfg.num_experts):
                     emit(f"model.layers.{i}.{suffix.format(e=e)}", layers[our][i, e], transpose)
 
@@ -941,7 +957,10 @@ def save_hf_checkpoint(cfg: ArchConfig, params: Params, ckpt_dir: str) -> None:
 
     save_file(tensors, os.path.join(ckpt_dir, "model.safetensors"))
 
-    if cfg.is_moe:
+    olmoe = cfg.is_moe and cfg.moe_family == "deepseek"
+    if olmoe:
+        model_type = "olmoe"
+    elif cfg.is_moe:
         model_type = "mixtral"
     elif cfg.post_norms:
         model_type = "gemma2"
@@ -968,8 +987,11 @@ def save_hf_checkpoint(cfg: ArchConfig, params: Params, ckpt_dir: str) -> None:
         "tie_word_embeddings": cfg.tie_embeddings,
     }
     if cfg.is_moe:
-        hf_config["num_local_experts"] = cfg.num_experts
+        hf_config["num_experts" if olmoe else "num_local_experts"] = cfg.num_experts
         hf_config["num_experts_per_tok"] = cfg.num_experts_per_token
+    if olmoe:
+        hf_config["norm_topk_prob"] = cfg.norm_topk_prob
+        hf_config["clip_qkv"] = None
     if cfg.post_norms:
         hf_config["attn_logit_softcapping"] = cfg.attn_softcap or None
         hf_config["final_logit_softcapping"] = cfg.final_softcap or None
@@ -1242,6 +1264,14 @@ def arch_from_hf_config(ckpt_dir: str) -> ArchConfig:
             # the flag (default true).
             rope_interleave=True if not v3 else bool(hf.get("rope_interleave", True)),
         )
+    # HF OlmoeAttention / OlmoeSparseMoeBlock: q/k RMS norms over the whole
+    # projection; softmax over all experts, then top-k, weights renormalised
+    # only when norm_topk_prob. intermediate_size is the expert width (there
+    # is no dense MLP).
+    olmoe = model_type == "olmoe"
+    if olmoe and hf.get("clip_qkv") is not None:
+        raise ValueError("olmoe clip_qkv is not supported (published OLMoE "
+                         "checkpoints leave it null)")
     return ArchConfig(
         name=hf.get("_name_or_path", model_type) or model_type,
         vocab_size=hf["vocab_size"],
@@ -1279,6 +1309,9 @@ def arch_from_hf_config(ckpt_dir: str) -> ArchConfig:
         query_scale=float(hf.get("query_pre_attn_scalar") or 0.0) if softcaps else 0.0,
         sliding_window=int(hf.get("sliding_window") or 0) if softcaps else 0,
         sliding_pattern=sliding_pattern,
-        num_experts=hf.get("num_local_experts", 0),
+        num_experts=hf.get("num_experts" if olmoe else "num_local_experts", 0),
         num_experts_per_token=hf.get("num_experts_per_tok", 2),
+        qk_norm_full=olmoe,
+        moe_family="deepseek" if olmoe else "mixtral",
+        norm_topk_prob=olmoe and bool(hf.get("norm_topk_prob", False)),
     )

@@ -76,6 +76,10 @@ class ArchConfig:
     sliding_pattern: int = 2
     # Gemma-3: per-head RMS norms on q and k (after projection, before rope).
     qk_norm: bool = False
+    # OLMoE: ONE RMS norm over the whole q projection (all heads, weight
+    # [H·Hd]) and one over the whole k projection ([K·Hd]), before the split
+    # into heads — a different reduction from the per-head form above.
+    qk_norm_full: bool = False
     # Mixture-of-experts (Mixtral/DeepSeek-style); 0 experts = dense MLP
     num_experts: int = 0
     num_experts_per_token: int = 2
@@ -231,6 +235,23 @@ PRESETS: dict[str, ArchConfig] = {
         num_experts=4,
         num_experts_per_token=2,
     ),
+    "tiny-olmoe": ArchConfig(
+        # OLMoE-shaped tiny: full-width q/k norm, softmax over ALL experts
+        # then top-k with the weights used as they are (no renormalisation),
+        # no shared expert, no dense prefix, untied head.
+        name="tiny-olmoe",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=32,
+        num_layers=2,
+        num_heads=4,
+        num_kv_heads=4,
+        max_position=512,
+        qk_norm_full=True,
+        moe_family="deepseek",
+        num_experts=8,
+        num_experts_per_token=2,
+    ),
     "tiny-mla": ArchConfig(
         # DeepSeek-V3-shaped tiny: MLA with q-lora, sigmoid router with
         # correction bias, group-limited top-k, shared expert, dense-first
@@ -323,6 +344,29 @@ PRESETS: dict[str, ArchConfig] = {
         max_position=32768,
         num_experts=8,
         num_experts_per_token=2,
+    ),
+    "olmoe-1b-7b": ArchConfig(
+        # allenai/OLMoE-1B-7B-0125-Instruct config.json: 6.9B total / 1.3B
+        # active; intermediate_size IS the expert width (no dense MLP, no
+        # shared expert); head_dim is not published (2048 / 16). The router
+        # is `_deepseek_route`'s order (softmax over all 64, then top-8)
+        # with norm_topk_prob false and no scaling.
+        name="olmoe-1b-7b",
+        vocab_size=50304,
+        hidden_size=2048,
+        intermediate_size=1024,
+        num_layers=16,
+        num_heads=16,
+        num_kv_heads=16,
+        rope_theta=10000.0,
+        max_position=4096,
+        rms_eps=1e-5,
+        qk_norm_full=True,
+        moe_family="deepseek",
+        num_experts=64,
+        num_experts_per_token=8,
+        scoring_func="softmax",
+        norm_topk_prob=False,
     ),
     "deepseek-v2-lite": ArchConfig(
         # Published card: 27 layers, 16B total / 2.4B active, MLA without

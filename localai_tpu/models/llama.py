@@ -35,6 +35,7 @@ from localai_tpu.ops.attention import (
     prefill_attention,
 )
 from localai_tpu.ops.norm import rms_norm
+from localai_tpu.ops.quant_matmul import QUANT_PALLAS_MAX_ROWS
 from localai_tpu.ops.rope import (
     apply_rope,
     rope_frequencies,
@@ -112,6 +113,9 @@ def _init_attn_layers(cfg: ArchConfig, rnd, keys, L: int) -> Params:
     if cfg.qk_norm:
         layers["q_norm"] = jnp.ones((L, Hd), dt)
         layers["k_norm"] = jnp.ones((L, Hd), dt)
+    elif cfg.qk_norm_full:
+        layers["q_norm"] = jnp.ones((L, H * Hd), dt)
+        layers["k_norm"] = jnp.ones((L, K * Hd), dt)
     if cfg.attn_qkv_bias:
         layers["bq"] = jnp.zeros((L, H * Hd), dt)
         layers["bk"] = jnp.zeros((L, K * Hd), dt)
@@ -321,16 +325,18 @@ def _deepseek_route(cfg: ArchConfig, lp: Params, x: jnp.ndarray):
 
 
 def _moe_dense(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
-               mesh=None) -> jnp.ndarray:
+               mesh=None, route=None) -> jnp.ndarray:
     """All-experts MoE: every expert runs on every token, outputs combined by
-    routing weight. FLOPs ∝ E, but the only path that works on quantized
-    (int8/int4 grouped) expert weights without materializing a dequantized
-    copy, and trivially shardable over "ep". Decode batches are tiny and
-    weight-HBM-bound (every expert's weights are read regardless), so for
-    quantized decode this is near-optimal anyway."""
+    routing weight. FLOPs and temporaries ∝ rows × E, but quantized expert
+    stacks go through the fused Pallas dequant-matmul without any
+    dequantized copy, and it is trivially shardable over "ep". Decode
+    batches are tiny and weight-HBM-bound (every expert's weights are read
+    regardless), so for quantized decode this is near-optimal; wide row
+    counts take `_moe_ragged` (see `_mlp`). `route` = (weights, sel) when
+    the caller already ran the router."""
     E = cfg.num_experts
     qk = cfg.quant_kernel
-    weights, sel = _moe_route(cfg, lp, x)
+    weights, sel = route or _moe_route(cfg, lp, x)
     onehot = jax.nn.one_hot(sel, E, dtype=jnp.float32)  # [..., topk, E]
     combine = jnp.einsum("...te,...t->...e", onehot, weights)
     gate = _act(cfg, _moe_mm(x, lp["w_gate"], "...d,edf->...ef", qk, mesh))
@@ -339,9 +345,41 @@ def _moe_dense(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
     return jnp.einsum("...ed,...e->...d", expert_out.astype(jnp.float32), combine).astype(x.dtype)
 
 
-def _moe_ragged(cfg: ArchConfig, lp: Params, x: jnp.ndarray) -> jnp.ndarray:
+def _ragged_mm(xg: jnp.ndarray, w, sizes: jnp.ndarray,
+               group: jnp.ndarray) -> jnp.ndarray:
+    """`lax.ragged_dot` of expert-sorted rows xg [M, in] with an expert stack
+    w [G, in, out], plain or quantized; `group` [M] is each row's group.
+    Flat int8 converts in the operand and scales each ROW afterwards by its
+    own expert's per-channel scale (exactly x @ (q·s): the scale does not
+    depend on the contracted axis). The grouped forms scale per in-group, so
+    their layer is dequantized in front of the dot: a temporary of one
+    layer's stack in x's dtype, whatever the rows."""
+    if not isinstance(w, dict):
+        return jax.lax.ragged_dot(xg, w, sizes)
+    if "q" in w:
+        y = jax.lax.ragged_dot(xg, w["q"].astype(xg.dtype), sizes)
+        return y * jnp.take(w["s"][..., 0, :], group, axis=0).astype(y.dtype)
+    return jax.lax.ragged_dot(
+        xg, quant.dequantize_tensor(w).astype(xg.dtype), sizes)
+
+
+def _expert_stack(w):
+    """One layer's expert weights [E, ...] for the XLA forms: a quantized
+    leaf still stacked over layers is sliced here (and counted as a sliced
+    site, like every other layer matmul that does not take the stack)."""
+    if not isinstance(w, dict):
+        return w
+    from localai_tpu.ops.quant_matmul import note_site
+
+    note_site(stacked=False)
+    return quant.layer_slice(w)
+
+
+def _moe_ragged(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
+                route=None) -> jnp.ndarray:
     """Exact top-k MoE via sort + `lax.ragged_dot`: per-token FLOPs ∝ top_k,
-    not E (4× fewer than dense for Mixtral top-2-of-8).
+    not E (4× fewer than dense for Mixtral top-2-of-8), and temporaries
+    ∝ rows × top_k.
 
     The (token, choice) pairs are stably sorted by expert id so each expert's
     rows are contiguous, then one grouped matmul per projection runs all
@@ -349,18 +387,22 @@ def _moe_ragged(cfg: ArchConfig, lp: Params, x: jnp.ndarray) -> jnp.ndarray:
     output is bit-comparable to the dense branch (up to f32 reduction order).
     The reference gets this for free from llama.cpp's per-expert CPU loops
     (ggml MoE graph); on TPU ragged_dot maps the grouped contraction onto the
-    MXU with static shapes.
+    MXU with static shapes. Plain and quantized expert stacks alike
+    (`_ragged_mm`). `route` = (weights, sel) when the caller already ran the
+    router.
     """
     E, k = cfg.num_experts, cfg.num_experts_per_token
     lead, D = x.shape[:-1], x.shape[-1]
     xf = x.reshape(-1, D)
     N = xf.shape[0]
-    weights, sel = _moe_route(cfg, lp, xf)  # [N, k]
+    weights, sel = route or _moe_route(cfg, lp, xf)
     M = N * k
     e_flat = sel.reshape(M)
     order = jnp.argsort(e_flat, stable=True)  # expert-major, token-minor
     tok = order // k  # source token of each sorted row
     xg = jnp.take(xf, tok, axis=0)  # [M, D]
+    group = jnp.take(e_flat, order)  # [M] expert of each sorted row
+    ws = [_expert_stack(lp[n]) for n in ("w_gate", "w_up", "w_down")]
     if M < E:
         # Decode-scale batches can touch at most M of E experts. Gathering
         # just the active experts' weights bounds HBM weight traffic by
@@ -371,17 +413,16 @@ def _moe_ragged(cfg: ArchConfig, lp: Params, x: jnp.ndarray) -> jnp.ndarray:
         # group slots; pad slots (fill E, clipped for the gather) count
         # zero rows and contribute nothing.
         uniq = jnp.unique(e_flat, size=M, fill_value=E)  # [M] sorted ids
-        gs = jnp.bincount(jnp.searchsorted(uniq, e_flat), length=M)
+        group = jnp.searchsorted(uniq, group)  # slot among the gathered
+        gs = jnp.bincount(group, length=M)
         gidx = jnp.minimum(uniq, E - 1)
-        w_gate = jnp.take(lp["w_gate"], gidx, axis=0)
-        w_up = jnp.take(lp["w_up"], gidx, axis=0)
-        w_down = jnp.take(lp["w_down"], gidx, axis=0)
+        ws = [jax.tree.map(lambda a: jnp.take(a, gidx, axis=0), w) for w in ws]
     else:
         gs = jnp.bincount(e_flat, length=E)  # rows per expert (sums to M)
-        w_gate, w_up, w_down = lp["w_gate"], lp["w_up"], lp["w_down"]
-    gate = _act(cfg, jax.lax.ragged_dot(xg, w_gate, gs))
-    up = jax.lax.ragged_dot(xg, w_up, gs)
-    dn = jax.lax.ragged_dot((gate * up).astype(xg.dtype), w_down, gs)  # [M, D]
+    w_gate, w_up, w_down = ws
+    gate = _act(cfg, _ragged_mm(xg, w_gate, gs, group))
+    up = _ragged_mm(xg, w_up, gs, group)
+    dn = _ragged_mm((gate * up).astype(xg.dtype), w_down, gs, group)  # [M, D]
     wf = jnp.take(weights.reshape(M), order)
     y = jnp.zeros((N, D), jnp.float32).at[tok].add(dn.astype(jnp.float32) * wf[:, None])
     return y.reshape(*lead, D).astype(x.dtype)
@@ -466,20 +507,28 @@ def _lora_add(cfg: ArchConfig, lora, key: str, x: jnp.ndarray,
 
 
 def _mlp(cfg: ArchConfig, lp: Params, x: jnp.ndarray, ep: int = 1,
-         mesh=None, lora=None) -> jnp.ndarray:
-    """SwiGLU MLP; dense or sparse-MoE (Mixtral/DeepSeek top-k routing).
+         mesh=None, lora=None, picks=None) -> jnp.ndarray:
+    """SwiGLU MLP; dense or sparse-MoE (Mixtral/DeepSeek/OLMoE top-k routing).
 
     x: [..., D]. MoE is detected per-stack ("router" in lp) so DeepSeek's
     dense-prefix layers run the plain branch under the same body. MoE picks
-    its implementation statically:
-    - quantized expert weights → dense all-experts (the grouped-int kernels
-      in models/quant.py only exist for the dense einsum shapes);
-    - ep > 1 → GShard capacity dispatch (shards over the "ep" mesh axis);
-    - otherwise → exact sort+ragged_dot top-k (FLOPs ∝ top_k; at decode
-      batch sizes only the ACTIVE experts' weights are gathered, which is
-      where top-8-of-256 models win — see _moe_ragged).
+    its implementation HERE and nowhere else, statically, from what the
+    trace sees (the expert leaf, the rows, the mesh):
+    - quantized experts at the rows the stacked Pallas kernel serves
+      (≤ QUANT_PALLAS_MAX_ROWS: decode blocks, verify chunks, short tails)
+      → all-experts `_moe_dense`: every expert's bytes are read whatever
+      the routing, the kernel reads its layer in place, and rows × E is
+      small. Also under ep > 1 (shards over "ep" as it is);
+    - quantized experts at wider rows (admission groups, long prompts)
+      → sort + ragged_dot on the quantized stack: FLOPs ∝ top_k and
+      temporaries ∝ rows × top_k, where all-experts builds rows × E;
+    - plain experts, ep > 1 → GShard capacity dispatch (shards over "ep");
+    - plain experts otherwise → the same sort + ragged_dot (at decode batch
+      sizes only the ACTIVE experts' weights are gathered, which is where
+      top-8-of-256 models win — see _moe_ragged).
     DeepSeek MoE layers add an always-on shared-expert MLP (HF
-    DeepseekV3MoE.shared_experts).
+    DeepseekV3MoE.shared_experts). `picks`, a list, receives the router's
+    chosen expert ids [..., k] (the engine's routing counters).
     """
     qk = cfg.quant_kernel
     if "router" not in lp:
@@ -496,12 +545,18 @@ def _mlp(cfg: ArchConfig, lp: Params, x: jnp.ndarray, ep: int = 1,
             cfg, lora, "w_down", gu, matmul(gu, lp["w_down"], qk, mesh, "row"),
             "row", mesh,
         ).astype(x.dtype)
-    if isinstance(lp["w_gate"], dict):
-        y = _moe_dense(cfg, lp, x, mesh=mesh)
-    elif ep > 1:
-        y = _moe_capacity(cfg, lp, x)
+    quantized = quant.is_quantized(lp["w_gate"])
+    if ep > 1 and not quantized:
+        y = _moe_capacity(cfg, lp, x)  # routes block by block, inside
     else:
-        y = _moe_ragged(cfg, lp, x)
+        route = _moe_route(cfg, lp, x)
+        if picks is not None:
+            picks.append(route[1])
+        rows = x.size // x.shape[-1]
+        if quantized and (ep > 1 or rows <= QUANT_PALLAS_MAX_ROWS):
+            y = _moe_dense(cfg, lp, x, mesh=mesh, route=route)
+        else:
+            y = _moe_ragged(cfg, lp, x, route=route)
     if "shared_gate" in lp:
         sg = _act(cfg, matmul(x, lp["shared_gate"], qk, mesh, "col"))
         y = y + matmul(sg * matmul(x, lp["shared_up"], qk, mesh, "col"),
@@ -526,9 +581,9 @@ def _attn_out(cfg: ArchConfig, lp: Params, attn_flat: jnp.ndarray,
 
 @jax.named_scope("mlp")
 def _mlp_out(cfg: ArchConfig, lp: Params, x: jnp.ndarray, ep: int = 1,
-             mesh=None, lora=None) -> jnp.ndarray:
+             mesh=None, lora=None, picks=None) -> jnp.ndarray:
     """MLP + optional gemma-2 post-feedforward sandwich norm."""
-    m = _mlp(cfg, lp, x, ep, mesh=mesh, lora=lora)
+    m = _mlp(cfg, lp, x, ep, mesh=mesh, lora=lora, picks=picks)
     if cfg.post_norms:
         m = rms_norm(m, lp["post_ffw_norm"], cfg.rms_eps)
     return m
@@ -570,6 +625,10 @@ def _attn_proj_qkv(cfg: ArchConfig, lp: Params, x: jnp.ndarray, mesh=None,
         q = q + lp["bq"]
         k = k + lp["bk"]
         v = v + lp["bv"]
+    if cfg.qk_norm_full:
+        # OLMoE: one RMS norm across the whole projection, all heads at once.
+        q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
     q = q.reshape(*x.shape[:-1], H, Hd)
     k = k.reshape(*x.shape[:-1], K, Hd)
     v = v.reshape(*x.shape[:-1], K, Hd)
@@ -1014,6 +1073,7 @@ def decode_step_windowed(
     # per-request constant, so plain rope at the shifted position is exact.
     lora=None,  # (stacked adapter factors, ids [B]) — per-slot runtime
     # LoRA deltas applied unmerged beside the base matmuls (ISSUE 10)
+    expert_rows: bool = False,  # also return the router's rows per expert
 ):
     """One step of a fused decode block with a block-local KV window.
 
@@ -1022,6 +1082,11 @@ def decode_step_windowed(
     the cache once per block. Returns (logits [B, V] f32, local_k, local_v).
     One layer body serves all three cache layouts (dense / sp-sharded /
     paged) — only the attention call differs.
+
+    With `expert_rows` a fourth value follows: [L, E] int32, how many of the
+    B rows the router sent to each expert in each layer (zeros for a
+    dense-prefix layer), or None where no layer ran a router that `_mlp`
+    sees (dense models, the ep > 1 capacity dispatch).
     """
     B = tokens.shape[0]
     use_sp = mesh is not None and mesh.shape.get("sp", 1) > 1
@@ -1029,6 +1094,19 @@ def decode_step_windowed(
     inv_local = rope_frequencies_local(cfg)
     rope_pos = positions if rope_delta is None else positions + rope_delta
     h = _embed(cfg, params, tokens)
+    routed = []  # trace time: did any layer body see a router's choice
+
+    def mlp(lp, x, llora=None):
+        """The layer's MLP and, with `expert_rows`, its rows per expert."""
+        if not expert_rows:
+            return _mlp_out(cfg, lp, x, ep, mesh, lora=llora), ()
+        picks: list = []
+        m = _mlp_out(cfg, lp, x, ep, mesh, lora=llora, picks=picks)
+        E = max(cfg.num_experts, 1)
+        if not picks:
+            return m, (jnp.zeros((E,), jnp.int32),)
+        routed.append(True)
+        return m, (jax.nn.one_hot(picks[0], E, dtype=jnp.int32).sum((0, 1)),)
 
     def layer(h, xs):
         if lora is None:
@@ -1060,8 +1138,8 @@ def decode_step_windowed(
                 )
             h = h + _attn_out(cfg, lp, _mla_unlatent(cfg, lp, attn), mesh)
             x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-            h = h + _mlp_out(cfg, lp, x, ep, mesh)
-            return h, (rows, rows[..., :0])
+            m, rows_e = mlp(lp, x)
+            return h + m, (rows, rows[..., :0]) + rows_e
         q, k, v = _attn_proj_qkv(cfg, lp, x, mesh, lora=llora)
         with jax.named_scope("attention"):
             q = apply_rope(q[:, None], rope_pos[:, None], inv)[:, 0]
@@ -1094,13 +1172,13 @@ def decode_step_windowed(
                 )
         h = h + _attn_out(cfg, lp, attn.reshape(B, -1), mesh, lora=llora)
         x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-        h = h + _mlp_out(cfg, lp, x, ep, mesh, lora=llora)
-        return h, (k, v)
+        m, rows_e = mlp(lp, x, llora)
+        return h + m, (k, v) + rows_e
 
     extras = (cache.k, cache.v, local_k, local_v)
     if lora is not None:
         extras = extras + (lora[0],)
-    h, (new_k, new_v) = _scan_layers(cfg, params, h, layer, extras)
+    h, (new_k, new_v, *rows_e) = _scan_layers(cfg, params, h, layer, extras)
     local_k = jax.lax.dynamic_update_index_in_dim(
         local_k, new_k.astype(local_k.dtype), step, axis=2
     )
@@ -1108,7 +1186,10 @@ def decode_step_windowed(
         local_v, new_v.astype(local_v.dtype), step, axis=2
     )
     h = rms_norm(h, params["final_norm"], cfg.rms_eps)
-    return _unembed(cfg, params, h, mesh), local_k, local_v
+    logits = _unembed(cfg, params, h, mesh)
+    if expert_rows:
+        return logits, local_k, local_v, (rows_e[0] if routed else None)
+    return logits, local_k, local_v
 
 
 def write_block_to_cache(

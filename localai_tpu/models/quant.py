@@ -272,8 +272,9 @@ def init_params_quantized(
     """Random init that lands directly in the quantized representation.
 
     Builds the same tree `quantize_params(mode=...)` would produce, but leaf
-    by leaf: the bf16 tensor only ever exists as a transient inside one jit,
-    so peak HBM ≈ the quantized tree + the largest single weight. This is how
+    by leaf (expert stacks layer by layer): the float tensor only ever
+    exists as a transient inside one jit, so peak HBM ≈ the quantized tree +
+    the largest single dense weight. This is how
     a synthetic llama-3-8b serves from a single 16 GB chip (a whole-tree bf16
     init is 2x HBM and OOMs before quantization could run).
     """
@@ -302,6 +303,15 @@ def init_params_quantized(
         if name in ("bq", "bk", "bv"):
             return jnp.zeros(sd.shape, sd.dtype)
         k = next(keys)
+        if name in QUANT_LAYER_KEYS and len(sd.shape) == 4:
+            # An expert stack [L, E, in, out] is E times a dense leaf (OLMoE:
+            # 2**31 elements, 8.6 GB as one float32 transient beside a tree
+            # that is already half there): drawn and quantized a layer at a
+            # time, so the transient is one layer's.
+            return jax.jit(lambda kk: jax.lax.map(
+                lambda k1: qfn(
+                    jax.random.normal(k1, sd.shape[1:], jnp.float32) * scale),
+                jax.random.split(kk, sd.shape[0])))(k)
         if name in QUANT_LAYER_KEYS:
             return jax.jit(lambda kk: qfn(
                 jax.random.normal(kk, sd.shape, jnp.float32) * scale

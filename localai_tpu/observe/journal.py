@@ -89,6 +89,12 @@ BASE_EVENTS = (
     "decode_rows_lost",  # the same block's other rows (a=overshoot: live at
     #                  dispatch, no token; b=empty: not live at dispatch);
     #                  a + b + decode_rows.b = decode_rows.a
+    "moe_experts",   # a MoE model's decode block was processed (a=expert
+    #                  slots offered: steps x MoE layers x experts, b=of
+    #                  those, experts at least one compiled row chose)
+    "moe_load",      # the same block's load (a=sum over step and layer of
+    #                  the busiest expert's rows, b=sum of the mean rows per
+    #                  expert: compiled rows x top-k / experts)
 )
 
 # One journal event type per fault-injection site (faults.SITES), checked

@@ -528,7 +528,9 @@ _TALLY: contextvars.ContextVar = contextvars.ContextVar(
     "quant_matmul_site_tally", default=None)
 
 
-def _note_site(stacked: bool) -> None:
+def note_site(stacked: bool) -> None:
+    """Count one quantized layer-matmul call site of the program being
+    traced (no-op outside `SiteCounts.tracing`)."""
     tally = _TALLY.get()
     if tally is not None:
         tally["stacked" if stacked else "sliced"] += 1
@@ -540,7 +542,7 @@ def dispatch_matmul(x, w: dict, impl: str = "auto", mesh=None, part=None,
     With `layer`, w's leaves are stacked over layers and the kernel reads
     that layer in place; a None return leaves the slicing to the caller."""
     y = _dispatch_matmul(x, w, impl, mesh, part, layer)
-    _note_site(stacked=y is not None and layer is not None)
+    note_site(stacked=y is not None and layer is not None)
     return y
 
 
@@ -567,7 +569,7 @@ def dispatch_moe_mm(x, w: dict, sub: str, impl: str = "auto", mesh=None,
     contracts the sharded F axis (row). Expert-parallel (ep>1) meshes fall
     back to the XLA path."""
     y = _dispatch_moe_mm(x, w, sub, impl, mesh, layer)
-    _note_site(stacked=y is not None and layer is not None)
+    note_site(stacked=y is not None and layer is not None)
     return y
 
 

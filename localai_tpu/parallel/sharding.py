@@ -84,7 +84,7 @@ def _attn_specs(cfg: ArchConfig) -> dict[str, P]:
     if cfg.post_norms:  # gemma-2 sandwich norms — replicated like the rest
         specs["post_attn_norm"] = P(None, None)
         specs["post_ffw_norm"] = P(None, None)
-    if cfg.qk_norm:
+    if cfg.qk_norm or cfg.qk_norm_full:
         specs["q_norm"] = P(None, None)
         specs["k_norm"] = P(None, None)
     if cfg.attn_qkv_bias:
