@@ -208,9 +208,14 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
     k_pool, v_pool = rnd((n_pool, page, K, D)), rnd((n_pool, page, K, D))
     perm = jax.random.permutation(next(keys), n_pool - 1)[: B * max_pages] + 1
     table = perm.reshape(B, max_pages).astype(jnp.int32)
-    limits = jnp.array(
-        [(i * 37 + 11) % (max_pages * page - page) + 1 for i in range(B)],
-        jnp.int32).at[0].set(0).at[1].set(max_pages * page - 1)
+
+    def ragged_limits(n):
+        """n slots' live rows: ragged, one idle slot, one full but a row."""
+        return jnp.array(
+            [(i * 37 + 11) % (max_pages * page - page) + 1 for i in range(n)],
+            jnp.int32).at[0].set(0).at[1].set(max_pages * page - 1)
+
+    limits = ragged_limits(B)
 
     def settled(partials):
         """(acc, m, l) -> (acc / l, m, l) with the rows of an idle slot
@@ -227,6 +232,28 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
 
     case("paged_decode", paged("auto"), paged("xla"),
          (rnd((B, H, D)), k_pool, v_pool, table, limits), 5e-3)
+
+    # The decode block's form: 32 rows, the pools still stacked over layers,
+    # the page DMAs read pool[layer, page] with the layer a scalar-prefetch
+    # operand (first and last layer of four). Same arithmetic and oracle as
+    # paged_decode -> the same 5e-3.
+    rows = 32
+    n_pool4 = rows * max_pages + 1
+    k_pool4 = rnd((4, n_pool4, page, K, D))
+    v_pool4 = rnd((4, n_pool4, page, K, D))
+    table4 = (jax.random.permutation(next(keys), n_pool4 - 1) + 1).reshape(
+        rows, max_pages).astype(jnp.int32)
+    limits4 = ragged_limits(rows)
+
+    def paged_stacked(impl):
+        return lambda q, kp, vp, t, lim, first, last: tuple(
+            settled(A.paged_partials(q, Q.StackedLayer(kp, i),
+                                     Q.StackedLayer(vp, i), t, lim, impl=impl))
+            for i in (first, last))
+
+    case("paged_decode_stacked", paged_stacked("auto"), paged_stacked("xla"),
+         (rnd((rows, H, D)), k_pool4, v_pool4, table4, limits4,
+          jnp.int32(0), jnp.int32(3)), 5e-3)
 
     T = s["verify"]
     qpos = limits[:, None] + jnp.arange(T)[None, :]

@@ -68,7 +68,6 @@ from localai_tpu.models.config import ArchConfig
 from localai_tpu.observe import postmortem as opostmortem
 from localai_tpu.observe import trace as otrace
 from localai_tpu.observe.journal import EventJournal
-from localai_tpu.ops.quant_matmul import SiteCounts
 from localai_tpu.ops.sampling import (
     NEG_INF,
     SamplingParams,
@@ -76,6 +75,7 @@ from localai_tpu.ops.sampling import (
     sample_greedy,
     sample_simple,
 )
+from localai_tpu.ops.stacked import SiteCounts
 from localai_tpu.parallel.mesh import MeshPlan, build_mesh
 from localai_tpu.parallel.sharding import cache_shardings, param_shardings, validate_plan
 from localai_tpu.testing import faults
@@ -668,7 +668,7 @@ PROGRAM_NAMES = frozenset({
 def _named_jit(fn, name: str, sites: Optional[SiteCounts] = None, **kw):
     """`jax.jit(fn, **kw)` under a stable program name (compile-time only).
     With `sites`, each trace of the program counts into it the quantized
-    matmul call sites it holds (ops/quant_matmul.SiteCounts)."""
+    matmul and paged-attention call sites it holds (ops/stacked.SiteCounts)."""
     assert name in PROGRAM_NAMES, name
     if sites is not None:
         body = fn
@@ -6156,13 +6156,18 @@ class Engine:
             out["moe_expert_slots_hit"] = float(self.m_moe_slots_hit)
             out["moe_rows_busiest"] = float(self.m_moe_rows_busiest)
             out["moe_rows_mean"] = float(self.m_moe_rows_mean)
+        # Call sites over every program traced so far: the Pallas kernel read
+        # its layer out of the stacked operand (weights; the paged K/V pool),
+        # or the layer was sliced out first (ops/stacked.SiteCounts).
         sites = self.quant_sites.totals()
         if sites["stacked"] or sites["sliced"]:
-            # Quantized layer matmuls over every program traced so far: the
-            # Pallas kernel read its layer out of the stacked weights, or
-            # the layer was sliced out first (ops/quant_matmul.SiteCounts).
             out["quant_matmul_stacked_sites"] = float(sites["stacked"])
             out["quant_matmul_sliced_sites"] = float(sites["sliced"])
+        if sites["paged_attention_stacked"] or sites["paged_attention_sliced"]:
+            out["paged_attention_stacked_sites"] = float(
+                sites["paged_attention_stacked"])
+            out["paged_attention_sliced_sites"] = float(
+                sites["paged_attention_sliced"])
         if self.m_forks or self.m_fork_clone_fallbacks:
             # Tree-batched fork sampling (ISSUE 18): branches admitted by
             # slot fork vs degraded to the N-clone path (fault/pressure).

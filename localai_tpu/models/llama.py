@@ -180,35 +180,44 @@ def _scan_layers(cfg: ArchConfig, params: Params, h, layer_fn, extras=()):
     scan; DeepSeek layouts run the dense-prefix stack then the MoE stack
     (the body's MLP branch keys statically on each stack's param tree), and
     per-layer outputs are re-concatenated to one [L, ...] stack. `extras`
-    are per-layer arrays (cache slices) with a leading L axis."""
+    are per-layer arrays (caches) with a leading axis over ALL L layers:
+    each stack's scan reads them whole at its global layer index, so a
+    two-stack model cuts no `[:kd]` copy out of the K/V pool."""
     L = cfg.num_layers
     kd = cfg.first_k_dense if ("dense_layers" in params) else 0
     if kd == 0:
         return _scan_stack(layer_fn, h, params["layers"], 0, L, extras)
-    head = tuple(e[:kd] for e in extras)
-    tail = tuple(e[kd:] for e in extras)
-    h, out_d = _scan_stack(layer_fn, h, params["dense_layers"], 0, kd, head)
-    h, out_m = _scan_stack(layer_fn, h, params["layers"], kd, L, tail)
+    h, out_d = _scan_stack(layer_fn, h, params["dense_layers"], 0, kd, extras)
+    h, out_m = _scan_stack(layer_fn, h, params["layers"], kd, L, extras)
     out = jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=0), out_d, out_m)
     return h, out
 
 
-def _scan_stack(layer_fn, h, stack, lo: int, hi: int, extras):
-    """`lax.scan` of `layer_fn` over layers lo..hi of one stack. The body
-    takes its own slice of the stacked weights and of each per-layer extra
-    (what scan does with `xs`), so that the two slices carry a name into the
-    compiled program: `layer_weights` and `layer_kv_pool` are what a profile
-    shows as per-layer copies out of the stacked arrays (PERF.md §5).
+def _rides_stacked(a) -> bool:
+    """The scan's one leaf test: an operand its consumer reads out of the
+    stack itself (ops/stacked.py) — a quantized weight leaf, or an extra
+    the caller marked (`quant.StackedLayer(pool)`: the paged K/V pool)."""
+    return isinstance(a, quant.StackedLayer) or quant.is_quantized(a)
 
-    Quantized weights are not sliced here: the body gets a
-    `quant.StackedLayer`, the whole stack plus `i`, and the consumer
-    (`quant.matmul`, `_moe_mm`) either hands both to the Pallas kernel, which
-    then reads layer `i` in place, or slices at its own call site
-    (`quant.layer_slice`) in front of the XLA form."""
+
+def _scan_stack(layer_fn, h, stack, lo: int, hi: int, extras):
+    """`lax.scan` of `layer_fn` over layers lo..hi: `stack` holds just those
+    layers' weights, each extra all the model's. The body takes its own
+    slice of the stacked weights and of each per-layer extra (what scan does
+    with `xs`), so that the two slices carry a name into the compiled
+    program: `layer_weights` and `layer_kv_pool` are what a profile shows as
+    per-layer copies out of the stacked arrays (PERF.md §5).
+
+    What `_rides_stacked` is not sliced here: the body gets a
+    `quant.StackedLayer`, the whole stack plus the index, and the consumer
+    (`quant.matmul`, `_moe_mm`, ops/attention's paged dispatchers) either
+    hands both to its Pallas kernel, which then reads the layer in place, or
+    slices at its own call site (`quant.layer_slice`) in front of the XLA
+    form."""
 
     def index(i):
         def take(a):
-            if quant.is_quantized(a):
+            if _rides_stacked(a):
                 return quant.StackedLayer(a, i)
             return jax.lax.dynamic_index_in_dim(
                 a, i, 0, keepdims=False, allow_negative_indices=False)
@@ -219,15 +228,23 @@ def _scan_stack(layer_fn, h, stack, lo: int, hi: int, extras):
         # `i` is carried beside h, not sliced out of an arange: the index of
         # every slice below is then a loop counter, as scan's own is.
         h, i = carry
+        li = i + lo if lo else i  # the model's layer number
         with jax.named_scope("layer_weights"):
-            lp = jax.tree.map(index(i), stack, is_leaf=quant.is_quantized)
+            lp = jax.tree.map(index(i), stack, is_leaf=_rides_stacked)
         with jax.named_scope("layer_kv_pool"):
-            ex = jax.tree.map(index(i), tuple(extras))
-        h, out = layer_fn(h, (lp, i + lo if lo else i) + ex)
+            ex = jax.tree.map(index(li), tuple(extras), is_leaf=_rides_stacked)
+        h, out = layer_fn(h, (lp, li) + ex)
         return (h, i + 1), out
 
     (h, _), out = jax.lax.scan(body, (h, jnp.int32(0)), None, length=hi - lo)
     return h, out
+
+
+def _paged_pool(cache: KVCache):
+    """A paged K/V pool as scan extras: marked to ride stacked, so the
+    paged-attention kernel reads its layer's pages out of the whole
+    [L, P, page, K, D] pool and no per-layer copy of it is made."""
+    return quant.StackedLayer(cache.k), quant.StackedLayer(cache.v)
 
 
 def _moe_mm(x: jnp.ndarray, w, sub: str, impl: str = "auto",
@@ -235,7 +252,7 @@ def _moe_mm(x: jnp.ndarray, w, sub: str, impl: str = "auto",
     """Per-expert matmul for plain or quantized expert weights. Quantized
     decode-shape calls dispatch to the fused Pallas dequant-matmul kernels
     (ops/quant_matmul, ISSUE 9); the einsum forms below stay the oracle."""
-    if isinstance(w, dict):
+    if quant.is_quantized(w):
         from localai_tpu.ops.quant_matmul import dispatch_moe_mm
 
         y = dispatch_moe_mm(x, dict(w), sub, impl=impl, mesh=mesh,
@@ -367,9 +384,9 @@ def _expert_stack(w):
     """One layer's expert weights [E, ...] for the XLA forms: a quantized
     leaf still stacked over layers is sliced here (and counted as a sliced
     site, like every other layer matmul that does not take the stack)."""
-    if not isinstance(w, dict):
+    if not quant.is_quantized(w):
         return w
-    from localai_tpu.ops.quant_matmul import note_site
+    from localai_tpu.ops.stacked import note_site
 
     note_site(stacked=False)
     return quant.layer_slice(w)
@@ -1175,7 +1192,8 @@ def decode_step_windowed(
         m, rows_e = mlp(lp, x, llora)
         return h + m, (k, v) + rows_e
 
-    extras = (cache.k, cache.v, local_k, local_v)
+    pool = _paged_pool(cache) if ptable is not None else (cache.k, cache.v)
+    extras = pool + (local_k, local_v)
     if lora is not None:
         extras = extras + (lora[0],)
     h, (new_k, new_v, *rows_e) = _scan_layers(cfg, params, h, layer, extras)
@@ -1348,7 +1366,7 @@ def decode_chunk(
         h = h + _mlp_out(cfg, lp, x, ep, mesh, lora=llora)
         return h, (k, v)
 
-    extras = (cache.k, cache.v)
+    extras = _paged_pool(cache) if ptable is not None else (cache.k, cache.v)
     if lora is not None:
         extras = extras + (lora[0],)
     h, (new_k, new_v) = _scan_layers(cfg, params, h, layer, extras)
@@ -1678,7 +1696,7 @@ def prefill_chunk_paged(
     win_dist = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]  # in-chunk t-u
 
     def layer(h, xs):
-        lp, li, kc, vc = xs  # kc/vc: [P, page, K, Hd] pool slices
+        lp, li, kc, vc = xs  # kc/vc: the [L, P, page, K, Hd] pools at layer li
         sliding = _layer_sliding(cfg, li)
         x = rms_norm(h, lp["attn_norm"], cfg.rms_eps)
         inv = _layer_inv_freq(cfg, inv_freq, inv_local, li)
@@ -1734,9 +1752,7 @@ def prefill_chunk_paged(
         h = h + _mlp_out(cfg, lp, x, ep, mesh)
         return h, (k, v)
 
-    h, (new_k, new_v) = _scan_layers(
-        cfg, params, h, layer, (pool.k, pool.v)
-    )
+    h, (new_k, new_v) = _scan_layers(cfg, params, h, layer, _paged_pool(pool))
     pool = write_chunk_to_pool(pool, table, new_k, new_v, positions,
                                kv_scale=kv_scale)
     if not with_logits:

@@ -39,6 +39,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+# The stacked-operand convention (a stack and its layer index) lives with the
+# kernels that read it; `quant.StackedLayer` is how the models spell it.
+from localai_tpu.ops.stacked import StackedLayer, layer_of, layer_slice
+
 Params = dict[str, Any]
 
 # 2D-matmul weights that benefit; embeddings stay bf16 (gather path).
@@ -129,7 +133,7 @@ def matmul(x: jnp.ndarray, w, impl: str = "auto", mesh=None,
     axis sharded + psum at the declared boundary) — pallas_call is opaque
     to GSPMD, so unwrapped it would all-gather the sharded weight per call.
     """
-    if isinstance(w, dict):
+    if is_quantized(w):
         from localai_tpu.ops.quant_matmul import dispatch_matmul
 
         y = dispatch_matmul(x, dict(w), impl=impl, mesh=mesh, part=part,
@@ -144,40 +148,10 @@ def matmul(x: jnp.ndarray, w, impl: str = "auto", mesh=None,
 
 
 def is_quantized(w) -> bool:
+    """A quantized leaf: its dict, or a StackedLayer that holds one."""
+    if isinstance(w, StackedLayer):
+        w = w.stack
     return isinstance(w, dict) and ("q" in w or "gq" in w or "g4" in w)
-
-
-class StackedLayer(dict):
-    """One layer of a quantized weight that is still stacked over layers:
-    the dict's leaves keep their leading [L] axis and `layer` (a traced
-    int32 scalar) says which one is meant. llama._scan_stack hands these to
-    the layer body in place of a sliced dict, so that the Pallas
-    dequant-matmul can read its layer straight out of the stack (a custom
-    call's operand is a buffer: a slice in front of it is a copy of the
-    whole matrix, every layer of every step). Only `matmul` and
-    llama._moe_mm look inside; whoever needs the plain per-layer dict calls
-    `layer_slice`."""
-
-    def __init__(self, stack: dict, layer):
-        super().__init__(stack)
-        self.layer = layer
-
-
-def layer_of(w):
-    """The layer index a StackedLayer carries; None for a plain dict."""
-    return getattr(w, "layer", None)
-
-
-def layer_slice(w: dict) -> dict:
-    """The plain per-layer dict of a StackedLayer (any other dict as it is):
-    the one place a quantized layer is sliced out of its stack. In front of
-    an XLA dot the slice fuses into the operand load."""
-    if layer_of(w) is None:
-        return w
-    with jax.named_scope("layer_weights"):
-        return {k: jax.lax.dynamic_index_in_dim(
-            v, w.layer, 0, keepdims=False, allow_negative_indices=False)
-            for k, v in w.items()}
 
 
 def is_grouped(w) -> bool:

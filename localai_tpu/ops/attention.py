@@ -616,19 +616,26 @@ def _paged_pallas_sharded(kernel_fn, mesh, q, k_pool, v_pool, table, limits,
     (GSPMD-handled) o-projection psum. The kernel body is unchanged — it
     just sees K/tp kv heads. `sliding` is a traced per-layer scalar, so it
     rides as an explicit replicated operand (closure capture of tracers is
-    not valid under shard_map)."""
+    not valid under shard_map). So does the layer index: the pool crosses
+    the boundary stacked over layers (a plain pool as a [1, ...] view at
+    layer 0), its layer axis whole on every shard, and the shard body
+    re-wraps its local stack."""
     from jax.sharding import PartitionSpec as P
 
     from localai_tpu.ops import ptable as _pt
+    from localai_tpu.ops.stacked import StackedLayer, stacks_of
 
+    K = k_pool.shape[2]
+    k_pool, v_pool, layer = stacks_of(k_pool, v_pool, "layer_kv_pool")
     sl_in = sliding if sliding is not None else jnp.zeros((), bool)
     # kv scales ride sharded on their head axis like the pool itself; ones
     # when the pool is unscaled (the kernel's multiply is exact identity).
-    kvs = (jnp.ones((2, k_pool.shape[2]), jnp.float32) if kv_scale is None
+    kvs = (jnp.ones((2, K), jnp.float32) if kv_scale is None
            else kv_scale.astype(jnp.float32))
 
-    def local(qs, kp, vp, tbl, lim, qp, sl, sc):
-        return kernel_fn(qs, kp, vp, tbl, lim, q_pos=qp,
+    def local(qs, kp, vp, tbl, lim, qp, sl, sc, li):
+        return kernel_fn(qs, StackedLayer(kp, li), StackedLayer(vp, li),
+                         tbl, lim, q_pos=qp,
                          sliding=sl if sliding is not None else None,
                          kv_scale=sc)
 
@@ -640,13 +647,31 @@ def _paged_pallas_sharded(kernel_fn, mesh, q, k_pool, v_pool, table, limits,
     out_specs = tuple(
         P(None, "tp", *([None] * (3 if mq else 2))) for _ in range(3)
     )
+    pool_spec = P(None, None, None, "tp", None)  # [L, P, page, K, D]
     fn = _head_shard_map(
         local, mesh,
-        in_specs=(q_spec, P(None, None, "tp", None), P(None, None, "tp", None),
-                  tbl_spec, P(None), qp_spec, P(), P(None, "tp")),
+        in_specs=(q_spec, pool_spec, pool_spec,
+                  tbl_spec, P(None), qp_spec, P(), P(None, "tp"), P()),
         out_specs=out_specs,
     )
-    return fn(q, k_pool, v_pool, table, limits, q_pos, sl_in, kvs)
+    return fn(q, k_pool, v_pool, table, limits, q_pos, sl_in, kvs,
+              jnp.asarray(layer, jnp.int32))
+
+
+def _paged_pools(k_pool, v_pool, pallas: bool):
+    """The pools as a paged dispatcher hands them on, the choice counted as
+    one paged-attention call site (stacked.SiteCounts). The Pallas kernel
+    takes a pool still stacked over layers (stacked.StackedLayer) as it is
+    and reads its layer's pages in place; the XLA walk gets the layer
+    sliced out here, where the slice fuses into the walk's page gather."""
+    from localai_tpu.ops.stacked import layer_slice, note_site, shared_layer
+
+    note_site(pallas and shared_layer(k_pool, v_pool) is not None,
+              kernel="paged_attention")
+    if pallas:
+        return k_pool, v_pool
+    return (layer_slice(k_pool, "layer_kv_pool"),
+            layer_slice(v_pool, "layer_kv_pool"))
 
 
 def paged_partials(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
@@ -661,12 +686,16 @@ def paged_partials(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
     mesh the Pallas kernel runs head-sharded under shard_map (the XLA walk
     needs nothing — its gathers/einsums partition over the kv-head axis by
     GSPMD propagation, no collectives). sink/swin: windowed+sink mask +
-    cold-page skip (ISSUE 14), identical semantics in both backends."""
+    cold-page skip (ISSUE 14), identical semantics in both backends.
+    k_pool/v_pool: one layer's [P, page, K, D] pool, or a StackedLayer of the
+    whole [L, P, page, K, D] pool (`_paged_pools`)."""
     import functools
 
     from localai_tpu.ops.paged_flash import paged_decode_partials, use_pallas
 
-    if use_pallas(impl):
+    pallas = use_pallas(impl)
+    k_pool, v_pool = _paged_pools(k_pool, v_pool, pallas)
+    if pallas:
         interp = jax.default_backend() != "tpu"
         if _tp_degree(mesh) > 1:
             return _paged_pallas_sharded(
@@ -702,7 +731,9 @@ def paged_partials_mq(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
         use_pallas,
     )
 
-    if use_pallas(impl):
+    pallas = use_pallas(impl)
+    k_pool, v_pool = _paged_pools(k_pool, v_pool, pallas)
+    if pallas:
         interp = jax.default_backend() != "tpu"
         if _tp_degree(mesh) > 1:
             T = q.shape[1]
@@ -747,7 +778,9 @@ def paged_prefill_partials(q, k_pool, v_pool, table, limits,
         use_pallas,
     )
 
-    if use_pallas(impl):
+    pallas = use_pallas(impl)
+    k_pool, v_pool = _paged_pools(k_pool, v_pool, pallas)
+    if pallas:
         interp = jax.default_backend() != "tpu"
         if _tp_degree(mesh) > 1:
             T = q.shape[1]
@@ -774,8 +807,8 @@ def paged_prefill_partials(q, k_pool, v_pool, table, limits,
 
 def decode_attention_windowed_paged(
     q: jnp.ndarray,  # [B, H, D]
-    k_pool: jnp.ndarray,  # [P, page, K, D] shared page pool
-    v_pool: jnp.ndarray,
+    k_pool,  # [P, page, K, D] shared page pool, or its StackedLayer
+    v_pool,
     table: jnp.ndarray,  # [B, MP] int32 page ids per slot
     k_local: jnp.ndarray,  # [B, n, K, D] block-local window
     v_local: jnp.ndarray,

@@ -20,7 +20,15 @@ Shapes (matching the XLA reference):
 - q rows     [B, K, QR, Dk] f32, 1/sqrt(D) pre-applied; QR = G query rows
   per kv head (G·T for the multi-query verify chunk).
 - k/v pool   [P, page, K, Dk|Dv] in the cache storage dtype (bf16/fp8 —
-  cast to f32 on read, same contract as every other cache reader).
+  cast to f32 on read, same contract as every other cache reader), or a
+  stacked.StackedLayer of the whole [L, P, page, K, D] pool: what the kernel
+  is handed is ALWAYS the stack plus a layer index (a plain pool rides as a
+  free [1, ...] view at layer 0), the index is one more scalar-prefetch
+  operand, and the page DMAs read `pool[layer, table[b, j]]`. The engine's
+  pool is stacked over layers and a custom call's operand is a buffer, so
+  a per-layer slice in front of the kernel was a copy of the layer's whole
+  pool, every layer of every step, to let the kernel DMA a few pages of it
+  (ISSUE 27; docs/PAGED_ATTENTION.md).
 - table      [B, MP] int32 page ids (scalar-prefetch: the DMA descriptors
   are computed from it before the body runs).
 - limits     [B] int32 — rows with global index >= limits[b] are masked;
@@ -97,10 +105,11 @@ def _ragged_paged_kernel(
         instead of an 8192-wide flat row that blows the SMEM prefetch
         budget.
 
-    Then: limits_ref [B] i32, sliding_ref [1] i32 (both prefetch), and the
-    regular operands q_ref [1, K, QR, Dk] f32, qpos_ref [1, QR, 1] i32,
-    kvs_ref [2, K] f32 SMEM, k_hbm/v_hbm pools (ANY), outputs acc/m/l, VMEM
-    scratch kbuf/vbuf/acc_s/m_s/l_s and the DMA semaphores.
+    Then: limits_ref [B] i32, sliding_ref [1] i32, layer_ref [1] i32 (all
+    prefetch), and the regular operands q_ref [1, K, QR, Dk] f32, qpos_ref
+    [1, QR, 1] i32, kvs_ref [2, K] f32 SMEM, k_hbm/v_hbm pools stacked over
+    layers (ANY), outputs acc/m/l, VMEM scratch kbuf/vbuf/acc_s/m_s/l_s and
+    the DMA semaphores.
 
     sink/swin (windowed+sink decode, docs/LONG_CONTEXT.md): a row is
     attended iff `gpos < sink` or `q_pos - gpos < swin`. The page walk then
@@ -123,12 +132,13 @@ def _ragged_paged_kernel(
     (
         limits_ref,  # scalar-prefetch [B] i32
         sliding_ref,  # scalar-prefetch [1] i32
+        layer_ref,  # scalar-prefetch [1] i32 — which layer of the pools
         q_ref,  # [1, K, QR, Dk] f32 (scale applied)
         qpos_ref,  # [1, QR, 1] i32
         kvs_ref,  # [2, K] f32 SMEM — per-head (k, v) dequant scales (fp8
         # KV); ones when the pool is unscaled (multiply is exact identity)
-        k_hbm,  # [P, page, K, Dk] pool dtype, memory_space=ANY
-        v_hbm,  # [P, page, K, Dv]
+        k_hbm,  # [L, P, page, K, Dk] pool dtype, memory_space=ANY
+        v_hbm,  # [L, P, page, K, Dv]
         acc_ref,  # out [1, K, QR, Dv] f32
         m_ref,  # out [1, K, QR, STAT_LANES] f32
         l_ref,  # out [1, K, QR, STAT_LANES] f32
@@ -143,6 +153,7 @@ def _ragged_paged_kernel(
     b = pl.program_id(0)
     QR = q_ref.shape[2]
     lim = limits_ref[b]
+    layer = layer_ref[0]
     # This slot's own page count (ragged), clamped to the table width so a
     # bad limit can never index the table out of bounds.
     np_live = jnp.minimum((lim + page - 1) // page, table_width)
@@ -172,12 +183,12 @@ def _ragged_paged_kernel(
 
     def dma_k(slot, j):
         return pltpu.make_async_copy(
-            k_hbm.at[tbl(j)], kbuf.at[slot], sem.at[slot, 0]
+            k_hbm.at[layer, tbl(j)], kbuf.at[slot], sem.at[slot, 0]
         )
 
     def dma_v(slot, j):
         return pltpu.make_async_copy(
-            v_hbm.at[tbl(j)], vbuf.at[slot], sem.at[slot, 1]
+            v_hbm.at[layer, tbl(j)], vbuf.at[slot], sem.at[slot, 1]
         )
 
     acc_s[...] = jnp.zeros_like(acc_s)
@@ -250,8 +261,8 @@ def _ragged_paged_kernel(
 def _paged_partials_rows(
     qr: jnp.ndarray,  # [B, K, QR, Dk] f32, scale applied
     qpos_rows: jnp.ndarray,  # [B, QR] i32
-    k_pool: jnp.ndarray,  # [P, page, K, Dk]
-    v_pool: jnp.ndarray,  # [P, page, K, Dv]
+    k_pool,  # [P, page, K, Dk], or a StackedLayer of [L, P, page, K, Dk]
+    v_pool,  # [P, page, K, Dv], likewise
     table,  # [B, MP] i32, or hierarchical (l1 [B, ML1], l0 [NTP, SPAN])
     limits: jnp.ndarray,  # [B] i32
     softcap: float,
@@ -266,10 +277,12 @@ def _paged_partials_rows(
     from jax.experimental.pallas import tpu as pltpu
 
     from localai_tpu.ops import ptable as _pt
+    from localai_tpu.ops.stacked import stacks_of
 
     B, K, QR, Dk = qr.shape
-    page = k_pool.shape[1]
-    Dv = v_pool.shape[3]
+    k_pool, v_pool, layer = stacks_of(k_pool, v_pool, "layer_kv_pool")
+    page = k_pool.shape[2]
+    Dv = v_pool.shape[4]
     sl_arr = jnp.asarray(
         sliding if sliding is not None else False
     ).reshape(1).astype(jnp.int32)
@@ -290,7 +303,7 @@ def _paged_partials_rows(
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(tbl_args) + 2,
+            num_scalar_prefetch=len(tbl_args) + 3,
             grid=(B,),
             in_specs=[
                 pl.BlockSpec((1, K, QR, Dk), lambda b, *_: (b, 0, 0, 0)),
@@ -322,6 +335,7 @@ def _paged_partials_rows(
         name="paged_attention",
     )(
         *tbl_args, limits.astype(jnp.int32), sl_arr,
+        jnp.asarray(layer, jnp.int32).reshape(1),
         qr, qpos_rows.astype(jnp.int32)[..., None], kvs, k_pool, v_pool,
     )
     return acc, m[..., :1], l[..., :1]
@@ -329,8 +343,8 @@ def _paged_partials_rows(
 
 def paged_decode_partials(
     q: jnp.ndarray,  # [B, H, D]
-    k_pool: jnp.ndarray,  # [P, page, K, Dk]
-    v_pool: jnp.ndarray,  # [P, page, K, Dv]
+    k_pool,  # [P, page, K, Dk], or a StackedLayer of [L, P, page, K, Dk]
+    v_pool,  # [P, page, K, Dv], likewise
     table: jnp.ndarray,  # [B, MP] int32
     limits: jnp.ndarray,  # [B] int32
     softcap: float = 0.0,
@@ -363,8 +377,8 @@ def paged_decode_partials(
 
 def paged_decode_partials_mq(
     q: jnp.ndarray,  # [B, T, H, D]
-    k_pool: jnp.ndarray,
-    v_pool: jnp.ndarray,
+    k_pool,  # a pool or its StackedLayer, as in paged_decode_partials
+    v_pool,
     table: jnp.ndarray,
     limits: jnp.ndarray,
     softcap: float = 0.0,
@@ -419,8 +433,8 @@ PREFILL_MAX_QROWS = 512
 
 def paged_prefill_partials_mq(
     q: jnp.ndarray,  # [B, T, H, D] — T = prefill-chunk tokens
-    k_pool: jnp.ndarray,
-    v_pool: jnp.ndarray,
+    k_pool,  # a pool or its StackedLayer, as in paged_decode_partials
+    v_pool,
     table: jnp.ndarray,
     limits: jnp.ndarray,  # [B] — rows already resident (the chunk's offset)
     softcap: float = 0.0,

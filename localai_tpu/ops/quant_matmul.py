@@ -27,19 +27,20 @@ Forms served (matching models/quant.py representations):
   _moe_dense einsum shapes (shared-x and per-expert-x)
 - unembed        {"q": [V, D] i8, "s": [V, 1] f32} used transposed (h @ qᵀ·s)
 
-Who slices what (ISSUE 25). A layer's weights live stacked over layers
-([L, ...] leaves), and a pallas_call's operand has to be a buffer: a slice in
-front of it is a copy of the whole matrix, every layer of every step (it was
-a third of the int8 decode step). So llama._scan_stack does not slice
-quantized leaves; it hands the layer body a quant.StackedLayer (the stack
-and the layer index), and the dispatchers here take `layer=`: engaged, the
-kernel gets the stack with every leading axis merged into one block axis
-and the index as a scalar-prefetch operand, and its BlockSpec index maps
-read block layer·E + e. Same blocks, grid order and arithmetic as on a
-slice, so the result is bit-identical. Not engaged (prefill-scale rows,
-impl xla, CPU auto, a shape _shardable refuses) the dispatcher returns None
-and the caller slices at its own call site (quant.layer_slice) in front of
-the XLA form. SiteCounts tallies the choice per traced program. The
+Who slices what (ISSUE 25; the convention is ops/stacked.py). A layer's
+weights live stacked over layers ([L, ...] leaves), and a pallas_call's
+operand has to be a buffer: a slice in front of it is a copy of the whole
+matrix, every layer of every step (it was a third of the int8 decode step).
+So llama._scan_stack does not slice quantized leaves; it hands the layer
+body a StackedLayer (the stack and the layer index), and the dispatchers
+here take `layer=`: engaged, the kernel gets the stack with every leading
+axis merged into one block axis and the index as a scalar-prefetch operand,
+and its BlockSpec index maps read block layer·E + e. Same blocks, grid order
+and arithmetic as on a slice, so the result is bit-identical. Not engaged
+(prefill-scale rows, impl xla, CPU auto, a shape _shardable refuses) the
+dispatcher returns None and the caller slices at its own call site
+(layer_slice) in front of the XLA form. stacked.SiteCounts tallies the
+choice per traced program. The
 grouped forms' [L, G, 1, out] scales and zeros are re-laid to [L, G, out]
 for the kernel, which XLA hoists out of the layer loop (one pass over them
 per program run, held as a temporary; PERF.md §7).
@@ -62,14 +63,13 @@ it, exactly like ops/paged_flash vs the XLA page walk).
 
 from __future__ import annotations
 
-import contextlib
-import contextvars
 import functools
 import os
-import threading
 
 import jax
 import jax.numpy as jnp
+
+from localai_tpu.ops.stacked import note_site
 
 # The ONLY function here allowed to issue cross-chip collectives: the
 # row-parallel shard_map closure psums its partial products over "tp" —
@@ -491,49 +491,6 @@ def _shardable(x, w: dict, part: str, tp: int, lead: int = 0) -> bool:
         return leaf.shape[out_ax] % tp == 0
     return (x.shape[-1] % tp == 0
             and leaf.shape[lead] % tp == 0)
-
-
-class SiteCounts:
-    """How many quantized layer-matmul call sites a program's trace held,
-    by what the site handed on: "stacked" (the Pallas kernel took the whole
-    layer stack and the index) or "sliced" (the layer was sliced out first,
-    for the XLA form or an unstacked kernel call). The choice is static, so
-    it is counted where it is made, once per trace. An engine owns one and
-    traces its programs under `tracing(<program>)`."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self.by_program: dict[str, dict[str, int]] = {}
-
-    @contextlib.contextmanager
-    def tracing(self, program: str):
-        tally = {"traces": 1, "stacked": 0, "sliced": 0}
-        token = _TALLY.set(tally)
-        try:
-            yield
-        finally:
-            _TALLY.reset(token)
-            with self._lock:
-                have = self.by_program.setdefault(program, dict.fromkeys(tally, 0))
-                for k, v in tally.items():
-                    have[k] += v
-
-    def totals(self) -> dict[str, int]:
-        with self._lock:
-            progs = list(self.by_program.values())
-        return {k: sum(p[k] for p in progs) for k in ("stacked", "sliced")}
-
-
-_TALLY: contextvars.ContextVar = contextvars.ContextVar(
-    "quant_matmul_site_tally", default=None)
-
-
-def note_site(stacked: bool) -> None:
-    """Count one quantized layer-matmul call site of the program being
-    traced (no-op outside `SiteCounts.tracing`)."""
-    tally = _TALLY.get()
-    if tally is not None:
-        tally["stacked" if stacked else "sliced"] += 1
 
 
 def dispatch_matmul(x, w: dict, impl: str = "auto", mesh=None, part=None,

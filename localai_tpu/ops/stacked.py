@@ -1,0 +1,162 @@
+"""A stacked operand and its layer index: one convention for every operand a
+Pallas kernel reads out of a per-layer stack (ROADMAP D11).
+
+What a layer reads lives stacked over layers ([L, ...]: the weights, the
+paged K/V pool), and a pallas_call's operand has to be a buffer: a slice in
+front of the custom call is a copy of that layer's whole operand, every
+layer of every step (the int8 weights were a third of the decode step,
+ISSUE 25; the K/V pool a quarter to a third, ISSUE 27). So
+llama._scan_stack does not slice such an operand. It hands the layer body a
+StackedLayer, the whole stack and the layer index, and the consumer either
+
+- gives both to its kernel, which takes the index as a scalar-prefetch
+  operand and reads its layer in place (ops/quant_matmul `_qmm_call`,
+  ops/paged_flash `_paged_partials_rows`), or
+- slices at its own call site (`layer_slice`) in front of the XLA form,
+  where the slice fuses into the dot's or the gather's operand.
+
+The choice is static per call site; `SiteCounts` tallies it per traced
+program, by kernel.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import contextvars
+import threading
+
+import jax
+
+
+class StackedLayer:
+    """One layer of an operand that is still stacked over layers. `stack`
+    keeps its leading [L] axis: a dict of quantized leaves, or one array
+    (the paged K/V pool). `layer` is the (traced int32) index meant, or None
+    on an operand a caller only marks for llama._scan_stack to bind.
+
+    A dict stack reads as the dict it holds (keys, `in`, `[]`, `dict(w)`:
+    the leaves keep their [L] axis); an array stack reads as ONE layer of it
+    (`shape`, `dtype`, `ndim`), so code that only asks a pool for its page
+    size or head count takes either."""
+
+    __slots__ = ("stack", "layer")
+
+    def __init__(self, stack, layer=None):
+        self.stack = stack.stack if isinstance(stack, StackedLayer) else stack
+        self.layer = layer
+
+    def keys(self):
+        return self.stack.keys()
+
+    def __getitem__(self, key):
+        return self.stack[key]
+
+    def __contains__(self, key):
+        return isinstance(self.stack, dict) and key in self.stack
+
+    @property
+    def shape(self):
+        return self.stack.shape[1:]
+
+    @property
+    def ndim(self):
+        return self.stack.ndim - 1
+
+    @property
+    def dtype(self):
+        return self.stack.dtype
+
+
+def layer_of(w):
+    """The layer index a StackedLayer carries; None for anything else."""
+    return w.layer if isinstance(w, StackedLayer) else None
+
+
+def layer_slice(w, scope: str = "layer_weights"):
+    """The plain per-layer operand of a StackedLayer (anything else as it
+    is): the one place a layer is sliced out of its stack, under a named
+    scope a profile can show. In front of an XLA dot or gather the slice
+    fuses into the operand load."""
+    if not isinstance(w, StackedLayer):
+        return w
+
+    def take(a):
+        return jax.lax.dynamic_index_in_dim(
+            a, w.layer, 0, keepdims=False, allow_negative_indices=False)
+
+    with jax.named_scope(scope):
+        if isinstance(w.stack, dict):
+            return {k: take(v) for k, v in w.stack.items()}
+        return take(w.stack)
+
+
+def shared_layer(a, b):
+    """The layer index two StackedLayers have in common (the same traced
+    value), else None."""
+    layer = layer_of(a)
+    return layer if layer is not None and layer_of(b) is layer else None
+
+
+def stacks_of(a, b, scope: str):
+    """(a's stack, b's stack, layer) for a kernel that reads the two with
+    one index: the stacks of two StackedLayers of the same layer as they
+    are; anything else as a free [1, ...] view of the per-layer operand
+    (sliced under `scope` if need be) and layer 0."""
+    layer = shared_layer(a, b)
+    if layer is not None:
+        return a.stack, b.stack, layer
+    return layer_slice(a, scope)[None], layer_slice(b, scope)[None], 0
+
+
+# What a site can hand its kernel, per kernel family: the quantized layer
+# matmuls keep the short names they were first counted under.
+_KEYS = {
+    "quant_matmul": ("stacked", "sliced"),
+    "paged_attention": ("paged_attention_stacked", "paged_attention_sliced"),
+}
+_ALL_KEYS = tuple(k for ks in _KEYS.values() for k in ks)
+
+
+class SiteCounts:
+    """How many call sites of a stack-reading kernel a program's trace held,
+    by what the site handed on: "stacked" (the Pallas kernel took the whole
+    stack and the layer index) or "sliced" (the layer was sliced out first,
+    for the XLA form or an unstacked kernel call). Quantized layer matmuls
+    count under `stacked` / `sliced`, paged attention under
+    `paged_attention_stacked` / `paged_attention_sliced`. The choice is
+    static, so it is counted where it is made, once per trace. An engine
+    owns one and traces its programs under `tracing(<program>)`."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.by_program: dict[str, dict[str, int]] = {}
+
+    @contextlib.contextmanager
+    def tracing(self, program: str):
+        tally = {"traces": 1, **dict.fromkeys(_ALL_KEYS, 0)}
+        token = _TALLY.set(tally)
+        try:
+            yield
+        finally:
+            _TALLY.reset(token)
+            with self._lock:
+                have = self.by_program.setdefault(program, dict.fromkeys(tally, 0))
+                for k, v in tally.items():
+                    have[k] += v
+
+    def totals(self) -> dict[str, int]:
+        with self._lock:
+            progs = list(self.by_program.values())
+        return {k: sum(p[k] for p in progs) for k in _ALL_KEYS}
+
+
+_TALLY: contextvars.ContextVar = contextvars.ContextVar(
+    "stacked_site_tally", default=None)
+
+
+def note_site(stacked: bool, kernel: str = "quant_matmul") -> None:
+    """Count one call site of `kernel` in the program being traced (no-op
+    outside `SiteCounts.tracing`)."""
+    tally = _TALLY.get()
+    if tally is not None:
+        tally[_KEYS[kernel][0 if stacked else 1]] += 1

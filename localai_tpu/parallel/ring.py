@@ -239,7 +239,12 @@ def ring_chunk_paged_attention(
     while the in-chunk K/V rotates around the ring. Composes with tp>1 —
     heads additionally shard over "tp" like every other kernel path."""
     from localai_tpu.ops import ptable as _pt
+    from localai_tpu.ops.stacked import layer_slice
 
+    # The walk inside the shard_map is XLA's: a pool still stacked over
+    # layers (stacked.StackedLayer) is sliced at this site, in front of it.
+    k_pool = layer_slice(k_pool, "layer_kv_pool")
+    v_pool = layer_slice(v_pool, "layer_kv_pool")
     n = mesh.shape[axis]
     tp = mesh.shape.get("tp", 1) > 1
     hspec = "tp" if tp else None

@@ -188,6 +188,10 @@ def test_wide_rows_run_top_k_and_match_all_experts(form, monkeypatch):
 # --------------------------------------------------------------------------- #
 
 
+# dense-cache programs hold no paged-attention site (ops/stacked.SiteCounts)
+_NO_PAGED_SITES = {"paged_attention_stacked": 0, "paged_attention_sliced": 0}
+
+
 def _pallas_calls(jaxpr, name):
     found = []
     for eqn in jaxpr.eqns:
@@ -216,7 +220,7 @@ def test_moe_decode_step_reads_experts_out_of_the_stack():
     """No int8 operand of a kernel is a per-layer copy: the four attention
     projections take [L, in, out], the three expert matmuls [L·E, in, out]
     (block layer·E + e), and the site counter saw 4 + 3 stacked."""
-    from localai_tpu.ops.quant_matmul import SiteCounts
+    from localai_tpu.ops.stacked import SiteCounts
 
     cfg, fn, args = _moe_decode_step(2)
     sites = SiteCounts()
@@ -234,7 +238,7 @@ def test_moe_decode_step_reads_experts_out_of_the_stack():
               for v in eqn.outvars if v.aval.dtype == jnp.int8]
     assert sliced == []
     assert sites.by_program == {
-        "decode_block": {"traces": 1, "stacked": 7, "sliced": 0}}
+        "decode_block": {"traces": 1, "stacked": 7, "sliced": 0, **_NO_PAGED_SITES}}
 
 
 def _all_eqns(jaxpr):
@@ -245,14 +249,15 @@ def _all_eqns(jaxpr):
 
 
 def test_moe_step_above_the_row_limit_counts_seven_sliced_sites():
-    from localai_tpu.ops.quant_matmul import QUANT_PALLAS_MAX_ROWS, SiteCounts
+    from localai_tpu.ops.quant_matmul import QUANT_PALLAS_MAX_ROWS
+    from localai_tpu.ops.stacked import SiteCounts
 
     _, fn, args = _moe_decode_step(QUANT_PALLAS_MAX_ROWS + 1)
     sites = SiteCounts()
     with sites.tracing("admit"):
         jaxpr = jax.make_jaxpr(fn)(*args)
     assert not _pallas_calls(jaxpr.jaxpr, "int8_matmul")
-    assert sites.by_program["admit"] == {"traces": 1, "stacked": 0, "sliced": 7}
+    assert sites.by_program["admit"] == {"traces": 1, "stacked": 0, "sliced": 7, **_NO_PAGED_SITES}
 
 
 # --------------------------------------------------------------------------- #

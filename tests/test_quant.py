@@ -495,6 +495,10 @@ def test_stacked_kernel_under_scan_with_a_traced_index(form):
                                rtol=2e-4, atol=2e-4)
 
 
+# dense-cache programs hold no paged-attention site (ops/stacked.SiteCounts)
+_NO_PAGED_SITES = {"paged_attention_stacked": 0, "paged_attention_sliced": 0}
+
+
 def _pallas_calls(jaxpr, name):
     """Every pallas_call equation named `name`, through all sub-jaxprs."""
     found = []
@@ -529,7 +533,7 @@ def test_decode_step_hands_the_kernels_the_stack_and_counts_it():
     """In the decode step's jaxpr every int8_matmul takes a weight whose
     leading dimension is the layer count, none a [1, in, out] copy, and the
     site counter saw the same seven."""
-    from localai_tpu.ops.quant_matmul import SiteCounts
+    from localai_tpu.ops.stacked import SiteCounts
 
     cfg, fn, args = _int8_decode_step(2, "pallas")
     sites = SiteCounts()
@@ -542,14 +546,77 @@ def test_decode_step_hands_the_kernels_the_stack_and_counts_it():
         assert [w.ndim for w in weights] == [3]
         assert weights[0].shape[0] == cfg.num_layers > 1
     assert sites.by_program == {
-        "decode_block": {"traces": 1, "stacked": 7, "sliced": 0}}
-    assert sites.totals() == {"stacked": 7, "sliced": 0}
+        "decode_block": {"traces": 1, "stacked": 7, "sliced": 0, **_NO_PAGED_SITES}}
+    assert sites.totals() == {"stacked": 7, "sliced": 0, **_NO_PAGED_SITES}
+
+
+def _eqns(jaxpr):
+    """Every equation, through all sub-jaxprs."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _paged_decode_step(paged_impl):
+    """The int8 decode step over a PAGED pool [L, P, page, K, D]."""
+    from localai_tpu.models import llama
+
+    cfg, _, (params, tok, _, _, local, _, step) = _int8_decode_step(2, "pallas")
+    kv = (cfg.num_kv_heads, cfg.head_dim_)
+    kk, kv_key = jax.random.split(jax.random.key(5))
+    pool = llama.KVCache(
+        k=jax.random.normal(kk, (cfg.num_layers, 7, 8, *kv), jnp.bfloat16),
+        v=jax.random.normal(kv_key, (cfg.num_layers, 7, 8, *kv), jnp.bfloat16))
+    table = jnp.array([[0, 1, 2], [3, 4, 5]], jnp.int32)
+    pos = jnp.array([19, 9], jnp.int32)
+    fn = lambda p, c, lk, lv: llama.decode_step_windowed(  # noqa: E731
+        cfg, p, tok, pos, c, lk, lv, step, ptable=table, paged_impl=paged_impl)
+    return cfg, fn, (params, pool, local, local)
+
+
+def test_paged_decode_step_hands_the_kernel_the_pool_and_counts_it():
+    """In the PAGED decode step's jaxpr the one paged_attention call takes
+    both pools whole, [L, P, page, K, D]; nothing slices a layer's pool out
+    in front of it; the site counter saw 1 stacked, 0 sliced beside the
+    seven matmuls. With the XLA walk it saw 0 / 1, the slice is there (at
+    the walk's own site) and the numbers agree."""
+    from localai_tpu.ops.stacked import SiteCounts
+
+    seen = {}
+    for impl in ("pallas", "xla"):
+        cfg, fn, args = _paged_decode_step(impl)
+        sites = SiteCounts()
+        with sites.tracing("decode_block"):
+            jaxpr = jax.make_jaxpr(fn)(*args)
+        pool_shape = args[1].k.shape
+        layer_pools = [e for e in _eqns(jaxpr.jaxpr)
+                       if e.primitive.name != "pallas_call" and any(
+                           v.aval.shape == pool_shape[1:] for v in e.outvars)]
+        seen[impl] = (_pallas_calls(jaxpr.jaxpr, "paged_attention"),
+                      layer_pools, sites.by_program["decode_block"],
+                      jax.jit(fn)(*args)[0])
+    calls, layer_pools, tally, got = seen["pallas"]
+    assert len(calls) == 1  # once, in the layer scan
+    pools = [v.aval for v in calls[0].invars if v.aval.ndim == 5]
+    assert [p.shape for p in pools] == [pool_shape] * 2
+    assert pool_shape[0] == cfg.num_layers > 1
+    assert not layer_pools
+    assert tally == {"traces": 1, "stacked": 7, "sliced": 0,
+                     "paged_attention_stacked": 1, "paged_attention_sliced": 0}
+    calls, layer_pools, tally, want = seen["xla"]
+    assert not calls and len(layer_pools) >= 2  # K and V, sliced at the walk
+    assert tally == {"traces": 1, "stacked": 7, "sliced": 0,
+                     "paged_attention_stacked": 0, "paged_attention_sliced": 1}
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-2, atol=2e-2)
 
 
 def test_decode_step_above_the_row_limit_slices_at_the_use_site():
     """Rows above QUANT_PALLAS_MAX_ROWS: no kernel in the jaxpr, every site
     counts as sliced, and the numbers are the XLA path's."""
-    from localai_tpu.ops.quant_matmul import QUANT_PALLAS_MAX_ROWS, SiteCounts
+    from localai_tpu.ops.quant_matmul import QUANT_PALLAS_MAX_ROWS
+    from localai_tpu.ops.stacked import SiteCounts
 
     B = QUANT_PALLAS_MAX_ROWS + 1
     _, fn, args = _int8_decode_step(B, "pallas")
@@ -558,7 +625,7 @@ def test_decode_step_above_the_row_limit_slices_at_the_use_site():
         jaxpr = jax.make_jaxpr(fn)(*args)
     assert not _pallas_calls(jaxpr.jaxpr, "int8_matmul")
     assert not _pallas_calls(jaxpr.jaxpr, "int8_unembed")
-    assert sites.by_program["admit"] == {"traces": 1, "stacked": 0, "sliced": 7}
+    assert sites.by_program["admit"] == {"traces": 1, "stacked": 0, "sliced": 7, **_NO_PAGED_SITES}
     _, fn_xla, _ = _int8_decode_step(B, "xla")
     got, want = jax.jit(fn)(*args)[0], jax.jit(fn_xla)(*args)[0]
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
