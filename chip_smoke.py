@@ -476,8 +476,7 @@ class Server:
 def stream_chat(port: int, model: str, prompt: str, max_tokens: int,
                 n: int = 1, timeout: float = 900.0) -> dict:
     """One streamed /v1/chat/completions. Raises unless the response is 200,
-    ends in [DONE] and carries one content chunk per completion token (the
-    contract bench.py's HTTP row enforces)."""
+    ends in [DONE] and carries one content chunk per completion token."""
     body = {"model": model, "stream": True, "ignore_eos": True, "n": n,
             "max_tokens": max_tokens, "temperature": 0.0,
             "messages": [{"role": "user", "content": prompt}]}

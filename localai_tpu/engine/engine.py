@@ -1222,7 +1222,7 @@ class Engine:
         self.m_spec_drafted = 0
         self.m_spec_draft_len = 0.0
         # Draft-length histogram {chosen length: dispatch count} over
-        # active slots (bench.py reports it; not a /metrics scalar).
+        # active slots (not a /metrics scalar).
         self.m_spec_dlen_hist: dict[int, int] = {}
 
         # Per-head (k, v) dequant scales for the SCALED fp8 paged pool

@@ -2,7 +2,7 @@
 (bge / sentence-transformers class) and cross-encoder reranking.
 
 Reference: backend/python/transformers/backend.py SentenceTransformer branch
-(BASELINE.json names bge-* embedding models) and the rerankers backend
+(bge-* embedding models) and the rerankers backend
 (cross-encoder scoring). TPU shape: stacked-layer pytree + lax.scan,
 post-LN blocks per original BERT, masked mean / CLS pooling, L2-normalized
 outputs; an optional classification head turns the same stack into a

@@ -205,8 +205,7 @@ class ArchConfig:
 
 
 # ---------------------------------------------------------------------------
-# Presets. Shapes match the public model cards for the configs listed in
-# /root/repo/BASELINE.json; weights are loaded from local safetensors when
+# Presets. Shapes match the public model cards; weights are loaded from local safetensors when
 # available or randomly initialized for benchmarking.
 # ---------------------------------------------------------------------------
 
@@ -397,7 +396,7 @@ PRESETS: dict[str, ArchConfig] = {
         v_head_dim=128,
     ),
     "deepseek-r1": ArchConfig(
-        # DeepSeek-V3/R1 (BASELINE.json configs[4]): 61 layers (3 dense),
+        # DeepSeek-V3/R1 (the round-1 flagship target): 61 layers (3 dense),
         # 256 routed experts top-8 in 8 groups, sigmoid router with
         # correction bias, MLA with q-lora. Serving shapes for the EP mesh
         # dryrun and decode benchmarks; full weights need a multi-host pod.

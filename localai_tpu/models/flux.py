@@ -3,7 +3,7 @@
 Reference: the diffusers backend special-cases the Flux family —
 /root/reference/backend/python/diffusers/backend.py:36 (FLUX import),
 :218-224 (FluxPipeline / FluxTransformer2DModel routing) and :594-603
-(the fp8-quantized transformer path). BASELINE.json's image config names
+(the fp8-quantized transformer path). The round-1 target list names
 Flux.1-dev alongside SDXL.
 
 TPU-native shape: the whole sampler is one `lax.scan` over flow-matching

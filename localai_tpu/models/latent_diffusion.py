@@ -47,6 +47,26 @@ SUPPORTED_SCHEDULERS = frozenset(
 )
 
 
+def resolve_scheduler(name: str) -> tuple[str, bool]:
+    """A serving scheduler name → (base scheduler, Karras sigma spacing).
+    `k_<base>` and `<base>_karras` are two spellings of one thing; the
+    timestep family (ddim, pndm, unipc) has no Karras variant."""
+    base, karras = name, False
+    if base.startswith("k_"):
+        base, karras = base[2:], True
+    if base.endswith("_karras"):
+        base, karras = base[: -len("_karras")], True
+    if (base not in K_SCHEDULERS + T_SCHEDULERS
+            or (karras and base in T_SCHEDULERS)):
+        raise ValueError(
+            f"unknown scheduler {base!r} (supported: "
+            + ", ".join(T_SCHEDULERS + K_SCHEDULERS)
+            + ", plus _karras/k_ variants of "
+            + ", ".join(K_SCHEDULERS) + ")"
+        )
+    return base, karras
+
+
 # --------------------------------------------------------------------------- #
 # Configs (subset of the diffusers configs we consume)
 # --------------------------------------------------------------------------- #
@@ -825,25 +845,8 @@ def generate(
         noised = jnp.sqrt(acp_prev) * known_latent + jnp.sqrt(1.0 - acp_prev) * noise
         return known_mask * xc + (1.0 - known_mask) * noised.astype(xc.dtype)
 
-    k_schedulers, t_schedulers = K_SCHEDULERS, T_SCHEDULERS
-    karras = False
-    if scheduler.startswith("k_"):
-        karras = True
-        scheduler = scheduler[2:]
-    if scheduler.endswith("_karras"):
-        karras = True
-        scheduler = scheduler[: -len("_karras")]
-    base_sched = scheduler
-    if (base_sched not in k_schedulers + t_schedulers
-            or (karras and base_sched in t_schedulers)):
-        raise ValueError(
-            f"unknown scheduler {scheduler!r} (supported: "
-            + ", ".join(t_schedulers + k_schedulers)
-            + ", plus _karras/k_ variants of "
-            + ", ".join(k_schedulers) + ")"
-        )
-    scheduler = base_sched
-    if scheduler in k_schedulers:
+    scheduler, karras = resolve_scheduler(scheduler)
+    if scheduler in K_SCHEDULERS:
         sigmas_np, ts_np = k_schedule(cfg, steps, karras)
         sigmas = jnp.asarray(sigmas_np)
         ts = jnp.asarray(ts_np)

@@ -20,6 +20,7 @@ loader), not here.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple
 
 import jax
@@ -29,18 +30,27 @@ from localai_tpu.models import quant
 from localai_tpu.models.config import ArchConfig
 from localai_tpu.models.quant import matmul, unembed_matmul
 from localai_tpu.ops.attention import (
+    _merge_partials_mq,
     decode_attention,  # noqa: F401 — public, used by tests/benchmarks
     decode_attention_appended,
+    decode_attention_appended_sp,
     decode_attention_windowed,
+    decode_attention_windowed_paged,
+    decode_attention_windowed_sp,
+    paged_partials_mq,
+    paged_prefill_partials,
     prefill_attention,
+    prefix_window_attention,
 )
 from localai_tpu.ops.norm import rms_norm
 from localai_tpu.ops.quant_matmul import QUANT_PALLAS_MAX_ROWS
 from localai_tpu.ops.rope import (
     apply_rope,
+    mrope_angles,
     rope_frequencies,
     rope_frequencies_local,
     rope_query_amp,
+    rope_rotate,
 )
 
 Params = dict[str, Any]
@@ -783,6 +793,116 @@ def _unembed(cfg: ArchConfig, params: Params, h: jnp.ndarray,
     return logits
 
 
+def _softcap(cfg: ArchConfig) -> float:
+    """gemma-2's attention softcap; MLA's calls run without (ROADMAP D15)."""
+    return 0.0 if cfg.is_mla else cfg.attn_softcap
+
+
+def _mask_opts(cfg: ArchConfig, sliding, **more):
+    """The mask options of one layer's GQA attention call: gemma-2's softcap
+    and the layer's sliding window, plus what the entry point adds (`mesh`,
+    `sink`/`swin`). MLA passes none of them: its ops run with their defaults
+    (the matrix under `_decoder_layer`; ROADMAP D15)."""
+    if cfg.is_mla:
+        return {}
+    return dict(softcap=_softcap(cfg), window=cfg.sliding_window,
+                sliding=sliding, **more)
+
+
+def _slid(cfg: ArchConfig, sliding, mask, dist):
+    """`mask` cut to the sliding window on the layers that slide: rows at
+    `dist` (query position − row position) >= sliding_window go."""
+    if cfg.sliding_window and sliding is not None:
+        return mask & (~sliding | (dist < cfg.sliding_window))
+    return mask
+
+
+def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
+                   mla_full: bool = False, mrope_ang=None, ep: int = 1,
+                   mesh=None, lora=None, picks=None):
+    """THE decoder layer, the body of every entry point's layer scan: input
+    norm → q/k/v (GQA: `_attn_proj_qkv` + rope; MLA: the full-rank or the
+    absorbed projections, which rotate inside) → `attend` → output projection
+    → post norm → MLP. Returns (h, the rows the layer emits for the cache):
+    (k, v), or MLA's (latent rows, a zero-width v).
+
+    h: [B, D] (one token per slot) or [B, T, D]; `pos` [B] / [B, T] the
+    positions rope rotates at (and MLA's rows are written for).
+    xs = (lp, li, *cache[, la]) as `_scan_stack` hands it on: this layer's
+    weights, its number, the entry point's per-layer cache operands in
+    (k, v) pairs and, with `lora` (the entry point's (stacks, ids)), this
+    layer's slice of the adapter stacks.
+    inv = (global, local | None) rope frequencies; `_layer_inv_freq` and
+    `_layer_sliding` pick this layer's kind.
+
+    `attend(q, k, v, sliding, *cache)` is the ONE thing an entry point
+    supplies: how it attends over its cache operand ⊕ the fresh rows (dense
+    cache ⊕ current row, ⊕ block window, paged pool + table, sp ring,
+    prefix ⊕ tail). Which implementation runs is decided where that closure
+    is built, never here. Under MLA's absorbed form the latent rows ride as
+    both the k and the v operand, fresh and cached alike.
+
+    What each entry point supports, as the code stands (a blank is a hole,
+    not a promise: the entry point lacks the parameter; ROADMAP D15):
+
+    | entry point            | LoRA | rope extra   | sink+window | sp        | expert_rows | MLA form |
+    | ---------------------- | ---- | ------------ | ----------- | --------- | ----------- | -------- |
+    | `_forward_hidden` (1)  | GQA  | m-rope (GQA) |             | ring, GQA |             | full     |
+    | `decode_step`          |      |              |             | GQA       |             | absorbed |
+    | `decode_step_windowed` | GQA  | `rope_delta` (GQA) | GQA (dense, sp, paged) | GQA | yes  | absorbed |
+    | `decode_chunk`         | GQA  |              |             |           |             | absorbed |
+    | `prefill_tail`         |      |              |             |           |             | absorbed |
+    | `prefill_chunk_paged`  |      |              | GQA (paged walk, ring) | ring (`sp_mesh`), GQA | | absorbed |
+
+    (1) `prefill`, `encode`, `sequence_logprob`; only `prefill` passes LoRA
+    and m-rope on. "GQA" = ignored under MLA (sp: refused); softcap and the
+    per-layer sliding window reach every GQA call and no MLA call."""
+    lp, li, *cache = xs
+    if lora is not None:
+        *cache, la = cache
+        lora = None if cfg.is_mla else (la, lora[1])
+    one = h.ndim == 2  # one token per slot: rope and MLA want a [B, 1] axis
+    sliding = _layer_sliding(cfg, li)
+    x = rms_norm(h, lp["attn_norm"], cfg.rms_eps)
+    inv = _layer_inv_freq(cfg, *inv, li)
+    if cfg.is_mla:
+        xm, at = (x[:, None], pos[:, None]) if one else (x, pos)
+        if mla_full:
+            q, k, v, rows = _mla_full_qkv(cfg, lp, xm, at, inv, mesh)
+            attn = attend(q, k, v, sliding, *cache)[..., : cfg.v_head_dim]
+            attn = attn.reshape(*h.shape[:-1], -1)
+        else:
+            q = _mla_absorbed_q(cfg, lp, xm, at, inv, mesh)
+            rows = _mla_rows(cfg, lp, xm, at, inv)
+            if one:
+                q, rows = q[:, 0], rows[:, 0]
+            latent = [c for kc in cache[::2] for c in (kc, kc)]
+            attn = attend(q, rows, rows, sliding, *latent)
+            attn = _mla_unlatent(cfg, lp, attn)
+        emit = (rows, rows[..., :0])
+    else:
+        q, k, v = _attn_proj_qkv(cfg, lp, x, mesh, lora=lora)
+        with jax.named_scope("attention"):
+            if mrope_ang is not None:
+                q, k = rope_rotate(q, mrope_ang), rope_rotate(k, mrope_ang)
+            elif one:
+                q = apply_rope(q[:, None], pos[:, None], inv)[:, 0]
+                k = apply_rope(k[:, None], pos[:, None], inv)[:, 0]
+            else:
+                q, k = apply_rope(q, pos, inv), apply_rope(k, pos, inv)
+            attn = attend(q, k, v, sliding, *cache)
+        attn = attn.reshape(*h.shape[:-1], -1)
+        emit = (k, v)
+    h = h + _attn_out(cfg, lp, attn, mesh, lora=lora)
+    x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
+    return h + _mlp_out(cfg, lp, x, ep, mesh, lora=lora, picks=picks), emit
+
+
+def _rope_inv(cfg: ArchConfig):
+    """(global, local | None) rope frequencies, as `_decoder_layer` takes them."""
+    return rope_frequencies(cfg), rope_frequencies_local(cfg)
+
+
 def _forward_hidden(
     cfg: ArchConfig,
     params: Params,
@@ -821,8 +941,6 @@ def _forward_hidden(
             raise ValueError("mrope positions passed but cfg.mrope_section empty")
         if inv_local is not None:
             raise ValueError("mrope + per-layer local rope is unsupported")
-        from localai_tpu.ops.rope import mrope_angles
-
         mrope_ang = mrope_angles(mrope, inv_freq, tuple(cfg.mrope_section))
 
     h = _embed(cfg, params, tokens)  # [B, S, D]
@@ -836,63 +954,36 @@ def _forward_hidden(
             )
         )(h, embeds, offsets)
 
-    def layer(h, xs):
-        if lora is None:
-            lp, li = xs  # li: layer index (sliding windows alternate by layer)
-            llora = None
-        else:
-            lp, li, la = xs  # la: this layer's adapter-factor slice
-            llora = (la, lora[1])
-        x = rms_norm(h, lp["attn_norm"], cfg.rms_eps)
-        inv = _layer_inv_freq(cfg, inv_freq, inv_local, li)
+    if use_ring:
         if cfg.is_mla:
-            if use_ring:
-                raise NotImplementedError(
-                    "MLA + sequence parallelism is excluded this round "
-                    "(PARITY.md: ring rotation of latent rows needs its own "
-                    "kernel); shard MLA models over tp/ep instead"
-                )
-            q, k, v, rows = _mla_full_qkv(cfg, lp, x, positions, inv, mesh)
-            # Dense path (no `lengths`): the flash kernel tiles head_dim in
-            # 128-lane blocks and MLA's qk width (192) is not a multiple.
-            attn = prefill_attention(q, k, v, length_mask)
-            attn = attn[..., : cfg.v_head_dim]
-            h = h + _attn_out(cfg, lp, attn.reshape(B, S, -1), mesh)
-            x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-            h = h + _mlp_out(cfg, lp, x, ep, mesh)
-            return h, (
-                (rows, rows[..., :0]) if collect_kv else None
+            raise NotImplementedError(
+                "MLA + sequence parallelism is excluded this round "
+                "(PARITY.md: ring rotation of latent rows needs its own "
+                "kernel); shard MLA models over tp/ep instead"
             )
-        q, k, v = _attn_proj_qkv(cfg, lp, x, mesh, lora=llora)
-        if mrope_ang is not None:
-            from localai_tpu.ops.rope import rope_rotate
+        from localai_tpu.parallel.ring import ring_prefill_attention
 
-            q = rope_rotate(q, mrope_ang)
-            k = rope_rotate(k, mrope_ang)
-        else:
-            q = apply_rope(q, positions, inv)
-            k = apply_rope(k, positions, inv)
-        if use_ring:
-            from localai_tpu.parallel.ring import ring_prefill_attention
+        def attend(q, k, v, sliding):
+            return ring_prefill_attention(q, k, v, lengths, mesh,
+                                          **_mask_opts(cfg, sliding))
+    else:
+        # MLA takes the dense path (no `lengths`): the flash kernel tiles
+        # head_dim in 128-lane blocks and MLA's qk width (192) is not a
+        # multiple.
+        def attend(q, k, v, sliding):
+            return prefill_attention(
+                q, k, v, length_mask, None if cfg.is_mla else lengths,
+                mesh=mesh, **_mask_opts(cfg, sliding))
 
-            attn = ring_prefill_attention(
-                q, k, v, lengths, mesh,
-                softcap=cfg.attn_softcap, window=cfg.sliding_window,
-                sliding=_layer_sliding(cfg, li),
-            )
-        else:
-            attn = prefill_attention(
-                q, k, v, length_mask, lengths,
-                softcap=cfg.attn_softcap, window=cfg.sliding_window,
-                sliding=_layer_sliding(cfg, li), mesh=mesh,
-            )
-        h = h + _attn_out(cfg, lp, attn.reshape(B, S, -1), mesh, lora=llora)
-        x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-        h = h + _mlp_out(cfg, lp, x, ep, mesh, lora=llora)
-        return h, ((k, v) if collect_kv else None)
+    def body(h, xs):  # lora: la, this layer's adapter factors, rides last
+        h, kv = _decoder_layer(
+            cfg, h, xs, pos=positions, inv=(inv_freq, inv_local),
+            attend=attend, mla_full=True, mrope_ang=mrope_ang, ep=ep,
+            mesh=mesh, lora=lora)
+        return h, (kv if collect_kv else None)
 
     extras = () if lora is None else (lora[0],)
-    h, kv = _scan_layers(cfg, params, h, layer, extras)
+    h, kv = _scan_layers(cfg, params, h, body, extras)
     h = rms_norm(h, params["final_norm"], cfg.rms_eps)
     return h, length_mask, kv
 
@@ -989,50 +1080,23 @@ def decode_step(
     """
     B = tokens.shape[0]
     use_sp = mesh is not None and mesh.shape.get("sp", 1) > 1
-    inv_freq = rope_frequencies(cfg)
-    inv_local = rope_frequencies_local(cfg)
+    if use_sp and cfg.is_mla:
+        raise NotImplementedError("MLA + sp is excluded (PARITY.md)")
     h = _embed(cfg, params, tokens)  # [B, D]
     batch_idx = jnp.arange(B)
 
-    def layer(h, xs):
-        lp, li, kc, vc = xs
-        x = rms_norm(h, lp["attn_norm"], cfg.rms_eps)
-        inv = _layer_inv_freq(cfg, inv_freq, inv_local, li)
-        if cfg.is_mla:
-            if use_sp:
-                raise NotImplementedError("MLA + sp is excluded (PARITY.md)")
-            x1 = x[:, None]  # [B, 1, D]
-            q_eff = _mla_absorbed_q(cfg, lp, x1, positions[:, None], inv, mesh)[:, 0]
-            rows = _mla_rows(cfg, lp, x1, positions[:, None], inv)[:, 0]  # [B,1,r+rot]
-            # The latent rides as BOTH k and v operands; [..., :r] of the
-            # output is probs·c_kv (see the MLA section header).
-            attn = decode_attention_appended(q_eff, kc, kc, rows, rows, positions)
-            h = h + _attn_out(cfg, lp, _mla_unlatent(cfg, lp, attn), mesh)
-            x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-            h = h + _mlp_out(cfg, lp, x, ep, mesh)
-            return h, (rows, rows[..., :0])
-        q, k, v = _attn_proj_qkv(cfg, lp, x, mesh)  # q [B,H,Hd], k/v [B,K,Hd]
-        q = apply_rope(q[:, None], positions[:, None], inv)[:, 0]
-        k = apply_rope(k[:, None], positions[:, None], inv)[:, 0]
-        if use_sp:
-            from localai_tpu.ops.attention import decode_attention_appended_sp
+    if use_sp:
+        def attend(q, k, v, sliding, kc, vc):
+            return decode_attention_appended_sp(
+                q, kc, vc, k, v, positions, mesh, **_mask_opts(cfg, sliding))
+    else:
+        def attend(q, k, v, sliding, kc, vc):
+            return decode_attention_appended(
+                q, kc, vc, k, v, positions, **_mask_opts(cfg, sliding))
 
-            attn = decode_attention_appended_sp(
-                q, kc, vc, k, v, positions, mesh,
-                softcap=cfg.attn_softcap, window=cfg.sliding_window,
-                sliding=_layer_sliding(cfg, li),
-            )
-        else:
-            attn = decode_attention_appended(
-                q, kc, vc, k, v, positions,
-                softcap=cfg.attn_softcap, window=cfg.sliding_window,
-                sliding=_layer_sliding(cfg, li),
-            )
-        h = h + _attn_out(cfg, lp, attn.reshape(B, -1), mesh)
-        x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-        h = h + _mlp_out(cfg, lp, x, ep, mesh)
-        return h, (k, v)
-
+    layer = functools.partial(
+        _decoder_layer, cfg, pos=positions, inv=_rope_inv(cfg), attend=attend,
+        ep=ep, mesh=mesh)
     h, (new_k, new_v) = _scan_layers(
         cfg, params, h, layer, (cache.k, cache.v)
     )
@@ -1105,98 +1169,55 @@ def decode_step_windowed(
     dense-prefix layer), or None where no layer ran a router that `_mlp`
     sees (dense models, the ep > 1 capacity dispatch).
     """
-    B = tokens.shape[0]
     use_sp = mesh is not None and mesh.shape.get("sp", 1) > 1
-    inv_freq = rope_frequencies(cfg)
-    inv_local = rope_frequencies_local(cfg)
-    rope_pos = positions if rope_delta is None else positions + rope_delta
+    if use_sp and cfg.is_mla:
+        raise NotImplementedError("MLA + sp is excluded (PARITY.md)")
+    # MLA rotates at the row index: `rope_delta` reaches GQA alone.
+    rope_pos = (positions if rope_delta is None or cfg.is_mla
+                else positions + rope_delta)
     h = _embed(cfg, params, tokens)
-    routed = []  # trace time: did any layer body see a router's choice
+    sink = dict(sink=cfg.attention_sink, swin=cfg.attention_window)
 
-    def mlp(lp, x, llora=None):
-        """The layer's MLP and, with `expert_rows`, its rows per expert."""
+    if ptable is not None:
+        def attend(q, k, v, sliding, kc, vc, lk, lv):
+            return decode_attention_windowed_paged(
+                q, kc, vc, ptable, lk, lv, k, v, positions, step,
+                impl=paged_impl, kv_scale=kv_scale,
+                **_mask_opts(cfg, sliding, mesh=mesh, **sink))
+    elif use_sp:
+        def attend(q, k, v, sliding, kc, vc, lk, lv):
+            return decode_attention_windowed_sp(
+                q, kc, vc, lk, lv, k, v, positions, step, mesh,
+                **_mask_opts(cfg, sliding, **sink))
+    else:
+        def attend(q, k, v, sliding, kc, vc, lk, lv):
+            return decode_attention_windowed(
+                q, kc, vc, lk, lv, k, v, positions, step,
+                **_mask_opts(cfg, sliding, **sink))
+
+    inv = _rope_inv(cfg)
+    routed = []  # trace time: did any layer see a router's choice
+
+    def body(h, xs):
+        """The layer and, with `expert_rows`, its rows per expert."""
+        picks = [] if expert_rows else None
+        h, rows = _decoder_layer(
+            cfg, h, xs, pos=rope_pos, inv=inv, attend=attend, ep=ep,
+            mesh=mesh, lora=lora, picks=picks)
         if not expert_rows:
-            return _mlp_out(cfg, lp, x, ep, mesh, lora=llora), ()
-        picks: list = []
-        m = _mlp_out(cfg, lp, x, ep, mesh, lora=llora, picks=picks)
+            return h, rows
         E = max(cfg.num_experts, 1)
         if not picks:
-            return m, (jnp.zeros((E,), jnp.int32),)
+            return h, rows + (jnp.zeros((E,), jnp.int32),)
         routed.append(True)
-        return m, (jax.nn.one_hot(picks[0], E, dtype=jnp.int32).sum((0, 1)),)
-
-    def layer(h, xs):
-        if lora is None:
-            lp, li, kc, vc, lk, lv = xs
-            llora = None
-        else:
-            lp, li, kc, vc, lk, lv, la = xs
-            llora = (la, lora[1])
-        x = rms_norm(h, lp["attn_norm"], cfg.rms_eps)
-        inv = _layer_inv_freq(cfg, inv_freq, inv_local, li)
-        if cfg.is_mla:
-            if use_sp:
-                raise NotImplementedError("MLA + sp is excluded (PARITY.md)")
-            x1 = x[:, None]
-            q_eff = _mla_absorbed_q(cfg, lp, x1, positions[:, None], inv, mesh)[:, 0]
-            rows = _mla_rows(cfg, lp, x1, positions[:, None], inv)[:, 0]
-            if ptable is not None:
-                from localai_tpu.ops.attention import (
-                    decode_attention_windowed_paged,
-                )
-
-                attn = decode_attention_windowed_paged(
-                    q_eff, kc, kc, ptable, lk, lk, rows, rows, positions, step,
-                    impl=paged_impl, kv_scale=kv_scale,
-                )
-            else:
-                attn = decode_attention_windowed(
-                    q_eff, kc, kc, lk, lk, rows, rows, positions, step,
-                )
-            h = h + _attn_out(cfg, lp, _mla_unlatent(cfg, lp, attn), mesh)
-            x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-            m, rows_e = mlp(lp, x)
-            return h + m, (rows, rows[..., :0]) + rows_e
-        q, k, v = _attn_proj_qkv(cfg, lp, x, mesh, lora=llora)
-        with jax.named_scope("attention"):
-            q = apply_rope(q[:, None], rope_pos[:, None], inv)[:, 0]
-            k = apply_rope(k[:, None], rope_pos[:, None], inv)[:, 0]
-            if ptable is not None:
-                from localai_tpu.ops.attention import decode_attention_windowed_paged
-
-                attn = decode_attention_windowed_paged(
-                    q, kc, vc, ptable, lk, lv, k, v, positions, step,
-                    softcap=cfg.attn_softcap, window=cfg.sliding_window,
-                    sliding=_layer_sliding(cfg, li), impl=paged_impl, mesh=mesh,
-                    kv_scale=kv_scale, sink=cfg.attention_sink,
-                    swin=cfg.attention_window,
-                )
-            elif use_sp:
-                from localai_tpu.ops.attention import decode_attention_windowed_sp
-
-                attn = decode_attention_windowed_sp(
-                    q, kc, vc, lk, lv, k, v, positions, step, mesh,
-                    softcap=cfg.attn_softcap, window=cfg.sliding_window,
-                    sliding=_layer_sliding(cfg, li), sink=cfg.attention_sink,
-                    swin=cfg.attention_window,
-                )
-            else:
-                attn = decode_attention_windowed(
-                    q, kc, vc, lk, lv, k, v, positions, step,
-                    softcap=cfg.attn_softcap, window=cfg.sliding_window,
-                    sliding=_layer_sliding(cfg, li), sink=cfg.attention_sink,
-                    swin=cfg.attention_window,
-                )
-        h = h + _attn_out(cfg, lp, attn.reshape(B, -1), mesh, lora=llora)
-        x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-        m, rows_e = mlp(lp, x, llora)
-        return h + m, (k, v) + rows_e
+        return h, rows + (
+            jax.nn.one_hot(picks[0], E, dtype=jnp.int32).sum((0, 1)),)
 
     pool = _paged_pool(cache) if ptable is not None else (cache.k, cache.v)
     extras = pool + (local_k, local_v)
     if lora is not None:
         extras = extras + (lora[0],)
-    h, (new_k, new_v, *rows_e) = _scan_layers(cfg, params, h, layer, extras)
+    h, (new_k, new_v, *rows_e) = _scan_layers(cfg, params, h, body, extras)
     local_k = jax.lax.dynamic_update_index_in_dim(
         local_k, new_k.astype(local_k.dtype), step, axis=2
     )
@@ -1254,118 +1275,40 @@ def decode_chunk(
     partials) and the write routes through the table — speculative decoding
     composes with the paged cache."""
     B, T = tokens.shape
-    inv_freq = rope_frequencies(cfg)
     h = _embed(cfg, params, tokens)  # [B, T, D]
     batch_idx = jnp.arange(B)[:, None].repeat(T, axis=1)  # [B, T]
-    inv_local = rope_frequencies_local(cfg)
-    scale = cfg.head_dim_**-0.5
     causal = jnp.tril(jnp.ones((T, T), bool))
-    S = None if ptable is not None else cache.k.shape[2]
     # In-window distance t-u (positions are contiguous per slot), for the
     # gemma-2 sliding mask.
     win_dist = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
 
-    def layer(h, xs):
-        if lora is None:
-            lp, li, kc, vc = xs
-            llora = None
-        else:
-            lp, li, kc, vc, la = xs
-            llora = (la, lora[1])
-        sliding = _layer_sliding(cfg, li)
-        x = rms_norm(h, lp["attn_norm"], cfg.rms_eps)
-        inv = _layer_inv_freq(cfg, inv_freq, inv_local, li)
-        if cfg.is_mla:
-            # Absorbed MLA verify chunk: q_eff scores the latent cache and
-            # the window's fresh latent rows; values come back out of the
-            # same latents ([..., :r] → W_vb).
-            q_eff = _mla_absorbed_q(cfg, lp, x, positions, inv, mesh)  # [B,T,H,De]
-            rows = _mla_rows(cfg, lp, x, positions, inv)  # [B,T,1,De]
-            if ptable is not None:
-                from localai_tpu.ops.attention import (
-                    _merge_partials_mq,
-                    paged_partials_mq,
-                )
-
-                acc, m, l = paged_partials_mq(
-                    q_eff, kc, kc, ptable, positions[:, 0], q_pos=positions,
-                    impl=paged_impl, kv_scale=kv_scale,
-                )
-                attn = _merge_partials_mq(
-                    q_eff, acc, m, l, rows, rows,  # [B, T, 1, De] = [B, E, K, D]
-                    jnp.broadcast_to(causal[None], (B, T, T)),
-                )
-            else:
-                De = q_eff.shape[-1]
-                qf = (q_eff.astype(jnp.float32) / De**0.5)
-                kcf = kc[..., 0, :].astype(jnp.float32)  # [B, S, De]
-                rf = rows[..., 0, :].astype(jnp.float32)  # [B, T, De]
-                sc = jnp.einsum("bthd,bsd->bhts", qf, kcf)
-                prefix = jnp.arange(S)[None, None, :] < positions[:, :1, None]
-                sc = jnp.where(prefix[:, None], sc, -1e30)
-                sw = jnp.einsum("bthd,bud->bhtu", qf, rf)
-                sw = jnp.where(causal[None, None], sw, -1e30)
-                probs = jax.nn.softmax(jnp.concatenate([sc, sw], axis=-1), axis=-1)
-                attn = jnp.einsum("bhts,bsd->bthd", probs[..., :S], kcf) + jnp.einsum(
-                    "bhtu,bud->bthd", probs[..., S:], rf
-                )
-                attn = attn.astype(h.dtype)
-            attn = _mla_unlatent(cfg, lp, attn)  # [B, T, H·v]
-            h = h + _attn_out(cfg, lp, attn, mesh)
-            x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-            h = h + _mlp_out(cfg, lp, x, ep, mesh)
-            return h, (rows, rows[..., :0])
-        q, k, v = _attn_proj_qkv(cfg, lp, x, mesh, lora=llora)  # q [B,T,H,Hd], k/v [B,T,K,Hd]
-        q = apply_rope(q, positions, inv)
-        k = apply_rope(k, positions, inv)
-        K_h = kc.shape[2]
-        G = q.shape[2] // K_h
-        wmask = causal  # [T, T]
-        if cfg.sliding_window and sliding is not None:
-            wmask = wmask & (~sliding | (win_dist < cfg.sliding_window))
-        if ptable is not None:
-            from localai_tpu.ops.attention import (
-                _merge_partials_mq,
-                paged_partials_mq,
-            )
-
+    if ptable is not None:
+        def attend(q, k, v, sliding, kc, vc):
             acc, m, l = paged_partials_mq(
-                q, kc, vc, ptable, positions[:, 0],
-                softcap=cfg.attn_softcap, window=cfg.sliding_window,
-                sliding=sliding, q_pos=positions, impl=paged_impl, mesh=mesh,
-                kv_scale=kv_scale,
-            )
-            attn = _merge_partials_mq(
-                q, acc, m, l, k, v,
-                jnp.broadcast_to(wmask[None], (B, T, T)),
-                softcap=cfg.attn_softcap,
-            ).reshape(B, T, -1).astype(h.dtype)
-        else:
-            qf = (q.astype(jnp.float32) * scale).reshape(B, T, K_h, G, cfg.head_dim_)
-            # Cache prefix: rows before the window start (later rows stale).
-            sc = jnp.einsum("btkgd,bskd->bkgts", qf, kc.astype(jnp.float32))
-            if cfg.attn_softcap:
-                sc = cfg.attn_softcap * jnp.tanh(sc / cfg.attn_softcap)
-            prefix = jnp.arange(S)[None, None, :] < positions[:, :1, None]  # [B,1,S]
-            if cfg.sliding_window and sliding is not None:
-                dist = positions[:, :, None] - jnp.arange(S)[None, None, :]
-                prefix = prefix & (~sliding | (dist < cfg.sliding_window))
-            sc = jnp.where(prefix[:, None, None], sc, -1e30)
-            # In-window causal attention against the fresh k.
-            sw = jnp.einsum("btkgd,bukd->bkgtu", qf, k.astype(jnp.float32))
-            if cfg.attn_softcap:
-                sw = cfg.attn_softcap * jnp.tanh(sw / cfg.attn_softcap)
-            sw = jnp.where(wmask[None, None, None], sw, -1e30)
-            probs = jax.nn.softmax(jnp.concatenate([sc, sw], axis=-1), axis=-1)
-            attn = jnp.einsum(
-                "bkgts,bskd->btkgd", probs[..., :S], vc.astype(jnp.float32)
-            ) + jnp.einsum("bkgtu,bukd->btkgd", probs[..., S:], v.astype(jnp.float32))
-            attn = attn.reshape(B, T, -1).astype(h.dtype)
-        h = h + _attn_out(cfg, lp, attn, mesh, lora=llora)
-        x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-        h = h + _mlp_out(cfg, lp, x, ep, mesh, lora=llora)
-        return h, (k, v)
+                q, kc, vc, ptable, positions[:, 0], q_pos=positions,
+                impl=paged_impl, kv_scale=kv_scale,
+                **_mask_opts(cfg, sliding, mesh=mesh))
+            wmask = _slid(cfg, sliding, causal, win_dist)
+            return _merge_partials_mq(
+                q, acc, m, l, k, v, jnp.broadcast_to(wmask[None], (B, T, T)),
+                softcap=_softcap(cfg))
+    else:
+        S = cache.k.shape[2]
 
+        def attend(q, k, v, sliding, kc, vc):
+            # Cache prefix: rows before the window start (later rows stale).
+            prefix = _slid(
+                cfg, sliding,
+                jnp.arange(S)[None, None, :] < positions[:, :1, None],  # [B,1,S]
+                positions[:, :, None] - jnp.arange(S)[None, None, :])
+            return prefix_window_attention(
+                q, kc, vc, k, v, prefix,
+                _slid(cfg, sliding, causal, win_dist)[None],
+                softcap=_softcap(cfg), latent=cfg.is_mla)
+
+    layer = functools.partial(
+        _decoder_layer, cfg, pos=positions, inv=_rope_inv(cfg), attend=attend,
+        ep=ep, mesh=mesh, lora=lora)
     extras = _paged_pool(cache) if ptable is not None else (cache.k, cache.v)
     if lora is not None:
         extras = extras + (lora[0],)
@@ -1402,79 +1345,27 @@ def prefill_tail(
     prompt. Returns (last_logits [B, V] f32, tail_ks [L, B, T, K, Hd],
     tail_vs) — the engine writes the tail rows after the cached span.
     """
-    B, T = tokens.shape
+    T = tokens.shape[1]
     P = prefix_k.shape[2]
-    inv_freq = rope_frequencies(cfg)
-    inv_local = rope_frequencies_local(cfg)
     positions = offsets[:, None] + jnp.arange(T)[None, :]  # [B, T] global
     length_mask = jnp.arange(T)[None, :] < lengths[:, None]
     h = _embed(cfg, params, tokens)  # [B, T, D]
-    scale = cfg.head_dim_**-0.5
     causal = jnp.tril(jnp.ones((T, T), bool))
     pvalid = jnp.arange(P)[None, :] < offsets[:, None]  # [B, P]
     win_dist = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]  # in-tail t-u
 
-    def layer(h, xs):
-        lp, li, kc, vc = xs  # kc/vc [B, P, K, Hd]
-        sliding = _layer_sliding(cfg, li)
-        x = rms_norm(h, lp["attn_norm"], cfg.rms_eps)
-        inv = _layer_inv_freq(cfg, inv_freq, inv_local, li)
-        if cfg.is_mla:
-            # Absorbed tail prefill against cached LATENT prefix rows: the
-            # identity q·k = q_eff·latent holds for the in-tail tokens too,
-            # so both segments score in latent space.
-            q_eff = _mla_absorbed_q(cfg, lp, x, positions, inv, mesh)  # [B,T,H,De]
-            rows = _mla_rows(cfg, lp, x, positions, inv)  # [B,T,1,De]
-            De = q_eff.shape[-1]
-            qf = q_eff.astype(jnp.float32) / De**0.5
-            kcf = kc[..., 0, :].astype(jnp.float32)  # [B, P, De]
-            rf = rows[..., 0, :].astype(jnp.float32)  # [B, T, De]
-            sc = jnp.einsum("bthd,bsd->bhts", qf, kcf)
-            sc = jnp.where(pvalid[:, None, None], sc, -1e30)
-            sw = jnp.einsum("bthd,bud->bhtu", qf, rf)
-            wm = causal[None, None] & length_mask[:, None, None, :]
-            sw = jnp.where(wm, sw, -1e30)
-            probs = jax.nn.softmax(jnp.concatenate([sc, sw], axis=-1), axis=-1)
-            attn = jnp.einsum("bhts,bsd->bthd", probs[..., :P], kcf) + jnp.einsum(
-                "bhtu,bud->bthd", probs[..., P:], rf
-            )
-            attn = _mla_unlatent(cfg, lp, attn.astype(h.dtype))
-            h = h + _attn_out(cfg, lp, attn, mesh)
-            x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-            h = h + _mlp_out(cfg, lp, x, ep, mesh)
-            return h, (rows, rows[..., :0])
-        q, k, v = _attn_proj_qkv(cfg, lp, x, mesh)  # q [B,T,H,Hd], k/v [B,T,K,Hd]
-        q = apply_rope(q, positions, inv)
-        k = apply_rope(k, positions, inv)
-        K_h = kc.shape[2]
-        G = q.shape[2] // K_h
-        qf = (q.astype(jnp.float32) * scale).reshape(B, T, K_h, G, cfg.head_dim_)
-        sc = jnp.einsum("btkgd,bskd->bkgts", qf, kc.astype(jnp.float32))
-        if cfg.attn_softcap:
-            sc = cfg.attn_softcap * jnp.tanh(sc / cfg.attn_softcap)
-        pmask = pvalid[:, None, :]  # [B, 1, P]
-        if cfg.sliding_window and sliding is not None:
-            dist = positions[:, :, None] - jnp.arange(P)[None, None, :]
-            pmask = pmask & (~sliding | (dist < cfg.sliding_window))
-        sc = jnp.where(pmask[:, None, None], sc, -1e30)
-        sw = jnp.einsum("btkgd,bukd->bkgtu", qf, k.astype(jnp.float32))
-        if cfg.attn_softcap:
-            sw = cfg.attn_softcap * jnp.tanh(sw / cfg.attn_softcap)
-        cmask = causal
-        if cfg.sliding_window and sliding is not None:
-            cmask = cmask & (~sliding | (win_dist < cfg.sliding_window))
-        wmask = cmask[None, None, None] & length_mask[:, None, None, None, :]
-        sw = jnp.where(wmask, sw, -1e30)
-        probs = jax.nn.softmax(jnp.concatenate([sc, sw], axis=-1), axis=-1)
-        attn = jnp.einsum(
-            "bkgts,bskd->btkgd", probs[..., :P], vc.astype(jnp.float32)
-        ) + jnp.einsum("bkgtu,bukd->btkgd", probs[..., P:], v.astype(jnp.float32))
-        attn = attn.reshape(B, T, -1).astype(h.dtype)
-        h = h + _attn_out(cfg, lp, attn, mesh)
-        x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-        h = h + _mlp_out(cfg, lp, x, ep, mesh)
-        return h, (k, v)
+    def attend(q, k, v, sliding, kc, vc):  # kc/vc [B, P, K, Hd]
+        pmask = _slid(cfg, sliding, pvalid[:, None, :],
+                      positions[:, :, None] - jnp.arange(P)[None, None, :])
+        wmask = (_slid(cfg, sliding, causal, win_dist)[None]
+                 & length_mask[:, None, :])
+        return prefix_window_attention(
+            q, kc, vc, k, v, pmask, wmask,
+            softcap=_softcap(cfg), latent=cfg.is_mla)
 
+    layer = functools.partial(
+        _decoder_layer, cfg, pos=positions, inv=_rope_inv(cfg), attend=attend,
+        ep=ep, mesh=mesh)
     h, (ks, vs) = _scan_layers(
         cfg, params, h, layer, (prefix_k, prefix_v)
     )
@@ -1681,77 +1572,37 @@ def prefill_chunk_paged(
     Returns (last_logits [B, V] f32 | None, new_pool) — mid chunks skip the
     unembed entirely (with_logits=False).
     """
-    B, T = tokens.shape
-    from localai_tpu.ops.attention import (
-        _merge_partials_mq,
-        paged_prefill_partials,
-    )
-
-    inv_freq = rope_frequencies(cfg)
-    inv_local = rope_frequencies_local(cfg)
+    T = tokens.shape[1]
     positions = offsets[:, None] + jnp.arange(T)[None, :]  # [B, T] global
     length_mask = jnp.arange(T)[None, :] < lengths[:, None]
     h = _embed(cfg, params, tokens)  # [B, T, D]
     causal = jnp.tril(jnp.ones((T, T), bool))
     win_dist = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]  # in-chunk t-u
+    sink = dict(sink=cfg.attention_sink, swin=cfg.attention_window)
 
-    def layer(h, xs):
-        lp, li, kc, vc = xs  # kc/vc: the [L, P, page, K, Hd] pools at layer li
-        sliding = _layer_sliding(cfg, li)
-        x = rms_norm(h, lp["attn_norm"], cfg.rms_eps)
-        inv = _layer_inv_freq(cfg, inv_freq, inv_local, li)
-        if cfg.is_mla:
-            # Absorbed MLA chunk: q_eff scores the latent prefix pages and
-            # the chunk's fresh latent rows (values come back out of the
-            # same latents — see decode_chunk's MLA branch).
-            q_eff = _mla_absorbed_q(cfg, lp, x, positions, inv, mesh)  # [B,T,H,De]
-            rows = _mla_rows(cfg, lp, x, positions, inv)  # [B,T,1,De]
-            acc, m, l = paged_prefill_partials(
-                q_eff, kc, kc, table, offsets, q_pos=positions,
-                impl=paged_impl, kv_scale=kv_scale,
-            )
-            wm = causal[None] & length_mask[:, None, :]  # [B, T, T]
-            attn = _merge_partials_mq(q_eff, acc, m, l, rows, rows, wm)
-            attn = _mla_unlatent(cfg, lp, attn)  # [B, T, H·v]
-            h = h + _attn_out(cfg, lp, attn, mesh)
-            x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-            h = h + _mlp_out(cfg, lp, x, ep, mesh)
-            return h, (rows, rows[..., :0])
-        q, k, v = _attn_proj_qkv(cfg, lp, x, mesh)  # q [B,T,H,Hd], k/v [B,T,K,Hd]
-        q = apply_rope(q, positions, inv)
-        k = apply_rope(k, positions, inv)
-        if sp_mesh is not None:
-            # Sequence-parallel chunk attention (ISSUE 14): ring over "sp".
-            from localai_tpu.parallel.ring import ring_chunk_paged_attention
+    # kc/vc below: the [L, P, page, K, Hd] pools at this layer (StackedLayer)
+    if sp_mesh is not None and not cfg.is_mla:
+        # Sequence-parallel chunk attention (ISSUE 14): ring over "sp".
+        from localai_tpu.parallel.ring import ring_chunk_paged_attention
 
-            attn = ring_chunk_paged_attention(
+        def attend(q, k, v, sliding, kc, vc):
+            return ring_chunk_paged_attention(
                 q, k, v, offsets, lengths, kc, vc, table, sp_mesh,
-                softcap=cfg.attn_softcap, window=cfg.sliding_window,
-                sliding=sliding, sink=cfg.attention_sink,
-                swin=cfg.attention_window, kv_scale=kv_scale,
-            ).reshape(B, T, -1).astype(h.dtype)
-            h = h + _attn_out(cfg, lp, attn, mesh)
-            x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-            h = h + _mlp_out(cfg, lp, x, ep, mesh)
-            return h, (k, v)
-        wmask = causal[None] & length_mask[:, None, :]  # [B, T, T]
-        if cfg.sliding_window and sliding is not None:
-            wmask = wmask & (~sliding | (win_dist[None] < cfg.sliding_window))
-        acc, m, l = paged_prefill_partials(
-            q, kc, vc, table, offsets,
-            softcap=cfg.attn_softcap, window=cfg.sliding_window,
-            sliding=sliding, q_pos=positions, impl=paged_impl, mesh=mesh,
-            kv_scale=kv_scale, sink=cfg.attention_sink,
-            swin=cfg.attention_window,
-        )
-        attn = _merge_partials_mq(
-            q, acc, m, l, k, v, wmask, softcap=cfg.attn_softcap,
-        ).reshape(B, T, -1).astype(h.dtype)
-        h = h + _attn_out(cfg, lp, attn, mesh)
-        x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-        h = h + _mlp_out(cfg, lp, x, ep, mesh)
-        return h, (k, v)
+                kv_scale=kv_scale, **_mask_opts(cfg, sliding, **sink))
+    else:
+        def attend(q, k, v, sliding, kc, vc):
+            acc, m, l = paged_prefill_partials(
+                q, kc, vc, table, offsets, q_pos=positions, impl=paged_impl,
+                kv_scale=kv_scale,
+                **_mask_opts(cfg, sliding, mesh=mesh, **sink))
+            wmask = _slid(cfg, sliding, causal[None] & length_mask[:, None, :],
+                          win_dist[None])  # [B, T, T]
+            return _merge_partials_mq(q, acc, m, l, k, v, wmask,
+                                      softcap=_softcap(cfg))
 
+    layer = functools.partial(
+        _decoder_layer, cfg, pos=positions, inv=_rope_inv(cfg), attend=attend,
+        ep=ep, mesh=mesh)
     h, (new_k, new_v) = _scan_layers(cfg, params, h, layer, _paged_pool(pool))
     pool = write_chunk_to_pool(pool, table, new_k, new_v, positions,
                                kv_scale=kv_scale)

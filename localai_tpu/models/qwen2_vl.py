@@ -1,8 +1,8 @@
 """Qwen2-VL: native-resolution vision tower + m-rope multimodal serving.
 
 Reference: the vLLM backend serves Qwen2-VL through multimodal passthrough
-(/root/reference/backend/python/vllm/backend.py:211-243); BASELINE.json's
-VLM config names "Llava-1.6 / Qwen2-VL". Unlike the llava tower
+(/root/reference/backend/python/vllm/backend.py:211-243); the round-1
+target list names "Llava-1.6 / Qwen2-VL". Unlike the llava tower
 (models/vision.py: fixed 336px grid, CLS+interp positions), Qwen2-VL
 encodes at NATIVE resolution: images resize to the nearest multiple of
 patch·merge (28), every 14px patch becomes a token with 2-axis rotary

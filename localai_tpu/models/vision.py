@@ -1,7 +1,7 @@
 """CLIP-style vision tower + Llava projector: image features for VLM chat.
 
 Reference capability: multimodal chat via llava / Qwen2-VL through the vllm
-backend (BASELINE.json configs; backend/python/vllm multimodal). TPU shape:
+backend (backend/python/vllm multimodal). TPU shape:
 a ViT encoder (patch conv → pre-LN transformer) whose `select_layer` hidden
 states (llava uses -2) pass through a 2-layer MLP projector into the LLM's
 embedding space; the serving engine injects the projected tokens into the

@@ -934,6 +934,58 @@ def _paged_cache_partials_mq(q, k_pool, v_pool, table, limits,
     return acc, m, l
 
 
+def prefix_window_attention(
+    q: jnp.ndarray,  # [B, T, H, D] the window's queries
+    k_prefix: jnp.ndarray,  # [B, S, K, D] dense cached rows
+    v_prefix: jnp.ndarray,
+    k_win: jnp.ndarray,  # [B, T, K, D] the window's own fresh rows
+    v_win: jnp.ndarray,
+    prefix_mask: jnp.ndarray,  # [B, 1 | T, S] bool: cached rows a query sees
+    window_mask: jnp.ndarray,  # [1 | B, T, T] bool: in-window (causal) rows
+    softcap: float = 0.0,
+    latent: bool = False,  # MLA absorbed form: K = 1, the v operands unread
+) -> jnp.ndarray:
+    """One softmax over `cached prefix ⊕ the window's own rows`, dense: the
+    verify chunk (models/llama.decode_chunk) and the cached-prefix admission
+    (prefill_tail) against a slot cache. Its paged twin is
+    `paged_partials_mq` / `paged_prefill_partials` + `_merge_partials_mq`
+    below: same masks, the prefix walked page by page. What a query may see
+    is the caller's: both masks come in whole. Returns [B, T, H, D].
+
+    `latent` is MLA's absorbed form, kept as a second form of the contraction
+    (the softmax is shared): the one latent pseudo-head serves every query
+    head as key AND value, so the head axis is contracted without a kv-head
+    axis and the scale divides. It IS the general form at K = 1 with the
+    latent as both operands (the paged twin runs it so), but folded in, the
+    MLA programs change (tiny-mla on XLA:CPU: 94 more instructions in
+    decode_chunk, the whole cache's f32 convert hoisted out of the layer
+    loop), and ISSUE 28 changes no program."""
+    B, T, H, D = q.shape
+    S, K = k_prefix.shape[1], k_prefix.shape[2]
+    f32 = jnp.float32
+    if latent:
+        qf = q.astype(f32) / D**0.5
+        kp, kw = k_prefix[..., 0, :].astype(f32), k_win[..., 0, :].astype(f32)
+        vp, vw = kp, kw
+        score, mix, lift = "bthd,bsd->bhts", "bhts,bsd->bthd", (slice(None), None)
+    else:
+        qf = (q.astype(f32) * D**-0.5).reshape(B, T, K, H // K, D)
+        kp, kw = k_prefix.astype(f32), k_win.astype(f32)
+        vp, vw = v_prefix.astype(f32), v_win.astype(f32)
+        score, mix = "btkgd,bskd->bkgts", "bkgts,bskd->btkgd"
+        lift = (slice(None), None, None)
+    sc = jnp.einsum(score, qf, kp)  # [B, (K, G | H), T, S]
+    sw = jnp.einsum(score, qf, kw)  # the same over the window's T rows
+    if softcap:
+        sc, sw = softcap_scores(sc, softcap), softcap_scores(sw, softcap)
+    sc = jnp.where(prefix_mask[lift], sc, NEG_INF)
+    sw = jnp.where(window_mask[lift], sw, NEG_INF)
+    probs = jax.nn.softmax(jnp.concatenate([sc, sw], axis=-1), axis=-1)
+    out = (jnp.einsum(mix, probs[..., :S], vp)
+           + jnp.einsum(mix, probs[..., S:], vw))
+    return out.reshape(B, T, H, D).astype(q.dtype)
+
+
 def _merge_partials_mq(q, acc_g, m_g, l_g, extra_k, extra_v, extra_mask,
                        softcap: float = 0.0):
     """Multi-query `_merge_partials`: q [B, T, H, D], partials [..., T, ...],

@@ -5,9 +5,8 @@ actual process + network boundary — separate jax runtimes, separate engine
 state, a real HTTP hop for the LAIKV span stream — without TPUs. This
 module spawns a minimal serving process (CPU backend, one models dir, a
 declared cluster role) and hands back its base URL; the `multiproc` pytest
-fixture (tests/conftest.py) and BENCH_MULTIHOST (bench.py) both build on
-it, mirroring the PR 7 `multichip` idiom of simulating hardware topology
-with host resources.
+fixture (tests/conftest.py) builds on it, mirroring the PR 7 `multichip`
+idiom of simulating hardware topology with host resources.
 
 Run directly it IS the worker:
 

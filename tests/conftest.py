@@ -27,26 +27,57 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 
 
-# The suite takes about 2,100 s of CPU compiles on the sandbox and tier-1
-# stops it at 870 s, so which modules run first decides what a tier-1 run
-# covers. The modules below each cost more than 40 s (measured one module
-# per process, PR 21) and run last, cheapest first; every other module keeps
-# its alphabetical place ahead of them. A run that is cut off has then
-# covered about 500 of the 737 tests, not the first 310 of the alphabet.
-_COSTLY_LAST = (
-    "test_prefix_cache.py", "test_robustness.py", "test_flux.py",
-    "test_audio.py", "test_vits.py", "test_engine_runtime.py",
-    "test_compose.py", "test_tp_engine.py", "test_video_diffusion.py",
-    "test_deepseek.py", "test_fork_sampling.py", "test_paged_flash.py",
-    "test_quant.py", "test_lora_serving.py", "test_engine.py",
-    "test_paged_kv.py", "test_speculative.py", "test_latent_diffusion.py",
+# The suite is some 4,900 CPU-seconds, nearly all of it XLA:CPU compiles, and
+# the driver runs it on six workers (`-n 6 --dist loadfile`: a worker takes
+# the next module when it has run out). What the run takes is then the
+# busiest worker's time, so the modules that cost more than 40 s go first,
+# longest first, and the cheap ones fill the gaps at the end. (Until PR 28
+# they ran last, cheapest first, for a run that a 870 s limit cut short; run
+# to its end that order left five workers waiting for the last module.)
+# Times: the driver's junit file of PR 27's tree, summed per module; the two
+# modules PR 28 added were measured here. Every other module keeps its
+# alphabetical place behind these.
+_COSTLY_FIRST = (
+    "test_paged_flash.py",  # 439 s
+    "test_speculative.py",  # 294
+    "test_quant.py",  # 260
+    "test_paged_kv.py",  # 259
+    "test_engine.py",  # 231
+    "test_lora_serving.py",  # 213
+    "test_latent_diffusion.py",  # 177 (520 with the scheduler cases)
+    "test_compose.py",  # 177
+    "test_tp_engine.py",  # 174
+    "test_fork_sampling.py",  # 169
+    "test_deepseek.py",  # 160
+    "test_model_llama.py",  # 55 + the entry-point matrix, about 100
+    "test_diffusion_schedulers.py",  # about 150: 20 compiles
+    "test_engine_runtime.py",  # 136
+    "test_robustness.py",  # 106
+    "test_olmoe.py",  # 104
+    "test_video_diffusion.py",  # 103
+    "test_multihost.py",  # 102
+    "test_audio.py",  # 95
+    "test_vits.py",  # 92
+    "test_cluster.py",  # 89
+    "test_realtime.py",  # 83
+    "test_manager.py",  # 79
+    "test_prefix_cache.py",  # 77
+    "test_train.py",  # 69
+    "test_server.py",  # 65
+    "test_flux.py",  # 64
+    "test_observe.py",  # 55
+    "test_musicgen.py",  # 51
+    "test_model_families.py",  # 44
+    "test_tracing.py",  # 44
+    "test_grammar_dfa.py",  # 43
 )
 
 
 def pytest_collection_modifyitems(items):
-    rank = {name: i + 1 for i, name in enumerate(_COSTLY_LAST)}
+    rank = {name: i for i, name in enumerate(_COSTLY_FIRST)}
     # list.sort is stable: order inside a module is untouched
-    items.sort(key=lambda it: rank.get(os.path.basename(str(it.fspath)), 0))
+    items.sort(key=lambda it: rank.get(os.path.basename(str(it.fspath)),
+                                       len(rank)))
 
 
 def pytest_configure(config):

@@ -14,8 +14,8 @@ load_hf_checkpoint, and match torch logits. Covers both generations:
 The decode tests assert the absorbed-weight MLA identity: the latent-cache
 decode path must reproduce full-rank prefill logits (greedy continuation
 parity against torch). Reference serves this family via vLLM passthrough
-(/root/reference/backend/python/vllm/backend.py:92-141); BASELINE.json
-configs[4] names DeepSeek-R1 tensor/expert-parallel as a flagship config.
+(/root/reference/backend/python/vllm/backend.py:92-141); the round-1
+target list names DeepSeek-R1 tensor/expert-parallel as a flagship config.
 """
 
 import jax

@@ -488,8 +488,16 @@ def test_short_request_completes_during_chunked_prefill():
 
     eng = _mk_chunk_engine(16, True)
     try:
-        long_ids = [(j * 3) % 200 + 1 for j in range(90)]
-        eng.generate(long_ids, max_new_tokens=2, ignore_eos=True)  # warm
+        # 13 chunks of prefill. "Mid-chunk" is made to hold, not slept for:
+        # the short request goes in when the first chunk has run. (A 90-token
+        # prompt and a 20 ms sleep left the short one to arrive after the
+        # last chunk under six workers' load; the two then decode in one
+        # block and finish together: 4 of 20 runs, 18 of 20 with the short
+        # path compiled. This form: 20 of 20, 1.8 s apart or more; PR 28.)
+        long_ids = [(j * 3) % 200 + 1 for j in range(200)]
+        warm_ids = [(j * 7) % 190 + 3 for j in range(200)]  # no prefix hit
+        eng.generate(warm_ids, max_new_tokens=2, ignore_eos=True)
+        warm_chunks = eng.m_prefill_chunks
         done = {}
 
         def run(name, ids, n):
@@ -499,12 +507,14 @@ def test_short_request_completes_during_chunked_prefill():
         tl = threading.Thread(target=run, args=("long", long_ids, 40))
         ts = threading.Thread(target=run, args=("short", [5, 6, 7], 4))
         tl.start()
-        time.sleep(0.02)
+        deadline = time.monotonic() + 60
+        while eng.m_prefill_chunks == warm_chunks and time.monotonic() < deadline:
+            time.sleep(0.001)
         ts.start()
         tl.join(timeout=120)
         ts.join(timeout=120)
         assert done["short"] < done["long"], done
-        assert eng.m_prefill_chunks >= 5  # 90 tokens / 16-chunk × 2 runs
+        assert eng.m_prefill_chunks >= warm_chunks + 5  # 200 tokens / 16
     finally:
         eng.stop()
 

@@ -1,13 +1,11 @@
-"""Tier-1 coverage of the BENCH_r05 rc=124 bug class, re-pointed (ISSUE 5)
-at the migrated lint passes: the Engine class must never read a `self._x`
+"""Tier-1 coverage of a round-5 hang (a benchmark run cut at its time limit,
+rc=124), re-pointed (ISSUE 5) at the migrated lint passes: the Engine class must never read a `self._x`
 attribute that construction does not assign — the admission path once read
 _admit_hold_start/_last_submit_t before any assignment, the loop thread
 died of AttributeError, and every caller hung on its token queue forever.
 
 The passes now live in tools/lint (attr-init, metric-counters,
-lock-discipline — see docs/STATIC_ANALYSIS.md); tools/check_engine_attrs.py
-is a deprecation shim over the same analyses, exercised in test_lint.py.
-Detector self-tests (the synthetic bad/good classes that used to live here)
+lock-discipline — see docs/STATIC_ANALYSIS.md). Detector self-tests (the synthetic bad/good classes that used to live here)
 moved to tests/lint_fixtures/ and run from test_lint.py, so this file pins
 only the production target: Engine stays clean under all three passes.
 """

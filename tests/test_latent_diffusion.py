@@ -265,44 +265,6 @@ def test_clip_text_encoder_matches_transformers(sd_dir):
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 
-def test_generate_shapes_determinism_and_schedulers(sd_dir):
-    cfg, params, tok = ld.load_pipeline(sd_dir)
-    ids = jnp.asarray(tok("a photo of a cat", padding="max_length",
-                          max_length=77, truncation=True)["input_ids"],
-                      jnp.int32)[None]
-    un = jnp.asarray(tok("", padding="max_length", max_length=77,
-                         truncation=True)["input_ids"], jnp.int32)[None]
-    # The reference's full A1111-mapped surface (diffusers backend.py:
-    # 100-168) in both spellings: our "_karras" suffix and its "k_" prefix.
-    for sched in ("ddim", "pndm", "unipc", "euler", "euler_a", "dpmpp_2m",
-                  "heun", "lms", "dpm_2", "dpm_2_a", "dpmpp_sde",
-                  "dpmpp_2m_sde", "dpmpp_2m_karras", "euler_a_karras",
-                  "lms_karras", "k_euler", "k_dpm_2", "k_dpm_2_a",
-                  "k_dpmpp_sde", "k_dpmpp_2m_sde"):
-        img1 = np.asarray(ld.generate(
-            cfg, params, ids, un, jax.random.key(7), steps=4,
-            height=64, width=64, scheduler=sched,
-        ))
-        assert img1.shape == (1, 64, 64, 3), sched
-        assert np.isfinite(img1).all(), sched
-        assert 0.0 <= img1.min() and img1.max() <= 1.0, sched
-        img2 = np.asarray(ld.generate(
-            cfg, params, ids, un, jax.random.key(7), steps=4,
-            height=64, width=64, scheduler=sched,
-        ))
-        np.testing.assert_array_equal(img1, img2)  # same seed → same image
-    # Karras spacing actually changes the trajectory.
-    a = np.asarray(ld.generate(cfg, params, ids, un, jax.random.key(7),
-                               steps=4, height=64, width=64, scheduler="euler"))
-    b = np.asarray(ld.generate(cfg, params, ids, un, jax.random.key(7),
-                               steps=4, height=64, width=64, scheduler="k_euler"))
-    assert np.abs(a - b).max() > 0
-    for bad in ("pndm-nope", "ddim_karras", "k_unipc"):
-        with pytest.raises(ValueError):
-            ld.generate(cfg, params, ids, un, jax.random.key(7), steps=2,
-                        height=64, width=64, scheduler=bad)
-
-
 def test_vae_encode_decode_roundtrip_shapes(sd_dir):
     cfg, params, _ = ld.load_pipeline(sd_dir)
     img = jnp.asarray(np.random.default_rng(0).random((1, 64, 64, 3)), jnp.float32)

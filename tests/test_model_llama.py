@@ -204,3 +204,168 @@ def test_moe_ep_sharded_matches_single(devices8):
     fn = jax.jit(lambda p, t, l: prefill(cfg, p, t, l, ep=2)[0])
     out = fn(sharded_params, tokens, lengths)
     assert jnp.allclose(out, ref, atol=5e-2), float(jnp.abs(out - ref).max())
+
+
+# --------------------------------------------------------------------------- #
+# Every entry point against `prefill`: the rows of the one layer body
+# (llama._decoder_layer). A skeleton edit that breaks one way of attending
+# fails that row.
+# --------------------------------------------------------------------------- #
+
+SEQ = [(i * 37) % 251 + 1 for i in range(22)]  # longer than g2's window of 16
+BOOT, S_MAX, PAGE = 18, 32, 8  # prefilled rows; cache rows; 4 pages a slot
+
+
+def _g2_cfg():
+    """Gemma-2 semantics on the tiny widths, as tests/test_compose.py builds
+    them: softcap, sliding window on alternate layers, sandwich norms."""
+    import dataclasses
+
+    return dataclasses.replace(
+        get_arch("tiny"), name="tiny-g2", attn_softcap=30.0,
+        final_softcap=20.0, sliding_window=16, post_norms=True,
+        query_scale=12.0, activation="gelu_tanh", embed_scale=True)
+
+
+_FAMILIES = {
+    "tiny": lambda: get_arch("tiny"),
+    "g2": _g2_cfg,
+    "mla": lambda: get_arch("tiny-mla"),
+    "olmoe": lambda: get_arch("tiny-olmoe"),
+}
+
+
+@pytest.fixture(scope="module")
+def booted():
+    """family → (cfg, params, reference logits of SEQ's last position, the
+    first BOOT rows' k/v from `prefill`), built once a family."""
+    made = {}
+
+    def get(family):
+        if family not in made:
+            cfg = _FAMILIES[family]()
+            params = init_params(cfg, jax.random.key(0))
+
+            def pre(n):
+                toks = jnp.array([SEQ[:n] + [0] * (S_MAX - n)], jnp.int32)
+                return prefill(cfg, params, toks, jnp.array([n], jnp.int32))
+
+            _, ks, vs = pre(BOOT)
+            made[family] = (cfg, params, pre(len(SEQ))[0][0], ks, vs)
+        return made[family]
+
+    return get
+
+
+def _dense(cfg, ks, vs):
+    cache = KVCache.zeros(cfg, 2, S_MAX, dtype=ks.dtype)
+    return write_prefill_to_cache(cache, ks, vs, jnp.int32(0)), None
+
+
+def _paged(cfg, ks, vs):
+    from localai_tpu.models.llama import paged_cache_zeros, write_prefill_to_pool
+
+    mp = S_MAX // PAGE
+    table = jnp.arange(2 * mp, dtype=jnp.int32).reshape(2, mp)
+    pool = paged_cache_zeros(cfg, 2 * mp + 1, PAGE, dtype=ks.dtype)
+    return write_prefill_to_pool(pool, table[0], ks, vs, 0), table
+
+
+def _via_decode_step(cfg, params, cache, table):
+    for i in range(BOOT, len(SEQ)):
+        logits, cache = decode_step(
+            cfg, params, jnp.array([SEQ[i], 0], jnp.int32),
+            jnp.array([i, 0], jnp.int32), cache)
+    return logits[0]
+
+
+def _via_windowed(cfg, params, cache, table):
+    from localai_tpu.models.llama import decode_step_windowed
+
+    n = len(SEQ) - BOOT
+    lk = jnp.zeros((cfg.num_layers, 2, n, cfg.cache_kv_heads, cfg.cache_k_dim),
+                   cache.k.dtype)
+    lv = jnp.zeros(lk.shape[:-1] + (cfg.cache_v_dim,), cache.v.dtype)
+    for s in range(n):  # the cache stays read-only: the block window grows
+        logits, lk, lv = decode_step_windowed(
+            cfg, params, jnp.array([SEQ[BOOT + s], 0], jnp.int32),
+            jnp.array([BOOT + s, 0], jnp.int32), cache, lk, lv, jnp.int32(s),
+            ptable=table, paged_impl="xla")
+    return logits[0]
+
+
+def _via_decode_chunk(cfg, params, cache, table):
+    from localai_tpu.models.llama import decode_chunk
+
+    n = len(SEQ) - BOOT
+    toks = jnp.array([SEQ[BOOT:], [0] * n], jnp.int32)
+    pos = jnp.array([list(range(BOOT, len(SEQ))), list(range(n))], jnp.int32)
+    logits, _ = decode_chunk(cfg, params, toks, pos, cache, ptable=table,
+                             paged_impl="xla")
+    return logits[0, -1]
+
+
+def _via_prefill_tail(cfg, params, ks, vs):
+    from localai_tpu.models.llama import prefill_tail
+
+    tail = SEQ[BOOT:]
+    toks = jnp.array([tail + [0] * (8 - len(tail))], jnp.int32)
+    logits, _, _ = prefill_tail(
+        cfg, params, toks, jnp.array([len(tail)], jnp.int32),
+        jnp.array([BOOT], jnp.int32), ks[:, :, :24], vs[:, :, :24])
+    return logits[0]
+
+
+def _via_prefill_chunk_paged(cfg, params, pool, table):
+    from localai_tpu.models.llama import prefill_chunk_paged
+
+    tail = SEQ[BOOT:]
+    toks = jnp.array([tail + [0] * (8 - len(tail))], jnp.int32)
+    logits, _ = prefill_chunk_paged(
+        cfg, params, toks, jnp.array([len(tail)], jnp.int32),
+        jnp.array([BOOT], jnp.int32), pool, table[:1], paged_impl="xla")
+    return logits[0]
+
+
+_ROUTES = {
+    "decode_step": (_via_decode_step, _dense),
+    "decode_step_windowed": (_via_windowed, _dense),
+    "decode_step_windowed-paged": (_via_windowed, _paged),
+    "decode_chunk": (_via_decode_chunk, _dense),
+    "decode_chunk-paged": (_via_decode_chunk, _paged),
+    "prefill_tail": (_via_prefill_tail, None),
+    "prefill_chunk_paged": (_via_prefill_chunk_paged, _paged),
+}
+# Asserted where they were written: test_decode_matches_prefill above,
+# tests/test_prefix_cache.py, tests/test_paged_flash.py.
+_ELSEWHERE = {("tiny", "decode_step"), ("tiny", "prefill_tail"),
+              ("tiny", "prefill_chunk_paged")}
+
+
+@pytest.mark.parametrize("family,route", [
+    (f, r) for f in _FAMILIES for r in _ROUTES if (f, r) not in _ELSEWHERE])
+def test_entry_point_matches_prefill(booted, family, route):
+    """The logits of SEQ's last position, its first BOOT tokens prefilled
+    and the rest fed through `route`, agree with `prefill` over all of SEQ."""
+    cfg, params, want, ks, vs = booted(family)
+    via, seat = _ROUTES[route]
+    got = via(cfg, params, *(seat(cfg, ks, vs) if seat else (ks, vs)))
+    assert jnp.allclose(got, want, atol=5e-2), float(jnp.abs(got - want).max())
+
+
+@pytest.mark.parametrize("family", list(_FAMILIES))
+def test_sequence_logprob_matches_prefill(booted, family):
+    """Mean log P(SEQ[BOOT:] | SEQ[:BOOT]) against the same mean taken from
+    `prefill`'s last-position logits at every length in between."""
+    from localai_tpu.models.llama import sequence_logprob
+
+    cfg, params, *_ = booted(family)
+    n = len(SEQ)
+    row = jnp.array([SEQ + [0] * (S_MAX - n)], jnp.int32)
+    got = sequence_logprob(cfg, params, row, jnp.array([n], jnp.int32),
+                           jnp.array([BOOT], jnp.int32))
+    lens = jnp.arange(BOOT, n, dtype=jnp.int32)  # logits at len i predict SEQ[i]
+    logits, _, _ = prefill(cfg, params, row.repeat(len(lens), axis=0), lens)
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    want = logp[jnp.arange(len(lens)), jnp.array(SEQ[BOOT:])].mean()
+    assert abs(float(got[0]) - float(want)) < 2e-2, (float(got[0]), float(want))

@@ -961,8 +961,7 @@ def test_512k_context_acceptance():
     CPU tiny model (paged, hierarchical table, cold-middle spill active)
     with greedy output byte-identical to the all-hot/flat-table oracle.
     Slow-marked (several minutes of chunked prefill on CPU); the same
-    check at 1500 tokens runs in tier-1 above, and BENCH_LONGCTX exercises
-    the full ladder."""
+    check at 1500 tokens runs in tier-1 above."""
     cfg = get_arch("tiny")
     params = init_params(cfg, jax.random.key(0))
     CTX = 512 * 1024

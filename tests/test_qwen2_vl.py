@@ -4,8 +4,8 @@ against the real transformers torch implementation on a fabricated
 checkpoint in the exact HF layout.
 
 Reference: the vLLM backend serves Qwen2-VL via multimodal passthrough
-(/root/reference/backend/python/vllm/backend.py:211-243); BASELINE.json
-configs[2] names "Llava-1.6 / Qwen2-VL".
+(/root/reference/backend/python/vllm/backend.py:211-243); the round-1
+target list names "Llava-1.6 / Qwen2-VL".
 """
 
 import json

@@ -27,6 +27,7 @@ from localai_tpu.parallel.sharding import (
     validate_plan,
 )
 from localai_tpu.testing import faults
+from localai_tpu.testing.streams import assert_same_until_near_tie, stream
 
 PAGE = 32
 PROMPT = [(i * 37) % 251 + 1 for i in range(70)]  # covers 2 full KV pages
@@ -135,20 +136,30 @@ def test_tensor_parallel_env_auto(tiny, multichip, monkeypatch):
 @pytest.mark.multichip
 @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
 def test_tp2_output_identical_to_tp1(tiny, multichip, paged):
+    """Greedy: the same tokens (a wrong head shard, a dropped all-reduce or a
+    pool shard read through the wrong table shows here, and in the logprobs
+    below at the first step). Sampled: what two reduction orders can promise
+    (localai_tpu/testing/streams.py): logprobs within LOGPROB_TOL while the
+    context is shared, tokens identical up to a tie within it. The top-k
+    request parts ways at step 7, where ranks 6 and 7 of its 8 candidates
+    sit 4.0e-4 apart at tp=1 and swap at tp=2 (PERF.md §6, PR 28); an rng,
+    seed or sampler-parameter fault parts them where no tie is."""
     if multichip < 2:
         pytest.skip("needs >= 2 devices")
     ref = _mk(tiny, 1, paged)
     tp2 = _mk(tiny, 2, paged)
     try:
+        want = _gen_ids(ref, PROMPT, max_new_tokens=12)
+        got = _gen_ids(tp2, PROMPT, max_new_tokens=12)
+        assert got == want, paged
         for kw in (
-            dict(max_new_tokens=12),  # greedy
             dict(max_new_tokens=12, temperature=0.8, seed=7),
             dict(max_new_tokens=12, temperature=0.9, top_k=8, min_p=0.02,
                  seed=1234),
         ):
-            want = _gen_ids(ref, PROMPT, **kw)
-            got = _gen_ids(tp2, PROMPT, **kw)
-            assert got == want, (paged, kw)
+            same = assert_same_until_near_tie(
+                stream(ref, PROMPT, **kw), stream(tp2, PROMPT, **kw))
+            assert same >= 4, (paged, kw, same)
         # Prefix-cache hit: the repeat admits through the cached path.
         hits0 = tp2.m_prefix_hits
         want = _gen_ids(ref, PROMPT, max_new_tokens=8)

@@ -1,5 +1,4 @@
-"""Shared AST helpers for class-level passes (migrated from
-tools/check_engine_attrs.py, which is now a thin deprecation shim)."""
+"""Shared AST helpers for class-level passes."""
 
 from __future__ import annotations
 

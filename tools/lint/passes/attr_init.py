@@ -47,7 +47,7 @@ DEFAULT_TARGETS = [
 
 def uninitialized_reads(cls, module_classes=None):
     """[(attr, method, line)] of self-attribute reads no construction path
-    assigns. Function-level API kept for the check_engine_attrs shim."""
+    assigns."""
     assigned = astutil.construction_assigned(cls, module_classes)
     exempt = astutil.hasattr_probes(cls)
     found: list[tuple[str, str, int]] = []

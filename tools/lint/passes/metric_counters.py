@@ -24,7 +24,7 @@ DEFAULT_GLOBS = ["localai_tpu/**/*.py", "localai_tpu/*.py"]
 
 def uninitialized_counters(cls, module_classes=None):
     """[(attr, line)] of m_* counters metrics() reads but construction never
-    assigns. Function-level API kept for the check_engine_attrs shim."""
+    assigns."""
     methods = astutil.methods_of(cls)
     if "metrics" not in methods:
         return []
