@@ -35,8 +35,15 @@ Key differences, TPU-first:
 
 Slot-finish detection (EOS / stop sequence / length) happens host-side with
 up to one block of lag; the device may decode a handful of tokens past the
-finish point, which are discarded. That waste is bounded by
-pipeline_depth * block size and is the price of keeping the device saturated.
+finish point, which are discarded. A request that ends on its token budget
+(max_new_tokens, or the end of the context) loses the rest of its last block
+and nothing more: when the block that covers the budget is dispatched the
+slot index is handed on at once and the request is parked until that block
+comes back (`Engine._park`). One that ends sooner (EOS, a stop sequence, a
+cancel, a deadline), a slot under a host-walk grammar and a speculative
+engine's slots are found out only when the block has been processed, so
+theirs is bounded by pipeline_depth * block size, the price of keeping the
+device saturated.
 """
 
 from __future__ import annotations
@@ -632,6 +639,29 @@ class _Slot:
     # Grammar enforced on device via DFA tables (functions/dfa.py): the host
     # never walks candidates and the slot runs in full-depth fused blocks.
     dfa: bool = False
+    # Set once the slot index has been handed on (Engine._park): what the
+    # index held for this request until its last block comes back.
+    parked: Optional["_Parked"] = None
+
+
+@dataclasses.dataclass
+class _Parked:
+    """What a slot index held for a request that left it early: the index
+    is another request's now, so the pages, table pages, spill images and
+    adapter pin wait here, under (idx, gen) in Engine._parked, until `last`
+    has been processed. They are released then and not before: the
+    finish-time prefix save needs the generated ids."""
+
+    idx: int
+    gen: int  # the index's generation while the request held it
+    last: "_Entry"  # the block that covers the request's budget
+    pages: list[int]
+    tps: list[int]  # hierarchical tables: the directory's table pages
+    spill: dict  # cold-page host images, by page column
+    adapter_row: int
+    # Dense cache with the prefix cache on: (pb, k, v), the row's snapshot
+    # taken behind `last`, before the next tenant's prefill overwrites it.
+    snap: Optional[tuple] = None
 
 
 def _parse_tp_env(val: str) -> int:
@@ -1264,6 +1294,9 @@ class Engine:
         self.h_rope_delta = np.zeros((B,), np.int32)
         self.slots: list[Optional[_Slot]] = [None] * B
         self._slot_gen = [0] * B
+        # Requests whose slot index was handed on before their last block
+        # came back (_park), by (slot index, the generation they held).
+        self._parked: dict[tuple[int, int], _Slot] = {}
         self._tok_strs: Optional[list[str]] = None  # lazy grammar cache
         self.grammar_topk = self.GRAMMAR_TOPK
         # On-device grammar DFA (functions/dfa.py): per-slot automaton state
@@ -1384,7 +1417,10 @@ class Engine:
         ml1 = (-(-max(self._max_pages, 1) // self._l1_span)
                if self._hier else 0)
         self._ml1 = ml1
-        ntp = ((B + max(self.ecfg.prefix_cache_entries, 0) + 2) * ml1
+        # A parked tenant (_park) keeps its directory until its last block
+        # is processed: at most one a row of each block in flight.
+        ntp = ((B * (1 + self.ecfg.pipeline_depth)
+                + max(self.ecfg.prefix_cache_entries, 0) + 2) * ml1
                if self._hier else 0)
         self._scratch_tp = 0
         self.h_l0 = np.full(
@@ -1536,6 +1572,10 @@ class Engine:
         self.m_rows_posted = 0
         self.m_rows_overshoot = 0
         self.m_rows_empty = 0
+        # Requests finished, and of those the ones whose slot index had
+        # been handed on before their `done` was posted (_park).
+        self.m_slots_released = 0
+        self.m_slots_released_early = 0
         # Decode-block routing of a MoE model, see _count_routing.
         self.m_moe_slots = 0
         self.m_moe_slots_hit = 0
@@ -1745,14 +1785,16 @@ class Engine:
             tps.append(tp)
         return tps
 
-    def _entry_tps(self, slot_idx: int, n_pages: int) -> list[int]:
+    def _entry_tps(self, slot_idx: int, n_pages: int,
+                   parked: Optional[_Parked] = None) -> list[int]:
         """Addref'd table pages covering a prefix entry's n_pages leading
         pages (hier mode) — the directory half of copy-on-write span
         sharing. The entry keeps these rows byte-stable: any later slot
-        write through a shared table page copies it first (_ptable_set)."""
+        write through a shared table page copies it first (_ptable_set).
+        The directory is the slot's, or a parked tenant's own."""
         span = self._l1_span
         n_tp = -(-n_pages // span)
-        tps = self._slot_tps[slot_idx][:n_tp]
+        tps = (parked.tps if parked else self._slot_tps[slot_idx])[:n_tp]
         for tp in tps:
             self._tp_refs[tp] += 1
         return list(tps)
@@ -1768,9 +1810,13 @@ class Engine:
         program donates its ptable operand. Serial mode (loop_prepare_ahead
         off) keeps the legacy per-dispatch upload for A/B parity runs."""
         if not self.ecfg.loop_prepare_ahead:
+            # Copies: the tables are rewritten while the dispatch that
+            # shipped them is in flight (_park), and jnp.asarray of an
+            # aligned numpy array is zero-copy on the CPU backend.
             if self._hier:
-                return (jnp.asarray(self.h_l1), jnp.asarray(self.h_l0))
-            return jnp.asarray(self.h_ptable)
+                return (jnp.asarray(self.h_l1.copy()),
+                        jnp.asarray(self.h_l0.copy()))
+            return jnp.asarray(self.h_ptable.copy())
         if self._hier:
             return (self._ctrl.commit("ptable_l1", self.h_l1),
                     self._ctrl.commit("ptable_l0", self.h_l0))
@@ -1780,9 +1826,11 @@ class Engine:
         """One slot's table operand from its host row (flat [MP] or hier
         L1 [ML1] — the l0 pool rides along CURRENT, so directory-content
         updates between dispatches are visible)."""
+        # Copies, as in _ptable_device: `row` may be a view of the host
+        # table, which is rewritten while this dispatch is in flight.
         if self._hier:
-            return (jnp.asarray(row), jnp.asarray(self.h_l0))
-        return jnp.asarray(row)
+            return (jnp.asarray(row.copy()), jnp.asarray(self.h_l0.copy()))
+        return jnp.asarray(row.copy())
 
     def _pages_worst(self, request: GenRequest) -> int:
         """Worst-case pages for a request: the prefill writes a full bucket
@@ -4864,8 +4912,18 @@ class Engine:
             self._snap_cache[pb] = fn
         return fn
 
+    def _snapshot_rows(self, slot_idx: int, rows: int) -> Optional[tuple]:
+        """Dense cache: (pb, k, v), a device-to-device copy of the slot's
+        first `rows` rows at their bucket, or None over the byte budget."""
+        pb = self._bucket_for(rows)
+        if self._prefix_span_bytes(pb) > self.ecfg.prefix_cache_bytes:
+            return None
+        k, v = self._get_snapshot(pb)(self.cache, jnp.int32(slot_idx))
+        return pb, k, v
+
     def _prefix_save(self, slot_idx: int, key_tokens, valid_len: int,
-                     min_extend: int = 0) -> None:
+                     min_extend: int = 0,
+                     parked: Optional[_Parked] = None) -> None:
         """Store the slot's KV rows [0:valid_len] under `key_tokens`.
 
         Called right after an admission dispatch (prompt KV) and at finish
@@ -4873,7 +4931,9 @@ class Engine:
         device-to-device snapshot slice. Paged cache: NO copy — the entry
         takes a refcount on the slot's FULL pages below valid_len
         (copy-on-write sharing; later admissions map them read-only and
-        prefill tails into fresh pages). Never blocks the loop."""
+        prefill tails into fresh pages). Never blocks the loop. With
+        `parked` the rows are a parked tenant's (_park): its own pages and
+        directory, or the dense snapshot taken when it left the index."""
         if not self._prefix_enabled or valid_len < self.ecfg.prefix_cache_min:
             return
         if self._paged:
@@ -4912,7 +4972,12 @@ class Engine:
                 cov = max(cov, n if eq.all() else int(np.argmin(eq)))
             if cov and valid_len - cov < min_extend:
                 return
-        if self._paged and self._slot_spill[slot_idx]:
+        if parked is not None and parked.spill:
+            # Restoring writes the slot's table, which is the next tenant's
+            # by now: degrade as on pool pressure below, no save.
+            return
+        if (self._paged and parked is None
+                and self._slot_spill[slot_idx]):
             # Cold pages were spilled off-device — a span can only pin HOT
             # pages. Restore them byte-exactly first; on pool pressure (or
             # an injected page_spill fault) skip the save: the request is
@@ -4950,7 +5015,8 @@ class Engine:
             with self._host_lock:
                 self._prefix_host = keep_h
         if self._paged:
-            pages = self._slot_pages[slot_idx][: n_pages]
+            pages = (parked.pages if parked
+                     else self._slot_pages[slot_idx])[: n_pages]
             if len(pages) < n_pages:
                 self._prefix_entries = kept
                 return  # slot reservation shorter than the span (shouldn't happen)
@@ -4960,7 +5026,7 @@ class Engine:
                 # Directory half of CoW span sharing (ISSUE 14): the entry
                 # pins the slot's table pages covering the span, so later
                 # admissions map the L1 chunks by addref.
-                entry_new["tps"] = self._entry_tps(slot_idx, n_pages)
+                entry_new["tps"] = self._entry_tps(slot_idx, n_pages, parked)
             kept.insert(0, entry_new)
             while len(kept) > self.ecfg.prefix_cache_entries:
                 self._prefix_drop(kept.pop())
@@ -4977,12 +5043,12 @@ class Engine:
                     break
             self._prefix_entries = kept
             return
-        pb = self._bucket_for(valid_len)
-        nbytes = self._prefix_span_bytes(pb)
-        if nbytes > self.ecfg.prefix_cache_bytes:
+        snap = (parked.snap if parked is not None
+                else self._snapshot_rows(slot_idx, valid_len))
+        if snap is None:  # over the byte budget (a parked one: when it left)
             self._prefix_entries = kept
             return
-        k, v = self._get_snapshot(pb)(self.cache, jnp.int32(slot_idx))
+        pb, k, v = snap
         kept.insert(0, {"key": key, "valid": valid_len, "pb": pb, "k": k, "v": v})
         del kept[self.ecfg.prefix_cache_entries:]
         total = 0
@@ -5817,12 +5883,11 @@ class Engine:
         # pending nor slot — then evict the engine, leaving the caller
         # blocked on the stream forever). Duplicate done events on already-
         # finished streams are harmless (the consumer stopped reading).
-        for slot in self.slots:
-            if slot is not None:
-                slot.handle._q.put(TokenEvent(kind="done", finish_reason="stop"))
-                for _r, bh in (slot.request.fork_group or ()):
-                    bh._q.put(TokenEvent(kind="done", finish_reason="stop"))
-                slot.request.fork_group = None
+        for slot in self._tenants():
+            slot.handle._q.put(TokenEvent(kind="done", finish_reason="stop"))
+            for _r, bh in (slot.request.fork_group or ()):
+                bh._q.put(TokenEvent(kind="done", finish_reason="stop"))
+            slot.request.fork_group = None
         with self._pending_lock:
             pending, self._pending = list(self._pending), deque()
         for req, handle in pending:
@@ -6026,10 +6091,9 @@ class Engine:
                 for _r, bh in (_req.fork_group or ()):
                     bh.cancel()
                     n += 1
-        for slot in list(self.slots):
-            if slot is not None:
-                slot.handle.cancel()
-                n += 1
+        for slot in self._tenants():
+            slot.handle.cancel()
+            n += 1
         self._wake.set()
         loop = self._thread
         if loop is None or not loop.is_alive():
@@ -6119,6 +6183,8 @@ class Engine:
                 # structure (shared-state-race) — the copy is GIL-atomic.
                 out["kv_spilled_pages"] = float(
                     sum(len(d) for d in list(self._slot_spill))
+                    + sum(len(s.parked.spill)
+                          for s in list(self._parked.values()))
                 )
                 out["kv_spill_host_bytes"] = float(self._spill_bytes)
                 out["kv_spill_bytes_out"] = float(self.m_kv_spill_bytes_out)
@@ -6150,6 +6216,8 @@ class Engine:
         out["decode_rows_posted"] = float(self.m_rows_posted)
         out["decode_rows_overshoot"] = float(self.m_rows_overshoot)
         out["decode_rows_empty"] = float(self.m_rows_empty)
+        out["slots_released"] = float(self.m_slots_released)
+        out["slots_released_early"] = float(self.m_slots_released_early)
         if self.cfg.is_moe:
             # Routing of the decode blocks processed, see _count_routing.
             out["moe_expert_slots"] = float(self.m_moe_slots)
@@ -6611,7 +6679,7 @@ class Engine:
             # events below post through these captured handles.
             live_slots = [
                 (i, s) for i, s in enumerate(self.slots) if s is not None
-            ]
+            ] + [(i, s) for (i, _g), s in self._parked.items()]
             live_snapshot = [
                 (i, s.handle.rid, len(s.generated), s.prompt_len)
                 for i, s in live_slots
@@ -6682,6 +6750,8 @@ class Engine:
             self.h_adapter[i] = 0
             if self._paged and self._slot_pages[i]:
                 self._pages_free(i)
+        for slot in list(self._parked.values()):
+            self._release_parked(slot)
         # No slot references an adapter row anymore; zero the pins so the
         # device rows are evictable (the registry and host tier survive —
         # a reloaded engine starts cold on factors, not on metadata).
@@ -6830,7 +6900,11 @@ class Engine:
 
             if self._inflight:
                 front = self._inflight[0]
-                if front.ready() or nblocks >= depth or not active:
+                # A parked tenant (_park) is still being served: keep the
+                # loop turning for arrivals and deadlines while its last
+                # block runs, as for a live one.
+                if (front.ready() or nblocks >= depth
+                        or not (active or self._parked)):
                     # begins the pull and process phases itself
                     self._process_entry(self._inflight.popleft())
                     processed = True
@@ -6884,7 +6958,9 @@ class Engine:
         """Containment for a failed decode-block dispatch OR a failed
         prepare-ahead plan (both run the same planning code, so both take
         the same path): post a typed error event to every active request
-        and release its state — fail requests, not the loop."""
+        and release its state — fail requests, not the loop. A parked
+        tenant (_park) needs no further dispatch: it gets its tokens and
+        its `done` from the block already in flight."""
         log.exception("decode block dispatch failed")
         self._jnote("error", a=1.0)
         self._jnote_fault(e)
@@ -7068,10 +7144,7 @@ class Engine:
         here: a growth-blocked or otherwise stalled engine must not pin a
         cancelled request's pages while waiting for traffic."""
         now = time.monotonic()
-        for i in range(self.ecfg.max_slots):
-            slot = self.slots[i]
-            if slot is None:
-                continue
+        for slot in self._tenants():
             h = slot.handle
             if (h.deadline is not None and now > h.deadline
                     and not h.cancelled.is_set()):
@@ -7853,8 +7926,8 @@ class Engine:
         if not self.ecfg.loop_prepare_ahead:
             return (
                 jnp.asarray(p.pack),
-                jnp.asarray(self.h_rope_delta) if rope else None,
-                jnp.asarray(self.h_adapter) if adapter else None,
+                jnp.asarray(self.h_rope_delta.copy()) if rope else None,
+                jnp.asarray(self.h_adapter.copy()) if adapter else None,
             )
         parts = [p.pack]
         if rope:
@@ -7931,14 +8004,89 @@ class Engine:
             if active_snapshot[i] and self.slots[i] is not None:
                 self.slots[i].scheduled += n
                 self.slots[i].sched_rows += n
-        self._track(
-            _Entry(
-                kind="block", toks=toks_block, tk=tk_block, lp=lp_block,
-                gen=list(self._slot_gen), active=active_snapshot, n=n,
-                moe=moe_block,
-            )
+        entry = _Entry(
+            kind="block", toks=toks_block, tk=tk_block, lp=lp_block,
+            gen=list(self._slot_gen), active=active_snapshot, n=n,
+            moe=moe_block,
         )
+        self._track(entry)
+        for i in range(self.ecfg.max_slots):
+            if active_snapshot[i] and self._budget_covered(i):
+                self._park(i, entry)
         return True
+
+    # thread: engine-loop-only
+    def _budget_covered(self, i: int) -> bool:
+        """Will no block after the ones in flight carry a token of slot i's
+        request, whatever it generates? True when its budget (max_new_tokens,
+        or the end of the context) is scheduled in full and nothing but the
+        budget decides what the next block holds for it: a host-walk grammar
+        writes the slot's override for its next block, a speculative
+        engine's `scheduled` is a lower bound, a staged fork copies the live
+        slot's rows."""
+        s = self.slots[i]
+        if s is None or self._spec_mode != "off":
+            return False
+        if s.request.grammar is not None and not s.dfa:
+            return False
+        if (s.request.max_new_tokens - s.scheduled > 0
+                and self.ecfg.max_seq - s.prompt_len - s.scheduled > 0):
+            return False
+        if self._fork_requests:
+            with self._fork_lock:
+                if any(src is s.handle for src, _s, _h in self._fork_requests):
+                    return False
+        return True
+
+    # thread: engine-loop-only
+    def _park(self, i: int, last: _Entry) -> None:
+        """Hand slot index i on: its request's budget is covered by `last`,
+        the block just dispatched, so the rows of every later block are
+        free for the next request. What the index held moves to a _Parked
+        record on the request, found again by the generation `last` and the
+        earlier entries carry (_tenant); the index is left as _release
+        leaves it, and the next _admit_pending seats the queue's head there
+        with its admission program behind `last`. The device runs programs
+        in dispatch order and `last` shipped the table it was planned with,
+        so the old tenant's rows and the new one's prefill never meet."""
+        slot = self.slots[i]
+        will_save = self._saves_at_finish(slot)
+        self._settle_deferred_saves(i, will_save)
+        snap = None
+        if will_save and not self._paged:
+            # The finish-time save reads the cache row, which the next
+            # tenant's prefill overwrites: copy it now, behind `last`.
+            rows = slot.prompt_len + min(slot.scheduled,
+                                         slot.request.max_new_tokens) - 1
+            if rows >= self.ecfg.prefix_cache_min:
+                snap = self._snapshot_rows(i, min(rows, self.ecfg.max_seq))
+        slot.parked = _Parked(
+            idx=i, gen=self._slot_gen[i], last=last,
+            pages=self._slot_pages[i], tps=self._slot_tps[i],
+            spill=self._slot_spill[i], adapter_row=int(self.h_adapter[i]),
+            snap=snap,
+        )
+        self._parked[(i, self._slot_gen[i])] = slot
+        # The record owns them now; the index keeps nothing to free.
+        self._slot_pages[i] = []
+        self._slot_tps[i] = []
+        self._slot_spill[i] = {}
+        self.h_adapter[i] = 0
+        self._release(i)  # moves the index on to its next generation
+
+    # thread: engine-loop-only
+    def _tenant(self, i: int, gen: int) -> Optional[_Slot]:
+        """The request a dispatched entry carried in row i under generation
+        `gen`: the live one, a parked one, or None (ended, preempted)."""
+        if self._slot_gen[i] == gen:
+            return self.slots[i]
+        return self._parked.get((i, gen))
+
+    def _tenants(self) -> list[_Slot]:
+        """Every request the engine holds state for: the slots' live ones
+        and the parked ones (_park). A copy; any thread may ask."""
+        return [s for s in [*self.slots, *list(self._parked.values())]
+                if s is not None]
 
     def _spec_len_for(self, i: int, kmax: int) -> int:
         """EWMA-chosen draft length for one active slot (pure — probe
@@ -8258,7 +8406,7 @@ class Engine:
                         continue
                     consumed += 1
                     emitted_per[i] += 1
-                    self._note_decode_first(i)
+                    self._note_decode_first(i, self.slots[i].handle)
                     self._post_token(i, tok)
             self.m_spec_rounds += int((emitted_per > 0).sum())
             self.m_spec_accepted += consumed
@@ -8286,9 +8434,10 @@ class Engine:
             return
         if e.kind == "admit":
             for j, (slot_idx, request, handle, plen, _t0) in enumerate(e.items):
-                if self._slot_gen[slot_idx] != e.gen[slot_idx]:
-                    continue
-                slot = self.slots[slot_idx]
+                # A short budget is covered by the request's first block,
+                # which may be dispatched (and the request parked) before
+                # this admission has come back.
+                slot = self._tenant(slot_idx, e.gen[slot_idx])
                 if slot is None:
                     continue
                 tok = int(toks[j])
@@ -8320,16 +8469,18 @@ class Engine:
                     tr.note("resumed")
                 self.m_prompt_tokens += plen
                 lpj = (lp[0][j], lp[1][j], lp[2][j]) if lp is not None else None
-                self._post_token(slot_idx, tok, lpj)
+                self._post_token(slot_idx, tok, lpj, slot)
             return
 
         consumed = 0
         for step in range(e.n):
             self._phases.begin("process")  # slices a long phase's span
             for i in range(self.ecfg.max_slots):
-                if not e.active[i] or self._slot_gen[i] != e.gen[i]:
+                if not e.active[i]:
                     continue
-                slot = self.slots[i]
+                # The row's tokens go to the request of the entry's own
+                # generation: the live one, or one parked since (_park).
+                slot = self._tenant(i, e.gen[i])
                 if slot is None:
                     continue
                 tok = int(toks[step, i])
@@ -8349,8 +8500,14 @@ class Engine:
                     tok = chosen
                 consumed += 1
                 lpi = (lp[0][step, i], lp[1][step, i], lp[2][step, i]) if lp is not None else None
-                self._note_decode_first(i)
-                self._post_token(i, tok, lpi)
+                self._note_decode_first(i, slot.handle)
+                self._post_token(i, tok, lpi, slot)
+        for slot in [s for s in self._parked.values() if s.parked.last is e]:
+            # Cannot happen while `scheduled` counts a plain block's tokens
+            # exactly; a tenant left parked would never get its `done`.
+            log.error("parked tenant of slot %d outlived its last block",
+                      slot.parked.idx)
+            self._finish(slot.parked.idx, "length", slot)
         self._decode_tokens += consumed
         self._count_rows(e, consumed)
         if moe is not None:
@@ -8382,9 +8539,13 @@ class Engine:
         rows were computed; `posted` of them carried a token that a handle
         received; rows not live at dispatch were `empty`; the rest were
         live at dispatch and lost before their step (the request ended
-        inside the block, its slot changed generation, or a verify round
-        rejected the draft) — `overshoot`. dispatched = posted + overshoot
-        + empty, exactly."""
+        inside the block, it was preempted, or a verify round rejected the
+        draft) — `overshoot`. dispatched = posted + overshoot + empty,
+        exactly. A request that ends on its budget overshoots by the rest
+        of its last block only (its index is handed on when that block is
+        dispatched, _park: the rows after it are the next request's, or
+        `empty`); one that ends sooner, a host-walk grammar's and a
+        speculative engine's by up to pipeline_depth blocks more."""
         rows = e.n * self.ecfg.max_slots
         live = e.n * int(e.active.sum())
         self.m_rows_dispatched += rows
@@ -8398,11 +8559,10 @@ class Engine:
                     b=float(rows - live))
 
     # thread: engine-loop-only
-    def _note_decode_first(self, slot_idx: int) -> None:
+    def _note_decode_first(self, slot_idx: int, h: RequestHandle) -> None:
         """Journal, once per request, the first token it gets from a decode
         block: `first_token` -> `decode_first` is its wait to join the
         decode stream (blocks dispatched before its admission run first)."""
-        h = self.slots[slot_idx].handle
         if h.join_blocks < 0:
             return
         self._jnote("decode_first", rid=h.rid, slot=slot_idx,
@@ -8488,17 +8648,21 @@ class Engine:
     # Token bookkeeping / streaming
     # ------------------------------------------------------------------ #
 
-    def _post_token(self, slot_idx: int, tok: int, lp=None) -> None:
+    def _post_token(self, slot_idx: int, tok: int, lp=None,
+                    slot: Optional[_Slot] = None) -> None:
         """Append one generated token to a slot: stream text, check stops.
 
         lp, when present, is this step's (tok_lp scalar, lp_ids [LK],
-        lp_vals [LK]) from the decode/admit program.
+        lp_vals [LK]) from the decode/admit program. `slot` is the request
+        the token belongs to when that is not (or may not be) the index's
+        live one: a parked tenant (_tenant).
         """
-        slot = self.slots[slot_idx]
+        if slot is None:
+            slot = self.slots[slot_idx]
         assert slot is not None
         r, handle = slot.request, slot.handle
         if handle.cancelled.is_set():
-            self._finish(slot_idx, "stop")
+            self._finish(slot_idx, "stop", slot)
             return
 
         logprob = None
@@ -8589,23 +8753,42 @@ class Engine:
                 logprob=logprob, top_logprobs=top_logprobs,
             ))
         if finish is not None:
-            self._finish(slot_idx, finish)
+            self._finish(slot_idx, finish, slot)
 
-    def _finish(self, slot_idx: int, reason: str) -> None:
-        slot = self.slots[slot_idx]
-        assert slot is not None
-        will_save = (self._prefix_enabled and slot.request.image_embeds is None
-                     and slot.request.adapter is None)
+    def _saves_at_finish(self, slot: _Slot) -> bool:
+        """Does _finish store this request's prompt + generated rows as a
+        prefix span? Adapter rows are tenant-specific, image rows have no
+        token key."""
+        return (self._prefix_enabled and slot.request.image_embeds is None
+                and slot.request.adapter is None)
+
+    # thread: engine-loop-only
+    def _settle_deferred_saves(self, slot_idx: int, will_save: bool) -> None:
+        """Before a request leaves its slot index: the finish-time span
+        covers prompt + generated rows, a superset of any admission save
+        still waiting on the sidecar (ISSUE 17), so with one to come drop
+        the waiting save instead of paying its snapshot twice; without, run
+        it now, while the rows are still the index's."""
         if will_save:
-            # The finish-time span below covers prompt + generated rows, a
-            # superset of any admission save still parked on the sidecar
-            # (ISSUE 17) — drop the parked one instead of paying its
-            # snapshot twice.
             self._deferred_saves = [
                 x for x in self._deferred_saves if x[0] != slot_idx
             ]
         else:
             self._flush_deferred_saves(slot_idx)
+
+    def _finish(self, slot_idx: int, reason: str,
+                slot: Optional[_Slot] = None) -> None:
+        """Post the request's `done`, take its finish-time prefix save and
+        release what it holds: the slot index, or for a parked tenant
+        (`slot.parked`, see _park) the record's pages and pin, the index
+        being another request's already."""
+        if slot is None:
+            slot = self.slots[slot_idx]
+        assert slot is not None
+        parked = slot.parked
+        will_save = self._saves_at_finish(slot)
+        if parked is None:
+            self._settle_deferred_saves(slot_idx, will_save)
         if will_save:
             # Rows for prompt + all but the last generated token are
             # guaranteed written (a token's KV row lands when it is consumed
@@ -8619,6 +8802,7 @@ class Engine:
                 valid,
                 min_extend=(0 if valid > slot.prompt_len
                             else self.ecfg.prefix_cache_min),
+                parked=parked,
             )
         now = time.monotonic()
         t_first = slot.t_first or now
@@ -8628,6 +8812,11 @@ class Engine:
             queue_wait = h.t_admit - h.t_submit
         self._jnote("terminal", rid=h.rid, slot=slot_idx,
                     a=float(len(slot.generated)))
+        # Had the index been handed on before this `done`? (_park)
+        self.m_slots_released += 1
+        self.m_slots_released_early += parked is not None
+        self._jnote("slot_turnover", rid=h.rid, slot=slot_idx,
+                    a=float(parked is not None), b=1.0)
         h._q.put(
             TokenEvent(
                 kind="done",
@@ -8639,7 +8828,24 @@ class Engine:
                 timing_queue_wait=queue_wait,
             )
         )
-        self._release(slot_idx)
+        if parked is None:
+            self._release(slot_idx)
+        else:
+            self._release_parked(slot)
+
+    # thread: engine-loop-only
+    def _release_parked(self, slot: _Slot) -> None:
+        """Give back what a parked tenant's record holds (_park). Pages are
+        freed, so a staged block plan is rebuilt, as in _release."""
+        parked = slot.parked
+        if self._parked.pop((parked.idx, parked.gen), None) is None:
+            return  # released already
+        self._plan_dirty()
+        self._adapter_unpin(parked.adapter_row)
+        self._pages_release(parked.pages)
+        if parked.spill:
+            self._spill_bytes -= len(parked.spill) * self._page_bytes()
+        self._tp_release(parked.tps)
 
     def _release(self, slot_idx: int) -> None:
         # Membership changed — and for paged engines the teardown below
@@ -8647,6 +8853,11 @@ class Engine:
         # growth included) must be rebuilt (ISSUE 17).
         self._plan_dirty()
         self.slots[slot_idx] = None
+        # An (index, generation) names one tenancy: what is still in flight
+        # for this one finds nobody (_tenant), whoever is seated here next
+        # and however (a chunked admission claims the index chunks before
+        # the program that activates it).
+        self._slot_gen[slot_idx] += 1
         # A chunked prefill whose slot is being torn down (dispatch failure,
         # stop) must not keep dispatching chunks into a freed slot.
         self._chunkings = [

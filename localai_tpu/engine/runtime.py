@@ -92,8 +92,12 @@ class ControlStager:
 
     def commit(self, key: str, host: np.ndarray, build=None):
         """Device value for `host`, reusing the previous upload when the
-        bytes are unchanged. `host` is copied on upload — callers keep
-        ownership and may mutate their array freely afterwards."""
+        bytes are unchanged. What is uploaded is a private copy of `host`
+        (the cache's own), so callers keep ownership and may mutate their
+        array freely afterwards: `jnp.asarray` of a 64-byte-aligned numpy
+        array is zero-copy on the CPU backend, and an upload may still be
+        in flight elsewhere, while the engine rewrites a slot's table row
+        the moment the block that shipped it is dispatched (Engine._park)."""
         self.commits += 1
         ent = self._cache.get(key)
         if (ent is not None and ent.host.shape == host.shape
@@ -112,9 +116,10 @@ class ControlStager:
                 self._cache[key] = _CtrlEntry(host.copy(), dev, out)
                 self.row_uploads += 1
                 return out
-        dev = jnp.asarray(host)
+        kept = host.copy()
+        dev = jnp.asarray(kept)
         out = build(dev) if build is not None else dev
-        self._cache[key] = _CtrlEntry(host.copy(), dev, out)
+        self._cache[key] = _CtrlEntry(kept, dev, out)
         self.uploads += 1
         return out
 

@@ -95,6 +95,9 @@ BASE_EVENTS = (
     "moe_load",      # the same block's load (a=sum over step and layer of
     #                  the busiest expert's rows, b=sum of the mean rows per
     #                  expert: compiled rows x top-k / experts)
+    "slot_turnover", # one per `terminal` (rid, slot; a=1 when the slot index
+    #                  had been handed on before the request's `done` was
+    #                  posted, Engine._park, else 0; b=1)
 )
 
 # One journal event type per fault-injection site (faults.SITES), checked

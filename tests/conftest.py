@@ -52,6 +52,7 @@ _COSTLY_FIRST = (
     "test_model_llama.py",  # 55 + the entry-point matrix, about 100
     "test_diffusion_schedulers.py",  # about 150: 20 compiles
     "test_engine_runtime.py",  # 136
+    "test_early_release.py",  # 128 (PR 29)
     "test_robustness.py",  # 106
     "test_olmoe.py",  # 104
     "test_video_diffusion.py",  # 103

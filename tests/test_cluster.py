@@ -435,8 +435,8 @@ def test_replica_death_mid_stream_reroutes_with_terminal_events(tiny):
         # nothing live reroutes nothing.
         loop_idents = {
             r.engine._thread.ident for r in replicas
-            if any(s is not None and len(s.generated) <= n_new - 8
-                   for s in r.engine.slots)
+            if any(len(s.generated) <= n_new - 8
+                   for s in r.engine._tenants())
         }
         assert loop_idents, "no replica mid-stream at fault activation"
         with faults.active(faults.FaultSchedule(

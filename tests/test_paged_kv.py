@@ -254,13 +254,16 @@ def _mk_engine_cfg(**kw):
 
 def _check_pool_invariants(eng):
     """Allocator ground truth: refcounts match the references actually
-    held (slot tables + prefix spans), no page is both free and
-    referenced, no duplicates on the free list, no page leaked. Covers the
-    hierarchical table (L1 directory refcounts, table-page sharing) and
-    the cold-spill accounting when those features are on (ISSUE 14)."""
+    held (slot tables + parked tenants + prefix spans), no page is both
+    free and referenced, no duplicates on the free list, no page leaked.
+    Covers the hierarchical table (L1 directory refcounts, table-page
+    sharing) and the cold-spill accounting when those features are on
+    (ISSUE 14). A parked tenant (Engine._park, ISSUE 29) holds its pages,
+    directory and spill images on its own record."""
     P = eng.ecfg.kv_pages
+    parked = [s.parked for s in eng._parked.values()]
     refs = np.zeros(P, np.int64)
-    for pages in eng._slot_pages:
+    for pages in [*eng._slot_pages, *(pk.pages for pk in parked)]:
         for p in pages:
             if p >= 0:  # SPILLED sentinels own no device page
                 refs[p] += 1
@@ -279,7 +282,7 @@ def _check_pool_invariants(eng):
         # (slot directories + prefix entry tps), free/held partition clean.
         NT = len(eng._tp_refs) - 1
         trefs = np.zeros(NT + 1, np.int64)
-        for tps in eng._slot_tps:
+        for tps in [*eng._slot_tps, *(pk.tps for pk in parked)]:
             for tp in tps:
                 trefs[tp] += 1
         for e in eng._prefix_entries:
@@ -316,7 +319,8 @@ def _check_pool_invariants(eng):
             assert row <= hot | {eng._scratch_page}, (
                 f"slot {i} table points at foreign pages")
     # Cold-spill accounting: bytes tracked == images held, within budget.
-    n_spilled = sum(len(d) for d in eng._slot_spill)
+    n_spilled = sum(len(d) for d in [*eng._slot_spill,
+                                     *(pk.spill for pk in parked)])
     assert eng._spill_bytes == n_spilled * eng._page_bytes(), (
         eng._spill_bytes, n_spilled)
     assert eng._spill_bytes <= max(eng.ecfg.kv_spill_bytes, 0)

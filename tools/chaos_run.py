@@ -215,8 +215,8 @@ def kill_mid_decode(seed=99):
         handles, firsts = _submit_streams(client, n_req, n_new)
         loop_idents = {
             r.engine._thread.ident for r in replicas
-            if any(s is not None and len(s.generated) <= n_new - 8
-                   for s in r.engine.slots)
+            if any(len(s.generated) <= n_new - 8
+                   for s in r.engine._tenants())
         }
         assert loop_idents, "no replica mid-stream at fault activation"
         script = faults.ChaosScript(seed=seed, threads=loop_idents, phases=[
@@ -434,7 +434,7 @@ def grammar_replay(seed=0):
         assert first.kind == "token", first
         # Exactly one engine is serving it — kill that loop.
         serving = [r for r in replicas
-                   if any(s is not None for s in r.engine.slots)]
+                   if r.engine._tenants()]
         assert serving, "request not live on any replica"
         idents = {r.engine._thread.ident for r in serving}
         script = faults.ChaosScript(seed=seed + 99, threads=idents, phases=[
