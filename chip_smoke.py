@@ -69,6 +69,11 @@ SIZES = {
         chunk=512, verify=6, lora_rank=16, flash_lens=(32, 512, 2048), tp=4,
         # olmoe-1b-7b's expert stack as published: layers, experts, widths
         moe=dict(layers=16, experts=64, hidden=2048, ffn=1024),
+        # kimi-linear-48b-a3b as published: KDA layers, slots, heads, head
+        # width; MLA's padded latent row and heads; chip 0's 32 held experts
+        hybrid=dict(kda_layers=20, slots=64, heads=32, dk=128, latent=640,
+                    mla_layers=7, mla_heads=32, pages=513,
+                    moe=dict(layers=26, experts=32, hidden=2304, ffn=1024)),
     ),
     "tiny": dict(
         arch="tiny", slots=4, context=512, page=16,
@@ -77,6 +82,9 @@ SIZES = {
         chunk=32, verify=3, lora_rank=4, flash_lens=(32,),
         tp=2,  # the tiny preset has two kv heads
         moe=dict(layers=2, experts=4, hidden=64, ffn=32),
+        hybrid=dict(kda_layers=3, slots=4, heads=4, dk=16, latent=64,
+                    mla_layers=2, mla_heads=4, pages=33,
+                    moe=dict(layers=2, experts=4, hidden=64, ffn=32)),
     ),
 }
 
@@ -335,6 +343,79 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
          (rnd((32, mo["hidden"])), expert_stack(mo["hidden"], mo["ffn"]),
           expert_stack(mo["ffn"], mo["hidden"]),
           jnp.int32(mo["layers"] // 3), jnp.int32(mo["layers"] - 1)), 2e-2)
+    # -- the hybrid model's kernels (kimi-linear-48b-a3b's shapes) -----------
+    hy = s["hybrid"]
+    from localai_tpu.ops import kda as KDA
+
+    # KDA decode on the stacked float32 state, first and last KDA layer, the
+    # layer a scalar-prefetch operand, the state aliased: against the XLA
+    # form (slice, update, put back). Elementwise float32 both sides; the
+    # sums over dk run in another order -> 1e-4.
+    Lk, Bk, Hk, dk = hy["kda_layers"], hy["slots"], hy["heads"], hy["dk"]
+
+    def unit(x):
+        x = x.astype(jnp.float32)
+        return x / jnp.sqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+    def kda_two(impl):
+        def fn(state, q, k, v, g, beta, first, last):
+            outs = []
+            for i in (first, last):
+                o, state = KDA.kda_decode(state, i, q, k, v, g, beta, impl=impl)
+                outs.append(o)
+            return tuple(outs) + (state[first], state[last])
+        return fn
+
+    kda_args = (
+        rnd((Lk, Bk, Hk, dk, dk), jnp.float32),
+        unit(rnd((Bk, Hk, dk))) * dk ** -0.5, unit(rnd((Bk, Hk, dk))),
+        rnd((Bk, Hk, dk), jnp.float32),
+        -jnp.exp(rnd((Bk, Hk, dk), jnp.float32) * 2.0 - 3.0),
+        jax.nn.sigmoid(rnd((Bk, Hk), jnp.float32)),
+        jnp.int32(0), jnp.int32(Lk - 1))
+    case("kda_decode_stacked", kda_two("auto"), kda_two("xla"), kda_args, 1e-4)
+
+    # MLA's absorbed decode over the latent pool stacked over the MLA layers
+    # ([L, P, page, 1, 640]: one row a token, key and value): the latent
+    # kernel against the XLA walk. Same arithmetic as paged_decode -> 5e-3.
+    Lm, Hm, W = hy["mla_layers"], hy["mla_heads"], hy["latent"]
+    lat_pool = rnd((Lm, hy["pages"], page, 1, W))
+    lat_pages = (hy["pages"] - 1) // Bk
+    lat_table = (jax.random.permutation(next(keys), hy["pages"] - 1)[
+        : Bk * lat_pages] + 1).reshape(Bk, lat_pages).astype(jnp.int32)
+    lat_limits = jnp.array(
+        [(i * 37 + 11) % (lat_pages * page - page) + 1 for i in range(Bk)],
+        jnp.int32).at[0].set(0).at[1].set(lat_pages * page - 1)
+
+    def latent(impl):
+        def fn(q, pool, t, lim, first, last):
+            out = []
+            for i in (first, last):
+                c = Q.StackedLayer(pool, i)
+                out.append(settled(A.paged_partials(
+                    q, c, c, t, lim, impl=impl, latent=True)))
+            return tuple(out)
+        return fn
+
+    case("latent_paged_decode_stacked", latent("auto"), latent("xla"),
+         (rnd((Bk, Hm, W)), lat_pool, lat_table, lat_limits,
+          jnp.int32(0), jnp.int32(Lm - 1)), 5e-3)
+
+    # The held experts' stacks [26 x 32, 2304, 1024] at 64 rows through the
+    # same kernel and `_tile` rule as olmoe's [16 x 64, 2048, 1024].
+    hm = hy["moe"]
+
+    def held_stack(kin, kout):
+        return jax.jit(lambda kk: jax.lax.map(
+            lambda k1: Q.quantize_tensor(jax.random.normal(
+                k1, (hm["experts"], kin, kout), jnp.float32) * 0.02),
+            jax.random.split(kk, hm["layers"])))(next(keys))
+
+    case("moe_int8_held_experts_stacked", experts("auto"), experts("xla"),
+         (rnd((Bk, hm["hidden"])), held_stack(hm["hidden"], hm["ffn"]),
+          held_stack(hm["ffn"], hm["hidden"]),
+          jnp.int32(hm["layers"] // 3), jnp.int32(hm["layers"] - 1)), 2e-2)
+
     head = rnd((s["vocab"], hid), jnp.float32, 0.02)
     hs = jnp.maximum(jnp.max(jnp.abs(head), axis=-1, keepdims=True) / 127.0,
                      1e-9)

@@ -118,6 +118,12 @@ class ModelConfig:
     # context instead of max_slots × context_size. 0 = dense cache.
     kv_pages: int = 0
     kv_page_size: int = 128
+    # The deployment's expert share of a MoE model: [index, of]. This process
+    # holds experts [index·E/of, (index+1)·E/of) of every MoE layer, routes
+    # over all E and returns its held experts' part of the layer's sum (plus
+    # the shared expert); the exchange with the other `of - 1` holders is the
+    # deployment's, not this process's. Absent = every expert.
+    expert_share: Optional[list] = None
     # On-demand KV page growth (docs/PAGED_ATTENTION.md): admission
     # reserves only the prompt's pages + this headroom; decode grows the
     # table as the context actually extends. LOCALAI_KV_PAGE_HEADROOM

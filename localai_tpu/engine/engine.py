@@ -65,6 +65,7 @@ import numpy as np
 
 from localai_tpu.models import llama
 from localai_tpu.engine import speclookup
+from localai_tpu.engine import state as rstate
 from localai_tpu.engine.runtime import (
     SPAN_SLICE_S,
     ControlStager,
@@ -1097,6 +1098,10 @@ class Engine:
                 "bucket >= 1"
             )
         self._spec_buckets = tuple(bl)
+        if cfg.is_hybrid:
+            # The recurrent state is engine/state.py's business: what this
+            # engine cannot run with it is refused here, by name.
+            rstate.refuse(cfg, self.ecfg, self.plan, draft_cfg, mode)
         if self.ecfg.attention_window and (
             mode != "off" or draft_cfg is not None
         ):
@@ -1176,12 +1181,18 @@ class Engine:
                     k=jax.device_put(pool.k, pool_shard),
                     v=jax.device_put(pool.v, pool_shard),
                 )
+                if cfg.is_hybrid:
+                    # One row a slot index, beside the pages (engine/state.py)
+                    st, cv = rstate.allocate(
+                        cfg, B, jnp.dtype(cfg.dtype),
+                        NamedSharding(self.mesh, P()))
+                    self.cache = self.cache._replace(state=st, conv=cv)
             else:
                 kshard, vshard = cache_shardings(
                     self.mesh, self.plan.sp, cfg.is_mla
                 )
                 cache_dt = self.ecfg.cache_dtype(cfg.dtype)
-                base = (cfg.num_layers, B, S, cfg.cache_kv_heads)
+                base = (cfg.cache_layers, B, S, cfg.cache_kv_heads)
                 self.cache = llama.KVCache(
                     k=jax.device_put(
                         jnp.zeros(base + (cfg.cache_k_dim,), cache_dt), kshard
@@ -1539,6 +1550,9 @@ class Engine:
             if self.ecfg.trace_journal_events > 0 else None
         )
         self._postmortem_path = ""
+        if cfg.is_hybrid and self.ecfg.prefix_cache_entries > 0:
+            self._jstage("prefix_reuse_off",
+                         a=float(self.ecfg.prefix_cache_entries))
         # Pipelined loop runtime (ISSUE 17, docs/ENGINE_RUNTIME.md).
         # thread: single-writer engine-loop — the control stager's cache
         # and counters are loop-thread state; bench/tests read the
@@ -1578,6 +1592,9 @@ class Engine:
         self.m_slots_released_early = 0
         # Decode-block routing of a MoE model, see _count_routing.
         self.m_moe_slots = 0
+        self.m_moe_picks = 0  # under an expert share: the router's picks,
+        self.m_moe_picks_here = 0  # and those of an expert held here
+        self.m_state_restores = 0  # recurrent-state rows recomputed (preempt)
         self.m_moe_slots_hit = 0
         self.m_moe_rows_busiest = 0
         self.m_moe_rows_mean = 0.0
@@ -2402,7 +2419,13 @@ class Engine:
         n_live = min(-(-ctx_rows // page), len(self._slot_pages[victim]))
         span_bytes = n_live * self._page_bytes()
         policy = self.ecfg.kv_preempt
-        if self.draft_cfg is not None:
+        if self.cfg.is_hybrid:
+            # No image of the recurrent state is taken: the row is dropped
+            # and the re-admission recomputes it from prompt + generated
+            # (engine/state.py: preempt).
+            policy = "recompute"
+            self.m_state_restores += 1
+        elif self.draft_cfg is not None:
             # Only the SEPARATE draft checkpoint forces recompute (its
             # dense KV has no swap image). Model-free spec slots swap
             # byte-exactly: prompt_lookup keeps no device draft state at
@@ -2653,9 +2676,11 @@ class Engine:
                 "self_draft) serves adapter tenants"
             )
         if self.cfg.is_mla or self.cfg.is_moe:
+            kind = ("hybrid KDA/MLA" if self.cfg.is_hybrid
+                    else "MLA" if self.cfg.is_mla else "MoE")
             raise AdapterError(
                 f"runtime LoRA adapters serve dense llama-family bases only "
-                f"({self.cfg.name} is {'MLA' if self.cfg.is_mla else 'MoE'}) "
+                f"({self.cfg.name} is {kind}) "
                 "— merge at load via `lora_adapters` instead"
             )
         with self._adapter_lock:
@@ -2997,16 +3022,22 @@ class Engine:
             ldt_k = cache.k.dtype if self._kv_scales is None else jnp.dtype(cfg.dtype)
             ldt_v = cache.v.dtype if self._kv_scales is None else jnp.dtype(cfg.dtype)
             local_k = jnp.zeros(
-                (cfg.num_layers, B, n, cfg.cache_kv_heads, cfg.cache_k_dim),
+                (cfg.cache_layers, B, n, cfg.cache_kv_heads, cfg.cache_k_dim),
                 ldt_k,
             )
             local_v = jnp.zeros(
-                (cfg.num_layers, B, n, cfg.cache_kv_heads, cfg.cache_v_dim),
+                (cfg.cache_layers, B, n, cfg.cache_kv_heads, cfg.cache_v_dim),
                 ldt_v,
             )
+            # A hybrid model's recurrent state is carried by the steps (each
+            # updates every row in place) while the pool stays read-only.
+            rec0 = (cache.state, cache.conv) if cfg.is_hybrid else None
+            if rec0 is not None:
+                cache = cache._replace(state=None, conv=None)
 
             def body(carry, step):
-                tokens, positions, counts, rngs, lk, lv, gs = carry
+                tokens, positions, counts, rngs, lk, lv, gs, rec = carry
+                hyb = {} if rec is None else {"recurrent": rec}
                 if paged:
                     # Idle/released slots' positions keep ratcheting toward
                     # S-1 (the carry advances every slot); left unmasked
@@ -3020,7 +3051,7 @@ class Engine:
                         paged_impl=self.ecfg.paged_kernel,
                         kv_scale=self._kv_scales,
                         rope_delta=rope_delta, mesh=self._op_mesh,
-                        lora=lora, expert_rows=cfg.is_moe,
+                        lora=lora, expert_rows=cfg.is_moe, **hyb,
                     )
                 else:
                     logits, lk, lv, *routed = llama.decode_step_windowed(
@@ -3029,6 +3060,8 @@ class Engine:
                         rope_delta=rope_delta, lora=lora,
                         expert_rows=cfg.is_moe,
                     )
+                if rec is not None:
+                    rec = routed.pop()
                 split = jax.vmap(lambda k: jax.random.split(k, 2))(rngs)
                 rngs, draw = split[:, 0], split[:, 1]
                 if with_dfa:
@@ -3067,15 +3100,18 @@ class Engine:
                 # got a row, and the busiest expert's rows summed over layers.
                 per = routed[0] if routed else None
                 moe = (None if per is None else
-                       jnp.stack([(per > 0).sum(), per.max(-1).sum()]))
+                       jnp.stack([(per > 0).sum(), per.max(-1).sum()]
+                                 # an expert share: the picks that landed here
+                                 + ([per.sum()] if cfg.expert_share else [])))
                 # Clamp so idle/overshooting slots keep writing inside their
                 # own cache row instead of out-of-bounds.
                 positions = jnp.minimum(positions + 1, S - 1)
-                return (nxt, positions, counts, rngs, lk, lv, gs), (out, moe)
+                return (nxt, positions, counts, rngs, lk, lv, gs, rec), (out, moe)
 
             gs0 = gstate if with_dfa else jnp.zeros((B,), jnp.int32)
-            (tokens, positions, counts, rngs, local_k, local_v, gs), (outs, moe) = jax.lax.scan(
-                body, (tokens, positions, counts, rngs, local_k, local_v, gs0),
+            (tokens, positions, counts, rngs, local_k, local_v, gs, rec), (outs, moe) = jax.lax.scan(
+                body,
+                (tokens, positions, counts, rngs, local_k, local_v, gs0, rec0),
                 jnp.arange(n),
             )
             if paged:
@@ -3085,10 +3121,13 @@ class Engine:
                 )
             else:
                 cache = llama.write_block_to_cache(cache, local_k, local_v, start_pos)
+            if rec is not None:
+                cache = cache._replace(state=rec[0], conv=rec[1])
             toks_block = outs[0]  # [n, B]
             tk_block = outs[1] if variant == "grammar" else None
             lp_block = tuple(outs[-3:]) if with_lp else None  # ([n,B],[n,B,LK],[n,B,LK])
-            # [2] i32 over the block's steps, MoE models only (_count_routing)
+            # [2] i32 over the block's steps ([3] under an expert share), MoE
+            # models only (_count_routing)
             moe_block = None if moe is None else moe.sum(0)
             out = (cache, counts, rngs, tokens, positions, toks_block, tk_block,
                    lp_block, moe_block)
@@ -3178,10 +3217,19 @@ class Engine:
                 presence_penalty=samp_pack[5], frequency_penalty=samp_pack[6],
             )
             inject = (img_embeds, img_offsets) if img_embeds is not None else None
-            logits, ks, vs = llama.prefill(
-                cfg, params, prompt_toks, lens, mesh=self._op_mesh,
-                inject=inject, ep=self.plan.ep, mrope=mrope_pos, lora=lora,
-            )
+            if cfg.is_hybrid:
+                # Each prompt's recurrent state is written to its slot's row
+                # layer by layer inside the prefill (engine/state.py: claim).
+                logits, ks, vs, (st, cv) = llama.prefill(
+                    cfg, params, prompt_toks, lens, ep=self.plan.ep,
+                    recurrent=(cache.state, cache.conv, slot_ids),
+                )
+                cache = cache._replace(state=st, conv=cv)
+            else:
+                logits, ks, vs = llama.prefill(
+                    cfg, params, prompt_toks, lens, mesh=self._op_mesh,
+                    inject=inject, ep=self.plan.ep, mrope=mrope_pos, lora=lora,
+                )
             valid = (jnp.arange(bucket)[None, :] < lens[:, None]).astype(jnp.int32)
             rows = jnp.zeros((m, V), jnp.int32)
             rows = rows.at[jnp.arange(m)[:, None], prompt_toks].add(valid)
@@ -4333,8 +4381,8 @@ class Engine:
         caches, multimodal and resume requests always clone."""
         if not (self._paged and self.ecfg.fork_sampling):
             return False
-        if self.draft_cfg is not None:
-            return False
+        if self.draft_cfg is not None or self.cfg.is_hybrid:
+            return False  # a hybrid model's branches clone: no state copy
         r0 = requests[0]
         b0 = r0.logit_bias or {}
         g0 = r0.grammar is not None
@@ -4660,6 +4708,11 @@ class Engine:
         equivalent of an in-flight RNG chain. Thread-safe."""
         if n < 1:
             raise ValueError("fork n must be >= 1")
+        if self.cfg.is_hybrid:
+            raise ValueError(
+                f"{self.cfg.name} keeps a per-slot recurrent state (KDA "
+                "layers): forking a live stream would need a copy of the "
+                "source's state row, which this engine does not make")
         if seeds is not None and len(seeds) != n:
             raise ValueError(f"fork got {len(seeds)} seeds for n={n}")
         out = []
@@ -4840,7 +4893,9 @@ class Engine:
         # prefills the DRAFT with the full prompt (its small cache has no
         # span to reuse) while the target still skips its prefix compute —
         # llama.cpp serves cache_prompt + draft together (grpc-server.cpp:125).
-        return self.ecfg.prefix_cache_entries > 0
+        # A hybrid model's prefix would need a snapshot of the recurrent
+        # state at the span's end: reuse is off for it (engine/state.py).
+        return self.ecfg.prefix_cache_entries > 0 and not self.cfg.is_hybrid
 
     def _cached_admit_ok(self, request: GenRequest) -> bool:
         """Whether this request may admit through the prefix-cache shortcut.
@@ -5153,7 +5208,7 @@ class Engine:
         configured capacity."""
         cfg = self.cfg
         return (
-            cfg.num_layers * pb * cfg.cache_kv_heads
+            cfg.cache_layers * pb * cfg.cache_kv_heads
             * (cfg.cache_k_dim + cfg.cache_v_dim)
             * jnp.dtype(self.ecfg.cache_dtype(cfg.dtype)).itemsize
         )
@@ -6224,6 +6279,18 @@ class Engine:
             out["moe_expert_slots_hit"] = float(self.m_moe_slots_hit)
             out["moe_rows_busiest"] = float(self.m_moe_rows_busiest)
             out["moe_rows_mean"] = float(self.m_moe_rows_mean)
+            if self.cfg.expert_share is not None:
+                out["moe_picks"] = float(self.m_moe_picks)
+                out["moe_picks_here"] = float(self.m_moe_picks_here)
+        if self.cfg.is_hybrid:
+            # The second kind of per-slot state (engine/state.py).
+            out["recurrent_state_bytes"] = float(
+                self.ecfg.max_slots * rstate.row_bytes(
+                    self.cfg, self.cache.conv.dtype))
+            out["state_snapshots"] = 0.0  # rows are dropped, never copied
+            out["state_restores"] = float(self.m_state_restores)
+            out["prefix_reuse_off"] = float(
+                self.ecfg.prefix_cache_entries > 0)
         # Call sites over every program traced so far: the Pallas kernel read
         # its layer out of the stacked operand (weights; the paged K/V pool),
         # or the layer was sliced out first (ops/stacked.SiteCounts).
@@ -7387,9 +7454,13 @@ class Engine:
             # fixed set of M values.
             chunks: list[list[tuple[GenRequest, RequestHandle]]] = [[gh] for gh in special]
             idx = 0
+            # A hybrid model's admission holds its prompts' KDA operands in
+            # float32, every request's at once: bounded rows a program.
+            m_max = (max(1, rstate.ADMIT_ROWS // bucket) if self.cfg.is_hybrid
+                     else len(plain))
             while idx < len(plain):
                 m = 1
-                while m * 2 <= len(plain) - idx:
+                while m * 2 <= min(len(plain) - idx, m_max):
                     m *= 2
                 chunks.append(plain[idx: idx + m])
                 idx += m
@@ -8523,9 +8594,15 @@ class Engine:
         all-experts kernel computes them all."""
         cfg = self.cfg
         layers = cfg.num_layers - cfg.first_k_dense
-        slots = e.n * layers * cfg.num_experts
-        mean = (e.n * layers * self.ecfg.max_slots
-                * cfg.num_experts_per_token / cfg.num_experts)
+        slots = e.n * layers * cfg.experts_here
+        picks = (e.n * layers * self.ecfg.max_slots
+                 * cfg.num_experts_per_token)
+        mean = picks / cfg.num_experts
+        if cfg.expert_share is not None:
+            # Of the picks the router made, those of an expert held here.
+            self.m_moe_picks += picks
+            self.m_moe_picks_here += int(sums[2])
+            self._jnote("moe_here", a=float(picks), b=float(sums[2]))
         self.m_moe_slots += slots
         self.m_moe_slots_hit += int(sums[0])
         self.m_moe_rows_busiest += int(sums[1])
@@ -8557,6 +8634,11 @@ class Engine:
         self._jnote("decode_rows", a=float(rows), b=float(posted))
         self._jnote("decode_rows_lost", a=float(live - posted),
                     b=float(rows - live))
+        if self.cfg.is_hybrid:
+            # Every row of the recurrent state is updated every step
+            # (a, over the KDA layers); b of them belonged to a tenant.
+            kl = len(self.cfg.kda_layers)
+            self._jnote("state_rows", a=float(rows * kl), b=float(live * kl))
 
     # thread: engine-loop-only
     def _note_decode_first(self, slot_idx: int, h: RequestHandle) -> None:

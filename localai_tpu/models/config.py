@@ -164,6 +164,64 @@ class ArchConfig:
     # `dataclasses.replace(cfg, ...)`. 0/0 = full attention (default).
     attention_sink: int = 0
     attention_window: int = 0
+    # Hybrid linear attention (Kimi-Linear, arXiv:2510.26692): one kind per
+    # layer, "kda" (Kimi Delta Attention: a per-slot recurrent state
+    # [kda_heads, kda_head_dim, kda_head_dim] f32 plus the short conv's last
+    # kda_conv-1 inputs, no cache rows) or "mla" (latent rows in the KV
+    # cache). Empty = every layer is the attention `kv_lora_rank` says.
+    # models/llama._scan_hybrid needs every "mla" layer to follow a "kda"
+    # layer and the dense-prefix layers to be "kda".
+    layer_kinds: tuple = ()
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv: int = 4  # short_conv_kernel_size
+    kda_gate_rank: int = 0  # low-rank width of the decay and output gates
+    # MLA whose rope dims are never rotated (Kimi-Linear's `mla_use_nope`).
+    mla_rope: bool = True
+    # Latent cache rows are stored padded to a multiple of this many values
+    # (0 = as they are): the paged kernel DMAs whole lane tiles.
+    latent_pad: int = 0
+    # The expert share this process holds, (index, of): the router scores
+    # all `num_experts`, the expert stacks hold experts
+    # [index·E/of, (index+1)·E/of) and the layer returns their part of the
+    # sum (plus the shared expert). None = every expert (models' YAML key
+    # `expert_share`, the deployment's, not the model's).
+    expert_share: Optional[tuple] = None
+
+    @property
+    def is_hybrid(self) -> bool:
+        return bool(self.layer_kinds)
+
+    @property
+    def kda_layers(self) -> tuple:
+        """Model layer numbers of the KDA layers, in order."""
+        return tuple(i for i, k in enumerate(self.layer_kinds) if k == "kda")
+
+    @property
+    def cache_layer_ids(self) -> tuple:
+        """Model layer numbers of the layers that write cache rows."""
+        if not self.layer_kinds:
+            return tuple(range(self.num_layers))
+        return tuple(i for i, k in enumerate(self.layer_kinds) if k != "kda")
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers the KV cache (dense or paged) holds rows for."""
+        return len(self.cache_layer_ids)
+
+    @property
+    def experts_here(self) -> int:
+        """Routed experts whose weights this process holds, per layer."""
+        if self.expert_share is None:
+            return self.num_experts
+        return self.num_experts // int(self.expert_share[1])
+
+    @property
+    def expert_lo(self) -> int:
+        """Id of the first routed expert held here."""
+        if self.expert_share is None:
+            return 0
+        return int(self.expert_share[0]) * self.experts_here
 
     @property
     def head_dim_(self) -> int:
@@ -193,7 +251,10 @@ class ArchConfig:
 
     @property
     def cache_k_dim(self) -> int:
-        return self.kv_lora_rank + self.qk_rope_head_dim if self.is_mla else self.head_dim_
+        if not self.is_mla:
+            return self.head_dim_
+        w = self.kv_lora_rank + self.qk_rope_head_dim
+        return -(-w // self.latent_pad) * self.latent_pad if self.latent_pad else w
 
     @property
     def cache_v_dim(self) -> int:
@@ -281,6 +342,44 @@ PRESETS: dict[str, ArchConfig] = {
         qk_nope_head_dim=24,
         qk_rope_head_dim=16,
         v_head_dim=24,
+    ),
+    "tiny-kimi-linear": ArchConfig(
+        # Kimi-Linear-shaped tiny: 3 KDA : 1 MLA with the pattern's ragged
+        # end (MLA at layers 3 and 6 of 0..6), NoPE MLA without q-lora, one
+        # dense layer, sigmoid router with correction bias in one group,
+        # renormalised and scaled, a shared expert, untied head, padded
+        # latent rows.
+        name="tiny-kimi-linear",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=7,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        max_position=512,
+        layer_kinds=("kda", "kda", "kda", "mla", "kda", "kda", "mla"),
+        kda_heads=4,
+        kda_head_dim=16,
+        kda_conv=4,
+        kda_gate_rank=16,
+        mla_rope=False,
+        latent_pad=64,
+        moe_family="deepseek",
+        num_experts=16,
+        num_experts_per_token=4,
+        first_k_dense=1,
+        n_shared_experts=1,
+        moe_intermediate_size=32,
+        routed_scaling_factor=2.446,
+        scoring_func="sigmoid",
+        router_bias=True,
+        norm_topk_prob=True,
+        kv_lora_rank=32,
+        q_lora_rank=None,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=16,
+        v_head_dim=16,
     ),
     "llama-3.2-1b": ArchConfig(
         name="llama-3.2-1b",
@@ -425,6 +524,52 @@ PRESETS: dict[str, ArchConfig] = {
         rope_interleave=True,
         kv_lora_rank=512,
         q_lora_rank=1536,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+    ),
+    "kimi-linear-48b-a3b": ArchConfig(
+        # moonshotai/Kimi-Linear-48B-A3B-Instruct config.json (arXiv:
+        # 2510.26692): 27 layers, 20 KDA : 7 MLA (MLA at layers 4, 8, ...,
+        # 24, 27 counted from 1), 32 heads of 128 in both kinds, MLA without
+        # q-lora and without rotation (mla_use_nope), layer 1 a dense SwiGLU
+        # of 9216, layers 2-27 256 routed experts of 1024 top-8 (sigmoid,
+        # correction bias, one group, renormalised, x2.446) plus one shared
+        # expert. `head_dim` 72 is the published key (2304 / 32); neither
+        # attention kind uses it.
+        name="kimi-linear-48b-a3b",
+        vocab_size=163840,
+        hidden_size=2304,
+        intermediate_size=9216,
+        num_layers=27,
+        num_heads=32,
+        num_kv_heads=32,
+        head_dim=72,
+        rope_theta=10000.0,
+        max_position=1048576,
+        rms_eps=1e-5,
+        layer_kinds=tuple(
+            "mla" if (i + 1) % 4 == 0 or i == 26 else "kda" for i in range(27)),
+        kda_heads=32,
+        kda_head_dim=128,
+        kda_conv=4,
+        kda_gate_rank=128,
+        mla_rope=False,
+        latent_pad=128,
+        moe_family="deepseek",
+        num_experts=256,
+        num_experts_per_token=8,
+        first_k_dense=1,
+        n_shared_experts=1,
+        moe_intermediate_size=1024,
+        routed_scaling_factor=2.446,
+        scoring_func="sigmoid",
+        router_bias=True,
+        norm_topk_prob=True,
+        n_group=1,
+        topk_group=1,
+        kv_lora_rank=512,
+        q_lora_rank=None,
         qk_nope_head_dim=128,
         qk_rope_head_dim=64,
         v_head_dim=128,

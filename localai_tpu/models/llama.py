@@ -73,11 +73,18 @@ class KVCache(NamedTuple):
 
     k: jnp.ndarray
     v: jnp.ndarray
+    # A hybrid model's (cfg.is_hybrid) per-slot recurrent state rides with
+    # the cache through every program that carries it, donated with it:
+    # state [Lk, B_slots, H, dk, dv] f32 and conv [Lk, B_slots, conv-1,
+    # 3·H·dk] (the short conv's last inputs), Lk the KDA layers; k/v then
+    # hold rows for the cache_layers (MLA) only. None everywhere else.
+    state: Any = None
+    conv: Any = None
 
     @staticmethod
     def zeros(cfg: ArchConfig, num_slots: int, max_seq: int, dtype=None) -> "KVCache":
         dtype = jnp.dtype(cfg.dtype) if dtype is None else dtype
-        base = (cfg.num_layers, num_slots, max_seq, cfg.cache_kv_heads)
+        base = (cfg.cache_layers, num_slots, max_seq, cfg.cache_kv_heads)
         return KVCache(
             k=jnp.zeros(base + (cfg.cache_k_dim,), dtype),
             v=jnp.zeros(base + (cfg.cache_v_dim,), dtype),
@@ -88,13 +95,18 @@ def _dtype(cfg: ArchConfig):
     return jnp.dtype(cfg.dtype)
 
 
-def _init_attn_layers(cfg: ArchConfig, rnd, keys, L: int) -> Params:
-    """Attention + norm keys for a stack of L layers (standard or MLA)."""
+def _init_attn_layers(cfg: ArchConfig, rnd, keys, L: int,
+                      mla_stack: bool = False) -> Params:
+    """Attention + norm keys for a stack of L layers (standard or MLA). A
+    hybrid model's layer stacks keep the two norms only; `mla_stack` builds
+    its "mla_layers" (the MLA weights, no norms)."""
     dt = _dtype(cfg)
     D = cfg.hidden_size
     H, K, Hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     layers: Params = {"attn_norm": jnp.ones((L, D), dt),
                       "mlp_norm": jnp.ones((L, D), dt)}
+    if cfg.is_hybrid and not mla_stack:
+        return layers  # the attention weights live in their kinds' stacks
     if cfg.is_mla:
         r, rot = cfg.kv_lora_rank, cfg.qk_rope_head_dim
         n, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
@@ -112,6 +124,8 @@ def _init_attn_layers(cfg: ArchConfig, rnd, keys, L: int) -> Params:
         layers["w_kb"] = rnd(next(keys), (L, H, n, r))
         layers["w_vb"] = rnd(next(keys), (L, H, vd, r))
         layers["wo"] = rnd(next(keys), (L, H * vd, D))
+        if mla_stack:
+            del layers["attn_norm"], layers["mlp_norm"]
         return layers
     layers["wq"] = rnd(next(keys), (L, D, H * Hd))
     layers["wk"] = rnd(next(keys), (L, D, K * Hd))
@@ -133,6 +147,62 @@ def _init_attn_layers(cfg: ArchConfig, rnd, keys, L: int) -> Params:
     return layers
 
 
+def init_special(name: str, key, shape):
+    """The leaves a normal draw at 0.02 would make degenerate (KDA's decay:
+    A as fla's KimiDeltaAttention draws it, the step from `KDA_DT`; the
+    short conv), float32. None for any other leaf."""
+    if name == "conv_w":  # four taps that pass their input on at its size
+        return jax.random.normal(key, shape, jnp.float32) * 0.5
+    if name == "A_log":  # A in U(1, 16)
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+    if name == "dt_bias":  # softplus^-1 of dt, log-uniform in KDA_DT
+        dt = jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, *(jnp.log(x) for x in KDA_DT)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    return None
+
+
+# The decay step of a synthetic KDA layer: a hundredth of fla's [1e-3, 1e-1].
+# With A in U(1, 16) a channel then forgets over some 10^3 to 10^5 tokens, as
+# a long-context model's slow channels do: the regime the float32 state is
+# kept for (held in bfloat16 it drifts by the root of the tokens remembered).
+KDA_DT = (1e-5, 1e-3)
+
+
+def init_gain(cfg: ArchConfig, name: str, shape) -> float:
+    """What the normal draw of a leaf is multiplied by besides `scale`. A
+    hybrid model's routed experts' down-projection ([L, E, F, D]) is drawn at
+    a tenth: its router renormalises and scales the picks, so with random
+    experts a pick that flips at a near-tie would move the stream by a
+    quarter of a layer, and every rounding anywhere reads as routing noise."""
+    return 0.1 if cfg.is_hybrid and name == "w_down" and len(shape) == 4 else 1.0
+
+
+def _init_kda_layers(cfg: ArchConfig, rnd, keys, L: int) -> Params:
+    """The KDA stack of a hybrid model: the int8-able matrices under the
+    names the GQA stack gives its projections, the small leaves in the model
+    dtype, the decay's two vectors in float32."""
+    D, H, dk = cfg.hidden_size, cfg.kda_heads, cfg.kda_head_dim
+    r, c = cfg.kda_gate_rank, cfg.kda_conv
+    return {
+        "wq": rnd(next(keys), (L, D, H * dk)),
+        "wk": rnd(next(keys), (L, D, H * dk)),
+        "wv": rnd(next(keys), (L, D, H * dk)),
+        "wo": rnd(next(keys), (L, H * dk, D)),
+        # depthwise causal conv over time of [q~ | k~ | v~], no bias
+        "conv_w": init_special(
+            "conv_w", next(keys), (L, c, 3 * H * dk)).astype(_dtype(cfg)),
+        "f_down": rnd(next(keys), (L, D, r)),
+        "f_up": rnd(next(keys), (L, r, H * dk)),
+        "dt_bias": init_special("dt_bias", next(keys), (L, H * dk)),
+        "A_log": init_special("A_log", next(keys), (L, H)),
+        "w_beta": rnd(next(keys), (L, D, H)),
+        "g_down": rnd(next(keys), (L, D, r)),
+        "g_up": rnd(next(keys), (L, r, H * dk)),
+        "o_norm": jnp.ones((L, dk), _dtype(cfg)),
+    }
+
+
 def init_params(cfg: ArchConfig, key: jnp.ndarray, scale: float = 0.02) -> Params:
     """Random init with HF-compatible tree structure (stacked layers).
 
@@ -145,20 +215,22 @@ def init_params(cfg: ArchConfig, key: jnp.ndarray, scale: float = 0.02) -> Param
     L, D, F = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size
     keys = iter(jax.random.split(key, 32))
 
-    def rnd(k, shape):
-        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dt)
+    def rnd(k, shape, gain=1.0):
+        return (jax.random.normal(k, shape, jnp.float32) * (scale * gain)).astype(dt)
 
     kd = cfg.first_k_dense if cfg.is_moe else 0
     Lm = L - kd
     layers = _init_attn_layers(cfg, rnd, keys, Lm)
     if cfg.is_moe:
         E, Fm = cfg.num_experts, cfg.moe_inter_size
+        Eh = cfg.experts_here  # the router scores all E, the stacks hold Eh
         layers["router"] = rnd(next(keys), (Lm, D, E))
         if cfg.router_bias:
             layers["router_bias"] = jnp.zeros((Lm, E), jnp.float32)
-        layers["w_gate"] = rnd(next(keys), (Lm, E, D, Fm))
-        layers["w_up"] = rnd(next(keys), (Lm, E, D, Fm))
-        layers["w_down"] = rnd(next(keys), (Lm, E, Fm, D))
+        layers["w_gate"] = rnd(next(keys), (Lm, Eh, D, Fm))
+        layers["w_up"] = rnd(next(keys), (Lm, Eh, D, Fm))
+        layers["w_down"] = rnd(next(keys), (Lm, Eh, Fm, D),
+                               init_gain(cfg, "w_down", (Lm, Eh, Fm, D)))
         if cfg.n_shared_experts:
             Fs = cfg.n_shared_experts * Fm
             layers["shared_gate"] = rnd(next(keys), (Lm, D, Fs))
@@ -182,6 +254,12 @@ def init_params(cfg: ArchConfig, key: jnp.ndarray, scale: float = 0.02) -> Param
         params["dense_layers"] = dense
     if not cfg.tie_embeddings:
         params["lm_head"] = rnd(next(keys), (cfg.vocab_size, D))
+    if cfg.is_hybrid:
+        hk = iter(jax.random.split(jax.random.fold_in(key, 1), 32))
+        params["kda_layers"] = _init_kda_layers(
+            cfg, rnd, hk, len(cfg.kda_layers))
+        params["mla_layers"] = _init_attn_layers(
+            cfg, rnd, hk, cfg.cache_layers, mla_stack=True)
     return params
 
 
@@ -210,6 +288,18 @@ def _rides_stacked(a) -> bool:
     return isinstance(a, quant.StackedLayer) or quant.is_quantized(a)
 
 
+def _take_layer(tree, i):
+    """Layer i of a tree of per-layer stacks: a leaf that rides stacked as
+    the stack and the index (`quant.StackedLayer`), any other sliced."""
+    def take(a):
+        if _rides_stacked(a):
+            return quant.StackedLayer(a, i)
+        return jax.lax.dynamic_index_in_dim(
+            a, i, 0, keepdims=False, allow_negative_indices=False)
+
+    return jax.tree.map(take, tree, is_leaf=_rides_stacked)
+
+
 def _scan_stack(layer_fn, h, stack, lo: int, hi: int, extras):
     """`lax.scan` of `layer_fn` over layers lo..hi: `stack` holds just those
     layers' weights, each extra all the model's. The body takes its own
@@ -225,24 +315,15 @@ def _scan_stack(layer_fn, h, stack, lo: int, hi: int, extras):
     slices at its own call site (`quant.layer_slice`) in front of the XLA
     form."""
 
-    def index(i):
-        def take(a):
-            if _rides_stacked(a):
-                return quant.StackedLayer(a, i)
-            return jax.lax.dynamic_index_in_dim(
-                a, i, 0, keepdims=False, allow_negative_indices=False)
-
-        return take
-
     def body(carry, _):
         # `i` is carried beside h, not sliced out of an arange: the index of
         # every slice below is then a loop counter, as scan's own is.
         h, i = carry
         li = i + lo if lo else i  # the model's layer number
         with jax.named_scope("layer_weights"):
-            lp = jax.tree.map(index(i), stack, is_leaf=_rides_stacked)
+            lp = _take_layer(stack, i)
         with jax.named_scope("layer_kv_pool"):
-            ex = jax.tree.map(index(li), tuple(extras), is_leaf=_rides_stacked)
+            ex = _take_layer(tuple(extras), li)
         h, out = layer_fn(h, (lp, li) + ex)
         return (h, i + 1), out
 
@@ -351,6 +432,20 @@ def _deepseek_route(cfg: ArchConfig, lp: Params, x: jnp.ndarray):
     return weights, sel
 
 
+def _held_route(cfg: ArchConfig, route):
+    """The router's choice as the expert stacks held here see it
+    (cfg.expert_share): ids relative to the first held expert, a pick that
+    landed elsewhere as the id `experts_here` (one past the stack: no row of
+    a one-hot, the last group of a sort) with weight 0. The weights were
+    normalised over all the picks before, so what the held experts add is
+    their part of the whole layer's sum."""
+    weights, sel = route
+    n = cfg.experts_here
+    local = sel - cfg.expert_lo
+    here = (local >= 0) & (local < n)
+    return jnp.where(here, weights, 0.0), jnp.where(here, local, n)
+
+
 def _moe_dense(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
                mesh=None, route=None) -> jnp.ndarray:
     """All-experts MoE: every expert runs on every token, outputs combined by
@@ -361,7 +456,7 @@ def _moe_dense(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
     regardless), so for quantized decode this is near-optimal; wide row
     counts take `_moe_ragged` (see `_mlp`). `route` = (weights, sel) when
     the caller already ran the router."""
-    E = cfg.num_experts
+    E = cfg.experts_here
     qk = cfg.quant_kernel
     weights, sel = route or _moe_route(cfg, lp, x)
     onehot = jax.nn.one_hot(sel, E, dtype=jnp.float32)  # [..., topk, E]
@@ -418,7 +513,7 @@ def _moe_ragged(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
     (`_ragged_mm`). `route` = (weights, sel) when the caller already ran the
     router.
     """
-    E, k = cfg.num_experts, cfg.num_experts_per_token
+    E, k = cfg.experts_here, cfg.num_experts_per_token
     lead, D = x.shape[:-1], x.shape[-1]
     xf = x.reshape(-1, D)
     N = xf.shape[0]
@@ -447,9 +542,19 @@ def _moe_ragged(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
     else:
         gs = jnp.bincount(e_flat, length=E)  # rows per expert (sums to M)
     w_gate, w_up, w_down = ws
+    elsewhere = None
+    if cfg.expert_share is not None:
+        # Picks of experts held elsewhere sort last (`_held_route`). What
+        # `ragged_dot` does with rows that lie in no group is not specified,
+        # so they ride in the last one and are zeroed below.
+        elsewhere = group >= gs.shape[0]
+        group = jnp.minimum(group, gs.shape[0] - 1)
+        gs = gs.at[-1].add(M - gs.sum())
     gate = _act(cfg, _ragged_mm(xg, w_gate, gs, group))
     up = _ragged_mm(xg, w_up, gs, group)
     dn = _ragged_mm((gate * up).astype(xg.dtype), w_down, gs, group)  # [M, D]
+    if elsewhere is not None:
+        dn = jnp.where(elsewhere[:, None], 0, dn)
     wf = jnp.take(weights.reshape(M), order)
     y = jnp.zeros((N, D), jnp.float32).at[tok].add(dn.astype(jnp.float32) * wf[:, None])
     return y.reshape(*lead, D).astype(x.dtype)
@@ -577,6 +682,8 @@ def _mlp(cfg: ArchConfig, lp: Params, x: jnp.ndarray, ep: int = 1,
         y = _moe_capacity(cfg, lp, x)  # routes block by block, inside
     else:
         route = _moe_route(cfg, lp, x)
+        if cfg.expert_share is not None:
+            route = _held_route(cfg, route)
         if picks is not None:
             picks.append(route[1])
         rows = x.size // x.shape[-1]
@@ -702,6 +809,22 @@ def _mla_q(cfg: ArchConfig, lp: Params, x: jnp.ndarray, mesh=None) -> jnp.ndarra
     return q.reshape(*x.shape[:-1], cfg.num_heads, cfg.qk_head_dim)
 
 
+def _mla_rope(cfg: ArchConfig, x, positions, inv):
+    """MLA's rotation of its rope dims; none at all under `mla_rope` off
+    (Kimi-Linear's NoPE MLA: the KDA layers carry the order)."""
+    return apply_rope(x, positions, inv) if cfg.mla_rope else x
+
+
+def _latent_pad(cfg: ArchConfig, a: jnp.ndarray) -> jnp.ndarray:
+    """Zero-pad the last axis from r+rot to the cache's row width
+    (cfg.latent_pad): zeros add nothing to a score and are never read back
+    as values."""
+    pad = cfg.cache_k_dim - a.shape[-1]
+    if not pad:
+        return a
+    return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
+
+
 def _mla_rows(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
               positions: jnp.ndarray, inv: jnp.ndarray) -> jnp.ndarray:
     """Latent cache rows [B, T, 1, r+rot] = [RMSNorm(c_kv) | RoPE(k_pe)] for
@@ -710,8 +833,9 @@ def _mla_rows(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
     r = cfg.kv_lora_rank
     ckv = matmul(x, lp["wkv_a"], cfg.quant_kernel)  # [B, T, r+rot] (replicated weight)
     c = rms_norm(ckv[..., :r], lp["kv_norm"], cfg.rms_eps)
-    k_pe = apply_rope(ckv[..., None, r:], positions, inv)  # [B, T, 1, rot]
-    return jnp.concatenate([c[..., None, :], k_pe], axis=-1)
+    k_pe = _mla_rope(cfg, ckv[..., None, r:], positions, inv)  # [B, T, 1, rot]
+    rows = jnp.concatenate([c[..., None, :], k_pe], axis=-1)
+    return _latent_pad(cfg, rows)
 
 
 def _mla_full_qkv(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
@@ -724,12 +848,12 @@ def _mla_full_qkv(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
     n, rot, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     r = cfg.kv_lora_rank
     q = _mla_q(cfg, lp, x, mesh)
-    q = jnp.concatenate([q[..., :n], apply_rope(q[..., n:], positions, inv)], axis=-1)
+    q = jnp.concatenate([q[..., :n], _mla_rope(cfg, q[..., n:], positions, inv)], axis=-1)
     amp = rope_query_amp(cfg)
     if amp != 1.0:
         q = q * float(amp)
     rows = _mla_rows(cfg, lp, x, positions, inv)
-    c, k_pe = rows[..., 0, :r], rows[..., :, r:]  # [B,T,r], [B,T,1,rot]
+    c, k_pe = rows[..., 0, :r], rows[..., :, r:r + rot]  # [B,T,r], [B,T,1,rot]
     k_nope = jnp.einsum("btr,hnr->bthn", c, lp["w_kb"]).astype(x.dtype)
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_pe, (*k_pe.shape[:2], H, rot)).astype(x.dtype)],
@@ -749,10 +873,11 @@ def _mla_absorbed_q(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
     true 1/sqrt(qk_head_dim) softmax scale (same trick as query_scale)."""
     n = cfg.qk_nope_head_dim
     q = _mla_q(cfg, lp, x, mesh)
-    q_pe = apply_rope(q[..., n:], positions, inv)
+    q_pe = _mla_rope(cfg, q[..., n:], positions, inv)
     q_lat = jnp.einsum("bthn,hnr->bthr", q[..., :n], lp["w_kb"]).astype(x.dtype)
-    q_eff = jnp.concatenate([q_lat, q_pe.astype(x.dtype)], axis=-1)
-    scale = ((cfg.kv_lora_rank + cfg.qk_rope_head_dim) / cfg.qk_head_dim) ** 0.5
+    q_eff = _latent_pad(
+        cfg, jnp.concatenate([q_lat, q_pe.astype(x.dtype)], axis=-1))
+    scale = (cfg.cache_k_dim / cfg.qk_head_dim) ** 0.5
     return q_eff * jnp.asarray(scale * rope_query_amp(cfg), x.dtype)
 
 
@@ -898,6 +1023,232 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
     return h + _mlp_out(cfg, lp, x, ep, mesh, lora=lora, picks=picks), emit
 
 
+# --------------------------------------------------------------------------- #
+# Hybrid linear attention (Kimi-Linear): KDA layers with a per-slot recurrent
+# state beside MLA layers with latent cache rows (cfg.layer_kinds).
+#
+# A KDA layer keeps, per slot, a [H, dk, dv] float32 state and the short
+# conv's last inputs; it writes no cache row. The two kinds' weights live in
+# their own stacks ("kda_layers", "mla_layers"), the norms and the MLPs in
+# the model's layer stacks as ever. `_scan_hybrid` scans the KDA layers and
+# runs the MLA layer that follows one under a `lax.cond`: the recurrent
+# state is carried by the scan and never enters the conditional.
+# --------------------------------------------------------------------------- #
+
+
+def _kda_inputs(cfg: ArchConfig, ap: Params, x: jnp.ndarray, conv_prev):
+    """x [B, T, D] (normed) -> the recurrence's operands and what the layer
+    needs after it. conv_prev [B, c-1, 3·H·dk]: the conv's inputs before
+    x's first token (zeros at a prompt's start).
+
+    Returns (q, k, v, g [B, T, H, dk] f32, beta [B, T, H] f32, gate
+    [B, T, H, dk], window [B, c-1+T, 3·H·dk]: the conv's inputs, from which
+    the caller cuts the rows the next token will need)."""
+    f32 = jnp.float32
+    B, T, _ = x.shape
+    H, dk, c = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv
+    qk = cfg.quant_kernel
+    pre = jnp.concatenate(
+        [matmul(x, ap[n], qk) for n in ("wq", "wk", "wv")], axis=-1)
+    window = jnp.concatenate([conv_prev.astype(pre.dtype), pre], axis=1)
+    w = ap["conv_w"].astype(f32)  # [c, 3·H·dk]
+    y = sum(window[:, i:i + T].astype(f32) * w[i] for i in range(c))
+    y = jax.nn.silu(y).reshape(B, T, 3, H, dk)
+    q, k, v = y[:, :, 0], y[:, :, 1], y[:, :, 2]
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) * dk ** -0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+    f = matmul(matmul(x, ap["f_down"], qk), ap["f_up"], qk).astype(f32)
+    g = -jnp.exp(ap["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+        f + ap["dt_bias"].astype(f32)).reshape(B, T, H, dk)
+    beta = jax.nn.sigmoid(matmul(x, ap["w_beta"], qk).astype(f32))
+    gate = jax.nn.sigmoid(matmul(
+        matmul(x, ap["g_down"], qk), ap["g_up"], qk).astype(f32))
+    return q, k, v, g, beta, gate.reshape(B, T, H, dk), window
+
+
+def _kda_out(cfg: ArchConfig, ap: Params, o, gate, dtype, mesh=None):
+    """o [..., H, dv] f32 -> per-head RMSNorm, the sigmoid gate, W_o."""
+    o = rms_norm(o, ap["o_norm"], cfg.rms_eps) * gate
+    o = o.reshape(*o.shape[:-2], -1).astype(dtype)
+    return matmul(o, ap["wo"], cfg.quant_kernel, mesh, "row")
+
+
+@jax.named_scope("attention")
+def _kda_decode_mix(cfg: ArchConfig, ap: Params, x, rec, j, impl="auto"):
+    """One token per slot: x [B, D], rec = (state, conv) stacked over the KDA
+    layers, j this layer's index in them. Returns (y [B, D], rec)."""
+    from localai_tpu.ops.kda import kda_decode
+
+    state, conv = rec
+    with jax.named_scope("layer_conv_rows"):
+        prev = jax.lax.dynamic_index_in_dim(conv, j, 0, keepdims=False)
+    q, k, v, g, beta, gate, window = _kda_inputs(cfg, ap, x[:, None], prev)
+    conv = jax.lax.dynamic_update_index_in_dim(
+        conv, window[:, 1:].astype(conv.dtype), j, 0)
+    o, state = kda_decode(state, j, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                          beta[:, 0], impl=impl)
+    return _kda_out(cfg, ap, o, gate[:, 0], x.dtype), (state, conv)
+
+
+@jax.named_scope("attention")
+def _kda_prefill_mix(cfg: ArchConfig, ap: Params, x, lengths, rec, j, slots):
+    """Whole prompts from an empty state: x [B, T, D] right-padded to
+    `lengths`. With rec = (state, conv) the state after each prompt's last
+    token and the conv's last inputs are written to rows `slots` [B] of
+    layer j. Returns (y [B, T, D], rec)."""
+    from localai_tpu.ops.kda import CHUNK, SUB, kda_chunk_prefill
+
+    B, T, _ = x.shape
+    c = cfg.kda_conv
+    zeros = jnp.zeros((B, c - 1, 3 * cfg.kda_heads * cfg.kda_head_dim), x.dtype)
+    q, k, v, g, beta, gate, window = _kda_inputs(cfg, ap, x, zeros)
+    valid = jnp.arange(T)[None, :] < lengths[:, None]
+    pad = -T % (CHUNK if T >= CHUNK else SUB)
+    if pad:  # a bucket that is no multiple of the chunk: rows that do nothing
+        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for a in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+        valid = jnp.pad(valid, ((0, 0), (0, pad)))
+    o, S = kda_chunk_prefill(q, k, v, g, beta, valid)
+    y = _kda_out(cfg, ap, o[:, :T], gate, x.dtype)
+    if rec is not None:
+        state, conv = rec
+        # the conv's inputs at tokens len-c+1 .. len-1 (zeros before 0)
+        rows = jnp.take_along_axis(
+            window, (lengths[:, None] + jnp.arange(c - 1)[None, :])[..., None],
+            axis=1)
+        state = state.at[j, slots].set(S)
+        conv = conv.at[j, slots].set(rows.astype(conv.dtype))
+        rec = (state, conv)
+    return y, rec
+
+
+def _hybrid_tables(cfg: ArchConfig):
+    """Static layout of a hybrid stack: the KDA layers' model layer numbers,
+    for each the index of the MLA layer that follows it (or -1), and how
+    many of them carry the dense-prefix MLPs."""
+    import numpy as np
+
+    kl = list(cfg.kda_layers)
+    ml = list(cfg.cache_layer_ids)
+    kd = cfg.first_k_dense if cfg.is_moe else 0
+    after = [ml.index(l + 1) if l + 1 in ml else -1 for l in kl]
+    nd = sum(1 for l in kl if l < kd)
+    covered = set(kl) | {l + 1 for l, m in zip(kl, after) if m >= 0}
+    if (len(cfg.layer_kinds) != cfg.num_layers
+            or covered != set(range(cfg.num_layers))
+            or any(l < kd for l in ml)
+            or any(m >= 0 and l + 1 <= kd for l, m in zip(kl, after))):
+        raise NotImplementedError(
+            f"{cfg.name}: layer_kinds {cfg.layer_kinds} — every 'mla' layer "
+            "has to follow a 'kda' layer and the dense-prefix layers have to "
+            "be 'kda' (models/llama._scan_hybrid)")
+    return np.asarray(kl, np.int32), np.asarray(after, np.int32), nd, kd
+
+
+def _scan_hybrid(cfg: ArchConfig, params: Params, h, rec, kda_fn, mla_fn,
+                 mla_zero, extras=()):
+    """The layer stack of a hybrid model: a scan over its KDA layers, each
+    followed (`lax.cond`) by the MLA layer behind it where there is one.
+
+    kda_fn(h, rec, lp, j) -> (h, rec, out): a KDA layer and its MLP; lp its
+    weights (the KDA stack's and the layer stack's, one dict), j its index
+    among the KDA layers. rec is whatever the entry point carries through
+    them (the recurrent state), never passed into the conditional.
+    mla_fn(h, lp, m, ex) -> (h, out): an MLA layer and its MLP; m its index
+    among the MLA layers, ex the `extras` (arrays stacked over the MLA
+    layers: the cache) as `_scan_stack` would hand them on. mla_zero(h) is
+    `out` of a layer that is not there.
+    Returns (h, rec, KDA outs stacked over the KDA layers, MLA outs stacked
+    over the MLA layers)."""
+    kl, after, nd, kd = _hybrid_tables(cfg)
+    kl_t, after_t = jnp.asarray(kl), jnp.asarray(after)
+
+    def run(h, rec, lo, hi, stack, off, with_mla):
+        def body(carry, _):
+            h, rec, j = carry
+            li = kl_t[j]
+            with jax.named_scope("layer_weights"):
+                lp = {**_take_layer(params["kda_layers"], j),
+                      **_take_layer(stack, li - off)}
+            h, rec, out_k = kda_fn(h, rec, lp, j)
+            if not with_mla:
+                return (h, rec, j + 1), (out_k, None)
+            m = after_t[j]
+
+            def there(h):
+                mi = jnp.maximum(m, 0)
+                with jax.named_scope("layer_weights"):
+                    lp = {**_take_layer(params["mla_layers"], mi),
+                          **_take_layer(stack, li + 1 - off)}
+                with jax.named_scope("layer_kv_pool"):
+                    ex = _take_layer(tuple(extras), mi)
+                return mla_fn(h, lp, mi, ex)
+
+            h, out_m = jax.lax.cond(m >= 0, there, lambda h: (h, mla_zero(h)), h)
+            return (h, rec, j + 1), (out_k, out_m)
+
+        (h, rec, _), outs = jax.lax.scan(
+            body, (h, rec, jnp.int32(lo)), None, length=hi - lo)
+        return h, rec, outs
+
+    outs_k = []
+    if nd:
+        h, rec, (ok, _) = run(h, rec, 0, nd, params["dense_layers"], 0, False)
+        outs_k.append(ok)
+    h, rec, (ok, om) = run(h, rec, nd, len(kl), params["layers"], kd, True)
+    outs_k.append(ok)
+    out_k = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs_k)
+    there = [j - nd for j in range(nd, len(kl)) if after[j] >= 0]
+    out_m = jax.tree.map(lambda a: a[jnp.asarray(there)], om)
+    return h, rec, out_k, out_m
+
+
+def _expert_counts(cfg: ArchConfig, picks) -> jnp.ndarray:
+    """[E] int32: rows per held expert of one MLP's router choice (zeros for
+    a dense MLP, whose `picks` stayed empty)."""
+    E = max(cfg.experts_here, 1)
+    if not picks:
+        return jnp.zeros((E,), jnp.int32)
+    lead = tuple(range(picks[0].ndim))
+    return jax.nn.one_hot(picks[0], E, dtype=jnp.int32).sum(lead)
+
+
+def _hybrid_layer_fns(cfg: ArchConfig, kda_mix, *, pos, inv, attend,
+                      mla_full: bool, ep: int, mesh, count: bool):
+    """(kda_fn, mla_fn, mla_zero) for `_scan_hybrid` from an entry point's
+    `kda_mix(lp, x, rec, j) -> (y, rec)` and its MLA `attend`. The MLA layer
+    is `_decoder_layer` itself. With `count` each layer's out ends with its
+    rows per held expert."""
+
+    def mlp(h, lp, picks):
+        x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
+        return h + _mlp_out(cfg, lp, x, ep, mesh, picks=picks)
+
+    def kda_fn(h, rec, lp, j):
+        picks = [] if count else None
+        y, rec = kda_mix(lp, rms_norm(h, lp["attn_norm"], cfg.rms_eps), rec, j)
+        h = mlp(h + y, lp, picks)
+        return h, rec, (_expert_counts(cfg, picks) if count else None)
+
+    def mla_fn(h, lp, m, ex):
+        picks = [] if count else None
+        h, rows = _decoder_layer(
+            cfg, h, (lp, m) + tuple(ex), pos=pos, inv=inv, attend=attend,
+            mla_full=mla_full, ep=ep, mesh=mesh, picks=picks)
+        return h, rows + ((_expert_counts(cfg, picks),) if count else ())
+
+    def mla_zero(h):
+        lead = h.shape[:-1]
+        rows = jnp.zeros(lead + (1, cfg.cache_k_dim), h.dtype)
+        out = (rows, rows[..., :0])
+        if count:
+            out = out + (jnp.zeros((max(cfg.experts_here, 1),), jnp.int32),)
+        return out
+
+    return kda_fn, mla_fn, mla_zero
+
+
 def _rope_inv(cfg: ArchConfig):
     """(global, local | None) rope frequencies, as `_decoder_layer` takes them."""
     return rope_frequencies(cfg), rope_frequencies_local(cfg)
@@ -915,9 +1266,12 @@ def _forward_hidden(
     mrope=None,  # [B, 3, S] (t, h, w) position streams — Qwen2-VL m-rope
     lora=None,  # (stacked adapter factors {key: {"a": [L,NA,in,R], "b":
     # [L,NA,R,out]}}, ids [B]) — per-row runtime LoRA (ISSUE 10)
+    recurrent=None,  # hybrid models: (state, conv, slots [B]) — each prompt's
+    # final recurrent state is written to its slot's rows, layer by layer
 ):
     """Shared full-sequence forward. Returns (h [B,S,D] after final norm,
-    length_mask [B,S], (ks, vs) or None). Single source of truth for the layer
+    length_mask [B,S], (ks, vs) or None; with `recurrent`, the (state, conv)
+    written follows). Single source of truth for the layer
     body used by both `prefill` and `encode`.
 
     With a mesh whose "sp" axis is > 1, attention runs as ring attention
@@ -982,9 +1336,31 @@ def _forward_hidden(
             mesh=mesh, lora=lora)
         return h, (kv if collect_kv else None)
 
-    extras = () if lora is None else (lora[0],)
-    h, kv = _scan_layers(cfg, params, h, body, extras)
+    rec = None
+    if cfg.is_hybrid:
+        if use_ring or lora is not None or mrope is not None:
+            raise NotImplementedError(
+                f"{cfg.name}: a hybrid (KDA) model prefills without sequence "
+                "parallelism, runtime LoRA and m-rope")
+        slots = None
+        if recurrent is not None:
+            *rec, slots = recurrent
+            rec = tuple(rec)
+
+        def kda_mix(lp, x, rec, j):
+            return _kda_prefill_mix(cfg, lp, x, lengths, rec, j, slots)
+
+        h, rec, _, kv = _scan_hybrid(
+            cfg, params, h, rec, *_hybrid_layer_fns(
+                cfg, kda_mix, pos=positions, inv=(inv_freq, inv_local),
+                attend=attend, mla_full=True, ep=ep, mesh=mesh, count=False))
+        kv = kv if collect_kv else None
+    else:
+        extras = () if lora is None else (lora[0],)
+        h, kv = _scan_layers(cfg, params, h, body, extras)
     h = rms_norm(h, params["final_norm"], cfg.rms_eps)
+    if recurrent is not None:
+        return h, length_mask, kv, rec
     return h, length_mask, kv
 
 
@@ -998,16 +1374,19 @@ def prefill(
     ep: int = 1,
     mrope=None,  # [B, 3, S] m-rope position streams (Qwen2-VL)
     lora=None,  # (stacked adapter factors, ids [B]) — runtime LoRA
+    recurrent=None,  # hybrid models: (state, conv, slots [B]), see
+    # `_forward_hidden`; the written (state, conv) is then returned last
 ):
-    """Prompt processing. Returns (last_logits [B, V] f32, k [L,B,S,K,Hd], v)."""
-    h, _, (ks, vs) = _forward_hidden(
+    """Prompt processing. Returns (last_logits [B, V] f32, k [L,B,S,K,Hd], v),
+    L the layers that write cache rows (cfg.cache_layers)."""
+    h, _, (ks, vs), *rec = _forward_hidden(
         cfg, params, tokens, lengths, collect_kv=True, mesh=mesh, inject=inject,
-        ep=ep, mrope=mrope, lora=lora,
+        ep=ep, mrope=mrope, lora=lora, recurrent=recurrent,
     )
     last_idx = jnp.maximum(lengths - 1, 0)  # empty prompt reads position 0, not wrap to S-1
     last = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]  # [B, D]
     logits = _unembed(cfg, params, last, mesh)
-    return logits, ks, vs
+    return (logits, ks, vs, *rec)
 
 
 def encode(
@@ -1155,6 +1534,9 @@ def decode_step_windowed(
     lora=None,  # (stacked adapter factors, ids [B]) — per-slot runtime
     # LoRA deltas applied unmerged beside the base matmuls (ISSUE 10)
     expert_rows: bool = False,  # also return the router's rows per expert
+    recurrent=None,  # hybrid models: (state, conv), the per-slot recurrent
+    # state of the KDA layers; updated in place, returned LAST
+    kda_impl: str = "auto",  # KDA decode kernel: auto|pallas|xla
 ):
     """One step of a fused decode block with a block-local KV window.
 
@@ -1179,10 +1561,13 @@ def decode_step_windowed(
     sink = dict(sink=cfg.attention_sink, swin=cfg.attention_window)
 
     if ptable is not None:
+        # A latent row padded to lane tiles (`cfg.latent_pad`) is laid out
+        # for the latent paged kernel: this step says so, the op checks it.
         def attend(q, k, v, sliding, kc, vc, lk, lv):
             return decode_attention_windowed_paged(
                 q, kc, vc, ptable, lk, lv, k, v, positions, step,
                 impl=paged_impl, kv_scale=kv_scale,
+                latent=cfg.is_mla and bool(cfg.latent_pad),
                 **_mask_opts(cfg, sliding, mesh=mesh, **sink))
     elif use_sp:
         def attend(q, k, v, sliding, kc, vc, lk, lv):
@@ -1206,18 +1591,33 @@ def decode_step_windowed(
             mesh=mesh, lora=lora, picks=picks)
         if not expert_rows:
             return h, rows
-        E = max(cfg.num_experts, 1)
-        if not picks:
-            return h, rows + (jnp.zeros((E,), jnp.int32),)
-        routed.append(True)
-        return h, rows + (
-            jax.nn.one_hot(picks[0], E, dtype=jnp.int32).sum((0, 1)),)
+        if picks:
+            routed.append(True)
+        return h, rows + (_expert_counts(cfg, picks),)
 
     pool = _paged_pool(cache) if ptable is not None else (cache.k, cache.v)
     extras = pool + (local_k, local_v)
     if lora is not None:
         extras = extras + (lora[0],)
-    h, (new_k, new_v, *rows_e) = _scan_layers(cfg, params, h, body, extras)
+    if cfg.is_hybrid:
+        if recurrent is None or lora is not None or use_sp:
+            raise NotImplementedError(
+                f"{cfg.name}: a hybrid (KDA) model decodes with its recurrent "
+                "state, without runtime LoRA and sequence parallelism")
+
+        def kda_mix(lp, x, rec, j):
+            return _kda_decode_mix(cfg, lp, x, rec, j, impl=kda_impl)
+
+        h, recurrent, rows_k, (new_k, new_v, *rows_e) = _scan_hybrid(
+            cfg, params, h, tuple(recurrent), *_hybrid_layer_fns(
+                cfg, kda_mix, pos=rope_pos, inv=inv, attend=attend,
+                mla_full=False, ep=ep, mesh=mesh, count=expert_rows),
+            extras=extras)
+        if expert_rows:  # KDA layers' MLPs, then the MLA layers'
+            rows_e = [jnp.concatenate([rows_k, rows_e[0]], axis=0)]
+            routed.extend([True] * cfg.is_moe)
+    else:
+        h, (new_k, new_v, *rows_e) = _scan_layers(cfg, params, h, body, extras)
     local_k = jax.lax.dynamic_update_index_in_dim(
         local_k, new_k.astype(local_k.dtype), step, axis=2
     )
@@ -1226,9 +1626,12 @@ def decode_step_windowed(
     )
     h = rms_norm(h, params["final_norm"], cfg.rms_eps)
     logits = _unembed(cfg, params, h, mesh)
+    out = (logits, local_k, local_v)
     if expert_rows:
-        return logits, local_k, local_v, (rows_e[0] if routed else None)
-    return logits, local_k, local_v
+        out = out + ((rows_e[0] if routed else None),)
+    if cfg.is_hybrid:
+        out = out + (recurrent,)
+    return out
 
 
 def write_block_to_cache(
@@ -1245,7 +1648,7 @@ def write_block_to_cache(
     bi = jnp.arange(B)[:, None]
     k = cache.k.at[:, bi, span].set(local_k.astype(cache.k.dtype))
     v = cache.v.at[:, bi, span].set(local_v.astype(cache.v.dtype))
-    return KVCache(k=k, v=v)
+    return cache._replace(k=k, v=v)
 
 
 def decode_chunk(
@@ -1405,9 +1808,10 @@ def paged_cache_zeros(cfg: ArchConfig, num_pages: int, page_size: int,
     """Page pool: k/v [L, P, page, K, Hd]. One pool serves every slot; the
     engine assigns pages to slots and passes per-slot tables to each program.
     HBM scales with pages in use, not slots × max_seq (SURVEY §7 ragged KV).
-    MLA pools hold latent rows (see KVCache docstring)."""
+    MLA pools hold latent rows (see KVCache docstring); L counts the layers
+    that write rows (a hybrid model's MLA layers, not its KDA layers)."""
     dtype = jnp.dtype(cfg.dtype) if dtype is None else dtype
-    base = (cfg.num_layers, num_pages, page_size, cfg.cache_kv_heads)
+    base = (cfg.cache_layers, num_pages, page_size, cfg.cache_kv_heads)
     return KVCache(
         k=jnp.zeros(base + (cfg.cache_k_dim,), dtype),
         v=jnp.zeros(base + (cfg.cache_v_dim,), dtype),
@@ -1452,7 +1856,7 @@ def write_block_to_pool(
     vs = None if kv_scale is None else kv_scale[1]
     k = pool.k.at[:, pid, off].set(_pool_store(local_k, pool.k.dtype, ks))
     v = pool.v.at[:, pid, off].set(_pool_store(local_v, pool.v.dtype, vs))
-    return KVCache(k=k, v=v)
+    return pool._replace(k=k, v=v)
 
 
 def write_chunk_to_pool(
@@ -1664,4 +2068,4 @@ def write_prefill_to_pool(
             v, _pool_store(chunk_v, v.dtype, vsc)[:, None],
             (0, _pt.row_lookup(table_row, p), 0, 0, 0)
         )
-    return KVCache(k=k, v=v)
+    return pool._replace(k=k, v=v)

@@ -53,6 +53,11 @@ QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                     "shared_gate", "shared_up", "shared_down")
 
 
+# The per-layer stacks of a param tree: the model's layers, DeepSeek's dense
+# prefix, and a hybrid model's two attention kinds (models/llama.py).
+LAYER_STACKS = ("layers", "dense_layers", "kda_layers", "mla_layers")
+
+
 def quantize_tensor(w: jnp.ndarray) -> dict[str, jnp.ndarray]:
     """Per-output-channel symmetric int8 over the reduction (-2) axis."""
     wf = w.astype(jnp.float32)
@@ -221,7 +226,7 @@ def quantize_params(cfg, params: Params, mode: str = "int8") -> Params:
     else:
         raise ValueError(f"unsupported quantization mode {mode!r}")
     out = dict(params)
-    for stack in ("layers", "dense_layers"):
+    for stack in LAYER_STACKS:
         if stack not in params:
             continue
         layers = dict(params[stack])
@@ -254,7 +259,7 @@ def init_params_quantized(
     """
     from jax import tree_util as jtu
 
-    from localai_tpu.models.llama import init_params
+    from localai_tpu.models.llama import init_gain, init_params, init_special
 
     if mode == "int8":
         qfn = quantize_tensor
@@ -277,14 +282,18 @@ def init_params_quantized(
         if name in ("bq", "bk", "bv"):
             return jnp.zeros(sd.shape, sd.dtype)
         k = next(keys)
+        special = init_special(name, k, sd.shape)
+        if special is not None:
+            return special.astype(sd.dtype)
         if name in QUANT_LAYER_KEYS and len(sd.shape) == 4:
             # An expert stack [L, E, in, out] is E times a dense leaf (OLMoE:
             # 2**31 elements, 8.6 GB as one float32 transient beside a tree
             # that is already half there): drawn and quantized a layer at a
             # time, so the transient is one layer's.
+            std = scale * init_gain(cfg, name, sd.shape)
             return jax.jit(lambda kk: jax.lax.map(
                 lambda k1: qfn(
-                    jax.random.normal(k1, sd.shape[1:], jnp.float32) * scale),
+                    jax.random.normal(k1, sd.shape[1:], jnp.float32) * std),
                 jax.random.split(kk, sd.shape[0])))(k)
         if name in QUANT_LAYER_KEYS:
             return jax.jit(lambda kk: qfn(

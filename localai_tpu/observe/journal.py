@@ -95,6 +95,15 @@ BASE_EVENTS = (
     "moe_load",      # the same block's load (a=sum over step and layer of
     #                  the busiest expert's rows, b=sum of the mean rows per
     #                  expert: compiled rows x top-k / experts)
+    "moe_here",      # the same block under an expert share (a=picks the
+    #                  router made: steps x MoE layers x compiled rows x
+    #                  top-k, b=of those, picks of an expert held here)
+    "state_rows",    # a hybrid model's decode block was processed (a=rows of
+    #                  the recurrent state it updated: steps x compiled rows x
+    #                  KDA layers, b=of those, rows of a live tenant)
+    "prefix_reuse_off",  # once, at start: prefix-span reuse was asked for
+    #                  and is off (a hybrid model's prefix would need a
+    #                  snapshot of its recurrent state; a=entries asked for)
     "slot_turnover", # one per `terminal` (rid, slot; a=1 when the slot index
     #                  had been handed on before the request's `done` was
     #                  posted, Engine._park, else 0; b=1)

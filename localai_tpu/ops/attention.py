@@ -677,7 +677,7 @@ def _paged_pools(k_pool, v_pool, pallas: bool):
 def paged_partials(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
                    window: int = 0, sliding=None, q_pos=None,
                    impl: str = "auto", mesh=None, kv_scale=None,
-                   sink: int = 0, swin: int = 0):
+                   sink: int = 0, swin: int = 0, latent: bool = False):
     """Paged online-softmax partials, dispatched: the fused Pallas ragged
     paged-attention kernel (ops/paged_flash — pages stream HBM→VMEM once,
     walk bounded per slot) or the XLA gather walk below (reference path and
@@ -688,7 +688,10 @@ def paged_partials(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
     GSPMD propagation, no collectives). sink/swin: windowed+sink mask +
     cold-page skip (ISSUE 14), identical semantics in both backends.
     k_pool/v_pool: one layer's [P, page, K, D] pool, or a StackedLayer of the
-    whole [L, P, page, K, D] pool (`_paged_pools`)."""
+    whole [L, P, page, K, D] pool (`_paged_pools`). `latent`: the caller's
+    pool holds MLA's latent rows laid out for the latent kernel (llama's
+    decode step says so from `cfg.latent_pad`); the XLA walk reads such a
+    pool as any other."""
     import functools
 
     from localai_tpu.ops.paged_flash import paged_decode_partials, use_pallas
@@ -697,6 +700,8 @@ def paged_partials(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
     k_pool, v_pool = _paged_pools(k_pool, v_pool, pallas)
     if pallas:
         interp = jax.default_backend() != "tpu"
+        if latent and _tp_degree(mesh) > 1:
+            raise NotImplementedError("a latent pool has one head: tp = 1")
         if _tp_degree(mesh) > 1:
             return _paged_pallas_sharded(
                 functools.partial(paged_decode_partials, softcap=softcap,
@@ -709,7 +714,7 @@ def paged_partials(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
         return paged_decode_partials(
             q, k_pool, v_pool, table, limits, softcap=softcap, window=window,
             sliding=sliding, q_pos=q_pos, interpret=interp, kv_scale=kv_scale,
-            sink=sink, swin=swin,
+            sink=sink, swin=swin, latent=latent,
         )
     return _paged_cache_partials(
         q, k_pool, v_pool, table, limits,
@@ -824,6 +829,7 @@ def decode_attention_windowed_paged(
     kv_scale=None,  # [2, K] f32 per-head (k, v) dequant scales (fp8 KV)
     sink: int = 0,  # windowed+sink decode (docs/LONG_CONTEXT.md): rows
     swin: int = 0,  # attended iff gpos < sink or q_pos - gpos < swin
+    latent: bool = False,  # the pool is MLA's latent one (`paged_partials`)
 ) -> jnp.ndarray:
     """`decode_attention_windowed` over a paged pool: paged partials for
     rows [0, block_start), dense merge of the (tiny) local window + current
@@ -833,6 +839,7 @@ def decode_attention_windowed_paged(
         q, k_pool, v_pool, table, positions - step,
         softcap=softcap, window=window, sliding=sliding, q_pos=positions,
         impl=impl, mesh=mesh, kv_scale=kv_scale, sink=sink, swin=swin,
+        latent=latent,
     )
     # f32 concat: the block-local window may live in the cache's storage
     # dtype (fp8 KV) while the current token is model-dtype.

@@ -258,6 +258,139 @@ def _ragged_paged_kernel(
     l_ref[0] = jnp.broadcast_to(l_s[...], l_ref.shape[1:])
 
 
+def _latent_paged_kernel(table_ref, limits_ref, layer_ref, q_ref, c_hbm,
+                         acc_ref, m_ref, l_ref, cbuf, acc_s, m_s, l_s, sem,
+                         *, page: int):
+    """The page walk of `_ragged_paged_kernel` over a LATENT pool (MLA's
+    absorbed decode: one row per token, read as key and as value): one DMA a
+    page into a [page, D] tile, scored against every query head and summed
+    back out of the same tile. Flat tables, no window, no softcap, no
+    scales: what MLA's calls ask for.
+
+    table_ref [B, MP], limits_ref [B], layer_ref [1] (prefetch); q_ref
+    [1, QR, D] f32 (scale applied); c_hbm [L, P, page, D] (ANY): the pool
+    without its one-wide head axis, so a page lands row-per-sublane (a
+    [page, 1, D] tile puts one row in a tile of 8 or 16 and is refused)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    QR = q_ref.shape[1]
+    lim = limits_ref[b]
+    layer = layer_ref[0]
+    n_iter = jnp.minimum((lim + page - 1) // page, table_ref.shape[1])
+
+    def dma(slot, j):
+        return pltpu.make_async_copy(
+            c_hbm.at[layer, table_ref[b, j]], cbuf.at[slot], sem.at[slot])
+
+    acc_s[...] = jnp.zeros_like(acc_s)
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+
+    @pl.when(n_iter > 0)
+    def _warmup():
+        dma(0, 0).start()
+
+    def body(j, carry):
+        slot = j % 2
+
+        @pl.when(j + 1 < n_iter)
+        def _prefetch():
+            dma((j + 1) % 2, j + 1).start()
+
+        dma(slot, j).wait()
+        gpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (QR, page), 1)
+        valid = gpos < lim
+        c = cbuf[slot].astype(jnp.float32)  # [page, D]
+        s = jax.lax.dot_general(
+            q_ref[0], c, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [QR, page]
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_s[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(jnp.maximum(m_prev - m_new, -80.0))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
+            p, c, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_s[...] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_iter, body, 0)
+    acc_ref[0] = acc_s[...]
+    m_ref[0] = jnp.broadcast_to(m_s[...], m_ref.shape[1:])
+    l_ref[0] = jnp.broadcast_to(l_s[...], l_ref.shape[1:])
+
+
+def _latent_partials_rows(qr, pool, table, limits, interpret: bool):
+    """`_paged_partials_rows` for a latent pool: qr [B, 1, QR, D], pool a
+    [P, page, 1, D] pool or its StackedLayer. Same contract of partials."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from localai_tpu.ops.stacked import stacks_of
+
+    B, _, QR, D = qr.shape
+    stack, _, layer = stacks_of(pool, pool, "layer_kv_pool")
+    L, P, page = stack.shape[:3]
+    kernel = functools.partial(_latent_paged_kernel, page=page)
+    acc, m, l = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, QR, D), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),  # pool stays in HBM
+            ],
+            out_specs=[
+                pl.BlockSpec((1, QR, D), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((1, QR, STAT_LANES), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((1, QR, STAT_LANES), lambda b, *_: (b, 0, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((2, page, D), stack.dtype),
+                pltpu.VMEM((QR, D), jnp.float32),
+                pltpu.VMEM((QR, 1), jnp.float32),
+                pltpu.VMEM((QR, 1), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, QR, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, QR, STAT_LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, QR, STAT_LANES), jnp.float32),
+        ],
+        interpret=interpret,
+        name="latent_paged_attention",
+    )(
+        table.astype(jnp.int32), limits.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        qr[:, 0], stack.reshape(L, P, page, D),
+    )
+    return acc[:, None], m[:, None, :, :1], l[:, None, :, :1]
+
+
+def latent_paged_attention(q, pool, table, limits, interpret: bool = False):
+    """Decode partials over a LATENT pool (MLA's absorbed form: one row a
+    token, key and value at once), for the caller that says its pool is one
+    (`paged_decode_partials(latent=True)`). q [B, H, D] at the pool's row
+    width; pool a [P, page, 1, D] pool or its StackedLayer; a flat table.
+    Returns (acc [B, 1, H, D], m [B, 1, H, 1], l [B, 1, H, 1]) f32."""
+    from localai_tpu.ops import ptable as _pt
+
+    B, H, D = q.shape
+    if pool.shape[2] != 1 or pool.shape[3] != D or _pt.is_hier(table):
+        raise ValueError(
+            "latent_paged_attention wants a one-head pool of the query's "
+            f"width and a flat page table: pool {tuple(pool.shape)}, q width "
+            f"{D}, hierarchical table {_pt.is_hier(table)}")
+    qr = (q.astype(jnp.float32) * (1.0 / D**0.5)).reshape(B, 1, H, D)
+    return _latent_partials_rows(qr, pool, table, limits, interpret)
+
+
 def _paged_partials_rows(
     qr: jnp.ndarray,  # [B, K, QR, Dk] f32, scale applied
     qpos_rows: jnp.ndarray,  # [B, QR] i32
@@ -355,10 +488,19 @@ def paged_decode_partials(
     kv_scale=None,  # [2, K] f32 per-head (k, v) dequant scales (fp8 KV)
     sink: int = 0,  # windowed+sink decode (docs/LONG_CONTEXT.md)
     swin: int = 0,
+    latent: bool = False,  # the caller's pool is MLA's latent one
 ):
     """Drop-in for attention._paged_cache_partials: returns
-    (acc [B, K, G, Dv], m [B, K, G, 1], l [B, K, G, 1]) f32, scale applied."""
+    (acc [B, K, G, Dv], m [B, K, G, 1], l [B, K, G, 1]) f32, scale applied.
+    `latent`: the one pool is key and value (`latent_paged_attention`, which
+    has none of the masks: asking for one with it is refused)."""
     B, H, D = q.shape
+    if latent:
+        if kv_scale is not None or softcap or swin or (
+                window and sliding is not None):
+            raise ValueError("the latent paged kernel has no dequant scale, "
+                             "softcap or window")
+        return latent_paged_attention(q, k_pool, table, limits, interpret)
     K = k_pool.shape[2]
     G = H // K
     scale = 1.0 / (D**0.5)

@@ -53,7 +53,7 @@ class ShardingPlanError(ValueError):
         self.max_tp = max_tp
 
 
-def _attn_specs(cfg: ArchConfig) -> dict[str, P]:
+def _attn_specs(cfg: ArchConfig, mla_stack: bool = False) -> dict[str, P]:
     """Attention-side specs shared by both layer stacks. MLA shards the
     per-head tensors over "tp" on the HEAD axis (q_b columns, w_kb/w_vb
     leading head dim, wo rows); the low-rank a-projections and the latent
@@ -62,7 +62,11 @@ def _attn_specs(cfg: ArchConfig) -> dict[str, P]:
         "attn_norm": P(None, None),
         "mlp_norm": P(None, None),
     }
+    if cfg.is_hybrid and not mla_stack:
+        return specs  # the attention weights live in their kinds' stacks
     if cfg.is_mla:
+        if mla_stack:
+            specs = {}
         if cfg.q_lora_rank:
             specs["wq_a"] = P(None, None, None)
             specs["q_norm_a"] = P(None, None)
@@ -135,6 +139,16 @@ def param_specs(cfg: ArchConfig) -> Params:
         specs["dense_layers"] = _dense_layer_specs(cfg)
     if not cfg.tie_embeddings:
         specs["lm_head"] = P("tp", None)
+    if cfg.is_hybrid:
+        # A hybrid model serves at tp = 1 (the engine refuses more): its KDA
+        # stack is replicated, its MLA stack sharded as any MLA's.
+        specs["kda_layers"] = {
+            **{n: P(None, None, None) for n in (
+                "wq", "wk", "wv", "wo", "conv_w", "f_down", "f_up", "w_beta",
+                "g_down", "g_up")},
+            **{n: P(None, None) for n in ("dt_bias", "A_log", "o_norm")},
+        }
+        specs["mla_layers"] = _attn_specs(cfg, mla_stack=True)
     return specs
 
 
@@ -217,6 +231,11 @@ def _tp_violation(cfg: ArchConfig, tp: int) -> Optional[str]:
     """First tp-divisibility violation, or None. Shared by validate_plan
     (raises) and max_valid_tp (probes) so probing never constructs
     exceptions n² deep."""
+    if cfg.is_hybrid and tp > 1:
+        # So an auto plan degrades to 1 (max_valid_tp) and only an engine
+        # handed tp > 1 outright is refused (engine/state.py).
+        return (f"{cfg.name} keeps a per-slot recurrent state (KDA layers) "
+                f"that is not sharded: tp={tp} > 1")
     if not cfg.is_mla and cfg.num_kv_heads % tp != 0:
         # MLA has no per-head kv cache to shard — the latent replicates and
         # only the H-axis tensors (q_b, w_kb/w_vb, wo) split over tp.

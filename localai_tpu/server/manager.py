@@ -1314,6 +1314,15 @@ def _apply_rope_overrides(arch, cfg):
     import dataclasses as _dc
 
     updates = {}
+    if cfg.expert_share:
+        # The deployment's one key of the model YAML (config/model_config.py).
+        idx, of = (int(x) for x in cfg.expert_share)
+        if not (arch.is_moe and of >= 1 and 0 <= idx < of
+                and arch.num_experts % of == 0):
+            raise ValueError(
+                f"model {cfg.name!r}: expert_share {cfg.expert_share} needs a "
+                f"MoE model whose {arch.num_experts} experts divide by `of`")
+        updates["expert_share"] = (idx, of)
     if cfg.rope_freq_base:
         updates["rope_theta"] = float(cfg.rope_freq_base)
     rs = cfg.rope_scaling
