@@ -263,6 +263,39 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
          (rnd((rows, H, D)), k_pool4, v_pool4, table4, limits4,
           jnp.int32(0), jnp.int32(3)), 5e-3)
 
+    # The three decode cells' own shapes (PERF.md §4): 32 rows, K/G of
+    # mistral-7b (8/4), OLMoE (16/1) and mistral-7b on one chip of four
+    # (2/4), contexts of 300-700 rows (3-6 pages), the page handed to the MXU
+    # as it is stored (ISSUE 32). The oracle is the XLA walk at
+    # Precision.HIGHEST, so the error read here is the kernel's own: q and p
+    # rounded to bfloat16, as Mosaic's one-pass float32 dot always rounded
+    # them. On the v5e the kernel before ISSUE 32 and the one after read the
+    # same 3.74e-3 / 2.78e-3 / 3.66e-3 here (PERF.md §6, PR 32) -> 6e-3. A
+    # change that rounds anything more (the scores, an accumulator, p in
+    # fp8) fails here on the chip.
+    def exact_walk(q, kp, vp, t, lim, layer):
+        with jax.default_matmul_precision("highest"):
+            return settled(A.paged_partials(
+                q, Q.StackedLayer(kp, layer), Q.StackedLayer(vp, layer), t,
+                lim, impl="xla"))
+
+    def kernel_walk(q, kp, vp, t, lim, layer):
+        return settled(A.paged_partials(
+            q, Q.StackedLayer(kp, layer), Q.StackedLayer(vp, layer), t, lim,
+            impl="auto"))
+
+    lo, hi = (300, 700) if page == 128 else (page + 1, 4 * page)  # rehearsal
+    cell_pages = -(-hi // page)
+    n_cell = rows * cell_pages + 1
+    for kc, gc in ((8, 4), (16, 1), (2, 4)):
+        table_c = (jax.random.permutation(next(keys), n_cell - 1) + 1).reshape(
+            rows, cell_pages).astype(jnp.int32)
+        limits_c = jax.random.randint(next(keys), (rows,), lo, hi + 1)
+        case(f"paged_decode_cell_K{kc}_G{gc}", kernel_walk, exact_walk,
+             (rnd((rows, kc * gc, D)), rnd((2, n_cell, page, kc, D)),
+              rnd((2, n_cell, page, kc, D)), table_c, limits_c,
+              jnp.int32(1)), 6e-3)
+
     T = s["verify"]
     qpos = limits[:, None] + jnp.arange(T)[None, :]
 

@@ -6303,6 +6303,12 @@ class Engine:
                 sites["paged_attention_stacked"])
             out["paged_attention_sliced_sites"] = float(
                 sites["paged_attention_sliced"])
+            # and what the Pallas kernel's dots were fed at those sites: the
+            # page as stored, or float32 tiles (stacked.note_arith)
+            out["paged_attention_native_sites"] = float(
+                sites["paged_attention_native"])
+            out["paged_attention_f32_sites"] = float(
+                sites["paged_attention_f32"])
         if self.m_forks or self.m_fork_clone_fallbacks:
             # Tree-batched fork sampling (ISSUE 18): branches admitted by
             # slot fork vs degraded to the N-clone path (fault/pressure).

@@ -10,17 +10,30 @@ the gather itself cannot overlap the matmul. BENCH_r04 put paged decode at
 
 This kernel walks each slot's page table IN-KERNEL ("Ragged Paged
 Attention", PAPERS.md): the pool stays in HBM (memory_space=ANY), and the
-kernel streams the listed pages through a double-buffered VMEM scratch with
-explicit async DMAs — page j+1 is in flight while page j is scored against
-the online-softmax running state. Each live KV byte crosses HBM→VMEM exactly
-once, the walk stops at the slot's OWN live-prefix bound (ragged, not the
-batch max), and idle slots (limits == 0) cost nothing.
+kernel streams the listed pages through a ring of VMEM page buffers with
+explicit async DMAs — pages j+1 .. j+ring-1 are in flight while page j is
+scored against the online-softmax running state (`_ring_depth`). Each live
+KV byte crosses HBM→VMEM exactly once, the walk stops at the slot's OWN
+live-prefix bound (ragged, not the batch max), and idle slots (limits == 0)
+cost nothing.
+
+What a page visit computes (ISSUE 32; docs/PAGED_ATTENTION.md). A page of a
+16- or 8-bit pool goes to the MXU AS IT IS STORED: its [page, K, D] tile is
+the [page·K, D] matrix of (token, head) rows, so one dot a pool a page
+scores every head's query rows against all of it and the columns of the
+other heads are masked with the dead rows (`_flat_rows` says when; the
+decode block of every paged model). Cutting each head's [page, D] tile out
+of that layout was most of a visit: 2 us over a 0.87 us DMA at K = 8, 4 us
+at K = 16. q and p enter the dots in bfloat16, which is what Mosaic's
+one-pass float32 dot made of them all along (2e-3 of the output against a
+float64 walk, before and after). A float32 pool and wide query tiles
+(prefill chunks) keep the per-head float32 tiles.
 
 Shapes (matching the XLA reference):
 - q rows     [B, K, QR, Dk] f32, 1/sqrt(D) pre-applied; QR = G query rows
   per kv head (G·T for the multi-query verify chunk).
-- k/v pool   [P, page, K, Dk|Dv] in the cache storage dtype (bf16/fp8 —
-  cast to f32 on read, same contract as every other cache reader), or a
+- k/v pool   [P, page, K, Dk|Dv] in the cache storage dtype (bf16/fp8: read
+  as stored, or cast to f32 on read in the per-head form), or a
   stacked.StackedLayer of the whole [L, P, page, K, D] pool: what the kernel
   is handed is ALWAYS the stack plus a layer index (a plain pool rides as a
   free [1, ...] view at layer 0), the index is one more scalar-prefetch
@@ -65,9 +78,40 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -1e30
 STAT_LANES = 128
+
+# Query rows (K·QR, every head's) up to which a narrow pool's page goes to
+# the MXU as stored (`_flat_rows`): the MXU's own row count. Past it the
+# wrong-head columns cost more rows streamed than the per-head tiles cost
+# to cut out, and the [rows, page·K] score array outgrows its registers.
+FLAT_MAX_ROWS = 128
+# VMEM the ring of page buffers may take, and its depth bounds (`_ring_depth`).
+RING_VMEM_BYTES = 3 * 1024 * 1024
+RING_MAX = 4
+
+
+def _flat_rows(k_dtype, v_dtype, num_kv: int, qr: int) -> bool:
+    """Does a page go to the MXU as it is stored? A `[page, K, D]` tile of a
+    16- or 8-bit pool IS the `[page·K, D]` matrix whose row n·K + h is token
+    n's head h, in HBM and in VMEM, as long as a token's K heads fill whole
+    32-bit words of a sublane (XLA:TPU then takes the reshape as a bitcast;
+    compiled for a v5e at K = 2, 8, 16 in bfloat16 and K = 8, 16 in fp8,
+    while fp8 at K = 2 was a copy of the pool). A float32 pool, an odd head
+    count and wide query tiles (prefill chunks) keep the per-head tiles."""
+    size = jnp.dtype(k_dtype).itemsize
+    return (size < 4 and jnp.dtype(v_dtype).itemsize == size
+            and (num_kv * size) % 4 == 0 and num_kv * qr <= FLAT_MAX_ROWS)
+
+
+def _ring_depth(page_bytes: int) -> int:
+    """Page buffers in the DMA ring: what RING_VMEM_BYTES holds of one
+    page's K and V, between the old double buffer and RING_MAX (a visit
+    that is shorter than its DMA wants two pages in flight behind the one
+    being scored; a fourth measured nothing more, PERF.md §6 PR 32)."""
+    return max(2, min(RING_MAX, RING_VMEM_BYTES // max(1, page_bytes)))
 
 
 def use_pallas(impl: str = "auto") -> bool:
@@ -95,6 +139,8 @@ def _ragged_paged_kernel(
     sink: int = 0,
     swin: int = 0,
     l1_span: int = 0,
+    ring: int = 2,
+    flat: bool = False,
 ):
     """Kernel body. Scalar-prefetch layout depends on the table layout:
 
@@ -106,10 +152,30 @@ def _ragged_paged_kernel(
         budget.
 
     Then: limits_ref [B] i32, sliding_ref [1] i32, layer_ref [1] i32 (all
-    prefetch), and the regular operands q_ref [1, K, QR, Dk] f32, qpos_ref
-    [1, QR, 1] i32, kvs_ref [2, K] f32 SMEM, k_hbm/v_hbm pools stacked over
-    layers (ANY), outputs acc/m/l, VMEM scratch kbuf/vbuf/acc_s/m_s/l_s and
-    the DMA semaphores.
+    prefetch), and the regular operands. What a page visit does with them
+    comes in two forms, chosen by `_flat_rows` from the pool's dtype, K and
+    QR (static):
+
+    PER HEAD (a float32 pool, wide query tiles): q_ref [1, K, QR, Dk] f32,
+        qpos_ref [1, QR, 1] i32, kvs_ref [2, K] f32 SMEM, k_hbm/v_hbm
+        [L, P, page, K, D] (ANY), outputs acc/m/l [1, K, QR, ·], scratch
+        kbuf/vbuf [ring, page, K, D], acc_s/m_s/l_s [K, QR, ·]. A static
+        unroll over heads: each head's [page, D] tile is cut out of the
+        page, upcast and scaled, and goes into a float32 dot.
+    AS STORED (`flat`: a 16- or 8-bit pool, K·QR <= FLAT_MAX_ROWS): the
+        page is the [C = page·K, D] matrix it is stored as (row n·K + h),
+        q_ref [1, R = K·QR, Dk] f32 (row h·QR + i, the k scale folded in by
+        the wrapper), qpos_ref [1, R, 1] i32, colhead_ref / colrow_ref
+        [1, C] i32 (h and n of a column), rowhead_ref [R, 1] i32, k_hbm/v_hbm
+        [L, P, C, D] (ANY), outputs [1, R, ·], scratch kbuf/vbuf
+        [ring, C, D], acc_s/m_s/l_s [R, ·]. ONE dot a pool a page: every
+        head's query rows against every (n, h) row, the columns of the
+        other heads masked with the dead rows, so `p @ V` over the same
+        C-long axis is each head's own sum. Nothing is cut out, upcast or
+        scaled per head; the MXU loads the same 2·K tiles either way.
+
+    The DMAs run `ring - 1` pages ahead of the page being scored, and a
+    slot's last visit starts the NEXT slot's first page (`handoff`).
 
     sink/swin (windowed+sink decode, docs/LONG_CONTEXT.md): a row is
     attended iff `gpos < sink` or `q_pos - gpos < swin`. The page walk then
@@ -129,29 +195,32 @@ def _ragged_paged_kernel(
         table_ref = refs[0]
         refs = refs[1:]
         table_width = table_ref.shape[1]
+    handoff = not swin
+    if handoff:
+        # SMEM [2] i32 scratch that outlives a program: did the slot before
+        # start this one's first page, and into which buffer of the ring
+        *refs, handed_ref = refs
     (
         limits_ref,  # scalar-prefetch [B] i32
         sliding_ref,  # scalar-prefetch [1] i32
         layer_ref,  # scalar-prefetch [1] i32 — which layer of the pools
-        q_ref,  # [1, K, QR, Dk] f32 (scale applied)
-        qpos_ref,  # [1, QR, 1] i32
-        kvs_ref,  # [2, K] f32 SMEM — per-head (k, v) dequant scales (fp8
-        # KV); ones when the pool is unscaled (multiply is exact identity)
-        k_hbm,  # [L, P, page, K, Dk] pool dtype, memory_space=ANY
-        v_hbm,  # [L, P, page, K, Dv]
-        acc_ref,  # out [1, K, QR, Dv] f32
-        m_ref,  # out [1, K, QR, STAT_LANES] f32
-        l_ref,  # out [1, K, QR, STAT_LANES] f32
-        kbuf,  # VMEM scratch [2, page, K, Dk] pool dtype
-        vbuf,  # VMEM scratch [2, page, K, Dv]
-        acc_s,  # VMEM scratch [K, QR, Dv] f32
-        m_s,  # VMEM scratch [K, QR, 1] f32
-        l_s,  # VMEM scratch [K, QR, 1] f32
-        sem,  # DMA semaphores [2, 2]
+        q_ref,  # f32, scale applied
+        qpos_ref,  # i32, a query row's position
+        *consts,  # per head: kvs_ref; as stored: colhead, colrow, rowhead
+        k_hbm,  # pool dtype, memory_space=ANY, stacked over layers
+        v_hbm,
+        acc_ref,  # out f32
+        m_ref,  # out f32, STAT_LANES wide
+        l_ref,
+        kbuf,  # VMEM scratch [ring, <a page>] pool dtype
+        vbuf,
+        acc_s,  # VMEM scratch f32: the running (acc, m, l)
+        m_s,
+        l_s,
+        sem,  # DMA semaphores [ring, 2]
     ) = refs
 
     b = pl.program_id(0)
-    QR = q_ref.shape[2]
     lim = limits_ref[b]
     layer = layer_ref[0]
     # This slot's own page count (ragged), clamped to the table width so a
@@ -175,55 +244,68 @@ def _ragged_paged_kernel(
         def col_of(j):
             return j
 
-    def tbl(j):
-        col = col_of(j)
+    def page_of(row, col):  # the pool page in a slot's table column
         if l1_span:
-            return l0_ref[l1_ref[b, col // l1_span], col % l1_span]
-        return table_ref[b, col]
+            return l0_ref[l1_ref[row, col // l1_span], col % l1_span]
+        return table_ref[row, col]
 
-    def dma_k(slot, j):
-        return pltpu.make_async_copy(
-            k_hbm.at[layer, tbl(j)], kbuf.at[slot], sem.at[slot, 0]
+    def copies(pid, buf):  # one page's K and V into a buffer of the ring
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[layer, pid], kbuf.at[buf], sem.at[buf, 0]),
+            pltpu.make_async_copy(
+                v_hbm.at[layer, pid], vbuf.at[buf], sem.at[buf, 1]),
         )
 
-    def dma_v(slot, j):
-        return pltpu.make_async_copy(
-            v_hbm.at[layer, tbl(j)], vbuf.at[slot], sem.at[slot, 1]
-        )
+    # A slot's first page is the one DMA nothing hides (a program was some
+    # 1.4 us beside 0.68 us a visit, PERF.md §6 PR 32), so the slot before
+    # starts it from its own last visit: the grid's programs run in order
+    # and the scratch outlives them. Visit j of this slot then lives in
+    # buffer (first + j) mod ring. Not under swin: where that walk starts
+    # depends on the next slot's own query positions.
+    if handoff:
+        handed = (b > 0) & (handed_ref[0] == 1)
+        first = jnp.where(handed, handed_ref[1], 0)
+        handed_ref[0] = 0
+    else:
+        handed, first = False, 0
+
+    def dmas(j):  # page j's two copies, into its buffer of the ring
+        return copies(page_of(b, col_of(j)), (first + j) % ring)
 
     acc_s[...] = jnp.zeros_like(acc_s)
     m_s[...] = jnp.full_like(m_s, NEG_INF)
     l_s[...] = jnp.zeros_like(l_s)
 
-    @pl.when(n_iter > 0)
-    def _warmup():
-        dma_k(0, 0).start()
-        dma_v(0, 0).start()
+    for ahead in range(ring - 1):  # the first pages ride before any is scored
+        mine_to_start = ahead < n_iter
+        if handoff and ahead == 0:
+            mine_to_start = mine_to_start & ~handed
 
-    def body(j, carry):
-        slot = j % 2
+        @pl.when(mine_to_start)
+        def _warmup(ahead=ahead):
+            for dma in dmas(ahead):
+                dma.start()
 
-        @pl.when(j + 1 < n_iter)
-        def _prefetch():  # next page rides the wire while this one computes
-            dma_k((j + 1) % 2, j + 1).start()
-            dma_v((j + 1) % 2, j + 1).start()
-
-        dma_k(slot, j).wait()
-        dma_v(slot, j).wait()
-
-        # Global row indices covered by the table column this step visits.
-        gpos = col_of(j) * page + jax.lax.broadcasted_iota(
-            jnp.int32, (QR, page), 1
-        )
+    def masked(gpos):
+        """Which of a page's rows a query row attends: gpos [1 | QR, ·]
+        global row indices against the slot's limit and the windows."""
         valid = gpos < lim
         if window:
             sl = sliding_ref[0] > 0
-            dist = qpos_ref[0] - gpos  # [QR, 1] - [QR, page]
+            dist = qpos_ref[0] - gpos  # [rows, 1] - [·, columns]
             valid = valid & (~sl | (dist < window))
         if swin:
             dist = qpos_ref[0] - gpos
             valid = valid & ((gpos < sink) | (dist < swin))
+        return valid
 
+    def visit_heads(slot, j):
+        QR = q_ref.shape[2]
+        (kvs_ref,) = consts
+        # Global row indices covered by the table column this step visits.
+        valid = masked(col_of(j) * page + jax.lax.broadcasted_iota(
+            jnp.int32, (QR, page), 1))
         for kh in range(num_kv):  # static unroll — one MXU pass per kv head
             q = q_ref[0, kh]  # [QR, Dk]
             # fp8 KV dequant happens HERE, in registers on the VMEM tile the
@@ -249,6 +331,57 @@ def _ragged_paged_kernel(
                 preferred_element_type=jnp.float32,
             )
             m_s[kh] = m_new
+
+    if flat:
+        colhead_ref, colrow_ref, rowhead_ref = consts
+        # Once a program: q in the MXU's dtype (what Mosaic's one-pass
+        # float32 dot made of it, page after page) and whose column is whose.
+        qb = q_ref[0].astype(jnp.bfloat16)  # [R, Dk]
+        mine = colhead_ref[...] == rowhead_ref[...]  # [1, C] == [R, 1]
+
+    def visit_flat(slot, j):
+        ok = mine & masked(col_of(j) * page + colrow_ref[...])  # [R, C]
+        # (a bfloat16 page goes as it is, the cast is none; fp8 -> bfloat16
+        # is exact)
+        s = jax.lax.dot_general(
+            qb, kbuf[slot].astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [R, C]: every head's rows against every (n, h) row
+        if softcap:
+            s = softcap * jnp.tanh(s / softcap)
+        s = jnp.where(ok, s, NEG_INF)
+        m_prev = m_s[...]  # [R, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(jnp.maximum(m_prev - m_new, -80.0))
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
+            p.astype(jnp.bfloat16), vbuf[slot].astype(jnp.bfloat16),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        )  # p is zero off its own head: the C-long sum is the head's own
+        m_s[...] = m_new
+
+    def body(j, carry):
+        @pl.when(j + ring - 1 < n_iter)
+        def _prefetch():  # later pages ride the wire while this one computes
+            for dma in dmas(j + ring - 1):
+                dma.start()
+
+        if handoff:
+            nxt = jnp.minimum(b + 1, pl.num_programs(0) - 1)
+
+            @pl.when((j == n_iter - 1) & (b + 1 < pl.num_programs(0))
+                     & (limits_ref[nxt] > 0))
+            def _hand_on():  # every later page of this slot is in: one is free
+                buf = (first + j + 1) % ring
+                for dma in copies(page_of(nxt, 0), buf):
+                    dma.start()
+                handed_ref[0] = 1
+                handed_ref[1] = buf
+
+        for dma in dmas(j):
+            dma.wait()
+        (visit_flat if flat else visit_heads)((first + j) % ring, j)
         return carry
 
     jax.lax.fori_loop(0, n_iter, body, 0)
@@ -330,10 +463,11 @@ def _latent_partials_rows(qr, pool, table, limits, interpret: bool):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from localai_tpu.ops.stacked import stacks_of
+    from localai_tpu.ops.stacked import note_arith, stacks_of
 
     B, _, QR, D = qr.shape
     stack, _, layer = stacks_of(pool, pool, "layer_kv_pool")
+    note_arith(native=False)  # float32 dots on the upcast tile, still
     L, P, page = stack.shape[:3]
     kernel = functools.partial(_latent_paged_kernel, page=page)
     acc, m, l = pl.pallas_call(
@@ -405,22 +539,26 @@ def _paged_partials_rows(
     kv_scale=None,  # [2, K] f32 per-head (k, v) dequant scales, or None
     sink: int = 0,  # windowed+sink decode (docs/LONG_CONTEXT.md)
     swin: int = 0,
+    ring: int | None = None,  # page buffers in the DMA ring (tests); None: `_ring_depth`
 ):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     from localai_tpu.ops import ptable as _pt
-    from localai_tpu.ops.stacked import stacks_of
+    from localai_tpu.ops.stacked import note_arith, stacks_of
 
     B, K, QR, Dk = qr.shape
     k_pool, v_pool, layer = stacks_of(k_pool, v_pool, "layer_kv_pool")
-    page = k_pool.shape[2]
+    L, P, page = k_pool.shape[:3]
     Dv = v_pool.shape[4]
+    flat = _flat_rows(k_pool.dtype, v_pool.dtype, K, QR)
+    note_arith(native=flat)
+    if ring is None:
+        ring = _ring_depth(page * K * (Dk * k_pool.dtype.itemsize
+                                       + Dv * v_pool.dtype.itemsize))
     sl_arr = jnp.asarray(
         sliding if sliding is not None else False
     ).reshape(1).astype(jnp.int32)
-    kvs = (jnp.ones((2, K), jnp.float32) if kv_scale is None
-           else kv_scale.astype(jnp.float32))
     if _pt.is_hier(table):
         l1, l0 = table
         l1_span = int(l0.shape[-1])
@@ -431,46 +569,82 @@ def _paged_partials_rows(
     kernel = functools.partial(
         _ragged_paged_kernel, page=page, num_kv=K,
         softcap=float(softcap), window=int(window),
-        sink=int(sink), swin=int(swin), l1_span=l1_span,
+        sink=int(sink), swin=int(swin), l1_span=l1_span, ring=ring, flat=flat,
     )
+    qpos_rows = qpos_rows.astype(jnp.int32)
+    if flat:
+        # The page as stored: rows h·QR + i of q against the [C, D] view of
+        # a page (a bitcast, `_flat_rows`). The k scale rides on q and the v
+        # scale on the finished sum: a multiply by ones is never traced.
+        R, C = K * QR, page * K
+        if kv_scale is not None:
+            qr = qr * kv_scale[0].astype(jnp.float32)[None, :, None, None]
+        col = np.arange(C, dtype=np.int32)
+        operands = (
+            qr.reshape(B, R, Dk), jnp.tile(qpos_rows, (1, K))[..., None],
+            jnp.asarray(col % K)[None], jnp.asarray(col // K)[None],
+            jnp.asarray(np.arange(R, dtype=np.int32) // QR)[:, None],
+            k_pool.reshape(L, P, C, Dk), v_pool.reshape(L, P, C, Dv),
+        )
+        lead, zeros = (R,), (0,)
+        const_specs = [
+            pl.BlockSpec((1, C), lambda b, *_: (0, 0)),
+            pl.BlockSpec((1, C), lambda b, *_: (0, 0)),
+            pl.BlockSpec((R, 1), lambda b, *_: (0, 0)),
+        ]
+        page_shape = (C,)
+    else:
+        kvs = (jnp.ones((2, K), jnp.float32) if kv_scale is None
+               else kv_scale.astype(jnp.float32))
+        operands = (qr, qpos_rows[..., None], kvs, k_pool, v_pool)
+        lead, zeros = (K, QR), (0, 0)
+        const_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]  # [2, K] scales
+        page_shape = (page, K)
+
+    def rows(n):  # one slot's block of a [B, *lead, n] array
+        return pl.BlockSpec((1, *lead, n), lambda b, *_: (b, *zeros, 0))
+
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tbl_args) + 3,
             grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, K, QR, Dk), lambda b, *_: (b, 0, 0, 0)),
-                pl.BlockSpec((1, QR, 1), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.SMEM),  # [2, K] kv scales
+                rows(Dk),
+                pl.BlockSpec((1, lead[-1], 1), lambda b, *_: (b, 0, 0)),
+                *const_specs,
                 pl.BlockSpec(memory_space=pl.ANY),  # pool stays in HBM
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=[
-                pl.BlockSpec((1, K, QR, Dv), lambda b, *_: (b, 0, 0, 0)),
-                pl.BlockSpec((1, K, QR, STAT_LANES), lambda b, *_: (b, 0, 0, 0)),
-                pl.BlockSpec((1, K, QR, STAT_LANES), lambda b, *_: (b, 0, 0, 0)),
-            ],
+            out_specs=[rows(Dv), rows(STAT_LANES), rows(STAT_LANES)],
             scratch_shapes=[
-                pltpu.VMEM((2, page, K, Dk), k_pool.dtype),
-                pltpu.VMEM((2, page, K, Dv), v_pool.dtype),
-                pltpu.VMEM((K, QR, Dv), jnp.float32),
-                pltpu.VMEM((K, QR, 1), jnp.float32),
-                pltpu.VMEM((K, QR, 1), jnp.float32),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((ring, *page_shape, Dk), k_pool.dtype),
+                pltpu.VMEM((ring, *page_shape, Dv), v_pool.dtype),
+                pltpu.VMEM((*lead, Dv), jnp.float32),
+                pltpu.VMEM((*lead, 1), jnp.float32),
+                pltpu.VMEM((*lead, 1), jnp.float32),
+                pltpu.SemaphoreType.DMA((ring, 2)),
+                *([] if swin else [pltpu.SMEM((2,), jnp.int32)]),  # handoff
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, K, QR, Dv), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, QR, STAT_LANES), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, QR, STAT_LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, *lead, n), jnp.float32)
+            for n in (Dv, STAT_LANES, STAT_LANES)
         ],
+        # the programs hand a DMA on to the next one: in order, on one core
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
     )(
         *tbl_args, limits.astype(jnp.int32), sl_arr,
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        qr, qpos_rows.astype(jnp.int32)[..., None], kvs, k_pool, v_pool,
+        jnp.asarray(layer, jnp.int32).reshape(1), *operands,
     )
+    if flat:
+        acc = acc.reshape(B, K, QR, Dv)
+        if kv_scale is not None:
+            acc = acc * kv_scale[1].astype(jnp.float32)[None, :, None, None]
+        m, l = m.reshape(B, K, QR, -1), l.reshape(B, K, QR, -1)
     return acc, m[..., :1], l[..., :1]
 
 

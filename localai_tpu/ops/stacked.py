@@ -113,6 +113,9 @@ def stacks_of(a, b, scope: str):
 _KEYS = {
     "quant_matmul": ("stacked", "sliced"),
     "paged_attention": ("paged_attention_stacked", "paged_attention_sliced"),
+    # not a stack's fate but the same kind of static choice: the arithmetic
+    # of a paged-attention kernel call (`note_arith`)
+    "paged_attention_arith": ("paged_attention_native", "paged_attention_f32"),
 }
 _ALL_KEYS = tuple(k for ks in _KEYS.values() for k in ks)
 
@@ -123,7 +126,9 @@ class SiteCounts:
     stack and the layer index) or "sliced" (the layer was sliced out first,
     for the XLA form or an unstacked kernel call). Quantized layer matmuls
     count under `stacked` / `sliced`, paged attention under
-    `paged_attention_stacked` / `paged_attention_sliced`. The choice is
+    `paged_attention_stacked` / `paged_attention_sliced`; beside them the
+    arithmetic of each Pallas paged-attention call, `paged_attention_native`
+    / `paged_attention_f32` (`note_arith`). The choice is
     static, so it is counted where it is made, once per trace. An engine
     owns one and traces its programs under `tracing(<program>)`."""
 
@@ -160,3 +165,13 @@ def note_site(stacked: bool, kernel: str = "quant_matmul") -> None:
     tally = _TALLY.get()
     if tally is not None:
         tally[_KEYS[kernel][0 if stacked else 1]] += 1
+
+
+def note_arith(native: bool) -> None:
+    """Count one Pallas paged-attention kernel call of the program being
+    traced by what its dots are fed (ops/paged_flash): `native`, the page
+    went to the MXU in the pool's own 16- or 8-bit dtype as it is stored
+    (key `paged_attention_native`), or each head's tile was upcast to
+    float32 first (`paged_attention_f32`: a float32 pool, a wide query
+    tile, the latent kernel). The XLA walk counts under neither."""
+    note_site(native, kernel="paged_attention_arith")

@@ -496,7 +496,8 @@ def test_stacked_kernel_under_scan_with_a_traced_index(form):
 
 
 # dense-cache programs hold no paged-attention site (ops/stacked.SiteCounts)
-_NO_PAGED_SITES = {"paged_attention_stacked": 0, "paged_attention_sliced": 0}
+_NO_PAGED_SITES = {"paged_attention_stacked": 0, "paged_attention_sliced": 0,
+                   "paged_attention_native": 0, "paged_attention_f32": 0}
 
 
 def _pallas_calls(jaxpr, name):
@@ -577,9 +578,10 @@ def _paged_decode_step(paged_impl):
 
 def test_paged_decode_step_hands_the_kernel_the_pool_and_counts_it():
     """In the PAGED decode step's jaxpr the one paged_attention call takes
-    both pools whole, [L, P, page, K, D]; nothing slices a layer's pool out
-    in front of it; the site counter saw 1 stacked, 0 sliced beside the
-    seven matmuls. With the XLA walk it saw 0 / 1, the slice is there (at
+    both pools whole, all L layers of them (a bfloat16 pool in the view it
+    is stored as, [L, P, page·K, D]: ISSUE 32); nothing slices a layer's
+    pool out in front of it; the site counter saw 1 stacked, 0 sliced, the
+    page handed on as stored, beside the seven matmuls. With the XLA walk it saw 0 / 1, the slice is there (at
     the walk's own site) and the numbers agree."""
     from localai_tpu.ops.stacked import SiteCounts
 
@@ -598,16 +600,21 @@ def test_paged_decode_step_hands_the_kernel_the_pool_and_counts_it():
                       jax.jit(fn)(*args)[0])
     calls, layer_pools, tally, got = seen["pallas"]
     assert len(calls) == 1  # once, in the layer scan
-    pools = [v.aval for v in calls[0].invars if v.aval.ndim == 5]
-    assert [p.shape for p in pools] == [pool_shape] * 2
+    L, P, page, K, D = pool_shape
+    pools = [v.aval for v in calls[0].invars
+             if v.aval.dtype == args[1].k.dtype and v.aval.ndim >= 4]
+    assert args[1].k.dtype == jnp.bfloat16
+    assert [p.shape for p in pools] == [(L, P, page * K, D)] * 2
     assert pool_shape[0] == cfg.num_layers > 1
     assert not layer_pools
     assert tally == {"traces": 1, "stacked": 7, "sliced": 0,
-                     "paged_attention_stacked": 1, "paged_attention_sliced": 0}
+                     "paged_attention_stacked": 1, "paged_attention_sliced": 0,
+                     "paged_attention_native": 1, "paged_attention_f32": 0}
     calls, layer_pools, tally, want = seen["xla"]
     assert not calls and len(layer_pools) >= 2  # K and V, sliced at the walk
     assert tally == {"traces": 1, "stacked": 7, "sliced": 0,
-                     "paged_attention_stacked": 0, "paged_attention_sliced": 1}
+                     "paged_attention_stacked": 0, "paged_attention_sliced": 1,
+                     "paged_attention_native": 0, "paged_attention_f32": 0}
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-2, atol=2e-2)
 
