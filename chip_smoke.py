@@ -74,6 +74,11 @@ SIZES = {
         hybrid=dict(kda_layers=20, slots=64, heads=32, dk=128, latent=640,
                     mla_layers=7, mla_heads=32, pages=513,
                     moe=dict(layers=26, experts=32, hidden=2304, ffn=1024)),
+        # solar-open2-250b as chip 0 of stage 0 holds it: 6 KDA layers of 64
+        # heads, GQA at 8 KV heads x 8, 40 held experts of width 1280 in each
+        # of 8 layers
+        hybrid_gqa=dict(kda_layers=6, slots=64, heads=64, dk=128, K=8, G=8,
+                        moe=dict(layers=8, experts=40, hidden=4096, ffn=1280)),
     ),
     "tiny": dict(
         arch="tiny", slots=4, context=512, page=16,
@@ -85,6 +90,8 @@ SIZES = {
         hybrid=dict(kda_layers=3, slots=4, heads=4, dk=16, latent=64,
                     mla_layers=2, mla_heads=4, pages=33,
                     moe=dict(layers=2, experts=4, hidden=64, ffn=32)),
+        hybrid_gqa=dict(kda_layers=2, slots=4, heads=4, dk=16, K=2, G=4,
+                        moe=dict(layers=2, experts=3, hidden=64, ffn=40)),
     ),
 }
 
@@ -142,7 +149,7 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
     s = SIZES[size]
     B, K, G, D, page = s["B"], s["K"], s["G"], s["D"], s["page"]
     H = K * G
-    keys = iter(jax.random.split(jax.random.key(21), 64))
+    keys = iter(jax.random.split(jax.random.key(21), 96))
     cases: dict[str, dict] = {}
 
     def rnd(shape, dtype=jnp.bfloat16, scale=1.0):
@@ -296,6 +303,20 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
               rnd((2, n_cell, page, kc, D)), table_c, limits_c,
               jnp.int32(1)), 6e-3)
 
+    # The second hybrid's cache layers (solar-open2-250b): 64 rows at 8 KV
+    # heads x 8 query heads, a shape no other cell runs. Same oracle and
+    # rounding as the three above -> 6e-3.
+    hg = s["hybrid_gqa"]
+    n_hg = hg["slots"] * cell_pages + 1
+    table_g = (jax.random.permutation(next(keys), n_hg - 1) + 1).reshape(
+        hg["slots"], cell_pages).astype(jnp.int32)
+    case(f"paged_decode_cell_K{hg['K']}_G{hg['G']}_rows{hg['slots']}",
+         kernel_walk, exact_walk,
+         (rnd((hg["slots"], hg["K"] * hg["G"], D)),
+          rnd((2, n_hg, page, hg["K"], D)), rnd((2, n_hg, page, hg["K"], D)),
+          table_g, jax.random.randint(next(keys), (hg["slots"],), lo, hi + 1),
+          jnp.int32(1)), 6e-3)
+
     T = s["verify"]
     qpos = limits[:, None] + jnp.arange(T)[None, :]
 
@@ -407,6 +428,16 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
         jax.nn.sigmoid(rnd((Bk, Hk), jnp.float32)),
         jnp.int32(0), jnp.int32(Lk - 1))
     case("kda_decode_stacked", kda_two("auto"), kda_two("xla"), kda_args, 1e-4)
+    # The same at solar-open2-250b's 64 heads ([6, 64, 64, 128, 128], a slot's
+    # row twice as large) and with beta in (0, 2), as `kda_neg_eigval` asks.
+    Lg, Bg, Hg, dg = hg["kda_layers"], hg["slots"], hg["heads"], hg["dk"]
+    case("kda_decode_stacked_h64_beta2", kda_two("auto"), kda_two("xla"), (
+        rnd((Lg, Bg, Hg, dg, dg), jnp.float32),
+        unit(rnd((Bg, Hg, dg))) * dg ** -0.5, unit(rnd((Bg, Hg, dg))),
+        rnd((Bg, Hg, dg), jnp.float32),
+        -jnp.exp(rnd((Bg, Hg, dg), jnp.float32) * 2.0 - 3.0),
+        2.0 * jax.nn.sigmoid(rnd((Bg, Hg), jnp.float32)),
+        jnp.int32(0), jnp.int32(Lg - 1)), 1e-4)
 
     # MLA's absorbed decode over the latent pool stacked over the MLA layers
     # ([L, P, page, 1, 640]: one row a token, key and value): the latent
@@ -438,7 +469,7 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
     # same kernel and `_tile` rule as olmoe's [16 x 64, 2048, 1024].
     hm = hy["moe"]
 
-    def held_stack(kin, kout):
+    def held_stack(kin, kout, hm=hm):
         return jax.jit(lambda kk: jax.lax.map(
             lambda k1: Q.quantize_tensor(jax.random.normal(
                 k1, (hm["experts"], kin, kout), jnp.float32) * 0.02),
@@ -447,6 +478,15 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
     case("moe_int8_held_experts_stacked", experts("auto"), experts("xla"),
          (rnd((Bk, hm["hidden"])), held_stack(hm["hidden"], hm["ffn"]),
           held_stack(hm["ffn"], hm["hidden"]),
+          jnp.int32(hm["layers"] // 3), jnp.int32(hm["layers"] - 1)), 2e-2)
+
+    # solar-open2-250b's held stacks [8 x 40, 4096, 1280] and [8 x 40, 1280,
+    # 4096]: the first expert width (10 lane tiles) and the first share (40
+    # experts a layer) that are no power of two.
+    hm = hg["moe"]
+    case("moe_int8_held_experts_stacked_w1280", experts("auto"), experts("xla"),
+         (rnd((Bg, hm["hidden"])), held_stack(hm["hidden"], hm["ffn"], hm),
+          held_stack(hm["ffn"], hm["hidden"], hm),
           jnp.int32(hm["layers"] // 3), jnp.int32(hm["layers"] - 1)), 2e-2)
 
     head = rnd((s["vocab"], hid), jnp.float32, 0.02)

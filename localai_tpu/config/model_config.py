@@ -124,6 +124,13 @@ class ModelConfig:
     # the shared expert); the exchange with the other `of - 1` holders is the
     # deployment's, not this process's. Absent = every expert.
     expert_share: Optional[list] = None
+    # Two more keys of the deployment, for a model cut over pipeline stages
+    # and a vocabulary-parallel head: this process runs the model's first
+    # `stage_layers` layers (stage 0; 0 = all), and holds rows [0, vocab_rows)
+    # of the embedding and the head (0 = all). Nothing stands in for the
+    # other stages or rows.
+    stage_layers: int = 0
+    vocab_rows: int = 0
     # On-demand KV page growth (docs/PAGED_ATTENTION.md): admission
     # reserves only the prompt's pages + this headroom; decode grows the
     # table as the context actually extends. LOCALAI_KV_PAGE_HEADROOM

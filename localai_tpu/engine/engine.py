@@ -1595,6 +1595,7 @@ class Engine:
         self.m_moe_picks = 0  # under an expert share: the router's picks,
         self.m_moe_picks_here = 0  # and those of an expert held here
         self.m_state_restores = 0  # recurrent-state rows recomputed (preempt)
+        self.m_admit_splits = 0  # admission groups cut by state.admit_rows
         self.m_moe_slots_hit = 0
         self.m_moe_rows_busiest = 0
         self.m_moe_rows_mean = 0.0
@@ -6289,6 +6290,8 @@ class Engine:
                     self.cfg, self.cache.conv.dtype))
             out["state_snapshots"] = 0.0  # rows are dropped, never copied
             out["state_restores"] = float(self.m_state_restores)
+            out["admit_splits"] = float(self.m_admit_splits)
+            out["admit_rows_max"] = float(rstate.admit_rows(self.cfg))
             out["prefix_reuse_off"] = float(
                 self.ecfg.prefix_cache_entries > 0)
         # Call sites over every program traced so far: the Pallas kernel read
@@ -7461,15 +7464,20 @@ class Engine:
             chunks: list[list[tuple[GenRequest, RequestHandle]]] = [[gh] for gh in special]
             idx = 0
             # A hybrid model's admission holds its prompts' KDA operands in
-            # float32, every request's at once: bounded rows a program.
-            m_max = (max(1, rstate.ADMIT_ROWS // bucket) if self.cfg.is_hybrid
-                     else len(plain))
+            # float32, every request's at once: bounded bytes a program.
+            m_max = (max(1, rstate.admit_rows(self.cfg) // bucket)
+                     if self.cfg.is_hybrid else len(plain))
+            unbounded = bin(len(plain)).count("1")  # programs without m_max
             while idx < len(plain):
                 m = 1
                 while m * 2 <= min(len(plain) - idx, m_max):
                     m *= 2
                 chunks.append(plain[idx: idx + m])
                 idx += m
+            if len(chunks) - len(special) > unbounded:
+                self.m_admit_splits += 1
+                self._jnote("admit_split", a=float(len(chunks) - len(special)),
+                            b=float(len(plain)))
             for chunk in chunks:
                 try:
                     self._dispatch_admit(

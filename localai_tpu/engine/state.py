@@ -1,11 +1,13 @@
 """The second kind of per-slot state: a hybrid model's recurrent state.
 
 A slot of a model with KDA layers (cfg.is_hybrid, models/llama.py) holds two
-things of different shape. Its cache rows grow with the context and live in
-pages the KV manager hands out. Its recurrent state is fixed in size: per KDA
-layer a [H, dk, dv] float32 matrix a head and the short conv's last inputs,
-42 MB a slot for Kimi-Linear-48B-A3B whatever the context. It needs no
-allocator: row i of the two arrays below belongs to slot index i, always.
+things of different shape. Its cache rows, of whichever kind the model's cache
+layers write (MLA's latent rows, or GQA's keys and values a KV head), grow
+with the context and live in pages the KV manager hands out. Its recurrent
+state is fixed in size: per KDA layer a [H, dk, dv] float32 matrix a head and
+the short conv's last inputs, `row_bytes` a slot whatever the context. It
+needs no allocator: row i of the two arrays below belongs to slot index i,
+always.
 
 The arrays ride in the cache pytree (`llama.KVCache.state`, `.conv`), so every
 program that carries the cache carries them, donated with it, and the
@@ -37,13 +39,24 @@ import jax
 import jax.numpy as jnp
 
 
-# Most prompt rows (requests x bucket) one admission program of a hybrid model
-# takes: the chunkwise KDA prefill holds its operands in float32 for every
-# request at once, some 0.5 MB a token at Kimi-Linear's widths (1.1 GB of
-# temporaries at 2,048 rows beside 11.7 GB held: compiled for the v5e,
-# PERF.md PR 31). A larger group of one bucket is admitted as several
-# programs in turn.
-ADMIT_ROWS = 2048
+# What the float32 temporaries of one admission program of a hybrid model may
+# take: the chunkwise KDA prefill holds its operands for every request of the
+# group at once (1.1 GB compiled for the v5e at this bound beside 11.7 GB
+# held, PERF.md PR 31). A larger group of one bucket is admitted as several
+# programs in turn (the `admit_split` gauge and journal event).
+ADMIT_BYTES = 1 << 30
+
+
+def admit_rows(cfg) -> int:
+    """Most prompt rows (requests x bucket) one admission program takes under
+    `ADMIT_BYTES`, from the model's own widths: the widest temporaries are
+    the diagonal blocks' pairwise exponents and their exponentials,
+    [SUB, SUB, dk] float32 a sub-block and head each (ops/kda.py), so
+    2·H·SUB·dk·4 bytes a prompt token: 0.5 MB and 2,048 rows at 32 heads of
+    128, 1 MB and 1,024 rows at 64."""
+    from localai_tpu.ops.kda import SUB
+
+    return max(1, ADMIT_BYTES // (2 * cfg.kda_heads * SUB * cfg.kda_head_dim * 4))
 
 
 def refuse(cfg, ecfg, plan, draft_cfg, spec_mode: str) -> None:
@@ -51,8 +64,8 @@ def refuse(cfg, ecfg, plan, draft_cfg, spec_mode: str) -> None:
     hybrid model as configured. Called once, before anything is allocated."""
     no = []
     if ecfg.kv_pages <= 0:
-        no.append("a dense KV cache (set kv_pages > 0: the latent rows live "
-                  "in the paged pool)")
+        no.append("a dense KV cache (set kv_pages > 0: the cache layers' "
+                  "rows live in the paged pool)")
     if plan.tp > 1 or plan.sp > 1 or plan.ep > 1 or plan.dp > 1:
         no.append(f"tp/sp/ep/dp > 1 (plan {plan}: the recurrent state and "
                   "the expert share are not sharded)")
@@ -65,11 +78,13 @@ def refuse(cfg, ecfg, plan, draft_cfg, spec_mode: str) -> None:
     if ecfg.attention_window or ecfg.kv_spill_bytes:
         no.append("windowed+sink attention and page spill")
     if float(ecfg.kv_scale) != 1.0:
-        no.append("a scaled fp8 latent pool (kv_scale != 1)")
+        no.append("a scaled fp8 pool (kv_scale != 1)")
     if no:
         raise ValueError(
-            f"{cfg.name} keeps a per-slot recurrent state (KDA layers); this "
-            "engine does not run it with: " + "; ".join(no))
+            f"{cfg.name} keeps a per-slot recurrent state (KDA layers, "
+            f"{row_bytes(cfg, cfg.dtype)} bytes a slot) beside its "
+            f"{'latent' if cfg.is_mla else 'K/V'} cache rows; this engine "
+            "does not run it with: " + "; ".join(no))
 
 
 def allocate(cfg, slots: int, conv_dtype, sharding=None):

@@ -33,6 +33,8 @@ _LAYER_MAP = {
     "wk": ("self_attn.k_proj.weight", True),
     "wv": ("self_attn.v_proj.weight", True),
     "wo": ("self_attn.o_proj.weight", True),
+    # gated attention's output gate (ArchConfig.attn_gate; absent elsewhere)
+    "wg": ("self_attn.g_proj.weight", True),
     "bq": ("self_attn.q_proj.bias", False),
     "bk": ("self_attn.k_proj.bias", False),
     "bv": ("self_attn.v_proj.bias", False),
@@ -248,7 +250,7 @@ def load_hf_checkpoint(
             return {k: jnp.asarray(v) for k, v in qt.items()}
         return put(path, arr)
 
-    _QUANT_KEYS = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}
+    _QUANT_KEYS = {"wq", "wk", "wv", "wo", "wg", "w_gate", "w_up", "w_down"}
 
     # Phi-3 fuses qkv and gate/up into single tensors; serve the per-head
     # names by row-block slicing so the rest of the loader stays uniform.
@@ -986,6 +988,9 @@ def save_hf_checkpoint(cfg: ArchConfig, params: Params, ckpt_dir: str) -> None:
         "rms_norm_eps": cfg.rms_eps,
         "tie_word_embeddings": cfg.tie_embeddings,
     }
+    if not cfg.attn_rope or cfg.attn_gate:
+        hf_config["use_rope"] = cfg.attn_rope
+        hf_config["use_gqa_gate"] = cfg.attn_gate
     if cfg.is_moe:
         hf_config["num_experts" if olmoe else "num_local_experts"] = cfg.num_experts
         hf_config["num_experts_per_tok"] = cfg.num_experts_per_token
@@ -1268,6 +1273,12 @@ def arch_from_hf_config(ckpt_dir: str) -> ArchConfig:
     # projection; softmax over all experts, then top-k, weights renormalised
     # only when norm_topk_prob. intermediate_size is the expert width (there
     # is no dense MLP).
+    if hf.get("linear_attn_config"):
+        # A hybrid's two attention stacks (models/llama.py) have no loader
+        # yet: its presets serve synthetic weights (models/config.py).
+        raise ValueError(
+            f"{model_type}: checkpoints with linear-attention layers are not "
+            "loaded yet; serve the preset with synthetic weights")
     olmoe = model_type == "olmoe"
     if olmoe and hf.get("clip_qkv") is not None:
         raise ValueError("olmoe clip_qkv is not supported (published OLMoE "
@@ -1298,6 +1309,9 @@ def arch_from_hf_config(ckpt_dir: str) -> ArchConfig:
         # Gemma ties embeddings but its configs often omit the flag.
         tie_embeddings=hf.get("tie_word_embeddings", gemma),
         attn_qkv_bias=(model_type in ("qwen2", "qwen2_vl", "qwen2_vl_text")),
+        # gated NoPE attention (Solar-Open2's key names)
+        attn_rope=bool(hf.get("use_rope", True)),
+        attn_gate=bool(hf.get("use_gqa_gate", False)),
         mrope_section=mrope_section,
         activation=("gelu_tanh" if "gelu" in act else "silu"),
         embed_scale=gemma,

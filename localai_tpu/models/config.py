@@ -164,18 +164,34 @@ class ArchConfig:
     # `dataclasses.replace(cfg, ...)`. 0/0 = full attention (default).
     attention_sink: int = 0
     attention_window: int = 0
+    # GQA whose q and k are never rotated (Solar-Open2's `use_rope` false):
+    # `rope_theta` then has nothing to act on.
+    attn_rope: bool = True
+    # GQA output gate (Solar-Open2's `use_gqa_gate`): the attention output is
+    # multiplied, element by element over heads x head width, by
+    # sigmoid(x W_g) of the layer's normed input before W_o ("wg" [D, H·Hd]).
+    attn_gate: bool = False
     # Hybrid linear attention (Kimi-Linear, arXiv:2510.26692): one kind per
     # layer, "kda" (Kimi Delta Attention: a per-slot recurrent state
     # [kda_heads, kda_head_dim, kda_head_dim] f32 plus the short conv's last
-    # kda_conv-1 inputs, no cache rows) or "mla" (latent rows in the KV
-    # cache). Empty = every layer is the attention `kv_lora_rank` says.
-    # models/llama._scan_hybrid needs every "mla" layer to follow a "kda"
-    # layer and the dense-prefix layers to be "kda".
+    # kda_conv-1 inputs, no cache rows) or a kind that writes cache rows:
+    # "mla" (latent rows) or "gqa" (the model's ordinary K/V rows), whichever
+    # `kv_lora_rank` says. Empty = every layer is that attention.
+    # models/llama._scan_hybrid needs every cache layer to stand beside a
+    # "kda" layer, all of them behind theirs or all of them in front, and the
+    # dense-prefix layers to be "kda".
     layer_kinds: tuple = ()
     kda_heads: int = 0
     kda_head_dim: int = 128
     kda_conv: int = 4  # short_conv_kernel_size
     kda_gate_rank: int = 0  # low-rank width of the decay and output gates
+    # beta = 2·sigmoid(.) in (0, 2) instead of (0, 1): I - beta k k^T then has
+    # the eigenvalue 1 - beta in (-1, 1) (Solar-Open2's `kda_allow_neg_eigval`).
+    kda_neg_eigval: bool = False
+    # Synthetic init only (llama.init_special): the range the KDA decay's
+    # step is drawn from, log-uniform. The default is a hundredth of fla's
+    # [1e-3, 1e-1] (why: llama.KDA_DT); a preset may ask for another.
+    kda_init_dt: tuple = (1e-5, 1e-3)
     # MLA whose rope dims are never rotated (Kimi-Linear's `mla_use_nope`).
     mla_rope: bool = True
     # Latent cache rows are stored padded to a multiple of this many values
@@ -203,6 +219,12 @@ class ArchConfig:
         if not self.layer_kinds:
             return tuple(range(self.num_layers))
         return tuple(i for i, k in enumerate(self.layer_kinds) if k != "kda")
+
+    @property
+    def cache_stack(self) -> str:
+        """The key of a hybrid model's second weight stack in its param tree:
+        the attention weights of its cache layers, whichever kind they are."""
+        return "mla_layers" if self.is_mla else "gqa_layers"
 
     @property
     def cache_layers(self) -> int:
@@ -380,6 +402,39 @@ PRESETS: dict[str, ArchConfig] = {
         qk_nope_head_dim=16,
         qk_rope_head_dim=16,
         v_head_dim=16,
+    ),
+    "tiny-solar-open2": ArchConfig(
+        # Solar-Open2-shaped tiny: two periods of 1 gated NoPE GQA : 3 KDA
+        # with the cache layer LEADING its period, beta in (0, 2), every
+        # layer MoE (no dense prefix), sigmoid router with correction bias
+        # in one group, renormalised, unscaled, a shared expert, an expert
+        # count (24) that is no power of two, untied head.
+        name="tiny-solar-open2",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=8,
+        num_heads=8,
+        num_kv_heads=2,
+        head_dim=16,
+        max_position=512,
+        attn_rope=False,
+        attn_gate=True,
+        layer_kinds=("gqa", "kda", "kda", "kda") * 2,
+        kda_heads=4,
+        kda_head_dim=16,
+        kda_conv=4,
+        kda_gate_rank=16,
+        kda_neg_eigval=True,
+        moe_family="deepseek",
+        num_experts=24,
+        num_experts_per_token=4,
+        n_shared_experts=1,
+        moe_intermediate_size=40,
+        routed_scaling_factor=1.0,
+        scoring_func="sigmoid",
+        router_bias=True,
+        norm_topk_prob=True,
     ),
     "llama-3.2-1b": ArchConfig(
         name="llama-3.2-1b",
@@ -573,6 +628,57 @@ PRESETS: dict[str, ArchConfig] = {
         qk_nope_head_dim=128,
         qk_rope_head_dim=64,
         v_head_dim=128,
+    ),
+    "solar-open2-250b": ArchConfig(
+        # upstage/Solar-Open2-250B config.json (`solar_open2`, 250B-A15B):
+        # 48 layers in periods of one gated NoPE GQA layer (`gqa_layers` 0,
+        # 4, ..., 44: 64 query / 8 KV heads of 128, `use_rope` false,
+        # `use_gqa_gate`) and three KDA layers (64 heads of 128, conv 4,
+        # `kda_allow_neg_eigval`, low-rank gates: `kda_use_full_proj` false);
+        # every layer 320 routed experts of 1280 top-8 (sigmoid, correction
+        # bias, one group, renormalised, x1) plus one shared expert.
+        # `intermediate_size` 10240 is published and has no layer to live in
+        # (`first_k_dense_replace` 0).
+        name="solar-open2-250b",
+        vocab_size=196608,
+        hidden_size=4096,
+        intermediate_size=10240,
+        num_layers=48,
+        num_heads=64,
+        num_kv_heads=8,
+        head_dim=128,
+        rope_theta=10000.0,
+        max_position=1048576,
+        rms_eps=1e-5,
+        attn_rope=False,
+        attn_gate=True,
+        layer_kinds=tuple("gqa" if i % 4 == 0 else "kda" for i in range(48)),
+        kda_heads=64,
+        kda_head_dim=128,
+        kda_conv=4,
+        kda_gate_rank=128,
+        kda_neg_eigval=True,
+        # fla's own step. At a hundredth of it (the default) a KDA layer of
+        # this model doubles a perturbation of its input at 1,000 tokens of
+        # context, and honest bfloat16 compute ends 14% off the float32
+        # hidden state after eight layers: as much, in log-probabilities, as
+        # separates the 20 best ids, so the benchmark's check lost the
+        # reference's best id from the top 20 in 1 run of 12. At fla's step
+        # it is 4.6% against 25% for a cache held one precision lower
+        # (PERF.md section 6, PR 34).
+        kda_init_dt=(1e-3, 1e-1),
+        moe_family="deepseek",
+        num_experts=320,
+        num_experts_per_token=8,
+        first_k_dense=0,
+        n_shared_experts=1,
+        moe_intermediate_size=1280,
+        routed_scaling_factor=1.0,
+        scoring_func="sigmoid",
+        router_bias=True,
+        norm_topk_prob=True,
+        n_group=1,
+        topk_group=1,
     ),
 }
 

@@ -77,7 +77,8 @@ class KVCache(NamedTuple):
     # the cache through every program that carries it, donated with it:
     # state [Lk, B_slots, H, dk, dv] f32 and conv [Lk, B_slots, conv-1,
     # 3·H·dk] (the short conv's last inputs), Lk the KDA layers; k/v then
-    # hold rows for the cache_layers (MLA) only. None everywhere else.
+    # hold rows for the cache_layers only (latent rows under MLA, the
+    # ordinary [K, Hd] keys and values otherwise). None everywhere else.
     state: Any = None
     conv: Any = None
 
@@ -96,16 +97,16 @@ def _dtype(cfg: ArchConfig):
 
 
 def _init_attn_layers(cfg: ArchConfig, rnd, keys, L: int,
-                      mla_stack: bool = False) -> Params:
+                      cache_stack: bool = False) -> Params:
     """Attention + norm keys for a stack of L layers (standard or MLA). A
-    hybrid model's layer stacks keep the two norms only; `mla_stack` builds
-    its "mla_layers" (the MLA weights, no norms)."""
+    hybrid model's layer stacks keep the two norms only; `cache_stack` builds
+    its `cfg.cache_stack` (the cache layers' attention weights, no norms)."""
     dt = _dtype(cfg)
     D = cfg.hidden_size
     H, K, Hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     layers: Params = {"attn_norm": jnp.ones((L, D), dt),
                       "mlp_norm": jnp.ones((L, D), dt)}
-    if cfg.is_hybrid and not mla_stack:
+    if cfg.is_hybrid and not cache_stack:
         return layers  # the attention weights live in their kinds' stacks
     if cfg.is_mla:
         r, rot = cfg.kv_lora_rank, cfg.qk_rope_head_dim
@@ -124,13 +125,17 @@ def _init_attn_layers(cfg: ArchConfig, rnd, keys, L: int,
         layers["w_kb"] = rnd(next(keys), (L, H, n, r))
         layers["w_vb"] = rnd(next(keys), (L, H, vd, r))
         layers["wo"] = rnd(next(keys), (L, H * vd, D))
-        if mla_stack:
+        if cache_stack:
             del layers["attn_norm"], layers["mlp_norm"]
         return layers
+    if cache_stack:
+        layers = {}
     layers["wq"] = rnd(next(keys), (L, D, H * Hd))
     layers["wk"] = rnd(next(keys), (L, D, K * Hd))
     layers["wv"] = rnd(next(keys), (L, D, K * Hd))
     layers["wo"] = rnd(next(keys), (L, H * Hd, D))
+    if cfg.attn_gate:
+        layers["wg"] = rnd(next(keys), (L, D, H * Hd))
     if cfg.post_norms:
         layers["post_attn_norm"] = jnp.ones((L, D), dt)
         layers["post_ffw_norm"] = jnp.ones((L, D), dt)
@@ -147,17 +152,18 @@ def _init_attn_layers(cfg: ArchConfig, rnd, keys, L: int,
     return layers
 
 
-def init_special(name: str, key, shape):
+def init_special(name: str, key, shape, step=None):
     """The leaves a normal draw at 0.02 would make degenerate (KDA's decay:
-    A as fla's KimiDeltaAttention draws it, the step from `KDA_DT`; the
-    short conv), float32. None for any other leaf."""
+    A as fla's KimiDeltaAttention draws it, the step from `step`, the model's
+    `kda_init_dt`, `KDA_DT` by default; the short conv), float32. None for
+    any other leaf."""
     if name == "conv_w":  # four taps that pass their input on at its size
         return jax.random.normal(key, shape, jnp.float32) * 0.5
     if name == "A_log":  # A in U(1, 16)
         return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
-    if name == "dt_bias":  # softplus^-1 of dt, log-uniform in KDA_DT
+    if name == "dt_bias":  # softplus^-1 of dt, log-uniform in `step`
         dt = jnp.exp(jax.random.uniform(
-            key, shape, jnp.float32, *(jnp.log(x) for x in KDA_DT)))
+            key, shape, jnp.float32, *(jnp.log(x) for x in step or KDA_DT)))
         return dt + jnp.log(-jnp.expm1(-dt))
     return None
 
@@ -194,7 +200,8 @@ def _init_kda_layers(cfg: ArchConfig, rnd, keys, L: int) -> Params:
             "conv_w", next(keys), (L, c, 3 * H * dk)).astype(_dtype(cfg)),
         "f_down": rnd(next(keys), (L, D, r)),
         "f_up": rnd(next(keys), (L, r, H * dk)),
-        "dt_bias": init_special("dt_bias", next(keys), (L, H * dk)),
+        "dt_bias": init_special("dt_bias", next(keys), (L, H * dk),
+                                cfg.kda_init_dt),
         "A_log": init_special("A_log", next(keys), (L, H)),
         "w_beta": rnd(next(keys), (L, D, H)),
         "g_down": rnd(next(keys), (L, D, r)),
@@ -258,8 +265,9 @@ def init_params(cfg: ArchConfig, key: jnp.ndarray, scale: float = 0.02) -> Param
         hk = iter(jax.random.split(jax.random.fold_in(key, 1), 32))
         params["kda_layers"] = _init_kda_layers(
             cfg, rnd, hk, len(cfg.kda_layers))
-        params["mla_layers"] = _init_attn_layers(
-            cfg, rnd, hk, cfg.cache_layers, mla_stack=True)
+        cache_stack = cfg.cache_stack  # "mla_layers" | "gqa_layers"
+        params[cache_stack] = _init_attn_layers(
+            cfg, rnd, hk, cfg.cache_layers, cache_stack=True)
     return params
 
 
@@ -713,6 +721,17 @@ def _attn_out(cfg: ArchConfig, lp: Params, attn_flat: jnp.ndarray,
     return a
 
 
+@jax.named_scope("attention")
+def _attn_gated(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
+                attn_flat: jnp.ndarray, mesh=None) -> jnp.ndarray:
+    """The output gate of gated attention (`cfg.attn_gate`): the attention
+    output times sigmoid(x W_g), element by element over heads x head width,
+    x the layer's normed input; what `_attn_out` then projects."""
+    g = matmul(x, lp["wg"], cfg.quant_kernel, mesh, "col")
+    return (attn_flat.astype(jnp.float32)
+            * jax.nn.sigmoid(g.astype(jnp.float32))).astype(attn_flat.dtype)
+
+
 @jax.named_scope("mlp")
 def _mlp_out(cfg: ArchConfig, lp: Params, x: jnp.ndarray, ep: int = 1,
              mesh=None, lora=None, picks=None) -> jnp.ndarray:
@@ -1008,7 +1027,9 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
     else:
         q, k, v = _attn_proj_qkv(cfg, lp, x, mesh, lora=lora)
         with jax.named_scope("attention"):
-            if mrope_ang is not None:
+            if not cfg.attn_rope:
+                pass  # NoPE: the causal mask is all the order there is
+            elif mrope_ang is not None:
                 q, k = rope_rotate(q, mrope_ang), rope_rotate(k, mrope_ang)
             elif one:
                 q = apply_rope(q[:, None], pos[:, None], inv)[:, 0]
@@ -1017,6 +1038,8 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
                 q, k = apply_rope(q, pos, inv), apply_rope(k, pos, inv)
             attn = attend(q, k, v, sliding, *cache)
         attn = attn.reshape(*h.shape[:-1], -1)
+        if cfg.attn_gate:
+            attn = _attn_gated(cfg, lp, x, attn, mesh)
         emit = (k, v)
     h = h + _attn_out(cfg, lp, attn, mesh, lora=lora)
     x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
@@ -1024,15 +1047,16 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
 
 
 # --------------------------------------------------------------------------- #
-# Hybrid linear attention (Kimi-Linear): KDA layers with a per-slot recurrent
-# state beside MLA layers with latent cache rows (cfg.layer_kinds).
+# Hybrid linear attention (Kimi-Linear, Solar-Open2): KDA layers with a
+# per-slot recurrent state beside layers that write cache rows, MLA's latent
+# ones or GQA's ordinary keys and values (cfg.layer_kinds).
 #
 # A KDA layer keeps, per slot, a [H, dk, dv] float32 state and the short
 # conv's last inputs; it writes no cache row. The two kinds' weights live in
-# their own stacks ("kda_layers", "mla_layers"), the norms and the MLPs in
-# the model's layer stacks as ever. `_scan_hybrid` scans the KDA layers and
-# runs the MLA layer that follows one under a `lax.cond`: the recurrent
-# state is carried by the scan and never enters the conditional.
+# their own stacks ("kda_layers", `cfg.cache_stack`), the norms and the MLPs
+# in the model's layer stacks as ever. `_scan_hybrid` scans the KDA layers
+# and runs the cache layer that stands beside one under a `lax.cond`: the
+# recurrent state is carried by the scan and never enters the conditional.
 # --------------------------------------------------------------------------- #
 
 
@@ -1061,6 +1085,8 @@ def _kda_inputs(cfg: ArchConfig, ap: Params, x: jnp.ndarray, conv_prev):
     g = -jnp.exp(ap["A_log"].astype(f32))[:, None] * jax.nn.softplus(
         f + ap["dt_bias"].astype(f32)).reshape(B, T, H, dk)
     beta = jax.nn.sigmoid(matmul(x, ap["w_beta"], qk).astype(f32))
+    if cfg.kda_neg_eigval:  # beta in (0, 2): I - beta k k^T reflects
+        beta = 2.0 * beta
     gate = jax.nn.sigmoid(matmul(
         matmul(x, ap["g_down"], qk), ap["g_up"], qk).astype(f32))
     return q, k, v, g, beta, gate.reshape(B, T, H, dk), window
@@ -1125,67 +1151,87 @@ def _kda_prefill_mix(cfg: ArchConfig, ap: Params, x, lengths, rec, j, slots):
 
 def _hybrid_tables(cfg: ArchConfig):
     """Static layout of a hybrid stack: the KDA layers' model layer numbers,
-    for each the index of the MLA layer that follows it (or -1), and how
-    many of them carry the dense-prefix MLPs."""
+    for each the index (among the cache layers) of the cache layer that
+    stands beside it (or -1), how many KDA layers carry the dense-prefix
+    MLPs, the dense prefix's length, and whether a cache layer stands IN
+    FRONT of its KDA layer (a period that begins with it: Solar-Open2) or
+    behind it (one that ends with it: Kimi-Linear)."""
     import numpy as np
 
     kl = list(cfg.kda_layers)
     ml = list(cfg.cache_layer_ids)
     kd = cfg.first_k_dense if cfg.is_moe else 0
-    after = [ml.index(l + 1) if l + 1 in ml else -1 for l in kl]
-    nd = sum(1 for l in kl if l < kd)
-    covered = set(kl) | {l + 1 for l, m in zip(kl, after) if m >= 0}
-    if (len(cfg.layer_kinds) != cfg.num_layers
-            or covered != set(range(cfg.num_layers))
-            or any(l < kd for l in ml)
-            or any(m >= 0 and l + 1 <= kd for l, m in zip(kl, after))):
-        raise NotImplementedError(
-            f"{cfg.name}: layer_kinds {cfg.layer_kinds} — every 'mla' layer "
-            "has to follow a 'kda' layer and the dense-prefix layers have to "
-            "be 'kda' (models/llama._scan_hybrid)")
-    return np.asarray(kl, np.int32), np.asarray(after, np.int32), nd, kd
+    kind = "mla" if cfg.is_mla else "gqa"
+    ok = (len(cfg.layer_kinds) == cfg.num_layers and bool(kl)
+          and all(k in ("kda", kind) for k in cfg.layer_kinds)
+          and not any(l < kd for l in ml))
+    for lead in (False, True) if ok else ():
+        step = -1 if lead else 1  # where a KDA layer's cache layer stands
+        beside = [ml.index(l + step) if l + step in ml else -1 for l in kl]
+        covered = set(kl) | {l + step for l, m in zip(kl, beside) if m >= 0}
+        if (covered == set(range(cfg.num_layers))
+                and not any(m >= 0 and l < kd for l, m in zip(kl, beside))):
+            nd = sum(1 for l in kl if l < kd)
+            return (np.asarray(kl, np.int32), np.asarray(beside, np.int32),
+                    nd, kd, lead)
+    raise NotImplementedError(
+        f"{cfg.name}: layer_kinds {cfg.layer_kinds} — every {kind!r} layer "
+        "has to stand beside a 'kda' layer of its own, all of them behind "
+        "theirs or all of them in front, and the dense-prefix layers have "
+        "to be 'kda' (models/llama._scan_hybrid)")
 
 
-def _scan_hybrid(cfg: ArchConfig, params: Params, h, rec, kda_fn, mla_fn,
-                 mla_zero, extras=()):
-    """The layer stack of a hybrid model: a scan over its KDA layers, each
-    followed (`lax.cond`) by the MLA layer behind it where there is one.
+def _scan_hybrid(cfg: ArchConfig, params: Params, h, rec, kda_fn, cache_fn,
+                 cache_zero, extras=()):
+    """The layer stack of a hybrid model: ONE scan over its KDA layers, each
+    with the cache layer that stands beside it (`lax.cond`) where there is
+    one: behind it, or in front of it where the model's periods begin with
+    their cache layer (`_hybrid_tables`).
 
     kda_fn(h, rec, lp, j) -> (h, rec, out): a KDA layer and its MLP; lp its
     weights (the KDA stack's and the layer stack's, one dict), j its index
     among the KDA layers. rec is whatever the entry point carries through
     them (the recurrent state), never passed into the conditional.
-    mla_fn(h, lp, m, ex) -> (h, out): an MLA layer and its MLP; m its index
-    among the MLA layers, ex the `extras` (arrays stacked over the MLA
-    layers: the cache) as `_scan_stack` would hand them on. mla_zero(h) is
-    `out` of a layer that is not there.
-    Returns (h, rec, KDA outs stacked over the KDA layers, MLA outs stacked
-    over the MLA layers)."""
-    kl, after, nd, kd = _hybrid_tables(cfg)
-    kl_t, after_t = jnp.asarray(kl), jnp.asarray(after)
+    cache_fn(h, lp, m, ex) -> (h, out): a cache layer (MLA or GQA) and its
+    MLP; m its index among the cache layers, ex the `extras` (arrays stacked
+    over the cache layers: the cache) as `_scan_stack` would hand them on.
+    cache_zero(h) is `out` of a layer that is not there.
+    Returns (h, rec, KDA outs stacked over the KDA layers, cache-layer outs
+    stacked over the cache layers)."""
+    kl, beside, nd, kd, lead = _hybrid_tables(cfg)
+    kl_t, beside_t = jnp.asarray(kl), jnp.asarray(beside)
 
-    def run(h, rec, lo, hi, stack, off, with_mla):
-        def body(carry, _):
-            h, rec, j = carry
-            li = kl_t[j]
-            with jax.named_scope("layer_weights"):
-                lp = {**_take_layer(params["kda_layers"], j),
-                      **_take_layer(stack, li - off)}
-            h, rec, out_k = kda_fn(h, rec, lp, j)
-            if not with_mla:
-                return (h, rec, j + 1), (out_k, None)
-            m = after_t[j]
+    def run(h, rec, lo, hi, stack, off, with_cache):
+        def cache_layer(h, j, li):
+            """The cache layer beside KDA layer j (model layer li), if any:
+            model layer li - 1 where it leads, li + 1 where it follows."""
+            m = beside_t[j]
 
             def there(h):
                 mi = jnp.maximum(m, 0)
                 with jax.named_scope("layer_weights"):
-                    lp = {**_take_layer(params["mla_layers"], mi),
-                          **_take_layer(stack, li + 1 - off)}
+                    lp = {**_take_layer(params[cfg.cache_stack], mi),
+                          **_take_layer(stack, li + (-1 if lead else 1) - off)}
                 with jax.named_scope("layer_kv_pool"):
                     ex = _take_layer(tuple(extras), mi)
-                return mla_fn(h, lp, mi, ex)
+                return cache_fn(h, lp, mi, ex)
 
-            h, out_m = jax.lax.cond(m >= 0, there, lambda h: (h, mla_zero(h)), h)
+            return jax.lax.cond(
+                m >= 0, there, lambda h: (h, cache_zero(h)), h)
+
+        def body(carry, _):
+            h, rec, j = carry
+            li = kl_t[j]
+            if with_cache and lead:
+                h, out_m = cache_layer(h, j, li)
+            with jax.named_scope("layer_weights"):
+                lp = {**_take_layer(params["kda_layers"], j),
+                      **_take_layer(stack, li - off)}
+            h, rec, out_k = kda_fn(h, rec, lp, j)
+            if not with_cache:
+                return (h, rec, j + 1), (out_k, None)
+            if not lead:
+                h, out_m = cache_layer(h, j, li)
             return (h, rec, j + 1), (out_k, out_m)
 
         (h, rec, _), outs = jax.lax.scan(
@@ -1199,7 +1245,7 @@ def _scan_hybrid(cfg: ArchConfig, params: Params, h, rec, kda_fn, mla_fn,
     h, rec, (ok, om) = run(h, rec, nd, len(kl), params["layers"], kd, True)
     outs_k.append(ok)
     out_k = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs_k)
-    there = [j - nd for j in range(nd, len(kl)) if after[j] >= 0]
+    there = [j - nd for j in range(nd, len(kl)) if beside[j] >= 0]
     out_m = jax.tree.map(lambda a: a[jnp.asarray(there)], om)
     return h, rec, out_k, out_m
 
@@ -1216,10 +1262,10 @@ def _expert_counts(cfg: ArchConfig, picks) -> jnp.ndarray:
 
 def _hybrid_layer_fns(cfg: ArchConfig, kda_mix, *, pos, inv, attend,
                       mla_full: bool, ep: int, mesh, count: bool):
-    """(kda_fn, mla_fn, mla_zero) for `_scan_hybrid` from an entry point's
-    `kda_mix(lp, x, rec, j) -> (y, rec)` and its MLA `attend`. The MLA layer
-    is `_decoder_layer` itself. With `count` each layer's out ends with its
-    rows per held expert."""
+    """(kda_fn, cache_fn, cache_zero) for `_scan_hybrid` from an entry point's
+    `kda_mix(lp, x, rec, j) -> (y, rec)` and its cache layers' `attend`. The
+    cache layer, MLA or GQA, is `_decoder_layer` itself. With `count` each
+    layer's out ends with its rows per held expert."""
 
     def mlp(h, lp, picks):
         x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
@@ -1231,22 +1277,22 @@ def _hybrid_layer_fns(cfg: ArchConfig, kda_mix, *, pos, inv, attend,
         h = mlp(h + y, lp, picks)
         return h, rec, (_expert_counts(cfg, picks) if count else None)
 
-    def mla_fn(h, lp, m, ex):
+    def cache_fn(h, lp, m, ex):
         picks = [] if count else None
         h, rows = _decoder_layer(
             cfg, h, (lp, m) + tuple(ex), pos=pos, inv=inv, attend=attend,
             mla_full=mla_full, ep=ep, mesh=mesh, picks=picks)
         return h, rows + ((_expert_counts(cfg, picks),) if count else ())
 
-    def mla_zero(h):
+    def cache_zero(h):
         lead = h.shape[:-1]
-        rows = jnp.zeros(lead + (1, cfg.cache_k_dim), h.dtype)
-        out = (rows, rows[..., :0])
+        rows = jnp.zeros(lead + (cfg.cache_kv_heads, cfg.cache_k_dim), h.dtype)
+        out = (rows, rows[..., :cfg.cache_v_dim])
         if count:
             out = out + (jnp.zeros((max(cfg.experts_here, 1),), jnp.int32),)
         return out
 
-    return kda_fn, mla_fn, mla_zero
+    return kda_fn, cache_fn, cache_zero
 
 
 def _rope_inv(cfg: ArchConfig):
@@ -1613,7 +1659,7 @@ def decode_step_windowed(
                 cfg, kda_mix, pos=rope_pos, inv=inv, attend=attend,
                 mla_full=False, ep=ep, mesh=mesh, count=expert_rows),
             extras=extras)
-        if expert_rows:  # KDA layers' MLPs, then the MLA layers'
+        if expert_rows:  # KDA layers' MLPs, then the cache layers'
             rows_e = [jnp.concatenate([rows_k, rows_e[0]], axis=0)]
             routed.extend([True] * cfg.is_moe)
     else:
@@ -1809,7 +1855,7 @@ def paged_cache_zeros(cfg: ArchConfig, num_pages: int, page_size: int,
     engine assigns pages to slots and passes per-slot tables to each program.
     HBM scales with pages in use, not slots × max_seq (SURVEY §7 ragged KV).
     MLA pools hold latent rows (see KVCache docstring); L counts the layers
-    that write rows (a hybrid model's MLA layers, not its KDA layers)."""
+    that write rows (a hybrid model's cache layers, not its KDA layers)."""
     dtype = jnp.dtype(cfg.dtype) if dtype is None else dtype
     base = (cfg.cache_layers, num_pages, page_size, cfg.cache_kv_heads)
     return KVCache(

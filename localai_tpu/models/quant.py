@@ -48,14 +48,16 @@ Params = dict[str, Any]
 # 2D-matmul weights that benefit; embeddings stay bf16 (gather path).
 # w_kb/w_vb (MLA latent up-projections) stay unquantized: they ride
 # einsum paths with no grouped-int kernel and are small next to the MoE.
-QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "wg", "w_gate", "w_up", "w_down",
                     "wq_a", "wq_b", "wkv_a",
                     "shared_gate", "shared_up", "shared_down")
 
 
 # The per-layer stacks of a param tree: the model's layers, DeepSeek's dense
-# prefix, and a hybrid model's two attention kinds (models/llama.py).
-LAYER_STACKS = ("layers", "dense_layers", "kda_layers", "mla_layers")
+# prefix, and a hybrid model's two attention kinds: its KDA layers and its
+# cache layers, MLA or GQA (models/llama.py, `ArchConfig.cache_stack`).
+LAYER_STACKS = ("layers", "dense_layers", "kda_layers", "mla_layers",
+                "gqa_layers")
 
 
 def quantize_tensor(w: jnp.ndarray) -> dict[str, jnp.ndarray]:
@@ -282,7 +284,7 @@ def init_params_quantized(
         if name in ("bq", "bk", "bv"):
             return jnp.zeros(sd.shape, sd.dtype)
         k = next(keys)
-        special = init_special(name, k, sd.shape)
+        special = init_special(name, k, sd.shape, cfg.kda_init_dt)
         if special is not None:
             return special.astype(sd.dtype)
         if name in QUANT_LAYER_KEYS and len(sd.shape) == 4:

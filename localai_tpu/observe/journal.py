@@ -107,6 +107,9 @@ BASE_EVENTS = (
     "slot_turnover", # one per `terminal` (rid, slot; a=1 when the slot index
     #                  had been handed on before the request's `done` was
     #                  posted, Engine._park, else 0; b=1)
+    "admit_split",   # a hybrid model's admission group of one bucket went
+    #                  out as several programs under the byte bound of
+    #                  engine/state.py (a=programs, b=requests of the group)
 )
 
 # One journal event type per fault-injection site (faults.SITES), checked

@@ -3,10 +3,18 @@ as a Pallas kernel over the stacked per-slot state, and the chunkwise
 prefill in plain XLA.
 
 Per head, with S in R^{dk x dv} (float32), a per-channel decay alpha = exp(g)
-in (0, 1]^dk and a scalar beta in (0, 1):
+in (0, 1]^dk and a scalar beta in (0, 1), or in (0, 2) for a model that
+allows negative eigenvalues (`ArchConfig.kda_neg_eigval`):
 
     S_t = (I - beta k k^T) Diag(alpha) S_{t-1} + beta k v^T
     o_t = S_t^T q_t
+
+Nothing below depends on beta < 1. The decode kernel takes beta folded into
+its operands. The chunkwise form solves (I + Diag(beta) A) u = rhs with A
+strictly lower triangular: unit triangular whatever beta is, so the solve is
+a finite substitution, not an iteration that needs a contraction. And on a
+unit k the transition I - beta k k^T has the eigenvalue 1 - beta in (-1, 1),
+so the state stays bounded either way.
 
 The state of every KDA layer and every slot lives in ONE array
 [Lk, slots, H, dk, dv] that the engine carries through its programs
@@ -211,7 +219,7 @@ def kda_chunk_prefill(q, k, v, g, beta, valid, chunk: int = CHUNK):
     The widest temporaries (the diagonal blocks' pairwise exponents,
     [SUB, SUB, dk] a sub-block) are T x H x SUB x dk float32 a request, 64 MB
     at 256 tokens of 32 heads, all requests at once: the engine bounds the
-    rows of an admission program (engine/state.ADMIT_ROWS). Running the
+    rows of an admission program (engine/state.admit_rows). Running the
     requests in turn inside the program (`lax.map`) did not come back on the
     chip at 4 x 512 rows (PERF.md, PR 31) and is not done."""
     f32 = jnp.float32

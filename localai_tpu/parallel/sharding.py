@@ -53,7 +53,7 @@ class ShardingPlanError(ValueError):
         self.max_tp = max_tp
 
 
-def _attn_specs(cfg: ArchConfig, mla_stack: bool = False) -> dict[str, P]:
+def _attn_specs(cfg: ArchConfig, cache_stack: bool = False) -> dict[str, P]:
     """Attention-side specs shared by both layer stacks. MLA shards the
     per-head tensors over "tp" on the HEAD axis (q_b columns, w_kb/w_vb
     leading head dim, wo rows); the low-rank a-projections and the latent
@@ -62,11 +62,11 @@ def _attn_specs(cfg: ArchConfig, mla_stack: bool = False) -> dict[str, P]:
         "attn_norm": P(None, None),
         "mlp_norm": P(None, None),
     }
-    if cfg.is_hybrid and not mla_stack:
+    if cfg.is_hybrid and not cache_stack:
         return specs  # the attention weights live in their kinds' stacks
+    if cache_stack:
+        specs = {}  # a hybrid model's cache layers' attention, no norms
     if cfg.is_mla:
-        if mla_stack:
-            specs = {}
         if cfg.q_lora_rank:
             specs["wq_a"] = P(None, None, None)
             specs["q_norm_a"] = P(None, None)
@@ -85,6 +85,8 @@ def _attn_specs(cfg: ArchConfig, mla_stack: bool = False) -> dict[str, P]:
         "wv": P(None, None, "tp"),
         "wo": P(None, "tp", None),
     })
+    if cfg.attn_gate:
+        specs["wg"] = P(None, None, "tp")
     if cfg.post_norms:  # gemma-2 sandwich norms — replicated like the rest
         specs["post_attn_norm"] = P(None, None)
         specs["post_ffw_norm"] = P(None, None)
@@ -141,14 +143,15 @@ def param_specs(cfg: ArchConfig) -> Params:
         specs["lm_head"] = P("tp", None)
     if cfg.is_hybrid:
         # A hybrid model serves at tp = 1 (the engine refuses more): its KDA
-        # stack is replicated, its MLA stack sharded as any MLA's.
+        # stack is replicated, its cache layers' stack sharded as any
+        # model's of their kind.
         specs["kda_layers"] = {
             **{n: P(None, None, None) for n in (
                 "wq", "wk", "wv", "wo", "conv_w", "f_down", "f_up", "w_beta",
                 "g_down", "g_up")},
             **{n: P(None, None) for n in ("dt_bias", "A_log", "o_norm")},
         }
-        specs["mla_layers"] = _attn_specs(cfg, mla_stack=True)
+        specs[cfg.cache_stack] = _attn_specs(cfg, cache_stack=True)
     return specs
 
 

@@ -668,7 +668,7 @@ class ModelManager:
 
             arch = arch_from_hf_config(ckpt_dir)
 
-        arch = _apply_rope_overrides(arch, cfg)
+        arch = _apply_rope_overrides(_apply_deployment_share(arch, cfg), cfg)
 
         from localai_tpu.parallel import distributed
         from localai_tpu.parallel.sharding import max_valid_tp
@@ -1307,15 +1307,14 @@ def whisper_presets() -> dict:
     return WHISPER_PRESETS
 
 
-def _apply_rope_overrides(arch, cfg):
-    """YAML rope knobs override the checkpoint's (reference parity:
-    model_config.go rope_scaling/rope_freq_base are user config, forwarded
-    over the checkpoint's own values)."""
+def _apply_deployment_share(arch, cfg):
+    """The part of the model this process holds under the YAML's deployment
+    keys (config/model_config.py): `expert_share`, `stage_layers`,
+    `vocab_rows`. Nothing stands in for the absent experts, stages or rows."""
     import dataclasses as _dc
 
     updates = {}
     if cfg.expert_share:
-        # The deployment's one key of the model YAML (config/model_config.py).
         idx, of = (int(x) for x in cfg.expert_share)
         if not (arch.is_moe and of >= 1 and 0 <= idx < of
                 and arch.num_experts % of == 0):
@@ -1323,6 +1322,32 @@ def _apply_rope_overrides(arch, cfg):
                 f"model {cfg.name!r}: expert_share {cfg.expert_share} needs a "
                 f"MoE model whose {arch.num_experts} experts divide by `of`")
         updates["expert_share"] = (idx, of)
+    if cfg.stage_layers:
+        # Stage 0 of a pipeline: the model's first n layers.
+        n = int(cfg.stage_layers)
+        if not 0 < n <= arch.num_layers:
+            raise ValueError(
+                f"model {cfg.name!r}: stage_layers {n} of {arch.num_layers}")
+        updates["num_layers"] = n
+        updates["layer_kinds"] = tuple(arch.layer_kinds[:n])
+    if cfg.vocab_rows:
+        # A vocabulary-parallel head's rows [0, n): the ids this process
+        # embeds and samples are its own rows'.
+        n = int(cfg.vocab_rows)
+        if not 0 < n <= arch.vocab_size:
+            raise ValueError(
+                f"model {cfg.name!r}: vocab_rows {n} of {arch.vocab_size}")
+        updates["vocab_size"] = n
+    return _dc.replace(arch, **updates) if updates else arch
+
+
+def _apply_rope_overrides(arch, cfg):
+    """YAML rope knobs override the checkpoint's (reference parity:
+    model_config.go rope_scaling/rope_freq_base are user config, forwarded
+    over the checkpoint's own values)."""
+    import dataclasses as _dc
+
+    updates = {}
     if cfg.rope_freq_base:
         updates["rope_theta"] = float(cfg.rope_freq_base)
     rs = cfg.rope_scaling

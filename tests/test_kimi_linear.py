@@ -299,10 +299,10 @@ def test_chunkwise_prefill_matches_the_recurrence():
 
 def test_an_admission_group_is_cut_to_the_state_modules_rows(monkeypatch):
     """Eight prompts of one bucket arrive together; no admission program
-    takes more than ADMIT_ROWS // bucket of them."""
+    takes more than admit_rows // bucket of them."""
     from localai_tpu.engine import state
 
-    monkeypatch.setattr(state, "ADMIT_ROWS", 64)
+    monkeypatch.setattr(state, "admit_rows", lambda cfg: 64)
     eng = _engine(CFG, _seeded(), max_slots=8, kv_pages=64)
     try:
         prompts = C.sample_prompts(15, CFG.vocab_size, [20] * 8)
