@@ -67,12 +67,13 @@ SIZES = {
         # kernel phase: B slots, K kv heads, G q heads per kv head, head dim
         B=8, K=8, G=4, D=128, hidden=4096, ffn=14336, vocab=32000,
         chunk=512, verify=6, lora_rank=16, flash_lens=(32, 512, 2048), tp=4,
+        row_limit=256,  # ops/quant_matmul.QUANT_PALLAS_MAX_ROWS
         # olmoe-1b-7b's expert stack as published: layers, experts, widths
         moe=dict(layers=16, experts=64, hidden=2048, ffn=1024),
         # kimi-linear-48b-a3b as published: KDA layers, slots, heads, head
         # width; MLA's padded latent row and heads; chip 0's 32 held experts
         hybrid=dict(kda_layers=20, slots=64, heads=32, dk=128, latent=640,
-                    mla_layers=7, mla_heads=32, pages=513,
+                    mla_layers=7, mla_heads=32, pages=513, vocab=163840,
                     moe=dict(layers=26, experts=32, hidden=2304, ffn=1024)),
         # solar-open2-250b as chip 0 of stage 0 holds it: 6 KDA layers of 64
         # heads, GQA at 8 KV heads x 8, 40 held experts of width 1280 in each
@@ -85,10 +86,11 @@ SIZES = {
         short_prompt=40, long_prompt=300, max_tokens=12,
         B=4, K=2, G=2, D=16, hidden=64, ffn=128, vocab=512,
         chunk=32, verify=3, lora_rank=4, flash_lens=(32,),
+        row_limit=256,
         tp=2,  # the tiny preset has two kv heads
         moe=dict(layers=2, experts=4, hidden=64, ffn=32),
         hybrid=dict(kda_layers=3, slots=4, heads=4, dk=16, latent=64,
-                    mla_layers=2, mla_heads=4, pages=33,
+                    mla_layers=2, mla_heads=4, pages=33, vocab=640,
                     moe=dict(layers=2, experts=4, hidden=64, ffn=32)),
         hybrid_gqa=dict(kda_layers=2, slots=4, heads=4, dk=16, K=2, G=4,
                         moe=dict(layers=2, experts=3, hidden=64, ffn=40)),
@@ -133,9 +135,10 @@ def child_probe() -> dict:
     }
 
 
-def child_kernels(size: str, rehearsal: bool) -> dict:
+def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
     """Compile each Pallas kernel through its dispatcher and compare it with
-    its XLA oracle. Returns {"cases": {name: {...}}, "failed": [...]}."""
+    its XLA oracle; `only` (comma list of substrings) keeps the cases whose
+    name holds one. Returns {"cases": {name: {...}}, "failed": [...]}."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -166,6 +169,8 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
 
     def case(name, fn_auto, fn_oracle, args, tol):
         """tol bounds max|got-want| / max|want| over every output."""
+        if only and not any(part in name for part in only.split(",")):
+            return
         t0 = time.time()
         rec: dict = {"tol": tol}
         try:
@@ -368,6 +373,43 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
 
     case("quant_int8_channel_ffn_stacked", stacked("auto"), stacked("xla"),
          (rnd((32, hid)), stack, jnp.int32(0), jnp.int32(3)), 2e-2)
+    # The kernel's row limit at the same widths (a speculative verify chunk):
+    # a full-width float32 accumulator does not fit the block rule's VMEM
+    # budget there, so this is the narrowed branch (a lane-multiple column
+    # strip, ops/quant_matmul._blocks) on the chip.
+    case("quant_int8_channel_ffn_row_limit", stacked("auto"), stacked("xla"),
+         (rnd((s["row_limit"], hid)), stack, jnp.int32(1), jnp.int32(2)), 2e-2)
+    # The other forms where the block rule has least room (grouped int8 and
+    # packed int4 hold more copies of a tile, and no cell runs them): the row
+    # limit at hidden -> ffn and ffn -> hidden, groups of 32 and of 128 (8
+    # groups of 128 are a 1,024-row block), and 32 rows at the ffn's tp-local
+    # width (14336 / 4 = 3584 = 28 lane tiles), all under Mosaic's default
+    # scoped VMEM: the kernels ask for no limit of their own.
+    def grouped_forms(kin, kout, group):
+        w = rnd((kin, kout), jnp.float32, 0.02)
+        gq = jnp.clip(jnp.round(w.reshape(kin // group, group, kout) / 5e-4),
+                      -127, 127).astype(jnp.int8)
+        return {"int8_channel": Q.quantize_tensor(w),
+                "int8_grouped": {"gq": gq, "gs": jnp.full(
+                    (kin // group, 1, kout), 5e-4, jnp.float32)},
+                "int4_packed": Q.quantize_tensor_g4(w, group)}
+
+    ffn, local = s["ffn"], s["ffn"] // s["tp"]
+    for tag, rows, kin, kout, group in (
+            ("row_limit_g32", s["row_limit"], hid, ffn, 32),
+            ("row_limit_g128", s["row_limit"], hid, ffn, 128),
+            ("down_row_limit_g128", s["row_limit"], ffn, hid, 128),
+            ("tp_local", 32, hid, local, 32),
+            ("down_tp_local", 32, local, hid, 32)):
+        if kin % group:
+            continue  # the rehearsal's tiny widths
+        for form, wq in grouped_forms(kin, kout, group).items():
+            if form == "int8_channel" and "tp_local" not in tag:
+                continue  # the stacked row-limit case above
+            case(f"quant_{form}_{tag}",
+                 lambda x, wq: Q.matmul(x, wq, impl="auto"),
+                 lambda x, wq: Q.matmul(x, wq, impl="xla"),
+                 (rnd((rows, kin)), wq), 2e-2)
     # The MoE decode block's form at olmoe-1b-7b's published widths: 32 rows,
     # the int8 experts still stacked over layers AND experts ([16·64, in, out]
     # blocks, block layer·E + e by scalar prefetch), both einsum shapes, two
@@ -466,7 +508,8 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
           jnp.int32(0), jnp.int32(Lm - 1)), 5e-3)
 
     # The held experts' stacks [26 x 32, 2304, 1024] at 64 rows through the
-    # same kernel and `_tile` rule as olmoe's [16 x 64, 2048, 1024].
+    # same kernel and block rule as olmoe's [16 x 64, 2048, 1024]: a whole
+    # expert matrix (2.3 MB) a grid step.
     hm = hy["moe"]
 
     def held_stack(kin, kout, hm=hm):
@@ -496,6 +539,17 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
           "s": hs}
     case("quant_unembed", lambda h, w: Q.unembed_matmul(h, w, impl="auto"),
          lambda h, w: Q.unembed_matmul(h, w, impl="xla"), (x, hq), 2e-2)
+    # kimi-linear-48b-a3b's head [163840, 2304] at 64 rows: whole rows of an
+    # 18-lane-tile width, 1,024 of them a block.
+    hv, hd = hy["vocab"], hy["moe"]["hidden"]
+    head = jax.jit(lambda k1: jnp.clip(jnp.round(jax.random.normal(
+        k1, (hv, hd), jnp.float32) * 40.0), -127, 127).astype(jnp.int8))(
+            next(keys))
+    case("quant_unembed_v163840",
+         lambda h, w: Q.unembed_matmul(h, w, impl="auto"),
+         lambda h, w: Q.unembed_matmul(h, w, impl="xla"),
+         (rnd((Bk, hd)), {"q": head, "s": jnp.full((hv, 1), 5e-4, jnp.float32)}),
+         2e-2)
 
     # -- ragged LoRA delta (ops/lora_matmul.py) vs the XLA gather form --------
     # bf16 factors, f32 accumulation; the oracle rounds the rank-r
@@ -512,10 +566,12 @@ def child_kernels(size: str, rehearsal: bool) -> dict:
     return {"cases": cases, "failed": failed}
 
 
-def run_child(mode: str, size: str, rehearsal: bool, timeout: float) -> dict:
+def run_child(mode: str, size: str, rehearsal: bool, timeout: float,
+              only: str = "") -> dict:
     """Run one jax child to its end and parse the JSON line it prints last."""
     cmd = [sys.executable, os.path.abspath(__file__), "--child", mode,
-           "--size", size] + (["--cpu-rehearsal"] if rehearsal else [])
+           "--size", size, "--cases", only] + (
+               ["--cpu-rehearsal"] if rehearsal else [])
     proc = subprocess.run(cmd, cwd=HERE, capture_output=True, text=True,
                           timeout=timeout)
     sys.stderr.write(proc.stderr[-6000:])
@@ -871,8 +927,8 @@ def phase_replicas(s: dict, out_dir: str, n: int) -> dict:
     return {"replica_param_devices": where}
 
 
-def phase_kernels(size: str, rehearsal: bool) -> dict:
-    res = run_child("kernels", size, rehearsal, timeout=900)
+def phase_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
+    res = run_child("kernels", size, rehearsal, timeout=900, only=only)
     need(not res["failed"], f"kernels failed: {res['failed']}: " + "; ".join(
         f"{n}: {res['cases'][n].get('error', res['cases'][n])}"
         for n in res["failed"][:3]))
@@ -894,6 +950,9 @@ def main() -> int:
                     help="tiny widths on the CPU backend; not a chip result")
     ap.add_argument("--phases", default=",".join(PHASES),
                     help=f"comma list out of {PHASES}")
+    ap.add_argument("--cases", default="",
+                    help="kernels phase: comma list of substrings; only the "
+                         "cases whose name holds one run (default: all)")
     ap.add_argument("--child", choices=("probe", "kernels"),
                     help=argparse.SUPPRESS)
     ap.add_argument("--size", choices=tuple(SIZES), default="full",
@@ -908,7 +967,7 @@ def main() -> int:
     if args.child:
         sys.path.insert(0, HERE)
         res = (child_probe() if args.child == "probe"
-               else child_kernels(args.size, args.cpu_rehearsal))
+               else child_kernels(args.size, args.cpu_rehearsal, args.cases))
         print(json.dumps(res))
         return 0
 
@@ -959,7 +1018,7 @@ def main() -> int:
             + (f" — {rec['error']}" if "error" in rec else ""))
 
     table = {
-        "kernels": lambda: phase_kernels(size, rehearsal),
+        "kernels": lambda: phase_kernels(size, rehearsal, args.cases),
         "serve": lambda: phase_serve(s, out_dir, device["platform"],
                                      "int8", "int8"),
         "restart": lambda: phase_restart(s, out_dir, cache_dir),

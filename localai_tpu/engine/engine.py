@@ -6301,6 +6301,10 @@ class Engine:
         if sites["stacked"] or sites["sliced"]:
             out["quant_matmul_stacked_sites"] = float(sites["stacked"])
             out["quant_matmul_sliced_sites"] = float(sites["sliced"])
+            # and the weight block the rule gave each kernel call: the
+            # weight's whole rows, or a column strip (stacked.note_blocks)
+            out["quant_matmul_wholerow_sites"] = float(sites["wholerow"])
+            out["quant_matmul_narrowed_sites"] = float(sites["narrowed"])
         if sites["paged_attention_stacked"] or sites["paged_attention_sliced"]:
             out["paged_attention_stacked_sites"] = float(
                 sites["paged_attention_stacked"])

@@ -14,8 +14,23 @@ block the Pallas pipeline is already streaming HBM→VMEM (double-buffered
 block DMA between grid steps), and accumulate in f32 on the MXU — each
 packed weight byte crosses HBM exactly once. Decode-shape only: the row
 count (batch × window) is small enough that x and the f32 accumulator sit
-whole in VMEM, so the grid walks (expert, out-tile, k-chunk) with the
-k-chunk axis innermost, revisiting one out block per (expert, out-tile).
+whole in VMEM, so the grid walks (expert, out-block, k-chunk) with the
+k-chunk axis innermost, revisiting one out block per (expert, out-block).
+
+The block rule (ISSUE 36, `_blocks`): a grid step's weight block is sized in
+bytes and laid along the weight's own rows. A leaf is [B, in, out] int8,
+row-major, so `kc` whole rows (bo = out) are ONE contiguous run of kc x out
+bytes; the rule takes the full out width and as many rows as keep the block
+within BLOCK_BYTES (2.5 MB: a whole expert matrix of the MoE cells, 128 rows
+of mistral's 14,336-wide ffn), counts everything a step holds against one
+VMEM budget, and narrows `out` to a lane multiple only where a full-width
+step does not fit (256 rows x 14,336). x stays resident whole instead of
+being fetched again per out block or expert. Inside the step the kernel
+walks the block in sub-tiles with rolled loops, so the converted float32
+tile does not grow with the DMA. On the v5e (my chip runs, PR 36) a step
+costs 0.22 us before it moves a byte; 512 x 512 column strips copied at
+65-72% of the HBM's rate and the whole kernel ran at 53-59% (33-36% where an
+axis of 2304 or 1280 forced 128 KB blocks); whole-row blocks run at 85-90%.
 
 Forms served (matching models/quant.py representations):
 - flat int8      {"q": [in, out] i8,      "s": [1, out] f32}
@@ -64,12 +79,14 @@ it, exactly like ops/paged_flash vs the XLA page walk).
 from __future__ import annotations
 
 import functools
+import math
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from localai_tpu.ops.stacked import note_site
+from localai_tpu.ops.stacked import note_blocks, note_site
 
 # The ONLY function here allowed to issue cross-chip collectives: the
 # row-parallel shard_map closure psums its partial products over "tp" —
@@ -101,12 +118,156 @@ def use_pallas_quant(impl: str = "auto") -> bool:
     return impl == "pallas"
 
 
-def _tile(n: int, targets=(512, 256, 128)) -> int:
-    """Largest target that divides n, else n whole (tiny test shapes)."""
-    for t in targets:
-        if t <= n and n % t == 0:
-            return t
-    return n
+# The block rule (ISSUE 36). A grid step of these kernels costs 0.22 us
+# before it moves a byte, and a DMA runs near the HBM's rate only over long
+# contiguous runs. A weight leaf is [B, in, out] int8, row-major: `kc` WHOLE
+# rows are one run of kc x out bytes, where a 512-wide column strip is kc
+# runs of 512 bytes (65-72% of the HBM's rate alone; whole rows 84-91%). So
+# a step's weight block is sized in bytes and laid along the rows, and x
+# stays in VMEM whole rather than being fetched again for every out block
+# or expert (6-12% of an expert matmul's traffic); see `_blocks`.
+BLOCK_BYTES = 2560 << 10  # weight bytes one grid step moves (the chip's sweep)
+TILE_ELEMS = 1 << 20    # weights converted to float32 at once inside a step
+# All the blocks and tiles a step holds at once. The kernels ask for NO scoped
+# VMEM limit of their own, so a step has to fit Mosaic's default (16 MiB on
+# the v5e) with real room: `_held` counts the buffers Mosaic allocates (the
+# pipelined blocks, the accumulator) and one float32 copy of the sub-tile on
+# top, which is not exactly what Mosaic allocates (9.9 MiB at most where the
+# count reads up to 11.9, compiled for the v5e at 70 shapes), so the budget
+# stands a quarter under the limit. Every cell's expert, ffn and attention
+# block at 32-64 rows fits it at 1.5-2.5 MB. A raised limit
+# is not free: XLA parks what it likes in VMEM BESIDE a custom call
+# (kimi-linear's [163840, 1] float32 head scale is 80 MiB there, tiled
+# 8 x 128), and with 48 MiB asked for, then 32, that model's admission
+# programs never came back on the chip (PERF.md section 6, PR 36).
+VMEM_BUDGET = 12 << 20
+
+
+class Blocks(NamedTuple):
+    """What one grid step of `_qmm_call` holds: the weight block is `kc`
+    rows of the in axis by `bo` out channels (gc = kc / gs groups for the
+    grouped forms, else 1); x is resident `xk` wide (the whole in axis, or
+    kc where that does not fit); inside the step the kernel converts and
+    multiplies the block `sk` x `so` at a time."""
+
+    kc: int
+    bo: int
+    xk: int
+    sk: int
+    so: int
+    gc: int
+
+
+def _divisors(n: int, unit: int, cap: int | None = None) -> list[int]:
+    """Multiples of `unit` that divide n (up to cap), ascending; n itself
+    where there is none (tiny shapes: the whole axis is always a legal
+    block)."""
+    top = n if cap is None else min(n, cap)
+    ds = [d for d in range(unit, top + 1, unit) if n % d == 0]
+    return ds or [n]
+
+
+def _up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _fit(widths: list[int], lengths: list[int], held, nbytes) -> tuple[int, int]:
+    """(length, width) of a block: the first of `widths` (widest first) at
+    which some of the ascending `lengths` fits (`held(length, width)` <=
+    VMEM_BUDGET), and of those the longest whose block is BLOCK_BYTES at
+    most, else the shortest that fits. Where nothing fits the count, the
+    smallest legal block and not a failure."""
+    for width in widths:
+        fits = [c for c in lengths if held(c, width) <= VMEM_BUDGET]
+        small = [c for c in fits if nbytes(c, width) <= BLOCK_BYTES]
+        if fits:
+            return (small[-1] if small else fits[0]), width
+    return lengths[0], widths[-1]
+
+
+def _sub_tile(kc: int, bo: int, gs: int = 0) -> tuple[int, int]:
+    """(sk, so): the part of a kc x bo block converted to float32 at once,
+    TILE_ELEMS weights at most (a quarter for the grouped forms, which hold
+    more copies of it), whole groups, lane multiples."""
+    k_unit = 128 if not gs else math.lcm(128, 8 * gs)
+    sk = _divisors(kc, k_unit, max(512, k_unit))[-1]
+    tile = TILE_ELEMS // (4 if gs else 1)
+    return sk, _divisors(bo, 128, max(128, tile // sk))[-1]
+
+
+def _x_whole(n: int, kin: int, x_bytes: int) -> bool:
+    """x stays resident whole where that takes a quarter of the budget at
+    most (else a k-chunk a step)."""
+    return 2 * _up(n, 16) * _up(kin, 128) * x_bytes <= VMEM_BUDGET // 4
+
+
+def _held(n: int, kin: int, kc: int, bo: int, *, gs: int = 0,
+          packed: bool = False, zeros: bool = False, x_bytes: int = 2,
+          out_bytes: int = 2) -> int:
+    """Bytes a grid step of `_qmm_call` holds at a kc x bo weight block:
+    the double-buffered weight, x, scale/zero and out blocks, the float32
+    accumulator [n, bo], and the converted float32 sub-tile (and its scaled
+    copy, grouped) the kernel walks the block in."""
+    rows, lanes = _up(n, 16), _up(bo, 128)
+    sk, so = _sub_tile(kc, bo, gs)
+    xk = kin if _x_whole(n, kin, x_bytes) else kc
+    scales = (_up(kc // gs, 8) if gs else 8) * lanes * 4 * (2 if zeros else 1)
+    return int(
+        2 * kc * (0.5 if packed else 1) * lanes
+        + 2 * rows * _up(xk, 128) * x_bytes
+        + 2 * scales
+        + rows * lanes * 4
+        + 2 * rows * lanes * out_bytes
+        + _up(sk, 32) * _up(so, 128) * 4 * (2 if gs else 1))
+
+
+def _blocks(n: int, kin: int, out: int, *, gs: int = 0, packed: bool = False,
+            zeros: bool = False, x_bytes: int = 2, out_bytes: int = 2) -> Blocks:
+    """Size a grid step's weight block in bytes and in whole rows: a pure
+    function of what the call can see (rows of x, the two widths, the
+    leaf's byte width, the group size) and the one VMEM budget.
+
+    Prefer bo = out, the weight's whole contiguous rows, and take the most
+    rows kc whose block stays within BLOCK_BYTES; narrow bo (to the widest
+    multiple of 128 lanes that divides out) only where a full-width step
+    cannot be held (`_held`) in VMEM_BUDGET. kc is a multiple of 128 (x's
+    lane tile; it covers the int8 sublane tile of 32, packed or not) and of
+    8 groups for the grouped forms (the scale block's sublane tile), or the
+    whole in axis."""
+    kcs = _divisors(kin, 128 if not gs else math.lcm(128, 8 * gs))
+    if kcs[-1] != kin:
+        kcs.append(kin)
+    widths = [out] + [d for d in reversed(_divisors(out, 128)) if d != out]
+    kc, bo = _fit(
+        widths, kcs,
+        functools.partial(_held, n, kin, gs=gs, packed=packed, zeros=zeros,
+                          x_bytes=x_bytes, out_bytes=out_bytes),
+        lambda c, w: c * w // (2 if packed else 1))
+    return Blocks(kc, bo, kin if _x_whole(n, kin, x_bytes) else kc,
+                  *_sub_tile(kc, bo, gs), kc // gs if gs else 1)
+
+
+def _unembed_blocks(n: int, v: int, d: int, *,
+                    x_bytes: int = 2) -> tuple[int, int, int]:
+    """(bv, kc, sv) for the vocab-major head [V, D], by the same rule
+    (`_fit`) on the other axis: a row of the head is one out channel, so
+    whole rows are kc = d and the block is `bv` of them (a multiple of 128:
+    the out block's lane tile); the kernel converts `sv` rows at a time. d
+    is cut (to a multiple of 128 that divides it) only where 128 whole rows
+    do not fit."""
+    rows = _up(n, 16)
+
+    def sub(bv, kc):
+        return _divisors(bv, 128, max(128, TILE_ELEMS // kc))[-1]
+
+    def held(bv, kc):  # weight, h, acc + out, the [bv, 1] scale, the tile
+        return (2 * bv * _up(kc, 128) + 2 * rows * _up(kc, 128) * x_bytes
+                + 3 * rows * _up(bv, 128) * 4 + 2 * _up(bv, 8) * 128 * 4
+                + 2 * sub(bv, kc) * _up(kc, 128) * 4)
+
+    bv, kc = _fit(list(reversed(_divisors(d, 128))), _divisors(v, 128), held,
+                  lambda b, c: b * c)
+    return bv, kc, sub(bv, kc)
 
 
 def _rows(x: jnp.ndarray, tail: int = 1) -> int:
@@ -132,19 +293,33 @@ def _tp_degree(mesh) -> int:
 # --------------------------------------------------------------------------- #
 
 
-def _qmm_kernel(_layer_ref, x_ref, w_ref, s_ref, *rest, gs: int, gc: int,
-                packed: bool):
-    """One (expert, out-tile, k-chunk) grid step of the dequant-matmul.
+def _span(i, size: int, whole: bool):
+    """The i-th run of `size` along a block's axis (i may be traced: the
+    start is a multiple of size), or the axis whole where one run spans it
+    (tiny shapes whose axes are no multiple of a tile)."""
+    import jax.experimental.pallas as pl
+
+    return slice(None) if whole else pl.ds(pl.multiple_of(i * size, size), size)
+
+
+def _qmm_kernel(_layer_ref, x_ref, w_ref, s_ref, *rest, gs: int, sk: int,
+                so: int, packed: bool):
+    """One (expert, out-block, k-chunk) grid step of the dequant-matmul.
 
     `_layer_ref` is the scalar-prefetched layer index: only the BlockSpec
     index maps read it (they pick this layer's blocks out of the stack).
 
-    Blocks: x (1, N, kc) float, w (1, kc[/2], bo) i8/u8, s (1, gc|1, bo)
+    Blocks: x (1, N, kc | Kin) float, w (1, kc[/2], bo) i8/u8, s (1, gc|1, bo)
     f32, optional z (1, gc, bo) f32, out (1, N, bo), acc scratch (N, bo)
     f32. gs == 0 means the flat per-channel form (scale applied once at the
     final write); packed means two nibbles per weight byte along the
     in-group axis (low nibble = first gs/2 elements — models/quant.py),
     shipped bitcast to int8.
+
+    The DMA is the whole block (`_blocks`); the arithmetic walks it in
+    sk x so sub-tiles with rolled loops (convert + dot a sub-tile,
+    accumulate), so the converted float32 tile is bounded (TILE_ELEMS)
+    and the body does not grow with the block.
     """
     import jax.experimental.pallas as pl
 
@@ -152,55 +327,80 @@ def _qmm_kernel(_layer_ref, x_ref, w_ref, s_ref, *rest, gs: int, gc: int,
     o_ref, acc_ref = rest[-2], rest[-1]
     k = pl.program_id(2)
     nk = pl.num_programs(2)
+    bo = o_ref.shape[-1]
+    kc = w_ref.shape[1] * (2 if packed else 1)
+    nks, nos = kc // sk, bo // so
+    # x is resident whole (its block spans every k-chunk) or a chunk a step
+    x_whole = x_ref.shape[-1] != kc
+    sgc = sk // gs if gs else 1  # groups a sub-tile holds
+    sk_w = sk // 2 if packed else sk
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    xb = x_ref[0].astype(jnp.float32)  # [N, kc]
-    wb = w_ref[0]  # [kc(,/2), bo] int8 (packed: two nibbles per byte)
-    bo = wb.shape[-1]
-    if packed:
-        # Widen to 32 bits BEFORE any reshape/bit op: Mosaic has no
-        # uint8→f32 convert and no 8-bit shifts, and the (gc, gs/2, bo)
-        # regroup only lands on whole sublane tiles at 32-bit width (the
-        # int8 tile is 32 rows, the half-group is 16). The wrapper bitcasts
-        # the uint8 bytes to int8, so the sign-extended arithmetic shift is
-        # masked back to the nibble.
-        half = gs // 2
-        wi = wb.astype(jnp.int32).reshape(gc, half, bo)
-        nib = jnp.concatenate([wi & 0xF, (wi >> 4) & 0xF], axis=1)
-        wf = nib.astype(jnp.float32)  # [gc, gs, bo]
-    elif gs:
-        wf = wb.astype(jnp.float32).reshape(gc, gs, bo)
-    else:
-        wf = wb.astype(jnp.float32)  # flat: [kc, bo]
-    if gs:
-        # Dequant in registers: the scaled f32 weight tile exists only in
-        # VMEM for this one MXU pass — never written back to HBM.
-        sb = s_ref[0].astype(jnp.float32)  # [gc, bo]
-        wf = (wf * sb[:, None, :]).reshape(gc * gs, bo)
-    acc_ref[...] += jax.lax.dot_general(
-        xb, wf, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    if z_ref is not None:
-        # Affine zero point: −Σᵢ x_{g,i} · z_{g,o} per group. The per-group
-        # x sums ride the MXU against a 0/1 group-membership matrix — a
-        # lane-splitting reshape of x is not something Mosaic lowers.
-        zb = z_ref[0].astype(jnp.float32)  # [gc, bo]
-        row = jax.lax.broadcasted_iota(jnp.int32, (gc * gs, gc), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (gc * gs, gc), 1)
-        member = ((row >= col * gs) & (row < (col + 1) * gs)).astype(
-            jnp.float32)
-        xs = jax.lax.dot_general(
-            xb, member, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [N, gc]
-        acc_ref[...] -= jax.lax.dot_general(
-            xs, zb, (((1,), (0,)), ((), ())),
+    def sub_tile(rows, cols):
+        """acc[:, cols] += x[:, rows] @ dequant(w[rows, cols]); `rows`
+        indexes sub-tiles of sk along in, `cols` of so along out."""
+        r_x = (_span(k * nks + rows, sk, False) if x_whole
+               else _span(rows, sk, nks == 1))
+        r_w = _span(rows, sk_w, nks == 1)
+        r_g = _span(rows, sgc, nks == 1)
+        c = _span(cols, so, nos == 1)
+        xb = x_ref[0, :, r_x].astype(jnp.float32)  # [N, sk]
+        wb = w_ref[0, r_w, c]  # [sk(,/2), so] int8 (packed: two nibbles a byte)
+        if packed:
+            # Widen to 32 bits BEFORE any reshape/bit op: Mosaic has no
+            # uint8→f32 convert and no 8-bit shifts, and the (sgc, gs/2, so)
+            # regroup only lands on whole sublane tiles at 32-bit width (the
+            # int8 tile is 32 rows, the half-group is 16). The wrapper
+            # bitcasts the uint8 bytes to int8, so the sign-extended
+            # arithmetic shift is masked back to the nibble.
+            half = gs // 2
+            wi = wb.astype(jnp.int32).reshape(sgc, half, so)
+            nib = jnp.concatenate([wi & 0xF, (wi >> 4) & 0xF], axis=1)
+            wf = nib.astype(jnp.float32)  # [sgc, gs, so]
+        elif gs:
+            wf = wb.astype(jnp.float32).reshape(sgc, gs, so)
+        else:
+            wf = wb.astype(jnp.float32)  # flat: [sk, so]
+        if gs:
+            # Dequant in registers: the scaled f32 weight tile exists only
+            # in VMEM for this one MXU pass — never written back to HBM.
+            sb = s_ref[0, r_g, c].astype(jnp.float32)  # [sgc, so]
+            wf = (wf * sb[:, None, :]).reshape(sk, so)
+        part = jax.lax.dot_general(
+            xb, wf, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        if z_ref is not None:
+            # Affine zero point: −Σᵢ x_{g,i} · z_{g,o} per group. The
+            # per-group x sums ride the MXU against a 0/1 group-membership
+            # matrix — a lane-splitting reshape of x is not something
+            # Mosaic lowers.
+            zb = z_ref[0, r_g, c].astype(jnp.float32)  # [sgc, so]
+            row = jax.lax.broadcasted_iota(jnp.int32, (sk, sgc), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (sk, sgc), 1)
+            member = ((row >= col * gs) & (row < (col + 1) * gs)).astype(
+                jnp.float32)
+            xs = jax.lax.dot_general(
+                xb, member, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [N, sgc]
+            part -= jax.lax.dot_general(
+                xs, zb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        acc_ref[:, c] += part
+
+    if nks == 1 and nos == 1:
+        sub_tile(0, 0)
+    else:  # rolled: an unrolled walk would pay in compile time (setup_s)
+        @pl.loop(0, nos)
+        def _cols(j):
+            @pl.loop(0, nks)
+            def _rows_of(i):
+                sub_tile(i, j)
 
     @pl.when(k == nk - 1)
     def _emit():
@@ -210,26 +410,36 @@ def _qmm_kernel(_layer_ref, x_ref, w_ref, s_ref, *rest, gs: int, gc: int,
         o_ref[0] = res.astype(o_ref.dtype)
 
 
-def _unembed_kernel(h_ref, w_ref, s_ref, o_ref, acc_ref):
+def _unembed_kernel(h_ref, w_ref, s_ref, o_ref, acc_ref, *, sv: int):
     """h @ qᵀ · s for the vocab-major lm_head layout {"q": [V, D],
-    "s": [V, 1]} — each out tile streams contiguous weight ROWS, so the
+    "s": [V, 1]} — each out block streams contiguous weight ROWS, so the
     transpose never materializes. Blocks: h (N, kc), w (bv, kc), s (bv, 1),
-    out (N, bv) f32."""
+    out (N, bv) f32; the block is converted and multiplied `sv` rows at a
+    time (a rolled loop, as in _qmm_kernel)."""
     import jax.experimental.pallas as pl
 
     k = pl.program_id(1)
     nk = pl.num_programs(1)
+    bv = w_ref.shape[0]
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     hb = h_ref[...].astype(jnp.float32)  # [N, kc]
-    wb = w_ref[...].astype(jnp.float32)  # [bv, kc]
-    acc_ref[...] += jax.lax.dot_general(
-        hb, wb, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+
+    def rows(i):
+        r = _span(i, sv, sv == bv)
+        wb = w_ref[r, :].astype(jnp.float32)  # [sv, kc]
+        acc_ref[:, r] += jax.lax.dot_general(
+            hb, wb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    if sv == bv:
+        rows(0)
+    else:
+        pl.loop(0, bv // sv)(rows)
 
     @pl.when(k == nk - 1)
     def _emit():
@@ -261,27 +471,21 @@ def _qmm_call(x3, wq, s3, z3, *, gs: int, packed: bool, out_dtype,
     from jax.experimental.pallas import tpu as pltpu
 
     E = experts
-    _, kin_w, out = wq.shape
+    out = wq.shape[-1]
     _, N, kin = x3.shape
     if packed:  # same bytes; the kernel masks the nibbles out of int32
         wq = jax.lax.bitcast_convert_type(wq, jnp.int8)
-    if gs:
-        g = kin // gs
-        # Scale/zero blocks are (1, gc, bo): gc must be a whole number of
-        # 8-row sublane tiles or the full group axis.
-        gc = _tile(g, (16, 8))
-        kc = gc * gs
-        kc_w = kc // 2 if packed else kc
-    else:
-        kc = _tile(kin)
-        kc_w = kc
-        gc = 1
-    bo = _tile(out)
-    nk = kin // kc
-    grid = (E, out // bo, nk)
+    blk = _blocks(
+        N, kin, out, gs=gs, packed=packed, zeros=z3 is not None,
+        x_bytes=x3.dtype.itemsize, out_bytes=jnp.dtype(out_dtype).itemsize)
+    kc, bo, gc = blk.kc, blk.bo, blk.gc
+    kc_w = kc // 2 if packed else kc
+    note_blocks(wholerow=bo == out)
+    grid = (E, out // bo, kin // kc)
 
     def xi(e, j, k, li):
-        return ((e, 0, k) if x_per_expert else (0, 0, k))
+        kx = k if blk.xk == kc else 0  # resident whole: fetched once
+        return ((e, 0, kx) if x_per_expert else (0, 0, kx))
 
     def wi(e, j, k, li):
         return (li[0] * E + e, k, j)
@@ -290,9 +494,9 @@ def _qmm_call(x3, wq, s3, z3, *, gs: int, packed: bool, out_dtype,
         return (li[0] * E + e, k if gs else 0, j)
 
     in_specs = [
-        pl.BlockSpec((1, N, kc), xi),
+        pl.BlockSpec((1, N, blk.xk), xi),
         pl.BlockSpec((1, kc_w, bo), wi),
-        pl.BlockSpec((1, gc if gs else 1, bo), si),
+        pl.BlockSpec((1, gc, bo), si),
     ]
     args = [x3, wq, s3]
     if z3 is not None:
@@ -300,7 +504,8 @@ def _qmm_call(x3, wq, s3, z3, *, gs: int, packed: bool, out_dtype,
         args.append(z3)
     li = jnp.zeros((1,), jnp.int32) if layer is None else (
         jnp.asarray(layer, jnp.int32).reshape(1))
-    kernel = functools.partial(_qmm_kernel, gs=gs, gc=gc, packed=packed)
+    kernel = functools.partial(
+        _qmm_kernel, gs=gs, sk=blk.sk, so=blk.so, packed=packed)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -385,10 +590,9 @@ def _plain_unembed(h: jnp.ndarray, w: dict) -> jnp.ndarray:
     d = h.shape[-1]
     v = w["q"].shape[0]
     h2 = h.reshape(n, d)
-    bv = _tile(v)
-    kc = _tile(d)
+    bv, kc, sv = _unembed_blocks(n, v, d, x_bytes=h.dtype.itemsize)
     out = pl.pallas_call(
-        _unembed_kernel,
+        functools.partial(_unembed_kernel, sv=sv),
         grid=(v // bv, d // kc),
         in_specs=[
             pl.BlockSpec((n, kc), lambda j, k: (0, k)),

@@ -112,6 +112,9 @@ def stacks_of(a, b, scope: str):
 # matmuls keep the short names they were first counted under.
 _KEYS = {
     "quant_matmul": ("stacked", "sliced"),
+    # not a stack's fate either: the weight block the rule gave a Pallas
+    # dequant-matmul call (`note_blocks`)
+    "quant_matmul_blocks": ("wholerow", "narrowed"),
     "paged_attention": ("paged_attention_stacked", "paged_attention_sliced"),
     # not a stack's fate but the same kind of static choice: the arithmetic
     # of a paged-attention kernel call (`note_arith`)
@@ -128,8 +131,9 @@ class SiteCounts:
     count under `stacked` / `sliced`, paged attention under
     `paged_attention_stacked` / `paged_attention_sliced`; beside them the
     arithmetic of each Pallas paged-attention call, `paged_attention_native`
-    / `paged_attention_f32` (`note_arith`). The choice is
-    static, so it is counted where it is made, once per trace. An engine
+    / `paged_attention_f32` (`note_arith`), and the weight block of each
+    Pallas dequant-matmul call, `wholerow` / `narrowed` (`note_blocks`).
+    The choice is static, so it is counted where it is made, once per trace. An engine
     owns one and traces its programs under `tracing(<program>)`."""
 
     def __init__(self):
@@ -165,6 +169,16 @@ def note_site(stacked: bool, kernel: str = "quant_matmul") -> None:
     tally = _TALLY.get()
     if tally is not None:
         tally[_KEYS[kernel][0 if stacked else 1]] += 1
+
+
+def note_blocks(wholerow: bool) -> None:
+    """Count one Pallas dequant-matmul call of the program being traced by
+    the weight block its rule chose (ops/quant_matmul `_blocks`):
+    `wholerow`, a grid step's block spans the weight's whole out axis (one
+    contiguous run of HBM), or `narrowed`, a full-width step did not fit
+    the VMEM budget and the block is a column strip. The XLA form counts
+    under neither."""
+    note_site(wholerow, kernel="quant_matmul_blocks")
 
 
 def note_arith(native: bool) -> None:

@@ -239,7 +239,8 @@ def test_moe_decode_step_reads_experts_out_of_the_stack():
               for v in eqn.outvars if v.aval.dtype == jnp.int8]
     assert sliced == []
     assert sites.by_program == {
-        "decode_block": {"traces": 1, "stacked": 7, "sliced": 0, **_NO_PAGED_SITES}}
+        "decode_block": {"traces": 1, "stacked": 7, "sliced": 0,
+                         "wholerow": 7, "narrowed": 0, **_NO_PAGED_SITES}}
 
 
 def _all_eqns(jaxpr):
@@ -258,7 +259,9 @@ def test_moe_step_above_the_row_limit_counts_seven_sliced_sites():
     with sites.tracing("admit"):
         jaxpr = jax.make_jaxpr(fn)(*args)
     assert not _pallas_calls(jaxpr.jaxpr, "int8_matmul")
-    assert sites.by_program["admit"] == {"traces": 1, "stacked": 0, "sliced": 7, **_NO_PAGED_SITES}
+    assert sites.by_program["admit"] == {
+        "traces": 1, "stacked": 0, "sliced": 7, "wholerow": 0, "narrowed": 0,
+        **_NO_PAGED_SITES}
 
 
 # --------------------------------------------------------------------------- #

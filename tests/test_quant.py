@@ -435,6 +435,257 @@ def _layer(q, l):
 _SHARED_X, _PER_EXPERT_X = "...d,edf->...ef", "...ef,efd->...ed"
 
 
+# --------------------------------------------------------------------------- #
+# The block rule (ISSUE 36): a grid step's weight block in bytes and whole rows
+# --------------------------------------------------------------------------- #
+
+# (rows, in, out, group size, packed, whole rows expected)
+_RULE_SHAPES = {
+    "mistral_gate_up": (32, 4096, 14336, 0, False, True),
+    "mistral_down": (32, 14336, 4096, 0, False, True),
+    "mistral_kv": (32, 4096, 1024, 0, False, True),
+    "olmoe_up": (32, 2048, 1024, 0, False, True),
+    "olmoe_down": (32, 1024, 2048, 0, False, True),
+    "kimi_up": (64, 2304, 1024, 0, False, True),
+    "kimi_down": (64, 1024, 2304, 0, False, True),
+    "kimi_proj": (64, 2304, 4096, 0, False, True),
+    "solar_up": (64, 4096, 1280, 0, False, True),
+    "solar_down": (64, 1280, 4096, 0, False, True),
+    "solar_q": (64, 4096, 8192, 0, False, True),
+    "verify_256_rows": (256, 4096, 14336, 0, False, False),
+    "verify_256_rows_down": (256, 14336, 4096, 0, False, True),
+    "tp_local_3584": (32, 4096, 3584, 0, False, True),
+    "tp_local_3584_down": (32, 3584, 4096, 0, False, True),
+    # 8 groups of 128 are 1,024 rows: at 4,096 wide a 4 MB block, twice
+    "grouped_128": (32, 14336, 4096, 128, False, False),
+    "grouped_32_moe": (64, 2304, 1024, 32, False, True),
+    # 8 groups of 128 are 1,024 rows: at 14,336 wide a 7 MB block, twice
+    "int4_128": (32, 4096, 14336, 128, True, False),
+    "int4_32": (32, 4096, 14336, 32, True, True),
+    "int4_256_rows": (256, 4096, 14336, 32, True, False),
+    "one_row": (1, 4096, 14336, 0, False, True),
+    "tiny": (5, 64, 96, 0, False, True),
+    "tiny_int4": (5, 64, 96, 32, True, True),
+    "odd_288_384": (5, 288, 384, 0, False, True),
+    "odd_grouped": (5, 288, 384, 32, False, True),
+}
+
+
+@pytest.mark.parametrize("shape", list(_RULE_SHAPES))
+def test_block_rule_divides_aligns_fits_and_prefers_whole_rows(monkeypatch, shape):
+    """`_blocks` as a pure function: the block divides both axes, meets the
+    lane / sublane / group alignment Mosaic asks of every BlockSpec, fits
+    the budget it counted, stays within the byte target unless one legal
+    chunk of rows is already more, and is whole-row wherever whole rows
+    fit (narrowed, to a lane multiple, only at 256 rows x 14,336)."""
+    from localai_tpu.ops import quant_matmul as QM
+
+    n, kin, out, gs, packed, wholerow = _RULE_SHAPES[shape]
+    b = QM._blocks(n, kin, out, gs=gs, packed=packed, zeros=packed)
+    assert kin % b.kc == 0 and out % b.bo == 0
+    assert b.kc % b.sk == 0 and b.bo % b.so == 0
+    assert b.kc == kin or b.kc % 128 == 0  # x's lane tile, int8 sublanes
+    assert b.bo == out or b.bo % 128 == 0
+    assert b.sk == b.kc or b.sk % 128 == 0
+    assert b.so == b.bo or b.so % 128 == 0
+    assert b.xk in (kin, b.kc)
+    if gs:
+        assert b.kc % gs == 0 and b.sk % gs == 0 and b.gc == b.kc // gs
+        assert b.kc == kin or b.gc % 8 == 0  # the scale block's sublane tile
+        assert b.sk == b.kc or (b.sk // gs) % 8 == 0
+    else:
+        assert b.gc == 1
+    assert QM._held(n, kin, b.kc, b.bo, gs=gs, packed=packed,
+                    zeros=packed) <= QM.VMEM_BUDGET
+    assert (b.bo == out) == wholerow
+    block_bytes = b.kc * b.bo // (2 if packed else 1)
+    smaller = [kc for kc in range(128, b.kc, 128)
+               if kin % kc == 0 and (not gs or (kc // gs) % 8 == 0 and kc % gs == 0)]
+    assert block_bytes <= QM.BLOCK_BYTES or not smaller
+    # with room for everything the rule never narrows
+    monkeypatch.setattr(QM, "VMEM_BUDGET", 1 << 40)
+    assert QM._blocks(n, kin, out, gs=gs, packed=packed, zeros=packed).bo == out
+
+
+def test_kernels_ask_for_no_scoped_vmem_of_their_own():
+    """Both kernels run under Mosaic's default scoped VMEM (16 MiB on the
+    v5e) and the rule's budget stays inside it. A raised limit changes what
+    XLA does around the call: with 48 and with 32 MiB asked for,
+    kimi-linear's admission did not come back on the chip."""
+    from localai_tpu.ops import quant_matmul as QM
+
+    assert QM.VMEM_BUDGET < 16 << 20
+    x = jax.ShapeDtypeStruct((4, 256), jnp.bfloat16)
+    w = {"q": jax.ShapeDtypeStruct((256, 384), jnp.int8),
+         "s": jax.ShapeDtypeStruct((1, 384), jnp.float32)}
+    head = {"q": jax.ShapeDtypeStruct((384, 256), jnp.int8),
+            "s": jax.ShapeDtypeStruct((384, 1), jnp.float32)}
+    jaxpr = jax.make_jaxpr(lambda x, w, h: (
+        QM.dispatch_matmul(x, w, impl="pallas"),
+        QM.dispatch_unembed(x, h, impl="pallas")))(x, w, head)
+    calls = (_pallas_calls(jaxpr.jaxpr, "int8_matmul")
+             + _pallas_calls(jaxpr.jaxpr, "int8_unembed"))
+    assert len(calls) == 2
+    for eqn in calls:
+        params = eqn.params["compiler_params"]
+        limit = getattr(params.get("mosaic_tpu"), "vmem_limit_bytes", None)
+        assert limit is None
+
+
+@pytest.mark.parametrize("shape", [(32, 32000, 4096), (32, 50304, 2048),
+                                   (64, 163840, 2304), (64, 24576, 4096),
+                                   (3, 512, 64)])
+def test_unembed_block_rule_takes_whole_rows_of_the_head(shape):
+    from localai_tpu.ops import quant_matmul as QM
+
+    n, v, d = shape
+    bv, kc, sv = QM._unembed_blocks(n, v, d)
+    assert kc == d  # whole rows of [V, D]: one contiguous run a block
+    assert v % bv == 0 and bv % sv == 0
+    assert bv == v or bv % 128 == 0
+    assert sv == bv or sv % 128 == 0
+    assert bv * kc <= QM.BLOCK_BYTES or bv == 128
+
+
+def _odd_case(form, shape, kin=288, out=384, L=2, E=2):
+    """Small analogues of the cells' odd widths: out = 384 = 3 x 128 lanes
+    (2304, 1280, 3584 are 18, 10, 28), in = 288 = 9 x 32 sublanes."""
+    moe = shape != "plain"
+    w = jax.random.normal(
+        jax.random.key(30), (L, E, kin, out) if moe else (L, kin, out)) * 0.1
+    x = jax.random.normal(
+        jax.random.key(31), (5, E, kin) if shape == "moe_per_expert_x" else (5, kin))
+    sub = _PER_EXPERT_X if shape == "moe_per_expert_x" else _SHARED_X
+    q = _quantize_form(w, form)
+
+    def mm(w, impl):
+        from localai_tpu.models.llama import _moe_mm
+        return _moe_mm(x, w, sub, impl=impl) if moe else matmul(x, w, impl=impl)
+
+    return q, mm, L
+
+
+# How the rule is bent to reach each branch of the kernel at a small size:
+# the module's constants are what `_blocks` reads when it is called.
+_RULE_BENDS = {
+    "as_is": {},
+    # a 128 x 128 sub-tile: the rolled walk inside the step, both axes
+    "sub_tile_walk": {"TILE_ELEMS": 128 * 128},
+    # 128 rows a step: several k-chunks, x resident whole and indexed by k
+    "k_chunks": {"BLOCK_BYTES": 128 * 384},
+}
+
+
+@pytest.mark.parametrize("bend,form,shape,kin", [
+    ("as_is", "flat_int8", "plain", 288),
+    ("as_is", "grouped_int8", "plain", 288),
+    ("as_is", "packed_int4", "plain", 288),
+    ("as_is", "flat_int8", "moe_shared_x", 288),
+    ("as_is", "flat_int8", "moe_per_expert_x", 288),
+    ("sub_tile_walk", "flat_int8", "moe_shared_x", 288),
+    ("sub_tile_walk", "flat_int8", "plain", 768),
+    ("sub_tile_walk", "grouped_int8", "plain", 768),
+    ("sub_tile_walk", "packed_int4", "moe_per_expert_x", 768),
+    ("k_chunks", "flat_int8", "plain", 768),
+    ("k_chunks", "grouped_int8", "plain", 768),
+    ("k_chunks", "packed_int4", "moe_per_expert_x", 768),
+])
+def test_block_rule_kernels_match_xla_at_odd_widths(monkeypatch, bend, form,
+                                                    shape, kin):
+    """Interpret-mode agreement with the XLA oracle where an axis is no
+    power of two, stacked at the first and the last layer, with the rule
+    as it is and bent so that the in-kernel sub-tile walk and the k-chunk
+    walk over a resident x both run."""
+    from localai_tpu.models.quant import StackedLayer
+    from localai_tpu.ops import quant_matmul as QM
+
+    for name, value in _RULE_BENDS[bend].items():
+        monkeypatch.setattr(QM, name, value)
+    q, mm, L = _odd_case(form, shape, kin=kin)
+    gs = 0 if form == "flat_int8" else 32
+    b = QM._blocks(5, kin, 384, gs=gs, packed=form == "packed_int4")
+    if bend == "sub_tile_walk":
+        assert (b.kc // b.sk) * (b.bo // b.so) > 1
+    if bend == "k_chunks":
+        assert kin // b.kc > 1 and b.xk == kin
+    for l in (0, L - 1):
+        got = mm(StackedLayer(q, jnp.int32(l)), "pallas")
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(mm(_layer(q, l), "xla")),
+            rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("form", ["flat_int8", "packed_int4"])
+def test_block_rule_narrows_where_whole_rows_do_not_fit(monkeypatch, form):
+    """A budget that cannot hold a full-width step: the block becomes a
+    lane-multiple column strip, x a chunk a step, the site counts as
+    narrowed, and the numbers are still the oracle's."""
+    from localai_tpu.ops import quant_matmul as QM
+    from localai_tpu.ops.stacked import SiteCounts
+
+    gs, packed = (0, False) if form == "flat_int8" else (32, True)
+    call = dict(gs=gs, packed=packed, zeros=packed, x_bytes=4, out_bytes=4)
+    for budget in range(64 << 10, 8 << 20, 32 << 10):
+        monkeypatch.setattr(QM, "VMEM_BUDGET", budget)
+        b = QM._blocks(5, 768, 384, **call)
+        if QM._held(5, 768, b.kc, b.bo, **call) <= budget:
+            break
+    assert b.bo == 128 and b.kc < 768
+    if form == "flat_int8":  # float32 rows past a quarter of the budget
+        assert b.xk == b.kc
+    q, mm, L = _odd_case(form, "plain", kin=768)
+    sites = SiteCounts()
+    with sites.tracing("call"):
+        got = mm(_layer(q, 1), "pallas")
+    assert sites.by_program["call"]["narrowed"] == 1
+    assert sites.by_program["call"]["wholerow"] == 0
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(mm(_layer(q, 1), "xla")),
+        rtol=2e-4, atol=2e-4)
+
+
+def test_unembed_kernel_walks_its_block_in_row_sub_tiles(monkeypatch):
+    """Kimi-Linear's head in small: V = 1280 = 10 x 128 rows of D = 288; a
+    small tile makes the kernel convert the block 128 rows at a time."""
+    from localai_tpu.ops import quant_matmul as QM
+
+    V, D = 1280, 288
+    w = jax.random.normal(jax.random.key(40), (V, D), jnp.float32) * 0.1
+    s = jnp.maximum(jnp.max(jnp.abs(w), axis=-1, keepdims=True) / 127.0, 1e-9)
+    q = {"q": jnp.clip(jnp.round(w / s), -127, 127).astype(jnp.int8), "s": s}
+    h = jax.random.normal(jax.random.key(41), (3, D), jnp.float32)
+    want = unembed_matmul(h, q, impl="xla")
+    for tile in (QM.TILE_ELEMS, 128 * D):
+        monkeypatch.setattr(QM, "TILE_ELEMS", tile)
+        bv, kc, sv = QM._unembed_blocks(3, V, D)
+        assert (bv, kc) == (1280, D) and sv == (1280 if tile > V * D else 128)
+        np.testing.assert_allclose(
+            np.asarray(unembed_matmul(h, q, impl="pallas")), np.asarray(want),
+            rtol=1e-4, atol=1e-4)
+
+
+def test_a_256_row_call_at_14336_wide_counts_as_narrowed():
+    """The row limit at mistral's ffn width: a full-width accumulator is
+    14.7 MB, so the rule narrows `out` and the site says so (traced only)."""
+    from localai_tpu.ops.quant_matmul import dispatch_matmul
+    from localai_tpu.ops.stacked import SiteCounts
+
+    x = jax.ShapeDtypeStruct((256, 4096), jnp.bfloat16)
+    w = {"q": jax.ShapeDtypeStruct((4096, 14336), jnp.int8),
+         "s": jax.ShapeDtypeStruct((1, 14336), jnp.float32)}
+    sites = SiteCounts()
+    with sites.tracing("verify"):
+        y = jax.eval_shape(lambda x, w: dispatch_matmul(x, w, impl="pallas"), x, w)
+    assert y.shape == (256, 14336)
+    tally = sites.by_program["verify"]
+    assert (tally["narrowed"], tally["wholerow"]) == (1, 0)
+    with sites.tracing("decode"):
+        jax.eval_shape(lambda x, w: dispatch_matmul(x, w, impl="pallas"),
+                       jax.ShapeDtypeStruct((32, 4096), jnp.bfloat16), w)
+    tally = sites.by_program["decode"]
+    assert (tally["narrowed"], tally["wholerow"]) == (0, 1)
+
+
 @pytest.mark.parametrize("shape", ["plain", "moe_shared_x", "moe_per_expert_x"])
 @pytest.mark.parametrize("form", ["flat_int8", "grouped_int8", "packed_int4"])
 def test_stacked_kernel_is_bit_identical_to_sliced(form, shape):
@@ -498,6 +749,9 @@ def test_stacked_kernel_under_scan_with_a_traced_index(form):
 # dense-cache programs hold no paged-attention site (ops/stacked.SiteCounts)
 _NO_PAGED_SITES = {"paged_attention_stacked": 0, "paged_attention_sliced": 0,
                    "paged_attention_native": 0, "paged_attention_f32": 0}
+# the seven Pallas dequant-matmul calls of a decode step, by the rule's block
+_WHOLEROW_7 = {"wholerow": 7, "narrowed": 0}
+_NO_BLOCKS = {"wholerow": 0, "narrowed": 0}
 
 
 def _pallas_calls(jaxpr, name):
@@ -547,8 +801,10 @@ def test_decode_step_hands_the_kernels_the_stack_and_counts_it():
         assert [w.ndim for w in weights] == [3]
         assert weights[0].shape[0] == cfg.num_layers > 1
     assert sites.by_program == {
-        "decode_block": {"traces": 1, "stacked": 7, "sliced": 0, **_NO_PAGED_SITES}}
-    assert sites.totals() == {"stacked": 7, "sliced": 0, **_NO_PAGED_SITES}
+        "decode_block": {"traces": 1, "stacked": 7, "sliced": 0, **_WHOLEROW_7,
+                         **_NO_PAGED_SITES}}
+    assert sites.totals() == {"stacked": 7, "sliced": 0, **_WHOLEROW_7,
+                              **_NO_PAGED_SITES}
 
 
 def _eqns(jaxpr):
@@ -607,12 +863,12 @@ def test_paged_decode_step_hands_the_kernel_the_pool_and_counts_it():
     assert [p.shape for p in pools] == [(L, P, page * K, D)] * 2
     assert pool_shape[0] == cfg.num_layers > 1
     assert not layer_pools
-    assert tally == {"traces": 1, "stacked": 7, "sliced": 0,
+    assert tally == {"traces": 1, "stacked": 7, "sliced": 0, **_WHOLEROW_7,
                      "paged_attention_stacked": 1, "paged_attention_sliced": 0,
                      "paged_attention_native": 1, "paged_attention_f32": 0}
     calls, layer_pools, tally, want = seen["xla"]
     assert not calls and len(layer_pools) >= 2  # K and V, sliced at the walk
-    assert tally == {"traces": 1, "stacked": 7, "sliced": 0,
+    assert tally == {"traces": 1, "stacked": 7, "sliced": 0, **_WHOLEROW_7,
                      "paged_attention_stacked": 0, "paged_attention_sliced": 1,
                      "paged_attention_native": 0, "paged_attention_f32": 0}
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -632,17 +888,20 @@ def test_decode_step_above_the_row_limit_slices_at_the_use_site():
         jaxpr = jax.make_jaxpr(fn)(*args)
     assert not _pallas_calls(jaxpr.jaxpr, "int8_matmul")
     assert not _pallas_calls(jaxpr.jaxpr, "int8_unembed")
-    assert sites.by_program["admit"] == {"traces": 1, "stacked": 0, "sliced": 7, **_NO_PAGED_SITES}
+    assert sites.by_program["admit"] == {
+        "traces": 1, "stacked": 0, "sliced": 7, **_NO_BLOCKS, **_NO_PAGED_SITES}
     _, fn_xla, _ = _int8_decode_step(B, "xla")
     got, want = jax.jit(fn)(*args)[0], jax.jit(fn_xla)(*args)[0]
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_engine_gauges_count_stacked_and_sliced_sites():
+@pytest.mark.parametrize("arch", ["tiny", "tiny-moe"])
+def test_engine_gauges_count_stacked_and_sliced_sites(arch):
     """Engine.metrics() totals the sites of every program traced; the decode
     block (2 rows) takes the stack at all seven, no kernel call is handed a
-    slice, and what the XLA engine slices it says too."""
-    cfg = get_arch("tiny")
+    slice, every kernel call got whole-row weight blocks from the block rule
+    (dense and MoE), and what the XLA engine slices it says too."""
+    cfg = get_arch(arch)
     params = init_params(cfg, jax.random.key(0))
     seen = {}
     for impl in ("pallas", "xla"):
@@ -662,13 +921,19 @@ def test_engine_gauges_count_stacked_and_sliced_sites():
     by_program, metrics = seen["pallas"]
     block = by_program["decode_block"]
     assert block["stacked"] == 7 * block["traces"] and block["sliced"] == 0
+    assert (block["wholerow"], block["narrowed"]) == (block["stacked"], 0)
     assert metrics["quant_matmul_stacked_sites"] == sum(
         p["stacked"] for p in by_program.values())
     assert metrics["quant_matmul_sliced_sites"] == 0
+    assert metrics["quant_matmul_wholerow_sites"] == sum(
+        p["wholerow"] for p in by_program.values()) >= block["stacked"]
+    assert metrics["quant_matmul_narrowed_sites"] == 0
     by_program, metrics = seen["xla"]
     assert metrics["quant_matmul_stacked_sites"] == 0
     assert metrics["quant_matmul_sliced_sites"] == sum(
         p["sliced"] for p in by_program.values()) >= 7
+    assert metrics["quant_matmul_wholerow_sites"] == 0
+    assert metrics["quant_matmul_narrowed_sites"] == 0
 
 
 @pytest.mark.multichip
