@@ -48,6 +48,7 @@ device saturated.
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import dataclasses
 import logging
@@ -76,6 +77,7 @@ from localai_tpu.models.config import ArchConfig
 from localai_tpu.observe import postmortem as opostmortem
 from localai_tpu.observe import trace as otrace
 from localai_tpu.observe.journal import EventJournal
+from localai_tpu.observe.scopes import scope
 from localai_tpu.ops.sampling import (
     NEG_INF,
     SamplingParams,
@@ -696,17 +698,28 @@ PROGRAM_NAMES = frozenset({
 })
 
 
-def _named_jit(fn, name: str, sites: Optional[SiteCounts] = None, **kw):
+def _named_jit(fn, name: str, sites: Optional[SiteCounts] = None,
+               leaf: str = "control", **kw):
     """`jax.jit(fn, **kw)` under a stable program name (compile-time only).
     With `sites`, each trace of the program counts into it the quantized
-    matmul and paged-attention call sites it holds (ops/stacked.SiteCounts)."""
-    assert name in PROGRAM_NAMES, name
-    if sites is not None:
-        body = fn
+    matmul and paged-attention call sites it holds (ops/stacked.SiteCounts).
 
-        def fn(*args, **kwargs):
-            with sites.tracing(name):
-                return body(*args, **kwargs)
+    The body is traced under the scope `leaf`, by default `control`: what a
+    program does outside the model's own scopes (models/llama.py) and outside
+    the `sample` regions marked below is the engine's glue (host control
+    unpacked, slot rows written, positions advanced, the step loop), and a
+    capture books it so (observe/scopes.py). The few programs whose glue is
+    something else say so: those that only move cache rows (a span, pages, a
+    slot's snapshot) pass `attention/cache_write`, the fork, which only
+    samples, `sample`."""
+    assert name in PROGRAM_NAMES, name
+    body = fn
+
+    def fn(*args, **kwargs):
+        counting = (sites.tracing(name) if sites is not None
+                    else contextlib.nullcontext())
+        with scope(leaf), counting:
+            return body(*args, **kwargs)
 
     fn.__name__ = fn.__qualname__ = name
     return jax.jit(fn, **kw)
@@ -1596,6 +1609,11 @@ class Engine:
         self.m_moe_picks_here = 0  # and those of an expert held here
         self.m_state_restores = 0  # recurrent-state rows recomputed (preempt)
         self.m_admit_splits = 0  # admission groups cut by state.admit_rows
+        # Admission programs dispatched (full, cached tail, chunk), the rows
+        # they were compiled for and the prompt tokens in them (_count_admit).
+        self.m_admit_programs = 0
+        self.m_admit_rows_dispatched = 0
+        self.m_admit_rows_prompt = 0
         self.m_moe_slots_hit = 0
         self.m_moe_rows_busiest = 0
         self.m_moe_rows_mean = 0.0
@@ -1624,6 +1642,15 @@ class Engine:
         j = self._journal
         if j is not None:
             j.append(event, rid=rid, slot=slot, a=a, b=b, phases=phases)
+
+    def _count_admit(self, rows: int, tokens: int) -> None:
+        """One admission program went out: `rows` it was compiled for (group
+        size x bucket; a chunk's or a cached tail's own rows), `tokens` of a
+        prompt in them. The rest is padding the device computes all the same."""
+        self.m_admit_programs += 1
+        self.m_admit_rows_dispatched += rows
+        self.m_admit_rows_prompt += tokens
+        self._jnote("admit_rows", a=float(rows), b=float(tokens))
 
     def _jstage(self, event: str, rid: str = "", slot: int = -1,
                 a: float = 0.0, b: float = 0.0) -> None:
@@ -2274,7 +2301,7 @@ class Engine:
             def gather(k, v, pages):
                 return k[:, pages], v[:, pages]
 
-            fn = self._jit(gather, "pages_gather")
+            fn = self._jit(gather, "pages_gather", leaf="attention/cache_write")
             self._block_cache[key] = fn
         return fn
 
@@ -2287,7 +2314,8 @@ class Engine:
                 v = cache.v.at[:, pages].set(hv.astype(cache.v.dtype))
                 return llama.KVCache(k=k, v=v)
 
-            fn = self._jit(swap_in, "swap_in", donate_argnums=(0,))
+            fn = self._jit(swap_in, "swap_in", leaf="attention/cache_write",
+                           donate_argnums=(0,))
             self._block_cache[key] = fn
         return fn
 
@@ -3011,9 +3039,10 @@ class Engine:
                 # so only the live prefix streams from HBM. Idle rows whose
                 # (discarded) positions exceed the window just attend over
                 # the whole slice; the final write targets the full cache.
-                read_cache = type(cache)(
-                    k=cache.k[:, :, :kv_win], v=cache.v[:, :, :kv_win]
-                )
+                with scope("attention/mix"):
+                    read_cache = type(cache)(
+                        k=cache.k[:, :, :kv_win], v=cache.v[:, :, :kv_win]
+                    )
             start_pos = positions
             # SCALED fp8 pool: the block-local window stays in MODEL dtype
             # (unscaled) — rows quantize ONCE, at the block's pool write,
@@ -3022,47 +3051,25 @@ class Engine:
             # the scale exists to keep.
             ldt_k = cache.k.dtype if self._kv_scales is None else jnp.dtype(cfg.dtype)
             ldt_v = cache.v.dtype if self._kv_scales is None else jnp.dtype(cfg.dtype)
-            local_k = jnp.zeros(
-                (cfg.cache_layers, B, n, cfg.cache_kv_heads, cfg.cache_k_dim),
-                ldt_k,
-            )
-            local_v = jnp.zeros(
-                (cfg.cache_layers, B, n, cfg.cache_kv_heads, cfg.cache_v_dim),
-                ldt_v,
-            )
+            with scope("attention/cache_write"):  # the block-local window
+                local_k = jnp.zeros(
+                    (cfg.cache_layers, B, n, cfg.cache_kv_heads,
+                     cfg.cache_k_dim), ldt_k,
+                )
+                local_v = jnp.zeros(
+                    (cfg.cache_layers, B, n, cfg.cache_kv_heads,
+                     cfg.cache_v_dim), ldt_v,
+                )
             # A hybrid model's recurrent state is carried by the steps (each
             # updates every row in place) while the pool stays read-only.
             rec0 = (cache.state, cache.conv) if cfg.is_hybrid else None
             if rec0 is not None:
                 cache = cache._replace(state=None, conv=None)
 
-            def body(carry, step):
-                tokens, positions, counts, rngs, lk, lv, gs, rec = carry
-                hyb = {} if rec is None else {"recurrent": rec}
-                if paged:
-                    # Idle/released slots' positions keep ratcheting toward
-                    # S-1 (the carry advances every slot); left unmasked
-                    # they would drive the paged fori_loop bound to the full
-                    # table forever. Their compute is discarded anyway, so
-                    # pin them to 0 for this step's attention.
-                    pos_eff = jnp.where(active, positions, 0)
-                    logits, lk, lv, *routed = llama.decode_step_windowed(
-                        cfg, params, tokens, pos_eff, cache, lk, lv, step,
-                        ep=self.plan.ep, ptable=ptable,
-                        paged_impl=self.ecfg.paged_kernel,
-                        kv_scale=self._kv_scales,
-                        rope_delta=rope_delta, mesh=self._op_mesh,
-                        lora=lora, expert_rows=cfg.is_moe, **hyb,
-                    )
-                else:
-                    logits, lk, lv, *routed = llama.decode_step_windowed(
-                        cfg, params, tokens, positions, read_cache, lk, lv, step,
-                        ep=self.plan.ep, mesh=self._op_mesh,
-                        rope_delta=rope_delta, lora=lora,
-                        expert_rows=cfg.is_moe,
-                    )
-                if rec is not None:
-                    rec = routed.pop()
+            @scope("sample")
+            def after_logits(logits, counts, rngs, gs):
+                """Everything after a step's logits: the draw, the grammar
+                mask, the token, its logprobs, the penalty counts."""
                 split = jax.vmap(lambda k: jax.random.split(k, 2))(rngs)
                 rngs, draw = split[:, 0], split[:, 1]
                 if with_dfa:
@@ -3097,13 +3104,45 @@ class Engine:
                     lp_vals, lp_ids = jax.lax.top_k(logp, LK)
                     tok_lp = jnp.take_along_axis(logp, nxt[:, None], axis=-1)[:, 0]
                     out = out + (tok_lp, lp_ids, lp_vals)
+                return out, nxt, counts, rngs, gs
+
+            def body(carry, step):
+                tokens, positions, counts, rngs, lk, lv, gs, rec = carry
+                hyb = {} if rec is None else {"recurrent": rec}
+                if paged:
+                    # Idle/released slots' positions keep ratcheting toward
+                    # S-1 (the carry advances every slot); left unmasked
+                    # they would drive the paged fori_loop bound to the full
+                    # table forever. Their compute is discarded anyway, so
+                    # pin them to 0 for this step's attention.
+                    pos_eff = jnp.where(active, positions, 0)
+                    logits, lk, lv, *routed = llama.decode_step_windowed(
+                        cfg, params, tokens, pos_eff, cache, lk, lv, step,
+                        ep=self.plan.ep, ptable=ptable,
+                        paged_impl=self.ecfg.paged_kernel,
+                        kv_scale=self._kv_scales,
+                        rope_delta=rope_delta, mesh=self._op_mesh,
+                        lora=lora, expert_rows=cfg.is_moe, **hyb,
+                    )
+                else:
+                    logits, lk, lv, *routed = llama.decode_step_windowed(
+                        cfg, params, tokens, positions, read_cache, lk, lv, step,
+                        ep=self.plan.ep, mesh=self._op_mesh,
+                        rope_delta=rope_delta, lora=lora,
+                        expert_rows=cfg.is_moe,
+                    )
+                if rec is not None:
+                    rec = routed.pop()
+                out, nxt, counts, rngs, gs = after_logits(
+                    logits, counts, rngs, gs)
                 # Routing of this step, [L, E] rows per expert → experts that
                 # got a row, and the busiest expert's rows summed over layers.
                 per = routed[0] if routed else None
-                moe = (None if per is None else
-                       jnp.stack([(per > 0).sum(), per.max(-1).sum()]
-                                 # an expert share: the picks that landed here
-                                 + ([per.sum()] if cfg.expert_share else [])))
+                with scope("mlp/router"):
+                    moe = (None if per is None else
+                           jnp.stack([(per > 0).sum(), per.max(-1).sum()]
+                                     # an expert share: the picks that landed here
+                                     + ([per.sum()] if cfg.expert_share else [])))
                 # Clamp so idle/overshooting slots keep writing inside their
                 # own cache row instead of out-of-bounds.
                 positions = jnp.minimum(positions + 1, S - 1)
@@ -3129,7 +3168,8 @@ class Engine:
             lp_block = tuple(outs[-3:]) if with_lp else None  # ([n,B],[n,B,LK],[n,B,LK])
             # [2] i32 over the block's steps ([3] under an expert share), MoE
             # models only (_count_routing)
-            moe_block = None if moe is None else moe.sum(0)
+            with scope("mlp/router"):
+                moe_block = None if moe is None else moe.sum(0)
             out = (cache, counts, rngs, tokens, positions, toks_block, tk_block,
                    lp_block, moe_block)
             if with_dfa:
@@ -3231,44 +3271,47 @@ class Engine:
                     cfg, params, prompt_toks, lens, mesh=self._op_mesh,
                     inject=inject, ep=self.plan.ep, mrope=mrope_pos, lora=lora,
                 )
-            valid = (jnp.arange(bucket)[None, :] < lens[:, None]).astype(jnp.int32)
-            rows = jnp.zeros((m, V), jnp.int32)
-            rows = rows.at[jnp.arange(m)[:, None], prompt_toks].add(valid)
-            brows = bias_rows if has_bias else jnp.zeros((m, V), jnp.float32)
-            if tok_v < V:
-                from localai_tpu.ops.sampling import NEG_INF
+            with scope("sample"):  # everything after the logits
+                valid = (jnp.arange(bucket)[None, :] < lens[:, None]).astype(jnp.int32)
+                rows = jnp.zeros((m, V), jnp.int32)
+                rows = rows.at[jnp.arange(m)[:, None], prompt_toks].add(valid)
+                brows = bias_rows if has_bias else jnp.zeros((m, V), jnp.float32)
+                if tok_v < V:
+                    from localai_tpu.ops.sampling import NEG_INF
 
-                brows = jnp.where(jnp.arange(V)[None, :] >= tok_v, NEG_INF, brows)
-            keys0 = jax.vmap(jax.random.key)(seeds.astype(jnp.uint32))
-            draws = jax.vmap(lambda k: jax.random.fold_in(k, 0))(keys0)
-            srows = brows + gmask0 if with_dfa else brows
-            toks = sample(logits, draws, samp, rows, srows)  # [m]
-            rows = rows.at[jnp.arange(m), toks].add(1)
-            tk = jax.lax.top_k(logits + brows, K)[1] if with_topk else None
-            lp = None
-            if with_lp:
-                logp = jax.nn.log_softmax(logits.astype(jnp.float32) + brows, axis=-1)
-                lp_vals, lp_ids = jax.lax.top_k(logp, LK)
-                tok_lp = jnp.take_along_axis(logp, toks[:, None], axis=-1)[:, 0]
-                lp = (tok_lp, lp_ids, lp_vals)
-            if with_dfa:
-                gnext = self._dfa_advance(with_dfa, gtrans, tok_cls, ginit, toks)  # [m]
+                    brows = jnp.where(jnp.arange(V)[None, :] >= tok_v, NEG_INF, brows)
+                keys0 = jax.vmap(jax.random.key)(seeds.astype(jnp.uint32))
+                draws = jax.vmap(lambda k: jax.random.fold_in(k, 0))(keys0)
+                srows = brows + gmask0 if with_dfa else brows
+                toks = sample(logits, draws, samp, rows, srows)  # [m]
+                rows = rows.at[jnp.arange(m), toks].add(1)
+                tk = jax.lax.top_k(logits + brows, K)[1] if with_topk else None
+                lp = None
+                if with_lp:
+                    logp = jax.nn.log_softmax(logits.astype(jnp.float32) + brows, axis=-1)
+                    lp_vals, lp_ids = jax.lax.top_k(logp, LK)
+                    tok_lp = jnp.take_along_axis(logp, toks[:, None], axis=-1)[:, 0]
+                    lp = (tok_lp, lp_ids, lp_vals)
+                if with_dfa:
+                    gnext = self._dfa_advance(with_dfa, gtrans, tok_cls, ginit, toks)  # [m]
             for j in range(m):  # m is static and small — unrolled
                 s = slot_ids[j]
                 if ptable is not None:
                     from localai_tpu.ops import ptable as _pt
 
+                    with scope("attention/cache_write"):
+                        row = _pt.select_row(ptable, j)
                     cache = llama.write_prefill_to_pool(
-                        cache, _pt.select_row(ptable, j), ks, vs, j,
-                        kv_scale=self._kv_scales,
+                        cache, row, ks, vs, j, kv_scale=self._kv_scales,
                     )
                 else:
-                    cache = llama.write_prefill_to_cache(
-                        cache, ks[:, j:j + 1], vs[:, j:j + 1], s
-                    )
-                counts = counts.at[s].set(rows[j])
-                rngs = rngs.at[s].set(keys0[j])
-                bias = bias.at[s].set(brows[j])
+                    with scope("attention/cache_write"):
+                        kj, vj = ks[:, j:j + 1], vs[:, j:j + 1]
+                    cache = llama.write_prefill_to_cache(cache, kj, vj, s)
+                with scope("sample"):  # the slot's sampling state
+                    counts = counts.at[s].set(rows[j])
+                    rngs = rngs.at[s].set(keys0[j])
+                    bias = bias.at[s].set(brows[j])
                 d_tokens = d_tokens.at[s].set(toks[j])
                 d_positions = d_positions.at[s].set(lens[j])
                 if with_dfa:
@@ -3416,38 +3459,41 @@ class Engine:
             # Penalty counts from the full prompt, on device (_get_admit's
             # exact recipe — the prefix tokens DO reach the device here, as
             # a token bucket two orders of magnitude smaller than a [V] row).
-            fvalid = (jnp.arange(fbp)[None, :] < (plen + tail_len)).astype(jnp.int32)
-            rows = jnp.zeros((1, V), jnp.int32)
-            rows = rows.at[jnp.arange(1)[:, None], full_toks].add(fvalid)
-            brows = bias_rows if has_bias else jnp.zeros((1, V), jnp.float32)
-            if tok_v < V:
-                from localai_tpu.ops.sampling import NEG_INF
+            with scope("sample"):  # everything after the logits
+                fvalid = (jnp.arange(fbp)[None, :] < (plen + tail_len)).astype(jnp.int32)
+                rows = jnp.zeros((1, V), jnp.int32)
+                rows = rows.at[jnp.arange(1)[:, None], full_toks].add(fvalid)
+                brows = bias_rows if has_bias else jnp.zeros((1, V), jnp.float32)
+                if tok_v < V:
+                    from localai_tpu.ops.sampling import NEG_INF
 
-                brows = jnp.where(jnp.arange(V)[None, :] >= tok_v, NEG_INF, brows)
-            keys0 = jax.vmap(jax.random.key)(aux[2:3].astype(jnp.uint32))
-            draws = jax.vmap(lambda kk: jax.random.fold_in(kk, 0))(keys0)
-            srows = brows + gmask0 if with_dfa else brows
-            toks = sample(logits, draws, samp, rows, srows)  # [1]
-            rows = rows.at[jnp.arange(1), toks].add(1)
-            tk = jax.lax.top_k(logits + brows, K)[1] if with_topk else None
-            lp = None
-            if with_lp:
-                logp = jax.nn.log_softmax(logits.astype(jnp.float32) + brows, axis=-1)
-                lp_vals, lp_ids = jax.lax.top_k(logp, LK)
-                tok_lp = jnp.take_along_axis(logp, toks[:, None], axis=-1)[:, 0]
-                lp = (tok_lp, lp_ids, lp_vals)
-            k = jax.lax.dynamic_update_slice(cache.k, pk.astype(cache.k.dtype),
-                                             (0, slot, 0, 0, 0))
-            v = jax.lax.dynamic_update_slice(cache.v, pv.astype(cache.v.dtype),
-                                             (0, slot, 0, 0, 0))
-            k = jax.lax.dynamic_update_slice(k, tks.astype(k.dtype),
-                                             (0, slot, plen, 0, 0))
-            v = jax.lax.dynamic_update_slice(v, tvs.astype(v.dtype),
-                                             (0, slot, plen, 0, 0))
-            cache = llama.KVCache(k=k, v=v)
-            counts = counts.at[slot].set(rows[0])
-            rngs = rngs.at[slot].set(keys0[0])
-            bias = bias.at[slot].set(brows[0])
+                    brows = jnp.where(jnp.arange(V)[None, :] >= tok_v, NEG_INF, brows)
+                keys0 = jax.vmap(jax.random.key)(aux[2:3].astype(jnp.uint32))
+                draws = jax.vmap(lambda kk: jax.random.fold_in(kk, 0))(keys0)
+                srows = brows + gmask0 if with_dfa else brows
+                toks = sample(logits, draws, samp, rows, srows)  # [1]
+                rows = rows.at[jnp.arange(1), toks].add(1)
+                tk = jax.lax.top_k(logits + brows, K)[1] if with_topk else None
+                lp = None
+                if with_lp:
+                    logp = jax.nn.log_softmax(logits.astype(jnp.float32) + brows, axis=-1)
+                    lp_vals, lp_ids = jax.lax.top_k(logp, LK)
+                    tok_lp = jnp.take_along_axis(logp, toks[:, None], axis=-1)[:, 0]
+                    lp = (tok_lp, lp_ids, lp_vals)
+            with scope("attention/cache_write"):
+                k = jax.lax.dynamic_update_slice(cache.k, pk.astype(cache.k.dtype),
+                                                 (0, slot, 0, 0, 0))
+                v = jax.lax.dynamic_update_slice(cache.v, pv.astype(cache.v.dtype),
+                                                 (0, slot, 0, 0, 0))
+                k = jax.lax.dynamic_update_slice(k, tks.astype(k.dtype),
+                                                 (0, slot, plen, 0, 0))
+                v = jax.lax.dynamic_update_slice(v, tvs.astype(v.dtype),
+                                                 (0, slot, plen, 0, 0))
+                cache = llama.KVCache(k=k, v=v)
+            with scope("sample"):  # the slot's sampling state
+                counts = counts.at[slot].set(rows[0])
+                rngs = rngs.at[slot].set(keys0[0])
+                bias = bias.at[slot].set(brows[0])
             d_tokens = d_tokens.at[slot].set(toks[0])
             d_positions = d_positions.at[slot].set(plen + tail_len)
             out = (cache, counts, rngs, bias, d_tokens, d_positions, toks, tk, lp)
@@ -3551,33 +3597,35 @@ class Engine:
                 cfg, params, tail_toks, aux[0:1], aux[3:4], pk, pv,
                 ep=self.plan.ep, mesh=self._op_mesh,
             )
-            fvalid = (jnp.arange(fbp)[None, :] < (plen + tail_len)).astype(jnp.int32)
-            rows = jnp.zeros((1, V), jnp.int32)
-            rows = rows.at[jnp.arange(1)[:, None], full_toks].add(fvalid)
-            brows = bias_rows if has_bias else jnp.zeros((1, V), jnp.float32)
-            if tok_v < V:
-                from localai_tpu.ops.sampling import NEG_INF
+            with scope("sample"):  # everything after the logits
+                fvalid = (jnp.arange(fbp)[None, :] < (plen + tail_len)).astype(jnp.int32)
+                rows = jnp.zeros((1, V), jnp.int32)
+                rows = rows.at[jnp.arange(1)[:, None], full_toks].add(fvalid)
+                brows = bias_rows if has_bias else jnp.zeros((1, V), jnp.float32)
+                if tok_v < V:
+                    from localai_tpu.ops.sampling import NEG_INF
 
-                brows = jnp.where(jnp.arange(V)[None, :] >= tok_v, NEG_INF, brows)
-            keys0 = jax.vmap(jax.random.key)(aux[2:3].astype(jnp.uint32))
-            draws = jax.vmap(lambda kk: jax.random.fold_in(kk, 0))(keys0)
-            srows = brows + gmask0 if with_dfa else brows
-            toks = sample(logits, draws, samp, rows, srows)  # [1]
-            rows = rows.at[jnp.arange(1), toks].add(1)
-            tk = jax.lax.top_k(logits + brows, K)[1] if with_topk else None
-            lp = None
-            if with_lp:
-                logp = jax.nn.log_softmax(logits.astype(jnp.float32) + brows, axis=-1)
-                lp_vals, lp_ids = jax.lax.top_k(logp, LK)
-                tok_lp = jnp.take_along_axis(logp, toks[:, None], axis=-1)[:, 0]
-                lp = (tok_lp, lp_ids, lp_vals)
+                    brows = jnp.where(jnp.arange(V)[None, :] >= tok_v, NEG_INF, brows)
+                keys0 = jax.vmap(jax.random.key)(aux[2:3].astype(jnp.uint32))
+                draws = jax.vmap(lambda kk: jax.random.fold_in(kk, 0))(keys0)
+                srows = brows + gmask0 if with_dfa else brows
+                toks = sample(logits, draws, samp, rows, srows)  # [1]
+                rows = rows.at[jnp.arange(1), toks].add(1)
+                tk = jax.lax.top_k(logits + brows, K)[1] if with_topk else None
+                lp = None
+                if with_lp:
+                    logp = jax.nn.log_softmax(logits.astype(jnp.float32) + brows, axis=-1)
+                    lp_vals, lp_ids = jax.lax.top_k(logp, LK)
+                    tok_lp = jnp.take_along_axis(logp, toks[:, None], axis=-1)[:, 0]
+                    lp = (tok_lp, lp_ids, lp_vals)
             # Only the tail rows are written — the span's pages stay
             # untouched (they may back other slots and the entry itself).
             cache = llama.write_rows_to_pool(cache, table_row, tks, tvs, plen,
                                              kv_scale=self._kv_scales)
-            counts = counts.at[slot].set(rows[0])
-            rngs = rngs.at[slot].set(keys0[0])
-            bias = bias.at[slot].set(brows[0])
+            with scope("sample"):  # the slot's sampling state
+                counts = counts.at[slot].set(rows[0])
+                rngs = rngs.at[slot].set(keys0[0])
+                bias = bias.at[slot].set(brows[0])
             d_tokens = d_tokens.at[slot].set(toks[0])
             d_positions = d_positions.at[slot].set(plen + tail_len)
             out = (cache, counts, rngs, bias, d_tokens, d_positions, toks, tk, lp)
@@ -3757,10 +3805,11 @@ class Engine:
                 slot = aux[1]
                 # Read-side slice of the slot's written prefix; rows past
                 # aux[2] are garbage and masked inside prefill_tail.
-                pk = jax.lax.dynamic_slice(
-                    cache.k, (0, slot, 0, 0, 0), (L, 1, pwin, K, kd))
-                pv = jax.lax.dynamic_slice(
-                    cache.v, (0, slot, 0, 0, 0), (L, 1, pwin, K, vd))
+                with scope("attention/mix"):  # the prefix the mixer reads
+                    pk = jax.lax.dynamic_slice(
+                        cache.k, (0, slot, 0, 0, 0), (L, 1, pwin, K, kd))
+                    pv = jax.lax.dynamic_slice(
+                        cache.v, (0, slot, 0, 0, 0), (L, 1, pwin, K, vd))
                 _, tks, tvs = llama.prefill_tail(
                     cfg, params, toks, aux[0:1], aux[2:3], pk, pv,
                     ep=self.plan.ep, mesh=self._op_mesh,
@@ -3804,7 +3853,8 @@ class Engine:
                     cache.v, pv.astype(cache.v.dtype), (0, slot, 0, 0, 0))
                 return llama.KVCache(k=k, v=v)
 
-            fn = self._jit(copy, "span_copy", donate_argnums=(0,))
+            fn = self._jit(copy, "span_copy", leaf="attention/cache_write",
+                           donate_argnums=(0,))
             self._block_cache[key] = fn
         return fn
 
@@ -3848,29 +3898,31 @@ class Engine:
                 paged_impl=self.ecfg.paged_kernel, mesh=self._op_mesh,
                 kv_scale=self._kv_scales, sp_mesh=self._sp_chunk_mesh,
             )
-            fvalid = (jnp.arange(fbp)[None, :] < (plen + tail_len)).astype(jnp.int32)
-            rows = jnp.zeros((1, V), jnp.int32)
-            rows = rows.at[jnp.arange(1)[:, None], full_toks].add(fvalid)
-            brows = bias_rows if has_bias else jnp.zeros((1, V), jnp.float32)
-            if tok_v < V:
-                from localai_tpu.ops.sampling import NEG_INF
+            with scope("sample"):  # everything after the logits
+                fvalid = (jnp.arange(fbp)[None, :] < (plen + tail_len)).astype(jnp.int32)
+                rows = jnp.zeros((1, V), jnp.int32)
+                rows = rows.at[jnp.arange(1)[:, None], full_toks].add(fvalid)
+                brows = bias_rows if has_bias else jnp.zeros((1, V), jnp.float32)
+                if tok_v < V:
+                    from localai_tpu.ops.sampling import NEG_INF
 
-                brows = jnp.where(jnp.arange(V)[None, :] >= tok_v, NEG_INF, brows)
-            keys0 = jax.vmap(jax.random.key)(aux[2:3].astype(jnp.uint32))
-            draws = jax.vmap(lambda kk: jax.random.fold_in(kk, 0))(keys0)
-            srows = brows + gmask0 if with_dfa else brows
-            toks = sample(logits, draws, samp, rows, srows)  # [1]
-            rows = rows.at[jnp.arange(1), toks].add(1)
-            tk = jax.lax.top_k(logits + brows, K)[1] if with_topk else None
-            lp = None
-            if with_lp:
-                logp = jax.nn.log_softmax(logits.astype(jnp.float32) + brows, axis=-1)
-                lp_vals, lp_ids = jax.lax.top_k(logp, LK)
-                tok_lp = jnp.take_along_axis(logp, toks[:, None], axis=-1)[:, 0]
-                lp = (tok_lp, lp_ids, lp_vals)
-            counts = counts.at[slot].set(rows[0])
-            rngs = rngs.at[slot].set(keys0[0])
-            bias = bias.at[slot].set(brows[0])
+                    brows = jnp.where(jnp.arange(V)[None, :] >= tok_v, NEG_INF, brows)
+                keys0 = jax.vmap(jax.random.key)(aux[2:3].astype(jnp.uint32))
+                draws = jax.vmap(lambda kk: jax.random.fold_in(kk, 0))(keys0)
+                srows = brows + gmask0 if with_dfa else brows
+                toks = sample(logits, draws, samp, rows, srows)  # [1]
+                rows = rows.at[jnp.arange(1), toks].add(1)
+                tk = jax.lax.top_k(logits + brows, K)[1] if with_topk else None
+                lp = None
+                if with_lp:
+                    logp = jax.nn.log_softmax(logits.astype(jnp.float32) + brows, axis=-1)
+                    lp_vals, lp_ids = jax.lax.top_k(logp, LK)
+                    tok_lp = jnp.take_along_axis(logp, toks[:, None], axis=-1)[:, 0]
+                    lp = (tok_lp, lp_ids, lp_vals)
+            with scope("sample"):  # the slot's sampling state
+                counts = counts.at[slot].set(rows[0])
+                rngs = rngs.at[slot].set(keys0[0])
+                bias = bias.at[slot].set(brows[0])
             d_tokens = d_tokens.at[slot].set(toks[0])
             d_positions = d_positions.at[slot].set(plen + tail_len)
             out = (cache, counts, rngs, bias, d_tokens, d_positions, toks, tk, lp)
@@ -4075,18 +4127,20 @@ class Engine:
         toks = np.zeros((1, n), np.int32)
         toks[0] = st["ids"][offset: offset + n]
         aux = np.asarray([n, slot_idx, offset], np.int32)
-        if self._paged:
-            fn = self._get_chunk_mid(n, None)
-            out = fn(self.params, self.cache, self.d_positions,
-                     jnp.asarray(toks), jnp.asarray(aux),
-                     self._ptable_device_row(st["table_row"]))
-        else:
-            pwin = self._bucket_for(max(offset, 1))
-            fn = self._get_chunk_mid(n, pwin)
-            out = fn(self.params, self.cache, self.d_positions,
-                     jnp.asarray(toks), jnp.asarray(aux))
+        with TraceAnnotation("dispatch/prefill_chunk", m=1, bucket=n, tokens=n):
+            if self._paged:
+                fn = self._get_chunk_mid(n, None)
+                out = fn(self.params, self.cache, self.d_positions,
+                         jnp.asarray(toks), jnp.asarray(aux),
+                         self._ptable_device_row(st["table_row"]))
+            else:
+                pwin = self._bucket_for(max(offset, 1))
+                fn = self._get_chunk_mid(n, pwin)
+                out = fn(self.params, self.cache, self.d_positions,
+                         jnp.asarray(toks), jnp.asarray(aux))
         self.cache, self.d_positions, marker = out
         self.m_prefill_chunks += 1
+        self._count_admit(n, n)
         self._jnote("chunk", rid=st["handle"].rid, slot=slot_idx, a=float(n))
         self._track(_Entry(kind="chunk", toks=marker, tk=None,
                            gen=list(self._slot_gen)))
@@ -4177,7 +4231,11 @@ class Engine:
             state = state + (self.d_gstate,)
         if draft:
             state = state + (self.draft_params, self.d_cache)
-        out = fn(*state, *args)
+        with TraceAnnotation(
+                "dispatch/prefill_chunk_final" if self._paged
+                else "dispatch/admit_cached", m=1, bucket=tb, tokens=len(tail)):
+            out = fn(*state, *args)
+        self._count_admit(tb, len(tail))
         (
             self.cache, self.counts, self.rngs, self.bias,
             self.d_tokens, self.d_positions, toks, tk, lp,
@@ -4318,7 +4376,7 @@ class Engine:
             return out
 
         donate = (0, 1, 2, 3, 4) + ((12,) if with_dfa else ())
-        fn = self._jit(fork_fn, "fork", donate_argnums=donate)
+        fn = self._jit(fork_fn, "fork", leaf="sample", donate_argnums=donate)
         self._admit_cache[key] = fn
         return fn
 
@@ -4338,7 +4396,8 @@ class Engine:
             v = cache.v.at[:, dstp].set(cache.v[:, srcp])
             return llama.KVCache(k=k, v=v)
 
-        fn = self._jit(copy_page, "page_copy", donate_argnums=(0,))
+        fn = self._jit(copy_page, "page_copy", leaf="attention/cache_write",
+                       donate_argnums=(0,))
         self._admit_cache[key] = fn
         return fn
 
@@ -4964,7 +5023,7 @@ class Engine:
                     cache.v, (0, slot, 0, 0, 0), (L, 1, pb, K, vd))
                 return k, v
 
-            fn = self._jit(snap, "snapshot")
+            fn = self._jit(snap, "snapshot", leaf="attention/cache_write")
             self._snap_cache[pb] = fn
         return fn
 
@@ -5544,7 +5603,11 @@ class Engine:
         if fn is None:
             fn = getter(*key[1:])
         try:
-            out = fn(*full_args)
+            with TraceAnnotation(
+                    "dispatch/admit_cached_paged" if key[0] == "cached-paged"
+                    else "dispatch/admit_cached", m=1, bucket=tb,
+                    tokens=len(tail)):
+                out = fn(*full_args)
         except Exception:
             if paged_alloc is not None:
                 self._pages_free(slot_idx)
@@ -5574,6 +5637,7 @@ class Engine:
         if with_logits:
             self._fork_logits = out[-1]
         _host_copy_async(toks)
+        self._count_admit(tb, len(tail))
         # LRU bump + metrics. Identity scan, not `in`: dict == would compare
         # the numpy key arrays elementwise (and raises on length mismatch).
         for idx, e in enumerate(self._prefix_entries):
@@ -6274,6 +6338,9 @@ class Engine:
         out["decode_rows_empty"] = float(self.m_rows_empty)
         out["slots_released"] = float(self.m_slots_released)
         out["slots_released_early"] = float(self.m_slots_released_early)
+        out["admit_programs"] = float(self.m_admit_programs)
+        out["admit_rows_dispatched"] = float(self.m_admit_rows_dispatched)
+        out["admit_rows_prompt"] = float(self.m_admit_rows_prompt)
         if self.cfg.is_moe:
             # Routing of the decode blocks processed, see _count_routing.
             out["moe_expert_slots"] = float(self.m_moe_slots)
@@ -6718,16 +6785,18 @@ class Engine:
     def _dfa_advance(cls, mode, gtrans, tok_cls, state, tok):
         """State after emitting `tok`: direct table gather (fast) or char
         walk. In fast mode `gtrans` IS the [S, V] next-token table."""
-        if mode == "fast":
-            return gtrans[state, tok].astype(jnp.int32)
-        return cls._dfa_next_state(gtrans, tok_cls, state, tok)
+        with scope("sample"):  # the grammar's automaton, beside the draw
+            if mode == "fast":
+                return gtrans[state, tok].astype(jnp.int32)
+            return cls._dfa_next_state(gtrans, tok_cls, state, tok)
 
     @staticmethod
     def _dfa_allowed(mask_bits, state, V):
         """Unpack per-state legality bits: state [B] → bool [B, V]."""
-        rows = mask_bits[state]  # [B, ceil(V/8)] u8
-        bits = (rows[:, :, None] >> jnp.arange(8, dtype=jnp.uint8)[None, None, :]) & 1
-        return bits.reshape(state.shape[0], -1)[:, :V].astype(bool)
+        with scope("sample"):
+            rows = mask_bits[state]  # [B, ceil(V/8)] u8
+            bits = (rows[:, :, None] >> jnp.arange(8, dtype=jnp.uint8)[None, None, :]) & 1
+            return bits.reshape(state.shape[0], -1)[:, :V].astype(bool)
 
     def _lp_active(self) -> bool:
         return any(
@@ -7703,9 +7772,11 @@ class Engine:
                 self._lora_tree, jnp.asarray(adapter_rows, dtype=jnp.int32),
             )
         try:
-            # The span a trace matches the admission's device execution to.
+            # The span a trace matches the admission's device execution to,
+            # with the prompt tokens that execution carries.
+            tokens = int(aux[0].sum())
             with TraceAnnotation("dispatch/admit", m=m, bucket=bucket,
-                                 live=int(self.h_active.sum())):
+                                 tokens=tokens):
                 if self.draft_cfg is None:
                     pre = (self.params, self.cache, self.counts, self.rngs,
                            self.bias, self.d_tokens, self.d_positions)
@@ -7742,6 +7813,7 @@ class Engine:
         if with_logits:
             self._fork_logits = out[-1]
         _host_copy_async(toks)
+        self._count_admit(m * bucket, tokens)
         # Claim slots only after a successful dispatch so a failed admission
         # (e.g. compile error) never leaks slot state.
         for j, ((r, handle), slot_idx) in enumerate(zip(chunk, slot_ids)):
@@ -8305,7 +8377,8 @@ class Engine:
                 v=sd.v.at[:, slot].set(cache.v[:kl, slot].astype(sd.v.dtype)),
             )
 
-        fn = self._jit(sync, "sd_sync", donate_argnums=(0,))
+        fn = self._jit(sync, "sd_sync", leaf="attention/cache_write",
+                       donate_argnums=(0,))
         self._block_cache[("sd-sync",)] = fn
         return fn
 
@@ -8337,7 +8410,8 @@ class Engine:
                 v=sd.v.at[:, slot, :W].set(gv.astype(sd.v.dtype)),
             )
 
-        fn = self._jit(sync, "sd_sync_paged", donate_argnums=(0,))
+        fn = self._jit(sync, "sd_sync_paged", leaf="attention/cache_write",
+                       donate_argnums=(0,))
         self._block_cache[key] = fn
         return fn
 

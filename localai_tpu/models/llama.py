@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from localai_tpu.models import quant
 from localai_tpu.models.config import ArchConfig
 from localai_tpu.models.quant import matmul, unembed_matmul
+from localai_tpu.observe.scopes import scope
 from localai_tpu.ops.attention import (
     _merge_partials_mq,
     decode_attention,  # noqa: F401 — public, used by tests/benchmarks
@@ -285,7 +286,9 @@ def _scan_layers(cfg: ArchConfig, params: Params, h, layer_fn, extras=()):
         return _scan_stack(layer_fn, h, params["layers"], 0, L, extras)
     h, out_d = _scan_stack(layer_fn, h, params["dense_layers"], 0, kd, extras)
     h, out_m = _scan_stack(layer_fn, h, params["layers"], kd, L, extras)
-    out = jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=0), out_d, out_m)
+    with scope("attention/cache_write"):  # the two stacks' rows, one stack
+        out = jax.tree.map(
+            lambda a, b: jnp.concatenate([a, b], axis=0), out_d, out_m)
     return h, out
 
 
@@ -335,7 +338,11 @@ def _scan_stack(layer_fn, h, stack, lo: int, hi: int, extras):
         h, out = layer_fn(h, (lp, li) + ex)
         return (h, i + 1), out
 
-    (h, _), out = jax.lax.scan(body, (h, jnp.int32(0)), None, length=hi - lo)
+    # `layer` owns what no scope inside it claims: norms, residual adds, the
+    # loop itself (observe/scopes.py).
+    with scope("layer"):
+        (h, _), out = jax.lax.scan(
+            body, (h, jnp.int32(0)), None, length=hi - lo)
     return h, out
 
 
@@ -672,41 +679,46 @@ def _mlp(cfg: ArchConfig, lp: Params, x: jnp.ndarray, ep: int = 1,
     """
     qk = cfg.quant_kernel
     if "router" not in lp:
-        gate = _act(cfg, _lora_add(
-            cfg, lora, "w_gate", x, matmul(x, lp["w_gate"], qk, mesh, "col"),
-            "col", mesh,
-        ))
-        up = _lora_add(
-            cfg, lora, "w_up", x, matmul(x, lp["w_up"], qk, mesh, "col"),
-            "col", mesh,
-        )
-        gu = gate * up
-        return _lora_add(
-            cfg, lora, "w_down", gu, matmul(gu, lp["w_down"], qk, mesh, "row"),
-            "row", mesh,
-        ).astype(x.dtype)
+        with scope("mlp/dense"):
+            gate = _act(cfg, _lora_add(
+                cfg, lora, "w_gate", x,
+                matmul(x, lp["w_gate"], qk, mesh, "col"), "col", mesh,
+            ))
+            up = _lora_add(
+                cfg, lora, "w_up", x, matmul(x, lp["w_up"], qk, mesh, "col"),
+                "col", mesh,
+            )
+            gu = gate * up
+            return _lora_add(
+                cfg, lora, "w_down", gu,
+                matmul(gu, lp["w_down"], qk, mesh, "row"), "row", mesh,
+            ).astype(x.dtype)
     quantized = quant.is_quantized(lp["w_gate"])
     if ep > 1 and not quantized:
-        y = _moe_capacity(cfg, lp, x)  # routes block by block, inside
+        with scope("mlp/experts"):  # routes block by block, inside
+            y = _moe_capacity(cfg, lp, x)
     else:
-        route = _moe_route(cfg, lp, x)
-        if cfg.expert_share is not None:
-            route = _held_route(cfg, route)
+        with scope("mlp/router"):
+            route = _moe_route(cfg, lp, x)
+            if cfg.expert_share is not None:
+                route = _held_route(cfg, route)
         if picks is not None:
             picks.append(route[1])
         rows = x.size // x.shape[-1]
-        if quantized and (ep > 1 or rows <= QUANT_PALLAS_MAX_ROWS):
-            y = _moe_dense(cfg, lp, x, mesh=mesh, route=route)
-        else:
-            y = _moe_ragged(cfg, lp, x, route=route)
+        with scope("mlp/experts"):
+            if quantized and (ep > 1 or rows <= QUANT_PALLAS_MAX_ROWS):
+                y = _moe_dense(cfg, lp, x, mesh=mesh, route=route)
+            else:
+                y = _moe_ragged(cfg, lp, x, route=route)
     if "shared_gate" in lp:
-        sg = _act(cfg, matmul(x, lp["shared_gate"], qk, mesh, "col"))
-        y = y + matmul(sg * matmul(x, lp["shared_up"], qk, mesh, "col"),
-                       lp["shared_down"], qk, mesh, "row").astype(x.dtype)
+        with scope("mlp/shared"):
+            sg = _act(cfg, matmul(x, lp["shared_gate"], qk, mesh, "col"))
+            y = y + matmul(sg * matmul(x, lp["shared_up"], qk, mesh, "col"),
+                           lp["shared_down"], qk, mesh, "row").astype(x.dtype)
     return y
 
 
-@jax.named_scope("attention")
+@scope("attention/out")
 def _attn_out(cfg: ArchConfig, lp: Params, attn_flat: jnp.ndarray,
               mesh=None, lora=None) -> jnp.ndarray:
     """Output projection + optional gemma-2 post-attention sandwich norm.
@@ -721,7 +733,7 @@ def _attn_out(cfg: ArchConfig, lp: Params, attn_flat: jnp.ndarray,
     return a
 
 
-@jax.named_scope("attention")
+@scope("attention/out")
 def _attn_gated(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
                 attn_flat: jnp.ndarray, mesh=None) -> jnp.ndarray:
     """The output gate of gated attention (`cfg.attn_gate`): the attention
@@ -732,10 +744,10 @@ def _attn_gated(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
             * jax.nn.sigmoid(g.astype(jnp.float32))).astype(attn_flat.dtype)
 
 
-@jax.named_scope("mlp")
 def _mlp_out(cfg: ArchConfig, lp: Params, x: jnp.ndarray, ep: int = 1,
              mesh=None, lora=None, picks=None) -> jnp.ndarray:
-    """MLP + optional gemma-2 post-feedforward sandwich norm."""
+    """MLP (scoped `mlp/...` where `_mlp` picks its form) + optional gemma-2
+    post-feedforward sandwich norm (the enclosing `layer`'s, as every norm)."""
     m = _mlp(cfg, lp, x, ep, mesh=mesh, lora=lora, picks=picks)
     if cfg.post_norms:
         m = rms_norm(m, lp["post_ffw_norm"], cfg.rms_eps)
@@ -762,7 +774,7 @@ def _layer_inv_freq(cfg: ArchConfig, inv_global, inv_local, li):
     return jnp.where(sliding, inv_local, inv_global)
 
 
-@jax.named_scope("attention")
+@scope("attention/proj")
 def _attn_proj_qkv(cfg: ArchConfig, lp: Params, x: jnp.ndarray, mesh=None,
                    lora=None):
     """x: [..., D] -> q [..., H, Hd], k/v [..., K, Hd]."""
@@ -844,6 +856,7 @@ def _latent_pad(cfg: ArchConfig, a: jnp.ndarray) -> jnp.ndarray:
     return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
 
 
+@scope("attention/proj")
 def _mla_rows(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
               positions: jnp.ndarray, inv: jnp.ndarray) -> jnp.ndarray:
     """Latent cache rows [B, T, 1, r+rot] = [RMSNorm(c_kv) | RoPE(k_pe)] for
@@ -857,6 +870,7 @@ def _mla_rows(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
     return _latent_pad(cfg, rows)
 
 
+@scope("attention/proj")
 def _mla_full_qkv(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
                   positions: jnp.ndarray, inv: jnp.ndarray, mesh=None):
     """Full-rank MLA projections for prefill. x [B, T, D] →
@@ -883,6 +897,7 @@ def _mla_full_qkv(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
     return q, k, v, rows
 
 
+@scope("attention/proj")
 def _mla_absorbed_q(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
                     positions: jnp.ndarray, inv: jnp.ndarray,
                     mesh=None) -> jnp.ndarray:
@@ -900,6 +915,7 @@ def _mla_absorbed_q(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
     return q_eff * jnp.asarray(scale * rope_query_amp(cfg), x.dtype)
 
 
+@scope("attention/out")
 def _mla_unlatent(cfg: ArchConfig, lp: Params, attn: jnp.ndarray) -> jnp.ndarray:
     """Absorbed attention output [..., H, r+rot] → flat per-head values
     [..., H·v_head_dim] via W_vb (the deferred value up-projection)."""
@@ -908,6 +924,7 @@ def _mla_unlatent(cfg: ArchConfig, lp: Params, attn: jnp.ndarray) -> jnp.ndarray
     return out.reshape(*attn.shape[:-2], -1)
 
 
+@scope("embed")
 def _embed(cfg: ArchConfig, params: Params, tokens: jnp.ndarray) -> jnp.ndarray:
     """Token embedding lookup; Gemma scales hidden states by sqrt(D) here
     while the tied unembed reads the raw matrix."""
@@ -924,7 +941,21 @@ def _act(cfg: ArchConfig, x: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.silu(x)
 
 
-@jax.named_scope("lm_head")
+@scope("lm_head")
+def _final_norm(cfg: ArchConfig, params: Params, h: jnp.ndarray) -> jnp.ndarray:
+    """The norm in front of the head (booked with it: XLA fuses the two)."""
+    return rms_norm(h, params["final_norm"], cfg.rms_eps)
+
+
+@scope("lm_head")
+def _last_row(h: jnp.ndarray, lengths: jnp.ndarray) -> jnp.ndarray:
+    """h [B, T, D] -> each row's last valid position [B, D]; an empty prompt
+    reads position 0, not wrap to T-1."""
+    last_idx = jnp.maximum(lengths - 1, 0)
+    return jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]
+
+
+@scope("lm_head")
 def _unembed(cfg: ArchConfig, params: Params, h: jnp.ndarray,
              mesh=None) -> jnp.ndarray:
     # bf16 (or int8-dequant) operands with f32 MXU accumulation: casting the
@@ -1013,7 +1044,8 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
         xm, at = (x[:, None], pos[:, None]) if one else (x, pos)
         if mla_full:
             q, k, v, rows = _mla_full_qkv(cfg, lp, xm, at, inv, mesh)
-            attn = attend(q, k, v, sliding, *cache)[..., : cfg.v_head_dim]
+            with scope("attention/mix"):
+                attn = attend(q, k, v, sliding, *cache)[..., : cfg.v_head_dim]
             attn = attn.reshape(*h.shape[:-1], -1)
         else:
             q = _mla_absorbed_q(cfg, lp, xm, at, inv, mesh)
@@ -1021,12 +1053,13 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
             if one:
                 q, rows = q[:, 0], rows[:, 0]
             latent = [c for kc in cache[::2] for c in (kc, kc)]
-            attn = attend(q, rows, rows, sliding, *latent)
+            with scope("attention/mix"):
+                attn = attend(q, rows, rows, sliding, *latent)
             attn = _mla_unlatent(cfg, lp, attn)
         emit = (rows, rows[..., :0])
     else:
         q, k, v = _attn_proj_qkv(cfg, lp, x, mesh, lora=lora)
-        with jax.named_scope("attention"):
+        with scope("attention/rope"):
             if not cfg.attn_rope:
                 pass  # NoPE: the causal mask is all the order there is
             elif mrope_ang is not None:
@@ -1036,6 +1069,7 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
                 k = apply_rope(k[:, None], pos[:, None], inv)[:, 0]
             else:
                 q, k = apply_rope(q, pos, inv), apply_rope(k, pos, inv)
+        with scope("attention/mix"):
             attn = attend(q, k, v, sliding, *cache)
         attn = attn.reshape(*h.shape[:-1], -1)
         if cfg.attn_gate:
@@ -1060,6 +1094,7 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
 # --------------------------------------------------------------------------- #
 
 
+@scope("attention/proj")
 def _kda_inputs(cfg: ArchConfig, ap: Params, x: jnp.ndarray, conv_prev):
     """x [B, T, D] (normed) -> the recurrence's operands and what the layer
     needs after it. conv_prev [B, c-1, 3·H·dk]: the conv's inputs before
@@ -1092,6 +1127,7 @@ def _kda_inputs(cfg: ArchConfig, ap: Params, x: jnp.ndarray, conv_prev):
     return q, k, v, g, beta, gate.reshape(B, T, H, dk), window
 
 
+@scope("attention/out")
 def _kda_out(cfg: ArchConfig, ap: Params, o, gate, dtype, mesh=None):
     """o [..., H, dv] f32 -> per-head RMSNorm, the sigmoid gate, W_o."""
     o = rms_norm(o, ap["o_norm"], cfg.rms_eps) * gate
@@ -1099,24 +1135,24 @@ def _kda_out(cfg: ArchConfig, ap: Params, o, gate, dtype, mesh=None):
     return matmul(o, ap["wo"], cfg.quant_kernel, mesh, "row")
 
 
-@jax.named_scope("attention")
 def _kda_decode_mix(cfg: ArchConfig, ap: Params, x, rec, j, impl="auto"):
     """One token per slot: x [B, D], rec = (state, conv) stacked over the KDA
     layers, j this layer's index in them. Returns (y [B, D], rec)."""
     from localai_tpu.ops.kda import kda_decode
 
     state, conv = rec
-    with jax.named_scope("layer_conv_rows"):
+    with scope("attention/proj"), jax.named_scope("layer_conv_rows"):
         prev = jax.lax.dynamic_index_in_dim(conv, j, 0, keepdims=False)
     q, k, v, g, beta, gate, window = _kda_inputs(cfg, ap, x[:, None], prev)
-    conv = jax.lax.dynamic_update_index_in_dim(
-        conv, window[:, 1:].astype(conv.dtype), j, 0)
-    o, state = kda_decode(state, j, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                          beta[:, 0], impl=impl)
+    with scope("attention/cache_write"):
+        conv = jax.lax.dynamic_update_index_in_dim(
+            conv, window[:, 1:].astype(conv.dtype), j, 0)
+    with scope("attention/mix"):  # the kernel writes the state's rows in place
+        o, state = kda_decode(state, j, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                              beta[:, 0], impl=impl)
     return _kda_out(cfg, ap, o, gate[:, 0], x.dtype), (state, conv)
 
 
-@jax.named_scope("attention")
 def _kda_prefill_mix(cfg: ArchConfig, ap: Params, x, lengths, rec, j, slots):
     """Whole prompts from an empty state: x [B, T, D] right-padded to
     `lengths`. With rec = (state, conv) the state after each prompt's last
@@ -1126,25 +1162,31 @@ def _kda_prefill_mix(cfg: ArchConfig, ap: Params, x, lengths, rec, j, slots):
 
     B, T, _ = x.shape
     c = cfg.kda_conv
-    zeros = jnp.zeros((B, c - 1, 3 * cfg.kda_heads * cfg.kda_head_dim), x.dtype)
+    with scope("attention/proj"):
+        zeros = jnp.zeros(
+            (B, c - 1, 3 * cfg.kda_heads * cfg.kda_head_dim), x.dtype)
     q, k, v, g, beta, gate, window = _kda_inputs(cfg, ap, x, zeros)
-    valid = jnp.arange(T)[None, :] < lengths[:, None]
-    pad = -T % (CHUNK if T >= CHUNK else SUB)
-    if pad:  # a bucket that is no multiple of the chunk: rows that do nothing
-        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                      for a in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-        valid = jnp.pad(valid, ((0, 0), (0, pad)))
-    o, S = kda_chunk_prefill(q, k, v, g, beta, valid)
-    y = _kda_out(cfg, ap, o[:, :T], gate, x.dtype)
+    with scope("attention/mix"):
+        valid = jnp.arange(T)[None, :] < lengths[:, None]
+        pad = -T % (CHUNK if T >= CHUNK else SUB)
+        if pad:  # a bucket that is no multiple of the chunk: rows that do nothing
+            q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                          for a in (q, k, v, g))
+            beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+            valid = jnp.pad(valid, ((0, 0), (0, pad)))
+        o, S = kda_chunk_prefill(q, k, v, g, beta, valid)
+        o = o[:, :T]
+    y = _kda_out(cfg, ap, o, gate, x.dtype)
     if rec is not None:
         state, conv = rec
-        # the conv's inputs at tokens len-c+1 .. len-1 (zeros before 0)
-        rows = jnp.take_along_axis(
-            window, (lengths[:, None] + jnp.arange(c - 1)[None, :])[..., None],
-            axis=1)
-        state = state.at[j, slots].set(S)
-        conv = conv.at[j, slots].set(rows.astype(conv.dtype))
+        with scope("attention/cache_write"):
+            # the conv's inputs at tokens len-c+1 .. len-1 (zeros before 0)
+            rows = jnp.take_along_axis(
+                window,
+                (lengths[:, None] + jnp.arange(c - 1)[None, :])[..., None],
+                axis=1)
+            state = state.at[j, slots].set(S)
+            conv = conv.at[j, slots].set(rows.astype(conv.dtype))
         rec = (state, conv)
     return y, rec
 
@@ -1234,8 +1276,9 @@ def _scan_hybrid(cfg: ArchConfig, params: Params, h, rec, kda_fn, cache_fn,
                 h, out_m = cache_layer(h, j, li)
             return (h, rec, j + 1), (out_k, out_m)
 
-        (h, rec, _), outs = jax.lax.scan(
-            body, (h, rec, jnp.int32(lo)), None, length=hi - lo)
+        with scope("layer"):  # as `_scan_stack`'s
+            (h, rec, _), outs = jax.lax.scan(
+                body, (h, rec, jnp.int32(lo)), None, length=hi - lo)
         return h, rec, outs
 
     outs_k = []
@@ -1244,12 +1287,14 @@ def _scan_hybrid(cfg: ArchConfig, params: Params, h, rec, kda_fn, cache_fn,
         outs_k.append(ok)
     h, rec, (ok, om) = run(h, rec, nd, len(kl), params["layers"], kd, True)
     outs_k.append(ok)
-    out_k = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs_k)
-    there = [j - nd for j in range(nd, len(kl)) if beside[j] >= 0]
-    out_m = jax.tree.map(lambda a: a[jnp.asarray(there)], om)
+    with scope("attention/cache_write"):  # the rows the cache layers emitted
+        out_k = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs_k)
+        there = [j - nd for j in range(nd, len(kl)) if beside[j] >= 0]
+        out_m = jax.tree.map(lambda a: a[jnp.asarray(there)], om)
     return h, rec, out_k, out_m
 
 
+@scope("mlp/router")
 def _expert_counts(cfg: ArchConfig, picks) -> jnp.ndarray:
     """[E] int32: rows per held expert of one MLP's router choice (zeros for
     a dense MLP, whose `picks` stayed empty)."""
@@ -1284,6 +1329,7 @@ def _hybrid_layer_fns(cfg: ArchConfig, kda_mix, *, pos, inv, attend,
             mla_full=mla_full, ep=ep, mesh=mesh, picks=picks)
         return h, rows + ((_expert_counts(cfg, picks),) if count else ())
 
+    @scope("attention/cache_write")
     def cache_zero(h):
         lead = h.shape[:-1]
         rows = jnp.zeros(lead + (cfg.cache_kv_heads, cfg.cache_k_dim), h.dtype)
@@ -1295,6 +1341,7 @@ def _hybrid_layer_fns(cfg: ArchConfig, kda_mix, *, pos, inv, attend,
     return kda_fn, cache_fn, cache_zero
 
 
+@scope("attention/rope")
 def _rope_inv(cfg: ArchConfig):
     """(global, local | None) rope frequencies, as `_decoder_layer` takes them."""
     return rope_frequencies(cfg), rope_frequencies_local(cfg)
@@ -1329,10 +1376,10 @@ def _forward_hidden(
     use_ring = mesh is not None and mesh.shape.get("sp", 1) > 1
     if use_ring and S % mesh.shape["sp"] != 0:
         raise ValueError(f"sequence bucket {S} not divisible by sp={mesh.shape['sp']}")
-    inv_freq = rope_frequencies(cfg)
-    inv_local = rope_frequencies_local(cfg)
-    positions = jnp.arange(S)[None, :].repeat(B, axis=0)  # [B, S]
-    length_mask = jnp.arange(S)[None, :] < lengths[:, None]
+    inv_freq, inv_local = _rope_inv(cfg)
+    with scope("attention/mix"):  # the masks every layer's mixer reads
+        positions = jnp.arange(S)[None, :].repeat(B, axis=0)  # [B, S]
+        length_mask = jnp.arange(S)[None, :] < lengths[:, None]
     mrope_ang = None
     if mrope is not None:
         # Qwen2-VL m-rope (HF get_rope_index semantics): section-selected
@@ -1341,18 +1388,20 @@ def _forward_hidden(
             raise ValueError("mrope positions passed but cfg.mrope_section empty")
         if inv_local is not None:
             raise ValueError("mrope + per-layer local rope is unsupported")
-        mrope_ang = mrope_angles(mrope, inv_freq, tuple(cfg.mrope_section))
+        with scope("attention/rope"):
+            mrope_ang = mrope_angles(mrope, inv_freq, tuple(cfg.mrope_section))
 
     h = _embed(cfg, params, tokens)  # [B, S, D]
     if inject is not None:
         # Multimodal: overwrite the placeholder span with projected image
         # features (models/vision.py) — the llava injection point.
         embeds, offsets = inject
-        h = jax.vmap(
-            lambda hb, eb, ob: jax.lax.dynamic_update_slice(
-                hb, eb.astype(hb.dtype), (ob, 0)
-            )
-        )(h, embeds, offsets)
+        with scope("embed"):
+            h = jax.vmap(
+                lambda hb, eb, ob: jax.lax.dynamic_update_slice(
+                    hb, eb.astype(hb.dtype), (ob, 0)
+                )
+            )(h, embeds, offsets)
 
     if use_ring:
         if cfg.is_mla:
@@ -1404,7 +1453,7 @@ def _forward_hidden(
     else:
         extras = () if lora is None else (lora[0],)
         h, kv = _scan_layers(cfg, params, h, body, extras)
-    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
+    h = _final_norm(cfg, params, h)
     if recurrent is not None:
         return h, length_mask, kv, rec
     return h, length_mask, kv
@@ -1429,9 +1478,7 @@ def prefill(
         cfg, params, tokens, lengths, collect_kv=True, mesh=mesh, inject=inject,
         ep=ep, mrope=mrope, lora=lora, recurrent=recurrent,
     )
-    last_idx = jnp.maximum(lengths - 1, 0)  # empty prompt reads position 0, not wrap to S-1
-    last = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]  # [B, D]
-    logits = _unembed(cfg, params, last, mesh)
+    logits = _unembed(cfg, params, _last_row(h, lengths), mesh)
     return (logits, ks, vs, *rec)
 
 
@@ -1450,10 +1497,12 @@ def encode(
     same decoder weights.
     """
     h, length_mask, _ = _forward_hidden(cfg, params, tokens, lengths, collect_kv=False, mesh=mesh, ep=ep)
-    h = h.astype(jnp.float32)
-    mask = length_mask[..., None].astype(jnp.float32)
-    pooled = (h * mask).sum(axis=1) / jnp.maximum(mask.sum(axis=1), 1.0)
-    return pooled / jnp.maximum(jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-9)
+    with scope("lm_head"):  # this program's head: the pooling
+        h = h.astype(jnp.float32)
+        mask = length_mask[..., None].astype(jnp.float32)
+        pooled = (h * mask).sum(axis=1) / jnp.maximum(mask.sum(axis=1), 1.0)
+        return pooled / jnp.maximum(
+            jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-9)
 
 
 def sequence_logprob(
@@ -1471,13 +1520,14 @@ def sequence_logprob(
     document's conditional likelihood under the LLM given the query)."""
     h, _, _ = _forward_hidden(cfg, params, tokens, lengths, collect_kv=False, mesh=mesh, ep=ep)
     logits = _unembed(cfg, params, h[:, :-1], mesh)  # [B, S-1, V] predicts tokens[1:]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    tgt = tokens[:, 1:]  # [B, S-1]
-    tok_lp = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-    pos = jnp.arange(tgt.shape[1])[None, :] + 1  # position of the target token
-    valid = (pos >= cond_lengths[:, None]) & (pos < lengths[:, None])
-    n = jnp.maximum(valid.sum(axis=-1), 1)
-    return (tok_lp * valid).sum(axis=-1) / n  # [B]
+    with scope("sample"):  # after the logits: the targets' logprobs
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        tgt = tokens[:, 1:]  # [B, S-1]
+        tok_lp = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+        pos = jnp.arange(tgt.shape[1])[None, :] + 1  # position of the target token
+        valid = (pos >= cond_lengths[:, None]) & (pos < lengths[:, None])
+        n = jnp.maximum(valid.sum(axis=-1), 1)
+        return (tok_lp * valid).sum(axis=-1) / n  # [B]
 
 
 def decode_step(
@@ -1508,7 +1558,6 @@ def decode_step(
     if use_sp and cfg.is_mla:
         raise NotImplementedError("MLA + sp is excluded (PARITY.md)")
     h = _embed(cfg, params, tokens)  # [B, D]
-    batch_idx = jnp.arange(B)
 
     if use_sp:
         def attend(q, k, v, sliding, kc, vc):
@@ -1526,10 +1575,11 @@ def decode_step(
         cfg, params, h, layer, (cache.k, cache.v)
     )
     # One scatter: cache[l, b, positions[b]] = new row, all layers at once.
-    k = cache.k.at[:, batch_idx, positions].set(new_k.astype(cache.k.dtype))
-    v = cache.v.at[:, batch_idx, positions].set(new_v.astype(cache.v.dtype))
-    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
-    logits = _unembed(cfg, params, h, mesh)
+    with scope("attention/cache_write"):
+        batch_idx = jnp.arange(B)
+        k = cache.k.at[:, batch_idx, positions].set(new_k.astype(cache.k.dtype))
+        v = cache.v.at[:, batch_idx, positions].set(new_v.astype(cache.v.dtype))
+    logits = _unembed(cfg, params, _final_norm(cfg, params, h), mesh)
     return logits, KVCache(k=k, v=v)
 
 
@@ -1601,8 +1651,9 @@ def decode_step_windowed(
     if use_sp and cfg.is_mla:
         raise NotImplementedError("MLA + sp is excluded (PARITY.md)")
     # MLA rotates at the row index: `rope_delta` reaches GQA alone.
-    rope_pos = (positions if rope_delta is None or cfg.is_mla
-                else positions + rope_delta)
+    with scope("attention/rope"):
+        rope_pos = (positions if rope_delta is None or cfg.is_mla
+                    else positions + rope_delta)
     h = _embed(cfg, params, tokens)
     sink = dict(sink=cfg.attention_sink, swin=cfg.attention_window)
 
@@ -1660,18 +1711,19 @@ def decode_step_windowed(
                 mla_full=False, ep=ep, mesh=mesh, count=expert_rows),
             extras=extras)
         if expert_rows:  # KDA layers' MLPs, then the cache layers'
-            rows_e = [jnp.concatenate([rows_k, rows_e[0]], axis=0)]
+            with scope("mlp/router"):
+                rows_e = [jnp.concatenate([rows_k, rows_e[0]], axis=0)]
             routed.extend([True] * cfg.is_moe)
     else:
         h, (new_k, new_v, *rows_e) = _scan_layers(cfg, params, h, body, extras)
-    local_k = jax.lax.dynamic_update_index_in_dim(
-        local_k, new_k.astype(local_k.dtype), step, axis=2
-    )
-    local_v = jax.lax.dynamic_update_index_in_dim(
-        local_v, new_v.astype(local_v.dtype), step, axis=2
-    )
-    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
-    logits = _unembed(cfg, params, h, mesh)
+    with scope("attention/cache_write"):
+        local_k = jax.lax.dynamic_update_index_in_dim(
+            local_k, new_k.astype(local_k.dtype), step, axis=2
+        )
+        local_v = jax.lax.dynamic_update_index_in_dim(
+            local_v, new_v.astype(local_v.dtype), step, axis=2
+        )
+    logits = _unembed(cfg, params, _final_norm(cfg, params, h), mesh)
     out = (logits, local_k, local_v)
     if expert_rows:
         out = out + ((rows_e[0] if routed else None),)
@@ -1680,6 +1732,7 @@ def decode_step_windowed(
     return out
 
 
+@scope("attention/cache_write")
 def write_block_to_cache(
     cache: KVCache,
     local_k: jnp.ndarray,  # [L, B, n, K, Hd]
@@ -1725,11 +1778,11 @@ def decode_chunk(
     composes with the paged cache."""
     B, T = tokens.shape
     h = _embed(cfg, params, tokens)  # [B, T, D]
-    batch_idx = jnp.arange(B)[:, None].repeat(T, axis=1)  # [B, T]
-    causal = jnp.tril(jnp.ones((T, T), bool))
-    # In-window distance t-u (positions are contiguous per slot), for the
-    # gemma-2 sliding mask.
-    win_dist = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+    with scope("attention/mix"):
+        causal = jnp.tril(jnp.ones((T, T), bool))
+        # In-window distance t-u (positions are contiguous per slot), for the
+        # gemma-2 sliding mask.
+        win_dist = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
 
     if ptable is not None:
         def attend(q, k, v, sliding, kc, vc):
@@ -1766,11 +1819,14 @@ def decode_chunk(
         cache = write_chunk_to_pool(cache, ptable, new_k, new_v, positions,
                                     kv_scale=kv_scale)
     else:
-        k = cache.k.at[:, batch_idx, positions].set(new_k.astype(cache.k.dtype))
-        v = cache.v.at[:, batch_idx, positions].set(new_v.astype(cache.v.dtype))
+        with scope("attention/cache_write"):
+            batch_idx = jnp.arange(B)[:, None].repeat(T, axis=1)  # [B, T]
+            k = cache.k.at[:, batch_idx, positions].set(
+                new_k.astype(cache.k.dtype))
+            v = cache.v.at[:, batch_idx, positions].set(
+                new_v.astype(cache.v.dtype))
         cache = KVCache(k=k, v=v)
-    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
-    logits = _unembed(cfg, params, h, mesh)  # [B, T, V]
+    logits = _unembed(cfg, params, _final_norm(cfg, params, h), mesh)  # [B, T, V]
     return logits, cache
 
 
@@ -1796,12 +1852,13 @@ def prefill_tail(
     """
     T = tokens.shape[1]
     P = prefix_k.shape[2]
-    positions = offsets[:, None] + jnp.arange(T)[None, :]  # [B, T] global
-    length_mask = jnp.arange(T)[None, :] < lengths[:, None]
+    with scope("attention/mix"):  # the masks every layer's mixer reads
+        positions = offsets[:, None] + jnp.arange(T)[None, :]  # [B, T] global
+        length_mask = jnp.arange(T)[None, :] < lengths[:, None]
+        causal = jnp.tril(jnp.ones((T, T), bool))
+        pvalid = jnp.arange(P)[None, :] < offsets[:, None]  # [B, P]
+        win_dist = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]  # in-tail t-u
     h = _embed(cfg, params, tokens)  # [B, T, D]
-    causal = jnp.tril(jnp.ones((T, T), bool))
-    pvalid = jnp.arange(P)[None, :] < offsets[:, None]  # [B, P]
-    win_dist = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]  # in-tail t-u
 
     def attend(q, k, v, sliding, kc, vc):  # kc/vc [B, P, K, Hd]
         pmask = _slid(cfg, sliding, pvalid[:, None, :],
@@ -1818,13 +1875,12 @@ def prefill_tail(
     h, (ks, vs) = _scan_layers(
         cfg, params, h, layer, (prefix_k, prefix_v)
     )
-    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
-    last_idx = jnp.maximum(lengths - 1, 0)
-    last = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]  # [B, D]
+    last = _last_row(_final_norm(cfg, params, h), lengths)  # [B, D]
     logits = _unembed(cfg, params, last, mesh)
     return logits, ks, vs
 
 
+@scope("attention/cache_write")
 def write_prefill_to_cache(
     cache: KVCache,
     ks: jnp.ndarray,  # [L, B_new, S, K, Hd] from prefill
@@ -1875,6 +1931,7 @@ def _pool_store(rows: jnp.ndarray, pool_dtype, scale_row) -> jnp.ndarray:
     return (rows.astype(jnp.float32) / scale_row[..., :, None]).astype(pool_dtype)
 
 
+@scope("attention/cache_write")
 def write_block_to_pool(
     pool: KVCache,
     table: jnp.ndarray,  # [B, MP] int32
@@ -1905,6 +1962,7 @@ def write_block_to_pool(
     return pool._replace(k=k, v=v)
 
 
+@scope("attention/cache_write")
 def write_chunk_to_pool(
     pool: KVCache,
     table: jnp.ndarray,  # [B, MP] int32
@@ -1932,6 +1990,7 @@ def write_chunk_to_pool(
     return KVCache(k=k, v=v)
 
 
+@scope("attention/cache_write")
 def write_rows_to_pool(
     pool: KVCache,
     table_row: jnp.ndarray,  # [MP] int32 — the destination slot's pages
@@ -1958,6 +2017,7 @@ def write_rows_to_pool(
     return KVCache(k=k, v=v)
 
 
+@scope("attention/mix")  # the prefix the mixer reads, made contiguous
 def gather_pages(
     pool: KVCache,
     pages: jnp.ndarray,  # [NP] int32 page ids (SCRATCH-padded past the span)
@@ -2023,11 +2083,12 @@ def prefill_chunk_paged(
     unembed entirely (with_logits=False).
     """
     T = tokens.shape[1]
-    positions = offsets[:, None] + jnp.arange(T)[None, :]  # [B, T] global
-    length_mask = jnp.arange(T)[None, :] < lengths[:, None]
+    with scope("attention/mix"):  # the masks every layer's mixer reads
+        positions = offsets[:, None] + jnp.arange(T)[None, :]  # [B, T] global
+        length_mask = jnp.arange(T)[None, :] < lengths[:, None]
+        causal = jnp.tril(jnp.ones((T, T), bool))
+        win_dist = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]  # in-chunk t-u
     h = _embed(cfg, params, tokens)  # [B, T, D]
-    causal = jnp.tril(jnp.ones((T, T), bool))
-    win_dist = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]  # in-chunk t-u
     sink = dict(sink=cfg.attention_sink, swin=cfg.attention_window)
 
     # kc/vc below: the [L, P, page, K, Hd] pools at this layer (StackedLayer)
@@ -2058,12 +2119,11 @@ def prefill_chunk_paged(
                                kv_scale=kv_scale)
     if not with_logits:
         return None, pool
-    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
-    last_idx = jnp.maximum(lengths - 1, 0)
-    last = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]  # [B, D]
+    last = _last_row(_final_norm(cfg, params, h), lengths)  # [B, D]
     return _unembed(cfg, params, last, mesh), pool
 
 
+@scope("attention/cache_write")
 def write_rows_to_cache(
     cache: KVCache,
     slot: jnp.ndarray,  # scalar int32 — destination slot
@@ -2083,6 +2143,7 @@ def write_rows_to_cache(
     return KVCache(k=k, v=v)
 
 
+@scope("attention/cache_write")
 def write_prefill_to_pool(
     pool: KVCache,
     table_row: jnp.ndarray,  # [MP] int32 — the destination slot's pages

@@ -20,6 +20,10 @@ minutes in). This package makes the lifecycle observable in four layers:
   journal events + an engine state snapshot dump to a JSON file whose path
   rides the `loop_dead` gauge labels and the manager log.
 
+`scopes` is the one vocabulary of `jax.named_scope`s the engine's programs
+are written under (compile-time metadata), so that a capture can be read by
+program and scope.
+
 `profile` is the DECLARED measurement point (the LOCALAI_PROFILE debug
 path) and is deliberately excluded from the trace-safety lint targets,
 exactly like the engine drainer thread. What a capture shows of the engine

@@ -110,6 +110,9 @@ BASE_EVENTS = (
     "admit_split",   # a hybrid model's admission group of one bucket went
     #                  out as several programs under the byte bound of
     #                  engine/state.py (a=programs, b=requests of the group)
+    "admit_rows",    # one admission program was dispatched (a=rows it was
+    #                  compiled for: group size x bucket, or a chunk's or a
+    #                  cached tail's own rows; b=prompt tokens in them)
 )
 
 # One journal event type per fault-injection site (faults.SITES), checked

@@ -1,0 +1,68 @@
+"""The named scopes the engine's programs are written under: one vocabulary.
+
+Every device op of every engine program carries, in its `op_name` metadata,
+the `jax.named_scope`s it was traced under (`jit(decode_block)/control/while/
+body/layer/while/body/attention/proj/dot_general`). A scope is compile-time
+metadata: no instruction, no run-time cost. A profiler capture holds the
+`op_name` of every op it timed (the op's `tf_op` stat), so device time can be
+read by program and scope with nothing emitted at run time
+(benchmark/reducers/scope_share.py; docs/OBSERVABILITY.md section 5).
+
+`SCOPES` are the leaves: an op belongs to the leaf its path ENDS in, once
+everything that is no word of the vocabulary (`jit(..)`, `while`, `body`,
+`cond`, `branch_*`, `closed_call`, `shard_map`, `vmap(..)`, the trailing
+primitive) is dropped; scopes nest, and an enclosing one is the owner of what
+nothing inside it claims (`layer` around the layer scan: norms, residual adds,
+the loop itself; `control` around a block's step scan). An op whose path
+holds one of `SLICES` is a per-layer slice out of a stacked array, whatever
+leaf it was cut for; a path that ends in no leaf is unscoped, and the
+partition test (tests/test_scopes.py) refuses it.
+
+The benchmark's reader holds the rule (`scope_share.leaf_of`) and its own
+copy of both tuples (it takes nothing from the program but the system under
+test); a unit test pins them equal, as `LOOP_PHASES` is pinned between
+engine/runtime.py and observe/journal.py.
+"""
+
+from __future__ import annotations
+
+import jax
+
+SCOPES = (
+    "embed",         # token embedding lookup (and its scale)
+    "lm_head",       # final norm, the last-position gather, the output head
+    "sample",        # everything after the logits: penalties, bias, grammar
+    #                  mask, top-k/top-p, the draw, logprobs, rng, the
+    #                  per-slot sampling state (counts, bias rows, keys)
+    "control",       # the engine's own glue inside a program: the packed
+    #                  host control unpacked, positions advanced, per-slot
+    #                  token/position rows written, routing sums, the step loop
+    "attention/proj",         # q/k/v, the MLA pair, KDA's q/k/v/g/beta/gate
+    #                           projections and its short conv, q/k norms
+    "attention/rope",         # the rotation (GQA; MLA rotates inside proj)
+    "attention/mix",          # the token mixer: paged_attention,
+    #                           latent_paged_attention, flash_prefill, the XLA
+    #                           softmax and the block-window merge, kda_decode,
+    #                           the chunkwise KDA prefill
+    "attention/cache_write",  # K/V rows into pool, window or dense cache;
+    #                           recurrent state and conv rows into their slots
+    "attention/out",          # gate, un-latent, per-head norm, output projection
+    "mlp/router",    # router logits, top-k, the held-share mapping, counters
+    "mlp/experts",   # the stacked kernel, or sort + ragged_dot + combine
+    "mlp/shared",    # the always-on shared expert
+    "mlp/dense",     # a dense SwiGLU
+    "layer",         # what is left of a layer: norms, residual adds, the scan
+)
+
+# Per-layer slices out of stacked arrays; they nest inside a leaf and are
+# read as "slices" wherever they occur in a path.
+SLICES = ("layer_weights", "layer_kv_pool", "layer_conv_rows", "layer_state")
+
+
+def scope(leaf: str):
+    """`jax.named_scope(leaf)` for a leaf of `SCOPES` (context manager or
+    decorator), refusing a name the vocabulary does not hold."""
+    if leaf not in SCOPES:
+        raise ValueError(f"{leaf!r} is not in observe.scopes.SCOPES")
+    return jax.named_scope(leaf)
+

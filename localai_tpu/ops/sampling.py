@@ -20,6 +20,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from localai_tpu.observe.scopes import scope
+
 NEG_INF = -1e30
 
 
@@ -57,6 +59,7 @@ class SamplingParams(NamedTuple):
         )
 
 
+@scope("sample")
 def apply_penalties(
     logits: jnp.ndarray,  # [B, V] f32
     counts: jnp.ndarray,  # [B, V] i32 — occurrences of each token so far (prompt+generated)
@@ -103,6 +106,7 @@ def _filter_sorted(sorted_logits: jnp.ndarray, params: SamplingParams) -> jnp.nd
     return jnp.where(keep, sorted_logits, NEG_INF)
 
 
+@scope("sample")
 def sample(
     logits: jnp.ndarray,  # [B, V] any float dtype
     rng: jnp.ndarray,  # [B] batch of PRNG keys (jax.random.key dtype)
@@ -152,6 +156,7 @@ def sample(
     return jnp.where(params.temperature <= 0.0, greedy_tok, sampled_tok)
 
 
+@scope("sample")
 def sample_simple(
     logits: jnp.ndarray,  # [B, V]
     rng: jnp.ndarray,  # [B] PRNG keys
@@ -175,6 +180,7 @@ def sample_simple(
     return jnp.where(params.temperature <= 0.0, greedy_tok, free_tok)
 
 
+@scope("sample")
 def sample_greedy(
     logits: jnp.ndarray,  # [B, V]
     params: SamplingParams,
@@ -190,6 +196,7 @@ def sample_greedy(
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
+@scope("sample")
 def update_counts(counts: jnp.ndarray, tokens: jnp.ndarray, active: jnp.ndarray) -> jnp.ndarray:
     """counts[b, tokens[b]] += 1 for active slots. All shapes static."""
     B = counts.shape[0]
@@ -197,6 +204,7 @@ def update_counts(counts: jnp.ndarray, tokens: jnp.ndarray, active: jnp.ndarray)
     return counts.at[jnp.arange(B), tokens].add(inc)
 
 
+@scope("sample")
 def deterministic_accept(
     pl: jnp.ndarray,  # [B, V] target processed log-probs (processed_logprobs)
     x: jnp.ndarray,  # [B] int32 draft token under test
@@ -222,6 +230,7 @@ def deterministic_accept(
     return log_ratio, res_log
 
 
+@scope("sample")
 def processed_logprobs(
     logits: jnp.ndarray,  # [B, V] any float dtype
     params: SamplingParams,

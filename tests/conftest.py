@@ -68,6 +68,7 @@ _COSTLY_FIRST = (
     "test_train.py",  # 69
     "test_server.py",  # 65
     "test_flux.py",  # 64
+    "test_scopes.py",  # about 70 alone (PR 37)
     "test_observe.py",  # 55
     "test_musicgen.py",  # 51
     "test_model_families.py",  # 44

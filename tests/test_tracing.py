@@ -3,6 +3,7 @@ section 5): program and kernel names, loop phases as spans, the decode-row
 account, the `decode_first` event and the `join` phase."""
 
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -200,19 +201,24 @@ def test_loop_phases_make_no_span_without_a_capture():
 
 def test_a_long_phase_is_spans_of_one_slice(monkeypatch):
     """The profiler records a span when it ends, so one that is open when a
-    capture stops is lost: a phase is cut into slices of SPAN_SLICE_S."""
+    capture stops is lost: a phase is cut into slices of SPAN_SLICE_S. On a
+    clock the test turns (six workers' compiles took most of a 20 ms
+    wall-clock window from this thread): steps of 2**-12 s, slices of eight."""
     _Span.log, _Span.enabled = [], True
-    monkeypatch.setattr(runtime, "SPAN_SLICE_S", 0.002)
+    now = [128.0]
+    monkeypatch.setattr(runtime, "time",
+                        types.SimpleNamespace(monotonic=lambda: now[0]))
+    monkeypatch.setattr(runtime, "SPAN_SLICE_S", 2.0 ** -9)
     ph = runtime.LoopPhases(annotate=_Span)
-    t_end = time.monotonic() + 0.02
-    while time.monotonic() < t_end:
+    for _ in range(80):
         ph.begin("pull")   # what the loop does every slice of its wait
-        time.sleep(0.0002)
+        now[0] += 2.0 ** -12
     ph.end()
     opens = [e for e in _Span.log if e == ("open", "loop/pull")]
-    assert 4 <= len(opens) <= 12
+    assert len(opens) == 10  # 80 steps in slices of eight
     assert _Span.log == [("open", "loop/pull"), ("close", "loop/pull")] * len(opens)
-    assert ph.ms["pull"] >= 19.0 and ph.total() == ph.ms["pull"]
+    assert ph.ms["pull"] == pytest.approx(80 * 2.0 ** -12 * 1e3)
+    assert ph.total() == ph.ms["pull"]
 
 
 def test_the_loops_spans_reach_a_real_capture(tiny, tmp_path):
