@@ -435,10 +435,56 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
             return tuple(out)
         return fn
 
+    up, down = (expert_stack(mo["hidden"], mo["ffn"]),
+                expert_stack(mo["ffn"], mo["hidden"]))
     case("moe_int8_experts_stacked", experts("auto"), experts("xla"),
-         (rnd((32, mo["hidden"])), expert_stack(mo["hidden"], mo["ffn"]),
-          expert_stack(mo["ffn"], mo["hidden"]),
+         (rnd((32, mo["hidden"])), up, down,
           jnp.int32(mo["layers"] // 3), jnp.int32(mo["layers"] - 1)), 2e-2)
+
+    # Admission's form (ISSUE 38): expert-sorted rows walk their groups over
+    # the same stack in `int8_grouped_matmul` (a visit a (row tile, expert)
+    # pair, the tiles of rows in no held group not visited), against
+    # `lax.ragged_dot` on the layer's slice; up then down at a non-zero
+    # layer, the rows of a group compared. Rows are a cell's common
+    # admission program's: 512 or 1,024 prompt rows x top-8.
+    from localai_tpu.ops import quant_matmul as QM
+
+    def grouped(held, impl):
+        def fn(xg, up, down, sizes, i):
+            if QM.grouped_engaged(xg, dict(up), impl, None, i):
+                walk = QM.group_visits(sizes, xg.shape[0])
+                h = QM.grouped_moe_mm(xg, dict(up), walk, layer=i)
+                y = QM.grouped_moe_mm(h, dict(down), walk, layer=i)
+            else:  # rows in no group ride in the last one (llama._ragged_mms)
+                m, e = xg.shape[0], sizes.shape[0]
+                rest = sizes.at[-1].add(m - held)
+                group = jnp.repeat(jnp.arange(e), rest, total_repeat_length=m)
+                h = LL._ragged_mm(xg, Q.layer_slice(Q.StackedLayer(up, i)),
+                                  rest, group)
+                y = LL._ragged_mm(h, Q.layer_slice(Q.StackedLayer(down, i)),
+                                  rest, group)
+            return h[:held], y[:held]
+        return fn
+
+    def group_sizes(rows, experts, share):
+        """Uneven groups (a few experts idle, one busy), `share` of the
+        sorted rows held."""
+        p = 1.0 / (1.0 + np.arange(experts)) ** 0.7
+        p[experts // 2] = 0.0  # an expert no row chose
+        n = np.floor(rows * share * p / p.sum()).astype(np.int32)
+        n[0] += int(rows * share) - int(n.sum())
+        return jnp.asarray(n)
+
+    def grouped_case(name, rows, m, share, up, down):
+        rows = rows if not rehearsal else 256
+        sizes = group_sizes(rows, m["experts"], share)
+        held = int(sizes.sum())
+        case(name, grouped(held, "auto"), grouped(held, "xla"),
+             (rnd((rows, m["hidden"])), up, down, sizes,
+              jnp.int32(m["layers"] - 1)), 2e-2)
+
+    grouped_case("moe_int8_grouped_olmoe", 4096, mo, 1.0, up, down)
+    del up, down
     # -- the hybrid model's kernels (kimi-linear-48b-a3b's shapes) -----------
     hy = s["hybrid"]
     from localai_tpu.ops import kda as KDA
@@ -518,19 +564,27 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
                 k1, (hm["experts"], kin, kout), jnp.float32) * 0.02),
             jax.random.split(kk, hm["layers"])))(next(keys))
 
+    up, down = (held_stack(hm["hidden"], hm["ffn"]),
+                held_stack(hm["ffn"], hm["hidden"]))
     case("moe_int8_held_experts_stacked", experts("auto"), experts("xla"),
-         (rnd((Bk, hm["hidden"])), held_stack(hm["hidden"], hm["ffn"]),
-          held_stack(hm["ffn"], hm["hidden"]),
+         (rnd((Bk, hm["hidden"])), up, down,
           jnp.int32(hm["layers"] // 3), jnp.int32(hm["layers"] - 1)), 2e-2)
+    # an eighth of the sorted rows are picks of an expert held here
+    grouped_case("moe_int8_grouped_kimi_held_eighth", 4096, hm, 0.125, up, down)
+    del up, down
 
     # solar-open2-250b's held stacks [8 x 40, 4096, 1280] and [8 x 40, 1280,
     # 4096]: the first expert width (10 lane tiles) and the first share (40
     # experts a layer) that are no power of two.
     hm = hg["moe"]
+    up, down = (held_stack(hm["hidden"], hm["ffn"], hm),
+                held_stack(hm["ffn"], hm["hidden"], hm))
     case("moe_int8_held_experts_stacked_w1280", experts("auto"), experts("xla"),
-         (rnd((Bg, hm["hidden"])), held_stack(hm["hidden"], hm["ffn"], hm),
-          held_stack(hm["ffn"], hm["hidden"], hm),
+         (rnd((Bg, hm["hidden"])), up, down,
           jnp.int32(hm["layers"] // 3), jnp.int32(hm["layers"] - 1)), 2e-2)
+    # a 5.2 MB expert matrix: two k-chunks a visit
+    grouped_case("moe_int8_grouped_solar_w1280", 8192, hm, 1.0, up, down)
+    del up, down
 
     head = rnd((s["vocab"], hid), jnp.float32, 0.02)
     hs = jnp.maximum(jnp.max(jnp.abs(head), axis=-1, keepdims=True) / 127.0,

@@ -748,6 +748,8 @@ class _Entry:
     dlens: Optional[np.ndarray] = None
     # Decode block of a MoE model: device [2] i32, the block's routing sums
     # (experts that got a row, busiest expert's rows); see _count_routing.
+    # Admission under an expert share: device [2] i32, what the grouped
+    # expert kernel walked; see _count_admit_routing.
     moe: Any = None
     # Host-side results pulled by the drainer thread (toks, tk, lp, moe as
     # numpy).
@@ -822,6 +824,11 @@ class Engine:
         # Per program kind: traces, and the quantized matmul sites in them
         # that took the layer stack or a slice (metrics(), OBSERVABILITY.md).
         self.quant_sites = SiteCounts()
+        # Under an expert share the admission program also returns what its
+        # grouped expert kernel walked: the sorted (row, pick) pairs it was
+        # compiled for and those of an expert held here (journal
+        # `moe_admit_rows`, _count_admit_routing).
+        self._held_rows = bool(cfg.is_moe and cfg.expert_share is not None)
         self.tokenizer = tokenizer
         self.ecfg = engine_cfg or EngineConfig()
         env_chunk = os.environ.get("LOCALAI_PREFILL_CHUNK")
@@ -1607,6 +1614,9 @@ class Engine:
         self.m_moe_slots = 0
         self.m_moe_picks = 0  # under an expert share: the router's picks,
         self.m_moe_picks_here = 0  # and those of an expert held here
+        # and the same of the wide admission programs (_count_admit_routing)
+        self.m_moe_admit_rows = 0
+        self.m_moe_admit_rows_held = 0
         self.m_state_restores = 0  # recurrent-state rows recomputed (preempt)
         self.m_admit_splits = 0  # admission groups cut by state.admit_rows
         # Admission programs dispatched (full, cached tail, chunk), the rows
@@ -3240,6 +3250,7 @@ class Engine:
         V = cfg.vocab_size
         K = min(self.GRAMMAR_TOPK, V)
         LK = min(self.LOGPROB_TOPK, V)
+        held_rows = self._held_rows
 
         # Logits may cover more ids than the tokenizer can decode (padded
         # embedding rows); permanently mask those out of sampling via the
@@ -3261,15 +3272,17 @@ class Engine:
             if cfg.is_hybrid:
                 # Each prompt's recurrent state is written to its slot's row
                 # layer by layer inside the prefill (engine/state.py: claim).
-                logits, ks, vs, (st, cv) = llama.prefill(
+                logits, ks, vs, *held, (st, cv) = llama.prefill(
                     cfg, params, prompt_toks, lens, ep=self.plan.ep,
                     recurrent=(cache.state, cache.conv, slot_ids),
+                    expert_rows=held_rows,
                 )
                 cache = cache._replace(state=st, conv=cv)
             else:
-                logits, ks, vs = llama.prefill(
+                logits, ks, vs, *held = llama.prefill(
                     cfg, params, prompt_toks, lens, mesh=self._op_mesh,
                     inject=inject, ep=self.plan.ep, mrope=mrope_pos, lora=lora,
+                    expert_rows=held_rows,
                 )
             with scope("sample"):  # everything after the logits
                 valid = (jnp.arange(bucket)[None, :] < lens[:, None]).astype(jnp.int32)
@@ -3317,6 +3330,7 @@ class Engine:
                 if with_dfa:
                     d_gstate = d_gstate.at[s].set(gnext[j])
             out = (cache, counts, rngs, bias, d_tokens, d_positions, toks, tk, lp)
+            out = out + tuple(held)  # under an expert share (_held_rows)
             if with_dfa:
                 out = out + (d_gstate,)
             if with_logits:
@@ -6350,6 +6364,8 @@ class Engine:
             if self.cfg.expert_share is not None:
                 out["moe_picks"] = float(self.m_moe_picks)
                 out["moe_picks_here"] = float(self.m_moe_picks_here)
+                out["moe_admit_rows"] = float(self.m_moe_admit_rows)
+                out["moe_admit_rows_held"] = float(self.m_moe_admit_rows_held)
         if self.cfg.is_hybrid:
             # The second kind of per-slot state (engine/state.py).
             out["recurrent_state_bytes"] = float(
@@ -6368,6 +6384,7 @@ class Engine:
         if sites["stacked"] or sites["sliced"]:
             out["quant_matmul_stacked_sites"] = float(sites["stacked"])
             out["quant_matmul_sliced_sites"] = float(sites["sliced"])
+            out["quant_matmul_grouped_sites"] = float(sites["grouped"])
             # and the weight block the rule gave each kernel call: the
             # weight's whole rows, or a column strip (stacked.note_blocks)
             out["quant_matmul_wholerow_sites"] = float(sites["wholerow"])
@@ -6567,22 +6584,21 @@ class Engine:
                     (m, self._max_pages), self._scratch_page, jnp.int32
                 ),)
         if self.draft_cfg is None:
-            (
-                self.cache, self.counts, self.rngs, self.bias,
-                self.d_tokens, self.d_positions, toks, _tk, _lp,
-            ) = fn(
+            out = fn(
                 self.params, self.cache, self.counts, self.rngs, self.bias,
                 self.d_tokens, self.d_positions, *args,
             )
         else:
-            (
-                self.cache, self.counts, self.rngs, self.bias,
-                self.d_tokens, self.d_positions, toks, _tk, _lp, self.d_cache,
-            ) = fn(
+            out = fn(
                 self.params, self.cache, self.counts, self.rngs, self.bias,
                 self.d_tokens, self.d_positions, self.draft_params, self.d_cache,
                 *args,
             )
+            self.d_cache = out[-1]
+        (
+            self.cache, self.counts, self.rngs, self.bias,
+            self.d_tokens, self.d_positions, toks, _tk, _lp,
+        ) = out[:9]  # then the held rows' count (_held_rows), the draft cache
         jax.block_until_ready(toks)
 
     # ------------------------------------------------------------------ #
@@ -7805,6 +7821,9 @@ class Engine:
             self.d_tokens, self.d_positions, toks, tk, lp,
         ) = out[:9]
         rest = out[9:]
+        held = None
+        if self._held_rows:
+            held, rest = rest[0], rest[1:]
         if with_dfa:
             self.d_gstate = rest[0]
             rest = rest[1:]
@@ -7846,7 +7865,8 @@ class Engine:
                 self._defer_prefix_save(slot_idx, r.prompt_ids,
                                         int(aux[0, j]))
         self._track(
-            _Entry(kind="admit", toks=toks, tk=tk, lp=lp, gen=list(self._slot_gen), items=items)
+            _Entry(kind="admit", toks=toks, tk=tk, lp=lp, gen=list(self._slot_gen),
+                   items=items, moe=held)
         )
         self._plan_dirty()
         self._last_admit_t = time.monotonic()
@@ -8596,6 +8616,8 @@ class Engine:
             self._jnote("spec_verify", a=float(drafted), b=float(consumed))
             return
         if e.kind == "admit":
+            if moe is not None:
+                self._count_admit_routing(moe)
             for j, (slot_idx, request, handle, plen, _t0) in enumerate(e.items):
                 # A short budget is covered by the request's first block,
                 # which may be dispatched (and the request parked) before
@@ -8675,6 +8697,21 @@ class Engine:
         self._count_rows(e, consumed)
         if moe is not None:
             self._count_routing(e, moe)
+
+    # thread: engine-loop-only
+    def _count_admit_routing(self, walked: np.ndarray) -> None:
+        """Account one admission program's expert path under an expert
+        share, as its grouped kernel saw it (`llama.prefill(expert_rows=
+        True)`, summed on the device over the MoE layers): the sorted (row,
+        pick) pairs the kernel was compiled for and those in a held group;
+        the rest sort last and their tiles are not visited. Nothing where
+        the kernel did not run (few rows, off the TPU: `llama._mlp`)."""
+        pairs, held = int(walked[0]), int(walked[1])
+        if not pairs:
+            return
+        self.m_moe_admit_rows += pairs
+        self.m_moe_admit_rows_held += held
+        self._jnote("moe_admit_rows", a=float(pairs), b=float(held))
 
     # thread: engine-loop-only
     def _count_routing(self, e: _Entry, sums: np.ndarray) -> None:

@@ -44,7 +44,13 @@ from localai_tpu.ops.attention import (
     prefix_window_attention,
 )
 from localai_tpu.ops.norm import rms_norm
-from localai_tpu.ops.quant_matmul import QUANT_PALLAS_MAX_ROWS
+from localai_tpu.ops.quant_matmul import (
+    MOE_ALL_EXPERTS_MAX_ROWS,
+    QUANT_PALLAS_MAX_ROWS,
+    group_visits,
+    grouped_engaged,
+    grouped_moe_mm,
+)
 from localai_tpu.ops.rope import (
     apply_rope,
     mrope_angles,
@@ -485,7 +491,10 @@ def _moe_dense(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
 def _ragged_mm(xg: jnp.ndarray, w, sizes: jnp.ndarray,
                group: jnp.ndarray) -> jnp.ndarray:
     """`lax.ragged_dot` of expert-sorted rows xg [M, in] with an expert stack
-    w [G, in, out], plain or quantized; `group` [M] is each row's group.
+    w [G, in, out], plain or quantized; `group` [M] is each row's group. The
+    XLA form of `_moe_ragged`'s grouped matmul, and the oracle of the Pallas
+    kernel that serves a quantized stack on the chip
+    (ops/quant_matmul.grouped_moe_mm).
     Flat int8 converts in the operand and scales each ROW afterwards by its
     own expert's per-channel scale (exactly x @ (q·s): the scale does not
     depend on the contracted axis). The grouped forms scale per in-group, so
@@ -512,21 +521,33 @@ def _expert_stack(w):
     return quant.layer_slice(w)
 
 
-def _moe_ragged(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
-                route=None) -> jnp.ndarray:
-    """Exact top-k MoE via sort + `lax.ragged_dot`: per-token FLOPs ∝ top_k,
-    not E (4× fewer than dense for Mixtral top-2-of-8), and temporaries
-    ∝ rows × top_k.
+def _moe_ragged(cfg: ArchConfig, lp: Params, x: jnp.ndarray, route=None,
+                grouped: bool = False, rows=None) -> jnp.ndarray:
+    """Exact top-k MoE via a sort and one grouped matmul a projection:
+    per-token FLOPs ∝ top_k, not E (4× fewer than dense for Mixtral
+    top-2-of-8), and temporaries ∝ rows × top_k.
 
     The (token, choice) pairs are stably sorted by expert id so each expert's
     rows are contiguous, then one grouped matmul per projection runs all
     experts without any capacity factor — no token is ever dropped, so the
     output is bit-comparable to the dense branch (up to f32 reduction order).
     The reference gets this for free from llama.cpp's per-expert CPU loops
-    (ggml MoE graph); on TPU ragged_dot maps the grouped contraction onto the
-    MXU with static shapes. Plain and quantized expert stacks alike
-    (`_ragged_mm`). `route` = (weights, sel) when the caller already ran the
-    router.
+    (ggml MoE graph). Two forms of the grouped matmul, `_mlp`'s to choose:
+    - `grouped` (a quantized stack on one TPU chip) → the Pallas kernel
+      `int8_grouped_matmul` / `int4_grouped_matmul` walks the sorted rows'
+      groups over the stack as it is stored, still stacked over layers
+      (ops/quant_matmul `grouped_moe_mm`): each visited expert's int8 bytes
+      cross the HBM once, no dequantized copy, no per-row scale gather; an
+      expert no row chose is not read, and under an expert share the picks
+      held elsewhere (they sort last, `_held_route`) are not visited at all;
+    - else (plain experts, tp > 1, off the TPU, impl xla) → `lax.ragged_dot`
+      on the layer's slice (`_ragged_mms`), the oracle.
+    Either way each token's k rows then come back beside each other by the
+    inverse permutation and are summed under their weights (a scatter-add
+    by token was 27-45% of this path at 8,192 sorted rows on the v5e: PR 38).
+    `route` = (weights, sel) when the caller already ran the router; `rows`,
+    a list, receives [2] int32 of the grouped kernel's walk: the sorted rows
+    it was compiled for and those of them in a held group.
     """
     E, k = cfg.experts_here, cfg.num_experts_per_token
     lead, D = x.shape[:-1], x.shape[-1]
@@ -536,10 +557,44 @@ def _moe_ragged(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
     M = N * k
     e_flat = sel.reshape(M)
     order = jnp.argsort(e_flat, stable=True)  # expert-major, token-minor
-    tok = order // k  # source token of each sorted row
-    xg = jnp.take(xf, tok, axis=0)  # [M, D]
+    # source token of each sorted row; every index is a row's own, so no
+    # fill pass behind the gather
+    xg = jnp.take(xf, order // k, axis=0, mode="clip")  # [M, D]
+    if grouped:
+        # rows per held expert (a pick held elsewhere, id E, counts nowhere)
+        walk = group_visits(
+            jax.nn.one_hot(e_flat, E, dtype=jnp.int32).sum(axis=0), M)
+        if rows is not None:
+            rows.append(jnp.stack([jnp.int32(M), walk.off[-1]]))
+
+        def mm(a, name):
+            w = lp[name]
+            return grouped_moe_mm(a, dict(w), walk, layer=quant.layer_of(w))
+    else:
+        mm = _ragged_mms(cfg, lp, e_flat, order)
+    gate = _act(cfg, mm(xg, "w_gate"))
+    up = mm(xg, "w_up")
+    dn = mm((gate * up).astype(xg.dtype), "w_down")  # [M, D]
+    back = jnp.take(dn, jnp.argsort(order), axis=0, mode="clip")
+    back = back.reshape(N, k, D).astype(jnp.float32)
+    if cfg.expert_share is not None:
+        # The kernel wrote no row of a pick held elsewhere: what stands
+        # there is not a number, and 0 x NaN is NaN.
+        back = jnp.where((sel.reshape(N, k) < E)[..., None], back, 0)
+    y = (back * weights.reshape(N, k, 1)).sum(axis=1)
+    return y.reshape(*lead, D).astype(x.dtype)
+
+
+def _ragged_mms(cfg: ArchConfig, lp: Params, e_flat: jnp.ndarray,
+                order: jnp.ndarray):
+    """The grouped matmul of `_moe_ragged` as `lax.ragged_dot` on the layer's
+    expert stack, plain or quantized (`_ragged_mm`); on TPU ragged_dot maps
+    the grouped contraction onto the MXU with static shapes. e_flat [M] each
+    (token, choice)'s expert, `order` the sort. Returns mm(rows [M, in],
+    name) -> [M, out], name one of the three projections' in lp."""
+    E, M = cfg.experts_here, e_flat.shape[0]
     group = jnp.take(e_flat, order)  # [M] expert of each sorted row
-    ws = [_expert_stack(lp[n]) for n in ("w_gate", "w_up", "w_down")]
+    ws = {n: _expert_stack(lp[n]) for n in ("w_gate", "w_up", "w_down")}
     if M < E:
         # Decode-scale batches can touch at most M of E experts. Gathering
         # just the active experts' weights bounds HBM weight traffic by
@@ -553,26 +608,16 @@ def _moe_ragged(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
         group = jnp.searchsorted(uniq, group)  # slot among the gathered
         gs = jnp.bincount(group, length=M)
         gidx = jnp.minimum(uniq, E - 1)
-        ws = [jax.tree.map(lambda a: jnp.take(a, gidx, axis=0), w) for w in ws]
+        ws = jax.tree.map(lambda a: jnp.take(a, gidx, axis=0), ws)
     else:
         gs = jnp.bincount(e_flat, length=E)  # rows per expert (sums to M)
-    w_gate, w_up, w_down = ws
-    elsewhere = None
     if cfg.expert_share is not None:
         # Picks of experts held elsewhere sort last (`_held_route`). What
         # `ragged_dot` does with rows that lie in no group is not specified,
-        # so they ride in the last one and are zeroed below.
-        elsewhere = group >= gs.shape[0]
+        # so they ride in the last one; the combine leaves them out.
         group = jnp.minimum(group, gs.shape[0] - 1)
         gs = gs.at[-1].add(M - gs.sum())
-    gate = _act(cfg, _ragged_mm(xg, w_gate, gs, group))
-    up = _ragged_mm(xg, w_up, gs, group)
-    dn = _ragged_mm((gate * up).astype(xg.dtype), w_down, gs, group)  # [M, D]
-    if elsewhere is not None:
-        dn = jnp.where(elsewhere[:, None], 0, dn)
-    wf = jnp.take(weights.reshape(M), order)
-    y = jnp.zeros((N, D), jnp.float32).at[tok].add(dn.astype(jnp.float32) * wf[:, None])
-    return y.reshape(*lead, D).astype(x.dtype)
+    return lambda a, name: _ragged_mm(a, ws[name], gs, group)
 
 
 def _moe_capacity(cfg: ArchConfig, lp: Params, x: jnp.ndarray, block: int = 1024) -> jnp.ndarray:
@@ -654,28 +699,38 @@ def _lora_add(cfg: ArchConfig, lora, key: str, x: jnp.ndarray,
 
 
 def _mlp(cfg: ArchConfig, lp: Params, x: jnp.ndarray, ep: int = 1,
-         mesh=None, lora=None, picks=None) -> jnp.ndarray:
+         mesh=None, lora=None, picks=None, admit=None) -> jnp.ndarray:
     """SwiGLU MLP; dense or sparse-MoE (Mixtral/DeepSeek/OLMoE top-k routing).
 
     x: [..., D]. MoE is detected per-stack ("router" in lp) so DeepSeek's
     dense-prefix layers run the plain branch under the same body. MoE picks
     its implementation HERE and nowhere else, statically, from what the
-    trace sees (the expert leaf, the rows, the mesh):
-    - quantized experts at the rows the stacked Pallas kernel serves
-      (≤ QUANT_PALLAS_MAX_ROWS: decode blocks, verify chunks, short tails)
-      → all-experts `_moe_dense`: every expert's bytes are read whatever
-      the routing, the kernel reads its layer in place, and rows × E is
-      small. Also under ep > 1 (shards over "ep" as it is);
-    - quantized experts at wider rows (admission groups, long prompts)
-      → sort + ragged_dot on the quantized stack: FLOPs ∝ top_k and
-      temporaries ∝ rows × top_k, where all-experts builds rows × E;
+    trace sees (the expert leaf, the rows, the mesh, the entry point):
+    - quantized experts at few rows → all-experts `_moe_dense`: every
+      expert's bytes are read whatever the routing, the stacked Pallas kernel
+      reads its layer in place, and rows × E is small. "Few" is the stacked
+      kernel's row limit QUANT_PALLAS_MAX_ROWS for a decode block and a
+      verify chunk (B·(k+1) rows) whatever serves the rows above it, and
+      for an admission program too where only `ragged_dot` does; an
+      admission program (`admit`) whose wider rows the grouped Pallas kernel
+      serves (`grouped_engaged`) leaves all-experts at the measured
+      crossover, MOE_ALL_EXPERTS_MAX_ROWS. Also under ep > 1 (shards over
+      "ep" as it is);
+    - quantized experts at wider rows (admission groups, long prompts; a
+      decode block or verify chunk above 256 rows) → sort + grouped matmul
+      on the quantized stack (`_moe_ragged`: the grouped Pallas kernel, or
+      `ragged_dot` where it does not engage): FLOPs ∝ top_k and temporaries
+      ∝ rows × top_k, where all-experts builds rows × E;
     - plain experts, ep > 1 → GShard capacity dispatch (shards over "ep");
     - plain experts otherwise → the same sort + ragged_dot (at decode batch
       sizes only the ACTIVE experts' weights are gathered, which is where
-      top-8-of-256 models win — see _moe_ragged).
+      top-8-of-256 models win — see _ragged_mms).
     DeepSeek MoE layers add an always-on shared-expert MLP (HF
     DeepseekV3MoE.shared_experts). `picks`, a list, receives the router's
-    chosen expert ids [..., k] (the engine's routing counters).
+    chosen expert ids [..., k] (the engine's routing counters). `admit`, a
+    list, is given by the admission entry points (prefill, a cached tail, a
+    prefill chunk) and by no decode entry point; it receives the grouped
+    kernel's rows (`_moe_ragged`), nothing where that kernel did not run.
     """
     qk = cfg.quant_kernel
     if "router" not in lp:
@@ -705,11 +760,17 @@ def _mlp(cfg: ArchConfig, lp: Params, x: jnp.ndarray, ep: int = 1,
         if picks is not None:
             picks.append(route[1])
         rows = x.size // x.shape[-1]
+        w = lp["w_gate"]
+        grouped = quantized and grouped_engaged(
+            x, dict(w), qk, mesh, quant.layer_of(w))
+        few = (MOE_ALL_EXPERTS_MAX_ROWS if grouped and admit is not None
+               else QUANT_PALLAS_MAX_ROWS)
         with scope("mlp/experts"):
-            if quantized and (ep > 1 or rows <= QUANT_PALLAS_MAX_ROWS):
+            if quantized and (ep > 1 or rows <= few):
                 y = _moe_dense(cfg, lp, x, mesh=mesh, route=route)
             else:
-                y = _moe_ragged(cfg, lp, x, route=route)
+                y = _moe_ragged(cfg, lp, x, route=route, grouped=grouped,
+                                rows=admit)
     if "shared_gate" in lp:
         with scope("mlp/shared"):
             sg = _act(cfg, matmul(x, lp["shared_gate"], qk, mesh, "col"))
@@ -745,10 +806,10 @@ def _attn_gated(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
 
 
 def _mlp_out(cfg: ArchConfig, lp: Params, x: jnp.ndarray, ep: int = 1,
-             mesh=None, lora=None, picks=None) -> jnp.ndarray:
+             mesh=None, lora=None, picks=None, admit=None) -> jnp.ndarray:
     """MLP (scoped `mlp/...` where `_mlp` picks its form) + optional gemma-2
     post-feedforward sandwich norm (the enclosing `layer`'s, as every norm)."""
-    m = _mlp(cfg, lp, x, ep, mesh=mesh, lora=lora, picks=picks)
+    m = _mlp(cfg, lp, x, ep, mesh=mesh, lora=lora, picks=picks, admit=admit)
     if cfg.post_norms:
         m = rms_norm(m, lp["post_ffw_norm"], cfg.rms_eps)
     return m
@@ -994,7 +1055,7 @@ def _slid(cfg: ArchConfig, sliding, mask, dist):
 
 def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
                    mla_full: bool = False, mrope_ang=None, ep: int = 1,
-                   mesh=None, lora=None, picks=None):
+                   mesh=None, lora=None, picks=None, admit=None):
     """THE decoder layer, the body of every entry point's layer scan: input
     norm → q/k/v (GQA: `_attn_proj_qkv` + rope; MLA: the full-rank or the
     absorbed projections, which rotate inside) → `attend` → output projection
@@ -1031,7 +1092,9 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
 
     (1) `prefill`, `encode`, `sequence_logprob`; only `prefill` passes LoRA
     and m-rope on. "GQA" = ignored under MLA (sp: refused); softcap and the
-    per-layer sliding window reach every GQA call and no MLA call."""
+    per-layer sliding window reach every GQA call and no MLA call. (1),
+    `prefill_tail` and `prefill_chunk_paged` are the admission entry points:
+    they pass `admit` on to `_mlp`, the decode entry points never do."""
     lp, li, *cache = xs
     if lora is not None:
         *cache, la = cache
@@ -1077,7 +1140,8 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
         emit = (k, v)
     h = h + _attn_out(cfg, lp, attn, mesh, lora=lora)
     x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-    return h + _mlp_out(cfg, lp, x, ep, mesh, lora=lora, picks=picks), emit
+    return h + _mlp_out(cfg, lp, x, ep, mesh, lora=lora, picks=picks,
+                        admit=admit), emit
 
 
 # --------------------------------------------------------------------------- #
@@ -1305,29 +1369,46 @@ def _expert_counts(cfg: ArchConfig, picks) -> jnp.ndarray:
     return jax.nn.one_hot(picks[0], E, dtype=jnp.int32).sum(lead)
 
 
+def _walk_rows(admit) -> jnp.ndarray:
+    """[2] int32: what one MLP's grouped kernel walked (`_moe_ragged`: the
+    sorted rows it was compiled for, those in a held group); zeros where the
+    kernel did not run and `admit` stayed empty."""
+    return admit[0] if admit else jnp.zeros((2,), jnp.int32)
+
+
 def _hybrid_layer_fns(cfg: ArchConfig, kda_mix, *, pos, inv, attend,
-                      mla_full: bool, ep: int, mesh, count: bool):
+                      mla_full: bool, ep: int, mesh, count: bool,
+                      admit: bool = False):
     """(kda_fn, cache_fn, cache_zero) for `_scan_hybrid` from an entry point's
     `kda_mix(lp, x, rec, j) -> (y, rec)` and its cache layers' `attend`. The
     cache layer, MLA or GQA, is `_decoder_layer` itself. With `count` each
-    layer's out ends with its rows per held expert."""
+    layer's out ends with its rows per held expert or, from an admission
+    entry point (`admit`), with its grouped kernel's rows (`_walk_rows`)."""
 
-    def mlp(h, lp, picks):
-        x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-        return h + _mlp_out(cfg, lp, x, ep, mesh, picks=picks)
+    def receiver():  # what `_mlp` fills, as `_mlp_out` takes it
+        if admit:
+            return {"admit": []}
+        return {"picks": [] if count else None}
+
+    def reading(kw):
+        if admit:
+            return _walk_rows(kw["admit"])
+        return _expert_counts(cfg, kw["picks"])
 
     def kda_fn(h, rec, lp, j):
-        picks = [] if count else None
+        kw = receiver()
         y, rec = kda_mix(lp, rms_norm(h, lp["attn_norm"], cfg.rms_eps), rec, j)
-        h = mlp(h + y, lp, picks)
-        return h, rec, (_expert_counts(cfg, picks) if count else None)
+        h = h + y
+        x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
+        h = h + _mlp_out(cfg, lp, x, ep, mesh, **kw)
+        return h, rec, (reading(kw) if count else None)
 
     def cache_fn(h, lp, m, ex):
-        picks = [] if count else None
+        kw = receiver()
         h, rows = _decoder_layer(
             cfg, h, (lp, m) + tuple(ex), pos=pos, inv=inv, attend=attend,
-            mla_full=mla_full, ep=ep, mesh=mesh, picks=picks)
-        return h, rows + ((_expert_counts(cfg, picks),) if count else ())
+            mla_full=mla_full, ep=ep, mesh=mesh, **kw)
+        return h, rows + ((reading(kw),) if count else ())
 
     @scope("attention/cache_write")
     def cache_zero(h):
@@ -1335,7 +1416,8 @@ def _hybrid_layer_fns(cfg: ArchConfig, kda_mix, *, pos, inv, attend,
         rows = jnp.zeros(lead + (cfg.cache_kv_heads, cfg.cache_k_dim), h.dtype)
         out = (rows, rows[..., :cfg.cache_v_dim])
         if count:
-            out = out + (jnp.zeros((max(cfg.experts_here, 1),), jnp.int32),)
+            n = 2 if admit else max(cfg.experts_here, 1)
+            out = out + (jnp.zeros((n,), jnp.int32),)
         return out
 
     return kda_fn, cache_fn, cache_zero
@@ -1361,10 +1443,14 @@ def _forward_hidden(
     # [L,NA,R,out]}}, ids [B]) — per-row runtime LoRA (ISSUE 10)
     recurrent=None,  # hybrid models: (state, conv, slots [B]) — each prompt's
     # final recurrent state is written to its slot's rows, layer by layer
+    expert_rows: bool = False,  # MoE: also return what the grouped expert
+    # kernel walked, summed over the layers ([2] int32, `_walk_rows`)
 ):
     """Shared full-sequence forward. Returns (h [B,S,D] after final norm,
-    length_mask [B,S], (ks, vs) or None; with `recurrent`, the (state, conv)
-    written follows). Single source of truth for the layer
+    length_mask [B,S], (ks, vs) or None; with `expert_rows`, the grouped
+    kernel's rows follow; with `recurrent`, the (state, conv) written comes
+    last). An admission entry point: its MLPs are given `admit` (`_mlp`).
+    Single source of truth for the layer
     body used by both `prefill` and `encode`.
 
     With a mesh whose "sp" axis is > 1, attention runs as ring attention
@@ -1425,11 +1511,13 @@ def _forward_hidden(
                 mesh=mesh, **_mask_opts(cfg, sliding))
 
     def body(h, xs):  # lora: la, this layer's adapter factors, rides last
+        admit = []
         h, kv = _decoder_layer(
             cfg, h, xs, pos=positions, inv=(inv_freq, inv_local),
             attend=attend, mla_full=True, mrope_ang=mrope_ang, ep=ep,
-            mesh=mesh, lora=lora)
-        return h, (kv if collect_kv else None)
+            mesh=mesh, lora=lora, admit=admit)
+        kv = kv if collect_kv else None
+        return h, ((kv, _walk_rows(admit)) if expert_rows else kv)
 
     rec = None
     if cfg.is_hybrid:
@@ -1445,18 +1533,28 @@ def _forward_hidden(
         def kda_mix(lp, x, rec, j):
             return _kda_prefill_mix(cfg, lp, x, lengths, rec, j, slots)
 
-        h, rec, _, kv = _scan_hybrid(
+        h, rec, walked, kv = _scan_hybrid(
             cfg, params, h, rec, *_hybrid_layer_fns(
                 cfg, kda_mix, pos=positions, inv=(inv_freq, inv_local),
-                attend=attend, mla_full=True, ep=ep, mesh=mesh, count=False))
-        kv = kv if collect_kv else None
+                attend=attend, mla_full=True, ep=ep, mesh=mesh,
+                count=expert_rows, admit=True))
+        if expert_rows:  # the KDA layers' MLPs, then the cache layers'
+            *kv, walked_m = kv
+            walked = (walked, walked_m)
+        kv = tuple(kv) if collect_kv else None
     else:
         extras = () if lora is None else (lora[0],)
         h, kv = _scan_layers(cfg, params, h, body, extras)
+        if expert_rows:
+            kv, walked = kv
     h = _final_norm(cfg, params, h)
+    out = (h, length_mask, kv)
+    if expert_rows:  # [layers, 2] a stack: their sum over the layers
+        with scope("mlp/experts"):
+            out = out + (sum(a.sum(axis=0) for a in jax.tree.leaves(walked)),)
     if recurrent is not None:
-        return h, length_mask, kv, rec
-    return h, length_mask, kv
+        out = out + (rec,)
+    return out
 
 
 def prefill(
@@ -1471,12 +1569,15 @@ def prefill(
     lora=None,  # (stacked adapter factors, ids [B]) — runtime LoRA
     recurrent=None,  # hybrid models: (state, conv, slots [B]), see
     # `_forward_hidden`; the written (state, conv) is then returned last
+    expert_rows: bool = False,  # MoE: what the grouped expert kernel walked
+    # over all layers ([2] int32) follows v, see `_forward_hidden`
 ):
     """Prompt processing. Returns (last_logits [B, V] f32, k [L,B,S,K,Hd], v),
     L the layers that write cache rows (cfg.cache_layers)."""
     h, _, (ks, vs), *rec = _forward_hidden(
         cfg, params, tokens, lengths, collect_kv=True, mesh=mesh, inject=inject,
         ep=ep, mrope=mrope, lora=lora, recurrent=recurrent,
+        expert_rows=expert_rows,
     )
     logits = _unembed(cfg, params, _last_row(h, lengths), mesh)
     return (logits, ks, vs, *rec)
@@ -1871,7 +1972,7 @@ def prefill_tail(
 
     layer = functools.partial(
         _decoder_layer, cfg, pos=positions, inv=_rope_inv(cfg), attend=attend,
-        ep=ep, mesh=mesh)
+        ep=ep, mesh=mesh, admit=[])
     h, (ks, vs) = _scan_layers(
         cfg, params, h, layer, (prefix_k, prefix_v)
     )
@@ -2113,7 +2214,7 @@ def prefill_chunk_paged(
 
     layer = functools.partial(
         _decoder_layer, cfg, pos=positions, inv=_rope_inv(cfg), attend=attend,
-        ep=ep, mesh=mesh)
+        ep=ep, mesh=mesh, admit=[])
     h, (new_k, new_v) = _scan_layers(cfg, params, h, layer, _paged_pool(pool))
     pool = write_chunk_to_pool(pool, table, new_k, new_v, positions,
                                kv_scale=kv_scale)

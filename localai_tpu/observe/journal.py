@@ -113,6 +113,11 @@ BASE_EVENTS = (
     "admit_rows",    # one admission program was dispatched (a=rows it was
     #                  compiled for: group size x bucket, or a chunk's or a
     #                  cached tail's own rows; b=prompt tokens in them)
+    "moe_admit_rows",  # an admission program under an expert share came
+    #                  back whose expert matmuls took the grouped kernel
+    #                  (a=(row, pick) pairs it was compiled for: rows x
+    #                  top-k x MoE layers, b=of those, the rows in a held
+    #                  expert's group, the ones the kernel visits)
 )
 
 # One journal event type per fault-injection site (faults.SITES), checked

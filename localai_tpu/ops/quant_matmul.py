@@ -32,6 +32,22 @@ costs 0.22 us before it moves a byte; 512 x 512 column strips copied at
 65-72% of the HBM's rate and the whole kernel ran at 53-59% (33-36% where an
 axis of 2304 or 1280 forced 128 KB blocks); whole-row blocks run at 85-90%.
 
+The grouped kernel (ISSUE 38, `_gmm_call`): admission's rows are too many
+for that layout and each needs top-k experts of E, so llama._moe_ragged
+sorts the (row, pick) pairs by expert and `int8_grouped_matmul`
+(`int4_grouped_matmul`) walks the sorted rows' groups over the same stack,
+still stacked over layers: the grid is (out-block, visit, k-chunk), a visit
+one (row tile, expert) pair that shares a row, in sorted order
+(`group_visits`, scalar-prefetched with the group offsets). Consecutive
+visits of one expert find its weight block resident, so a visited expert's
+bytes cross HBM once (once a visit where the matrix takes several k-chunks);
+a tile that straddles groups is visited once a group and writes only that
+group's rows; a tile wholly in no group (under an expert share the picks
+held elsewhere sort last) and an expert no row chose are never visited. The
+step's body, block rule and arithmetic are the stacked kernel's
+(`_accumulate`, `_blocks` at a row tile of GROUP_ROWS): no dequantized copy,
+the per-channel scale on the float32 accumulator at the final write.
+
 Forms served (matching models/quant.py representations):
 - flat int8      {"q": [in, out] i8,      "s": [1, out] f32}
 - grouped int8   {"gq": [G, gs, out] i8,  "gs": [G, 1, out] f32}
@@ -39,7 +55,8 @@ Forms served (matching models/quant.py representations):
   (value = nibble·s − z; the −z side is a rank-1 correction: −Σᵢx·z per
   group, one extra tiny MXU dot on the per-group x sums)
 - MoE variants of all three with a leading expert axis, for the two
-  _moe_dense einsum shapes (shared-x and per-expert-x)
+  _moe_dense einsum shapes (shared-x and per-expert-x), and for
+  expert-sorted rows (the grouped kernel)
 - unembed        {"q": [V, D] i8, "s": [V, 1] f32} used transposed (h @ qᵀ·s)
 
 Who slices what (ISSUE 25; the convention is ops/stacked.py). A layer's
@@ -70,7 +87,10 @@ boundary GSPMD would have placed at the o/down projection). A stacked
 weight keeps its layer axis whole on every shard; the index is replicated.
 
 Dispatch: models/quant.matmul / unembed_matmul and models/llama._moe_mm call
-the dispatch_* helpers here; a None return means "not engaged" and the
+the dispatch_* helpers here (llama._mlp asks `grouped_engaged` once, where
+it picks the MoE form; llama._moe_ragged then calls `group_visits` once and
+`grouped_moe_mm` for its three projections); a None return
+means "not engaged" and the
 caller falls through to its XLA form, which stays the numeric oracle
 (tests/test_quant.py runs these kernels in interpret mode on CPU against
 it, exactly like ops/paged_flash vs the XLA page walk).
@@ -86,7 +106,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from localai_tpu.ops.stacked import note_blocks, note_site
+from localai_tpu.ops.stacked import note_blocks, note_grouped, note_site
 
 # The ONLY function here allowed to issue cross-chip collectives: the
 # row-parallel shard_map closure psums its partial products over "tp" —
@@ -101,6 +121,30 @@ COLLECTIVE_BOUNDARY = ("_sharded_quant_matmul",)
 # verify chunks (B·(k+1)) and short cached-admit tails all sit far under it.
 QUANT_PALLAS_MAX_ROWS = 256
 
+# The MoE rule's crossover (llama._mlp): the most rows at which an ADMISSION
+# program (prefill, a cached tail, a prefill chunk) runs quantized experts
+# all-experts on the stacked kernel where the grouped kernel below can serve
+# the rows above it. Measured on the v5e (my chip runs 1-2,
+# PR 38; the three MoE cells' stacks, uniform routing, the whole expert path
+# a layer): at 64 rows all-experts is 7-14% faster on two stacks and 3%
+# slower on the third; at 128 rows it costs 1.2-1.3 x its 64-row time
+# (arithmetic starts to bind) and sort + grouped kernel is 9-12% faster on
+# two stacks and 3% slower on the third; at 256 rows 1.5-1.8 x faster on all
+# three. The decode entry points (a block of any slot count, a verify chunk
+# of B·(k+1) rows) do not take this bound: they stay all-experts up to
+# QUANT_PALLAS_MAX_ROWS as before (no cell runs them wider, and a kernel
+# that skips idle experts would gain there from the synthetic routing's
+# collapse: ROADMAP S4).
+MOE_ALL_EXPERTS_MAX_ROWS = 64
+
+# Row tile of the grouped kernel. A visit converts its whole weight block
+# whatever the rows it multiplies (1.3 us of a 2 MB block) and its dot costs
+# by the tile's rows, so a sorted run of M rows over G groups costs about
+# (M / tile + G) x (1.3 us + 0.02 us x tile): 64 is within 5% of the best
+# tile from 1,024 to 8,192 sorted rows on all three cells' stacks (my chip
+# run 1, PR 38: 64 / 128 / 256 / 512 rows a tile read 322 / 326 / 509 / 871
+# us a 64 x 2048 x 1024 stack at 4,096 rows).
+GROUP_ROWS = 64
 
 def use_pallas_quant(impl: str = "auto") -> bool:
     """Resolve the quantized-matmul kernel choice.
@@ -302,19 +346,16 @@ def _span(i, size: int, whole: bool):
     return slice(None) if whole else pl.ds(pl.multiple_of(i * size, size), size)
 
 
-def _qmm_kernel(_layer_ref, x_ref, w_ref, s_ref, *rest, gs: int, sk: int,
+def _accumulate(k, x_ref, w_ref, s_ref, z_ref, acc_ref, *, gs: int, sk: int,
                 so: int, packed: bool):
-    """One (expert, out-block, k-chunk) grid step of the dequant-matmul.
-
-    `_layer_ref` is the scalar-prefetched layer index: only the BlockSpec
-    index maps read it (they pick this layer's blocks out of the stack).
+    """acc += x @ dequant(w) for k-chunk `k` of one weight block (acc zeroed
+    at k == 0): the body the dequant-matmul kernels share.
 
     Blocks: x (1, N, kc | Kin) float, w (1, kc[/2], bo) i8/u8, s (1, gc|1, bo)
-    f32, optional z (1, gc, bo) f32, out (1, N, bo), acc scratch (N, bo)
-    f32. gs == 0 means the flat per-channel form (scale applied once at the
-    final write); packed means two nibbles per weight byte along the
-    in-group axis (low nibble = first gs/2 elements — models/quant.py),
-    shipped bitcast to int8.
+    f32, optional z (1, gc, bo) f32, acc scratch (N, bo) f32. gs == 0 means
+    the flat per-channel form (the caller scales once at its final write);
+    packed means two nibbles per weight byte along the in-group axis (low
+    nibble = first gs/2 elements — models/quant.py), shipped bitcast to int8.
 
     The DMA is the whole block (`_blocks`); the arithmetic walks it in
     sk x so sub-tiles with rolled loops (convert + dot a sub-tile,
@@ -323,11 +364,7 @@ def _qmm_kernel(_layer_ref, x_ref, w_ref, s_ref, *rest, gs: int, sk: int,
     """
     import jax.experimental.pallas as pl
 
-    z_ref = rest[0] if len(rest) == 3 else None
-    o_ref, acc_ref = rest[-2], rest[-1]
-    k = pl.program_id(2)
-    nk = pl.num_programs(2)
-    bo = o_ref.shape[-1]
+    bo = acc_ref.shape[-1]
     kc = w_ref.shape[1] * (2 if packed else 1)
     nks, nos = kc // sk, bo // so
     # x is resident whole (its block spans every k-chunk) or a chunk a step
@@ -402,12 +439,73 @@ def _qmm_kernel(_layer_ref, x_ref, w_ref, s_ref, *rest, gs: int, sk: int,
             def _rows_of(i):
                 sub_tile(i, j)
 
+
+def _result(acc_ref, s_ref, gs: int):
+    """The accumulator as a step's result: the flat form's per-channel scale
+    is applied here, once ([1, bo] broadcasts); the grouped forms scaled
+    inside the step."""
+    res = acc_ref[...]
+    return res if gs else res * s_ref[0].astype(jnp.float32)
+
+
+def _qmm_kernel(_layer_ref, x_ref, w_ref, s_ref, *rest, gs: int, sk: int,
+                so: int, packed: bool):
+    """One (expert, out-block, k-chunk) grid step of the dequant-matmul
+    (`_accumulate`; out (1, N, bo) written at the last k-chunk).
+
+    `_layer_ref` is the scalar-prefetched layer index: only the BlockSpec
+    index maps read it (they pick this layer's blocks out of the stack).
+    """
+    import jax.experimental.pallas as pl
+
+    z_ref = rest[0] if len(rest) == 3 else None
+    o_ref, acc_ref = rest[-2], rest[-1]
+    k = pl.program_id(2)
+    nk = pl.num_programs(2)
+    _accumulate(k, x_ref, w_ref, s_ref, z_ref, acc_ref, gs=gs, sk=sk, so=so,
+                packed=packed)
+
     @pl.when(k == nk - 1)
     def _emit():
-        res = acc_ref[...]
-        if not gs:
-            res = res * s_ref[0].astype(jnp.float32)  # [1, bo] broadcasts
-        o_ref[0] = res.astype(o_ref.dtype)
+        o_ref[0] = _result(acc_ref, s_ref, gs).astype(o_ref.dtype)
+
+
+def _gmm_kernel(_layer_ref, nvis_ref, gid_ref, tid_ref, off_ref, x_ref, w_ref,
+                s_ref, *rest, gs: int, sk: int, so: int, packed: bool):
+    """One (out-block, visit, k-chunk) grid step of the grouped
+    dequant-matmul: visit v multiplies row tile `tid[v]` of the
+    expert-sorted rows by expert `gid[v]`'s block (`_accumulate`, as the
+    stacked kernel) and at the last k-chunk writes the rows of the tile
+    that lie in that expert's group, [off[g], off[g + 1]); the tile's other
+    rows keep what an earlier visit of the same out block wrote (the block
+    stays in VMEM while consecutive visits name it). Visits from `nvis` on
+    are padding of the static grid: they name the last real visit's blocks,
+    so they move no byte, and do nothing.
+
+    Blocks: x (1, tm, kc | Kin), out (1, tm, bo), acc (tm, bo); w, s, z as
+    `_accumulate` takes them. Scalar prefetch: the layer index (index maps
+    only), nvis [1], gid / tid [V], off [E + 1].
+    """
+    import jax.experimental.pallas as pl
+
+    z_ref = rest[0] if len(rest) == 3 else None
+    o_ref, acc_ref = rest[-2], rest[-1]
+    v, k = pl.program_id(1), pl.program_id(2)
+    nk = pl.num_programs(2)
+
+    @pl.when(v < nvis_ref[0])
+    def _visit():
+        _accumulate(k, x_ref, w_ref, s_ref, z_ref, acc_ref, gs=gs, sk=sk,
+                    so=so, packed=packed)
+
+        @pl.when(k == nk - 1)
+        def _emit():
+            res = _result(acc_ref, s_ref, gs)
+            g = gid_ref[v]
+            row = tid_ref[v] * res.shape[0] + jax.lax.broadcasted_iota(
+                jnp.int32, res.shape, 0)
+            mine = (row >= off_ref[g]) & (row < off_ref[g + 1])
+            o_ref[0] = jnp.where(mine, res.astype(o_ref.dtype), o_ref[0])
 
 
 def _unembed_kernel(h_ref, w_ref, s_ref, o_ref, acc_ref, *, sv: int):
@@ -453,6 +551,13 @@ def _unembed_kernel(h_ref, w_ref, s_ref, o_ref, acc_ref, *, sv: int):
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _layer_operand(layer):
+    """The scalar-prefetch operand [1] int32 of a layer index (0 without)."""
+    if layer is None:
+        return jnp.zeros((1,), jnp.int32)
+    return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 def _qmm_call(x3, wq, s3, z3, *, gs: int, packed: bool, out_dtype,
@@ -502,8 +607,7 @@ def _qmm_call(x3, wq, s3, z3, *, gs: int, packed: bool, out_dtype,
     if z3 is not None:
         in_specs.append(pl.BlockSpec((1, gc, bo), si))
         args.append(z3)
-    li = jnp.zeros((1,), jnp.int32) if layer is None else (
-        jnp.asarray(layer, jnp.int32).reshape(1))
+    li = _layer_operand(layer)
     kernel = functools.partial(
         _qmm_kernel, gs=gs, sk=blk.sk, so=blk.so, packed=packed)
     return pl.pallas_call(
@@ -519,6 +623,120 @@ def _qmm_call(x3, wq, s3, z3, *, gs: int, packed: bool, out_dtype,
         interpret=_interpret(),
         name="int4_matmul" if packed else "int8_matmul",
     )(li, *args)
+
+
+class Visits(NamedTuple):
+    """The grouped kernel's walk over expert-sorted rows (`group_visits`):
+    int32 nvis [1], gid / tid [V], off [E + 1], its scalar-prefetch
+    operands after the layer index."""
+
+    nvis: jax.Array
+    gid: jax.Array
+    tid: jax.Array
+    off: jax.Array
+
+
+def group_visits(sizes, m: int) -> Visits:
+    """The grouped kernel's walk over `m` expert-sorted rows: `sizes` [E]
+    rows a group, in sorted order from row 0 (rows past their sum, off[-1],
+    lie in no group); tiles of `_row_tile(m)` rows. One visit a (row tile,
+    group) pair that shares a row, in sorted order: consecutive visits of
+    one group find its weight block resident, a tile that straddles groups
+    is visited once a group, a tile wholly in no group and a group of no
+    rows not at all. V = tiles + E - 1, the most visits there can be;
+    entries from nvis on repeat the last real visit. One walk serves every
+    matmul over the same sorted rows (llama._moe_ragged's three)."""
+    e, tm = sizes.shape[0], _row_tile(m)
+    sizes = sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    first = (ends - sizes) // tm  # a group's first tile
+    count = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    vend = jnp.cumsum(count)
+    nvis = vend[-1]
+    v = jnp.minimum(jnp.arange(-(-m // tm) + e - 1, dtype=jnp.int32),
+                    jnp.maximum(nvis - 1, 0))
+    gid = jnp.minimum(jnp.searchsorted(vend, v, side="right"), e - 1)
+    tid = first[gid] + v - (vend - count)[gid]
+    off = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return Visits(nvis.reshape(1), gid.astype(jnp.int32),
+                  tid.astype(jnp.int32), off)
+
+
+def _row_tile(m: int) -> int:
+    """Rows a visit of the grouped kernel multiplies, of `m` sorted rows."""
+    return min(GROUP_ROWS, m)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "tm", "blk", "gs", "packed", "experts", "interpret"))
+def _gmm_call(li, visits: Visits, xg, wq, s3, z3, *, tm: int, blk: Blocks,
+              gs: int, packed: bool, experts: int, interpret: bool):
+    """Grid launch over (out-tiles, visits, k-chunks) of the grouped
+    dequant-matmul: expert-sorted rows xg [M, Kin] (any M: x is tiled
+    `tm` = `_row_tile(M)` at a time, never resident whole) against the stack wq
+    [B, Kin(/2), out], B = E or L·E with the layer index `li` [1]
+    scalar-prefetched as in `_qmm_call` (block `layer·E + e`: no slice of
+    the stack, no dequantized copy). The walk is `group_visits`' over M
+    rows; `blk` the weight block `_blocks` gives a row tile. Returns
+    [M, out] in xg's dtype; rows in no group are NOT written (uninitialised
+    memory: the caller zeroes them).
+
+    Jitted on its own inside the program that calls it, so that calls of
+    one shape share one trace of the kernel and one Mosaic lowering: an
+    admission program's gate and up projections, and in a hybrid model
+    those of both layer kinds (a call costs the warm-up some 0.15 s of
+    Python a program otherwise, `setup_s`: PERF.md §6 PR 38). Everything
+    the trace depends on besides the operands is a static argument."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    E = experts
+    out = wq.shape[-1]
+    M, kin = xg.shape
+    if packed:  # same bytes; the kernel masks the nibbles out of int32
+        wq = jax.lax.bitcast_convert_type(wq, jnp.int8)
+    kc, bo, gc = blk.kc, blk.bo, blk.gc
+    kc_w = kc // 2 if packed else kc
+    nk = kin // kc
+
+    def kq(v, k, nv):  # a padding visit stays on the last block fetched
+        return jnp.where(v < nv[0], k, nk - 1)
+
+    def xi(j, v, k, li, nv, gid, tid, off):
+        return (0, tid[v], kq(v, k, nv) if blk.xk == kc else 0)
+
+    def wi(j, v, k, li, nv, gid, tid, off):
+        return (li[0] * E + gid[v], kq(v, k, nv), j)
+
+    def si(j, v, k, li, nv, gid, tid, off):
+        return (li[0] * E + gid[v], kq(v, k, nv) if gs else 0, j)
+
+    in_specs = [
+        pl.BlockSpec((1, tm, blk.xk), xi),
+        pl.BlockSpec((1, kc_w, bo), wi),
+        pl.BlockSpec((1, gc, bo), si),
+    ]
+    args = [xg[None], wq, s3]
+    if z3 is not None:
+        in_specs.append(pl.BlockSpec((1, gc, bo), si))
+        args.append(z3)
+    kernel = functools.partial(
+        _gmm_kernel, gs=gs, sk=blk.sk, so=blk.so, packed=packed)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(out // bo, visits.gid.shape[0], nk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (1, tm, bo),
+                lambda j, v, k, li, nv, gid, tid, off: (0, tid[v], j)),
+            scratch_shapes=[pltpu.VMEM((tm, bo), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((1, M, out), xg.dtype),
+        interpret=interpret,
+        name="int4_grouped_matmul" if packed else "int8_grouped_matmul",
+    )(li, *visits, *args)[0]
 
 
 def _operands(w: dict, lead: int):
@@ -749,6 +967,43 @@ def _dispatch_moe_mm(x, w, sub, impl, mesh, layer):
             return None
         return _sharded_quant_matmul(x, w, mesh, part, moe_sub=sub, layer=layer)
     return _plain_moe_mm(x, w, sub, layer=layer)
+
+
+def grouped_engaged(x, w: dict, impl: str = "auto", mesh=None,
+                    layer=None) -> bool:
+    """Does the grouped kernel take expert-sorted rows like x [.., in]
+    against the expert stack w ([E, ...] leaves, [L, E, ...] with `layer`)?
+    Any quantized form, any row count; not under a tp or ep mesh (Pallas is
+    opaque to GSPMD and the sort is over the whole batch), off the TPU only
+    when asked for by name (interpret mode)."""
+    leaf = _leaf(w)
+    if leaf is None or leaf.ndim != (3 if "q" in w else 4) + (layer is not None):
+        return False
+    if mesh is not None and (_tp_degree(mesh) > 1
+                             or int(mesh.shape.get("ep", 1)) > 1):
+        return False
+    return (use_pallas_quant(impl) and jnp.issubdtype(x.dtype, jnp.floating)
+            and _rows(x) > 0)
+
+
+def grouped_moe_mm(xg, w: dict, visits: Visits, layer=None):
+    """xg [M, in] expert-sorted rows times their experts' matrices, on the
+    quantized stack as it is stored (`grouped_engaged` said yes): `visits`
+    the walk of the M rows' groups (`group_visits`). Returns [M, out]; rows
+    in no group (from visits.off[-1] on) are not written."""
+    axes = 1 if layer is None else 2  # leaves [E, ...] or [L, E, ...]
+    wq, s3, z3, gs, packed = _operands(w, axes)
+    tm = _row_tile(xg.shape[0])
+    blk = _blocks(
+        tm, xg.shape[1], wq.shape[-1], gs=gs, packed=packed,
+        zeros=z3 is not None, x_bytes=xg.dtype.itemsize,
+        out_bytes=xg.dtype.itemsize)
+    note_grouped()
+    note_blocks(wholerow=blk.bo == wq.shape[-1])
+    return _gmm_call(
+        _layer_operand(layer), visits, xg, wq, s3, z3, tm=tm, blk=blk, gs=gs,
+        packed=packed, experts=_leaf(w).shape[axes - 1],
+        interpret=_interpret())
 
 
 def dispatch_unembed(h, w: dict, impl: str = "auto", mesh=None):

@@ -112,6 +112,9 @@ def stacks_of(a, b, scope: str):
 # matmuls keep the short names they were first counted under.
 _KEYS = {
     "quant_matmul": ("stacked", "sliced"),
+    # an expert matmul over expert-sorted rows that took the grouped kernel
+    # on the stack (`note_grouped`); it counts under neither of the above
+    "quant_matmul_grouped": ("grouped",),
     # not a stack's fate either: the weight block the rule gave a Pallas
     # dequant-matmul call (`note_blocks`)
     "quant_matmul_blocks": ("wholerow", "narrowed"),
@@ -128,7 +131,9 @@ class SiteCounts:
     by what the site handed on: "stacked" (the Pallas kernel took the whole
     stack and the layer index) or "sliced" (the layer was sliced out first,
     for the XLA form or an unstacked kernel call). Quantized layer matmuls
-    count under `stacked` / `sliced`, paged attention under
+    count under `stacked` / `sliced`, or, an expert matmul over
+    expert-sorted rows that took the grouped kernel on the stack, under
+    `grouped` (`note_grouped`); paged attention under
     `paged_attention_stacked` / `paged_attention_sliced`; beside them the
     arithmetic of each Pallas paged-attention call, `paged_attention_native`
     / `paged_attention_f32` (`note_arith`), and the weight block of each
@@ -169,6 +174,14 @@ def note_site(stacked: bool, kernel: str = "quant_matmul") -> None:
     tally = _TALLY.get()
     if tally is not None:
         tally[_KEYS[kernel][0 if stacked else 1]] += 1
+
+
+def note_grouped() -> None:
+    """Count one expert matmul of the program being traced that took the
+    grouped Pallas kernel (ops/quant_matmul `grouped_moe_mm`): sorted rows
+    against the quantized stack in place, no slice and no dequantized copy.
+    The XLA `ragged_dot` form counts as `sliced` (llama._expert_stack)."""
+    note_site(True, kernel="quant_matmul_grouped")
 
 
 def note_blocks(wholerow: bool) -> None:

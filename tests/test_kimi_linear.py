@@ -104,6 +104,17 @@ def test_engine_agrees_with_the_plain_reference(served):
     picks, landed = sum(e["a"] for e in here), sum(e["b"] for e in here)
     assert picks == m["moe_picks"] and landed == m["moe_picks_here"]
     assert 0.1 < landed / picks < 0.45  # a quarter of the experts held
+    # An admission program's account is its grouped expert kernel's, which
+    # does not run off the TPU: every program came back with a walk of no
+    # rows and none was entered. One that did run (as the drain hands it on):
+    assert not [e for e in ev if e["event"] == "moe_admit_rows"]
+    assert m["moe_admit_rows"] == 0 == m["moe_admit_rows_held"]
+    eng._count_admit_routing(np.asarray([6 * 128 * 8, 1600], np.int32))
+    (adm,) = [e for e in eng.journal.snapshot()
+              if e["event"] == "moe_admit_rows"]
+    assert (adm["a"], adm["b"]) == (6144.0, 1600.0)
+    m2 = eng.metrics()
+    assert (m2["moe_admit_rows"], m2["moe_admit_rows_held"]) == (6144, 1600)
     assert sum(e["event"] == "prefix_reuse_off" for e in ev) == 1
     # moe_experts counts the held experts: 6 MoE layers x 4 of 16
     assert all(e["a"] % (6 * 4) == 0 for e in ev if e["event"] == "moe_experts")
@@ -251,17 +262,48 @@ def test_the_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(prog, whole, atol=2e-5)
 
 
-def test_wide_rows_sort_the_held_picks_and_match_all_experts(monkeypatch):
-    """Above the row limit the quantized share runs sort + ragged_dot with
-    the picks held elsewhere in no group; same sum as the all-experts form."""
+@pytest.mark.parametrize("kernel", ["auto", "pallas"])
+def test_wide_rows_sort_the_held_picks_and_match_all_experts(monkeypatch, kernel):
+    """Above the rule's row bound the quantized share runs the sort with the
+    picks held elsewhere in no group: `ragged_dot` lets them ride in the
+    last group and zeroes them ("auto" off the TPU); the grouped kernel
+    ("pallas": interpret mode here) never visits their tiles, and the rows
+    it did not write are zeroed before the combine. Same sum as the
+    all-experts form either way."""
+    from localai_tpu.ops import quant_matmul as QM
+
+    cfg = dataclasses.replace(CFG, quant_kernel=kernel)
     params = _seeded(quantize="int8")
     lp = {k: jax.tree.map(lambda a: a[1], v)
           for k, v in params["layers"].items()}
-    x = jax.random.normal(jax.random.key(4), (3, 16, CFG.hidden_size),
+    x = jax.random.normal(jax.random.key(4), (3, 16, cfg.hidden_size),
                           jnp.bfloat16)
-    dense = L._mlp(CFG, lp, x)
+    dense = L._mlp(cfg, lp, x)
     monkeypatch.setattr(L, "QUANT_PALLAS_MAX_ROWS", 8)
-    ragged = L._mlp(CFG, lp, x)
+    monkeypatch.setattr(L, "MOE_ALL_EXPERTS_MAX_ROWS", 8)
+    monkeypatch.setattr(QM, "GROUP_ROWS", 16)  # 384 sorted rows: 24 tiles
+    visited = []
+    real = QM.group_visits
+
+    def spy(sizes, m):
+        out = real(sizes, m)
+        visited.append((int(out.nvis[0]), int(sizes.sum()), m))
+        return out
+
+    monkeypatch.setattr(L, "group_visits", spy)
+    admit = []
+    ragged = L._mlp(cfg, lp, x, admit=admit)
+    if kernel == "pallas":
+        # most sorted rows are picks held elsewhere, and their tiles are not
+        # visited: at most a visit a held tile and one more a held expert;
+        # one walk serves the three projections
+        assert len(visited) == 1
+        nvis, held, m = visited[0]
+        assert admit[0].tolist() == [m, held]
+        assert m == 3 * 16 * cfg.num_experts_per_token and 0 < held < m // 2
+        assert nvis <= -(-held // 16) + cfg.experts_here
+    else:
+        assert visited == [] and admit == []
     assert np.isfinite(np.asarray(ragged, np.float32)).all()
     np.testing.assert_allclose(np.asarray(ragged, np.float32),
                                np.asarray(dense, np.float32), atol=2e-2)

@@ -150,12 +150,20 @@ QUANTIZERS = {
 }
 
 
+@pytest.mark.parametrize("kernel", ["auto", "pallas"])
 @pytest.mark.parametrize("form", sorted(QUANTIZERS))
-def test_wide_rows_run_top_k_and_match_all_experts(form, monkeypatch):
-    """Above the row limit `_mlp` takes `_moe_ragged` on the quantized stack
-    (still stacked over layers: it slices its own layer) and gives what
-    `_moe_dense` gives on the same weights, to float32 reduction order."""
-    cfg = dataclasses.replace(CFG, dtype="float32")
+def test_wide_rows_run_top_k_and_match_all_experts(form, kernel, monkeypatch):
+    """Above the rule's row bound `_mlp` takes `_moe_ragged` on the quantized
+    stack, still stacked over layers, and gives what `_moe_dense` gives on
+    the same weights, to float32 reduction order. In an admission program
+    (`admit`) where the grouped kernel engages ("pallas": interpret mode
+    here, a TPU chip in a cell) the bound is the measured crossover and the
+    kernel reads the stack in place; where it does not ("auto" off the TPU),
+    and in every decode program, the bound is the stacked kernel's row limit
+    (above it `ragged_dot` runs on the layer's slice, or the kernel)."""
+    from localai_tpu.ops import quant_matmul as QM
+
+    cfg = dataclasses.replace(CFG, dtype="float32", quant_kernel=kernel)
     params = jax.tree.map(lambda a: a.astype(jnp.float32), _seeded(cfg))
     stack = dict(params["layers"])
     for k in ("w_gate", "w_up", "w_down"):
@@ -163,7 +171,9 @@ def test_wide_rows_run_top_k_and_match_all_experts(form, monkeypatch):
     layer = jnp.int32(1)
     lp = {k: (Q.StackedLayer(v, layer) if Q.is_quantized(v) else v[1])
           for k, v in stack.items()}
-    rows = L.QUANT_PALLAS_MAX_ROWS + 44
+    bound = (QM.MOE_ALL_EXPERTS_MAX_ROWS if kernel == "pallas"
+             else L.QUANT_PALLAS_MAX_ROWS)
+    rows = bound + 44
     x = jax.random.normal(jax.random.key(2), (rows, cfg.hidden_size), jnp.float32)
     took, real = [], L._moe_ragged
 
@@ -172,15 +182,30 @@ def test_wide_rows_run_top_k_and_match_all_experts(form, monkeypatch):
         return real(*a, **k)
 
     monkeypatch.setattr(L, "_moe_ragged", spy)
-    wide = L._mlp(cfg, lp, x)
+    grouped = []
+    monkeypatch.setattr(QM, "note_grouped", lambda: grouped.append(1))
+    admit = []
+    wide = L._mlp(cfg, lp, x, admit=admit)
     assert took == ["ragged"]
+    assert len(grouped) == (3 if kernel == "pallas" else 0)
+    # the kernel's walk is reported where it ran: every sorted row is held
+    pairs = rows * cfg.num_experts_per_token
+    assert [a.tolist() for a in admit] == (
+        [[pairs, pairs]] if kernel == "pallas" else [])
     dense = L._moe_dense(cfg, lp, x)
     np.testing.assert_allclose(np.asarray(wide), np.asarray(dense),
                                rtol=2e-4, atol=2e-6)
-    # decode row counts stay on the all-experts form
+    # an admission of few rows stays on the all-experts form, and a decode
+    # program (no `admit`: 32- and 64-row blocks, a verify chunk of 32 slots
+    # x 5, the widest the stacked kernel serves) whatever serves wider rows
     took.clear()
-    L._mlp(cfg, lp, x[:32])
-    assert took == []
+    for n in (32, 64):
+        L._mlp(cfg, lp, x[:n], admit=[])
+    for n in (32, 64, rows, 160, L.QUANT_PALLAS_MAX_ROWS):
+        xn = jnp.resize(x, (n, cfg.hidden_size))
+        L._mlp(cfg, lp, xn.reshape(32, 5, -1) if n == 160 else xn)
+    assert took == ([] if kernel == "pallas" else ["ragged"])  # 108 | 300
+    assert QM.MOE_ALL_EXPERTS_MAX_ROWS >= 64
 
 
 # --------------------------------------------------------------------------- #
@@ -239,7 +264,7 @@ def test_moe_decode_step_reads_experts_out_of_the_stack():
               for v in eqn.outvars if v.aval.dtype == jnp.int8]
     assert sliced == []
     assert sites.by_program == {
-        "decode_block": {"traces": 1, "stacked": 7, "sliced": 0,
+        "decode_block": {"traces": 1, "stacked": 7, "sliced": 0, "grouped": 0,
                          "wholerow": 7, "narrowed": 0, **_NO_PAGED_SITES}}
 
 
@@ -250,18 +275,64 @@ def _all_eqns(jaxpr):
             yield from _all_eqns(sub)
 
 
-def test_moe_step_above_the_row_limit_counts_seven_sliced_sites():
+@pytest.mark.parametrize("kernel", ["pallas", "xla"])
+def test_moe_step_above_the_row_limit_counts_seven_sliced_sites(kernel):
+    """A wide program: the four attention projections slice their layer in
+    front of the XLA form either way; the three expert matmuls take the
+    grouped kernel on the stack (4 sliced + 3 grouped), or, where it does
+    not engage, `ragged_dot` on the layer's slice (4 + 3 sliced)."""
     from localai_tpu.ops.quant_matmul import QUANT_PALLAS_MAX_ROWS
     from localai_tpu.ops.stacked import SiteCounts
 
-    _, fn, args = _moe_decode_step(QUANT_PALLAS_MAX_ROWS + 1)
+    cfg, fn, args = _moe_decode_step(QUANT_PALLAS_MAX_ROWS + 1)
+    cfg = dataclasses.replace(cfg, quant_kernel=kernel)
     sites = SiteCounts()
     with sites.tracing("admit"):
-        jaxpr = jax.make_jaxpr(fn)(*args)
+        jaxpr = jax.make_jaxpr(lambda *a: L.decode_step_windowed(cfg, *a))(*args)
     assert not _pallas_calls(jaxpr.jaxpr, "int8_matmul")
+    calls = _pallas_calls(jaxpr.jaxpr, "int8_grouped_matmul")
+    took = kernel == "pallas"
+    assert len(calls) == (3 if took else 0)
+    for eqn in calls:  # the stack as it is stored: [L·E, in, out]
+        (lead,) = [v.aval.shape[0] for v in eqn.invars if v.aval.dtype == jnp.int8]
+        assert lead == cfg.num_layers * cfg.num_experts
     assert sites.by_program["admit"] == {
-        "traces": 1, "stacked": 0, "sliced": 7, "wholerow": 0, "narrowed": 0,
-        **_NO_PAGED_SITES}
+        "traces": 1, "stacked": 0, "sliced": 4 if took else 7,
+        "grouped": 3 if took else 0, "wholerow": 3 if took else 0,
+        "narrowed": 0, **_NO_PAGED_SITES}
+
+
+@pytest.mark.parametrize("slots,window", [(32, 3), (32, 5), (64, 4), (96, 1)])
+def test_verify_chunks_and_wide_decode_blocks_keep_all_experts(slots, window):
+    """The decode entry points never take the admission bound: a verify
+    chunk of B·(k+1) rows and a decode block of more than 64 slots run
+    all-experts on the stacked kernel up to its row limit where the grouped
+    kernel would engage, as they did before it existed (ROADMAP S4: no
+    idle-expert skipping in decode until a cell has real routing)."""
+    from localai_tpu.ops import quant_matmul as QM
+    from localai_tpu.ops.stacked import SiteCounts
+
+    rows = slots * window
+    assert QM.MOE_ALL_EXPERTS_MAX_ROWS < rows <= QM.QUANT_PALLAS_MAX_ROWS
+    cfg, step, args = _moe_decode_step(slots)
+    params, tok, pos, cache, *_ = args
+    if window == 1:
+        fn, args = step, args
+    else:
+        toks = jnp.tile(tok[:, None], (1, window))
+        at = pos[:, None] + jnp.arange(window)[None]
+        fn = lambda p, t, a, c: L.decode_chunk(cfg, p, t, a, c)  # noqa: E731
+        args = (params, toks, at, cache)
+    sites = SiteCounts()
+    with sites.tracing("decode"):
+        jaxpr = jax.make_jaxpr(fn)(*args)
+    assert not _pallas_calls(jaxpr.jaxpr, "int8_grouped_matmul")
+    lead = sorted(
+        [v.aval for v in eqn.invars if v.aval.dtype == jnp.int8][0].shape[0]
+        for eqn in _pallas_calls(jaxpr.jaxpr, "int8_matmul"))
+    assert lead == [cfg.num_layers] * 4 + [cfg.num_layers * cfg.num_experts] * 3
+    assert sites.by_program["decode"]["grouped"] == 0
+    assert sites.by_program["decode"]["stacked"] == 7
 
 
 # --------------------------------------------------------------------------- #

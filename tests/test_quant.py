@@ -750,8 +750,9 @@ def test_stacked_kernel_under_scan_with_a_traced_index(form):
 _NO_PAGED_SITES = {"paged_attention_stacked": 0, "paged_attention_sliced": 0,
                    "paged_attention_native": 0, "paged_attention_f32": 0}
 # the seven Pallas dequant-matmul calls of a decode step, by the rule's block
-_WHOLEROW_7 = {"wholerow": 7, "narrowed": 0}
-_NO_BLOCKS = {"wholerow": 0, "narrowed": 0}
+# a dense model has no expert matmul to take the grouped kernel (`grouped`)
+_WHOLEROW_7 = {"wholerow": 7, "narrowed": 0, "grouped": 0}
+_NO_BLOCKS = {"wholerow": 0, "narrowed": 0, "grouped": 0}
 
 
 def _pallas_calls(jaxpr, name):
@@ -934,6 +935,111 @@ def test_engine_gauges_count_stacked_and_sliced_sites(arch):
         p["sliced"] for p in by_program.values()) >= 7
     assert metrics["quant_matmul_wholerow_sites"] == 0
     assert metrics["quant_matmul_narrowed_sites"] == 0
+
+
+# --------------------------------------------------------------------------- #
+# The grouped kernel (ISSUE 38): expert-sorted rows over the quantized stack
+# --------------------------------------------------------------------------- #
+
+# name: (sorted rows M, rows a group [E], row tile, form, layer of 3, bend)
+_GROUPED_CASES = {
+    "empty_groups": (200, [0, 90, 0, 0, 110, 0], 64, "flat_int8", 0, "as_is"),
+    "a_group_over_several_tiles": (320, [10, 290, 20], 64, "flat_int8", 0, "as_is"),
+    "several_groups_in_one_tile": (64, [7, 9, 1, 30, 17], 64, "flat_int8", 0, "as_is"),
+    "all_rows_in_one_expert": (192, [0, 0, 192, 0], 64, "flat_int8", 0, "as_is"),
+    "rows_no_multiple_of_the_tile": (150, [70, 80], 64, "flat_int8", 0, "as_is"),
+    "fewer_rows_than_a_tile": (24, [5, 0, 19], 64, "flat_int8", 0, "as_is"),
+    "rows_in_no_held_group": (256, [20, 0, 3, 10], 64, "flat_int8", 0, "as_is"),
+    "no_row_held_at_all": (128, [0, 0, 0], 64, "flat_int8", 0, "as_is"),
+    "a_layer_of_the_stack": (200, [60, 0, 140], 64, "flat_int8", 2, "as_is"),
+    "two_k_chunks": (200, [60, 40, 100], 64, "flat_int8", 1, "k_chunks"),
+    "two_k_chunks_rows_in_no_group": (256, [9, 0, 40], 64, "flat_int8", 1, "k_chunks"),
+    "sub_tile_walk": (200, [60, 40, 100], 64, "flat_int8", 1, "sub_tile_walk"),
+    "grouped_int8": (200, [60, 0, 140], 64, "grouped_int8", 1, "as_is"),
+    "grouped_int8_k_chunks": (200, [60, 0, 140], 64, "grouped_int8", 2, "k_chunks"),
+    "packed_int4": (200, [60, 0, 140], 64, "packed_int4", 1, "as_is"),
+    "packed_int4_k_chunks": (150, [70, 80], 64, "packed_int4", 2, "k_chunks"),
+}
+
+
+@pytest.mark.parametrize("case", list(_GROUPED_CASES))
+def test_grouped_kernel_matches_the_xla_form(monkeypatch, case):
+    """Interpret mode against `lax.ragged_dot` on the layer's slice
+    (llama._ragged_mm): every row of a group agrees; the rows in no group
+    are left to the caller, which zeroes them (`_moe_ragged`, below)."""
+    from localai_tpu.models.llama import _ragged_mm
+    from localai_tpu.ops import quant_matmul as QM
+
+    m, sizes, tm, form, layer, bend = _GROUPED_CASES[case]
+    kin, out, L = (768, 384, 3)  # 384 = 3 x 128 lanes, as 1280 is 10
+    for name, value in _RULE_BENDS[bend].items():
+        monkeypatch.setattr(QM, name, value)
+    monkeypatch.setattr(QM, "GROUP_ROWS", tm)
+    gs = 0 if form == "flat_int8" else 32
+    b = QM._blocks(min(tm, m), kin, out, gs=gs, packed=form == "packed_int4",
+                   zeros=form == "packed_int4")
+    if bend == "k_chunks":
+        assert kin // b.kc > 1
+    if bend == "sub_tile_walk":
+        assert (b.kc // b.sk) * (b.bo // b.so) > 1
+    E = len(sizes)
+    w = jax.random.normal(jax.random.key(40), (L, E, kin, out)) * 0.1
+    q = _quantize_form(w, form)
+    xg = jax.random.normal(jax.random.key(41), (m, kin), jnp.float32)
+    sz = jnp.asarray(sizes, jnp.int32)
+    held = int(sum(sizes))
+    assert QM.grouped_engaged(xg, q, "pallas", None, jnp.int32(layer))
+    got = jax.jit(lambda xg, q, sz, l: QM.grouped_moe_mm(
+        xg, q, QM.group_visits(sz, m), layer=l))(xg, q, sz, jnp.int32(layer))
+    assert got.shape == (m, out)
+    # the oracle lets the rows in no group ride in the last one
+    group = jnp.minimum(jnp.repeat(jnp.arange(E + 1), jnp.asarray(
+        sizes + [m - held]), total_repeat_length=m), E - 1)
+    want = _ragged_mm(xg, _layer(q, layer), sz.at[-1].add(m - held), group)
+    np.testing.assert_allclose(np.asarray(got)[:held], np.asarray(want)[:held],
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_grouped_visits_walk_tiles_and_groups_in_sorted_order():
+    """The walk as a pure function: one visit a (tile, group) pair that
+    shares a row, none for an empty group or a tile in no group, padding
+    visits repeat the last real one."""
+    from localai_tpu.ops import quant_matmul as QM
+
+    assert QM.GROUP_ROWS == 64
+    nvis, gid, tid, off = QM.group_visits(
+        jnp.asarray([0, 130, 0, 2, 60]), 640)
+    assert int(nvis[0]) == 5 and gid.shape == (10 + 5 - 1,)
+    assert gid.tolist()[:5] == [1, 1, 1, 3, 4]
+    assert tid.tolist()[:5] == [0, 1, 2, 2, 2]  # rows 130-191 share tile 2
+    assert set(zip(gid.tolist()[5:], tid.tolist()[5:])) == {(4, 2)}
+    assert off.tolist() == [0, 0, 130, 130, 132, 192]
+    nvis, gid, tid, _ = QM.group_visits(jnp.asarray([0, 0]), 128)
+    assert int(nvis[0]) == 0 and gid.tolist() == [1, 1, 1] and tid.tolist() == [0] * 3
+
+
+def test_grouped_kernel_asks_for_no_scoped_vmem_and_tiles_x():
+    """x is tiled GROUP_ROWS at a time whatever the rows (the check's 2,000
+    token prompt is 16,384 sorted rows), the stack rides whole with the
+    layer index scalar-prefetched, and no VMEM limit is asked for."""
+    from localai_tpu.ops import quant_matmul as QM
+
+    xg = jax.ShapeDtypeStruct((16384, 256), jnp.bfloat16)
+    w = {"q": jax.ShapeDtypeStruct((3, 8, 256, 384), jnp.int8),
+         "s": jax.ShapeDtypeStruct((3, 8, 1, 384), jnp.float32)}
+    jaxpr = jax.make_jaxpr(lambda xg, w, sz, l: QM.grouped_moe_mm(
+        xg, w, QM.group_visits(sz, 16384), layer=l))(
+            xg, w, jax.ShapeDtypeStruct((8,), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32))
+    (eqn,) = _pallas_calls(jaxpr.jaxpr, "int8_grouped_matmul")
+    limit = getattr(eqn.params["compiler_params"].get("mosaic_tpu"),
+                    "vmem_limit_bytes", None)
+    assert limit is None
+    int8 = [v.aval.shape for v in eqn.invars if v.aval.dtype == jnp.int8]
+    assert int8 == [(3 * 8, 256, 384)]
+    x_block = eqn.params["grid_mapping"].block_mappings[0].block_shape
+    assert tuple(int(getattr(d, "block_size", d)) for d in x_block) == (
+        1, QM.GROUP_ROWS, 256)
 
 
 @pytest.mark.multichip

@@ -116,9 +116,41 @@ def test_engine_agrees_with_the_plain_reference(served):
     picks, landed = sum(e["a"] for e in here), sum(e["b"] for e in here)
     assert picks == m["moe_picks"] and landed == m["moe_picks_here"]
     assert 0.03 < landed / picks < 0.3  # an eighth of the experts held
+    # the grouped expert kernel does not run off the TPU, so no admission
+    # program reports a walk (below: where it runs)
+    assert not [e for e in ev if e["event"] == "moe_admit_rows"]
+    assert m["moe_admit_rows"] == 0 == m["moe_admit_rows_held"]
     # moe_experts counts the held experts: 8 MoE layers x 3 of 24
     assert all(e["a"] % (8 * 3) == 0 for e in ev if e["event"] == "moe_experts")
     assert any(e["event"] == "moe_load" for e in ev)
+
+
+def test_admission_reports_what_its_grouped_kernel_walked():
+    """`prefill(expert_rows=True)` where the grouped kernel engages (asked
+    for by name: interpret mode here): the sorted (row, pick) pairs it was
+    compiled for over the 8 MoE layers and those of an expert held here, an
+    eighth; the same logits as the XLA form. Nothing to report at few rows,
+    where the admission runs all-experts."""
+    params = _seeded(quantize="int8")
+    toks = jax.random.randint(jax.random.key(5), (2, 48), 0, CFG.vocab_size)
+    lens = jnp.asarray([48, 31], jnp.int32)
+    logits = {}
+    for kernel in ("xla", "pallas"):
+        cfg = dataclasses.replace(CFG, quant_kernel=kernel)
+        logits[kernel], _, _, walked = jax.jit(
+            lambda p, t, n, cfg=cfg: L.prefill(cfg, p, t, n, expert_rows=True)
+        )(params, toks, lens)
+        pairs = 8 * 2 * 48 * CFG.num_experts_per_token
+        if kernel == "xla":
+            assert walked.tolist() == [0, 0]
+        else:
+            assert int(walked[0]) == pairs
+            assert 0.03 < int(walked[1]) / pairs < 0.3
+    np.testing.assert_allclose(logits["pallas"], logits["xla"], atol=3e-2)
+    cfg = dataclasses.replace(CFG, quant_kernel="pallas")
+    *_, walked = L.prefill(cfg, params, toks[:, :16], lens // 3,
+                           expert_rows=True)
+    assert walked.tolist() == [0, 0]  # 32 rows: all-experts
 
 
 def test_one_period_stage_agrees_with_the_plain_reference():
