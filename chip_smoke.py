@@ -152,7 +152,7 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
     s = SIZES[size]
     B, K, G, D, page = s["B"], s["K"], s["G"], s["D"], s["page"]
     H = K * G
-    keys = iter(jax.random.split(jax.random.key(21), 96))
+    keys = iter(jax.random.split(jax.random.key(21), 128))
     cases: dict[str, dict] = {}
 
     def rnd(shape, dtype=jnp.bfloat16, scale=1.0):
@@ -299,11 +299,21 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
     lo, hi = (300, 700) if page == 128 else (page + 1, 4 * page)  # rehearsal
     cell_pages = -(-hi // page)
     n_cell = rows * cell_pages + 1
-    for kc, gc in ((8, 4), (16, 1), (2, 4)):
+    # Since ISSUE 41 a visit at K = 2 is six pages side by side under one
+    # dot and at K = 4 three (ops/paged_flash._visit_pages), a slot's last
+    # visit ragged: the same oracle and 6e-3, the K = 4 shape beside the
+    # cells' three, and K = 2 once more with every context ending on a
+    # page's last row (no dead row in any page, whole pages dead in the
+    # last visit).
+    for kc, gc, ends in ((8, 4, False), (16, 1, False), (2, 4, False),
+                         (4, 4, False), (2, 4, True)):
         table_c = (jax.random.permutation(next(keys), n_cell - 1) + 1).reshape(
             rows, cell_pages).astype(jnp.int32)
         limits_c = jax.random.randint(next(keys), (rows,), lo, hi + 1)
-        case(f"paged_decode_cell_K{kc}_G{gc}", kernel_walk, exact_walk,
+        if ends:
+            limits_c = -(-limits_c // page) * page
+        case(f"paged_decode_cell_K{kc}_G{gc}" + ("_page_ends" if ends else ""),
+             kernel_walk, exact_walk,
              (rnd((rows, kc * gc, D)), rnd((2, n_cell, page, kc, D)),
               rnd((2, n_cell, page, kc, D)), table_c, limits_c,
               jnp.int32(1)), 6e-3)

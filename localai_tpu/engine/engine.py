@@ -6400,6 +6400,12 @@ class Engine:
                 sites["paged_attention_native"])
             out["paged_attention_f32_sites"] = float(
                 sites["paged_attention_f32"])
+            # and what a visit of its page walk held: several pages side
+            # by side under one dot, or one (stacked.note_visit)
+            out["paged_attention_multipage_sites"] = float(
+                sites["paged_attention_multipage"])
+            out["paged_attention_onepage_sites"] = float(
+                sites["paged_attention_onepage"])
         if self.m_forks or self.m_fork_clone_fallbacks:
             # Tree-batched fork sampling (ISSUE 18): branches admitted by
             # slot fork vs degraded to the N-clone path (fault/pressure).

@@ -88,7 +88,17 @@ STAT_LANES = 128
 # wrong-head columns cost more rows streamed than the per-head tiles cost
 # to cut out, and the [rows, page·K] score array outgrows its registers.
 FLAT_MAX_ROWS = 128
-# VMEM the ring of page buffers may take, and its depth bounds (`_ring_depth`).
+# (token, head) rows of K and V an as-stored visit scores at once
+# (`_visit_pages`): the largest visit of which a ring of RING_MAX buffers
+# still fits RING_VMEM_BYTES at 128-wide bfloat16 heads (3 MB / (4 x 512 B)),
+# and under two pages of an 8-KV-head pool, so 8 heads and more walk a page
+# a visit as they did. Measured (PERF.md §6 PR 41, a 32-layer scan at the
+# tp = 4 cell's shape, us a layer at 1,024 / 1,536 / 2,048 rows): K = 2
+# 51.1 / 36.3 / 39.2 beside 73.1 at a page a visit, K = 4 74.2 / 69.5 / 70.9
+# beside 88.9; contexts of 1,500-3,000 tokens K = 2 127.4 / 123.4 / 118.6
+# beside 251.5.
+VISIT_ROWS = 1536
+# VMEM the ring of visit buffers may take, and its depth bounds (`_ring_depth`).
 RING_VMEM_BYTES = 3 * 1024 * 1024
 RING_MAX = 4
 
@@ -106,12 +116,34 @@ def _flat_rows(k_dtype, v_dtype, num_kv: int, qr: int) -> bool:
             and (num_kv * size) % 4 == 0 and num_kv * qr <= FLAT_MAX_ROWS)
 
 
-def _ring_depth(page_bytes: int) -> int:
-    """Page buffers in the DMA ring: what RING_VMEM_BYTES holds of one
-    page's K and V, between the old double buffer and RING_MAX (a visit
-    that is shorter than its DMA wants two pages in flight behind the one
-    being scored; a fourth measured nothing more, PERF.md §6 PR 32)."""
-    return max(2, min(RING_MAX, RING_VMEM_BYTES // max(1, page_bytes)))
+def _visit_pages(page: int, num_kv: int, width: int, *, flat: bool,
+                 swin: int = 0) -> int:
+    """Consecutive table columns of a slot that ONE visit of the page walk
+    lands side by side and scores with one dot a pool (ISSUE 41): as many
+    whole pages as fit VISIT_ROWS (token, head) rows, never more than the
+    table has columns. A visit pays a fixed chain (score dot -> max -> exp
+    -> sum -> `p @ V` -> rescale, each waiting on the one before: some
+    0.3 us whether it holds 256 rows or 2,048) and nothing hides it, so at
+    2 KV heads a chip (tp = 4), where a 128-row page is 256 rows, the
+    chain was paid once a 128 KB: sized in rows, a visit is the same work
+    whatever the caller's head count or page size. 128-row pages: K = 2
+    gives 6, K = 4 gives 3, K = 8 and 16 give 1, the walk as it was,
+    traced as it was. One page a visit too for the per-head form (not
+    `flat`: a float32 pool, wide query tiles) and under `swin`, whose walk
+    skips the cold middle, so consecutive visits are not consecutive
+    columns (no cell runs it; it stays without the handoff as well)."""
+    if not flat or swin:
+        return 1
+    return max(1, min(VISIT_ROWS // (page * num_kv), width))
+
+
+def _ring_depth(visit_bytes: int) -> int:
+    """Visit buffers in the DMA ring: what RING_VMEM_BYTES holds of one
+    visit's K and V (`_visit_pages` pages of each), between the old double
+    buffer and RING_MAX (a visit that is shorter than its DMA wants two in
+    flight behind the one being scored; a fourth measured nothing more,
+    PERF.md §6 PR 32)."""
+    return max(2, min(RING_MAX, RING_VMEM_BYTES // max(1, visit_bytes)))
 
 
 def use_pallas(impl: str = "auto") -> bool:
@@ -141,6 +173,7 @@ def _ragged_paged_kernel(
     l1_span: int = 0,
     ring: int = 2,
     flat: bool = False,
+    pages: int = 1,
 ):
     """Kernel body. Scalar-prefetch layout depends on the table layout:
 
@@ -166,16 +199,27 @@ def _ragged_paged_kernel(
         page is the [C = page·K, D] matrix it is stored as (row n·K + h),
         q_ref [1, R = K·QR, Dk] f32 (row h·QR + i, the k scale folded in by
         the wrapper), qpos_ref [1, R, 1] i32, colhead_ref / colrow_ref
-        [1, C] i32 (h and n of a column), rowhead_ref [R, 1] i32, k_hbm/v_hbm
-        [L, P, C, D] (ANY), outputs [1, R, ·], scratch kbuf/vbuf
-        [ring, C, D], acc_s/m_s/l_s [R, ·]. ONE dot a pool a page: every
-        head's query rows against every (n, h) row, the columns of the
-        other heads masked with the dead rows, so `p @ V` over the same
-        C-long axis is each head's own sum. Nothing is cut out, upcast or
-        scaled per head; the MXU loads the same 2·K tiles either way.
+        [1, pages·C] i32 (h and n of a column), rowhead_ref [R, 1] i32,
+        k_hbm/v_hbm [L, P, C, D] (ANY), outputs [1, R, ·], scratch kbuf/vbuf
+        [ring, pages·C, D], acc_s/m_s/l_s [R, ·]. ONE dot a pool a visit:
+        every head's query rows against every (n, h) row, the columns of
+        the other heads masked with the dead rows, so `p @ V` over the same
+        long axis is each head's own sum. Nothing is cut out, upcast or
+        scaled per head; the MXU loads the same 2·K tiles a page either way.
 
-    The DMAs run `ring - 1` pages ahead of the page being scored, and a
-    slot's last visit starts the NEXT slot's first page (`handoff`).
+    A VISIT is `pages` consecutive table columns of the slot
+    (`_visit_pages`; 1 unless `flat`): one DMA pair a page (pages are
+    scattered in the pool) into the `pages` parts of ONE ring buffer, one
+    dot a pool over all of it, one max / exp / sum / rescale. Column c of
+    a visit is row c // K of the visit's first page onwards, so the masks
+    read as before. A slot's last visit holds 1..pages live pages: the rest
+    are not fetched, their columns lie past the slot's limit and are masked
+    like any dead row, and their part of the V buffer is ZEROED before the
+    dot (`p` is 0 there, but the buffer may hold anything, and 0 x NaN is
+    NaN; garbage in K only makes scores that the mask replaces).
+
+    The DMAs run `ring - 1` visits ahead of the visit being scored, and a
+    slot's last visit starts the NEXT slot's first visit (`handoff`).
 
     sink/swin (windowed+sink decode, docs/LONG_CONTEXT.md): a row is
     attended iff `gpos < sink` or `q_pos - gpos < swin`. The page walk then
@@ -212,20 +256,24 @@ def _ragged_paged_kernel(
         acc_ref,  # out f32
         m_ref,  # out f32, STAT_LANES wide
         l_ref,
-        kbuf,  # VMEM scratch [ring, <a page>] pool dtype
+        kbuf,  # VMEM scratch [ring, <a visit's pages>] pool dtype
         vbuf,
         acc_s,  # VMEM scratch f32: the running (acc, m, l)
         m_s,
         l_s,
-        sem,  # DMA semaphores [ring, 2]
+        sem,  # DMA semaphores [ring, 2·pages]: a page's K and V copy
     ) = refs
 
     b = pl.program_id(0)
     lim = limits_ref[b]
     layer = layer_ref[0]
-    # This slot's own page count (ragged), clamped to the table width so a
-    # bad limit can never index the table out of bounds.
-    np_live = jnp.minimum((lim + page - 1) // page, table_width)
+
+    def live_pages(limit):
+        # A slot's own page count (ragged), clamped to the table width so a
+        # bad limit can never index the table out of bounds.
+        return jnp.minimum((limit + page - 1) // page, table_width)
+
+    np_live = live_pages(lim)
 
     if swin:
         # Cold-middle skip: walk iteration j covers table column col(j).
@@ -239,7 +287,7 @@ def _ragged_paged_kernel(
         def col_of(j):
             return jnp.where(j < sink_cols, j, j + gap)
     else:
-        n_iter = np_live
+        n_iter = np_live if pages == 1 else (np_live + pages - 1) // pages
 
         def col_of(j):
             return j
@@ -249,18 +297,53 @@ def _ragged_paged_kernel(
             return l0_ref[l1_ref[row, col // l1_span], col % l1_span]
         return table_ref[row, col]
 
-    def copies(pid, buf):  # one page's K and V into a buffer of the ring
+    # rows of a visit's buffer that one page fills
+    part_rows = kbuf.shape[1] // pages
+
+    def copies(pid, buf, part=0):
+        """One page's K and V into part `part` of a buffer of the ring."""
+        def into(ref):
+            if pages == 1:
+                return ref.at[buf]
+            return ref.at[buf, pl.ds(part * part_rows, part_rows)]
+
         return (
             pltpu.make_async_copy(
-                k_hbm.at[layer, pid], kbuf.at[buf], sem.at[buf, 0]),
+                k_hbm.at[layer, pid], into(kbuf), sem.at[buf, 2 * part]),
             pltpu.make_async_copy(
-                v_hbm.at[layer, pid], vbuf.at[buf], sem.at[buf, 1]),
+                v_hbm.at[layer, pid], into(vbuf), sem.at[buf, 2 * part + 1]),
         )
 
-    # A slot's first page is the one DMA nothing hides (a program was some
-    # 1.4 us beside 0.68 us a visit, PERF.md §6 PR 32), so the slot before
-    # starts it from its own last visit: the grid's programs run in order
-    # and the scratch outlives them. Visit j of this slot then lives in
+    def each_copy(act, row, j, live=None, buf=None):
+        """`act` (start or wait) on the copies of visit j of slot `row`, into
+        this slot's buffer for its visit j or into `buf`: the visit's first
+        page, which the caller knows to be live, and of the others those
+        below the slot's `live` pages."""
+        for part in range(pages):
+            col = col_of(j) if pages == 1 else j * pages + part
+
+            def go(col=col, part=part):
+                pid = page_of(row, col)
+                dst = (first + j) % ring if buf is None else buf
+                for dma in copies(pid, dst, part):
+                    act(dma)
+
+            if part == 0:
+                go()
+            else:
+                pl.when(col < live)(go)
+
+    def start(dma):
+        dma.start()
+
+    def wait(dma):
+        dma.wait()
+
+    # A slot's first visit is the one DMA nothing hides (a program was some
+    # 1.4 us beside 0.68 us a visit, PERF.md §6 PR 32; where a visit is
+    # several pages it is most or all of a short slot's walk), so the slot
+    # before starts it from its own last visit: the grid's programs run in
+    # order and the scratch outlives them. Visit j of this slot then lives in
     # buffer (first + j) mod ring. Not under swin: where that walk starts
     # depends on the next slot's own query positions.
     if handoff:
@@ -270,22 +353,18 @@ def _ragged_paged_kernel(
     else:
         handed, first = False, 0
 
-    def dmas(j):  # page j's two copies, into its buffer of the ring
-        return copies(page_of(b, col_of(j)), (first + j) % ring)
-
     acc_s[...] = jnp.zeros_like(acc_s)
     m_s[...] = jnp.full_like(m_s, NEG_INF)
     l_s[...] = jnp.zeros_like(l_s)
 
-    for ahead in range(ring - 1):  # the first pages ride before any is scored
+    for ahead in range(ring - 1):  # the first visits ride before any is scored
         mine_to_start = ahead < n_iter
         if handoff and ahead == 0:
             mine_to_start = mine_to_start & ~handed
 
         @pl.when(mine_to_start)
         def _warmup(ahead=ahead):
-            for dma in dmas(ahead):
-                dma.start()
+            each_copy(start, b, ahead, np_live)
 
     def masked(gpos):
         """Which of a page's rows a query row attends: gpos [1 | QR, ·]
@@ -340,13 +419,23 @@ def _ragged_paged_kernel(
         mine = colhead_ref[...] == rowhead_ref[...]  # [1, C] == [R, 1]
 
     def visit_flat(slot, j):
-        ok = mine & masked(col_of(j) * page + colrow_ref[...])  # [R, C]
+        if pages == 1:
+            first_row = col_of(j) * page
+        else:
+            first_row = j * (pages * page)
+            for part in range(1, pages):  # a last visit's unfetched pages
+
+                @pl.when(j * pages + part >= np_live)
+                def _finite(part=part):
+                    vbuf[slot, pl.ds(part * part_rows, part_rows)] = (
+                        jnp.zeros((part_rows, vbuf.shape[2]), vbuf.dtype))
+        ok = mine & masked(first_row + colrow_ref[...])  # [R, pages·C]
         # (a bfloat16 page goes as it is, the cast is none; fp8 -> bfloat16
         # is exact)
         s = jax.lax.dot_general(
             qb, kbuf[slot].astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [R, C]: every head's rows against every (n, h) row
+        )  # [R, pages·C]: every head's rows against every (n, h) row
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
         s = jnp.where(ok, s, NEG_INF)
@@ -358,14 +447,13 @@ def _ragged_paged_kernel(
         acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
             p.astype(jnp.bfloat16), vbuf[slot].astype(jnp.bfloat16),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )  # p is zero off its own head: the C-long sum is the head's own
+        )  # p is zero off its own head: the long sum is the head's own
         m_s[...] = m_new
 
     def body(j, carry):
         @pl.when(j + ring - 1 < n_iter)
-        def _prefetch():  # later pages ride the wire while this one computes
-            for dma in dmas(j + ring - 1):
-                dma.start()
+        def _prefetch():  # later visits ride the wire while this one computes
+            each_copy(start, b, j + ring - 1, np_live)
 
         if handoff:
             nxt = jnp.minimum(b + 1, pl.num_programs(0) - 1)
@@ -374,13 +462,12 @@ def _ragged_paged_kernel(
                      & (limits_ref[nxt] > 0))
             def _hand_on():  # every later page of this slot is in: one is free
                 buf = (first + j + 1) % ring
-                for dma in copies(page_of(nxt, 0), buf):
-                    dma.start()
+                each_copy(start, nxt, 0, buf=buf, live=(
+                    None if pages == 1 else live_pages(limits_ref[nxt])))
                 handed_ref[0] = 1
                 handed_ref[1] = buf
 
-        for dma in dmas(j):
-            dma.wait()
+        each_copy(wait, b, j, np_live)
         (visit_flat if flat else visit_heads)((first + j) % ring, j)
         return carry
 
@@ -463,11 +550,12 @@ def _latent_partials_rows(qr, pool, table, limits, interpret: bool):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from localai_tpu.ops.stacked import note_arith, stacks_of
+    from localai_tpu.ops.stacked import note_arith, note_visit, stacks_of
 
     B, _, QR, D = qr.shape
     stack, _, layer = stacks_of(pool, pool, "layer_kv_pool")
     note_arith(native=False)  # float32 dots on the upcast tile, still
+    note_visit(multipage=False)  # and one page a visit
     L, P, page = stack.shape[:3]
     kernel = functools.partial(_latent_paged_kernel, page=page)
     acc, m, l = pl.pallas_call(
@@ -539,13 +627,13 @@ def _paged_partials_rows(
     kv_scale=None,  # [2, K] f32 per-head (k, v) dequant scales, or None
     sink: int = 0,  # windowed+sink decode (docs/LONG_CONTEXT.md)
     swin: int = 0,
-    ring: int | None = None,  # page buffers in the DMA ring (tests); None: `_ring_depth`
+    ring: int | None = None,  # visit buffers in the DMA ring (tests); None: `_ring_depth`
 ):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     from localai_tpu.ops import ptable as _pt
-    from localai_tpu.ops.stacked import note_arith, stacks_of
+    from localai_tpu.ops.stacked import note_arith, note_visit, stacks_of
 
     B, K, QR, Dk = qr.shape
     k_pool, v_pool, layer = stacks_of(k_pool, v_pool, "layer_kv_pool")
@@ -553,33 +641,39 @@ def _paged_partials_rows(
     Dv = v_pool.shape[4]
     flat = _flat_rows(k_pool.dtype, v_pool.dtype, K, QR)
     note_arith(native=flat)
-    if ring is None:
-        ring = _ring_depth(page * K * (Dk * k_pool.dtype.itemsize
-                                       + Dv * v_pool.dtype.itemsize))
     sl_arr = jnp.asarray(
         sliding if sliding is not None else False
     ).reshape(1).astype(jnp.int32)
     if _pt.is_hier(table):
         l1, l0 = table
         l1_span = int(l0.shape[-1])
+        width = int(l1.shape[1]) * l1_span
         tbl_args = (l1.astype(jnp.int32), l0.astype(jnp.int32))
     else:
         l1_span = 0
+        width = int(table.shape[1])
         tbl_args = (table.astype(jnp.int32),)
+    pages = _visit_pages(page, K, width, flat=flat, swin=int(swin))
+    note_visit(multipage=pages > 1)
+    if ring is None:
+        ring = _ring_depth(pages * page * K * (Dk * k_pool.dtype.itemsize
+                                               + Dv * v_pool.dtype.itemsize))
     kernel = functools.partial(
         _ragged_paged_kernel, page=page, num_kv=K,
         softcap=float(softcap), window=int(window),
         sink=int(sink), swin=int(swin), l1_span=l1_span, ring=ring, flat=flat,
+        pages=pages,
     )
     qpos_rows = qpos_rows.astype(jnp.int32)
     if flat:
         # The page as stored: rows h·QR + i of q against the [C, D] view of
-        # a page (a bitcast, `_flat_rows`). The k scale rides on q and the v
-        # scale on the finished sum: a multiply by ones is never traced.
+        # a page (a bitcast, `_flat_rows`), `pages` of them side by side a
+        # visit. The k scale rides on q and the v scale on the finished sum:
+        # a multiply by ones is never traced.
         R, C = K * QR, page * K
         if kv_scale is not None:
             qr = qr * kv_scale[0].astype(jnp.float32)[None, :, None, None]
-        col = np.arange(C, dtype=np.int32)
+        col = np.arange(pages * C, dtype=np.int32)
         operands = (
             qr.reshape(B, R, Dk), jnp.tile(qpos_rows, (1, K))[..., None],
             jnp.asarray(col % K)[None], jnp.asarray(col // K)[None],
@@ -588,11 +682,11 @@ def _paged_partials_rows(
         )
         lead, zeros = (R,), (0,)
         const_specs = [
-            pl.BlockSpec((1, C), lambda b, *_: (0, 0)),
-            pl.BlockSpec((1, C), lambda b, *_: (0, 0)),
+            pl.BlockSpec((1, pages * C), lambda b, *_: (0, 0)),
+            pl.BlockSpec((1, pages * C), lambda b, *_: (0, 0)),
             pl.BlockSpec((R, 1), lambda b, *_: (0, 0)),
         ]
-        page_shape = (C,)
+        page_shape = (pages * C,)
     else:
         kvs = (jnp.ones((2, K), jnp.float32) if kv_scale is None
                else kv_scale.astype(jnp.float32))
@@ -623,7 +717,7 @@ def _paged_partials_rows(
                 pltpu.VMEM((*lead, Dv), jnp.float32),
                 pltpu.VMEM((*lead, 1), jnp.float32),
                 pltpu.VMEM((*lead, 1), jnp.float32),
-                pltpu.SemaphoreType.DMA((ring, 2)),
+                pltpu.SemaphoreType.DMA((ring, 2 * pages)),
                 *([] if swin else [pltpu.SMEM((2,), jnp.int32)]),  # handoff
             ],
         ),

@@ -122,6 +122,10 @@ _KEYS = {
     # not a stack's fate but the same kind of static choice: the arithmetic
     # of a paged-attention kernel call (`note_arith`)
     "paged_attention_arith": ("paged_attention_native", "paged_attention_f32"),
+    # nor this: what a visit of a paged-attention kernel's page walk holds
+    # (`note_visit`)
+    "paged_attention_visit": ("paged_attention_multipage",
+                              "paged_attention_onepage"),
 }
 _ALL_KEYS = tuple(k for ks in _KEYS.values() for k in ks)
 
@@ -136,8 +140,10 @@ class SiteCounts:
     `grouped` (`note_grouped`); paged attention under
     `paged_attention_stacked` / `paged_attention_sliced`; beside them the
     arithmetic of each Pallas paged-attention call, `paged_attention_native`
-    / `paged_attention_f32` (`note_arith`), and the weight block of each
-    Pallas dequant-matmul call, `wholerow` / `narrowed` (`note_blocks`).
+    / `paged_attention_f32` (`note_arith`) and what a visit of its page walk
+    holds, `paged_attention_multipage` / `paged_attention_onepage`
+    (`note_visit`), and the weight block of each Pallas dequant-matmul call,
+    `wholerow` / `narrowed` (`note_blocks`).
     The choice is static, so it is counted where it is made, once per trace. An engine
     owns one and traces its programs under `tracing(<program>)`."""
 
@@ -202,3 +208,16 @@ def note_arith(native: bool) -> None:
     float32 first (`paged_attention_f32`: a float32 pool, a wide query
     tile, the latent kernel). The XLA walk counts under neither."""
     note_site(native, kernel="paged_attention_arith")
+
+
+def note_visit(multipage: bool) -> None:
+    """Count one Pallas paged-attention kernel call of the program being
+    traced by what a visit of its page walk holds (ops/paged_flash
+    `_visit_pages`): `multipage`, several consecutive pages of the slot
+    landed side by side and scored by one dot a pool (a narrow pool of
+    whose pages two or more fit VISIT_ROWS (token, head) rows: 2 or 4 KV
+    heads a chip at 128-row pages; key `paged_attention_multipage`), or one
+    page a visit (`paged_attention_onepage`: 8 KV heads and more, the
+    per-head form, the cold-middle walk, the latent kernel). The XLA walk
+    counts under neither."""
+    note_site(multipage, kernel="paged_attention_visit")

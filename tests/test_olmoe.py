@@ -215,7 +215,9 @@ def test_wide_rows_run_top_k_and_match_all_experts(form, kernel, monkeypatch):
 
 # dense-cache programs hold no paged-attention site (ops/stacked.SiteCounts)
 _NO_PAGED_SITES = {"paged_attention_stacked": 0, "paged_attention_sliced": 0,
-                   "paged_attention_native": 0, "paged_attention_f32": 0}
+                   "paged_attention_native": 0, "paged_attention_f32": 0,
+                   "paged_attention_multipage": 0,
+                   "paged_attention_onepage": 0}
 
 
 def _pallas_calls(jaxpr, name):

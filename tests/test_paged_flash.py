@@ -815,21 +815,28 @@ def test_stacked_pool_sharded_tp2(multichip):
                 np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
-@pytest.mark.parametrize("impl", ["pallas", "xla"])
-def test_engine_gauges_count_paged_attention_sites(impl):
+@pytest.mark.parametrize("impl,kv_heads,page", [
+    ("pallas", 2, 64), ("xla", 2, 64), ("pallas", 2, 128), ("pallas", 8, 128)])
+def test_engine_gauges_count_paged_attention_sites(impl, kv_heads, page):
     """After a paged request Engine.metrics() says what every traced
     paged-attention site handed on: the Pallas kernel takes the stacked pool
     (0 sliced), the XLA walk slices at its own site (0 stacked)."""
+    import dataclasses
+
     from localai_tpu.engine.engine import Engine, EngineConfig
     from localai_tpu.engine.tokenizer import ByteTokenizer
     from localai_tpu.models import get_arch
     from localai_tpu.models.llama import init_params
 
     cfg = get_arch("tiny")
+    if kv_heads != cfg.num_kv_heads:  # eight heads of 8 in place of 4 of 16
+        cfg = dataclasses.replace(cfg, num_heads=kv_heads,
+                                  num_kv_heads=kv_heads)
     eng = Engine(
         cfg, init_params(cfg, jax.random.key(0)), ByteTokenizer(cfg.vocab_size),
-        engine_cfg=EngineConfig(max_slots=2, max_seq=256, kv_pages=6,
-                                kv_page_size=64, paged_kernel=impl),
+        engine_cfg=EngineConfig(max_slots=2, max_seq=256,
+                                kv_pages=6 * 64 // page, kv_page_size=page,
+                                paged_kernel=impl),
     )
     try:
         _, ev = eng.generate(list(range(1, 20)), max_new_tokens=4,
@@ -857,6 +864,19 @@ def test_engine_gauges_count_paged_attention_sites(impl):
     assert metrics["paged_attention_native_sites"] == sum(
         p["paged_attention_native"] for p in by_program.values())
     assert metrics["paged_attention_f32_sites"] == 0
+    # and what a visit of the walk held (ISSUE 41): at 2 KV heads a page is
+    # 128 or 256 (token, head) rows of the 1,536 a visit takes, so the
+    # kernel lands several side by side (the slot's four or two columns:
+    # what one chip of tp = 4 runs); at 8 heads a 128-row page is a visit,
+    # the one-chip cells' walk
+    mine, other = (("multipage", "onepage") if kv_heads == 2
+                   else ("onepage", "multipage"))
+    assert block[f"paged_attention_{mine}"] == (
+        block["traces"] if impl == "pallas" else 0)
+    assert block[f"paged_attention_{other}"] == 0
+    for key in (mine, other):
+        assert metrics[f"paged_attention_{key}_sites"] == sum(
+            p[f"paged_attention_{key}"] for p in by_program.values())
 
 
 # ---------------------------------------------------------------------- #
@@ -876,12 +896,14 @@ def _bf16_round(x):
 
 
 def _f64_walk(qr, qpos_rows, k_pool, v_pool, table, limits, *, kv_scale=None,
-              softcap=0.0, window=0, sliding=False, sink=0, swin=0):
-    """The page walk in float64 numpy, a page at a time, rounding to
-    bfloat16 exactly what the kernel hands the MXU in bfloat16: q (scale and
-    k scale applied in float32 first, as the wrapper does) and each page's p
-    against the running max. qr [B, K, QR, D] float32 with 1/sqrt(D) in it;
-    returns (acc, m, l) as the kernel's [B, K, QR, ·]."""
+              softcap=0.0, window=0, sliding=False, sink=0, swin=0, pages=1):
+    """The page walk in float64 numpy, a visit of `pages` consecutive table
+    columns at a time (ISSUE 41; a slot's last visit holds what is left),
+    rounding to bfloat16 exactly what the kernel hands the MXU in bfloat16:
+    q (scale and k scale applied in float32 first, as the wrapper does) and
+    each visit's p against the running max. It reads the listed pages
+    only. qr [B, K, QR, D] float32 with 1/sqrt(D) in it; returns
+    (acc, m, l) as the kernel's [B, K, QR, ·]."""
     qr = np.asarray(qr, np.float32)
     if kv_scale is not None:
         qr = qr * np.asarray(kv_scale[0], np.float32)[None, :, None, None]
@@ -897,15 +919,20 @@ def _f64_walk(qr, qpos_rows, k_pool, v_pool, table, limits, *, kv_scale=None,
     m = np.full((B, K, QR, 1), neg)
     l = np.zeros((B, K, QR, 1))
     for b in range(B):
-        for j in range(min(-(-int(limits[b]) // page), table.shape[1])):
-            gpos = j * page + np.arange(page)[None, :]  # [1, page]
-            ok = np.broadcast_to(gpos < limits[b], (QR, page))
+        live = min(-(-int(limits[b]) // page), table.shape[1])
+        for j in range(0, live, pages):
+            pids = table[b, j:min(j + pages, live)]
+            rows = len(pids) * page
+            gpos = j * page + np.arange(rows)[None, :]  # [1, rows]
+            ok = np.broadcast_to(gpos < limits[b], (QR, rows))
             dist = qpos_rows[b][:, None] - gpos
             if window and sliding:
                 ok = ok & (dist < window)
             if swin:
                 ok = ok & ((gpos < sink) | (dist < swin))
-            s = np.einsum("kqd,nkd->kqn", q[b], k[table[b, j]])
+            kk = k[pids].reshape(rows, *k.shape[2:])
+            vv = v[pids].reshape(rows, *v.shape[2:])
+            s = np.einsum("kqd,nkd->kqn", q[b], kk)
             if softcap:
                 s = softcap * np.tanh(s / softcap)
             s = np.where(ok[None], s, neg)
@@ -914,7 +941,7 @@ def _f64_walk(qr, qpos_rows, k_pool, v_pool, table, limits, *, kv_scale=None,
             p = np.where(ok[None], np.exp(s - m_new), 0.0)
             l[b] = l[b] * alpha + p.sum(-1, keepdims=True)
             acc[b] = acc[b] * alpha + np.einsum(
-                "kqn,nkd->kqd", _bf16_round(p), v[table[b, j]])
+                "kqn,nkd->kqd", _bf16_round(p), vv)
             m[b] = m_new
     if kv_scale is not None:
         acc = acc * np.asarray(kv_scale[1], np.float64)[None, :, None, None]
@@ -984,12 +1011,25 @@ def _native_case(wrapper, pool, variant):
 @pytest.mark.parametrize("wrapper", ["decode", "mq", "prefill"])
 def test_narrow_pool_page_as_stored_matches_float64_walk(wrapper, pool,
                                                          variant):
-    from localai_tpu.ops.paged_flash import _flat_rows
-
     fn, q, k4, v4, table, limits, kw = _native_case(wrapper, pool, variant)
+    # 16-row pages of 2 or 4 heads: a visit is the table's five columns, all
+    # of a slot's walk (one page under the cold-middle skip)
+    pages = 1 if variant == "sink_window" else table.shape[1]
+    _check_against_float64_walk(fn, q, k4, v4, table, limits, kw, pages)
+
+
+def _check_against_float64_walk(fn, q, k4, v4, table, limits, kw, pages,
+                                flips=0.01):
+    """A narrow-pool wrapper call against `_f64_walk` at `pages` a visit,
+    which has to be what `_visit_pages` gives the call."""
+    from localai_tpu.ops.paged_flash import _flat_rows, _visit_pages
+
+    kw = dict(kw)
     B, K, D = q.shape[0], k4.shape[2], q.shape[-1]
     G = q.shape[-2] // K
     assert _flat_rows(k4.dtype, v4.dtype, K, G * (1 if q.ndim == 3 else 2))
+    assert pages == _visit_pages(k4.shape[1], K, table.shape[1], flat=True,
+                                 swin=kw.get("swin", 0))
     got = fn(q, k4, v4, kw.pop("table", table), limits, interpret=True, **kw)
     # the walk's rows, as the wrappers lay them out: r = t·G + g
     qf = np.asarray(q, np.float32) * np.float32(1.0 / D**0.5)
@@ -1004,12 +1044,148 @@ def test_narrow_pool_page_as_stored_matches_float64_walk(wrapper, pool,
     walk = {k: kw[k] for k in ("kv_scale", "softcap", "window", "sink", "swin")
             if k in kw}
     acc, m, l = _f64_walk(qr, qpos_rows, k4, v4, table, limits,
-                          sliding="sliding" in kw, **walk)
+                          sliding="sliding" in kw, pages=pages, **walk)
     if q.ndim == 4:
         back = lambda a: a.reshape(B, K, q.shape[1], G, -1).transpose(
             0, 1, 3, 2, 4)
         acc, m, l = back(acc), back(m), back(l)
-    _assert_float32_grade(got, (acc, m, l))
+    _assert_float32_grade(got, (acc, m, l), flips)
+    return got
+
+
+# ---------------------------------------------------------------------- #
+# ISSUE 41: a visit of the as-stored walk is as many consecutive pages of
+# the slot as fit VISIT_ROWS (token, head) rows: one dot a pool over all of
+# them, one rescale, a last visit of 1..n live pages whose unfetched part
+# may hold anything.
+# ---------------------------------------------------------------------- #
+
+
+def _multipage_case(n, wrapper, variant):
+    """(fn, q, pools, table, limits, kwargs) at `n` pages a visit: 128-row
+    pages at K = 8 / 4 / 2 give 1 / 3 / 6 (fp8 needs four heads a word:
+    64-row pages for n = 6), 192-row pages at K = 4 / 2 give 2 / 4. Slots
+    of 1, n - 1, n, n + 1 and 2n + 1 pages, ending inside a page and on a
+    page's last row, with idle slots between live ones (the handoff skips
+    them) and an idle first one."""
+    fp8 = variant == "fp8_scale"
+    K, page = {1: (8, 128), 2: (4, 192), 3: (4, 128), 4: (2, 192),
+               6: (4, 64) if fp8 else (2, 128)}[n]
+    G, D, T = 2, 32, 3
+    MP = 2 * n + 1
+    lengths = [0, 1, 0, max(n - 1, 1), n, 0, n + 1, MP]  # live pages a slot
+    ends = [0, 5, 0, page, page - 1, 0, page, 7]  # rows of the last one
+    limits = jnp.array([max(c - 1, 0) * page + e
+                        for c, e in zip(lengths, ends)], jnp.int32)
+    B, P = len(lengths), len(lengths) * MP + 1
+    k4, v4 = _pool(jax.random.key(70 + n), P, page, K, D)
+    table = _table(B, MP, P, seed=20 + n)
+    kw = {}
+    if fp8:
+        scales = [2.0, 0.5, 1.25, 0.75, 1.5, 3.0, 0.5, 1.0]
+        kw["kv_scale"] = jnp.asarray([scales[:K], scales[::-1][:K]],
+                                     jnp.float32)
+        k4 = (k4 / kw["kv_scale"][0][:, None]).astype(jnp.float8_e4m3fn)
+        v4 = (v4 / kw["kv_scale"][1][:, None]).astype(jnp.float8_e4m3fn)
+    else:
+        k4, v4 = k4.astype(jnp.bfloat16), v4.astype(jnp.bfloat16)
+    if variant == "nan_unlisted":
+        # every page no live slot lists holds NaN: the columns behind a
+        # slot's last live page, the pool's free pages
+        listed = np.zeros(P, bool)
+        for b, c in enumerate(lengths):
+            listed[np.asarray(table)[b, :c]] = True
+        poison = jnp.asarray(~listed)[:, None, None, None]
+        k4 = jnp.where(poison, jnp.nan, k4).astype(k4.dtype)
+        v4 = jnp.where(poison, jnp.nan, v4).astype(v4.dtype)
+    elif variant == "hier":
+        kw["table"] = _hier_of(table, 3)
+    elif variant == "sliding":
+        kw.update(window=page + page // 2 + 3, sliding=jnp.asarray(True))
+    elif variant == "softcap":
+        kw["softcap"] = 2.5
+    if wrapper == "decode":
+        fn, q = paged_decode_partials, jax.random.normal(
+            jax.random.key(80 + n), (B, K * G, D))
+    else:
+        fn, q = paged_decode_partials_mq, jax.random.normal(
+            jax.random.key(90 + n), (B, T, K * G, D))
+        kw["q_pos"] = limits[:, None] + jnp.arange(T)[None, :]
+    return fn, q, k4, v4, table, limits, kw
+
+
+@pytest.mark.parametrize("variant", ["flat", "hier", "sliding", "fp8_scale",
+                                     "softcap", "nan_unlisted"])
+@pytest.mark.parametrize("wrapper", ["decode", "mq"])
+@pytest.mark.parametrize("n", [1, 3, 6])
+def test_multipage_visit_matches_float64_walk(n, wrapper, variant):
+    """n pages a visit (K = 8 / 4 / 2 at 128-row pages) against the float64
+    walk that takes the same visits, over tails of every length; under
+    `nan_unlisted` every page the walk must not read is NaN, the stale and
+    the never-written part of a ring buffer included (the interpreter
+    hands out NaN scratch)."""
+    fn, q, k4, v4, table, limits, kw = _multipage_case(n, wrapper, variant)
+    # a sum over a thousand rows meets a rounding boundary of some p more
+    # often than one over eighty (3% of acc's entries under the softcap,
+    # whose p are all near 1)
+    got = _check_against_float64_walk(fn, q, k4, v4, table, limits, kw, n,
+                                      flips=0.05)
+    assert all(np.isfinite(np.asarray(g)).all() for g in got)
+
+
+@pytest.mark.parametrize("variant", ["flat", "nan_unlisted"])
+@pytest.mark.parametrize("n", [2, 4])
+def test_multipage_visit_of_192_row_pages(n, variant):
+    """The visits between: 192-row pages at K = 4 / 2 are 2 / 4 a visit."""
+    fn, q, k4, v4, table, limits, kw = _multipage_case(n, "decode", variant)
+    got = _check_against_float64_walk(fn, q, k4, v4, table, limits, kw, n,
+                                      flips=0.05)
+    assert all(np.isfinite(np.asarray(g)).all() for g in got)
+
+
+@pytest.mark.parametrize("n", [3, 6])
+def test_multipage_visit_matches_xla_walk(n):
+    """The same call against the XLA page walk (float32 throughout, a chunk
+    of pages at a time): bfloat16-grade agreement of the settled output."""
+    fn, q, k4, v4, table, limits, kw = _multipage_case(n, "decode", "flat")
+    got = fn(q, k4, v4, table, limits, interpret=True)
+    want = _paged_cache_partials(q, k4, v4, table, limits)
+    live = np.asarray(limits) > 0
+    for g, w in ((got[0] / jnp.maximum(got[2], 1e-30),
+                  want[0] / jnp.maximum(want[2], 1e-30)), (got[1], want[1])):
+        np.testing.assert_allclose(np.asarray(g)[live], np.asarray(w)[live],
+                                   atol=1e-2, rtol=1e-2)
+
+
+@pytest.mark.parametrize("page,K,width,flat,swin,want", [
+    (128, 8, 32, True, 0, 1),  # mistral int8, Solar-Open2's cache layers
+    (128, 16, 32, True, 0, 1),  # OLMoE
+    (128, 4, 32, True, 0, 3),
+    (128, 2, 32, True, 0, 6),  # one chip of tp = 4
+    (128, 1, 32, True, 0, 12),  # one chip of tp = 8
+    (192, 4, 32, True, 0, 2),
+    (192, 2, 32, True, 0, 4),
+    (64, 8, 64, True, 0, 3),
+    (64, 2, 64, True, 0, 12),
+    (16, 2, 5, True, 0, 5),  # never more than the table has columns
+    (256, 8, 16, True, 0, 1),
+    (128, 2, 32, False, 0, 1),  # the per-head form
+    (128, 2, 32, True, 512, 1),  # the cold-middle walk
+])
+def test_visit_rule_sizes_a_visit_in_rows(page, K, width, flat, swin, want):
+    """`page · K` -> pages a visit, and what the ring then holds: at most
+    VISIT_ROWS rows a visit wherever it is more than a page, and at 128-wide
+    bfloat16 heads never more than RING_VMEM_BYTES (a visit of VISIT_ROWS
+    rows is exactly what RING_MAX buffers of it fill)."""
+    from localai_tpu.ops.paged_flash import (
+        RING_VMEM_BYTES, VISIT_ROWS, _ring_depth, _visit_pages)
+
+    n = _visit_pages(page, K, width, flat=flat, swin=swin)
+    assert n == want
+    assert n == 1 or n * page * K <= VISIT_ROWS
+    visit_bytes = n * page * K * (128 + 128) * 2
+    assert _ring_depth(visit_bytes) * visit_bytes <= RING_VMEM_BYTES
+    assert _ring_depth(VISIT_ROWS * (128 + 128) * 2) == 4
 
 
 def _parent_rows(qr, k_pool, v_pool, table, limits):
@@ -1147,18 +1323,23 @@ def test_ring_depth_gives_the_double_buffers_numbers_bit_for_bit(ring, dtype):
                                      1)] == [4, 3, 4, 2, 4]
 
 
-@pytest.mark.parametrize("dtype,key", [("bfloat16", "paged_attention_native"),
-                                       ("float32", "paged_attention_f32")])
-def test_site_counts_tell_the_kernels_arithmetic(dtype, key):
+@pytest.mark.parametrize("dtype,key,K,page,visit", [
+    ("bfloat16", "paged_attention_native", 2, PAGE, "multipage"),
+    ("float32", "paged_attention_f32", 2, PAGE, "onepage"),
+    ("bfloat16", "paged_attention_native", 2, 128, "multipage"),
+    ("bfloat16", "paged_attention_native", 8, 128, "onepage")])
+def test_site_counts_tell_the_kernels_arithmetic(dtype, key, K, page, visit):
     """What a traced kernel call fed its dots is counted with the site
     (ops/stacked.SiteCounts): a narrow pool native, a float32 pool f32, the
-    XLA walk neither."""
+    XLA walk neither. Beside it what a visit held (ISSUE 41): K = 2 several
+    pages, K = 8 at 128-row pages and the per-head form one."""
     from localai_tpu.ops.attention import paged_partials
     from localai_tpu.ops.stacked import SiteCounts
 
-    k4, v4 = _pool(jax.random.key(64), 8, PAGE, 2, 32, jnp.dtype(dtype))
-    table, limits = _table(2, 3, 8, seed=16), jnp.array([20, 40], jnp.int32)
-    q = jax.random.normal(jax.random.key(65), (2, 4, 32))
+    k4, v4 = _pool(jax.random.key(64), 8, page, K, 32, jnp.dtype(dtype))
+    table = _table(2, 3, 8, seed=16)
+    limits = jnp.array([page + 4, 2 * page + 8], jnp.int32)
+    q = jax.random.normal(jax.random.key(65), (2, 2 * K, 32))
     other = ({"paged_attention_native", "paged_attention_f32"} - {key}).pop()
     for impl, n in (("pallas", 1), ("xla", 0)):
         sites = SiteCounts()
@@ -1168,3 +1349,6 @@ def test_site_counts_tell_the_kernels_arithmetic(dtype, key):
         tally = sites.by_program["decode_block"]
         assert (tally[key], tally[other]) == (n, 0)
         assert tally["paged_attention_sliced"] == 1  # a plain pool
+        assert tally[f"paged_attention_{visit}"] == n
+        assert tally["paged_attention_multipage"] + tally[
+            "paged_attention_onepage"] == n
