@@ -332,6 +332,22 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
           table_g, jax.random.randint(next(keys), (hg["slots"],), lo, hi + 1),
           jnp.int32(1)), 6e-3)
 
+    # LFM2's cache layers (lfm2-8b-a1b): 32 rows at 8 KV heads x 4 query
+    # heads of HALF the width, a pool of two heads a 128-lane row
+    # (`ArchConfig.cache_pack`: Mosaic refuses the `[page, 8, 64]` tile as
+    # stored, PERF.md §7 item 6b): the kernel walks the rows as stored with q
+    # in its own head's lanes, the oracle a reshape of the pool to a head a
+    # row. Same rounding -> 6e-3.
+    Dn = D // 2
+    table_n = (jax.random.permutation(next(keys), n_cell - 1) + 1).reshape(
+        rows, cell_pages).astype(jnp.int32)
+    case(f"paged_decode_cell_K8_G4_D{Dn}_two_heads_a_row",
+         kernel_walk, exact_walk,
+         (rnd((rows, 8 * 4, Dn)), rnd((2, n_cell, page, 4, D)),
+          rnd((2, n_cell, page, 4, D)), table_n,
+          jax.random.randint(next(keys), (rows,), lo, hi + 1),
+          jnp.int32(1)), 6e-3)
+
     T = s["verify"]
     qpos = limits[:, None] + jnp.arange(T)[None, :]
 

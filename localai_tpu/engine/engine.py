@@ -2715,7 +2715,9 @@ class Engine:
                 "self_draft) serves adapter tenants"
             )
         if self.cfg.is_mla or self.cfg.is_moe:
-            kind = ("hybrid KDA/MLA" if self.cfg.is_hybrid
+            kind = (f"hybrid {self.cfg.recurrent_kind.upper()}/"
+                    f"{'MLA' if self.cfg.is_mla else 'GQA'}"
+                    if self.cfg.is_hybrid
                     else "MLA" if self.cfg.is_mla else "MoE")
             raise AdapterError(
                 f"runtime LoRA adapters serve dense llama-family bases only "
@@ -4784,9 +4786,10 @@ class Engine:
             raise ValueError("fork n must be >= 1")
         if self.cfg.is_hybrid:
             raise ValueError(
-                f"{self.cfg.name} keeps a per-slot recurrent state (KDA "
-                "layers): forking a live stream would need a copy of the "
-                "source's state row, which this engine does not make")
+                f"{self.cfg.name} keeps a per-slot recurrent state "
+                f"({self.cfg.recurrent_kind} layers): forking a live stream "
+                "would need a copy of the source's state row, which this "
+                "engine does not make")
         if seeds is not None and len(seeds) != n:
             raise ValueError(f"fork got {len(seeds)} seeds for n={n}")
         out = []
@@ -6373,8 +6376,10 @@ class Engine:
                     self.cfg, self.cache.conv.dtype))
             out["state_snapshots"] = 0.0  # rows are dropped, never copied
             out["state_restores"] = float(self.m_state_restores)
-            out["admit_splits"] = float(self.m_admit_splits)
-            out["admit_rows_max"] = float(rstate.admit_rows(self.cfg))
+            bound = rstate.admit_rows(self.cfg)
+            if bound:  # KDA's byte bound on an admission program
+                out["admit_splits"] = float(self.m_admit_splits)
+                out["admit_rows_max"] = float(bound)
             out["prefix_reuse_off"] = float(
                 self.ecfg.prefix_cache_entries > 0)
         # Call sites over every program traced so far: the Pallas kernel read
@@ -7558,10 +7563,10 @@ class Engine:
             # fixed set of M values.
             chunks: list[list[tuple[GenRequest, RequestHandle]]] = [[gh] for gh in special]
             idx = 0
-            # A hybrid model's admission holds its prompts' KDA operands in
+            # A KDA model's admission holds its prompts' KDA operands in
             # float32, every request's at once: bounded bytes a program.
-            m_max = (max(1, rstate.admit_rows(self.cfg) // bucket)
-                     if self.cfg.is_hybrid else len(plain))
+            bound = rstate.admit_rows(self.cfg)
+            m_max = max(1, bound // bucket) if bound else len(plain)
             unbounded = bin(len(plain)).count("1")  # programs without m_max
             while idx < len(plain):
                 m = 1
@@ -8771,8 +8776,8 @@ class Engine:
                     b=float(rows - live))
         if self.cfg.is_hybrid:
             # Every row of the recurrent state is updated every step
-            # (a, over the KDA layers); b of them belonged to a tenant.
-            kl = len(self.cfg.kda_layers)
+            # (a, over the recurrent layers); b of them belonged to a tenant.
+            kl = len(self.cfg.recurrent_layers)
             self._jnote("state_rows", a=float(rows * kl), b=float(live * kl))
 
     # thread: engine-loop-only

@@ -14,6 +14,10 @@ import dataclasses
 from typing import Optional
 
 
+# The layer kinds that keep a per-slot recurrent state and write no cache row.
+RECURRENT_KINDS = ("kda", "conv")
+
+
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     """Shape/hyperparameter description of a Llama-family decoder.
@@ -105,6 +109,9 @@ class ArchConfig:
     scoring_func: str = "softmax"
     router_bias: bool = False  # V3 e_score_correction_bias
     norm_topk_prob: bool = False  # V3: renormalize the selected weights
+    # What the renormalisation adds to the picks' sum (DeepSeek-V3, Kimi-Linear
+    # and Solar-Open2 1e-20; LFM2 1e-6).
+    norm_topk_eps: float = 1e-20
     # Group-limited routing (device-limited in the paper): experts are split
     # into n_group groups; selection is restricted to the topk_group
     # best-scoring groups (V2 scores a group by its max, V3 by the sum of
@@ -180,7 +187,15 @@ class ArchConfig:
     # models/llama._scan_hybrid needs every cache layer to stand beside a
     # "kda" layer, all of them behind theirs or all of them in front, and the
     # dense-prefix layers to be "kda".
+    # "conv" (LFM2's gated short convolution) is the other recurrent kind: in
+    # "kda"'s place everywhere above, its per-slot state the operator's last
+    # conv_cache-1 inputs [conv_cache-1, hidden_size] and nothing else. One
+    # model has one recurrent kind (`recurrent_kind`).
     layer_kinds: tuple = ()
+    # LFM2's conv_L_cache: the taps of the short conv. Neither the taps nor
+    # the two projections have a bias (the published `conv_bias` is false in
+    # every LFM2 config; there is no field for a value nothing here computes)
+    conv_cache: int = 3
     kda_heads: int = 0
     kda_head_dim: int = 128
     kda_conv: int = 4  # short_conv_kernel_size
@@ -209,16 +224,35 @@ class ArchConfig:
         return bool(self.layer_kinds)
 
     @property
-    def kda_layers(self) -> tuple:
-        """Model layer numbers of the KDA layers, in order."""
-        return tuple(i for i, k in enumerate(self.layer_kinds) if k == "kda")
+    def recurrent_kind(self) -> str:
+        """A hybrid model's recurrent kind, "kda" or "conv" ("" = none). A
+        stack that mixes the two is refused here, by name."""
+        kinds = {k for k in self.layer_kinds if k in RECURRENT_KINDS}
+        if len(kinds) > 1:
+            raise NotImplementedError(
+                f"{self.name}: layer_kinds mixes the recurrent kinds "
+                f"{sorted(kinds)}; one model has one (a 'kda' + 'conv' stack "
+                "would need two per-slot states and two scans)")
+        return next(iter(kinds), "")
+
+    @property
+    def recurrent_layers(self) -> tuple:
+        """Model layer numbers of the recurrent (KDA or conv) layers."""
+        return tuple(i for i, k in enumerate(self.layer_kinds)
+                     if k in RECURRENT_KINDS)
+
+    @property
+    def recurrent_stack(self) -> str:
+        """The key of the recurrent layers' weight stack in the param tree."""
+        return f"{self.recurrent_kind}_layers"
 
     @property
     def cache_layer_ids(self) -> tuple:
         """Model layer numbers of the layers that write cache rows."""
         if not self.layer_kinds:
             return tuple(range(self.num_layers))
-        return tuple(i for i, k in enumerate(self.layer_kinds) if k != "kda")
+        return tuple(i for i, k in enumerate(self.layer_kinds)
+                     if k not in RECURRENT_KINDS)
 
     @property
     def cache_stack(self) -> str:
@@ -266,21 +300,37 @@ class ArchConfig:
     # KV cache from these three, so MLA's latent layout (one pseudo-head of
     # [kv_lora_rank + rope] per token, no separate V — values are read back
     # out of the same latent) threads through every cache variant (dense /
-    # windowed / paged / fp8) without per-call-site branches.
+    # windowed / paged / fp8) without per-call-site branches; so does a
+    # narrow-head pool's two heads a row (`cache_pack`).
+    @property
+    def cache_pack(self) -> int:
+        """KV heads a cache row holds side by side. 2 for a hybrid model's
+        64-wide GQA heads: a `[page, K, 64]` tile of a 16-bit pool has no
+        128-lane row and Mosaic refuses it as stored (PERF.md section 7 item
+        6b), so the pool is `[page, K/2, 128]`, head 2i in lanes 0-63 and
+        2i + 1 in 64-127 of row i: the same bytes, the reshape of the rows a
+        layer emits free. Only where the cache layers' rows are read by the
+        decode step's page walk alone (what a hybrid model is held to,
+        engine/state.refuse); every other model keeps a head a row, which its
+        dense cache and its prefix, chunk and verify readers assume."""
+        narrow = (self.is_hybrid and not self.is_mla and self.head_dim_ == 64
+                  and self.num_kv_heads % 2 == 0)
+        return 2 if narrow else 1
+
     @property
     def cache_kv_heads(self) -> int:
-        return 1 if self.is_mla else self.num_kv_heads
+        return 1 if self.is_mla else self.num_kv_heads // self.cache_pack
 
     @property
     def cache_k_dim(self) -> int:
         if not self.is_mla:
-            return self.head_dim_
+            return self.head_dim_ * self.cache_pack
         w = self.kv_lora_rank + self.qk_rope_head_dim
         return -(-w // self.latent_pad) * self.latent_pad if self.latent_pad else w
 
     @property
     def cache_v_dim(self) -> int:
-        return 0 if self.is_mla else self.head_dim_
+        return 0 if self.is_mla else self.head_dim_ * self.cache_pack
 
     @property
     def moe_inter_size(self) -> int:
@@ -435,6 +485,38 @@ PRESETS: dict[str, ArchConfig] = {
         scoring_func="sigmoid",
         router_bias=True,
         norm_topk_prob=True,
+    ),
+    "tiny-lfm2": ArchConfig(
+        # LFM2-MoE-shaped tiny: the published pattern cut to its 2 dense conv
+        # layers and one whole period (attention, conv, conv, conv), heads of
+        # the published 64 (so the pool holds two a row, `cache_pack`),
+        # per-head q/k norms, rope, a tied head, sigmoid router with a
+        # selection bias, renormalised with 1e-6 and unscaled, no shared
+        # expert.
+        name="tiny-lfm2",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=6,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=64,
+        max_position=512,
+        rope_theta=1000000.0,
+        tie_embeddings=True,
+        qk_norm=True,
+        layer_kinds=("conv", "conv", "gqa", "conv", "conv", "conv"),
+        conv_cache=3,
+        moe_family="deepseek",
+        num_experts=8,
+        num_experts_per_token=2,
+        first_k_dense=2,
+        moe_intermediate_size=32,
+        routed_scaling_factor=1.0,
+        scoring_func="sigmoid",
+        router_bias=True,
+        norm_topk_prob=True,
+        norm_topk_eps=1e-6,
     ),
     "llama-3.2-1b": ArchConfig(
         name="llama-3.2-1b",
@@ -679,6 +761,41 @@ PRESETS: dict[str, ArchConfig] = {
         norm_topk_prob=True,
         n_group=1,
         topk_group=1,
+    ),
+    "lfm2-8b-a1b": ArchConfig(
+        # LiquidAI/LFM2-8B-A1B config.json (`lfm2_moe`, 8.3B-A1.5B): 24
+        # layers, 18 gated short convolutions (`conv_L_cache` 3, no bias) and
+        # 6 GQA layers (32 query / 8 KV heads of 64, per-head q/k norms, rope
+        # 1e6) at 2, 6, 10, 14, 18, 21; layers 0-1 a dense SwiGLU of 7168,
+        # the other 22 with 32 experts of 1792 top-4 (sigmoid, selection
+        # bias, renormalised with 1e-6, x1), no shared expert; tied head.
+        name="lfm2-8b-a1b",
+        vocab_size=65536,
+        hidden_size=2048,
+        intermediate_size=7168,
+        num_layers=24,
+        num_heads=32,
+        num_kv_heads=8,
+        head_dim=64,
+        rope_theta=1000000.0,
+        max_position=128000,
+        rms_eps=1e-5,
+        tie_embeddings=True,
+        qk_norm=True,
+        layer_kinds=tuple(
+            "gqa" if i in (2, 6, 10, 14, 18, 21) else "conv"
+            for i in range(24)),
+        conv_cache=3,
+        moe_family="deepseek",
+        num_experts=32,
+        num_experts_per_token=4,
+        first_k_dense=2,
+        moe_intermediate_size=1792,
+        routed_scaling_factor=1.0,
+        scoring_func="sigmoid",
+        router_bias=True,
+        norm_topk_prob=True,
+        norm_topk_eps=1e-6,
     ),
 }
 

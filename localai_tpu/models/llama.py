@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from localai_tpu.models import quant
 from localai_tpu.models.config import ArchConfig
 from localai_tpu.models.quant import matmul, unembed_matmul
-from localai_tpu.observe.scopes import scope
+from localai_tpu.observe.scopes import CONV_MIX, scope
 from localai_tpu.ops.attention import (
     _merge_partials_mq,
     decode_attention,  # noqa: F401 — public, used by tests/benchmarks
@@ -83,9 +83,11 @@ class KVCache(NamedTuple):
     # A hybrid model's (cfg.is_hybrid) per-slot recurrent state rides with
     # the cache through every program that carries it, donated with it:
     # state [Lk, B_slots, H, dk, dv] f32 and conv [Lk, B_slots, conv-1,
-    # 3·H·dk] (the short conv's last inputs), Lk the KDA layers; k/v then
-    # hold rows for the cache_layers only (latent rows under MLA, the
-    # ordinary [K, Hd] keys and values otherwise). None everywhere else.
+    # 3·H·dk] (the short conv's last inputs), Lk the KDA layers; a "conv"
+    # model's row is conv [Lc, B_slots, conv_cache-1, D] alone, its state
+    # None; k/v then hold rows for the cache_layers only (latent rows under
+    # MLA, the ordinary [K, Hd] keys and values otherwise). None everywhere
+    # else.
     state: Any = None
     conv: Any = None
 
@@ -217,6 +219,20 @@ def _init_kda_layers(cfg: ArchConfig, rnd, keys, L: int) -> Params:
     }
 
 
+def _init_conv_layers(cfg: ArchConfig, rnd, keys, L: int) -> Params:
+    """The stack of a hybrid model's gated short convolutions (LFM2): the
+    in-projection to [b | c | z] and the out-projection int8-able, the
+    depthwise taps in the model dtype."""
+    D = cfg.hidden_size
+    return {
+        "w_in": rnd(next(keys), (L, D, 3 * D)),
+        # tap conv_cache-1 on the current token, no bias
+        "conv_w": init_special(
+            "conv_w", next(keys), (L, cfg.conv_cache, D)).astype(_dtype(cfg)),
+        "wo": rnd(next(keys), (L, D, D)),
+    }
+
+
 def init_params(cfg: ArchConfig, key: jnp.ndarray, scale: float = 0.02) -> Params:
     """Random init with HF-compatible tree structure (stacked layers).
 
@@ -270,8 +286,11 @@ def init_params(cfg: ArchConfig, key: jnp.ndarray, scale: float = 0.02) -> Param
         params["lm_head"] = rnd(next(keys), (cfg.vocab_size, D))
     if cfg.is_hybrid:
         hk = iter(jax.random.split(jax.random.fold_in(key, 1), 32))
-        params["kda_layers"] = _init_kda_layers(
-            cfg, rnd, hk, len(cfg.kda_layers))
+        Lr = len(cfg.recurrent_layers)
+        if cfg.recurrent_kind == "conv":
+            params["conv_layers"] = _init_conv_layers(cfg, rnd, hk, Lr)
+        else:
+            params["kda_layers"] = _init_kda_layers(cfg, rnd, hk, Lr)
         cache_stack = cfg.cache_stack  # "mla_layers" | "gqa_layers"
         params[cache_stack] = _init_attn_layers(
             cfg, rnd, hk, cfg.cache_layers, cache_stack=True)
@@ -443,7 +462,8 @@ def _deepseek_route(cfg: ArchConfig, lp: Params, x: jnp.ndarray):
     _, sel = jax.lax.top_k(choice, k)
     weights = jnp.take_along_axis(scores, sel, axis=-1)
     if cfg.norm_topk_prob and k > 1:
-        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (weights.sum(axis=-1, keepdims=True)
+                             + cfg.norm_topk_eps)
         # Original DeepseekV2MoEGate: normalization REPLACES the scaling
         # factor on the softmax path; V3 (sigmoid) normalizes AND scales.
         if sigmoid:
@@ -1137,7 +1157,9 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
         attn = attn.reshape(*h.shape[:-1], -1)
         if cfg.attn_gate:
             attn = _attn_gated(cfg, lp, x, attn, mesh)
-        emit = (k, v)
+        # as the cache holds them: `cache_pack` heads a row (a free reshape)
+        emit = tuple(a.reshape(*a.shape[:-2], cfg.cache_kv_heads, -1)
+                     for a in (k, v)) if cfg.cache_pack > 1 else (k, v)
     h = h + _attn_out(cfg, lp, attn, mesh, lora=lora)
     x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
     return h + _mlp_out(cfg, lp, x, ep, mesh, lora=lora, picks=picks,
@@ -1145,16 +1167,19 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
 
 
 # --------------------------------------------------------------------------- #
-# Hybrid linear attention (Kimi-Linear, Solar-Open2): KDA layers with a
-# per-slot recurrent state beside layers that write cache rows, MLA's latent
-# ones or GQA's ordinary keys and values (cfg.layer_kinds).
+# Hybrid models (Kimi-Linear, Solar-Open2, LFM2): recurrent layers with a
+# per-slot state beside layers that write cache rows, MLA's latent ones or
+# GQA's ordinary keys and values (cfg.layer_kinds).
 #
-# A KDA layer keeps, per slot, a [H, dk, dv] float32 state and the short
-# conv's last inputs; it writes no cache row. The two kinds' weights live in
-# their own stacks ("kda_layers", `cfg.cache_stack`), the norms and the MLPs
-# in the model's layer stacks as ever. `_scan_hybrid` scans the KDA layers
-# and runs the cache layer that stands beside one under a `lax.cond`: the
-# recurrent state is carried by the scan and never enters the conditional.
+# A recurrent layer writes no cache row. Of the model's one recurrent kind
+# (`cfg.recurrent_kind`) a KDA layer keeps, per slot, a [H, dk, dv] float32
+# state and the short conv's last inputs; a gated short convolution ("conv")
+# its last conv_cache-1 inputs and nothing else. The two kinds' weights live
+# in their own stacks (`cfg.recurrent_stack`, `cfg.cache_stack`), the norms
+# and the MLPs in the model's layer stacks as ever. `_scan_hybrid` scans the
+# recurrent layers and runs the cache layer that stands beside one under a
+# `lax.cond`: the recurrent state is carried by the scan and never enters
+# the conditional.
 # --------------------------------------------------------------------------- #
 
 
@@ -1255,24 +1280,92 @@ def _kda_prefill_mix(cfg: ArchConfig, ap: Params, x, lengths, rec, j, slots):
     return y, rec
 
 
+@scope("attention/proj")
+def _conv_inputs(cfg: ArchConfig, ap: Params, x: jnp.ndarray, conv_prev):
+    """x [B, T, D] (normed) -> (c [B, T, D], window [B, conv_cache-1+T, D]):
+    [b | c | z] = x W_in, the conv's inputs u = b ⊙ z behind those before x's
+    first token (`conv_prev`, zeros at a prompt's start). u is rounded to the
+    rows' type here, so that a token reads the same inputs whether they come
+    from this window or, a step later, from its slot's rows."""
+    f32 = jnp.float32
+    b, c, z = jnp.split(matmul(x, ap["w_in"], cfg.quant_kernel), 3, axis=-1)
+    u = (b.astype(f32) * z.astype(f32)).astype(x.dtype)
+    return c, jnp.concatenate([conv_prev.astype(u.dtype), u], axis=1)
+
+
+def _conv_out(cfg: ArchConfig, ap: Params, c, window):
+    """The gated short convolution itself and its out-projection: v_t = the
+    conv_cache taps over u_{t-conv_cache+1..t} (depthwise, causal, no
+    activation), y = (c ⊙ v) W_out."""
+    f32 = jnp.float32
+    T = c.shape[1]
+    with scope("attention/mix"):
+        w = ap["conv_w"].astype(f32)  # [conv_cache, D]
+        v = sum(window[:, i:i + T].astype(f32) * w[i]
+                for i in range(cfg.conv_cache))
+        y = (c.astype(f32) * v).astype(c.dtype)
+    with scope("attention/out"):
+        return matmul(y, ap["wo"], cfg.quant_kernel)
+
+
+@jax.named_scope(CONV_MIX)
+def _conv_decode_mix(cfg: ArchConfig, ap: Params, x, rec, j):
+    """One token per slot: x [B, D], rec = (None, conv) with conv the rows
+    stacked over the conv layers, j this layer's index in them. Returns
+    (y [B, D], rec). The operator whole is written under `CONV_MIX`, around
+    its leaves: XLA names a fusion after any op in it (on the chip the taps
+    and the gate after W_out's reshape), so only a word every op of the
+    operator carries is still there to read."""
+    _, conv = rec
+    with scope("attention/proj"), jax.named_scope("layer_conv_rows"):
+        prev = jax.lax.dynamic_index_in_dim(conv, j, 0, keepdims=False)
+    c, window = _conv_inputs(cfg, ap, x[:, None], prev)
+    with scope("attention/cache_write"):
+        conv = jax.lax.dynamic_update_index_in_dim(
+            conv, window[:, 1:].astype(conv.dtype), j, 0)
+    return _conv_out(cfg, ap, c, window)[:, 0], (None, conv)
+
+
+@jax.named_scope(CONV_MIX)
+def _conv_prefill_mix(cfg: ArchConfig, ap: Params, x, lengths, rec, j, slots):
+    """Whole prompts from an empty row: x [B, T, D] right-padded to
+    `lengths`. With rec = (None, conv) the conv's inputs at each prompt's
+    last conv_cache-1 tokens are written to rows `slots` [B] of layer j.
+    Returns (y [B, T, D], rec)."""
+    n = cfg.conv_cache - 1
+    with scope("attention/proj"):
+        zeros = jnp.zeros((x.shape[0], n, cfg.hidden_size), x.dtype)
+    c, window = _conv_inputs(cfg, ap, x, zeros)
+    if rec is not None:
+        with scope("attention/cache_write"):
+            # the conv's inputs at tokens len-n .. len-1 (zeros before 0)
+            rows = jnp.take_along_axis(
+                window, (lengths[:, None] + jnp.arange(n)[None, :])[..., None],
+                axis=1)
+            conv = rec[1].at[j, slots].set(rows.astype(rec[1].dtype))
+        rec = (None, conv)
+    return _conv_out(cfg, ap, c, window), rec
+
+
 def _hybrid_tables(cfg: ArchConfig):
-    """Static layout of a hybrid stack: the KDA layers' model layer numbers,
-    for each the index (among the cache layers) of the cache layer that
-    stands beside it (or -1), how many KDA layers carry the dense-prefix
-    MLPs, the dense prefix's length, and whether a cache layer stands IN
-    FRONT of its KDA layer (a period that begins with it: Solar-Open2) or
-    behind it (one that ends with it: Kimi-Linear)."""
+    """Static layout of a hybrid stack: the recurrent layers' model layer
+    numbers, for each the index (among the cache layers) of the cache layer
+    that stands beside it (or -1), how many recurrent layers carry the
+    dense-prefix MLPs, the dense prefix's length, and whether a cache layer
+    stands IN FRONT of its recurrent layer (a period that begins with it:
+    Solar-Open2, LFM2) or behind it (one that ends with it: Kimi-Linear)."""
     import numpy as np
 
-    kl = list(cfg.kda_layers)
+    rk = cfg.recurrent_kind  # refuses a stack of two recurrent kinds
+    kl = list(cfg.recurrent_layers)
     ml = list(cfg.cache_layer_ids)
     kd = cfg.first_k_dense if cfg.is_moe else 0
     kind = "mla" if cfg.is_mla else "gqa"
     ok = (len(cfg.layer_kinds) == cfg.num_layers and bool(kl)
-          and all(k in ("kda", kind) for k in cfg.layer_kinds)
+          and all(k in (rk, kind) for k in cfg.layer_kinds)
           and not any(l < kd for l in ml))
     for lead in (False, True) if ok else ():
-        step = -1 if lead else 1  # where a KDA layer's cache layer stands
+        step = -1 if lead else 1  # where a recurrent layer's cache layer stands
         beside = [ml.index(l + step) if l + step in ml else -1 for l in kl]
         covered = set(kl) | {l + step for l, m in zip(kl, beside) if m >= 0}
         if (covered == set(range(cfg.num_layers))
@@ -1282,34 +1375,36 @@ def _hybrid_tables(cfg: ArchConfig):
                     nd, kd, lead)
     raise NotImplementedError(
         f"{cfg.name}: layer_kinds {cfg.layer_kinds} — every {kind!r} layer "
-        "has to stand beside a 'kda' layer of its own, all of them behind "
-        "theirs or all of them in front, and the dense-prefix layers have "
-        "to be 'kda' (models/llama._scan_hybrid)")
+        f"has to stand beside a {rk or 'kda'!r} layer of its own, all of "
+        "them behind theirs or all of them in front, and the dense-prefix "
+        f"layers have to be {rk or 'kda'!r} (models/llama._scan_hybrid)")
 
 
-def _scan_hybrid(cfg: ArchConfig, params: Params, h, rec, kda_fn, cache_fn,
+def _scan_hybrid(cfg: ArchConfig, params: Params, h, rec, rec_fn, cache_fn,
                  cache_zero, extras=()):
-    """The layer stack of a hybrid model: ONE scan over its KDA layers, each
-    with the cache layer that stands beside it (`lax.cond`) where there is
-    one: behind it, or in front of it where the model's periods begin with
-    their cache layer (`_hybrid_tables`).
+    """The layer stack of a hybrid model: ONE scan over its recurrent layers
+    (KDA or conv, `cfg.recurrent_kind`), each with the cache layer that
+    stands beside it (`lax.cond`) where there is one: behind it, or in front
+    of it where the model's periods begin with their cache layer
+    (`_hybrid_tables`).
 
-    kda_fn(h, rec, lp, j) -> (h, rec, out): a KDA layer and its MLP; lp its
-    weights (the KDA stack's and the layer stack's, one dict), j its index
-    among the KDA layers. rec is whatever the entry point carries through
-    them (the recurrent state), never passed into the conditional.
+    rec_fn(h, rec, lp, j) -> (h, rec, out): a recurrent layer and its MLP; lp
+    its weights (the recurrent stack's and the layer stack's, one dict), j
+    its index among the recurrent layers. rec is whatever the entry point
+    carries through them (the recurrent state), never passed into the
+    conditional.
     cache_fn(h, lp, m, ex) -> (h, out): a cache layer (MLA or GQA) and its
     MLP; m its index among the cache layers, ex the `extras` (arrays stacked
     over the cache layers: the cache) as `_scan_stack` would hand them on.
     cache_zero(h) is `out` of a layer that is not there.
-    Returns (h, rec, KDA outs stacked over the KDA layers, cache-layer outs
-    stacked over the cache layers)."""
+    Returns (h, rec, the recurrent layers' outs stacked over them,
+    cache-layer outs stacked over the cache layers)."""
     kl, beside, nd, kd, lead = _hybrid_tables(cfg)
     kl_t, beside_t = jnp.asarray(kl), jnp.asarray(beside)
 
     def run(h, rec, lo, hi, stack, off, with_cache):
         def cache_layer(h, j, li):
-            """The cache layer beside KDA layer j (model layer li), if any:
+            """The cache layer beside recurrent layer j (model layer li), if any:
             model layer li - 1 where it leads, li + 1 where it follows."""
             m = beside_t[j]
 
@@ -1331,9 +1426,9 @@ def _scan_hybrid(cfg: ArchConfig, params: Params, h, rec, kda_fn, cache_fn,
             if with_cache and lead:
                 h, out_m = cache_layer(h, j, li)
             with jax.named_scope("layer_weights"):
-                lp = {**_take_layer(params["kda_layers"], j),
+                lp = {**_take_layer(params[cfg.recurrent_stack], j),
                       **_take_layer(stack, li - off)}
-            h, rec, out_k = kda_fn(h, rec, lp, j)
+            h, rec, out_k = rec_fn(h, rec, lp, j)
             if not with_cache:
                 return (h, rec, j + 1), (out_k, None)
             if not lead:
@@ -1376,11 +1471,12 @@ def _walk_rows(admit) -> jnp.ndarray:
     return admit[0] if admit else jnp.zeros((2,), jnp.int32)
 
 
-def _hybrid_layer_fns(cfg: ArchConfig, kda_mix, *, pos, inv, attend,
+def _hybrid_layer_fns(cfg: ArchConfig, rec_mix, *, pos, inv, attend,
                       mla_full: bool, ep: int, mesh, count: bool,
                       admit: bool = False):
-    """(kda_fn, cache_fn, cache_zero) for `_scan_hybrid` from an entry point's
-    `kda_mix(lp, x, rec, j) -> (y, rec)` and its cache layers' `attend`. The
+    """(rec_fn, cache_fn, cache_zero) for `_scan_hybrid` from an entry point's
+    `rec_mix(lp, x, rec, j) -> (y, rec)` (its recurrent kind's mixer, KDA's
+    or the gated short conv's) and its cache layers' `attend`. The
     cache layer, MLA or GQA, is `_decoder_layer` itself. With `count` each
     layer's out ends with its rows per held expert or, from an admission
     entry point (`admit`), with its grouped kernel's rows (`_walk_rows`)."""
@@ -1395,9 +1491,9 @@ def _hybrid_layer_fns(cfg: ArchConfig, kda_mix, *, pos, inv, attend,
             return _walk_rows(kw["admit"])
         return _expert_counts(cfg, kw["picks"])
 
-    def kda_fn(h, rec, lp, j):
+    def rec_fn(h, rec, lp, j):
         kw = receiver()
-        y, rec = kda_mix(lp, rms_norm(h, lp["attn_norm"], cfg.rms_eps), rec, j)
+        y, rec = rec_mix(lp, rms_norm(h, lp["attn_norm"], cfg.rms_eps), rec, j)
         h = h + y
         x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
         h = h + _mlp_out(cfg, lp, x, ep, mesh, **kw)
@@ -1420,7 +1516,7 @@ def _hybrid_layer_fns(cfg: ArchConfig, kda_mix, *, pos, inv, attend,
             out = out + (jnp.zeros((n,), jnp.int32),)
         return out
 
-    return kda_fn, cache_fn, cache_zero
+    return rec_fn, cache_fn, cache_zero
 
 
 @scope("attention/rope")
@@ -1523,22 +1619,25 @@ def _forward_hidden(
     if cfg.is_hybrid:
         if use_ring or lora is not None or mrope is not None:
             raise NotImplementedError(
-                f"{cfg.name}: a hybrid (KDA) model prefills without sequence "
-                "parallelism, runtime LoRA and m-rope")
+                f"{cfg.name}: a hybrid ({cfg.recurrent_kind}) model prefills "
+                "without sequence parallelism, runtime LoRA and m-rope")
         slots = None
         if recurrent is not None:
             *rec, slots = recurrent
             rec = tuple(rec)
 
-        def kda_mix(lp, x, rec, j):
-            return _kda_prefill_mix(cfg, lp, x, lengths, rec, j, slots)
+        mix = (_conv_prefill_mix if cfg.recurrent_kind == "conv"
+               else _kda_prefill_mix)
+
+        def rec_mix(lp, x, rec, j):
+            return mix(cfg, lp, x, lengths, rec, j, slots)
 
         h, rec, walked, kv = _scan_hybrid(
             cfg, params, h, rec, *_hybrid_layer_fns(
-                cfg, kda_mix, pos=positions, inv=(inv_freq, inv_local),
+                cfg, rec_mix, pos=positions, inv=(inv_freq, inv_local),
                 attend=attend, mla_full=True, ep=ep, mesh=mesh,
                 count=expert_rows, admit=True))
-        if expert_rows:  # the KDA layers' MLPs, then the cache layers'
+        if expert_rows:  # the recurrent layers' MLPs, then the cache layers'
             *kv, walked_m = kv
             walked = (walked, walked_m)
         kv = tuple(kv) if collect_kv else None
@@ -1732,7 +1831,8 @@ def decode_step_windowed(
     # LoRA deltas applied unmerged beside the base matmuls (ISSUE 10)
     expert_rows: bool = False,  # also return the router's rows per expert
     recurrent=None,  # hybrid models: (state, conv), the per-slot recurrent
-    # state of the KDA layers; updated in place, returned LAST
+    # state of the KDA layers ((None, conv) of conv layers); updated in
+    # place, returned LAST
     kda_impl: str = "auto",  # KDA decode kernel: auto|pallas|xla
 ):
     """One step of a fused decode block with a block-local KV window.
@@ -1800,18 +1900,21 @@ def decode_step_windowed(
     if cfg.is_hybrid:
         if recurrent is None or lora is not None or use_sp:
             raise NotImplementedError(
-                f"{cfg.name}: a hybrid (KDA) model decodes with its recurrent "
-                "state, without runtime LoRA and sequence parallelism")
+                f"{cfg.name}: a hybrid ({cfg.recurrent_kind}) model decodes "
+                "with its recurrent state, without runtime LoRA and sequence "
+                "parallelism")
 
-        def kda_mix(lp, x, rec, j):
+        def rec_mix(lp, x, rec, j):
+            if cfg.recurrent_kind == "conv":
+                return _conv_decode_mix(cfg, lp, x, rec, j)
             return _kda_decode_mix(cfg, lp, x, rec, j, impl=kda_impl)
 
         h, recurrent, rows_k, (new_k, new_v, *rows_e) = _scan_hybrid(
             cfg, params, h, tuple(recurrent), *_hybrid_layer_fns(
-                cfg, kda_mix, pos=rope_pos, inv=inv, attend=attend,
+                cfg, rec_mix, pos=rope_pos, inv=inv, attend=attend,
                 mla_full=False, ep=ep, mesh=mesh, count=expert_rows),
             extras=extras)
-        if expert_rows:  # KDA layers' MLPs, then the cache layers'
+        if expert_rows:  # recurrent layers' MLPs, then the cache layers'
             with scope("mlp/router"):
                 rows_e = [jnp.concatenate([rows_k, rows_e[0]], axis=0)]
             routed.extend([True] * cfg.is_moe)

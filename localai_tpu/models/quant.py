@@ -49,15 +49,16 @@ Params = dict[str, Any]
 # w_kb/w_vb (MLA latent up-projections) stay unquantized: they ride
 # einsum paths with no grouped-int kernel and are small next to the MoE.
 QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "wg", "w_gate", "w_up", "w_down",
-                    "wq_a", "wq_b", "wkv_a",
+                    "wq_a", "wq_b", "wkv_a", "w_in",
                     "shared_gate", "shared_up", "shared_down")
 
 
 # The per-layer stacks of a param tree: the model's layers, DeepSeek's dense
-# prefix, and a hybrid model's two attention kinds: its KDA layers and its
-# cache layers, MLA or GQA (models/llama.py, `ArchConfig.cache_stack`).
-LAYER_STACKS = ("layers", "dense_layers", "kda_layers", "mla_layers",
-                "gqa_layers")
+# prefix, and a hybrid model's two attention kinds: its recurrent layers (KDA
+# or the gated short conv) and its cache layers, MLA or GQA (models/llama.py,
+# `ArchConfig.recurrent_stack`, `.cache_stack`).
+LAYER_STACKS = ("layers", "dense_layers", "kda_layers", "conv_layers",
+                "mla_layers", "gqa_layers")
 
 
 def quantize_tensor(w: jnp.ndarray) -> dict[str, jnp.ndarray]:

@@ -38,12 +38,14 @@ SCOPES = (
     #                  host control unpacked, positions advanced, per-slot
     #                  token/position rows written, routing sums, the step loop
     "attention/proj",         # q/k/v, the MLA pair, KDA's q/k/v/g/beta/gate
-    #                           projections and its short conv, q/k norms
+    #                           projections and its short conv, q/k norms,
+    #                           the gated short conv's [b | c | z] and b ⊙ z
     "attention/rope",         # the rotation (GQA; MLA rotates inside proj)
     "attention/mix",          # the token mixer: paged_attention,
     #                           latent_paged_attention, flash_prefill, the XLA
     #                           softmax and the block-window merge, kda_decode,
-    #                           the chunkwise KDA prefill
+    #                           the chunkwise KDA prefill, the gated short
+    #                           conv's taps and gate
     "attention/cache_write",  # K/V rows into pool, window or dense cache;
     #                           recurrent state and conv rows into their slots
     "attention/out",          # gate, un-latent, per-head norm, output projection
@@ -57,6 +59,17 @@ SCOPES = (
 # Per-layer slices out of stacked arrays; they nest inside a leaf and are
 # read as "slices" wherever they occur in a path.
 SLICES = ("layer_weights", "layer_kv_pool", "layer_conv_rows", "layer_state")
+
+
+# The gated short convolution (LFM2), the operator whole: its two matmuls,
+# u = b * z, the rows read and written, the taps and the gate, written AROUND
+# their leaves (`conv_mix/attention/proj/...`), the name a reader of a capture
+# tells the conv layers' operator from the cache layers' by. Around and not
+# inside one leaf: XLA names a fusion after any op in it, and on the chip the
+# taps and the gate read `attention/out`. No leaf of its own: `SCOPES` is
+# pinned to the benchmark's reader, which drops the word like any other it
+# does not know and books each op to its leaf.
+CONV_MIX = "conv_mix"
 
 
 def scope(leaf: str):

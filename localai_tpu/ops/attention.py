@@ -691,13 +691,24 @@ def paged_partials(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
     whole [L, P, page, K, D] pool (`_paged_pools`). `latent`: the caller's
     pool holds MLA's latent rows laid out for the latent kernel (llama's
     decode step says so from `cfg.latent_pad`); the XLA walk reads such a
-    pool as any other."""
+    pool as any other. A pool whose rows are wider than q's heads holds
+    several heads a row (`ArchConfig.cache_pack`: [P, page, K/p, p·D]): the
+    kernel walks it as stored (`paged_decode_partials`), the XLA walk a
+    reshape of it."""
     import functools
 
     from localai_tpu.ops.paged_flash import paged_decode_partials, use_pallas
 
     pallas = use_pallas(impl)
     k_pool, v_pool = _paged_pools(k_pool, v_pool, pallas)
+    packed = not latent and k_pool.shape[-1] != q.shape[-1]
+    if packed and _tp_degree(mesh) > 1:
+        raise NotImplementedError(
+            "a pool of several heads a row (ArchConfig.cache_pack) is read "
+            "at tp = 1")
+    if packed and not pallas:  # the XLA walk reads a head a row
+        k_pool, v_pool = (a.reshape(*a.shape[:2], -1, q.shape[-1])
+                          for a in (k_pool, v_pool))
     if pallas:
         interp = jax.default_backend() != "tpu"
         if latent and _tp_degree(mesh) > 1:
@@ -835,6 +846,9 @@ def decode_attention_windowed_paged(
     rows [0, block_start), dense merge of the (tiny) local window + current
     token."""
     n = k_local.shape[1]
+    # a window of several heads a row (`ArchConfig.cache_pack`), a head a row
+    k_local, v_local = (a.reshape(*a.shape[:2], *new.shape[1:])
+                        for a, new in ((k_local, k_new), (v_local, v_new)))
     acc, m, l = paged_partials(
         q, k_pool, v_pool, table, positions - step,
         softcap=softcap, window=window, sliding=sliding, q_pos=positions,

@@ -769,7 +769,9 @@ def paged_decode_partials(
             raise ValueError("the latent paged kernel has no dequant scale, "
                              "softcap or window")
         return latent_paged_attention(q, k_pool, table, limits, interpret)
-    K = k_pool.shape[2]
+    Kp, Dp = k_pool.shape[-2:]  # as stored: `pack` heads a row
+    pack = Dp // D
+    K = Kp * pack
     G = H // K
     scale = 1.0 / (D**0.5)
     if q_pos is None:
@@ -777,12 +779,36 @@ def paged_decode_partials(
     if sliding is None:
         window = 0
     qr = (q.astype(jnp.float32) * scale).reshape(B, K, G, D)
-    qpos_rows = jnp.broadcast_to(q_pos[:, None], (B, G))
-    return _paged_partials_rows(
-        qr, qpos_rows, k_pool, v_pool, table, limits,
-        softcap, window, sliding, interpret, kv_scale=kv_scale,
-        sink=sink, swin=swin,
+    if pack == 1:
+        qpos_rows = jnp.broadcast_to(q_pos[:, None], (B, G))
+        return _paged_partials_rows(
+            qr, qpos_rows, k_pool, v_pool, table, limits,
+            softcap, window, sliding, interpret, kv_scale=kv_scale,
+            sink=sink, swin=swin,
+        )
+    # Narrow heads, `pack` of them a row of the pool (ArchConfig.cache_pack:
+    # row i of a token holds heads pack·i .. pack·i + pack - 1 side by side,
+    # a 128-lane row where one 64-wide head is none and Mosaic refuses the
+    # page as stored). The walk is the wide-head walk over Kp row-heads: the
+    # pack·G query rows of a row-head carry q in their own head's lanes and
+    # zeros in the others, so a row's score is its own head's and `p @ V`
+    # holds its head's sum in the same lanes, which are cut out here. The
+    # kernel and its bytes are those of Kp heads of width pack·D.
+    if kv_scale is not None:
+        raise NotImplementedError("several heads a row: no dequant scales")
+    Dv = v_pool.shape[-1] // pack
+    own = jnp.eye(pack, dtype=qr.dtype)  # [head j of the row, lanes' part]
+    qp = jnp.einsum("bkjgd,ji->bkjgid", qr.reshape(B, Kp, pack, G, D), own)
+    acc, m, l = _paged_partials_rows(
+        qp.reshape(B, Kp, pack * G, Dp),
+        jnp.broadcast_to(q_pos[:, None], (B, pack * G)), k_pool, v_pool,
+        table, limits, softcap, window, sliding, interpret, sink=sink,
+        swin=swin,
     )
+    acc = jnp.einsum("bkjgid,ji->bkjgd",
+                     acc.reshape(B, Kp, pack, G, pack, Dv), own)
+    return (acc.reshape(B, K, G, Dv), m.reshape(B, K, G, 1),
+            l.reshape(B, K, G, 1))
 
 
 def paged_decode_partials_mq(

@@ -142,15 +142,19 @@ def param_specs(cfg: ArchConfig) -> Params:
     if not cfg.tie_embeddings:
         specs["lm_head"] = P("tp", None)
     if cfg.is_hybrid:
-        # A hybrid model serves at tp = 1 (the engine refuses more): its KDA
-        # stack is replicated, its cache layers' stack sharded as any
-        # model's of their kind.
-        specs["kda_layers"] = {
-            **{n: P(None, None, None) for n in (
-                "wq", "wk", "wv", "wo", "conv_w", "f_down", "f_up", "w_beta",
-                "g_down", "g_up")},
-            **{n: P(None, None) for n in ("dt_bias", "A_log", "o_norm")},
-        }
+        # A hybrid model serves at tp = 1 (the engine refuses more): its
+        # recurrent stack is replicated, its cache layers' stack sharded as
+        # any model's of their kind.
+        if cfg.recurrent_kind == "conv":
+            specs["conv_layers"] = {
+                n: P(None, None, None) for n in ("w_in", "conv_w", "wo")}
+        else:
+            specs["kda_layers"] = {
+                **{n: P(None, None, None) for n in (
+                    "wq", "wk", "wv", "wo", "conv_w", "f_down", "f_up",
+                    "w_beta", "g_down", "g_up")},
+                **{n: P(None, None) for n in ("dt_bias", "A_log", "o_norm")},
+            }
         specs[cfg.cache_stack] = _attn_specs(cfg, cache_stack=True)
     return specs
 
@@ -237,7 +241,8 @@ def _tp_violation(cfg: ArchConfig, tp: int) -> Optional[str]:
     if cfg.is_hybrid and tp > 1:
         # So an auto plan degrades to 1 (max_valid_tp) and only an engine
         # handed tp > 1 outright is refused (engine/state.py).
-        return (f"{cfg.name} keeps a per-slot recurrent state (KDA layers) "
+        return (f"{cfg.name} keeps a per-slot recurrent state "
+                f"({cfg.recurrent_kind} layers) "
                 f"that is not sharded: tp={tp} > 1")
     if not cfg.is_mla and cfg.num_kv_heads % tp != 0:
         # MLA has no per-head kv cache to shard — the latent replicates and

@@ -92,7 +92,7 @@ def test_engine_agrees_with_the_plain_reference(served):
     errs = _worst(eng, params, CFG, prompts, 9)
     assert C.verdict(errs, TOLERANCE), errs
     m = eng.metrics()
-    kl = len(CFG.kda_layers)
+    kl = len(CFG.recurrent_layers)
     assert m["recurrent_state_bytes"] == 2 * kl * (
         4 * 16 * 16 * 4 + 3 * 3 * 64 * 4)
     assert m["state_snapshots"] == 0 and m["prefix_reuse_off"] == 1
@@ -408,8 +408,8 @@ def test_decode_step_slices_no_layer_out_of_the_state():
     params = _seeded()
     B, n = 2, 4
     pool = L.paged_cache_zeros(cfg, 5, 16)
-    state = jnp.zeros((len(cfg.kda_layers), B, 4, 16, 16), jnp.float32)
-    conv = jnp.zeros((len(cfg.kda_layers), B, 3, 3 * 64), jnp.float32)
+    state = jnp.zeros((len(cfg.recurrent_layers), B, 4, 16, 16), jnp.float32)
+    conv = jnp.zeros((len(cfg.recurrent_layers), B, 3, 3 * 64), jnp.float32)
     lk = jnp.zeros((cfg.cache_layers, B, n, 1, cfg.cache_k_dim), jnp.float32)
 
     def text(impl):

@@ -1,7 +1,8 @@
 """The named scopes are a partition of every engine program (ISSUE 37).
 
 Every instruction that does work in the programs `decode_block` and `admit`
-compile to, for the tiny dense, MoE, KDA + MLA and KDA + GQA configurations,
+compile to, for the tiny dense, MoE, KDA + MLA, KDA + GQA and conv + GQA
+configurations,
 is written under exactly one leaf of `observe.scopes.SCOPES` (or a per-layer
 slice scope): device time can then be read by program and scope out of a
 profiler capture with nothing emitted at run time. And the rows of every
@@ -23,7 +24,8 @@ from localai_tpu.models import llama as L
 from localai_tpu.observe import scopes
 from tools.same_program import tiny_engine_programs
 
-CONFIGS = ("tiny", "tiny-olmoe", "tiny-kimi-linear", "tiny-solar-open2")
+CONFIGS = ("tiny", "tiny-olmoe", "tiny-kimi-linear", "tiny-solar-open2",
+           "tiny-lfm2")
 PROGRAMS = ("decode_block", "admit")
 # what does no work: the issue's list
 NO_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast"}
@@ -37,7 +39,7 @@ def leaf_of(op_name):
 
 def _cfg(name):
     cfg = get_arch(name)
-    if cfg.is_hybrid:  # as served: this chip holds a share of the experts
+    if cfg.recurrent_kind == "kda":  # as served: a share of the experts
         cfg = dataclasses.replace(cfg, expert_share=(0, 2))
     return cfg
 
@@ -181,7 +183,8 @@ def test_the_mlp_scopes_tell_the_models_apart(compiled, config):
     cfg = _cfg(config)
     assert ("mlp/router" in leaves) == ("mlp/experts" in leaves) == cfg.is_moe
     assert ("mlp/shared" in leaves) == bool(cfg.is_moe and cfg.n_shared_experts)
-    assert ("attention/rope" in leaves) == (config in ("tiny", "tiny-olmoe"))
+    assert ("attention/rope" in leaves) == (
+        config in ("tiny", "tiny-olmoe", "tiny-lfm2"))
 
 
 @pytest.mark.parametrize("config", [c for c in CONFIGS if c != "tiny"])

@@ -101,7 +101,7 @@ def test_engine_agrees_with_the_plain_reference(served):
     errs = [_err(params, CFG, p, r) for p, r in zip(prompts, recs)]
     assert C.verdict(errs, TOLERANCE), errs
     m = eng.metrics()
-    kl = len(CFG.kda_layers)
+    kl = len(CFG.recurrent_layers)
     assert kl == 6 and CFG.cache_layer_ids == (0, 4)
     assert m["recurrent_state_bytes"] == 2 * rstate.row_bytes(CFG, "float32")
     assert m["state_snapshots"] == 0 and m["admit_splits"] == 0
@@ -184,7 +184,7 @@ def test_the_pallas_walk_reads_its_layer_from_inside_the_hybrid_scan():
     bfloat16, as native."""
     params = _seeded()
     B, n, page, MP = 2, 4, 16, 4
-    kl = len(CFG.kda_layers)
+    kl = len(CFG.recurrent_layers)
     ks = jax.random.split(jax.random.key(21), 4)
     pool = L.paged_cache_zeros(CFG, B * MP + 1, page)
     pool = pool._replace(k=jax.random.normal(ks[0], pool.k.shape),

@@ -22,7 +22,8 @@ import sys
 
 _INSTR = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = (\S+) (\w[\w\-]*)\(")
 
-TINY = ("tiny", "tiny-olmoe", "tiny-kimi-linear", "tiny-solar-open2")
+TINY = ("tiny", "tiny-olmoe", "tiny-kimi-linear", "tiny-solar-open2",
+        "tiny-lfm2")
 
 
 def digest(texts) -> str:
@@ -86,7 +87,7 @@ def tiny_engine_programs(name: str) -> dict:
     from localai_tpu.models import llama as L
 
     cfg = get_arch(name)
-    if cfg.is_hybrid:  # as served: this chip holds a share of the experts
+    if cfg.recurrent_kind == "kda":  # as served: a share of the experts
         cfg = dataclasses.replace(cfg, expert_share=(0, 2))
     with recorded_programs() as texts:
         eng = Engine(cfg, L.init_params(cfg, jax.random.key(0)),
