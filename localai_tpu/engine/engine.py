@@ -42,8 +42,8 @@ slot index is handed on at once and the request is parked until that block
 comes back (`Engine._park`). One that ends sooner (EOS, a stop sequence, a
 cancel, a deadline), a slot under a host-walk grammar and a speculative
 engine's slots are found out only when the block has been processed, so
-theirs is bounded by pipeline_depth * block size, the price of keeping the
-device saturated.
+theirs is bounded by pipeline_depth * block size (3 x 16 = 48 steps at the
+defaults), the price of keeping the device saturated.
 """
 
 from __future__ import annotations
@@ -136,11 +136,17 @@ class EngineConfig:
     max_seq: int = 2048
     min_prefill_bucket: int = 32
     base_seed: int = 0
-    # Decode-block sizes the scheduler chooses from (descending). Bigger
-    # blocks amortize per-dispatch overhead; smaller ones bound
-    # end-of-request overshoot and keep streaming/stop-sequence reaction
-    # granular.
-    block_sizes: tuple[int, ...] = (64, 16, 4, 1)
+    # Decode-block sizes the scheduler chooses from (descending); the first
+    # is the throughput block, the rest serve the tail (_pick_block_size).
+    # A request is live to the end of the block its budget ends in, so it
+    # throws away (n - 1) / 2 steps on average, and a parked request's
+    # `done` comes back pipeline_depth blocks after its slot was handed on:
+    # at 64 steps 13-17% of a saturated batch's rows carried no token, at
+    # 16 2.3% (PERF.md section 6, PR 43). What a shorter block costs is one
+    # dispatch, one control commit and one pull every n steps; the
+    # per-token host work does not depend on n. The block-local K/V window
+    # is [cache_layers, B, n, K, D] (_get_block), read whole every step.
+    block_sizes: tuple[int, ...] = (16, 4, 1)
     # Decode blocks kept in flight while the host processes earlier results.
     pipeline_depth: int = 3
     # Pipelined loop runtime (ISSUE 17, docs/ENGINE_RUNTIME.md). True: while
@@ -164,12 +170,13 @@ class EngineConfig:
     # Admission coalescing: when no decode block is in flight yet and a slot
     # was admitted within this window, hold the first block briefly so a
     # burst of simultaneous arrivals lands in the SAME block phase. A
-    # 64-step block costs the same with 1 active slot as with 8 — one
-    # straggler admitted just after dispatch forces a whole extra block
-    # (measured: 3x260 ms instead of 2x260 ms for 8 parallel requests on
-    # llama-3.2-1b, ~30% of the decode wall; GIL scheduling staggers a
-    # simultaneous 8-thread burst by several ms, so the window must cover
-    # that). Costs at most this many ms of added latency on a lone request.
+    # block costs the same with 1 active slot as with 8 — one straggler
+    # admitted just after dispatch forces a whole extra block (measured
+    # with 64-step blocks: 3x260 ms instead of 2x260 ms for 8 parallel
+    # requests on llama-3.2-1b, ~30% of the decode wall; GIL scheduling
+    # staggers a simultaneous 8-thread burst by several ms, so the window
+    # must cover that). Costs at most this many ms of added latency on a
+    # lone request.
     admit_coalesce_ms: float = 6.0
     # Prompt/prefix KV cache (reference: cache_prompt, grpc-server.cpp:125):
     # device-resident LRU of prefilled KV spans keyed by token prefixes.
@@ -631,6 +638,9 @@ class _Slot:
     prompt_len: int
     generated: list[int] = dataclasses.field(default_factory=list)
     emitted_len: int = 0  # chars of decoded text already streamed
+    # Text of the first `dec_n` generated tokens, settled (Engine._decoded).
+    dec_n: int = 0
+    dec_text: str = ""
     scheduled: int = 0  # decode steps dispatched (>= len(generated))
     # Upper bound on KV rows dispatched writes may touch (prompt rows +
     # decode steps scheduled) — what on-demand page growth must cover
@@ -802,9 +812,11 @@ class Engine:
     _SPEC_EWMA_FLOOR = 0.15
     _SPEC_PROBE_EVERY = 32
     # When a model-free spec round found nothing to draft, the fallback
-    # plain block is capped at this many steps: a full-depth (64-step)
-    # block would forfeit every draft opportunity inside its window — the
-    # suffix index / EWMA only get to re-plan between dispatches.
+    # plain block is capped at this many steps: a longer block would
+    # forfeit every draft opportunity inside its window — the suffix index
+    # / EWMA only get to re-plan between dispatches. The default throughput
+    # block is this long already; the cap binds where block_sizes[0] was
+    # set larger from code.
     _SPEC_REPLAN_BLOCK = 16
 
     def __init__(
@@ -2672,6 +2684,7 @@ class Engine:
         )
         slot.prompt_len = rec["orig_prompt_len"]
         slot.generated = list(rec["generated"])
+        slot.dec_n, slot.dec_text = 0, ""
         slot.emitted_len = rec["emitted_len"]
         # The admission just sampled the NEXT token (it rides the tracked
         # admit entry and will append to the restored list).
@@ -7903,10 +7916,13 @@ class Engine:
     def _pick_block_size(self) -> int:
         """Largest remaining token budget over active slots picks the block.
 
-        remaining >= max block size → max block (throughput). Otherwise the
-        smallest block that covers `remaining` — one slightly-overshooting
-        dispatch beats a tail of tiny dispatches, each with its own fixed
-        dispatch cost."""
+        remaining >= block_sizes[0] → that block (throughput: with a full
+        batch some slot always has that much left, so a saturated engine
+        dispatches nothing else). Otherwise the smallest block that covers
+        `remaining` — one slightly-overshooting dispatch beats a tail of
+        tiny dispatches, each with its own fixed dispatch cost. On the
+        default sizes (16, 4, 1): 5 and more left give 16, 2-4 give 4, 1
+        gives 1."""
         remaining = 1
         for i in range(self.ecfg.max_slots):
             s = self.slots[i]
@@ -8039,7 +8055,8 @@ class Engine:
                                                   "self_draft"):
             # Nothing to draft THIS round — keep the fallback block short
             # so the scheduler re-plans soon (token streams turn repetitive
-            # mid-flight; a 64-step block would sail past every match).
+            # mid-flight; a block longer than _SPEC_REPLAN_BLOCK would sail
+            # past every match).
             for bs in sorted(self.ecfg.block_sizes, reverse=True):
                 if bs <= self._SPEC_REPLAN_BLOCK:
                     n = min(n, bs)
@@ -8908,7 +8925,7 @@ class Engine:
             slot.generated.append(tok)
             self.m_generated_tokens += 1
 
-        text = self.tokenizer.decode(slot.generated)
+        text = self._decoded(slot)
         new = text[slot.emitted_len:]
 
         # Stop-sequence scan over the un-emitted tail (+ held-back overlap).
@@ -8976,6 +8993,30 @@ class Engine:
             ))
         if finish is not None:
             self._finish(slot_idx, finish, slot)
+
+    _DECODE_TAIL = 16  # tokens re-decoded behind a settled prefix
+
+    def _decoded(self, slot: _Slot) -> str:
+        """`tokenizer.decode(slot.generated)`, without decoding the whole
+        answer again for every token (the loop's largest cost a token, and
+        one that grew with the answer: PERF.md section 6, PR 43). The text
+        of the first `dec_n` tokens is kept once it is settled: a decode is
+        context-free but for what adjoins a cut (a split UTF-8 sequence, a
+        SentencePiece space, HF's clean-up of " ." and the like), so a cut
+        is taken only with _DECODE_TAIL tokens behind it, only where the
+        two sides decode to the whole, and never behind a replacement
+        character that later bytes could complete."""
+        gen, dec = slot.generated, self.tokenizer.decode
+        tail = dec(gen[slot.dec_n:])
+        text = slot.dec_text + tail
+        pending = len(gen) - slot.dec_n
+        if pending >= 2 * self._DECODE_TAIL and pending % self._DECODE_TAIL == 0:
+            cut = len(gen) - self._DECODE_TAIL
+            head = dec(gen[slot.dec_n:cut])
+            if not head.endswith("\ufffd") and head + dec(gen[cut:]) == tail:
+                slot.dec_text += head
+                slot.dec_n = cut
+        return text
 
     def _saves_at_finish(self, slot: _Slot) -> bool:
         """Does _finish store this request's prompt + generated rows as a
