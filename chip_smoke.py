@@ -348,6 +348,37 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
           jax.random.randint(next(keys), (rows,), lo, hi + 1),
           jnp.int32(1)), 6e-3)
 
+    # A decode block's window written into the pool (ISSUE 44): at the tp = 4
+    # shard's shape (2 KV heads a chip, 32 layers, the cell's 257 pages and
+    # 32 slots, a 16-step block) and at tp = 2's (4 heads), where
+    # `attention.write_window` takes the `pool_write` DMA kernel, against
+    # XLA's scatter: the same pool, exactly (tol 0). Starts that lie inside
+    # a page, straddle two, begin one and end one; one idle slot on the
+    # SCRATCH page; no two rows to one address, where neither form promises
+    # an order. On ONE chip: the kernel's faults are found here, before any
+    # four-chip call.
+    from localai_tpu.models import llama as LL
+
+    wl, wn, wmp = (32, 16, 8) if page == 128 else (2, 4, 4)
+    w_pages = rows * wmp + 1
+
+    def block_write(impl):
+        return lambda kp, vp, t, wk, wv, st: tuple(LL.write_block_to_pool(
+            LL.KVCache(kp, vp), t, wk, wv, st, paged_impl=impl)[:2])
+
+    for kc in (2, 4):
+        w_table = (jax.random.permutation(next(keys), w_pages - 1) + 1).reshape(
+            rows, wmp).astype(jnp.int32).at[3].set(0)
+        w_start = jax.random.randint(
+            next(keys), (rows,), 0, wmp * page - wn).at[0].set(
+            page - wn // 2).at[1].set(2 * page - 1).at[2].set(
+            page - wn).at[4].set(3 * page).at[3].set(7)
+
+        case(f"pool_write_K{kc}_n{wn}", block_write("auto"), block_write("xla"),
+             (rnd((wl, w_pages, page, kc, D)), rnd((wl, w_pages, page, kc, D)),
+              w_table, rnd((wl, rows, wn, kc, D)), rnd((wl, rows, wn, kc, D)),
+              w_start), 0.0)
+
     T = s["verify"]
     qpos = limits[:, None] + jnp.arange(T)[None, :]
 

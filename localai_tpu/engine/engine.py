@@ -3183,6 +3183,7 @@ class Engine:
                 cache = llama.write_block_to_pool(
                     cache, ptable, local_k, local_v, start_pos,
                     kv_scale=self._kv_scales,
+                    paged_impl=self.ecfg.paged_kernel, mesh=self._op_mesh,
                 )
             else:
                 cache = llama.write_block_to_cache(cache, local_k, local_v, start_pos)
@@ -6424,6 +6425,13 @@ class Engine:
                 sites["paged_attention_multipage"])
             out["paged_attention_onepage_sites"] = float(
                 sites["paged_attention_onepage"])
+        if sites["pool_write_inplace"] or sites["pool_write_scatter"]:
+            # how each decode block's window reached its two page pools: the
+            # DMA kernel in place, or XLA's scatter (stacked.note_pool_write)
+            out["pool_write_inplace_sites"] = float(
+                sites["pool_write_inplace"])
+            out["pool_write_scatter_sites"] = float(
+                sites["pool_write_scatter"])
         if self.m_forks or self.m_fork_clone_fallbacks:
             # Tree-batched fork sampling (ISSUE 18): branches admitted by
             # slot fork vs degraded to the N-clone path (fault/pressure).

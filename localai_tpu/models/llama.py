@@ -2143,14 +2143,20 @@ def write_block_to_pool(
     local_v: jnp.ndarray,
     start_positions: jnp.ndarray,  # [B]
     kv_scale=None,  # [2, K] f32 → pool rows store value/scale (fp8 KV)
+    paged_impl: str = "auto",  # the engine's paged reader (EngineConfig.paged_kernel)
+    mesh=None,  # Mesh with tp>1 → the pool is head-sharded
 ) -> KVCache:
-    """Scatter a decode block's window into the page pool (once per block).
+    """Write a decode block's window into the page pool (once per block).
     Rows may straddle pages; each (slot, step) row lands at
     (table[b, row // page], row % page). Every slot is written every step —
     idle slots and rows past a slot's reservation resolve through the
     engine's SCRATCH-filled table entries to a page nobody attends, so they
-    can never corrupt a live request."""
+    can never corrupt a live request. How the rows get there
+    (`attention.write_window`): XLA's scatter, or, where a token's row of
+    the pool is narrower than the native tile and the paged reader is the
+    Pallas kernel, a DMA kernel that writes them in place."""
     from localai_tpu.ops import ptable as _pt
+    from localai_tpu.ops.attention import write_window
 
     L, B, n = local_k.shape[:3]
     page = pool.k.shape[2]
@@ -2161,8 +2167,10 @@ def write_block_to_pool(
     off = row % page
     ks = None if kv_scale is None else kv_scale[0]
     vs = None if kv_scale is None else kv_scale[1]
-    k = pool.k.at[:, pid, off].set(_pool_store(local_k, pool.k.dtype, ks))
-    v = pool.v.at[:, pid, off].set(_pool_store(local_v, pool.v.dtype, vs))
+    k = write_window(pool.k, _pool_store(local_k, pool.k.dtype, ks),
+                     pid, off, impl=paged_impl, mesh=mesh)
+    v = write_window(pool.v, _pool_store(local_v, pool.v.dtype, vs),
+                     pid, off, impl=paged_impl, mesh=mesh)
     return pool._replace(k=k, v=v)
 
 

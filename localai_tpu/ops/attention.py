@@ -658,6 +658,51 @@ def _paged_pallas_sharded(kernel_fn, mesh, q, k_pool, v_pool, table, limits,
               jnp.asarray(layer, jnp.int32))
 
 
+def write_window(pool, win, pid, off, impl: str = "auto", mesh=None):
+    """A decode block's window into ONE page pool (K or V): row (b, r) of
+    `win` [L, B, n, K, D], already in the pool's dtype, lands at
+    `pool[:, pid[b, r], off[b, r]]` (`llama.write_block_to_pool` resolves
+    the table). The rule is on what this sees and nowhere else (ISSUE 44):
+
+    - the engine's paged reader is the Pallas kernel (`impl`, resolved as
+      `paged_partials` resolves it) AND a token's row of one chip's part of
+      the pool is narrower than the native tile and whole sublane words
+      (`pool_write.in_place_rows`: 2 to 7 rows of D at 16 bits, so not the
+      latent pool's one): the `pool_write` kernel copies the window's
+      rows into the donated pool by DMA, in place; under a tp mesh inside
+      `shard_map` with the pool spec `_paged_pallas_sharded` gives the
+      reader, indices replicated. XLA stores such a pool tiled `T(K,128)`
+      and ran the scatter in another layout, a copy of the whole pool each
+      way, K and V, every block;
+    - otherwise XLA's scatter, which at 8 rows and more runs in the layout
+      the pool is stored in.
+
+    Counted per traced program (`stacked.note_pool_write`)."""
+    import functools
+
+    from jax.sharding import PartitionSpec as P
+
+    from localai_tpu.ops.paged_flash import use_pallas
+    from localai_tpu.ops.pool_write import in_place_rows, pool_write
+    from localai_tpu.ops.stacked import note_pool_write
+
+    tp = _tp_degree(mesh)
+    local = (*pool.shape[:3], pool.shape[3] // tp, pool.shape[4])
+    inplace = use_pallas(impl) and in_place_rows(local, pool.dtype)
+    note_pool_write(inplace)
+    if not inplace:
+        return pool.at[:, pid, off].set(win)
+    kernel = functools.partial(pool_write,
+                               interpret=jax.default_backend() != "tpu")
+    if tp > 1:
+        pool_spec = P(None, None, None, "tp", None)  # [L, P, page, K, D]
+        kernel = _head_shard_map(
+            kernel, mesh,
+            in_specs=(pool_spec, pool_spec, P(None, None), P(None, None)),
+            out_specs=pool_spec)
+    return kernel(pool, win, pid, off)
+
+
 def _paged_pools(k_pool, v_pool, pallas: bool):
     """The pools as a paged dispatcher hands them on, the choice counted as
     one paged-attention call site (stacked.SiteCounts). The Pallas kernel

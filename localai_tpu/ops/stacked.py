@@ -126,6 +126,9 @@ _KEYS = {
     # (`note_visit`)
     "paged_attention_visit": ("paged_attention_multipage",
                               "paged_attention_onepage"),
+    # nor this: how a decode block's window reached one page pool
+    # (`note_pool_write`)
+    "pool_write": ("pool_write_inplace", "pool_write_scatter"),
 }
 _ALL_KEYS = tuple(k for ks in _KEYS.values() for k in ks)
 
@@ -143,7 +146,9 @@ class SiteCounts:
     / `paged_attention_f32` (`note_arith`) and what a visit of its page walk
     holds, `paged_attention_multipage` / `paged_attention_onepage`
     (`note_visit`), and the weight block of each Pallas dequant-matmul call,
-    `wholerow` / `narrowed` (`note_blocks`).
+    `wholerow` / `narrowed` (`note_blocks`); and how a decode block's window
+    reached each page pool, `pool_write_inplace` / `pool_write_scatter`
+    (`note_pool_write`).
     The choice is static, so it is counted where it is made, once per trace. An engine
     owns one and traces its programs under `tracing(<program>)`."""
 
@@ -221,3 +226,16 @@ def note_visit(multipage: bool) -> None:
     per-head form, the cold-middle walk, the latent kernel). The XLA walk
     counts under neither."""
     note_site(multipage, kernel="paged_attention_visit")
+
+
+def note_pool_write(inplace: bool) -> None:
+    """Count one page pool (K or V) a decode block of the program being
+    traced wrote its window into (ops/attention `write_window`), by how:
+    `inplace`, the Pallas `pool_write` kernel copied the window's rows into
+    the donated pool by DMA (the paged reader is the Pallas kernel and a
+    token's row of the pool is narrower than the native tile: 2 or 4 KV
+    heads a chip, four packed rows; key `pool_write_inplace`), or XLA's
+    scatter did (`pool_write_scatter`: 8 rows and more a token, where the
+    scatter runs in the layout the pool is stored in; the latent pool's one
+    row, which no DMA can slice; every pool under the XLA walk)."""
+    note_site(inplace, kernel="pool_write")

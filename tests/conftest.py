@@ -71,6 +71,7 @@ _COSTLY_FIRST = (
     "test_scopes.py",  # about 70 alone (PR 37)
     "test_observe.py",  # 55
     "test_block_length.py",  # about 55 alone (PR 43)
+    "test_pool_write.py",  # about 55 alone (PR 44)
     "test_musicgen.py",  # 51
     "test_model_families.py",  # 44
     "test_tracing.py",  # 44

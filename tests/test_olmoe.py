@@ -217,7 +217,8 @@ def test_wide_rows_run_top_k_and_match_all_experts(form, kernel, monkeypatch):
 _NO_PAGED_SITES = {"paged_attention_stacked": 0, "paged_attention_sliced": 0,
                    "paged_attention_native": 0, "paged_attention_f32": 0,
                    "paged_attention_multipage": 0,
-                   "paged_attention_onepage": 0}
+                   "paged_attention_onepage": 0,
+                   "pool_write_inplace": 0, "pool_write_scatter": 0}
 
 
 def _pallas_calls(jaxpr, name):

@@ -877,6 +877,14 @@ def test_engine_gauges_count_paged_attention_sites(impl, kv_heads, page):
     for key in (mine, other):
         assert metrics[f"paged_attention_{key}_sites"] == sum(
             p[f"paged_attention_{key}"] for p in by_program.values())
+    # and how the block's window reached the two pools (ISSUE 44): 16- and
+    # 8-wide heads are no whole lane tile, which no DMA slices, so these
+    # pools keep XLA's scatter under either reader (the kernel's engine
+    # test, at 128-wide heads, is tests/test_pool_write.py)
+    assert block["pool_write_scatter"] == 2 * block["traces"]
+    assert block["pool_write_inplace"] == 0
+    assert metrics["pool_write_scatter_sites"] == block["pool_write_scatter"]
+    assert metrics["pool_write_inplace_sites"] == 0
 
 
 # ---------------------------------------------------------------------- #

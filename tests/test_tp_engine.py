@@ -234,6 +234,14 @@ def test_tp2_pallas_kernel_matches_tp1_xla(tiny, multichip):
         for kw in (dict(max_new_tokens=8),
                    dict(max_new_tokens=8, temperature=0.8, seed=5)):
             assert _gen_ids(tp2, PROMPT2, **kw) == _gen_ids(ref, PROMPT2, **kw)
+        # How each block's window reached the pools is counted (ISSUE 44).
+        # One 16-wide head a chip is no DMA slice, so this pair scatters;
+        # the pair that writes in place (two 128-wide heads a chip) is
+        # tests/test_pool_write.py::test_tp2_engine_writes_in_place_and_matches_tp1
+        for eng in (ref, tp2):
+            m, block = eng.metrics(), eng.quant_sites.by_program["decode_block"]
+            assert m["pool_write_scatter_sites"] == 2 * block["traces"] > 0
+            assert m["pool_write_inplace_sites"] == 0
     finally:
         ref.stop()
         tp2.stop()
