@@ -149,18 +149,8 @@ class EngineConfig:
     block_sizes: tuple[int, ...] = (16, 4, 1)
     # Decode blocks kept in flight while the host processes earlier results.
     pipeline_depth: int = 3
-    # Pipelined loop runtime (ISSUE 17, docs/ENGINE_RUNTIME.md). True: while
-    # a block is in flight the loop prepares the NEXT block's control plan
-    # (pack/variant/growth) into a staging slot, commits control state as
-    # ONE dirty-diffed H2D transfer (skipped entirely when unchanged — the
-    # steady-state decode case), and runs purge/deadline/spill housekeeping
-    # on a budgeted tick instead of every iteration. False: the serial
-    # pre-ISSUE-17 loop (per-field uploads, every-iteration housekeeping) —
-    # byte-identical output either way; the serial path is the bench
-    # baseline. LOCALAI_LOOP_PREPARE_AHEAD env var overrides.
-    loop_prepare_ahead: bool = True
     # Wall budget in ms for one housekeeping tick of the pipelined loop
-    # (loop_prepare_ahead). The lifecycle-critical sweeps (pending purge +
+    # (docs/ENGINE_RUNTIME.md). The lifecycle-critical sweeps (pending purge +
     # active-deadline enforcement) always run on a due tick; optional work
     # (cold-page spill, deferred prefix-span saves) runs only while the
     # tick is under budget, so housekeeping can never delay a ready
@@ -872,8 +862,6 @@ class Engine:
             "LOCALAI_KV_L1_SPAN": ("kv_l1_span", int),
             "LOCALAI_SP_PREFILL": ("sp_prefill", _parse_flag_env),
             "LOCALAI_FORK_SAMPLING": ("fork_sampling", _parse_flag_env),
-            "LOCALAI_LOOP_PREPARE_AHEAD": ("loop_prepare_ahead",
-                                           _parse_flag_env),
             "LOCALAI_HOUSEKEEPING_BUDGET_MS": ("housekeeping_budget_ms",
                                                float),
         }.items():
@@ -1874,16 +1862,7 @@ class Engine:
         blocks (steady decode grows one slot's row occasionally), so the
         dirty-diff cache skips the upload entirely on a byte match and
         ships only the changed rows otherwise. Sound because no block/spec
-        program donates its ptable operand. Serial mode (loop_prepare_ahead
-        off) keeps the legacy per-dispatch upload for A/B parity runs."""
-        if not self.ecfg.loop_prepare_ahead:
-            # Copies: the tables are rewritten while the dispatch that
-            # shipped them is in flight (_park), and jnp.asarray of an
-            # aligned numpy array is zero-copy on the CPU backend.
-            if self._hier:
-                return (jnp.asarray(self.h_l1.copy()),
-                        jnp.asarray(self.h_l0.copy()))
-            return jnp.asarray(self.h_ptable.copy())
+        program donates its ptable operand."""
         if self._hier:
             return (self._ctrl.commit("ptable_l1", self.h_l1),
                     self._ctrl.commit("ptable_l0", self.h_l0))
@@ -6991,7 +6970,6 @@ class Engine:
         # an iteration that finds nothing to do stays in `wait` and a
         # waiting loop is one `loop/wait` span, not one per spin.
         ph = self._phases
-        pipelined = bool(self.ecfg.loop_prepare_ahead)
         while not self._shutdown.is_set():
             faults.fire("engine_loop")  # injected loop death (ISSUE 4)
             self._charge()
@@ -7003,19 +6981,14 @@ class Engine:
                 # single-writer ring in order.
                 ph.begin("drain")
                 jr.drain_staged()
-            if pipelined:
-                # Budgeted sidecar (ISSUE 17): purge/deadline sweeps run on
-                # a DUE tick — the deadline heap says something expired, or
-                # the forced interval elapsed — instead of scanning every
-                # pending request every iteration.
-                now = time.monotonic()
-                if self._hk_due(now):
-                    ph.begin("housekeeping")
-                    self._housekeeping(now)
-            else:
-                ph.begin("purge")
-                self._purge_pending()
-                self._enforce_deadlines()
+            # Budgeted sidecar (ISSUE 17): purge/deadline sweeps run on
+            # a DUE tick — the deadline heap says something expired, or
+            # the forced interval elapsed — instead of scanning every
+            # pending request every iteration.
+            now = time.monotonic()
+            if self._hk_due(now):
+                ph.begin("housekeeping")
+                self._housekeeping(now)
             self._drain_span_inbox()
 
             if self._growth_blocked and not self.h_active.any():
@@ -7087,14 +7060,6 @@ class Engine:
                 ph.begin("dispatch")
                 self._advance_chunked()
 
-            if not pipelined:
-                # Cold-page spill tick (ISSUE 14): pages that fell out of
-                # every live query's sink+window move to the host tier,
-                # bounded per iteration so the copy never stalls dispatch.
-                # Pipelined loops run this from the budgeted sidecar.
-                ph.begin("dispatch")
-                self._spill_cold_pages()
-
             if self._inflight:
                 front = self._inflight[0]
                 # A parked tenant (_park) is still being served: keep the
@@ -7111,7 +7076,7 @@ class Engine:
                     # result path is commit + dispatch only), give the
                     # budgeted sidecar the idle window, then sleep.
                     staged = False
-                    if pipelined and not grammar:
+                    if not grammar:
                         try:
                             # begins the prep phase when it builds a plan
                             staged = self._stage_plan()
@@ -7119,27 +7084,22 @@ class Engine:
                             self._fail_block(e)
                             self._flush_loop_iter(False, False)
                             continue
-                    if pipelined:
-                        now = time.monotonic()
-                        if self._hk_due(now, idle=True):
-                            ph.begin("housekeeping")
-                            self._housekeeping(now)
+                    now = time.monotonic()
+                    if self._hk_due(now, idle=True):
+                        ph.begin("housekeeping")
+                        self._housekeeping(now)
                     if not staged:
                         # Nothing ready, nothing to prepare (e.g. grammar
                         # mode waiting on an in-flight admit): don't
                         # busy-spin.
                         ph.begin("wait")
-                        if pipelined:
-                            self._wake.wait(timeout=0.001)
-                            self._wake.clear()
-                        else:
-                            time.sleep(0.001)
+                        self._wake.wait(timeout=0.001)
+                        self._wake.clear()
             elif not active and not admitted:
-                if pipelined:
-                    now = time.monotonic()
-                    if self._hk_due(now, idle=True):
-                        ph.begin("housekeeping")
-                        self._housekeeping(now)
+                now = time.monotonic()
+                if self._hk_due(now, idle=True):
+                    ph.begin("housekeeping")
+                    self._housekeeping(now)
                 ph.begin("wait")
                 self._wake.wait(timeout=0.05)
                 self._wake.clear()
@@ -7213,16 +7173,10 @@ class Engine:
     # thread: engine-loop-only
     def _defer_prefix_save(self, slot_idx: int, ids, rows: int) -> None:
         """Admission-time prefix-span save, moved off the admission path
-        (ISSUE 17): the snapshot costs a device gather + host copy that the
-        serial loop paid before the next dispatch could go out. Pipelined
-        loops park the save for the budgeted sidecar; _finish flushes (or
-        subsumes) whatever is still parked, so a span is only ever saved
-        LATER than the serial loop would have — never lost. Serial mode
-        saves inline, unchanged."""
-        if not self.ecfg.loop_prepare_ahead:
-            self._prefix_save(slot_idx, ids, rows,
-                              min_extend=self.ecfg.prefix_cache_min)
-            return
+        (ISSUE 17): the snapshot costs a device gather + host copy that would
+        otherwise stand before the next dispatch. The save is parked for the
+        budgeted sidecar; _finish flushes (or subsumes) whatever is still
+        parked, so a span is saved later than at admission, never lost."""
         if not self._prefix_enabled:
             return
         self._deferred_saves.append(
@@ -8112,8 +8066,7 @@ class Engine:
         plan = self._staged_plan
         self._staged_plan = None
         if (not isinstance(plan, _BlockPlan) or plan.epoch != self._ctrl_epoch
-                or plan.grammar != grammar
-                or not self.ecfg.loop_prepare_ahead):
+                or plan.grammar != grammar):
             self._phases.begin("prep")
             plan = self._plan_block(grammar)
         if plan is None or isinstance(plan, str):
@@ -8134,18 +8087,11 @@ class Engine:
         state did not change issues ZERO transfers; any change issues
         exactly one. Every carried value is f32 sampling state or a small
         int (< 2^24: token ids, rope deltas, adapter rows), so the f32
-        stack is exact and the int rows cast back losslessly. Serial mode
-        (loop_prepare_ahead off) keeps the legacy per-field uploads for
-        A/B parity runs. Returns (d_pack, d_rope, d_adapter)."""
+        stack is exact and the int rows cast back losslessly. Returns
+        (d_pack, d_rope, d_adapter)."""
         faults.fire("control_commit")
         rope = self._mrope
         adapter = p.with_lora
-        if not self.ecfg.loop_prepare_ahead:
-            return (
-                jnp.asarray(p.pack),
-                jnp.asarray(self.h_rope_delta.copy()) if rope else None,
-                jnp.asarray(self.h_adapter.copy()) if adapter else None,
-            )
         parts = [p.pack]
         if rope:
             parts.append(np.asarray(self.h_rope_delta, np.float32)[None])

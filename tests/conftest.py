@@ -10,7 +10,15 @@ import os
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
-    os.environ["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
+    flags += " --xla_force_host_platform_device_count=8"
+# The tests' programs are tiny and run for milliseconds: what they cost is
+# their compile, so LLVM builds them unoptimised (the HLO passes, and so every
+# compiled text a test reads, are the same: tools/same_program.py prints the
+# same digests either way). Servers and workers that tests spawn inherit the
+# flag with the device count.
+if "xla_backend_optimization_level" not in flags:
+    flags += " --xla_backend_optimization_level=0"
+os.environ["XLA_FLAGS"] = flags.strip()
 # No persistent compilation cache under pytest, in this process or in the
 # servers and workers tests spawn (they inherit the variable): ModelManager
 # places the cache inside the checkout, and a test run must neither write
@@ -27,63 +35,26 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 
 
-# The suite is some 4,900 CPU-seconds, nearly all of it XLA:CPU compiles, and
-# the driver runs it on six workers (`-n 6 --dist loadfile`: a worker takes
-# the next module when it has run out). What the run takes is then the
-# busiest worker's time, so the modules that cost more than 40 s go first,
-# longest first, and the cheap ones fill the gaps at the end. (Until PR 28
-# they ran last, cheapest first, for a run that a 870 s limit cut short; run
-# to its end that order left five workers waiting for the last module.)
-# Times: the driver's junit file of PR 27's tree, summed per module; the two
-# modules PR 28 added were measured here. Every other module keeps its
-# alphabetical place behind these.
-_COSTLY_FIRST = (
-    "test_paged_flash.py",  # 439 s
-    "test_speculative.py",  # 294
-    "test_quant.py",  # 260
-    "test_paged_kv.py",  # 259
-    "test_engine.py",  # 231
-    "test_lora_serving.py",  # 213
-    "test_latent_diffusion.py",  # 177 (520 with the scheduler cases)
-    "test_compose.py",  # 177
-    "test_tp_engine.py",  # 174
-    "test_fork_sampling.py",  # 169
-    "test_deepseek.py",  # 160
-    "test_model_llama.py",  # 55 + the entry-point matrix, about 100
-    "test_diffusion_schedulers.py",  # about 150: 20 compiles
-    "test_engine_runtime.py",  # 136
-    "test_early_release.py",  # 128 (PR 29)
-    "test_robustness.py",  # 106
-    "test_olmoe.py",  # 104
-    "test_kimi_linear.py",  # about 110 alone, 200 beside five workers (PR 31)
-    "test_solar_open2.py",  # about 85 alone (PR 34)
-    "test_video_diffusion.py",  # 103
-    "test_multihost.py",  # 102
-    "test_audio.py",  # 95
-    "test_vits.py",  # 92
-    "test_cluster.py",  # 89
-    "test_realtime.py",  # 83
-    "test_manager.py",  # 79
-    "test_prefix_cache.py",  # 77
-    "test_train.py",  # 69
-    "test_server.py",  # 65
-    "test_flux.py",  # 64
-    "test_scopes.py",  # about 70 alone (PR 37)
-    "test_observe.py",  # 55
-    "test_block_length.py",  # about 55 alone (PR 43)
-    "test_pool_write.py",  # about 55 alone (PR 44)
-    "test_musicgen.py",  # 51
-    "test_model_families.py",  # 44
-    "test_tracing.py",  # 44
-    "test_grammar_dfa.py",  # 43
-)
+# Nearly all of the suite's time is XLA:CPU compiles, and the driver runs it
+# on six workers (`-n 6 --dist loadfile`: a worker takes the next module when
+# it has run out). What the run takes is then the busiest worker's time, so
+# the modules go longest first and the cheap ones fill the gaps at the end.
+# The seconds are those of the last whole run somebody measured:
+# `python tools/test_budget.py <junit.xml> --write` rewrites the file, and a
+# module it does not know goes first of all (a new file is presumed costly
+# until it is measured).
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def pytest_collection_modifyitems(items):
-    rank = {name: i for i, name in enumerate(_COSTLY_FIRST)}
+    import json
+
+    with open(os.path.join(_REPO_ROOT, "tests", "module_seconds.json")) as f:
+        seconds = json.load(f)
     # list.sort is stable: order inside a module is untouched
-    items.sort(key=lambda it: rank.get(os.path.basename(str(it.fspath)),
-                                       len(rank)))
+    items.sort(key=lambda it: -seconds.get(
+        os.path.relpath(str(it.fspath), _REPO_ROOT).replace(os.sep, "/"),
+        float("inf")))
 
 
 def pytest_configure(config):
@@ -191,7 +162,6 @@ def multiproc_worker(tmp_path_factory):
 # by neither the guard nor the documented exemption list there.
 import sys as _sys
 
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO_ROOT not in _sys.path:
     _sys.path.insert(0, _REPO_ROOT)
 

@@ -1,8 +1,8 @@
 """The image API's scheduler names (the reference's A1111-mapped surface,
 diffusers backend.py:100-168), one case per name: each is a program of its
 own to compile. A module of its own, so that `--dist loadfile` places these
-compiles beside the other costly modules and not behind them
-(tests/conftest.py `_COSTLY_FIRST`)."""
+compiles beside the other costly modules and not behind them; the
+Karras-spaced names are tests/test_diffusion_schedulers_karras.py."""
 
 import jax
 import jax.numpy as jnp
@@ -14,13 +14,9 @@ pytest.importorskip("transformers")
 from localai_tpu.models import latent_diffusion as ld  # noqa: E402
 from tests.test_latent_diffusion import sd_dir  # noqa: E402,F401 — fixture reuse
 
-# Both spellings of the Karras variants: our "_karras" suffix and the
-# reference's "k_" prefix.
 SCHEDULERS = (
     "ddim", "pndm", "unipc", "euler", "euler_a", "dpmpp_2m", "heun", "lms",
-    "dpm_2", "dpm_2_a", "dpmpp_sde", "dpmpp_2m_sde", "dpmpp_2m_karras",
-    "euler_a_karras", "lms_karras", "k_euler", "k_dpm_2", "k_dpm_2_a",
-    "k_dpmpp_sde", "k_dpmpp_2m_sde",
+    "dpm_2", "dpm_2_a", "dpmpp_sde", "dpmpp_2m_sde",
 )
 
 
@@ -49,24 +45,17 @@ def images(sd_dir):  # noqa: F811
     return image
 
 
-@pytest.mark.parametrize("sched", SCHEDULERS)
-def test_generate_shape_range_and_determinism(images, sched):
-    img1, img2 = images(sched)
+def check_image(drawn):
+    img1, img2 = drawn
     assert img1.shape == (1, 64, 64, 3)
     assert np.isfinite(img1).all()
     assert 0.0 <= img1.min() and img1.max() <= 1.0
     np.testing.assert_array_equal(img1, img2)  # same seed → same image
 
 
-def test_karras_spacing_changes_the_trajectory(images):
-    assert np.abs(images("euler")[0] - images("k_euler")[0]).max() > 0
-
-
-@pytest.mark.parametrize("base", ld.K_SCHEDULERS)
-def test_both_karras_spellings_are_one_scheduler(base):
-    assert ld.resolve_scheduler(base) == (base, False)
-    assert (ld.resolve_scheduler(f"k_{base}")
-            == ld.resolve_scheduler(f"{base}_karras") == (base, True))
+@pytest.mark.parametrize("sched", SCHEDULERS)
+def test_generate_shape_range_and_determinism(images, sched):
+    check_image(images(sched))
 
 
 def test_supported_names_resolve_and_others_are_refused():

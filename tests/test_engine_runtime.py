@@ -1,11 +1,12 @@
 """Pipelined engine-loop runtime (ISSUE 17, docs/ENGINE_RUNTIME.md).
 
-The contract under test: `loop_prepare_ahead` changes WHEN host work runs
-and HOW MUCH crosses the host→device link, never WHAT the programs
-compute. Every sweep below runs the same requests through an engine pair
-that differs only in that flag and requires byte-identical outputs —
-dense and paged, greedy and seeded, chunked prefill, speculative rounds,
-grammar-DFA. On top of that: the steady-state transfer probe (a decode
+The contract under test: the loop decides WHEN host work runs and HOW MUCH
+crosses the host→device link, never WHAT the programs compute. Every sweep
+below submits a set of requests together, so that plans are staged ahead,
+control state is committed by difference and admissions land between
+blocks, and requires of each the bytes it gets served alone on the same
+engine — dense and paged, greedy and seeded, chunked prefill, speculative
+rounds, grammar-DFA. On top of that: the steady-state transfer probe (a decode
 block whose control state didn't change uploads NOTHING), the budgeted
 housekeeping sidecar, the admit-coalesce hold regression (hold must only
 suppress dispatch, not starve chunk progress), and the `control_commit`
@@ -50,12 +51,6 @@ def _mk(tiny, **kw):
     return eng
 
 
-def _mk_pair(tiny, **kw):
-    """Engine pair differing ONLY in loop_prepare_ahead."""
-    return (_mk(tiny, loop_prepare_ahead=True, **kw),
-            _mk(tiny, loop_prepare_ahead=False, **kw))
-
-
 def _run_set(eng, reqs):
     """Submit all requests up front (concurrent admission) and collect
     (text, kind, finish_reason) per request, in submit order."""
@@ -63,18 +58,24 @@ def _run_set(eng, reqs):
     return [h.result() for h in handles]
 
 
-def _pair_sweep(tiny, reqs, **cfg):
-    pipe, serial = _mk_pair(tiny, **cfg)
+def _together_and_alone(eng, reqs):
+    """The set submitted together, then each request with the engine to
+    itself; every request must read the same either way."""
+    together = _run_set(eng, reqs)
+    alone = [_run_set(eng, [r])[0] for r in reqs]
+    for i, ((tt, et), (ta, ea)) in enumerate(zip(together, alone)):
+        assert et.kind == ea.kind == "done", (i, et, ea)
+        assert tt == ta, f"request {i}: together != alone\n{tt!r}\n{ta!r}"
+        assert et.finish_reason == ea.finish_reason, i
+    return together
+
+
+def _sweep(tiny, reqs, **cfg):
+    eng = _mk(tiny, **cfg)
     try:
-        got_p = _run_set(pipe, reqs)
-        got_s = _run_set(serial, reqs)
+        _together_and_alone(eng, reqs)
     finally:
-        pipe.stop()
-        serial.stop()
-    for i, ((tp, ep), (ts, es)) in enumerate(zip(got_p, got_s)):
-        assert ep.kind == es.kind == "done", (i, ep, es)
-        assert tp == ts, f"request {i}: pipelined != serial\n{tp!r}\n{ts!r}"
-        assert ep.finish_reason == es.finish_reason, i
+        eng.stop()
 
 
 # --------------------------------------------------------------------- #
@@ -90,70 +91,65 @@ def test_loop_phases_pinned():
 
 
 # --------------------------------------------------------------------- #
-# Byte-identical sweeps: pipelined vs serial
+# Byte-identical sweeps: served together vs served alone
 # --------------------------------------------------------------------- #
 
 
-def test_pipelined_matches_serial_dense(tiny):
+def test_served_together_matches_served_alone_dense(tiny):
     reqs = (
         # Greedy, varied prompt lengths (different prefill buckets).
         [dict(prompt_ids=list(range(65, 65 + n)), max_new_tokens=24,
               ignore_eos=True) for n in (3, 17, 40)]
         # Seeded sampling: per-slot rng chains must be unaffected by
-        # admission timing / prepare-ahead reordering.
+        # admission timing / prepare-ahead reordering / who shares a block.
         + [dict(prompt_ids=[70, 71, 72], max_new_tokens=24,
                 temperature=0.9, seed=1000 + i, ignore_eos=True)
            for i in range(3)]
     )
-    _pair_sweep(tiny, reqs)
+    _sweep(tiny, reqs)
 
 
-def test_pipelined_matches_serial_paged_chunked(tiny):
+def test_served_together_matches_served_alone_paged_chunked(tiny):
     # Paged KV + chunked prefill: the long prompt takes the multi-chunk
-    # admission path; page-table growth happens at stage time on the
-    # pipelined engine and at dispatch time on the serial one.
+    # admission path while the short one decodes; page-table growth
+    # happens at stage time, ahead of the dispatch that needs it.
     reqs = [
         dict(prompt_ids=[(65 + i) % 256 for i in range(150)],
              max_new_tokens=20, ignore_eos=True),
         dict(prompt_ids=[66, 67], max_new_tokens=20, temperature=0.8,
              seed=7, ignore_eos=True),
     ]
-    _pair_sweep(tiny, reqs, kv_pages=24, kv_page_size=PAGE,
-                max_seq=512, prefill_chunk=64)
+    _sweep(tiny, reqs, kv_pages=24, kv_page_size=PAGE,
+           max_seq=512, prefill_chunk=64)
 
 
 @pytest.mark.slow
-def test_pipelined_matches_serial_spec(tiny):
+def test_served_together_matches_served_alone_spec(tiny):
     # Speculative rounds never stage (the spec planner commits probe/EWMA
-    # state when it runs) but the pipelined commit/ptable path still
-    # carries them — outputs must not move.
+    # state when it runs) but the commit/ptable path still carries them —
+    # outputs must not move from one serving to the next.
     base = [65, 66, 67, 68] * 6
     reqs = [dict(prompt_ids=base, max_new_tokens=24, ignore_eos=True)]
-    _pair_sweep(tiny, reqs, spec_mode="prompt_lookup", max_slots=2)
+    _sweep(tiny, reqs, spec_mode="prompt_lookup", max_slots=2)
 
 
-def test_pipelined_matches_serial_grammar_dfa(tiny):
+def test_served_together_matches_served_alone_grammar_dfa(tiny):
     schema = {"type": "object",
               "properties": {"a": {"type": "integer"},
                              "b": {"type": "boolean"}},
               "required": ["a", "b"]}
     reqs = [dict(prompt_ids=[10, 20, 30], max_new_tokens=120,
                  grammar=GrammarConstraint(schema))]
-    pipe, serial = _mk_pair(tiny, max_slots=2)
+    eng = _mk(tiny, max_slots=2)
     try:
         # Sync table build: otherwise early tokens ride the host-walk
         # fallback or wait on the async compile, and the outputs depend on
         # admission TIMING rather than on the runtime under test.
-        pipe.prewarm_grammar(schema)
-        serial.prewarm_grammar(schema)
-        (tp, ep), = _run_set(pipe, reqs)
-        (ts, es), = _run_set(serial, reqs)
+        eng.prewarm_grammar(schema)
+        (text, _ev), = _together_and_alone(eng, reqs)
     finally:
-        pipe.stop()
-        serial.stop()
-    assert ep.kind == es.kind == "done"
-    assert tp == ts
-    json.loads(tp)  # still valid under the schema's DFA
+        eng.stop()
+    json.loads(text)  # still valid under the schema's DFA
 
 
 # --------------------------------------------------------------------- #
@@ -182,16 +178,6 @@ def test_steady_state_decode_skips_control_upload(tiny):
         assert m["ctrl_commit_skips"] == c.skips
         assert m["loop_blocks"] == blocks
         assert m["loop_host_overhead_per_block_ms"] > 0.0
-    finally:
-        eng.stop()
-
-
-def test_serial_mode_bypasses_stager(tiny):
-    eng = _mk(tiny, max_slots=2, loop_prepare_ahead=False)
-    try:
-        _txt, ev = eng.generate([65], max_new_tokens=8, ignore_eos=True)
-        assert ev.kind == "done"
-        assert eng._ctrl.commits == 0  # per-field jnp.asarray, legacy path
     finally:
         eng.stop()
 
@@ -253,29 +239,24 @@ def test_deadline_index_wakes_housekeeping(tiny):
 
 
 def test_deferred_prefix_save_flushes_on_finish(tiny):
-    # Pipelined admission parks the span save on the sidecar; by the time
-    # the request finishes, the span (or its finish-time superset) must be
-    # queryable exactly as the serial loop would have left it.
+    # Admission parks the span save on the sidecar; by the time the
+    # request finishes, the span (or its finish-time superset) must be
+    # queryable as if it had been saved at admission.
     prompt = [65 + (i % 20) for i in range(40)]
-    pipe, serial = _mk_pair(tiny, prefix_cache_entries=4,
-                            prefix_cache_min=16,
-                            prefix_admit_async_compile=False)
+    eng = _mk(tiny, prefix_cache_entries=4, prefix_cache_min=16,
+              prefix_admit_async_compile=False)
     try:
-        for eng in (pipe, serial):
-            _t, ev = eng.generate(list(prompt), max_new_tokens=4,
-                                  ignore_eos=True)
-            assert ev.kind == "done"
-        # Same prompt again: both engines must hit their prefix cache.
-        for eng in (pipe, serial):
-            _t, ev = eng.generate(list(prompt), max_new_tokens=4,
-                                  ignore_eos=True)
-            assert ev.kind == "done"
-        assert pipe.m_prefix_hits >= 1
-        assert serial.m_prefix_hits >= 1
-        assert not pipe._deferred_saves  # nothing left parked
+        _t, ev = eng.generate(list(prompt), max_new_tokens=4,
+                              ignore_eos=True)
+        assert ev.kind == "done"
+        # Same prompt again: it must hit the prefix cache.
+        _t, ev = eng.generate(list(prompt), max_new_tokens=4,
+                              ignore_eos=True)
+        assert ev.kind == "done"
+        assert eng.m_prefix_hits >= 1
+        assert not eng._deferred_saves  # nothing left parked
     finally:
-        pipe.stop()
-        serial.stop()
+        eng.stop()
 
 
 # --------------------------------------------------------------------- #

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from model_cases import _engine, served_engine
 from benchmark.harness import check as C
 from benchmark.reference import kda_mla_moe as REF
 from localai_tpu.engine import ByteTokenizer, Engine, EngineConfig, GenRequest
@@ -55,16 +56,6 @@ def _seeded(cfg=CFG, quantize=""):
     return Q.quantize_params(cfg, params, quantize) if quantize else params
 
 
-def _engine(cfg, params, **kw):
-    kw = {"max_slots": 2, "max_seq": 256, "block_sizes": (8, 1),
-          "kv_pages": 40, "kv_page_size": 16, "trace_journal_events": 2048,
-          **kw}
-    eng = Engine(cfg, params, ByteTokenizer(cfg.vocab_size),
-                 engine_cfg=EngineConfig(**kw))
-    eng.start()
-    return eng
-
-
 def _worst(eng, params, cfg, prompts, new):
     recs = C.run_system(eng, prompts, new)
     return [C.compare(r, C.reference_logprobs(
@@ -75,15 +66,9 @@ def _worst(eng, params, cfg, prompts, new):
 # ---- the engine against the reference ---------------------------------------- #
 
 
-@pytest.fixture(scope="module")
-def served():
-    """The module's one long-lived engine, on int8 matrices as the cell's
-    (both sides read them as data); the tests that build their own engine
-    (preemption, the planted faults, the cut group) run plain weights."""
-    params = _seeded(quantize="int8")
-    eng = _engine(CFG, params)
-    yield eng, params
-    eng.stop()
+# the tests that build their own engine (preemption, the planted faults, the
+# cut group) run plain weights
+served = served_engine(_seeded, CFG)
 
 
 def test_engine_agrees_with_the_plain_reference(served):

@@ -13,6 +13,7 @@ it was; the layouts `_hybrid_tables` takes and refuses.
 """
 
 import dataclasses
+import functools
 import time
 
 import jax
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from model_cases import _collect, _engine, _err_against, served_engine
 from benchmark.harness import check as C
 from benchmark.reference import conv_gqa_moe as REF
 from localai_tpu.engine import ByteTokenizer, Engine, EngineConfig, GenRequest
@@ -51,45 +53,13 @@ def _seeded(cfg=CFG, quantize=""):
     return Q.quantize_params(cfg, params, quantize) if quantize else params
 
 
-def _engine(cfg, params, **kw):
-    kw = {"max_slots": 2, "max_seq": 256, "block_sizes": (8, 1),
-          "kv_pages": 40, "kv_page_size": 16, "trace_journal_events": 2048,
-          **kw}
-    eng = Engine(cfg, params, ByteTokenizer(cfg.vocab_size),
-                 engine_cfg=EngineConfig(**kw))
-    eng.start()
-    return eng
-
-
-def _collect(handle, n):
-    rec = {"ids": [], "lp": [], "top": []}
-    for ev in handle:
-        assert ev.kind != "error", ev.error
-        if ev.kind == "token":
-            rec["ids"].append(int(ev.token_id))
-            rec["lp"].append(float(ev.logprob))
-            rec["top"].append({int(i): float(v)
-                               for i, v in (ev.top_logprobs or [])})
-    assert len(rec["ids"]) == n
-    return rec
-
-
-def _err(params, cfg, prompt, rec):
-    return C.compare(rec, C.reference_logprobs(
-        REF.forward, params, cfg, prompt, rec["ids"], pad_to=16))
+_err = functools.partial(_err_against, REF.forward)
 
 
 # ---- the engine against the reference ---------------------------------------- #
 
 
-@pytest.fixture(scope="module")
-def served():
-    """The module's one long-lived engine, on int8 matrices as the cell's
-    (both sides read them as data)."""
-    params = _seeded(quantize="int8")
-    eng = _engine(CFG, params)
-    yield eng, params
-    eng.stop()
+served = served_engine(_seeded, CFG)
 
 
 def test_engine_agrees_with_the_plain_reference(served):

@@ -12,13 +12,6 @@ import numpy as np
 import pytest
 
 torch = pytest.importorskip("torch")
-from transformers import EncodecConfig  # noqa: E402
-from transformers import MusicgenConfig as HFMusicgenConfig  # noqa: E402
-from transformers import MusicgenForConditionalGeneration, T5Config  # noqa: E402
-from transformers.models.musicgen.configuration_musicgen import (  # noqa: E402
-    MusicgenDecoderConfig,
-)
-
 from localai_tpu.models import musicgen as M  # noqa: E402
 
 
@@ -26,6 +19,15 @@ from localai_tpu.models import musicgen as M  # noqa: E402
 def tiny_ckpt(tmp_path_factory):
     """A tiny random MusicgenForConditionalGeneration in the real HF layout,
     plus a WordLevel text tokenizer AutoTokenizer can load."""
+    # imported here and not at the top: `transformers`' model classes pull in
+    # TensorFlow, 7 s that every worker of the run would pay at collection
+    from transformers import EncodecConfig
+    from transformers import MusicgenConfig as HFMusicgenConfig
+    from transformers import MusicgenForConditionalGeneration, T5Config
+    from transformers.models.musicgen.configuration_musicgen import (
+        MusicgenDecoderConfig,
+    )
+
     d = tmp_path_factory.mktemp("musicgen")
     t5 = T5Config(
         vocab_size=99, d_model=16, d_kv=4, d_ff=32, num_layers=2, num_heads=4,

@@ -16,9 +16,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import model_cases as M
 from benchmark.harness import check as C
 from benchmark.reference import moe_qknorm as REF
-from localai_tpu.engine import ByteTokenizer, Engine, EngineConfig
 from localai_tpu.models import llama as L
 from localai_tpu.models import quant as Q
 from localai_tpu.models.config import get_arch
@@ -47,13 +47,8 @@ def _seeded(cfg=CFG, quantize=""):
 
 
 def _engine(cfg, params, paged=False, **kw):
-    ecfg = EngineConfig(max_slots=2, max_seq=128, block_sizes=(8, 1),
-                        trace_journal_events=256,
-                        **({"kv_pages": 24, "kv_page_size": 16} if paged else {}),
-                        **kw)
-    eng = Engine(cfg, params, ByteTokenizer(cfg.vocab_size), engine_cfg=ecfg)
-    eng.start()
-    return eng
+    return M._engine(cfg, params, max_seq=128, trace_journal_events=256,
+                     kv_pages=24 if paged else 0, **kw)
 
 
 def _errors(cfg, params, ref_params, **eng_kw):

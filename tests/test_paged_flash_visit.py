@@ -1,0 +1,160 @@
+"""A visit of the as-stored walk is as many consecutive pages of the slot as
+fit VISIT_ROWS (token, head) rows (ISSUE 41): one dot a pool over all of
+them, one rescale, a last visit of 1..n live pages whose unfetched part may
+hold anything. The kernel in interpret mode against the float64 walk that
+takes the same visits (tests/paged_cases.py), and the rule as a table.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from localai_tpu.ops.attention import _paged_cache_partials
+from localai_tpu.ops.paged_flash import (
+    paged_decode_partials,
+    paged_decode_partials_mq,
+)
+from paged_cases import (
+    _check_against_float64_walk,
+    _hier_of,
+    _one_compile,
+    _pool,
+    _table,
+)
+
+
+def _multipage_case(n, wrapper, variant):
+    """(fn, q, pools, table, limits, kwargs) at `n` pages a visit: 128-row
+    pages at K = 8 / 4 / 2 give 1 / 3 / 6 (fp8 needs four heads a word:
+    64-row pages for n = 6), 192-row pages at K = 4 / 2 give 2 / 4. Slots
+    of 1, n - 1, n, n + 1 and 2n + 1 pages, ending inside a page and on a
+    page's last row, with idle slots between live ones (the handoff skips
+    them) and an idle first one."""
+    fp8 = variant == "fp8_scale"
+    K, page = {1: (8, 128), 2: (4, 192), 3: (4, 128), 4: (2, 192),
+               6: (4, 64) if fp8 else (2, 128)}[n]
+    G, D, T = 2, 32, 3
+    MP = 2 * n + 1
+    lengths = [0, 1, 0, max(n - 1, 1), n, 0, n + 1, MP]  # live pages a slot
+    ends = [0, 5, 0, page, page - 1, 0, page, 7]  # rows of the last one
+    limits = jnp.array([max(c - 1, 0) * page + e
+                        for c, e in zip(lengths, ends)], jnp.int32)
+    B, P = len(lengths), len(lengths) * MP + 1
+    k4, v4 = _pool(jax.random.key(70 + n), P, page, K, D)
+    table = _table(B, MP, P, seed=20 + n)
+    kw = {}
+    if fp8:
+        scales = [2.0, 0.5, 1.25, 0.75, 1.5, 3.0, 0.5, 1.0]
+        kw["kv_scale"] = jnp.asarray([scales[:K], scales[::-1][:K]],
+                                     jnp.float32)
+        k4 = (k4 / kw["kv_scale"][0][:, None]).astype(jnp.float8_e4m3fn)
+        v4 = (v4 / kw["kv_scale"][1][:, None]).astype(jnp.float8_e4m3fn)
+    else:
+        k4, v4 = k4.astype(jnp.bfloat16), v4.astype(jnp.bfloat16)
+    if variant == "nan_unlisted":
+        # every page no live slot lists holds NaN: the columns behind a
+        # slot's last live page, the pool's free pages
+        listed = np.zeros(P, bool)
+        for b, c in enumerate(lengths):
+            listed[np.asarray(table)[b, :c]] = True
+        poison = jnp.asarray(~listed)[:, None, None, None]
+        k4 = jnp.where(poison, jnp.nan, k4).astype(k4.dtype)
+        v4 = jnp.where(poison, jnp.nan, v4).astype(v4.dtype)
+    elif variant == "hier":
+        kw["table"] = _hier_of(table, 3)
+    elif variant == "sliding":
+        kw.update(window=page + page // 2 + 3, sliding=jnp.asarray(True))
+    elif variant == "softcap":
+        kw["softcap"] = 2.5
+    if wrapper == "decode":
+        fn, q = paged_decode_partials, jax.random.normal(
+            jax.random.key(80 + n), (B, K * G, D))
+    else:
+        fn, q = paged_decode_partials_mq, jax.random.normal(
+            jax.random.key(90 + n), (B, T, K * G, D))
+        kw["q_pos"] = limits[:, None] + jnp.arange(T)[None, :]
+    return fn, q, k4, v4, table, limits, kw
+
+
+def _program(n, wrapper, variant):
+    """The key a case's compiled call is kept under (`_one_compile`):
+    `nan_unlisted` is `flat`'s program on another pool."""
+    return ("visit", n, wrapper, "flat" if variant == "nan_unlisted" else variant)
+
+
+@pytest.mark.parametrize("variant", ["flat", "hier", "sliding", "fp8_scale",
+                                     "softcap", "nan_unlisted"])
+@pytest.mark.parametrize("wrapper", ["decode", "mq"])
+@pytest.mark.parametrize("n", [1, 3, 6])
+def test_multipage_visit_matches_float64_walk(n, wrapper, variant):
+    """n pages a visit (K = 8 / 4 / 2 at 128-row pages) against the float64
+    walk that takes the same visits, over tails of every length; under
+    `nan_unlisted` every page the walk must not read is NaN, the stale and
+    the never-written part of a ring buffer included (the interpreter
+    hands out NaN scratch)."""
+    fn, q, k4, v4, table, limits, kw = _multipage_case(n, wrapper, variant)
+    # a sum over a thousand rows meets a rounding boundary of some p more
+    # often than one over eighty (3% of acc's entries under the softcap,
+    # whose p are all near 1)
+    got = _check_against_float64_walk(
+        _program(n, wrapper, variant), fn, q, k4, v4, table, limits, kw, n,
+        flips=0.05)
+    assert all(np.isfinite(np.asarray(g)).all() for g in got)
+
+
+@pytest.mark.parametrize("variant", ["flat", "nan_unlisted"])
+@pytest.mark.parametrize("n", [2, 4])
+def test_multipage_visit_of_192_row_pages(n, variant):
+    """The visits between: 192-row pages at K = 4 / 2 are 2 / 4 a visit."""
+    fn, q, k4, v4, table, limits, kw = _multipage_case(n, "decode", variant)
+    got = _check_against_float64_walk(
+        _program(n, "decode", variant), fn, q, k4, v4, table, limits, kw, n,
+        flips=0.05)
+    assert all(np.isfinite(np.asarray(g)).all() for g in got)
+
+
+@pytest.mark.parametrize("n", [3, 6])
+def test_multipage_visit_matches_xla_walk(n):
+    """The same call against the XLA page walk (float32 throughout, a chunk
+    of pages at a time): bfloat16-grade agreement of the settled output."""
+    fn, q, k4, v4, table, limits, kw = _multipage_case(n, "decode", "flat")
+    got = _one_compile(_program(n, "decode", "flat"), fn, kw)(
+        q, k4, v4, table, limits)
+    want = _paged_cache_partials(q, k4, v4, table, limits)
+    live = np.asarray(limits) > 0
+    for g, w in ((got[0] / jnp.maximum(got[2], 1e-30),
+                  want[0] / jnp.maximum(want[2], 1e-30)), (got[1], want[1])):
+        np.testing.assert_allclose(np.asarray(g)[live], np.asarray(w)[live],
+                                   atol=1e-2, rtol=1e-2)
+
+
+@pytest.mark.parametrize("page,K,width,flat,swin,want", [
+    (128, 8, 32, True, 0, 1),  # mistral int8, Solar-Open2's cache layers
+    (128, 16, 32, True, 0, 1),  # OLMoE
+    (128, 4, 32, True, 0, 3),
+    (128, 2, 32, True, 0, 6),  # one chip of tp = 4
+    (128, 1, 32, True, 0, 12),  # one chip of tp = 8
+    (192, 4, 32, True, 0, 2),
+    (192, 2, 32, True, 0, 4),
+    (64, 8, 64, True, 0, 3),
+    (64, 2, 64, True, 0, 12),
+    (16, 2, 5, True, 0, 5),  # never more than the table has columns
+    (256, 8, 16, True, 0, 1),
+    (128, 2, 32, False, 0, 1),  # the per-head form
+    (128, 2, 32, True, 512, 1),  # the cold-middle walk
+])
+def test_visit_rule_sizes_a_visit_in_rows(page, K, width, flat, swin, want):
+    """`page · K` -> pages a visit, and what the ring then holds: at most
+    VISIT_ROWS rows a visit wherever it is more than a page, and at 128-wide
+    bfloat16 heads never more than RING_VMEM_BYTES (a visit of VISIT_ROWS
+    rows is exactly what RING_MAX buffers of it fill)."""
+    from localai_tpu.ops.paged_flash import (
+        RING_VMEM_BYTES, VISIT_ROWS, _ring_depth, _visit_pages)
+
+    n = _visit_pages(page, K, width, flat=flat, swin=swin)
+    assert n == want
+    assert n == 1 or n * page * K <= VISIT_ROWS
+    visit_bytes = n * page * K * (128 + 128) * 2
+    assert _ring_depth(visit_bytes) * visit_bytes <= RING_VMEM_BYTES
+    assert _ring_depth(VISIT_ROWS * (128 + 128) * 2) == 4

@@ -276,15 +276,36 @@ def _mk_free(cfg, params, mode, tp=1, paged=False, **kw):
     return eng
 
 
+# A deterministic draft opportunity: the prompt repeats the biased
+# continuation token, so the FIRST dispatch after admission is a verify round.
+PINNED = [10] + [77] * 20
+PINNED_BIAS = {77: 25.0}
+
+
+@pytest.fixture(scope="module")
+def plain32(setup32):
+    """What plain greedy decode gives for PROMPTS and the pinned prompt: the
+    reference of every model-free case, from one engine built once."""
+    cfg, params = setup32
+    plain = _mk(cfg, params)
+    try:
+        out = [plain.generate(prompt, max_new_tokens=24, ignore_eos=True)
+               for prompt in PROMPTS]
+        out.append(plain.generate(PINNED, max_new_tokens=24, ignore_eos=True,
+                                  logit_bias=PINNED_BIAS))
+    finally:
+        plain.stop()
+    return out
+
+
 @pytest.mark.parametrize("mode", ["prompt_lookup", "self_draft"])
 @pytest.mark.parametrize("paged", [False, True])
-def test_model_free_greedy_byte_identical(setup32, mode, paged):
+def test_model_free_greedy_byte_identical(setup32, plain32, mode, paged):
     """Greedy output under model-free speculation is byte-identical to
     plain decode — dense and paged — with ZERO extra checkpoint bytes
     (no draft params, no draft KV; self_draft only adds the k-layer
     scratch)."""
     cfg, params = setup32
-    plain = _mk(cfg, params)
     spec = _mk_free(cfg, params, mode, paged=paged)
     try:
         assert spec.draft_params is None and spec.d_cache is None
@@ -292,32 +313,23 @@ def test_model_free_greedy_byte_identical(setup32, mode, paged):
             assert spec.sd_cache.k.shape[0] == spec._sd_layers < cfg.num_layers
         else:
             assert spec.sd_cache is None
-        for prompt in PROMPTS:
-            t_p, ev_p = plain.generate(prompt, max_new_tokens=24,
-                                       ignore_eos=True)
+        for prompt, (t_p, ev_p) in zip(PROMPTS, plain32):
             t_s, ev_s = spec.generate(prompt, max_new_tokens=24,
                                       ignore_eos=True)
             assert t_s == t_p, (mode, paged, prompt, t_p, t_s)
             assert ev_s.completion_tokens == ev_p.completion_tokens
         # Whether rounds fire on arbitrary prompts depends on when the
         # stream turns repetitive vs how much budget the plain pipeline
-        # already scheduled — pin a deterministic draft opportunity (the
-        # prompt repeats the biased continuation token, so the FIRST
-        # dispatch after admission is a verify round) for the engagement
+        # already scheduled — the pinned prompt is there for the engagement
         # asserts.
-        pinned = [10] + [77] * 20
-        bias = {77: 25.0}
-        t_p, _ = plain.generate(pinned, max_new_tokens=24, ignore_eos=True,
-                                logit_bias=bias)
-        t_s, _ = spec.generate(pinned, max_new_tokens=24, ignore_eos=True,
-                               logit_bias=bias)
-        assert t_s == t_p
+        t_s, _ = spec.generate(PINNED, max_new_tokens=24, ignore_eos=True,
+                               logit_bias=PINNED_BIAS)
+        assert t_s == plain32[-1][0]
         m = spec.metrics()
         assert m["spec_rounds"] > 0, "model-free speculation never engaged"
         assert 0.0 < m["spec_accept_rate"] <= 1.0
         assert m["spec_tokens_drafted"] > 0
     finally:
-        plain.stop()
         spec.stop()
 
 

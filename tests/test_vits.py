@@ -10,15 +10,17 @@ import numpy as np
 import pytest
 
 torch = pytest.importorskip("torch")
-from transformers import VitsConfig as HFVitsConfig  # noqa: E402
-from transformers import VitsModel  # noqa: E402
-
 from localai_tpu.models import vits as V  # noqa: E402
 
 
 @pytest.fixture(scope="module")
 def tiny_ckpt(tmp_path_factory):
     """A tiny random VitsModel saved in the real HF layout."""
+    # imported here and not at the top: `transformers`' model classes pull in
+    # TensorFlow, 7 s that every worker of the run would pay at collection
+    from transformers import VitsConfig as HFVitsConfig
+    from transformers import VitsModel
+
     d = tmp_path_factory.mktemp("vits")
     cfg = HFVitsConfig(
         vocab_size=40, hidden_size=16, num_hidden_layers=2, num_attention_heads=2,

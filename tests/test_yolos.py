@@ -8,14 +8,16 @@ import numpy as np
 import pytest
 
 torch = pytest.importorskip("torch")
-from transformers import YolosConfig as HFYolosConfig  # noqa: E402
-from transformers import YolosForObjectDetection  # noqa: E402
-
 from localai_tpu.models import yolos as Y  # noqa: E402
 
 
 @pytest.fixture(scope="module")
 def tiny_ckpt(tmp_path_factory):
+    # imported here and not at the top: `transformers`' model classes pull in
+    # TensorFlow, 7 s that every worker of the run would pay at collection
+    from transformers import YolosConfig as HFYolosConfig
+    from transformers import YolosForObjectDetection
+
     d = tmp_path_factory.mktemp("yolos")
     cfg = HFYolosConfig(
         hidden_size=32, num_hidden_layers=2, num_attention_heads=2,
