@@ -80,6 +80,9 @@ SIZES = {
         # of 8 layers
         hybrid_gqa=dict(kda_layers=6, slots=64, heads=64, dk=128, K=8, G=8,
                         moe=dict(layers=8, experts=40, hidden=4096, ffn=1280)),
+        # granite-4.0-h-small's SSD state as published, 4 of its 36 layers:
+        # 32 slots of 128 heads of 64 x 128 float32 (4 MiB a slot and layer)
+        ssd=dict(layers=4, slots=32, heads=128, P=64, N=128),
     ),
     "tiny": dict(
         arch="tiny", slots=4, context=512, page=16,
@@ -94,6 +97,7 @@ SIZES = {
                     moe=dict(layers=2, experts=4, hidden=64, ffn=32)),
         hybrid_gqa=dict(kda_layers=2, slots=4, heads=4, dk=16, K=2, G=4,
                         moe=dict(layers=2, experts=3, hidden=64, ffn=40)),
+        ssd=dict(layers=3, slots=4, heads=8, P=16, N=32),
     ),
 }
 
@@ -583,6 +587,35 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
         -jnp.exp(rnd((Bg, Hg, dg), jnp.float32) * 2.0 - 3.0),
         2.0 * jax.nn.sigmoid(rnd((Bg, Hg), jnp.float32)),
         jnp.int32(0), jnp.int32(Lg - 1)), 1e-4)
+
+    # SSD (Mamba-2) decode on the stacked float32 state at Granite-4.0-H's
+    # published 128 heads of 64 x 128: first and last layer, the layer a
+    # scalar-prefetch operand, the state aliased, against the XLA step. Half
+    # the rows live (a state of unit size), half dead (a released tenant's
+    # garbage, 1e4 as large: the kernel updates every row alike); the layers
+    # between keep their rows. Elementwise float32 both sides; the sum over
+    # d_state runs in another order -> 1e-4.
+    sd = s["ssd"]
+    from localai_tpu.ops import ssd as SSD
+
+    Ls, Bs, Hs, Ps, Ns = sd["layers"], sd["slots"], sd["heads"], sd["P"], sd["N"]
+
+    def ssd_two(impl):
+        def fn(state, x, dt, A, Bm, Cm, Dk, first, last):
+            outs = []
+            for i in (first, last):
+                y, state = SSD.ssd_decode(state, i, x, dt, A, Bm, Cm, Dk,
+                                          impl=impl)
+                outs.append(y)
+            return tuple(outs) + (state[first], state[last], state[1])
+        return fn
+
+    dead = jnp.where(jnp.arange(Bs) % 2 == 1, 1e4, 1.0)[None, :, None, None, None]
+    case("ssd_decode_stacked_h128_p64_n128", ssd_two("auto"), ssd_two("xla"), (
+        rnd((Ls, Bs, Hs, Ps, Ns), jnp.float32) * dead,
+        rnd((Bs, Hs, Ps)), jax.nn.softplus(rnd((Bs, Hs), jnp.float32) - 3.0),
+        -jnp.exp(rnd((Hs,), jnp.float32)), rnd((Bs, 1, Ns)), rnd((Bs, 1, Ns)),
+        jnp.ones((Hs,), jnp.float32), jnp.int32(0), jnp.int32(Ls - 1)), 1e-4)
 
     # MLA's absorbed decode over the latent pool stacked over the MLA layers
     # ([L, P, page, 1, 640]: one row a token, key and value): the latent

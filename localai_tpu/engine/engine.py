@@ -6411,6 +6411,11 @@ class Engine:
                 sites["pool_write_inplace"])
             out["pool_write_scatter_sites"] = float(
                 sites["pool_write_scatter"])
+        if sites["ssd_decode_pallas"] or sites["ssd_decode_xla"]:
+            # the form each SSD layer's decode update took: the kernel on the
+            # stacked state in place, or the XLA step (stacked.note_ssd)
+            out["ssd_decode_pallas_sites"] = float(sites["ssd_decode_pallas"])
+            out["ssd_decode_xla_sites"] = float(sites["ssd_decode_xla"])
         if self.m_forks or self.m_fork_clone_fallbacks:
             # Tree-batched fork sampling (ISSUE 18): branches admitted by
             # slot fork vs degraded to the N-clone path (fault/pressure).

@@ -8,8 +8,12 @@ recurrent state is fixed in size, `row_bytes` a slot whatever the context,
 and its shape is the recurrent kind's (`cfg.recurrent_kind`): per KDA layer
 a [H, dk, dv] float32 matrix a head and the short conv's last inputs; per
 gated short convolution ("conv", LFM2) the operator's last conv_cache-1
-inputs [conv_cache-1, D] and no matrix at all (`state` is then None). It
-needs no allocator: row i of the arrays below belongs to slot index i,
+inputs [conv_cache-1, D] and no matrix at all (`state` is then None); per SSD
+layer ("ssd", Mamba-2: Granite-4.0-H) a [H, P, N] float32 matrix (4 MiB at
+128 heads of 64 x 128) and the conv's last mamba_conv-1 inputs over
+[x | B | C]. `_ROWS` holds the shape a kind, `_ADMIT_TOKEN_BYTES` what its
+chunked prefill holds a prompt token. It needs no allocator: row i of the
+arrays below belongs to slot index i,
 always.
 
 The arrays ride in the cache pytree (`llama.KVCache.state`, `.conv`), so every
@@ -20,9 +24,10 @@ device's order of programs is the order of their owners:
             (`llama.prefill(recurrent=...)`: the state after the last prompt
             token, computed from zero). Nothing of an earlier tenant is read.
 - decode:   every block updates every row in place, live or not
-            (ops/kda.kda_decode, aliased; a conv row shifts by one input). A
-            row without a tenant decays garbage into garbage; it stays
-            bounded (KDA's update is a contraction, a conv row forgets after
+            (ops/kda.kda_decode, ops/ssd.ssd_decode, aliased; a conv row
+            shifts by one input). A row without a tenant decays garbage into
+            garbage; it stays bounded (KDA's update is a contraction, SSD's
+            a decay < 1 plus a bounded input, a conv row forgets after
             conv_cache-1 steps) and is never read by a tenant.
 - park:     a tenant whose dispatched blocks cover its budget leaves the index
             (`Engine._park`); its blocks in flight still update the row, and
@@ -53,20 +58,48 @@ import jax.numpy as jnp
 ADMIT_BYTES = 1 << 30
 
 
-def admit_rows(cfg) -> int | None:
-    """Most prompt rows (requests x bucket) one admission program takes under
-    `ADMIT_BYTES`, from the model's own widths: the widest temporaries are
-    the diagonal blocks' pairwise exponents and their exponentials,
-    [SUB, SUB, dk] float32 a sub-block and head each (ops/kda.py), so
-    2·H·SUB·dk·4 bytes a prompt token: 0.5 MB and 2,048 rows at 32 heads of
-    128, 1 MB and 1,024 rows at 64. None for a model without KDA layers (a
-    conv model's prefill holds a few [T, D] rows a prompt, as any layer's):
-    its admission groups are not bounded here."""
-    if cfg.recurrent_kind != "kda":
-        return None
+def _kda_token_bytes(cfg) -> int:
+    """The chunkwise KDA prefill's widest temporaries a prompt token: the
+    diagonal blocks' pairwise exponents and their exponentials,
+    [SUB, SUB, dk] float32 a sub-block and head each (ops/kda.py):
+    2·H·SUB·dk·4 bytes, 0.5 MB at 32 heads of 128, 1 MB at 64."""
     from localai_tpu.ops.kda import SUB
 
-    return max(1, ADMIT_BYTES // (2 * cfg.kda_heads * SUB * cfg.kda_head_dim * 4))
+    return 2 * cfg.kda_heads * SUB * cfg.kda_head_dim * 4
+
+
+def _ssd_token_bytes(cfg) -> int:
+    """The chunkwise SSD prefill's float32 temporaries a prompt token
+    (ops/ssd.ssd_chunk_prefill): four rows of [heads, chunk] (the chunk's
+    pairwise log-decays, their exponentials, the product with C B^T and its
+    copy in the layout the dot reads), a chunk's share of its state
+    ([H, P, N] / chunk, held twice: what the chunk adds and what enters it),
+    and six of [H, P] (x, dt x, the two halves of y, y, the gated y): 0.5 MB
+    at 128 heads of 64 x 128 in chunks of 128. The whole admission program
+    holds 0.65 MB a token beside them, most of it the grouped expert path's
+    [rows, top-k, D] float32 rows, any MoE model's (tools/cell_program.py
+    --program admit for a described v5e: 0.87 GB of temporaries at 4 x 256
+    rows, 1.53 GB at 8 x 256)."""
+    from localai_tpu.ops.ssd import CHUNK
+
+    H, P, N = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_d_state
+    C = min(cfg.mamba_chunk, CHUNK)
+    return 4 * (4 * H * C + 2 * H * P * N // C + 6 * H * P)
+
+
+# Bytes of float32 temporaries one prompt token costs an admission program,
+# by recurrent kind; a kind that is not here is not bounded (a conv model's
+# prefill holds a few [T, D] rows a prompt, as any layer's).
+_ADMIT_TOKEN_BYTES = {"kda": _kda_token_bytes, "ssd": _ssd_token_bytes}
+
+
+def admit_rows(cfg) -> int | None:
+    """Most prompt rows (requests x bucket) one admission program takes under
+    `ADMIT_BYTES`, from the model's own widths (`_ADMIT_TOKEN_BYTES`): 2,048
+    rows at KDA's 32 heads of 128, 1,024 at 64; 2,048 at SSD's 128 heads of
+    64 x 128. None for a kind without a bound."""
+    per_token = _ADMIT_TOKEN_BYTES.get(cfg.recurrent_kind)
+    return max(1, ADMIT_BYTES // per_token(cfg)) if per_token else None
 
 
 def refuse(cfg, ecfg, plan, draft_cfg, spec_mode: str) -> None:
@@ -98,18 +131,33 @@ def refuse(cfg, ecfg, plan, draft_cfg, spec_mode: str) -> None:
             "does not run it with: " + "; ".join(no))
 
 
-def _shapes(cfg, slots: int):
-    """(state shape | None, conv shape) of `slots` rows, by recurrent kind."""
-    Lk = len(cfg.recurrent_layers)
-    if cfg.recurrent_kind == "conv":
-        return None, (Lk, slots, cfg.conv_cache - 1, cfg.hidden_size)
+def _kda_rows(cfg, Lk: int, slots: int):
     H, d = cfg.kda_heads, cfg.kda_head_dim
     return (Lk, slots, H, d, d), (Lk, slots, cfg.kda_conv - 1, 3 * H * d)
 
 
+def _conv_rows(cfg, Lk: int, slots: int):
+    return None, (Lk, slots, cfg.conv_cache - 1, cfg.hidden_size)
+
+
+def _ssd_rows(cfg, Lk: int, slots: int):
+    return ((Lk, slots, cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_d_state),
+            (Lk, slots, cfg.mamba_conv - 1, cfg.mamba_conv_dim))
+
+
+_ROWS = {"kda": _kda_rows, "conv": _conv_rows, "ssd": _ssd_rows}
+
+
+def _shapes(cfg, slots: int):
+    """(state shape | None, conv shape) of `slots` rows, by recurrent kind."""
+    return _ROWS[cfg.recurrent_kind](cfg, len(cfg.recurrent_layers), slots)
+
+
 def allocate(cfg, slots: int, conv_dtype, sharding=None):
     """(state [Lk, slots, H, dk, dv] f32, conv [Lk, slots, c-1, 3·H·dk]) of a
-    KDA model; (None, conv [Lc, slots, conv_cache-1, D]) of a conv model."""
+    KDA model; (None, conv [Lc, slots, conv_cache-1, D]) of a conv model;
+    (state [Lm, slots, H, P, N] f32, conv [Lm, slots, c-1, d_inner + 2·G·N])
+    of an SSD model."""
     st, cv = _shapes(cfg, slots)
     rows = (None if st is None else jnp.zeros(st, jnp.float32),
             jnp.zeros(cv, conv_dtype))

@@ -15,7 +15,7 @@ from typing import Optional
 
 
 # The layer kinds that keep a per-slot recurrent state and write no cache row.
-RECURRENT_KINDS = ("kda", "conv")
+RECURRENT_KINDS = ("kda", "conv", "ssd")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,6 +65,15 @@ class ArchConfig:
     # two need runtime branches.
     activation: str = "silu"  # "silu" | "gelu_tanh"
     embed_scale: bool = False
+    # Granite's four scalars (HF `GraniteMoeHybrid`): the embedding row times
+    # `embedding_multiplier`, every residual branch times
+    # `residual_multiplier` before it is added, the logits divided by
+    # `logits_scaling`. 1 = no op is emitted. The fourth, the published
+    # `attention_multiplier` m in place of head_dim^-0.5, is `query_scale`
+    # = m^-2 below.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     norm_plus_one: bool = False  # load-time fold (engine/weights.py)
     # Gemma-2: sandwich norms (post-attention and post-feedforward RMSNorms
     # inside the residual adds), tanh softcapping on attention scores and
@@ -189,13 +198,30 @@ class ArchConfig:
     # dense-prefix layers to be "kda".
     # "conv" (LFM2's gated short convolution) is the other recurrent kind: in
     # "kda"'s place everywhere above, its per-slot state the operator's last
-    # conv_cache-1 inputs [conv_cache-1, hidden_size] and nothing else. One
+    # conv_cache-1 inputs [conv_cache-1, hidden_size] and nothing else.
+    # "ssd" (Mamba-2's state-space duality layer, Granite-4.0-H) is the third:
+    # its per-slot state a [mamba_heads, mamba_head_dim, mamba_d_state] f32
+    # matrix a layer with a SCALAR decay a head, and the conv's last
+    # mamba_conv-1 inputs [mamba_conv-1, d_inner + 2·groups·d_state]. One
     # model has one recurrent kind (`recurrent_kind`).
     layer_kinds: tuple = ()
     # LFM2's conv_L_cache: the taps of the short conv. Neither the taps nor
     # the two projections have a bias (the published `conv_bias` is false in
     # every LFM2 config; there is no field for a value nothing here computes)
     conv_cache: int = 3
+    # Mamba-2 / SSD widths under their published names (`mamba_n_heads`,
+    # `mamba_d_head`, `mamba_d_state`, `mamba_n_groups`, `mamba_d_conv`,
+    # `mamba_chunk_size`: the chunk the model was trained in; the serving
+    # prefill blocks by at most ops/ssd.CHUNK, an exact sub-blocking);
+    # d_inner = heads x head_dim. The conv has a bias
+    # (`mamba_conv_bias` true in every published Granite-4.0-H config), the
+    # two projections none.
+    mamba_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_d_state: int = 128
+    mamba_groups: int = 1
+    mamba_conv: int = 4
+    mamba_chunk: int = 256
     kda_heads: int = 0
     kda_head_dim: int = 128
     kda_conv: int = 4  # short_conv_kernel_size
@@ -225,19 +251,19 @@ class ArchConfig:
 
     @property
     def recurrent_kind(self) -> str:
-        """A hybrid model's recurrent kind, "kda" or "conv" ("" = none). A
-        stack that mixes the two is refused here, by name."""
+        """A hybrid model's recurrent kind, "kda", "conv" or "ssd" ("" =
+        none). A stack that mixes two is refused here, by name."""
         kinds = {k for k in self.layer_kinds if k in RECURRENT_KINDS}
         if len(kinds) > 1:
             raise NotImplementedError(
                 f"{self.name}: layer_kinds mixes the recurrent kinds "
-                f"{sorted(kinds)}; one model has one (a 'kda' + 'conv' stack "
+                f"{sorted(kinds)}; one model has one (a stack of two "
                 "would need two per-slot states and two scans)")
         return next(iter(kinds), "")
 
     @property
     def recurrent_layers(self) -> tuple:
-        """Model layer numbers of the recurrent (KDA or conv) layers."""
+        """Model layer numbers of the recurrent (KDA, conv or SSD) layers."""
         return tuple(i for i, k in enumerate(self.layer_kinds)
                      if k in RECURRENT_KINDS)
 
@@ -245,6 +271,15 @@ class ArchConfig:
     def recurrent_stack(self) -> str:
         """The key of the recurrent layers' weight stack in the param tree."""
         return f"{self.recurrent_kind}_layers"
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of the SSD layer's short conv: [x | B | C]."""
+        return self.mamba_d_inner + 2 * self.mamba_groups * self.mamba_d_state
 
     @property
     def cache_layer_ids(self) -> tuple:
@@ -517,6 +552,41 @@ PRESETS: dict[str, ArchConfig] = {
         router_bias=True,
         norm_topk_prob=True,
         norm_topk_eps=1e-6,
+    ),
+    "tiny-granite-h": ArchConfig(
+        # Granite-4.0-H-shaped tiny: two periods of 4 SSD (Mamba-2) layers
+        # and one NoPE GQA layer BEHIND a Mamba layer of its own (the
+        # published pattern has its attention layers at 5, 15, 25, 35), every
+        # layer 8 experts top-3 routed the "mixtral" way (softmax over the
+        # picks' logits) beside a shared MLP, a tied head, and all four
+        # scalar multipliers away from 1.
+        name="tiny-granite-h",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=32,
+        num_layers=10,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_position=512,
+        tie_embeddings=True,
+        attn_rope=False,
+        query_scale=64.0,  # scores x 1/8, not 16^-0.5
+        embedding_multiplier=6.0,
+        residual_multiplier=0.35,
+        logits_scaling=4.0,
+        layer_kinds=("ssd", "ssd", "ssd", "ssd", "gqa") * 2,
+        mamba_heads=8,
+        mamba_head_dim=16,
+        mamba_d_state=32,
+        mamba_groups=1,
+        mamba_conv=4,
+        mamba_chunk=32,
+        moe_family="mixtral",
+        num_experts=8,
+        num_experts_per_token=3,
+        n_shared_experts=2,
+        moe_intermediate_size=32,
     ),
     "llama-3.2-1b": ArchConfig(
         name="llama-3.2-1b",
@@ -796,6 +866,46 @@ PRESETS: dict[str, ArchConfig] = {
         router_bias=True,
         norm_topk_prob=True,
         norm_topk_eps=1e-6,
+    ),
+    "granite-4.0-h-small": ArchConfig(
+        # ibm-granite/granite-4.0-h-small config.json (`granitemoehybrid`,
+        # 32B-A9B): 40 layers, 36 Mamba-2 (SSD) layers (128 heads of 64,
+        # d_state 128, one group, conv 4 with a bias, chunk 256) and 4 NoPE
+        # GQA layers (32 query / 8 KV heads of 128, scores x 1/128) at 5, 15,
+        # 25, 35; every layer 72 experts of 768 top-10 (the 10 largest
+        # logits, softmax over those 10) plus a shared MLP of 1536 (2 x 768
+        # in this repo's fields); embeddings x 12, residual branches x 0.22,
+        # logits / 16; tied head.
+        name="granite-4.0-h-small",
+        vocab_size=100352,
+        hidden_size=4096,
+        intermediate_size=768,
+        num_layers=40,
+        num_heads=32,
+        num_kv_heads=8,
+        head_dim=128,
+        rope_theta=10000.0,
+        max_position=131072,
+        rms_eps=1e-5,
+        tie_embeddings=True,
+        attn_rope=False,
+        query_scale=16384.0,  # attention_multiplier 0.0078125 = 16384^-0.5
+        embedding_multiplier=12.0,
+        residual_multiplier=0.22,
+        logits_scaling=16.0,
+        layer_kinds=tuple(
+            "gqa" if i in (5, 15, 25, 35) else "ssd" for i in range(40)),
+        mamba_heads=128,
+        mamba_head_dim=64,
+        mamba_d_state=128,
+        mamba_groups=1,
+        mamba_conv=4,
+        mamba_chunk=256,
+        moe_family="mixtral",
+        num_experts=72,
+        num_experts_per_token=10,
+        n_shared_experts=2,
+        moe_intermediate_size=768,
     ),
 }
 

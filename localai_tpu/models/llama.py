@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from localai_tpu.models import quant
 from localai_tpu.models.config import ArchConfig
 from localai_tpu.models.quant import matmul, unembed_matmul
-from localai_tpu.observe.scopes import CONV_MIX, scope
+from localai_tpu.observe.scopes import CONV_MIX, SSD_MIX, scope
 from localai_tpu.ops.attention import (
     _merge_partials_mq,
     decode_attention,  # noqa: F401 — public, used by tests/benchmarks
@@ -142,7 +142,8 @@ def _init_attn_layers(cfg: ArchConfig, rnd, keys, L: int,
     layers["wq"] = rnd(next(keys), (L, D, H * Hd))
     layers["wk"] = rnd(next(keys), (L, D, K * Hd))
     layers["wv"] = rnd(next(keys), (L, D, K * Hd))
-    layers["wo"] = rnd(next(keys), (L, H * Hd, D))
+    layers["wo"] = rnd(next(keys), (L, H * Hd, D),
+                       init_gain(cfg, "wo", (L, H * Hd, D)))
     if cfg.attn_gate:
         layers["wg"] = rnd(next(keys), (L, D, H * Hd))
     if cfg.post_norms:
@@ -164,8 +165,11 @@ def _init_attn_layers(cfg: ArchConfig, rnd, keys, L: int,
 def init_special(name: str, key, shape, step=None):
     """The leaves a normal draw at 0.02 would make degenerate (KDA's decay:
     A as fla's KimiDeltaAttention draws it, the step from `step`, the model's
-    `kda_init_dt`, `KDA_DT` by default; the short conv), float32. None for
-    any other leaf."""
+    `kda_init_dt`, `KDA_DT` by default; the short conv; SSD's A and step drawn
+    the same way, as Mamba-2 does, from `SSD_DT`, and its skip D at 1),
+    float32. None for any other leaf."""
+    if name == "ssm_D":  # the skip passes x on whole
+        return jnp.ones(shape, jnp.float32)
     if name == "conv_w":  # four taps that pass their input on at its size
         return jax.random.normal(key, shape, jnp.float32) * 0.5
     if name == "A_log":  # A in U(1, 16)
@@ -182,6 +186,8 @@ def init_special(name: str, key, shape, step=None):
 # a long-context model's slow channels do: the regime the float32 state is
 # kept for (held in bfloat16 it drifts by the root of the tokens remembered).
 KDA_DT = (1e-5, 1e-3)
+# The step of a synthetic SSD layer: Mamba-2's published dt_min and dt_max.
+SSD_DT = (1e-3, 1e-1)
 
 
 def init_gain(cfg: ArchConfig, name: str, shape) -> float:
@@ -189,8 +195,31 @@ def init_gain(cfg: ArchConfig, name: str, shape) -> float:
     hybrid model's routed experts' down-projection ([L, E, F, D]) is drawn at
     a tenth: its router renormalises and scales the picks, so with random
     experts a pick that flips at a near-tie would move the stream by a
-    quarter of a layer, and every rounding anywhere reads as routing noise."""
-    return 0.1 if cfg.is_hybrid and name == "w_down" and len(shape) == 4 else 1.0
+    quarter of a layer, and every rounding anywhere reads as routing noise.
+
+    A model with Granite's scalar multipliers is drawn so that they are
+    undone, and the random model is the random model of every other family:
+    the embedding `logits_scaling` times as large (a random head then
+    spreads its logits by scale x sqrt(D), where a sixteenth of it leaves
+    every log-probability at log(1/V) and no rounding, honest or not, can be
+    told from another), and every residual branch's out-projection by
+    `embedding_multiplier` x `logits_scaling` / `residual_multiplier`, so
+    that the stream is its layers' as much as in a model without them. With
+    the embedding alone enlarged a tied head reads the token's own row out
+    of the stream: every position predicted its own token with probability
+    1 on the chip, every slot decoded the chat template's last token, all
+    rows routed alike (19% of the held experts active) and a cache held one
+    precision lower read like the honest one (PERF.md section 6, PR 46).
+    All three are 1.0 exactly where the multipliers are 1."""
+    if name == "embed":
+        return float(cfg.logits_scaling)
+    gain = 1.0
+    if name in ("wo", "w_down", "shared_down"):
+        gain = float(cfg.embedding_multiplier * cfg.logits_scaling
+                     / cfg.residual_multiplier)
+    if cfg.is_hybrid and name == "w_down" and len(shape) == 4:
+        gain *= 0.1
+    return gain
 
 
 def _init_kda_layers(cfg: ArchConfig, rnd, keys, L: int) -> Params:
@@ -233,6 +262,36 @@ def _init_conv_layers(cfg: ArchConfig, rnd, keys, L: int) -> Params:
     }
 
 
+def _init_ssd_layers(cfg: ArchConfig, rnd, keys, L: int) -> Params:
+    """The stack of a hybrid model's SSD (Mamba-2) layers: the in-projection
+    to [z | xBC | dt], held as its three column blocks, and the
+    out-projection int8-able, the depthwise taps, their bias and the gated
+    norm's weight in the model dtype, the decay's two vectors and the skip
+    in float32. Three leaves and not one [D, 2 d_inner + 2 G N + H]: at
+    Granite-4.0-H's widths that is 16,768 = 131 x 128 columns, no multiple
+    of 128 under it divides them, and the dequant-matmul walks such a block
+    in 131 dots of 128 columns (19% of the HBM's rate on the chip, PERF.md
+    section 6, PR 46); 8,192 | 8,448 | 128 each take whole-row blocks."""
+    D, H = cfg.hidden_size, cfg.mamba_heads
+    di, cd = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    gain = init_gain(cfg, "wo", (L, di, D))
+    return {
+        "w_z": rnd(next(keys), (L, D, di)),
+        "w_xbc": rnd(next(keys), (L, D, cd)),
+        "w_dt": rnd(next(keys), (L, D, H)),
+        # depthwise causal conv over time of [x | B | C], tap mamba_conv-1 on
+        # the current token, with a bias
+        "conv_w": init_special(
+            "conv_w", next(keys), (L, cfg.mamba_conv, cd)).astype(_dtype(cfg)),
+        "conv_b": rnd(next(keys), (L, cd)),
+        "dt_bias": init_special("dt_bias", next(keys), (L, H), SSD_DT),
+        "A_log": init_special("A_log", next(keys), (L, H)),
+        "ssm_D": init_special("ssm_D", next(keys), (L, H)),
+        "o_norm": jnp.ones((L, di), _dtype(cfg)),
+        "wo": rnd(next(keys), (L, di, D), gain),
+    }
+
+
 def init_params(cfg: ArchConfig, key: jnp.ndarray, scale: float = 0.02) -> Params:
     """Random init with HF-compatible tree structure (stacked layers).
 
@@ -265,14 +324,16 @@ def init_params(cfg: ArchConfig, key: jnp.ndarray, scale: float = 0.02) -> Param
             Fs = cfg.n_shared_experts * Fm
             layers["shared_gate"] = rnd(next(keys), (Lm, D, Fs))
             layers["shared_up"] = rnd(next(keys), (Lm, D, Fs))
-            layers["shared_down"] = rnd(next(keys), (Lm, Fs, D))
+            layers["shared_down"] = rnd(next(keys), (Lm, Fs, D),
+                                        init_gain(cfg, "shared_down", (Lm, Fs, D)))
     else:
         layers["w_gate"] = rnd(next(keys), (L, D, F))
         layers["w_up"] = rnd(next(keys), (L, D, F))
         layers["w_down"] = rnd(next(keys), (L, F, D))
 
     params: Params = {
-        "embed": rnd(next(keys), (cfg.vocab_size, D)),
+        "embed": rnd(next(keys), (cfg.vocab_size, D),
+                     init_gain(cfg, "embed", (cfg.vocab_size, D))),
         "layers": layers,
         "final_norm": jnp.ones((D,), dt),
     }
@@ -287,10 +348,8 @@ def init_params(cfg: ArchConfig, key: jnp.ndarray, scale: float = 0.02) -> Param
     if cfg.is_hybrid:
         hk = iter(jax.random.split(jax.random.fold_in(key, 1), 32))
         Lr = len(cfg.recurrent_layers)
-        if cfg.recurrent_kind == "conv":
-            params["conv_layers"] = _init_conv_layers(cfg, rnd, hk, Lr)
-        else:
-            params["kda_layers"] = _init_kda_layers(cfg, rnd, hk, Lr)
+        stack = cfg.recurrent_stack  # "kda_layers" | "conv_layers" | "ssd_layers"
+        params[stack] = RECURRENT[cfg.recurrent_kind].init(cfg, rnd, hk, Lr)
         cache_stack = cfg.cache_stack  # "mla_layers" | "gqa_layers"
         params[cache_stack] = _init_attn_layers(
             cfg, rnd, hk, cfg.cache_layers, cache_stack=True)
@@ -1012,7 +1071,18 @@ def _embed(cfg: ArchConfig, params: Params, tokens: jnp.ndarray) -> jnp.ndarray:
     h = params["embed"][tokens]
     if cfg.embed_scale:
         h = (h.astype(jnp.float32) * (cfg.hidden_size**0.5)).astype(h.dtype)
+    if cfg.embedding_multiplier != 1.0:  # Granite
+        h = (h.astype(jnp.float32) * cfg.embedding_multiplier).astype(h.dtype)
     return h
+
+
+def _residual(cfg: ArchConfig, h: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
+    """h + y, the branch first scaled by Granite's `residual_multiplier`
+    (in float32, rounded once; a multiplier of 1 emits the plain add)."""
+    if cfg.residual_multiplier == 1.0:
+        return h + y
+    return (h.astype(jnp.float32) + y.astype(jnp.float32)
+            * cfg.residual_multiplier).astype(h.dtype)
 
 
 def _act(cfg: ArchConfig, x: jnp.ndarray) -> jnp.ndarray:
@@ -1046,6 +1116,8 @@ def _unembed(cfg: ArchConfig, params: Params, h: jnp.ndarray,
     logits = unembed_matmul(h, w, cfg.quant_kernel, mesh)
     if cfg.final_softcap:
         logits = cfg.final_softcap * jnp.tanh(logits / cfg.final_softcap)
+    if cfg.logits_scaling != 1.0:  # Granite
+        logits = logits / cfg.logits_scaling
     return logits
 
 
@@ -1160,21 +1232,24 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
         # as the cache holds them: `cache_pack` heads a row (a free reshape)
         emit = tuple(a.reshape(*a.shape[:-2], cfg.cache_kv_heads, -1)
                      for a in (k, v)) if cfg.cache_pack > 1 else (k, v)
-    h = h + _attn_out(cfg, lp, attn, mesh, lora=lora)
+    h = _residual(cfg, h, _attn_out(cfg, lp, attn, mesh, lora=lora))
     x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-    return h + _mlp_out(cfg, lp, x, ep, mesh, lora=lora, picks=picks,
-                        admit=admit), emit
+    return _residual(cfg, h, _mlp_out(cfg, lp, x, ep, mesh, lora=lora,
+                                      picks=picks, admit=admit)), emit
 
 
 # --------------------------------------------------------------------------- #
-# Hybrid models (Kimi-Linear, Solar-Open2, LFM2): recurrent layers with a
-# per-slot state beside layers that write cache rows, MLA's latent ones or
-# GQA's ordinary keys and values (cfg.layer_kinds).
+# Hybrid models (Kimi-Linear, Solar-Open2, LFM2, Granite-4.0-H): recurrent
+# layers with a per-slot state beside layers that write cache rows, MLA's
+# latent ones or GQA's ordinary keys and values (cfg.layer_kinds).
 #
 # A recurrent layer writes no cache row. Of the model's one recurrent kind
 # (`cfg.recurrent_kind`) a KDA layer keeps, per slot, a [H, dk, dv] float32
 # state and the short conv's last inputs; a gated short convolution ("conv")
-# its last conv_cache-1 inputs and nothing else. The two kinds' weights live
+# its last conv_cache-1 inputs and nothing else; an SSD (Mamba-2) layer a
+# [H, P, N] float32 state and its conv's last inputs. What a kind brings
+# (its stack's init, its decode and prefill mixers) is one entry of
+# `RECURRENT`. The two kinds' weights live
 # in their own stacks (`cfg.recurrent_stack`, `cfg.cache_stack`), the norms
 # and the MLPs in the model's layer stacks as ever. `_scan_hybrid` scans the
 # recurrent layers and runs the cache layer that stands beside one under a
@@ -1309,9 +1384,10 @@ def _conv_out(cfg: ArchConfig, ap: Params, c, window):
 
 
 @jax.named_scope(CONV_MIX)
-def _conv_decode_mix(cfg: ArchConfig, ap: Params, x, rec, j):
+def _conv_decode_mix(cfg: ArchConfig, ap: Params, x, rec, j, impl="auto"):
     """One token per slot: x [B, D], rec = (None, conv) with conv the rows
-    stacked over the conv layers, j this layer's index in them. Returns
+    stacked over the conv layers, j this layer's index in them (`impl`: the
+    operator has no kernel to choose). Returns
     (y [B, D], rec). The operator whole is written under `CONV_MIX`, around
     its leaves: XLA names a fusion after any op in it (on the chip the taps
     and the gate after W_out's reshape), so only a word every op of the
@@ -1345,6 +1421,127 @@ def _conv_prefill_mix(cfg: ArchConfig, ap: Params, x, lengths, rec, j, slots):
             conv = rec[1].at[j, slots].set(rows.astype(rec[1].dtype))
         rec = (None, conv)
     return _conv_out(cfg, ap, c, window), rec
+
+
+@scope("attention/proj")
+def _ssd_inputs(cfg: ArchConfig, ap: Params, x: jnp.ndarray, conv_prev):
+    """x [B, T, D] (normed) -> the recurrence's operands and what the layer
+    needs after it: [z | xBC | dt] = x W_in (its three column blocks, a leaf
+    each), xBC through the short conv (its
+    inputs before x's first token in `conv_prev` [B, c-1, conv_dim], zeros at
+    a prompt's start), its bias and silu, then split [x | B | C].
+
+    Returns (xs [B, T, H, P] f32, dt [B, T, H] f32 after the softplus, Bm, Cm
+    [B, T, G, N] f32, z [B, T, d_inner], window [B, c-1+T, conv_dim]: the
+    conv's inputs, from which the caller cuts the rows the next token will
+    need)."""
+    f32 = jnp.float32
+    B, T, _ = x.shape
+    H, P, N, G = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_d_state,
+                  cfg.mamba_groups)
+    di, c = cfg.mamba_d_inner, cfg.mamba_conv
+    z, pre, dt = (matmul(x, ap[n], cfg.quant_kernel)
+                  for n in ("w_z", "w_xbc", "w_dt"))
+    window = jnp.concatenate([conv_prev.astype(pre.dtype), pre], axis=1)
+    w = ap["conv_w"].astype(f32)  # [c, conv_dim]
+    y = sum(window[:, i:i + T].astype(f32) * w[i] for i in range(c))
+    y = jax.nn.silu(y + ap["conv_b"].astype(f32))
+    xs = y[..., :di].reshape(B, T, H, P)
+    Bm = y[..., di:di + G * N].reshape(B, T, G, N)
+    Cm = y[..., di + G * N:].reshape(B, T, G, N)
+    dt = jax.nn.softplus(dt.astype(f32) + ap["dt_bias"].astype(f32))
+    return xs, dt, Bm, Cm, z, window
+
+
+@scope("attention/out")
+def _ssd_out(cfg: ArchConfig, ap: Params, y, z, dtype, mesh=None):
+    """y [..., H, P] f32 -> the gate silu(z), THEN one RMSNorm over all of
+    d_inner (Mamba-2's gated norm, `norm_before_gate` false, one group), W_o."""
+    y = y.reshape(*y.shape[:-2], -1) * jax.nn.silu(z.astype(jnp.float32))
+    y = rms_norm(y, ap["o_norm"], cfg.rms_eps).astype(dtype)
+    return matmul(y, ap["wo"], cfg.quant_kernel, mesh, "row")
+
+
+@jax.named_scope(SSD_MIX)
+def _ssd_decode_mix(cfg: ArchConfig, ap: Params, x, rec, j, impl="auto"):
+    """One token per slot: x [B, D], rec = (state, conv) stacked over the SSD
+    layers, j this layer's index in them. Returns (y [B, D], rec). The
+    operator whole is written under `SSD_MIX`, around its leaves, as
+    `CONV_MIX` is."""
+    from localai_tpu.ops.ssd import ssd_decode
+
+    state, conv = rec
+    with scope("attention/proj"), jax.named_scope("layer_conv_rows"):
+        prev = jax.lax.dynamic_index_in_dim(conv, j, 0, keepdims=False)
+    xs, dt, Bm, Cm, z, window = _ssd_inputs(cfg, ap, x[:, None], prev)
+    with scope("attention/cache_write"):
+        conv = jax.lax.dynamic_update_index_in_dim(
+            conv, window[:, 1:].astype(conv.dtype), j, 0)
+    with scope("attention/mix"):  # the kernel writes the state's rows in place
+        A = -jnp.exp(ap["A_log"].astype(jnp.float32))
+        y, state = ssd_decode(state, j, xs[:, 0], dt[:, 0], A, Bm[:, 0],
+                              Cm[:, 0], ap["ssm_D"], impl=impl)
+    return _ssd_out(cfg, ap, y, z[:, 0], x.dtype), (state, conv)
+
+
+@jax.named_scope(SSD_MIX)
+def _ssd_prefill_mix(cfg: ArchConfig, ap: Params, x, lengths, rec, j, slots):
+    """Whole prompts from an empty state: x [B, T, D] right-padded to
+    `lengths`. With rec = (state, conv) the state after each prompt's last
+    token and the conv's last inputs are written to rows `slots` [B] of
+    layer j. Returns (y [B, T, D], rec). Chunks of ops/ssd.CHUNK = 128 (an
+    exact sub-blocking of the published `mamba_chunk` 256, or a smaller
+    model's own chunk), a shorter bucket as one chunk."""
+    from localai_tpu.ops.ssd import CHUNK, ssd_chunk_prefill
+
+    B, T, _ = x.shape
+    c = cfg.mamba_conv
+    with scope("attention/proj"):
+        zeros = jnp.zeros((B, c - 1, cfg.mamba_conv_dim), x.dtype)
+    xs, dt, Bm, Cm, z, window = _ssd_inputs(cfg, ap, x, zeros)
+    with scope("attention/mix"):
+        valid = jnp.arange(T)[None, :] < lengths[:, None]
+        chunk = min(cfg.mamba_chunk, CHUNK)
+        pad = -T % min(chunk, T)
+        if pad:  # a bucket that is no multiple of the chunk: rows that do nothing
+            xs, Bm, Cm = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                          for a in (xs, Bm, Cm))
+            dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
+            valid = jnp.pad(valid, ((0, 0), (0, pad)))
+        A = -jnp.exp(ap["A_log"].astype(jnp.float32))
+        y, S = ssd_chunk_prefill(xs, dt, A, Bm, Cm, ap["ssm_D"], valid, chunk)
+        y = y[:, :T]
+    out = _ssd_out(cfg, ap, y, z, x.dtype)
+    if rec is not None:
+        state, conv = rec
+        with scope("attention/cache_write"):
+            # the conv's inputs at tokens len-c+1 .. len-1 (zeros before 0)
+            rows = jnp.take_along_axis(
+                window,
+                (lengths[:, None] + jnp.arange(c - 1)[None, :])[..., None],
+                axis=1)
+            state = state.at[j, slots].set(S)
+            conv = conv.at[j, slots].set(rows.astype(conv.dtype))
+        rec = (state, conv)
+    return out, rec
+
+
+class RecurrentKind(NamedTuple):
+    """What a recurrent kind brings to the hybrid scan: its weight stack's
+    init, its one-token mixer `(cfg, ap, x, rec, j, impl)` and its
+    whole-prompt mixer `(cfg, ap, x, lengths, rec, j, slots)`."""
+
+    init: Any
+    decode_mix: Any
+    prefill_mix: Any
+
+
+RECURRENT = {
+    "kda": RecurrentKind(_init_kda_layers, _kda_decode_mix, _kda_prefill_mix),
+    "conv": RecurrentKind(_init_conv_layers, _conv_decode_mix,
+                          _conv_prefill_mix),
+    "ssd": RecurrentKind(_init_ssd_layers, _ssd_decode_mix, _ssd_prefill_mix),
+}
 
 
 def _hybrid_tables(cfg: ArchConfig):
@@ -1494,9 +1691,9 @@ def _hybrid_layer_fns(cfg: ArchConfig, rec_mix, *, pos, inv, attend,
     def rec_fn(h, rec, lp, j):
         kw = receiver()
         y, rec = rec_mix(lp, rms_norm(h, lp["attn_norm"], cfg.rms_eps), rec, j)
-        h = h + y
+        h = _residual(cfg, h, y)
         x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
-        h = h + _mlp_out(cfg, lp, x, ep, mesh, **kw)
+        h = _residual(cfg, h, _mlp_out(cfg, lp, x, ep, mesh, **kw))
         return h, rec, (reading(kw) if count else None)
 
     def cache_fn(h, lp, m, ex):
@@ -1626,8 +1823,7 @@ def _forward_hidden(
             *rec, slots = recurrent
             rec = tuple(rec)
 
-        mix = (_conv_prefill_mix if cfg.recurrent_kind == "conv"
-               else _kda_prefill_mix)
+        mix = RECURRENT[cfg.recurrent_kind].prefill_mix
 
         def rec_mix(lp, x, rec, j):
             return mix(cfg, lp, x, lengths, rec, j, slots)
@@ -1831,9 +2027,10 @@ def decode_step_windowed(
     # LoRA deltas applied unmerged beside the base matmuls (ISSUE 10)
     expert_rows: bool = False,  # also return the router's rows per expert
     recurrent=None,  # hybrid models: (state, conv), the per-slot recurrent
-    # state of the KDA layers ((None, conv) of conv layers); updated in
-    # place, returned LAST
-    kda_impl: str = "auto",  # KDA decode kernel: auto|pallas|xla
+    # state of the KDA or SSD layers ((None, conv) of conv layers); updated
+    # in place, returned LAST
+    kda_impl: str = "auto",  # the recurrent kind's decode kernel (KDA's or
+    # SSD's): auto|pallas|xla
 ):
     """One step of a fused decode block with a block-local KV window.
 
@@ -1904,10 +2101,10 @@ def decode_step_windowed(
                 "with its recurrent state, without runtime LoRA and sequence "
                 "parallelism")
 
+        mix = RECURRENT[cfg.recurrent_kind].decode_mix
+
         def rec_mix(lp, x, rec, j):
-            if cfg.recurrent_kind == "conv":
-                return _conv_decode_mix(cfg, lp, x, rec, j)
-            return _kda_decode_mix(cfg, lp, x, rec, j, impl=kda_impl)
+            return mix(cfg, lp, x, rec, j, impl=kda_impl)
 
         h, recurrent, rows_k, (new_k, new_v, *rows_e) = _scan_hybrid(
             cfg, params, h, tuple(recurrent), *_hybrid_layer_fns(

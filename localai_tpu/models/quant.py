@@ -49,16 +49,16 @@ Params = dict[str, Any]
 # w_kb/w_vb (MLA latent up-projections) stay unquantized: they ride
 # einsum paths with no grouped-int kernel and are small next to the MoE.
 QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "wg", "w_gate", "w_up", "w_down",
-                    "wq_a", "wq_b", "wkv_a", "w_in",
+                    "wq_a", "wq_b", "wkv_a", "w_in", "w_z", "w_xbc", "w_dt",
                     "shared_gate", "shared_up", "shared_down")
 
 
 # The per-layer stacks of a param tree: the model's layers, DeepSeek's dense
-# prefix, and a hybrid model's two attention kinds: its recurrent layers (KDA
-# or the gated short conv) and its cache layers, MLA or GQA (models/llama.py,
+# prefix, and a hybrid model's two attention kinds: its recurrent layers (KDA,
+# the gated short conv or SSD) and its cache layers, MLA or GQA (models/llama.py,
 # `ArchConfig.recurrent_stack`, `.cache_stack`).
 LAYER_STACKS = ("layers", "dense_layers", "kda_layers", "conv_layers",
-                "mla_layers", "gqa_layers")
+                "ssd_layers", "mla_layers", "gqa_layers")
 
 
 def quantize_tensor(w: jnp.ndarray) -> dict[str, jnp.ndarray]:
@@ -262,7 +262,8 @@ def init_params_quantized(
     """
     from jax import tree_util as jtu
 
-    from localai_tpu.models.llama import init_gain, init_params, init_special
+    from localai_tpu.models.llama import (
+        SSD_DT, init_gain, init_params, init_special)
 
     if mode == "int8":
         qfn = quantize_tensor
@@ -285,7 +286,9 @@ def init_params_quantized(
         if name in ("bq", "bk", "bv"):
             return jnp.zeros(sd.shape, sd.dtype)
         k = next(keys)
-        special = init_special(name, k, sd.shape, cfg.kda_init_dt)
+        special = init_special(
+            name, k, sd.shape,
+            SSD_DT if cfg.recurrent_kind == "ssd" else cfg.kda_init_dt)
         if special is not None:
             return special.astype(sd.dtype)
         if name in QUANT_LAYER_KEYS and len(sd.shape) == 4:
@@ -299,8 +302,9 @@ def init_params_quantized(
                     jax.random.normal(k1, sd.shape[1:], jnp.float32) * std),
                 jax.random.split(kk, sd.shape[0])))(k)
         if name in QUANT_LAYER_KEYS:
+            std = scale * init_gain(cfg, name, sd.shape)
             return jax.jit(lambda kk: qfn(
-                jax.random.normal(kk, sd.shape, jnp.float32) * scale
+                jax.random.normal(kk, sd.shape, jnp.float32) * std
             ))(k)
         if name == "lm_head" and not cfg.tie_embeddings:
             def head(kk):
@@ -312,8 +316,9 @@ def init_params_quantized(
                 return {"q": q, "s": s}
 
             return jax.jit(head)(k)
+        std = scale * init_gain(cfg, name, sd.shape)
         return jax.jit(lambda kk: (
-            jax.random.normal(kk, sd.shape, jnp.float32) * scale
+            jax.random.normal(kk, sd.shape, jnp.float32) * std
         ).astype(sd.dtype))(k)
 
     leaves = [build(path, sd) for path, sd in flat]

@@ -39,13 +39,15 @@ SCOPES = (
     #                  token/position rows written, routing sums, the step loop
     "attention/proj",         # q/k/v, the MLA pair, KDA's q/k/v/g/beta/gate
     #                           projections and its short conv, q/k norms,
-    #                           the gated short conv's [b | c | z] and b ⊙ z
+    #                           the gated short conv's [b | c | z] and b ⊙ z,
+    #                           SSD's [z | xBC | dt], its conv and softplus
     "attention/rope",         # the rotation (GQA; MLA rotates inside proj)
     "attention/mix",          # the token mixer: paged_attention,
     #                           latent_paged_attention, flash_prefill, the XLA
     #                           softmax and the block-window merge, kda_decode,
     #                           the chunkwise KDA prefill, the gated short
-    #                           conv's taps and gate
+    #                           conv's taps and gate, ssd_decode and the
+    #                           chunkwise SSD prefill
     "attention/cache_write",  # K/V rows into pool, window or dense cache;
     #                           recurrent state and conv rows into their slots
     "attention/out",          # gate, un-latent, per-head norm, output projection
@@ -70,6 +72,11 @@ SLICES = ("layer_weights", "layer_kv_pool", "layer_conv_rows", "layer_state")
 # pinned to the benchmark's reader, which drops the word like any other it
 # does not know and books each op to its leaf.
 CONV_MIX = "conv_mix"
+
+# The SSD (Mamba-2) layer, the operator whole, by the same rule: its two
+# matmuls, the conv with its bias and silu, the rows read and written, the
+# `ssd_decode` kernel (or the chunkwise prefill) and the gated norm.
+SSD_MIX = "ssd_mix"
 
 
 def scope(leaf: str):

@@ -129,6 +129,8 @@ _KEYS = {
     # nor this: how a decode block's window reached one page pool
     # (`note_pool_write`)
     "pool_write": ("pool_write_inplace", "pool_write_scatter"),
+    # nor this: which form an SSD layer's decode update took (`note_ssd`)
+    "ssd_decode": ("ssd_decode_pallas", "ssd_decode_xla"),
 }
 _ALL_KEYS = tuple(k for ks in _KEYS.values() for k in ks)
 
@@ -148,7 +150,8 @@ class SiteCounts:
     (`note_visit`), and the weight block of each Pallas dequant-matmul call,
     `wholerow` / `narrowed` (`note_blocks`); and how a decode block's window
     reached each page pool, `pool_write_inplace` / `pool_write_scatter`
-    (`note_pool_write`).
+    (`note_pool_write`); and the form each SSD layer's decode update took,
+    `ssd_decode_pallas` / `ssd_decode_xla` (`note_ssd`).
     The choice is static, so it is counted where it is made, once per trace. An engine
     owns one and traces its programs under `tracing(<program>)`."""
 
@@ -239,3 +242,12 @@ def note_pool_write(inplace: bool) -> None:
     scatter runs in the layout the pool is stored in; the latent pool's one
     row, which no DMA can slice; every pool under the XLA walk)."""
     note_site(inplace, kernel="pool_write")
+
+
+def note_ssd(pallas: bool) -> None:
+    """Count one SSD (Mamba-2) decode update of the program being traced
+    (ops/ssd `ssd_decode`) by its form: `pallas`, the kernel read and wrote
+    its layer's rows of the stacked state in place (key `ssd_decode_pallas`),
+    or the XLA step sliced the layer out and put it back
+    (`ssd_decode_xla`: off the TPU, or where a caller names it)."""
+    note_site(pallas, kernel="ssd_decode")

@@ -131,6 +131,16 @@ def _dense_layer_specs(cfg: ArchConfig) -> dict[str, P]:
     return specs
 
 
+# A recurrent stack's leaves by kind: ([L, a, b] matrices, [L, a] vectors).
+_RECURRENT_LEAVES = {
+    "kda": (("wq", "wk", "wv", "wo", "conv_w", "f_down", "f_up", "w_beta",
+             "g_down", "g_up"), ("dt_bias", "A_log", "o_norm")),
+    "conv": (("w_in", "conv_w", "wo"), ()),
+    "ssd": (("w_z", "w_xbc", "w_dt", "conv_w", "wo"),
+            ("conv_b", "dt_bias", "A_log", "ssm_D", "o_norm")),
+}
+
+
 def param_specs(cfg: ArchConfig) -> Params:
     specs: Params = {
         "embed": P("tp", None),
@@ -145,16 +155,10 @@ def param_specs(cfg: ArchConfig) -> Params:
         # A hybrid model serves at tp = 1 (the engine refuses more): its
         # recurrent stack is replicated, its cache layers' stack sharded as
         # any model's of their kind.
-        if cfg.recurrent_kind == "conv":
-            specs["conv_layers"] = {
-                n: P(None, None, None) for n in ("w_in", "conv_w", "wo")}
-        else:
-            specs["kda_layers"] = {
-                **{n: P(None, None, None) for n in (
-                    "wq", "wk", "wv", "wo", "conv_w", "f_down", "f_up",
-                    "w_beta", "g_down", "g_up")},
-                **{n: P(None, None) for n in ("dt_bias", "A_log", "o_norm")},
-            }
+        mats, vecs = _RECURRENT_LEAVES[cfg.recurrent_kind]
+        specs[cfg.recurrent_stack] = {
+            **{n: P(None, None, None) for n in mats},
+            **{n: P(None, None) for n in vecs}}
         specs[cfg.cache_stack] = _attn_specs(cfg, cache_stack=True)
     return specs
 

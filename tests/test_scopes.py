@@ -25,7 +25,7 @@ from localai_tpu.observe import scopes
 from tools.same_program import tiny_engine_programs
 
 CONFIGS = ("tiny", "tiny-olmoe", "tiny-kimi-linear", "tiny-solar-open2",
-           "tiny-lfm2")
+           "tiny-lfm2", "tiny-granite-h")
 PROGRAMS = ("decode_block", "admit")
 # what does no work: the issue's list
 NO_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast"}
@@ -39,7 +39,7 @@ def leaf_of(op_name):
 
 def _cfg(name):
     cfg = get_arch(name)
-    if cfg.recurrent_kind == "kda":  # as served: a share of the experts
+    if cfg.recurrent_kind in ("kda", "ssd"):  # as served: a share of the experts
         cfg = dataclasses.replace(cfg, expert_share=(0, 2))
     return cfg
 

@@ -10,9 +10,12 @@ by kv head over "tp").
 
     python tools/cell_program.py [cell ...] [--layers N] [--steps 16]
         [--tp N] [--kv-dtype float8_e4m3fn] [--out DIR]
+        [--program decode_block|admit|both] [--admit 4x256]
     PYTHONPATH=<another tree> python tools/cell_program.py ...   # a parent
 
-prints per cell the digest of the compiled text (the multiset of (opcode,
+(`--program admit`: the model's part of an admission of 4 prompts of the
+256 bucket, `llama.prefill` and `llama.write_prefill_to_pool`)
+prints per cell and program the digest of the compiled text (the multiset of (opcode,
 result shape, custom-call target), `tools/same_program.digest`), the Pallas
 kernels by name, the call-site tallies (`ops/stacked.SiteCounts`) and every
 `copy` whose result has the pool's per-chip shape. `tests/test_pool_write.py`
@@ -92,28 +95,30 @@ def cell_arch(y: dict, layers: int | None = None):
 
 @dataclasses.dataclass
 class Program:
-    fn: object  # the jitted decode block
+    fn: object  # the jitted program
     args: tuple  # ShapeDtypeStructs under the described shardings
     pool_local: tuple  # one chip's K pool shape [L, P, page, K / tp, D]
     sites: dict | None = None  # SiteCounts of the trace, once compiled
+    name: str = "decode_block"
+    memory: object = None  # the compiler's memory analysis, once compiled
 
     def compile_text(self) -> str:
         from localai_tpu.ops.stacked import SiteCounts
 
         sites = SiteCounts()
-        with as_on_tpu(), sites.tracing("decode_block"):
+        with as_on_tpu(), sites.tracing(self.name):
             traced = self.fn.trace(*self.args)
         with as_on_tpu():
-            text = (traced.lower(lowering_platforms=("tpu",))
-                    .compile().as_text())
-        self.sites = sites.by_program["decode_block"]
-        return text
+            compiled = traced.lower(lowering_platforms=("tpu",)).compile()
+        self.sites = sites.by_program[self.name]
+        self.memory = compiled.memory_analysis()
+        return compiled.as_text()
 
 
-def decode_block(y: dict, topo, *, layers: int | None = None,
-                 steps: int = 16, tp: int | None = None,
-                 kv_dtype: str | None = None) -> Program:
-    """The decode block of a cell's YAML (`cell_yaml`) for `topo`."""
+def _operands(y: dict, topo, layers, tp, kv_dtype) -> types.SimpleNamespace:
+    """What both programs take, as shapes under the described shardings: the
+    cell's ArchConfig, its parameters, the pool, the recurrent rows, the page
+    table, the pool's scales."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -169,8 +174,25 @@ def decode_block(y: dict, topo, *, layers: int | None = None,
         st, cv = jax.eval_shape(lambda: ST.allocate(cfg, B, dt))
         rec = (None if st is None else sds(st.shape, st.dtype),
                sds(cv.shape, cv.dtype))
-    table = sds((B, S // page), jnp.int32)
-    kv_scale = sds((2, cfg.cache_kv_heads), jnp.float32) if scaled else None
+    return types.SimpleNamespace(
+        cfg=cfg, tp=tp, mesh=mesh, B=B, S=S, page=page, dt=dt, sds=sds,
+        params=params, pool=pool, rec=rec, table=sds((B, S // page), jnp.int32),
+        kv_scale=(sds((2, cfg.cache_kv_heads), jnp.float32) if scaled
+                  else None),
+        pool_local=base[:3] + (cfg.cache_kv_heads // tp, cfg.cache_k_dim))
+
+
+def decode_block(y: dict, topo, *, layers: int | None = None,
+                 steps: int = 16, tp: int | None = None,
+                 kv_dtype: str | None = None) -> Program:
+    """The decode block of a cell's YAML (`cell_yaml`) for `topo`."""
+    import jax
+    import jax.numpy as jnp
+
+    from localai_tpu.models import llama
+
+    o = _operands(y, topo, layers, tp, kv_dtype)
+    cfg, mesh, B, S, dt, sds = o.cfg, o.mesh, o.B, o.S, o.dt, o.sds
     # a tree older than the in-place write takes no impl / mesh there
     takes = inspect.signature(llama.write_block_to_pool).parameters
     write_kw = {k: v for k, v in (("paged_impl", "auto"), ("mesh", mesh))
@@ -200,10 +222,47 @@ def decode_block(y: dict, topo, *, layers: int | None = None,
                                          kv_scale=kv_scale, **write_kw)
         return pool, toks, rec
 
-    args = (params, pool, table, sds((B,), jnp.int32), sds((B,), jnp.int32),
-            rec, kv_scale)
-    local = base[:3] + (cfg.cache_kv_heads // tp, cfg.cache_k_dim)
-    return Program(jax.jit(block, donate_argnums=(1,)), args, local)
+    args = (o.params, o.pool, o.table, sds((B,), jnp.int32),
+            sds((B,), jnp.int32), o.rec, o.kv_scale)
+    return Program(jax.jit(block, donate_argnums=(1,)), args, o.pool_local)
+
+
+def admit(y: dict, topo, *, layers: int | None = None, m: int = 4,
+          bucket: int = 256, tp: int | None = None,
+          kv_dtype: str | None = None) -> Program:
+    """The model's part of a cell's admission program for a group of `m`
+    prompts of one `bucket`, as the engine composes it: `llama.prefill` (a
+    hybrid model's recurrent rows written to their slots, the grouped
+    kernel's rows counted under an expert share) and each prompt's rows into
+    its pages (`llama.write_prefill_to_pool`), pool and rows donated. The
+    sampling of the first token is the engine's own and is not here."""
+    import jax
+    import jax.numpy as jnp
+
+    from localai_tpu.models import llama
+    from localai_tpu.ops import ptable as PT
+
+    o = _operands(y, topo, layers, tp, kv_dtype)
+    cfg, sds = o.cfg, o.sds
+    held_rows = cfg.expert_share is not None
+
+    def program(params, pool, rec, table, toks, lens, slots, kv_scale):
+        kw = {} if rec is None else {"recurrent": (*rec, slots)}
+        logits, ks, vs, *rest = llama.prefill(
+            cfg, params, toks, lens, mesh=None if rec else o.mesh,
+            expert_rows=held_rows, **kw)
+        if rec is not None:
+            rec = rest.pop()
+        for j in range(m):
+            pool = llama.write_prefill_to_pool(
+                pool, PT.select_row(table, j), ks, vs, j, kv_scale=kv_scale)
+        return pool, rec, logits, rest
+
+    args = (o.params, o.pool, o.rec, sds((m, o.S // o.page), jnp.int32),
+            sds((m, bucket), jnp.int32), sds((m,), jnp.int32),
+            sds((m,), jnp.int32), o.kv_scale)
+    return Program(jax.jit(program, donate_argnums=(1, 2)), args,
+                   o.pool_local, name="admit")
 
 
 def pool_copies(text: str, pool_local: tuple) -> list[str]:
@@ -237,29 +296,43 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=16)
     ap.add_argument("--tp", type=int)
     ap.add_argument("--kv-dtype")
-    ap.add_argument("--out", help="directory for <cell>.decode_block.hlo")
+    ap.add_argument("--out", help="directory for <cell>.<program>.hlo")
+    ap.add_argument("--program", default="decode_block",
+                    choices=("decode_block", "admit", "both"))
+    ap.add_argument("--admit", default="4x256",
+                    help="the admission group: prompts x bucket")
     a = ap.parse_args()
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.path.append(str(ROOT))  # tools.same_program; PYTHONPATH's tree first
     from tools.same_program import digest
 
     topo = describe()
+    m, bucket = (int(n) for n in a.admit.split("x"))
     for cell in a.cells:
-        prog = decode_block(cell_yaml(cell), topo, layers=a.layers,
-                            steps=a.steps, tp=a.tp, kv_dtype=a.kv_dtype)
-        text = prog.compile_text()
-        if a.out:
-            pathlib.Path(a.out).mkdir(parents=True, exist_ok=True)
-            pathlib.Path(a.out, f"{cell}.decode_block.hlo").write_text(text)
-        copies = pool_copies(text, prog.pool_local)
-        print(f"{cell} decode_block: {digest([text])} kernels "
-              f"{kernels(text)}", flush=True)
-        print(f"{cell} decode_block: sites " + json.dumps(
-            {k: v for k, v in prog.sites.items() if v and k != "traces"}))
-        print(f"{cell} decode_block: {len(copies)} pool-shaped copies "
-              f"{list(prog.pool_local)}")
-        for line in copies:
-            print("   ", line[:200])
+        y = cell_yaml(cell)
+        progs = []
+        if a.program in ("decode_block", "both"):
+            progs.append(decode_block(y, topo, layers=a.layers, steps=a.steps,
+                                      tp=a.tp, kv_dtype=a.kv_dtype))
+        if a.program in ("admit", "both"):
+            progs.append(admit(y, topo, layers=a.layers, m=m, bucket=bucket,
+                               tp=a.tp, kv_dtype=a.kv_dtype))
+        for prog in progs:
+            text = prog.compile_text()
+            if a.out:
+                pathlib.Path(a.out).mkdir(parents=True, exist_ok=True)
+                pathlib.Path(a.out, f"{cell}.{prog.name}.hlo").write_text(text)
+            copies = pool_copies(text, prog.pool_local)
+            print(f"{cell} {prog.name}: {digest([text])} kernels "
+                  f"{kernels(text)}", flush=True)
+            print(f"{cell} {prog.name}: sites " + json.dumps(
+                {k: v for k, v in prog.sites.items() if v and k != "traces"}))
+            mem = prog.memory
+            print(f"{cell} {prog.name}: temporaries "
+                  f"{getattr(mem, 'temp_size_in_bytes', 0) / 1e9:.3f} GB, "
+                  f"{len(copies)} pool-shaped copies {list(prog.pool_local)}")
+            for line in copies:
+                print("   ", line[:200])
 
 
 if __name__ == "__main__":

@@ -23,7 +23,7 @@ import sys
 _INSTR = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = (\S+) (\w[\w\-]*)\(")
 
 TINY = ("tiny", "tiny-olmoe", "tiny-kimi-linear", "tiny-solar-open2",
-        "tiny-lfm2")
+        "tiny-lfm2", "tiny-granite-h")
 
 
 def digest(texts) -> str:
@@ -87,7 +87,7 @@ def tiny_engine_programs(name: str) -> dict:
     from localai_tpu.models import llama as L
 
     cfg = get_arch(name)
-    if cfg.recurrent_kind == "kda":  # as served: a share of the experts
+    if cfg.recurrent_kind in ("kda", "ssd"):  # as served: a share of the experts
         cfg = dataclasses.replace(cfg, expert_share=(0, 2))
     with recorded_programs() as texts:
         eng = Engine(cfg, L.init_params(cfg, jax.random.key(0)),
@@ -112,7 +112,12 @@ def tiny_engine_programs(name: str) -> dict:
 def main() -> None:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+    from localai_tpu.models.config import PRESETS
+
     for name in TINY:
+        if name not in PRESETS:  # a parent tree that has no such model yet
+            print(f"{name}: not a preset of this tree")
+            continue
         texts = tiny_engine_programs(name)
         for prog in sorted(texts):
             print(f"{name} {prog} x{len(texts[prog])}: {digest(texts[prog])}")
