@@ -26,13 +26,18 @@ ever made: one layer's rows are 134 MB at 64 slots x 32 heads x 128 x 128.
 
 Prefill runs the chunkwise (WY) form: chunks of 64 tokens, all chunks'
 intra-chunk terms in parallel, one sequential pass over the chunks for the
-state. Decays are per channel, so every factored product is taken against a
-reference point that keeps both exponents <= 0 (sub-blocks of 16 inside a
-chunk): nothing overflows however strong the decay, and what underflows is
-smaller than float32 can hold anyway.
+state. A chunk's unit-triangular system is solved by blocks of 16 rows
+(`_solve_unit_lower`: jnp dots, nothing a Pallas body could not hold; XLA's
+own triangular solve was a custom call that took a quarter of an admission,
+PERF.md PR 47). Decays are per channel, so every factored product is taken
+against a reference point that keeps both exponents <= 0 (sub-blocks of 16
+inside a chunk): nothing overflows however strong the decay, and what
+underflows is smaller than float32 can hold anyway.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -208,6 +213,49 @@ def _pair_decay_products(a, b, G, *, strict: bool):
     return off + full_diag
 
 
+def _solve_unit_lower(N, rhs):
+    """X with (I + N) X = rhs; N [..., C, C] strictly lower triangular,
+    rhs [..., C, d], C a multiple of SUB, float32 in and out.
+
+    By blocks of SUB rows. First every diagonal block's inverse D at once,
+    by substitution, D[t] = e_t - sum_{s<t} N[t, s] D[s]: SUB - 1 dependent
+    steps, each an elementwise float32 product summed over the earlier
+    rows (no dot). Then block forward substitution,
+    X_i = D_i (R_i - sum_{j<i} N_ij X_j): C / SUB dependent steps of two
+    batched dots at HIGHEST. The chunk's inverse is never formed.
+
+    Not the closed product (I - N)(I + N^2)(I + N^4)(I + N^8), exact as it
+    is for a nilpotent block: the powers hold N's path sums, thousands where
+    beta nears 2 on near-parallel keys, and cancel to an inverse of order
+    one; that loses 1e-3 of the solution in float32 where the substitution
+    loses 4e-7, as the row-by-row solve it replaced did
+    (tests/test_kimi_linear.py holds this form to that one's error). Which
+    arrangement of the same arithmetic XLA:TPU lays out well was measured,
+    not reasoned (PERF.md PR 47): this one; the same rows held with the blocks
+    on the minor axis ran more than twice as long."""
+    *lead, C, d = rhs.shape
+    n = C // SUB
+    hi = jax.lax.Precision.HIGHEST
+    Nb = N.reshape(*lead, n, SUB, n, SUB)
+    Nd = jnp.stack([Nb[..., i, :, i, :] for i in range(n)], axis=-3)
+    eye = jnp.eye(SUB, dtype=N.dtype)
+    rows = [jnp.broadcast_to(eye[0], Nd.shape[:-1])]
+    for t in range(1, SUB):
+        rows.append(eye[t] - jnp.sum(
+            Nd[..., t, :t, None] * jnp.stack(rows, axis=-2), axis=-2))
+    D = jnp.stack(rows, axis=-2)  # [..., n, SUB, SUB]
+    R = rhs.reshape(*lead, n, SUB, d)
+    X = []
+    for i in range(n):
+        r = R[..., i, :, :]
+        if i:
+            r = r - jnp.matmul(N[..., i * SUB:(i + 1) * SUB, :i * SUB],
+                               jnp.concatenate(X, axis=-2), precision=hi)
+        X.append(jnp.matmul(D[..., i, :, :], r, precision=hi))
+    return jnp.concatenate(X, axis=-2)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
 def kda_chunk_prefill(q, k, v, g, beta, valid, chunk: int = CHUNK):
     """Chunkwise KDA from a zero state over right-padded prompts.
 
@@ -245,11 +293,9 @@ def kda_chunk_prefill(q, k, v, g, beta, valid, chunk: int = CHUNK):
     A = _pair_decay_products(kc, kc, G, strict=True)  # [B,H,N,C,C]
     Aqk = _pair_decay_products(qc, kc, G, strict=False)
     # (I + Diag(beta) A) u = beta (v - K+ S0): unit lower triangular
-    M = jnp.eye(C, dtype=f32) + bc[..., :, None] * A
     kplus = kc * jnp.exp(G)  # k_t decayed from the chunk's start
     rhs = jnp.concatenate([bc[..., None] * vc, bc[..., None] * kplus], -1)
-    sol = jax.scipy.linalg.solve_triangular(M, rhs, lower=True,
-                                            unit_diagonal=True)
+    sol = _solve_unit_lower(bc[..., :, None] * A, rhs)
     U0, W = sol[..., :dv], sol[..., dv:]  # u = U0 - W S0
     qplus = qc * jnp.exp(G)
     kend = kc * jnp.exp(Gend - G)  # k_s decayed to the chunk's end
