@@ -651,7 +651,11 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
 
     # MLA's absorbed decode over the latent pool stacked over the MLA layers
     # ([L, P, page, 1, 640]: one row a token, key and value): the latent
-    # kernel against the XLA walk. Same arithmetic as paged_decode -> 5e-3.
+    # walk (the as-stored visit over the one pool, six pages a visit at the
+    # published row; ISSUE 48) against the XLA walk. Same arithmetic as
+    # paged_decode -> 5e-3. Ragged contexts with an idle slot, a one-page
+    # slot in front of a live one, contexts that end on a page's last row
+    # and on the next one's first, and exactly a visit and a row more.
     Lm, Hm, W = hy["mla_layers"], hy["mla_heads"], hy["latent"]
     lat_pool = rnd((Lm, hy["pages"], page, 1, W))
     lat_pages = (hy["pages"] - 1) // Bk
@@ -660,6 +664,10 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
     lat_limits = jnp.array(
         [(i * 37 + 11) % (lat_pages * page - page) + 1 for i in range(Bk)],
         jnp.int32).at[0].set(0).at[1].set(lat_pages * page - 1)
+    for i, n in enumerate((page, 3 * page, 3 * page + 1, 6 * page,
+                           6 * page + 1)):
+        if i + 2 < Bk:
+            lat_limits = lat_limits.at[i + 2].set(n)
 
     def latent(impl):
         def fn(q, pool, t, lim, first, last):
@@ -674,6 +682,22 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
     case("latent_paged_decode_stacked", latent("auto"), latent("xla"),
          (rnd((Bk, Hm, W)), lat_pool, lat_table, lat_limits,
           jnp.int32(0), jnp.int32(Lm - 1)), 5e-3)
+    del lat_pool
+    # the same walk where a slot is several visits: contexts of half to all
+    # of a 32-page table (2,048-4,096 tokens at the published page), two
+    # layers of such a pool
+    long_pages = 32
+    long_pool = rnd((2, Bk * long_pages + 1, page, 1, W))
+    long_table = (jax.random.permutation(next(keys), Bk * long_pages)
+                  + 1).reshape(Bk, long_pages).astype(jnp.int32)
+    long_limits = jnp.array(
+        [(i * 37 + 11) % (long_pages * page // 2) + long_pages * page // 2 + 1
+         for i in range(Bk)], jnp.int32).at[0].set(0).at[1].set(
+             long_pages * page).at[2].set(18 * page)
+    case("latent_paged_decode_stacked_long", latent("auto"), latent("xla"),
+         (rnd((Bk, Hm, W)), long_pool, long_table, long_limits,
+          jnp.int32(0), jnp.int32(1)), 5e-3)
+    del long_pool
 
     # The held experts' stacks [26 x 32, 2304, 1024] at 64 rows through the
     # same kernel and block rule as olmoe's [16 x 64, 2048, 1024]: a whole

@@ -29,6 +29,15 @@ one-pass float32 dot made of them all along (2e-3 of the output against a
 float64 walk, before and after). A float32 pool and wide query tiles
 (prefill chunks) keep the per-head float32 tiles.
 
+A LATENT pool (MLA's absorbed decode: one row a token, read as key and as
+value; `latent_paged_attention`) is walked by the same visit (ISSUE 48): its
+page [page, W] is the [C, D] matrix of the as-stored form at K = 1 already,
+and the kernel body is told that the value pool IS the key pool (`shared`):
+one copy and one semaphore a page, the score dot and `p @ kbuf` on the one
+tile, and a last visit's dots over its live pages alone. A visit is bounded
+in bytes as well as in rows (`_visit_pages`), so six 128-row pages of 640
+bfloat16 lanes are one.
+
 Shapes (matching the XLA reference):
 - q rows     [B, K, QR, Dk] f32, 1/sqrt(D) pre-applied; QR = G query rows
   per kv head (G·T for the multi-query verify chunk).
@@ -98,6 +107,18 @@ FLAT_MAX_ROWS = 128
 # beside 88.9; contexts of 1,500-3,000 tokens K = 2 127.4 / 123.4 / 118.6
 # beside 251.5.
 VISIT_ROWS = 1536
+# ... and the bytes a visit lands in VMEM at most: VISIT_ROWS rows of a wide
+# row (MLA's latent one, 1,280 B, key and value at once) would be 1.9 MB a
+# visit and a ring of two. Above VISIT_ROWS x 512 B, so that at 128-wide 16-
+# and 8-bit heads the rows decide as they did; six 128-row latent pages.
+# Measured (PERF.md §6 PR 48, a 7-layer scan at the Kimi-Linear cell's shape,
+# us a layer at 1 / 2 / 4 / 6 / 12 pages a visit, every visit's dots over all
+# its columns): contexts of 300-800 tokens 167.5 / 125.6 / 103.5 / 97.3 /
+# 100.3 beside 205.6 for the kernel before and 70.8 for the copies alone, of
+# 2,000-4,000 771.0 / 510.1 / 381.3 / 361.7 / 355.6 beside 848.5 and 321.0;
+# with a last visit's dots over its live pages alone 4 / 6 / 8 / 12 pages
+# read 105.8 / 90.9 / 88.0 / 87.7 and 381.8 / 358.3 / 357.9 / 354.0.
+VISIT_BYTES = 1024 * 1024
 # VMEM the ring of visit buffers may take, and its depth bounds (`_ring_depth`).
 RING_VMEM_BYTES = 3 * 1024 * 1024
 RING_MAX = 4
@@ -116,25 +137,29 @@ def _flat_rows(k_dtype, v_dtype, num_kv: int, qr: int) -> bool:
             and (num_kv * size) % 4 == 0 and num_kv * qr <= FLAT_MAX_ROWS)
 
 
-def _visit_pages(page: int, num_kv: int, width: int, *, flat: bool,
-                 swin: int = 0) -> int:
+def _visit_pages(page: int, num_kv: int, width: int, row_bytes: int, *,
+                 flat: bool, swin: int = 0) -> int:
     """Consecutive table columns of a slot that ONE visit of the page walk
     lands side by side and scores with one dot a pool (ISSUE 41): as many
-    whole pages as fit VISIT_ROWS (token, head) rows, never more than the
-    table has columns. A visit pays a fixed chain (score dot -> max -> exp
-    -> sum -> `p @ V` -> rescale, each waiting on the one before: some
-    0.3 us whether it holds 256 rows or 2,048) and nothing hides it, so at
-    2 KV heads a chip (tp = 4), where a 128-row page is 256 rows, the
-    chain was paid once a 128 KB: sized in rows, a visit is the same work
-    whatever the caller's head count or page size. 128-row pages: K = 2
-    gives 6, K = 4 gives 3, K = 8 and 16 give 1, the walk as it was,
-    traced as it was. One page a visit too for the per-head form (not
-    `flat`: a float32 pool, wide query tiles) and under `swin`, whose walk
-    skips the cold middle, so consecutive visits are not consecutive
-    columns (no cell runs it; it stays without the handoff as well)."""
+    whole pages as fit VISIT_ROWS (token, head) rows and VISIT_BYTES at
+    `row_bytes` a row in VMEM (K and V; a latent pool's one row), never
+    more than the table has columns. A visit pays a fixed chain (score dot
+    -> max -> exp -> sum -> `p @ V` -> rescale, each waiting on the one
+    before: some 0.3 us whether it holds 256 rows or 2,048) and nothing
+    hides it, so at 2 KV heads a chip (tp = 4), where a 128-row page is 256
+    rows, the chain was paid once a 128 KB: sized in rows, a visit is the
+    same work whatever the caller's head count or page size. 128-row pages:
+    K = 2 gives 6, K = 4 gives 3, K = 8 and 16 give 1, the walk as it was,
+    traced as it was (512 B a row there, so the rows decide); a latent pool
+    of 1,280 B rows gives 6 by its bytes. One page a visit too for the
+    per-head form (not `flat`: a float32 pool, wide query tiles) and under
+    `swin`, whose walk skips the cold middle, so consecutive visits are not
+    consecutive columns (no cell runs it; it stays without the handoff as
+    well)."""
     if not flat or swin:
         return 1
-    return max(1, min(VISIT_ROWS // (page * num_kv), width))
+    rows = min(VISIT_ROWS, VISIT_BYTES // row_bytes)
+    return max(1, min(rows // (page * num_kv), width))
 
 
 def _ring_depth(visit_bytes: int) -> int:
@@ -174,6 +199,7 @@ def _ragged_paged_kernel(
     ring: int = 2,
     flat: bool = False,
     pages: int = 1,
+    shared: bool = False,
 ):
     """Kernel body. Scalar-prefetch layout depends on the table layout:
 
@@ -221,6 +247,12 @@ def _ragged_paged_kernel(
     The DMAs run `ring - 1` visits ahead of the visit being scored, and a
     slot's last visit starts the NEXT slot's first visit (`handoff`).
 
+    `shared` (as stored only; MLA's latent pool, K = 1): the value pool IS
+    the key pool. No v_hbm and no vbuf are passed: a page is ONE copy on
+    one semaphore (sem [ring, pages]) into kbuf, which `p @ V` reads too.
+    A last visit's dots run over its live pages alone there (`visit_flat`),
+    so no unfetched part is read and none is zeroed.
+
     sink/swin (windowed+sink decode, docs/LONG_CONTEXT.md): a row is
     attended iff `gpos < sink` or `q_pos - gpos < swin`. The page walk then
     SKIPS the cold middle — it visits columns [0, sink_cols) ∪ [win_lo,
@@ -250,19 +282,21 @@ def _ragged_paged_kernel(
         layer_ref,  # scalar-prefetch [1] i32 — which layer of the pools
         q_ref,  # f32, scale applied
         qpos_ref,  # i32, a query row's position
-        *consts,  # per head: kvs_ref; as stored: colhead, colrow, rowhead
-        k_hbm,  # pool dtype, memory_space=ANY, stacked over layers
-        v_hbm,
-        acc_ref,  # out f32
-        m_ref,  # out f32, STAT_LANES wide
-        l_ref,
-        kbuf,  # VMEM scratch [ring, <a visit's pages>] pool dtype
-        vbuf,
+        *refs,  # the constants, the pools, the outputs, the visit buffers
         acc_s,  # VMEM scratch f32: the running (acc, m, l)
         m_s,
         l_s,
-        sem,  # DMA semaphores [ring, 2·pages]: a page's K and V copy
+        sem,  # DMA semaphores [ring, <copies a visit>]: a page's K and V copy
     ) = refs
+    # consts: per head kvs_ref; as stored colhead, colrow, rowhead. k_hbm /
+    # v_hbm: pool dtype, memory_space=ANY, stacked over layers. acc / m / l:
+    # out f32, m and l STAT_LANES wide. kbuf / vbuf: VMEM scratch
+    # [ring, <a visit's pages>], pool dtype.
+    if shared:  # one pool and one buffer: V's names are K's
+        *consts, k_hbm, acc_ref, m_ref, l_ref, kbuf = refs
+        v_hbm, vbuf = k_hbm, kbuf
+    else:
+        *consts, k_hbm, v_hbm, acc_ref, m_ref, l_ref, kbuf, vbuf = refs
 
     b = pl.program_id(0)
     lim = limits_ref[b]
@@ -301,18 +335,20 @@ def _ragged_paged_kernel(
     part_rows = kbuf.shape[1] // pages
 
     def copies(pid, buf, part=0):
-        """One page's K and V into part `part` of a buffer of the ring."""
+        """One page's K and V (`shared`: its one copy) into part `part` of a
+        buffer of the ring."""
         def into(ref):
             if pages == 1:
                 return ref.at[buf]
             return ref.at[buf, pl.ds(part * part_rows, part_rows)]
 
-        return (
-            pltpu.make_async_copy(
-                k_hbm.at[layer, pid], into(kbuf), sem.at[buf, 2 * part]),
-            pltpu.make_async_copy(
-                v_hbm.at[layer, pid], into(vbuf), sem.at[buf, 2 * part + 1]),
-        )
+        per = 1 if shared else 2  # copies, and semaphores, a page
+        k_copy = pltpu.make_async_copy(
+            k_hbm.at[layer, pid], into(kbuf), sem.at[buf, per * part])
+        if shared:
+            return (k_copy,)
+        return (k_copy, pltpu.make_async_copy(
+            v_hbm.at[layer, pid], into(vbuf), sem.at[buf, 2 * part + 1]))
 
     def each_copy(act, row, j, live=None, buf=None):
         """`act` (start or wait) on the copies of visit j of slot `row`, into
@@ -420,22 +456,44 @@ def _ragged_paged_kernel(
 
     def visit_flat(slot, j):
         if pages == 1:
-            first_row = col_of(j) * page
-        else:
-            first_row = j * (pages * page)
-            for part in range(1, pages):  # a last visit's unfetched pages
+            return score(slot, col_of(j) * page)
+        first_row = j * (pages * page)
+        if shared:
+            # The dots of a last visit over its LIVE pages alone (one of
+            # `pages` traced sizes): with 32 query rows on a 128-row array a
+            # page's two dots take as long as its copy, so the columns of a
+            # page that was never fetched are not free to score and mask,
+            # and with none in the dots nothing is left to zero.
+            live = np_live - j * pages
+            for n in range(1, pages + 1):
+                pl.when((live == n) if n < pages else (live >= n))(
+                    functools.partial(score, slot, first_row, n))
+            return
+        for part in range(1, pages):  # a last visit's unfetched pages
 
-                @pl.when(j * pages + part >= np_live)
-                def _finite(part=part):
-                    vbuf[slot, pl.ds(part * part_rows, part_rows)] = (
-                        jnp.zeros((part_rows, vbuf.shape[2]), vbuf.dtype))
-        ok = mine & masked(first_row + colrow_ref[...])  # [R, pages·C]
+            @pl.when(j * pages + part >= np_live)
+            def _finite(part=part):
+                vbuf[slot, pl.ds(part * part_rows, part_rows)] = (
+                    jnp.zeros((part_rows, vbuf.shape[2]), vbuf.dtype))
+        score(slot, first_row)
+
+    def score(slot, first_row, live=pages):
+        """One visit's dots and its step of the online softmax, over the
+        first `live` pages of the visit's buffer."""
+        if live == pages:
+            colrow, own = colrow_ref[...], mine
+            tile = lambda buf: buf[slot]
+        else:
+            cols = live * part_rows
+            colrow, own = colrow_ref[:, pl.ds(0, cols)], mine[:, :cols]
+            tile = lambda buf: buf[slot, pl.ds(0, cols)]
+        ok = own & masked(first_row + colrow)  # [R, live·C]
         # (a bfloat16 page goes as it is, the cast is none; fp8 -> bfloat16
         # is exact)
         s = jax.lax.dot_general(
-            qb, kbuf[slot].astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+            qb, tile(kbuf).astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [R, pages·C]: every head's rows against every (n, h) row
+        )  # [R, live·C]: every head's rows against every (n, h) row
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
         s = jnp.where(ok, s, NEG_INF)
@@ -445,7 +503,7 @@ def _ragged_paged_kernel(
         p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
         l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-            p.astype(jnp.bfloat16), vbuf[slot].astype(jnp.bfloat16),
+            p.astype(jnp.bfloat16), tile(vbuf).astype(jnp.bfloat16),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
         )  # p is zero off its own head: the long sum is the head's own
         m_s[...] = m_new
@@ -478,128 +536,13 @@ def _ragged_paged_kernel(
     l_ref[0] = jnp.broadcast_to(l_s[...], l_ref.shape[1:])
 
 
-def _latent_paged_kernel(table_ref, limits_ref, layer_ref, q_ref, c_hbm,
-                         acc_ref, m_ref, l_ref, cbuf, acc_s, m_s, l_s, sem,
-                         *, page: int):
-    """The page walk of `_ragged_paged_kernel` over a LATENT pool (MLA's
-    absorbed decode: one row per token, read as key and as value): one DMA a
-    page into a [page, D] tile, scored against every query head and summed
-    back out of the same tile. Flat tables, no window, no softcap, no
-    scales: what MLA's calls ask for.
-
-    table_ref [B, MP], limits_ref [B], layer_ref [1] (prefetch); q_ref
-    [1, QR, D] f32 (scale applied); c_hbm [L, P, page, D] (ANY): the pool
-    without its one-wide head axis, so a page lands row-per-sublane (a
-    [page, 1, D] tile puts one row in a tile of 8 or 16 and is refused)."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b = pl.program_id(0)
-    QR = q_ref.shape[1]
-    lim = limits_ref[b]
-    layer = layer_ref[0]
-    n_iter = jnp.minimum((lim + page - 1) // page, table_ref.shape[1])
-
-    def dma(slot, j):
-        return pltpu.make_async_copy(
-            c_hbm.at[layer, table_ref[b, j]], cbuf.at[slot], sem.at[slot])
-
-    acc_s[...] = jnp.zeros_like(acc_s)
-    m_s[...] = jnp.full_like(m_s, NEG_INF)
-    l_s[...] = jnp.zeros_like(l_s)
-
-    @pl.when(n_iter > 0)
-    def _warmup():
-        dma(0, 0).start()
-
-    def body(j, carry):
-        slot = j % 2
-
-        @pl.when(j + 1 < n_iter)
-        def _prefetch():
-            dma((j + 1) % 2, j + 1).start()
-
-        dma(slot, j).wait()
-        gpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (QR, page), 1)
-        valid = gpos < lim
-        c = cbuf[slot].astype(jnp.float32)  # [page, D]
-        s = jax.lax.dot_general(
-            q_ref[0], c, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [QR, page]
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_s[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(jnp.maximum(m_prev - m_new, -80.0))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-            p, c, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_s[...] = m_new
-        return carry
-
-    jax.lax.fori_loop(0, n_iter, body, 0)
-    acc_ref[0] = acc_s[...]
-    m_ref[0] = jnp.broadcast_to(m_s[...], m_ref.shape[1:])
-    l_ref[0] = jnp.broadcast_to(l_s[...], l_ref.shape[1:])
-
-
-def _latent_partials_rows(qr, pool, table, limits, interpret: bool):
-    """`_paged_partials_rows` for a latent pool: qr [B, 1, QR, D], pool a
-    [P, page, 1, D] pool or its StackedLayer. Same contract of partials."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    from localai_tpu.ops.stacked import note_arith, note_visit, stacks_of
-
-    B, _, QR, D = qr.shape
-    stack, _, layer = stacks_of(pool, pool, "layer_kv_pool")
-    note_arith(native=False)  # float32 dots on the upcast tile, still
-    note_visit(multipage=False)  # and one page a visit
-    L, P, page = stack.shape[:3]
-    kernel = functools.partial(_latent_paged_kernel, page=page)
-    acc, m, l = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, QR, D), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),  # pool stays in HBM
-            ],
-            out_specs=[
-                pl.BlockSpec((1, QR, D), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec((1, QR, STAT_LANES), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec((1, QR, STAT_LANES), lambda b, *_: (b, 0, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((2, page, D), stack.dtype),
-                pltpu.VMEM((QR, D), jnp.float32),
-                pltpu.VMEM((QR, 1), jnp.float32),
-                pltpu.VMEM((QR, 1), jnp.float32),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, QR, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, QR, STAT_LANES), jnp.float32),
-            jax.ShapeDtypeStruct((B, QR, STAT_LANES), jnp.float32),
-        ],
-        interpret=interpret,
-        name="latent_paged_attention",
-    )(
-        table.astype(jnp.int32), limits.astype(jnp.int32),
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        qr[:, 0], stack.reshape(L, P, page, D),
-    )
-    return acc[:, None], m[:, None, :, :1], l[:, None, :, :1]
-
-
 def latent_paged_attention(q, pool, table, limits, interpret: bool = False):
     """Decode partials over a LATENT pool (MLA's absorbed form: one row a
     token, key and value at once), for the caller that says its pool is one
-    (`paged_decode_partials(latent=True)`). q [B, H, D] at the pool's row
-    width; pool a [P, page, 1, D] pool or its StackedLayer; a flat table.
+    (`paged_decode_partials(latent=True)`): the as-stored walk of
+    `_paged_partials_rows` over the one pool, every query head a row of the
+    one pseudo-head. q [B, H, D] at the pool's row width; pool a
+    [P, page, 1, D] pool or its StackedLayer; a flat table.
     Returns (acc [B, 1, H, D], m [B, 1, H, 1], l [B, 1, H, 1]) f32."""
     from localai_tpu.ops import ptable as _pt
 
@@ -610,7 +553,9 @@ def latent_paged_attention(q, pool, table, limits, interpret: bool = False):
             f"width and a flat page table: pool {tuple(pool.shape)}, q width "
             f"{D}, hierarchical table {_pt.is_hier(table)}")
     qr = (q.astype(jnp.float32) * (1.0 / D**0.5)).reshape(B, 1, H, D)
-    return _latent_partials_rows(qr, pool, table, limits, interpret)
+    return _paged_partials_rows(
+        qr, jnp.broadcast_to(limits[:, None], (B, H)), pool, pool, table,
+        limits, 0.0, 0, None, interpret, latent=True)
 
 
 def _paged_partials_rows(
@@ -628,6 +573,7 @@ def _paged_partials_rows(
     sink: int = 0,  # windowed+sink decode (docs/LONG_CONTEXT.md)
     swin: int = 0,
     ring: int | None = None,  # visit buffers in the DMA ring (tests); None: `_ring_depth`
+    latent: bool = False,  # v_pool IS k_pool, [.., page, 1, D]: MLA's latent rows
 ):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -639,8 +585,12 @@ def _paged_partials_rows(
     k_pool, v_pool, layer = stacks_of(k_pool, v_pool, "layer_kv_pool")
     L, P, page = k_pool.shape[:3]
     Dv = v_pool.shape[4]
-    flat = _flat_rows(k_pool.dtype, v_pool.dtype, K, QR)
-    note_arith(native=flat)
+    # A latent page [page, 1, D] is the [C, D] matrix of the as-stored form
+    # already (K = 1, every column the one head's), whatever `_flat_rows`
+    # says of a pool WITH a head axis; a float32 one is rounded to bfloat16
+    # on its way to the MXU, which is what Mosaic's float32 dot did with it.
+    flat = latent or _flat_rows(k_pool.dtype, v_pool.dtype, K, QR)
+    note_arith(native=flat and k_pool.dtype.itemsize < 4)
     sl_arr = jnp.asarray(
         sliding if sliding is not None else False
     ).reshape(1).astype(jnp.int32)
@@ -653,17 +603,20 @@ def _paged_partials_rows(
         l1_span = 0
         width = int(table.shape[1])
         tbl_args = (table.astype(jnp.int32),)
-    pages = _visit_pages(page, K, width, flat=flat, swin=int(swin))
+    # what a (token, head) row lands in VMEM: K and V, or the one latent row
+    row_bytes = Dk * k_pool.dtype.itemsize + (
+        0 if latent else Dv * v_pool.dtype.itemsize)
+    pages = _visit_pages(page, K, width, row_bytes, flat=flat, swin=int(swin))
     note_visit(multipage=pages > 1)
     if ring is None:
-        ring = _ring_depth(pages * page * K * (Dk * k_pool.dtype.itemsize
-                                               + Dv * v_pool.dtype.itemsize))
+        ring = _ring_depth(pages * page * K * row_bytes)
     kernel = functools.partial(
         _ragged_paged_kernel, page=page, num_kv=K,
         softcap=float(softcap), window=int(window),
         sink=int(sink), swin=int(swin), l1_span=l1_span, ring=ring, flat=flat,
-        pages=pages,
+        pages=pages, shared=latent,
     )
+    pools = (k_pool,) if latent else (k_pool, v_pool)
     qpos_rows = qpos_rows.astype(jnp.int32)
     if flat:
         # The page as stored: rows h·QR + i of q against the [C, D] view of
@@ -678,7 +631,7 @@ def _paged_partials_rows(
             qr.reshape(B, R, Dk), jnp.tile(qpos_rows, (1, K))[..., None],
             jnp.asarray(col % K)[None], jnp.asarray(col // K)[None],
             jnp.asarray(np.arange(R, dtype=np.int32) // QR)[:, None],
-            k_pool.reshape(L, P, C, Dk), v_pool.reshape(L, P, C, Dv),
+            *(a.reshape(L, P, C, a.shape[4]) for a in pools),
         )
         lead, zeros = (R,), (0,)
         const_specs = [
@@ -690,7 +643,7 @@ def _paged_partials_rows(
     else:
         kvs = (jnp.ones((2, K), jnp.float32) if kv_scale is None
                else kv_scale.astype(jnp.float32))
-        operands = (qr, qpos_rows[..., None], kvs, k_pool, v_pool)
+        operands = (qr, qpos_rows[..., None], kvs, *pools)
         lead, zeros = (K, QR), (0, 0)
         const_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]  # [2, K] scales
         page_shape = (page, K)
@@ -707,17 +660,17 @@ def _paged_partials_rows(
                 rows(Dk),
                 pl.BlockSpec((1, lead[-1], 1), lambda b, *_: (b, 0, 0)),
                 *const_specs,
-                pl.BlockSpec(memory_space=pl.ANY),  # pool stays in HBM
-                pl.BlockSpec(memory_space=pl.ANY),
+                # the pools stay in HBM
+                *(pl.BlockSpec(memory_space=pl.ANY) for _ in pools),
             ],
             out_specs=[rows(Dv), rows(STAT_LANES), rows(STAT_LANES)],
             scratch_shapes=[
-                pltpu.VMEM((ring, *page_shape, Dk), k_pool.dtype),
-                pltpu.VMEM((ring, *page_shape, Dv), v_pool.dtype),
+                *(pltpu.VMEM((ring, *page_shape, a.shape[4]), a.dtype)
+                  for a in pools),
                 pltpu.VMEM((*lead, Dv), jnp.float32),
                 pltpu.VMEM((*lead, 1), jnp.float32),
                 pltpu.VMEM((*lead, 1), jnp.float32),
-                pltpu.SemaphoreType.DMA((ring, 2 * pages)),
+                pltpu.SemaphoreType.DMA((ring, len(pools) * pages)),
                 *([] if swin else [pltpu.SMEM((2,), jnp.int32)]),  # handoff
             ],
         ),
@@ -729,7 +682,8 @@ def _paged_partials_rows(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_attention",
+        # the name a capture's reader finds the kernel's self time by
+        name="latent_paged_attention" if latent else "paged_attention",
     )(
         *tbl_args, limits.astype(jnp.int32), sl_arr,
         jnp.asarray(layer, jnp.int32).reshape(1), *operands,

@@ -212,9 +212,10 @@ def note_arith(native: bool) -> None:
     """Count one Pallas paged-attention kernel call of the program being
     traced by what its dots are fed (ops/paged_flash): `native`, the page
     went to the MXU in the pool's own 16- or 8-bit dtype as it is stored
-    (key `paged_attention_native`), or each head's tile was upcast to
+    (key `paged_attention_native`: a GQA pool's `[page·K, D]` tile, a
+    latent pool's `[page, W]` one), or each head's tile was upcast to
     float32 first (`paged_attention_f32`: a float32 pool, a wide query
-    tile, the latent kernel). The XLA walk counts under neither."""
+    tile). The XLA walk counts under neither."""
     note_site(native, kernel="paged_attention_arith")
 
 
@@ -223,11 +224,11 @@ def note_visit(multipage: bool) -> None:
     traced by what a visit of its page walk holds (ops/paged_flash
     `_visit_pages`): `multipage`, several consecutive pages of the slot
     landed side by side and scored by one dot a pool (a narrow pool of
-    whose pages two or more fit VISIT_ROWS (token, head) rows: 2 or 4 KV
-    heads a chip at 128-row pages; key `paged_attention_multipage`), or one
-    page a visit (`paged_attention_onepage`: 8 KV heads and more, the
-    per-head form, the cold-middle walk, the latent kernel). The XLA walk
-    counts under neither."""
+    whose pages two or more fit VISIT_ROWS (token, head) rows and
+    VISIT_BYTES: 2 or 4 KV heads a chip at 128-row pages, a latent pool;
+    key `paged_attention_multipage`), or one page a visit
+    (`paged_attention_onepage`: 8 KV heads and more, the per-head form, the
+    cold-middle walk). The XLA walk counts under neither."""
     note_site(multipage, kernel="paged_attention_visit")
 
 

@@ -156,8 +156,10 @@ def _check_against_float64_walk(key, fn, q, k4, v4, table, limits, kw, pages,
     B, K, D = q.shape[0], k4.shape[2], q.shape[-1]
     G = q.shape[-2] // K
     assert _flat_rows(k4.dtype, v4.dtype, K, G * (1 if q.ndim == 3 else 2))
-    assert pages == _visit_pages(k4.shape[1], K, table.shape[1], flat=True,
-                                 swin=kw.get("swin", 0))
+    assert pages == _visit_pages(
+        k4.shape[1], K, table.shape[1],
+        (k4.shape[-1] + v4.shape[-1]) * k4.dtype.itemsize, flat=True,
+        swin=kw.get("swin", 0))
     tbl = kw.pop("table", table)
     got = _one_compile(key, fn, kw)(q, k4, v4, tbl, limits)
     # the walk's rows, as the wrappers lay them out: r = t·G + g

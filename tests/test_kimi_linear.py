@@ -413,32 +413,92 @@ def test_kda_decode_kernel_updates_its_layer_of_the_stack_in_place():
         np.testing.assert_array_equal(st[2], state[2])
 
 
-def test_latent_kernel_matches_the_xla_walk():
+# The latent walk's cases, at the cell's page and row (128 rows of 640: six
+# pages a visit by `_visit_pages`' byte bound) over a table of thirteen
+# columns, and the first test's tiny shape, whose whole table is one visit.
+# Limits a slot; "nan": every page no live slot lists holds NaN, in every
+# layer.
+_LATENT_PAGE, _LATENT_W, _LATENT_MP, _V = 128, 640, 13, 6 * 128
+_LATENT_CASES = {
+    # ragged limits, an idle slot, a page's last row
+    "ragged_tiny": dict(page=16, W=64, MP=4, limits=[0, 37, 64]),
+    # more pages than one visit holds: two and three visits, a second visit
+    # of one live page's first row
+    "longer_than_a_visit": dict(limits=[_V + 128 + 3, 13 * 128, _V + 1]),
+    # exactly a visit, and exactly two: both end on their visit's last row
+    "exactly_a_visit": dict(limits=[_V, 2 * _V, _V - 1]),
+    # a last visit of one, two and three live pages: the rest of its buffer
+    # holds whatever it held (here NaN, from the pages of an earlier slot or
+    # never-written scratch) and no dot may read it
+    "last_visit_unfetched_nan": dict(
+        limits=[128 + 5, _V + 1, _V + 3 * 128 - 3], nan=True),
+    "one_token_nan": dict(limits=[1, 0, 11 * 128], nan=True),
+    # the handoff: a live slot after an idle one, after a one-page one, after
+    # two idle ones, and an idle last one
+    "handoff_after_idle_and_one_page": dict(
+        limits=[0, 300, 128, 700, 0, 0, _V + 1, 0]),
+    "handoff_from_a_second_visit": dict(limits=[_V + 128, 64, 13 * 128, 200]),
+}
+_LATENT_PROGRAMS = {}
+
+
+@pytest.mark.parametrize("layer", [1, 0])
+@pytest.mark.parametrize("case", list(_LATENT_CASES))
+def test_latent_kernel_matches_the_xla_walk(case, layer):
     """A caller that says its [P, page, 1, W] pool is a latent one gets the
-    latent kernel (one DMA a page); ragged limits, an idle slot, a stacked
-    pool. What that kernel lacks is refused, not dropped."""
-    B, H, W, page, MP, Lm = 3, 4, 64, 16, 4, 2
+    latent walk (the as-stored visit over the one pool: several pages a
+    visit, the ring, the slot handoff), reading its layer out of the stacked
+    pool; against the XLA walk at the parent's tolerance. What that kernel
+    lacks is refused, not dropped."""
+    from localai_tpu.ops.paged_flash import _visit_pages
+
+    spec = _LATENT_CASES[case]
+    page, W, MP = (spec.get("page", _LATENT_PAGE), spec.get("W", _LATENT_W),
+                   spec.get("MP", _LATENT_MP))
+    limits = jnp.array(spec["limits"], jnp.int32)
+    B, H, Lm = len(spec["limits"]), 4, 2
+    assert _visit_pages(page, 1, MP, 2 * W, flat=True) == min(6, MP)
     pool = jax.random.normal(jax.random.key(5), (Lm, B * MP + 1, page, 1, W),
                              jnp.bfloat16)
     table = (jnp.arange(B * MP, dtype=jnp.int32) + 1).reshape(B, MP)
-    limits = jnp.array([0, 37, 64], jnp.int32)
     q = jax.random.normal(jax.random.key(6), (B, H, W), jnp.bfloat16)
 
     def partials(impl):
-        c = Q.StackedLayer(pool, jnp.int32(1))
-        return A.paged_partials(q, c, c, table, limits, impl=impl,
-                                latent=True)
+        # cases of one shape share a trace of the interpreted kernel
+        key = (impl, B, page, W, MP)
+        if key not in _LATENT_PROGRAMS:
+            _LATENT_PROGRAMS[key] = jax.jit(
+                lambda q, pool, table, limits, i: A.paged_partials(
+                    q, Q.StackedLayer(pool, i), Q.StackedLayer(pool, i),
+                    table, limits, impl=impl, latent=True))
+        return _LATENT_PROGRAMS[key]
 
-    with pytest.raises(ValueError, match="softcap or window"):
-        A.paged_partials(q, pool[0], pool[0], table, limits, impl="pallas",
-                         latent=True, softcap=30.0)
-    acc, m, l = partials("pallas")
-    acc0, m0, l0 = partials("xla")
+    if case == "ragged_tiny":
+        with pytest.raises(ValueError, match="softcap or window"):
+            A.paged_partials(q, pool[0], pool[0], table, limits,
+                             impl="pallas", latent=True, softcap=30.0)
+    acc0, m0, l0 = partials("xla")(q, pool, table, limits, jnp.int32(layer))
+    if spec.get("nan"):
+        listed = np.zeros(pool.shape[1], bool)
+        for b, n in enumerate(spec["limits"]):
+            listed[np.asarray(table)[b, : -(-n // page)]] = True
+        pool = jnp.where(listed[None, :, None, None, None], pool, jnp.nan)
+    acc, m, l = partials("pallas")(q, pool, table, limits, jnp.int32(layer))
+    assert np.isfinite(np.asarray(acc)).all() and np.isfinite(np.asarray(l)).all()
     live = np.asarray(l0) > 0
+    assert live.any(axis=(1, 2, 3)).tolist() == [n > 0 for n in spec["limits"]]
+    np.testing.assert_array_equal(np.asarray(l)[~live], 0)
     np.testing.assert_allclose(np.where(live, acc / np.where(live, l, 1), 0),
                                np.where(live, acc0 / np.where(live, l0, 1), 0),
                                atol=2e-3)
-    np.testing.assert_allclose(l, l0, rtol=2e-3)
+    # (m, l) as the merge reads the pair, m + log l: l alone is a sum of
+    # exp(s - m) in the frame of its own m, and the kernel's m sits some
+    # |s| · 2^-9 off the walk's (it rounds the scaled q to bfloat16, as
+    # Mosaic's float32 dot did on the chip), which a one-token slot shows in
+    # m and a long one in l
+    lse, lse0 = (np.where(live, m_ + np.log(np.where(live, l_, 1)), 0)
+                 for m_, l_ in ((m, l), (m0, l0)))
+    np.testing.assert_allclose(lse, lse0, rtol=2e-3, atol=2e-3)
 
 
 def test_decode_step_slices_no_layer_out_of_the_state():

@@ -243,3 +243,37 @@ def test_site_counts_tell_the_kernels_arithmetic(dtype, key, K, page, visit):
         assert tally[f"paged_attention_{visit}"] == n
         assert tally["paged_attention_multipage"] + tally[
             "paged_attention_onepage"] == n
+
+
+def test_the_latent_walk_counts_what_it_does():
+    """The tiny Kimi-Linear preset's decode step over a bfloat16 latent pool
+    (ISSUE 48): every `latent_paged_attention` call of the trace hands its
+    pages to the MXU as stored, several a visit, out of the stacked pool; no
+    float32 site is left. The XLA walk counts under neither."""
+    from localai_tpu.models import llama as L
+    from localai_tpu.models.config import get_arch
+    from localai_tpu.ops.stacked import SiteCounts
+
+    cfg = get_arch("tiny-kimi-linear")
+    params = jax.eval_shape(lambda: L.init_params(cfg, jax.random.key(0)))
+    B, n, kl = 2, 4, len(cfg.recurrent_layers)
+    pool = L.paged_cache_zeros(cfg, 5, 16, dtype=jnp.bfloat16)
+    lk = jnp.zeros((cfg.cache_layers, B, n, 1, cfg.cache_k_dim), jnp.float32)
+    for impl, kernel in (("pallas", True), ("xla", False)):
+        sites = SiteCounts()
+        with sites.tracing("decode_block"):
+            text = str(jax.make_jaxpr(lambda p, st, cv: L.decode_step_windowed(
+                cfg, p, jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
+                pool, lk, lk[..., :0], jnp.int32(0),
+                ptable=jnp.zeros((B, 4), jnp.int32), paged_impl=impl,
+                recurrent=(st, cv), kda_impl="xla"))(
+                    params, jnp.zeros((kl, B, 4, 16, 16), jnp.float32),
+                    jnp.zeros((kl, B, 3, 3 * 64), jnp.float32)))
+        tally = sites.by_program["decode_block"]
+        calls = text.count("name=latent_paged_attention")
+        assert (calls > 0) == kernel and "name=paged_attention" not in text
+        assert tally["paged_attention_f32"] == 0
+        assert tally["paged_attention_native"] == calls
+        assert tally["paged_attention_multipage"] == calls
+        assert tally["paged_attention_onepage"] == 0
+        assert tally["paged_attention_stacked"] == calls

@@ -152,9 +152,50 @@ def test_visit_rule_sizes_a_visit_in_rows(page, K, width, flat, swin, want):
     from localai_tpu.ops.paged_flash import (
         RING_VMEM_BYTES, VISIT_ROWS, _ring_depth, _visit_pages)
 
-    n = _visit_pages(page, K, width, flat=flat, swin=swin)
+    n = _visit_pages(page, K, width, (128 + 128) * 2, flat=flat, swin=swin)
     assert n == want
     assert n == 1 or n * page * K <= VISIT_ROWS
     visit_bytes = n * page * K * (128 + 128) * 2
     assert _ring_depth(visit_bytes) * visit_bytes <= RING_VMEM_BYTES
     assert _ring_depth(VISIT_ROWS * (128 + 128) * 2) == 4
+
+
+
+@pytest.mark.parametrize("cell,K,row_bytes,want,ring", [
+    ("mistral-7b-int8", 8, 512, 1, 4),
+    ("mistral-7b-bf16-tp4", 2, 512, 6, 4),
+    ("olmoe-1b-7b-int8", 16, 512, 1, 3),
+    ("solar-open2-250b-int8-ep8", 8, 512, 1, 4),
+    ("lfm2-8b-a1b-int8", 4, 512, 3, 4),  # 8 heads of 64, two a row
+    ("granite-4.0-h-small-int8-ep8", 8, 512, 1, 4),
+    # the latent pool: one 640-wide bfloat16 row a token, key and value
+    ("kimi-linear-48b-a3b-int8-ep8", 1, 1280, 6, 3),
+])
+def test_every_cells_visit_is_what_it_was(cell, K, row_bytes, want, ring):
+    """The byte bound (ISSUE 48) sizes the latent visit and no other cell's:
+    at 512 B of K and V a row VISIT_BYTES is more than VISIT_ROWS rows, so
+    the rule in rows decides as it did (the GQA cells' figures are PR 41's
+    and PR 42's). K row-heads a chip and the bytes a row lands are read from the
+    cell's configuration (`benchmark/configs/<cell>.json`), so a cell that
+    changes its pool shows up here."""
+    import json
+    import pathlib
+
+    from localai_tpu.models.config import get_arch
+    from localai_tpu.ops.paged_flash import (
+        VISIT_BYTES, VISIT_ROWS, _ring_depth, _visit_pages)
+
+    y = json.loads((pathlib.Path(__file__).parent.parent / "benchmark"
+                    / "configs" / f"{cell}.json").read_text())["yaml"]
+    cfg = get_arch(y["model"])
+    page, width = y["kv_page_size"], y["context_size"] // y["kv_page_size"]
+    assert (page, width) == (128, 32)
+    assert K == (1 if cfg.is_mla else cfg.num_kv_heads // cfg.cache_pack
+                 // int(y.get("tensor_parallel") or 1))
+    assert row_bytes == (cfg.cache_k_dim + cfg.cache_v_dim) * 2  # bfloat16
+    assert VISIT_BYTES >= VISIT_ROWS * 512
+    n = _visit_pages(page, K, width, row_bytes, flat=True)
+    assert n == want
+    if row_bytes == 512:  # the rule before the byte bound
+        assert n == max(1, VISIT_ROWS // (page * K))
+    assert _ring_depth(n * page * K * row_bytes) == ring
