@@ -83,6 +83,12 @@ SIZES = {
         # granite-4.0-h-small's SSD state as published, 4 of its 36 layers:
         # 32 slots of 128 heads of 64 x 128 float32 (4 MiB a slot and layer)
         ssd=dict(layers=4, slots=32, heads=128, P=64, N=128),
+        # glm-4.7-flash as published: 47 latent layers, 20 heads (no multiple
+        # of 8) of 256-wide q/k/v; 8 slots of 20 pages for contexts of 2,000
+        # tokens (1.2 GB of pool); the block write over 16 slots of 3 pages
+        # (0.4 GB: the scatter it is held against relays the pool twice)
+        mla=dict(layers=47, heads=20, head=256, latent=640, slots=8,
+                 pages=20, write_slots=16, write_pages=49, prefill=(512, 2048)),
     ),
     "tiny": dict(
         arch="tiny", slots=4, context=512, page=16,
@@ -98,6 +104,8 @@ SIZES = {
         hybrid_gqa=dict(kda_layers=2, slots=4, heads=4, dk=16, K=2, G=4,
                         moe=dict(layers=2, experts=3, hidden=64, ffn=40)),
         ssd=dict(layers=3, slots=4, heads=8, P=16, N=32),
+        mla=dict(layers=3, heads=5, head=32, latent=128, slots=4,
+                 pages=6, write_slots=4, write_pages=17, prefill=(32,)),
     ),
 }
 
@@ -698,6 +706,71 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
          (rnd((Bk, Hm, W)), long_pool, long_table, long_limits,
           jnp.int32(0), jnp.int32(1)), 5e-3)
     del long_pool
+
+    # GLM-4.7-Flash's shapes (ISSUE 49). The latent walk at 20 query rows a
+    # slot, which is no multiple of a sublane tile, over the first and the
+    # last of 47 layers, contexts of 1,500-2,500 tokens: same arithmetic as
+    # the two cases above -> 5e-3.
+    gm = s["mla"]
+    Lg, Bg, gp = gm["layers"], gm["slots"], gm["pages"]
+    def rnd16(shape):  # a pool drawn in its own 16 bits: no float32 twin
+        return jax.random.normal(next(keys), shape, jnp.bfloat16)
+
+    g_pool = rnd16((Lg, Bg * gp + 1, page, 1, gm["latent"]))
+    g_table = (jax.random.permutation(next(keys), Bg * gp) + 1).reshape(
+        Bg, gp).astype(jnp.int32)
+    g_limits = jnp.array(
+        [(i * 61 + 17) % (gp * page * 2 // 5) + gp * page * 3 // 5
+         for i in range(Bg)], jnp.int32).at[0].set(0).at[1].set(gp * page)
+    case(f"latent_paged_decode_h{gm['heads']}_l{Lg}_long",
+         latent("auto"), latent("xla"),
+         (rnd((Bg, gm["heads"], gm["latent"])), g_pool, g_table, g_limits,
+          jnp.int32(0), jnp.int32(Lg - 1)), 5e-3)
+    del g_pool
+    # The latent pool's block write (`ops/pool_write.latent_pool_write`: a
+    # slot's rows staged through VMEM in their one or two 16-row tile
+    # groups) at the cell's 47 layers and 16-step block over a small pool
+    # (the scatter it is held against relays the whole pool twice and does
+    # not fit at the cell's): the same pool, exactly (tol 0).
+    # Starts at every offset of a tile group among them, a straddle of two
+    # pages, a page's first and last rows, an idle slot on the SCRATCH page;
+    # no row past the table, which the kernel drops and the scatter clamps.
+    lw_slots, lw_n = gm["write_slots"], 16 if page == 128 else 4
+    lw_mp = (gm["write_pages"] - 1) // lw_slots
+    lw_table = (jax.random.permutation(next(keys), gm["write_pages"] - 1)[
+        : lw_slots * lw_mp] + 1).reshape(lw_slots, lw_mp).astype(
+        jnp.int32).at[3].set(0)
+    lw_start = ((jnp.arange(lw_slots) * 37 + jnp.arange(lw_slots) % 16)
+                % (lw_mp * page - lw_n)).astype(jnp.int32).at[0].set(
+        page - lw_n // 2).at[1].set(page - lw_n).at[2].set(page).at[3].set(7)
+
+    def latent_write(impl):
+        return lambda kp, t, wk, st: LL.write_block_to_pool(
+            LL.KVCache(kp, kp[..., :0]), t, wk, wk[..., :0], st,
+            paged_impl=impl).k
+
+    case(f"latent_pool_write_l{Lg}_n{lw_n}", latent_write("auto"),
+         latent_write("xla"),
+         (rnd16((Lg, gm["write_pages"], page, 1, gm["latent"])), lw_table,
+          rnd((Lg, lw_slots, lw_n, 1, gm["latent"])), lw_start), 0.0)
+    # The prefill's flash kernel at MLA's full-rank heads, 20 of 256 wide
+    # for q, k and v alike (the cells so far prefill at 128 and 64): same
+    # rounding as flash_prefill_S* -> 2e-2.
+    for S in gm["prefill"]:
+        lens = jnp.array([S, max(1, S - 7)], jnp.int32)
+        qkv = [rnd((2, S, gm["heads"], gm["head"])) for _ in range(3)]
+
+        def valid_rows(out, lens, S=S):
+            return jnp.where((jnp.arange(S)[None, :] < lens[:, None])[
+                :, :, None, None], out, 0)
+
+        case(f"flash_prefill_h{gm['heads']}_d{gm['head']}_S{S}",
+             lambda q, k, v, lens: valid_rows(A.prefill_attention(
+                 q, k, v, None, lengths=lens), lens),
+             lambda q, k, v, lens: valid_rows(A.causal_prefill_attention(
+                 q, k, v, jnp.arange(q.shape[1])[None, :] < lens[:, None]),
+                 lens),
+             (*qkv, lens), 2e-2)
 
     # The held experts' stacks [26 x 32, 2304, 1024] at 64 rows through the
     # same kernel and block rule as olmoe's [16 x 64, 2048, 1024]: a whole

@@ -725,6 +725,22 @@ def _named_jit(fn, name: str, sites: Optional[SiteCounts] = None,
     return jax.jit(fn, **kw)
 
 
+def _gather_pages(k, v, pages):
+    """`k[:, pages], v[:, pages]` of a page pool, a page at a time. XLA's
+    gather over a row wider than one lane tile first cuts the WHOLE pool into
+    128-lane slices, a pool-sized temporary (5.9 GB under a 47-layer latent
+    pool of 640-value rows: it could not load beside the pool, PERF.md
+    section 7 item 15a). A slice of the page axis reads the pages it wants
+    whatever the row."""
+    def per_page(x):
+        got = jax.lax.map(
+            lambda p: jax.lax.dynamic_index_in_dim(x, p, axis=1,
+                                                   keepdims=False), pages)
+        return jnp.moveaxis(got, 0, 1)
+
+    return per_page(k), per_page(v)
+
+
 def _host_copy_async(arr: Any) -> None:
     """Start a device→host copy without blocking; np.asarray later is then a
     cheap wait instead of a full round trip."""
@@ -2299,10 +2315,8 @@ class Engine:
         key = ("pages-gather", npgb)
         fn = self._block_cache.get(key)
         if fn is None:
-            def gather(k, v, pages):
-                return k[:, pages], v[:, pages]
-
-            fn = self._jit(gather, "pages_gather", leaf="attention/cache_write")
+            fn = self._jit(_gather_pages, "pages_gather",
+                           leaf="attention/cache_write")
             self._block_cache[key] = fn
         return fn
 
@@ -6369,12 +6383,12 @@ class Engine:
                     self.cfg, self.cache.conv.dtype))
             out["state_snapshots"] = 0.0  # rows are dropped, never copied
             out["state_restores"] = float(self.m_state_restores)
-            bound = rstate.admit_rows(self.cfg)
-            if bound:  # KDA's byte bound on an admission program
-                out["admit_splits"] = float(self.m_admit_splits)
-                out["admit_rows_max"] = float(bound)
             out["prefix_reuse_off"] = float(
                 self.ecfg.prefix_cache_entries > 0)
+        bound = rstate.admit_rows(self.cfg)
+        if bound:  # the byte bound on an admission program (KDA, SSD, MLA)
+            out["admit_splits"] = float(self.m_admit_splits)
+            out["admit_rows_max"] = float(bound)
         # Call sites over every program traced so far: the Pallas kernel read
         # its layer out of the stacked operand (weights; the paged K/V pool),
         # or the layer was sliced out first (ops/stacked.SiteCounts).
@@ -8168,10 +8182,18 @@ class Engine:
         if tk_block is not None:
             _host_copy_async(tk_block)
         self.h_override_mask[:] = False
+        held = 0  # pool rows the live slots hold as the block starts
         for i in range(self.ecfg.max_slots):
             if active_snapshot[i] and self.slots[i] is not None:
+                held += self.slots[i].sched_rows
                 self.slots[i].scheduled += n
                 self.slots[i].sched_rows += n
+        if self.cfg.is_mla and self._paged:
+            # What the latent walk reads: every step of the block walks the
+            # rows its slots held at dispatch (the block's own rows ride in
+            # the window), of a pool of so many rows.
+            self._jnote("latent_rows", a=float(n * held), b=float(
+                n * self.ecfg.kv_pages * self.ecfg.kv_page_size))
         entry = _Entry(
             kind="block", toks=toks_block, tk=tk_block, lp=lp_block,
             gen=list(self._slot_gen), active=active_snapshot, n=n,

@@ -87,18 +87,39 @@ def _ssd_token_bytes(cfg) -> int:
     return 4 * (4 * H * C + 2 * H * P * N // C + 6 * H * P)
 
 
-# Bytes of float32 temporaries one prompt token costs an admission program,
-# by recurrent kind; a kind that is not here is not bounded (a conv model's
-# prefill holds a few [T, D] rows a prompt, as any layer's).
-_ADMIT_TOKEN_BYTES = {"kda": _kda_token_bytes, "ssd": _ssd_token_bytes}
+def _mla_token_bytes(cfg) -> int:
+    """What an admission of a plain-scan MLA model holds a prompt token: the
+    latent rows of EVERY layer until `write_prefill_to_pool` has them (the
+    scan's stacked output: 60 KB at 47 layers of 640 bfloat16), the
+    full-rank q, k and v of the layer at work (3 x H x the q/k width: 30 KB
+    at 20 heads of 256) and, for experts, the grouped path's [rows, top-k,
+    D] float32 rows in and out (64 KB at top-4 of 2048): 156 KB, 6,800 rows
+    a program (tools/cell_program.py --program admit for a described v5e:
+    3.03 GB of temporaries at 32 x 512 rows, 0.81 GB at 4 x 1,024, beside
+    12.7 GB held)."""
+    size = 2  # the rows' and the activations' 16 bits
+    rows = cfg.cache_layers * cfg.cache_k_dim * size
+    qkv = 3 * cfg.num_heads * cfg.qk_head_dim * size
+    moe = 2 * cfg.num_experts_per_token * cfg.hidden_size * 4 if cfg.is_moe else 0
+    return rows + qkv + moe
+
+
+# Bytes of temporaries one prompt token costs an admission program, by
+# recurrent kind, or "mla" for a model of latent attention in every layer; a
+# kind that is not here is not bounded (a conv model's prefill holds a few
+# [T, D] rows a prompt, as any layer's).
+_ADMIT_TOKEN_BYTES = {"kda": _kda_token_bytes, "ssd": _ssd_token_bytes,
+                      "mla": _mla_token_bytes}
 
 
 def admit_rows(cfg) -> int | None:
     """Most prompt rows (requests x bucket) one admission program takes under
     `ADMIT_BYTES`, from the model's own widths (`_ADMIT_TOKEN_BYTES`): 2,048
     rows at KDA's 32 heads of 128, 1,024 at 64; 2,048 at SSD's 128 heads of
-    64 x 128. None for a kind without a bound."""
-    per_token = _ADMIT_TOKEN_BYTES.get(cfg.recurrent_kind)
+    64 x 128; 6,864 at GLM-4.7-Flash's 47 latent layers. None for a kind
+    without a bound."""
+    kind = cfg.recurrent_kind or ("mla" if cfg.is_mla else "")
+    per_token = _ADMIT_TOKEN_BYTES.get(kind)
     return max(1, ADMIT_BYTES // per_token(cfg)) if per_token else None
 
 

@@ -1206,8 +1206,24 @@ def arch_from_hf_config(ckpt_dir: str) -> ArchConfig:
             )
     act = hf.get("hidden_activation") or hf.get("hidden_act") or "silu"
     softcaps = gemma2 or gemma3  # gemma-3 configs carry the keys but None
-    if model_type in ("deepseek_v2", "deepseek_v3"):
-        v3 = model_type == "deepseek_v3"
+    if model_type in ("deepseek_v2", "deepseek_v3", "glm4_moe_lite"):
+        # `glm4_moe_lite` (GLM-4.7-Flash) is the V3 block under other sizes:
+        # MLA with a q-lora, `noaux_tc` routing (sigmoid, correction bias),
+        # the same tensor names. Its `num_nextn_predict_layers` MTP block
+        # lies at `model.layers.<num_hidden_layers>.*` and is never read:
+        # `_load_deepseek` asks for layers below `num_layers` by name.
+        glm = model_type == "glm4_moe_lite"
+        v3 = model_type == "deepseek_v3" or glm
+        if glm and "rope_interleave" not in hf:
+            # The published config.json carries no such key, and this
+            # repository holds no source (modeling code or config class)
+            # that fixes the family's default: a wrong guess rotates the
+            # wrong pairs of a real checkpoint's dims and nothing flags it.
+            raise ValueError(
+                "glm4_moe_lite: config.json has no `rope_interleave`; add "
+                "it (true: the rope columns of q_b_proj / kv_a_proj are "
+                "stored pair-interleaved and are permuted at load; false: "
+                "half-split as stored) from the checkpoint's modeling code")
         if scaling_type == "yarn":
             # DeepSeek yarn: the cos/sin attention_factor (mscale /
             # mscale_all_dim ratio) COMBINES with the extra softmax-scale
@@ -1266,8 +1282,9 @@ def arch_from_hf_config(ckpt_dir: str) -> ArchConfig:
             v_head_dim=hf.get("v_head_dim", 128),
             # V2 applies complex (pair-interleaved) rope unconditionally
             # (the modeling code ignores any flag); V3 checkpoints carry
-            # the flag (default true).
-            rope_interleave=True if not v3 else bool(hf.get("rope_interleave", True)),
+            # the flag (default true); a GLM config has to state it (above).
+            rope_interleave=(True if not v3
+                             else bool(hf.get("rope_interleave", True))),
         )
     # HF OlmoeAttention / OlmoeSparseMoeBlock: q/k RMS norms over the whole
     # projection; softmax over all experts, then top-k, weights renormalised

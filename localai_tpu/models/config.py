@@ -233,6 +233,10 @@ class ArchConfig:
     # step is drawn from, log-uniform. The default is a hundredth of fla's
     # [1e-3, 1e-1] (why: llama.KDA_DT); a preset may ask for another.
     kda_init_dt: tuple = (1e-5, 1e-3)
+    # Synthetic init only (llama.init_gain): what the routed experts'
+    # down-projection is drawn at besides the scale. The hybrid presets and
+    # GLM-4.7-Flash's say a tenth: a preset whose check needs it asks here.
+    routed_down_gain: float = 1.0
     # MLA whose rope dims are never rotated (Kimi-Linear's `mla_use_nope`).
     mla_rope: bool = True
     # Latent cache rows are stored padded to a multiple of this many values
@@ -465,6 +469,7 @@ PRESETS: dict[str, ArchConfig] = {
         num_kv_heads=4,
         head_dim=16,
         max_position=512,
+        routed_down_gain=0.1,
         layer_kinds=("kda", "kda", "kda", "mla", "kda", "kda", "mla"),
         kda_heads=4,
         kda_head_dim=16,
@@ -488,6 +493,43 @@ PRESETS: dict[str, ArchConfig] = {
         qk_rope_head_dim=16,
         v_head_dim=16,
     ),
+    "tiny-glm-4.7-flash": ArchConfig(
+        # GLM-4.7-Flash-shaped tiny: MLA in every layer through the plain
+        # scan, rotated, a q-lora bottleneck, value heads WIDER than the nope
+        # heads (so v is not padded up to the q/k width: 24 + 8 = 32), 5
+        # heads (no multiple of 8), one dense layer, 8 experts top-2 (sigmoid,
+        # correction bias, one group, renormalised, x1.8) beside a shared one,
+        # latent rows padded to one whole lane tile, [c 32 | k_pe 8 | 0 x
+        # 88], so that the pool's block write is the staged kernel's.
+        name="tiny-glm-4.7-flash",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=4,
+        num_heads=5,
+        num_kv_heads=5,
+        head_dim=8,  # rope table width = qk_rope_head_dim
+        rope_theta=1000000.0,
+        max_position=512,
+        rms_eps=1e-5,
+        latent_pad=128,
+        moe_family="deepseek",
+        num_experts=8,
+        num_experts_per_token=2,
+        first_k_dense=1,
+        n_shared_experts=1,
+        moe_intermediate_size=32,
+        routed_scaling_factor=1.8,
+        scoring_func="sigmoid",
+        router_bias=True,
+        norm_topk_prob=True,
+        routed_down_gain=0.1,
+        kv_lora_rank=32,
+        q_lora_rank=24,
+        qk_nope_head_dim=24,
+        qk_rope_head_dim=8,
+        v_head_dim=32,
+    ),
     "tiny-solar-open2": ArchConfig(
         # Solar-Open2-shaped tiny: two periods of 1 gated NoPE GQA : 3 KDA
         # with the cache layer LEADING its period, beta in (0, 2), every
@@ -505,6 +547,7 @@ PRESETS: dict[str, ArchConfig] = {
         max_position=512,
         attn_rope=False,
         attn_gate=True,
+        routed_down_gain=0.1,
         layer_kinds=("gqa", "kda", "kda", "kda") * 2,
         kda_heads=4,
         kda_head_dim=16,
@@ -540,6 +583,7 @@ PRESETS: dict[str, ArchConfig] = {
         rope_theta=1000000.0,
         tie_embeddings=True,
         qk_norm=True,
+        routed_down_gain=0.1,
         layer_kinds=("conv", "conv", "gqa", "conv", "conv", "conv"),
         conv_cache=3,
         moe_family="deepseek",
@@ -575,6 +619,7 @@ PRESETS: dict[str, ArchConfig] = {
         embedding_multiplier=6.0,
         residual_multiplier=0.35,
         logits_scaling=4.0,
+        routed_down_gain=0.1,
         layer_kinds=("ssd", "ssd", "ssd", "ssd", "gqa") * 2,
         mamba_heads=8,
         mamba_head_dim=16,
@@ -755,6 +800,7 @@ PRESETS: dict[str, ArchConfig] = {
         rope_theta=10000.0,
         max_position=1048576,
         rms_eps=1e-5,
+        routed_down_gain=0.1,
         layer_kinds=tuple(
             "mla" if (i + 1) % 4 == 0 or i == 26 else "kda" for i in range(27)),
         kda_heads=32,
@@ -781,6 +827,49 @@ PRESETS: dict[str, ArchConfig] = {
         qk_rope_head_dim=64,
         v_head_dim=128,
     ),
+    "glm-4.7-flash": ArchConfig(
+        # zai-org/GLM-4.7-Flash config.json (`glm4_moe_lite`, 30B-A3B): 47
+        # layers, every one MLA: 20 heads, a 768-wide q-lora bottleneck, a
+        # 512-wide latent, 192 nope + 64 rope dims a q/k head (all 64
+        # rotated, theta 1e6, no scaling) and 256-wide value heads; layer 1 a
+        # dense SwiGLU of 10240, layers 2-47 64 routed experts of 1536 top-4
+        # (`noaux_tc`: sigmoid, correction bias, one group, renormalised,
+        # x1.8) plus one shared expert; untied head. The config's one MTP
+        # block (`num_nextn_predict_layers` 1, checkpoint layer 47) is a
+        # draft source and no part of the language model: not built, its
+        # names never read (engine/weights._load_deepseek stops at
+        # num_layers). Latent rows [c 512 | k_pe 64 | 0 x 64].
+        name="glm-4.7-flash",
+        vocab_size=154880,
+        hidden_size=2048,
+        intermediate_size=10240,
+        num_layers=47,
+        num_heads=20,
+        num_kv_heads=20,
+        head_dim=64,  # rope table width = qk_rope_head_dim
+        rope_theta=1000000.0,
+        max_position=202752,
+        rms_eps=1e-5,
+        latent_pad=128,
+        moe_family="deepseek",
+        num_experts=64,
+        num_experts_per_token=4,
+        first_k_dense=1,
+        n_shared_experts=1,
+        moe_intermediate_size=1536,
+        routed_scaling_factor=1.8,
+        scoring_func="sigmoid",
+        router_bias=True,
+        norm_topk_prob=True,
+        n_group=1,
+        topk_group=1,
+        routed_down_gain=0.1,
+        kv_lora_rank=512,
+        q_lora_rank=768,
+        qk_nope_head_dim=192,
+        qk_rope_head_dim=64,
+        v_head_dim=256,
+    ),
     "solar-open2-250b": ArchConfig(
         # upstage/Solar-Open2-250B config.json (`solar_open2`, 250B-A15B):
         # 48 layers in periods of one gated NoPE GQA layer (`gqa_layers` 0,
@@ -804,6 +893,7 @@ PRESETS: dict[str, ArchConfig] = {
         rms_eps=1e-5,
         attn_rope=False,
         attn_gate=True,
+        routed_down_gain=0.1,
         layer_kinds=tuple("gqa" if i % 4 == 0 else "kda" for i in range(48)),
         kda_heads=64,
         kda_head_dim=128,
@@ -852,6 +942,7 @@ PRESETS: dict[str, ArchConfig] = {
         rms_eps=1e-5,
         tie_embeddings=True,
         qk_norm=True,
+        routed_down_gain=0.1,
         layer_kinds=tuple(
             "gqa" if i in (2, 6, 10, 14, 18, 21) else "conv"
             for i in range(24)),
@@ -893,6 +984,7 @@ PRESETS: dict[str, ArchConfig] = {
         embedding_multiplier=12.0,
         residual_multiplier=0.22,
         logits_scaling=16.0,
+        routed_down_gain=0.1,
         layer_kinds=tuple(
             "gqa" if i in (5, 15, 25, 35) else "ssd" for i in range(40)),
         mamba_heads=128,

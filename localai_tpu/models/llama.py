@@ -191,11 +191,12 @@ SSD_DT = (1e-3, 1e-1)
 
 
 def init_gain(cfg: ArchConfig, name: str, shape) -> float:
-    """What the normal draw of a leaf is multiplied by besides `scale`. A
-    hybrid model's routed experts' down-projection ([L, E, F, D]) is drawn at
-    a tenth: its router renormalises and scales the picks, so with random
-    experts a pick that flips at a near-tie would move the stream by a
-    quarter of a layer, and every rounding anywhere reads as routing noise.
+    """What the normal draw of a leaf is multiplied by besides `scale`. The
+    routed experts' down-projection ([L, E, F, D]) is drawn at
+    `cfg.routed_down_gain`, a tenth in the hybrid presets and
+    GLM-4.7-Flash's: with random experts a pick that flips at a near-tie
+    moves the stream by a quarter of a layer, and every rounding anywhere
+    reads as routing noise.
 
     A model with Granite's scalar multipliers is drawn so that they are
     undone, and the random model is the random model of every other family:
@@ -217,8 +218,8 @@ def init_gain(cfg: ArchConfig, name: str, shape) -> float:
     if name in ("wo", "w_down", "shared_down"):
         gain = float(cfg.embedding_multiplier * cfg.logits_scaling
                      / cfg.residual_multiplier)
-    if cfg.is_hybrid and name == "w_down" and len(shape) == 4:
-        gain *= 0.1
+    if name == "w_down" and len(shape) == 4:
+        gain *= cfg.routed_down_gain
     return gain
 
 
@@ -1795,12 +1796,15 @@ def _forward_hidden(
             return ring_prefill_attention(q, k, v, lengths, mesh,
                                           **_mask_opts(cfg, sliding))
     else:
-        # MLA takes the dense path (no `lengths`): the flash kernel tiles
-        # head_dim in 128-lane blocks and MLA's qk width (192) is not a
-        # multiple.
+        # The flash kernel tiles head_dim in 128-lane blocks: MLA at a q/k
+        # width that is no multiple (192: Kimi-Linear, the DeepSeek presets)
+        # takes the dense path (no `lengths`); at 256 (GLM-4.7-Flash, whose
+        # values are as wide, so `_mla_full_qkv` pads nothing) the kernel.
+        flash = not cfg.is_mla or cfg.qk_head_dim % 128 == 0
+
         def attend(q, k, v, sliding):
             return prefill_attention(
-                q, k, v, length_mask, None if cfg.is_mla else lengths,
+                q, k, v, length_mask, lengths if flash else None,
                 mesh=mesh, **_mask_opts(cfg, sliding))
 
     def body(h, xs):  # lora: la, this layer's adapter factors, rides last
@@ -2443,10 +2447,10 @@ def gather_pages(
         # prefill_tail consumes must be real values again.
         k = k.astype(jnp.float32) * kv_scale[0][..., :, None]
         v = v.astype(jnp.float32) * kv_scale[1][..., :, None]
-    L, NP, page, K, Hd = k.shape
+    L, NP, page, K = k.shape[:4]  # each at its own width: MLA's v has none
     return (
-        k.reshape(L, 1, NP * page, K, Hd),
-        v.reshape(L, 1, NP * page, K, Hd),
+        k.reshape(L, 1, NP * page, K, k.shape[-1]),
+        v.reshape(L, 1, NP * page, K, v.shape[-1]),
     )
 
 

@@ -101,6 +101,11 @@ BASE_EVENTS = (
     "state_rows",    # a hybrid model's decode block was processed (a=rows of
     #                  the recurrent state it updated: steps x compiled rows x
     #                  KDA layers, b=of those, rows of a live tenant)
+    "latent_rows",   # a decode block of an MLA model under the paged pool
+    #                  was dispatched (a=latent rows its live slots held at
+    #                  dispatch x its steps: what the block's page walks
+    #                  read, its own rows ride in the window; b=the pool's
+    #                  rows x its steps)
     "prefix_reuse_off",  # once, at start: prefix-span reuse was asked for
     #                  and is off (a hybrid model's prefix would need a
     #                  snapshot of its recurrent state; a=entries asked for)

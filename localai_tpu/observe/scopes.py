@@ -78,6 +78,13 @@ CONV_MIX = "conv_mix"
 # `ssd_decode` kernel (or the chunkwise prefill) and the gated norm.
 SSD_MIX = "ssd_mix"
 
+# A latent pool's block write (MLA's one 16-bit row a token, staged through
+# VMEM by `ops/pool_write.latent_pool_write`), by the same rule: written
+# around the kernel INSIDE `attention/cache_write`, which books it; the word
+# is what tells a reader this write from the zero-width V pool's scatter and
+# from a recurrent state's rows beside it.
+LATENT_WRITE = "latent_write"
+
 
 def scope(leaf: str):
     """`jax.named_scope(leaf)` for a leaf of `SCOPES` (context manager or

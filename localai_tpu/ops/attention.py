@@ -667,33 +667,47 @@ def write_window(pool, win, pid, off, impl: str = "auto", mesh=None):
     - the engine's paged reader is the Pallas kernel (`impl`, resolved as
       `paged_partials` resolves it) AND a token's row of one chip's part of
       the pool is narrower than the native tile and whole sublane words
-      (`pool_write.in_place_rows`: 2 to 7 rows of D at 16 bits, so not the
-      latent pool's one): the `pool_write` kernel copies the window's
+      (`pool_write.in_place_rows`: 2 to 7 rows of D at 16 bits): the
+      `pool_write` kernel copies the window's
       rows into the donated pool by DMA, in place; under a tp mesh inside
       `shard_map` with the pool spec `_paged_pallas_sharded` gives the
       reader, indices replicated. XLA stores such a pool tiled `T(K,128)`
       and ran the scatter in another layout, a copy of the whole pool each
       way, K and V, every block;
+    - the Pallas reader AND a latent pool's one 16-bit row a token
+      (`pool_write.staged_rows`, ISSUE 49): half a sublane word, which no
+      DMA slices, so `latent_pool_write` reads the one or two 16-row tile
+      groups a slot's rows lie in into VMEM, replaces the rows and writes
+      the groups back, in place. Its ops carry `scopes.LATENT_WRITE`. The
+      scatter it replaces relaid the pool like the narrow K/V pools';
     - otherwise XLA's scatter, which at 8 rows and more runs in the layout
       the pool is stored in.
 
-    Counted per traced program (`stacked.note_pool_write`)."""
+    Counted per traced program (`stacked.note_pool_write`): the two kernels
+    are `pool_write_inplace`, the scatter `pool_write_scatter`."""
     import functools
 
     from jax.sharding import PartitionSpec as P
 
+    from localai_tpu.observe.scopes import LATENT_WRITE
     from localai_tpu.ops.paged_flash import use_pallas
-    from localai_tpu.ops.pool_write import in_place_rows, pool_write
+    from localai_tpu.ops.pool_write import (
+        in_place_rows, latent_pool_write, pool_write, staged_rows)
     from localai_tpu.ops.stacked import note_pool_write
 
     tp = _tp_degree(mesh)
     local = (*pool.shape[:3], pool.shape[3] // tp, pool.shape[4])
-    inplace = use_pallas(impl) and in_place_rows(local, pool.dtype)
-    note_pool_write(inplace)
+    pallas = use_pallas(impl)
+    staged = pallas and tp == 1 and staged_rows(local, pool.dtype, win.shape[2])
+    inplace = pallas and in_place_rows(local, pool.dtype)
+    note_pool_write(inplace or staged)
+    interpret = jax.default_backend() != "tpu"
+    if staged:
+        with jax.named_scope(LATENT_WRITE):
+            return latent_pool_write(pool, win, pid, off, interpret=interpret)
     if not inplace:
         return pool.at[:, pid, off].set(win)
-    kernel = functools.partial(pool_write,
-                               interpret=jax.default_backend() != "tpu")
+    kernel = functools.partial(pool_write, interpret=interpret)
     if tp > 1:
         pool_spec = P(None, None, None, "tp", None)  # [L, P, page, K, D]
         kernel = _head_shard_map(

@@ -335,3 +335,32 @@ def test_mosaic_takes_the_kernel(topo, shape, n, dtype):
     assert cell_program.kernels(text) == {"pool_write": 1}
     assert cell_program.pool_copies(text, shape) == []
     assert "output_to_operand_aliasing" in text
+
+
+@pytest.mark.parametrize("k,v", [
+    ((47, 897, 128, 1, 640), (47, 897, 128, 1, 0)),  # GLM-4.7-Flash's latent pool
+    ((32, 257, 128, 8, 128), (32, 257, 128, 8, 128)),  # mistral-7b's
+], ids=["latent", "gqa"])
+def test_swap_gather_holds_no_copy_of_the_pool(topo, k, v):
+    """The swap path's gather of 8 pages: XLA's own gather cut a pool of
+    640-value rows into five pool-sized slices (5.9 GB of temporaries, which
+    failed to load beside the pool on the chip); a page at a time holds the
+    pages it returns and no more."""
+    from jax.sharding import SingleDeviceSharding
+
+    from localai_tpu.engine.engine import _gather_pages
+    from tools import cell_program
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(s, d):
+        return jax.ShapeDtypeStruct(s, jnp.dtype(d), sharding=one)
+
+    with cell_program.as_on_tpu():
+        prog = jax.jit(_gather_pages).trace(
+            sds(k, "bfloat16"), sds(v, "bfloat16"), sds((8,), "int32"),
+        ).lower(lowering_platforms=("tpu",)).compile()
+    image = 2 * 8 * (np.prod(k) + np.prod(v)) // k[1]
+    mem = prog.memory_analysis()
+    assert mem.output_size_in_bytes >= image
+    assert mem.temp_size_in_bytes < image
