@@ -158,6 +158,7 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
     from localai_tpu.models import quant as Q
     from localai_tpu.ops import attention as A
     from localai_tpu.ops import lora_matmul as LM
+    from localai_tpu.ops import paged_flash as PF
     from localai_tpu.utils.compile_cache import configure_compile_cache
 
     configure_compile_cache()
@@ -663,7 +664,10 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
     # published row; ISSUE 48) against the XLA walk. Same arithmetic as
     # paged_decode -> 5e-3. Ragged contexts with an idle slot, a one-page
     # slot in front of a live one, contexts that end on a page's last row
-    # and on the next one's first, and exactly a visit and a row more.
+    # and on the next one's first, and exactly a visit and a row more. The
+    # call states the value width the cells state (kv_lora_rank, 512 of the
+    # 640 lanes: the value dot on those lanes alone; ISSUE 50) and the lanes
+    # that are read are compared; the slots' visits are one stream of copies.
     Lm, Hm, W = hy["mla_layers"], hy["mla_heads"], hy["latent"]
     lat_pool = rnd((Lm, hy["pages"], page, 1, W))
     lat_pages = (hy["pages"] - 1) // Bk
@@ -679,11 +683,14 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
 
     def latent(impl):
         def fn(q, pool, t, lim, first, last):
+            values = q.shape[-1] * 4 // 5  # kv_lora_rank of the padded row
+            read = PF.value_lanes(values, q.shape[-1])
             out = []
             for i in (first, last):
                 c = Q.StackedLayer(pool, i)
-                out.append(settled(A.paged_partials(
-                    q, c, c, t, lim, impl=impl, latent=True)))
+                acc, m, l = A.paged_partials(
+                    q, c, c, t, lim, impl=impl, latent=True, values=values)
+                out.append(settled((acc[..., :read], m, l)))
             return tuple(out)
         return fn
 
@@ -725,6 +732,18 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
     case(f"latent_paged_decode_h{gm['heads']}_l{Lg}_long",
          latent("auto"), latent("xla"),
          (rnd((Bg, gm["heads"], gm["latent"])), g_pool, g_table, g_limits,
+          jnp.int32(0), jnp.int32(Lg - 1)), 5e-3)
+    # ... and contexts as `decode-reasoning` holds them, 150 to 2,560 tokens:
+    # slots of one visit and of two, three and four, whose first visits the
+    # slots before them start (the stream), a last visit of every live size
+    # 1..6, a page's last row and first
+    g_mix = jnp.array(  # (live pages, rows of the last one) a slot
+        [(min(n, gp) - 1) * page + min(rows, page) for n, rows in (
+            (2, 22), (5, page - 7), (7, 1), (9, page - 3), (3, page),
+            (10, page), (gp, page), (gp - 2, 5))][:Bg], jnp.int32)
+    case(f"latent_paged_decode_h{gm['heads']}_l{Lg}_mix",
+         latent("auto"), latent("xla"),
+         (rnd((Bg, gm["heads"], gm["latent"])), g_pool, g_table, g_mix,
           jnp.int32(0), jnp.int32(Lg - 1)), 5e-3)
     del g_pool
     # The latent pool's block write (`ops/pool_write.latent_pool_write`: a

@@ -6418,6 +6418,15 @@ class Engine:
                 sites["paged_attention_multipage"])
             out["paged_attention_onepage_sites"] = float(
                 sites["paged_attention_onepage"])
+        if (sites["paged_attention_value_lanes"]
+                or sites["paged_attention_value_row"]):
+            # a latent pool's kernel calls alone, by the lanes their value
+            # dot runs over: the stated value lanes, or the whole row
+            # (stacked.note_value_lanes)
+            out["paged_attention_value_lanes_sites"] = float(
+                sites["paged_attention_value_lanes"])
+            out["paged_attention_value_row_sites"] = float(
+                sites["paged_attention_value_row"])
         if sites["pool_write_inplace"] or sites["pool_write_scatter"]:
             # how each decode block's window reached its two page pools: the
             # DMA kernel in place, or XLA's scatter (stacked.note_pool_write)

@@ -2061,12 +2061,15 @@ def decode_step_windowed(
 
     if ptable is not None:
         # A latent row padded to lane tiles (`cfg.latent_pad`) is laid out
-        # for the latent paged kernel: this step says so, the op checks it.
+        # for the latent paged kernel: this step says so, and which of the
+        # row's lanes `_mla_unlatent` reads as values; the op checks it.
+        latent = cfg.is_mla and bool(cfg.latent_pad)
+
         def attend(q, k, v, sliding, kc, vc, lk, lv):
             return decode_attention_windowed_paged(
                 q, kc, vc, ptable, lk, lv, k, v, positions, step,
-                impl=paged_impl, kv_scale=kv_scale,
-                latent=cfg.is_mla and bool(cfg.latent_pad),
+                impl=paged_impl, kv_scale=kv_scale, latent=latent,
+                values=cfg.kv_lora_rank if latent else 0,
                 **_mask_opts(cfg, sliding, mesh=mesh, **sink))
     elif use_sp:
         def attend(q, k, v, sliding, kc, vc, lk, lv):

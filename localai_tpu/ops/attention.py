@@ -348,7 +348,8 @@ def _merge_partials(q, acc_g, m_g, l_g, extra_k, extra_v, extra_mask,
                     softcap: float = 0.0):
     """Merge sharded-cache partials with a small dense tail (local window
     and/or the current token). extra_k: [B, E, K, D]; extra_mask: [B, E] or
-    [E]. Returns [B, H, D] in q's dtype."""
+    [E]. Returns [B, H, Dv] in q's dtype, Dv the width of the partials and
+    of extra_v (q's, or a latent walk's value lanes)."""
     B, H, D = q.shape
     K = extra_k.shape[2]
     G = H // K
@@ -369,7 +370,7 @@ def _merge_partials(q, acc_g, m_g, l_g, extra_k, extra_v, extra_mask,
     num = acc_g * w_c + jnp.einsum("bkge,bekd->bkgd", p_e, extra_v.astype(jnp.float32))
     den = l_g * w_c + jnp.sum(p_e, axis=-1, keepdims=True)
     out = num / jnp.maximum(den, 1e-30)
-    return out.reshape(B, H, D).astype(q.dtype)
+    return out.reshape(B, H, -1).astype(q.dtype)
 
 
 def decode_attention_appended_sp(
@@ -736,7 +737,8 @@ def _paged_pools(k_pool, v_pool, pallas: bool):
 def paged_partials(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
                    window: int = 0, sliding=None, q_pos=None,
                    impl: str = "auto", mesh=None, kv_scale=None,
-                   sink: int = 0, swin: int = 0, latent: bool = False):
+                   sink: int = 0, swin: int = 0, latent: bool = False,
+                   values: int = 0):
     """Paged online-softmax partials, dispatched: the fused Pallas ragged
     paged-attention kernel (ops/paged_flash — pages stream HBM→VMEM once,
     walk bounded per slot) or the XLA gather walk below (reference path and
@@ -749,11 +751,13 @@ def paged_partials(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
     k_pool/v_pool: one layer's [P, page, K, D] pool, or a StackedLayer of the
     whole [L, P, page, K, D] pool (`_paged_pools`). `latent`: the caller's
     pool holds MLA's latent rows laid out for the latent kernel (llama's
-    decode step says so from `cfg.latent_pad`); the XLA walk reads such a
-    pool as any other. A pool whose rows are wider than q's heads holds
-    several heads a row (`ArchConfig.cache_pack`: [P, page, K/p, p·D]): the
-    kernel walks it as stored (`paged_decode_partials`), the XLA walk a
-    reshape of it."""
+    decode step says so from `cfg.latent_pad`, and with `values` how many
+    leading lanes of a row it reads as values: the kernel's acc then holds
+    those lanes alone, in whole lane tiles, `paged_flash.value_lanes`); the
+    XLA walk reads such a pool as any other, whole rows. A pool whose rows
+    are wider than q's heads holds several heads a row
+    (`ArchConfig.cache_pack`: [P, page, K/p, p·D]): the kernel walks it as
+    stored (`paged_decode_partials`), the XLA walk a reshape of it."""
     import functools
 
     from localai_tpu.ops.paged_flash import paged_decode_partials, use_pallas
@@ -784,7 +788,7 @@ def paged_partials(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
         return paged_decode_partials(
             q, k_pool, v_pool, table, limits, softcap=softcap, window=window,
             sliding=sliding, q_pos=q_pos, interpret=interp, kv_scale=kv_scale,
-            sink=sink, swin=swin, latent=latent,
+            sink=sink, swin=swin, latent=latent, values=values,
         )
     return _paged_cache_partials(
         q, k_pool, v_pool, table, limits,
@@ -900,10 +904,12 @@ def decode_attention_windowed_paged(
     sink: int = 0,  # windowed+sink decode (docs/LONG_CONTEXT.md): rows
     swin: int = 0,  # attended iff gpos < sink or q_pos - gpos < swin
     latent: bool = False,  # the pool is MLA's latent one (`paged_partials`)
+    values: int = 0,  # ... and these leading lanes of a row are read as values
 ) -> jnp.ndarray:
     """`decode_attention_windowed` over a paged pool: paged partials for
     rows [0, block_start), dense merge of the (tiny) local window + current
-    token."""
+    token. Where the latent kernel summed the value lanes alone the merge
+    runs on those lanes, and so does the result: [B, H, <value lanes>]."""
     n = k_local.shape[1]
     # a window of several heads a row (`ArchConfig.cache_pack`), a head a row
     k_local, v_local = (a.reshape(*a.shape[:2], *new.shape[1:])
@@ -912,14 +918,17 @@ def decode_attention_windowed_paged(
         q, k_pool, v_pool, table, positions - step,
         softcap=softcap, window=window, sliding=sliding, q_pos=positions,
         impl=impl, mesh=mesh, kv_scale=kv_scale, sink=sink, swin=swin,
-        latent=latent,
+        latent=latent, values=values,
     )
     # f32 concat: the block-local window may live in the cache's storage
     # dtype (fp8 KV) while the current token is model-dtype.
     ek = jnp.concatenate([k_local.astype(jnp.float32),
                           k_new[:, None].astype(jnp.float32)], axis=1)
-    ev = jnp.concatenate([v_local.astype(jnp.float32),
-                          v_new[:, None].astype(jnp.float32)], axis=1)
+    if latent:  # the rows are key AND value: the window's value lanes too
+        ev = ek[..., :acc.shape[-1]]
+    else:
+        ev = jnp.concatenate([v_local.astype(jnp.float32),
+                              v_new[:, None].astype(jnp.float32)], axis=1)
     mask = jnp.concatenate([jnp.arange(n) < step, jnp.ones((1,), bool)], axis=0)
     if window and sliding is not None:
         dist = jnp.concatenate([step - jnp.arange(n), jnp.zeros((1,), jnp.int32)])

@@ -36,7 +36,17 @@ and the kernel body is told that the value pool IS the key pool (`shared`):
 one copy and one semaphore a page, the score dot and `p @ kbuf` on the one
 tile, and a last visit's dots over its live pages alone. A visit is bounded
 in bytes as well as in rows (`_visit_pages`), so six 128-row pages of 640
-bfloat16 lanes are one.
+bfloat16 lanes are one. Two things more are the latent walk's own (ISSUE
+50). The caller states how many leading lanes of a row are VALUES (MLA's
+kv_lora_rank, 512 of the 640: the rest is the rope part and the pad, which
+only the score reads), and `p @ kbuf` runs over those lanes alone, in whole
+lane tiles (`value_lanes`), into an accumulator and an output that wide. And
+the visits of ALL the slots are ONE STREAM in the grid's order: the ring
+holds the stream's next `ring` visits whatever slots they belong to
+(`top_up` in the kernel body), so the wire does not wait for a slot's last
+dots, its write-back and the next program's prologue, which is what a walk
+of one or two visits a slot otherwise spends a fifth of its time on (the
+dots themselves hide under the copies: PERF.md §6 PR 50).
 
 Shapes (matching the XLA reference):
 - q rows     [B, K, QR, Dk] f32, 1/sqrt(D) pre-applied; QR = G query rows
@@ -200,6 +210,7 @@ def _ragged_paged_kernel(
     flat: bool = False,
     pages: int = 1,
     shared: bool = False,
+    values: int = 0,
 ):
     """Kernel body. Scalar-prefetch layout depends on the table layout:
 
@@ -251,7 +262,15 @@ def _ragged_paged_kernel(
     the key pool. No v_hbm and no vbuf are passed: a page is ONE copy on
     one semaphore (sem [ring, pages]) into kbuf, which `p @ V` reads too.
     A last visit's dots run over its live pages alone there (`visit_flat`),
-    so no unfetched part is read and none is zeroed.
+    so no unfetched part is read and none is zeroed. `values` (> 0: fewer
+    than the row has) are the leading lanes of a row that are read as VALUES:
+    `p @ V` runs over `kbuf[..., :values]` and acc is [R, values]; the lanes
+    past them (MLA's rope part and the pad) only ever entered the score. And
+    the slots' visits are one STREAM: in place of the warm-up, the prefetch
+    and the handoff of a slot's first visit, every visit of every slot
+    starts `ring - 1` visits ahead of the one being scored, across slots
+    (the scratch is [4]: visits started, visits scored, and the slot and
+    visit to start next).
 
     sink/swin (windowed+sink decode, docs/LONG_CONTEXT.md): a row is
     attended iff `gpos < sink` or `q_pos - gpos < swin`. The page walk then
@@ -382,7 +401,29 @@ def _ragged_paged_kernel(
     # order and the scratch outlives them. Visit j of this slot then lives in
     # buffer (first + j) mod ring. Not under swin: where that walk starts
     # depends on the next slot's own query positions.
-    if handoff:
+    # A latent walk goes further (`shared`; ISSUE 50): the visits of ALL the
+    # slots are one stream, in the grid's order, and the ring holds its next
+    # `ring` visits whatever slots they belong to (`top_up`). The scratch is
+    # [issued, consumed, slot, visit]: how many visits of the stream were
+    # started and how many the slots before this one scored, and the first
+    # visit not started yet. Visit j of this slot is number consumed + j of
+    # the stream and lives in that buffer mod ring.
+    stream = shared and handoff
+    if stream:
+        @pl.when(b == 0)
+        def _first_slot():
+            for i in range(4):
+                handed_ref[i] = 0
+
+        @pl.when(handed_ref[2] < b)  # nothing left to start in the slots behind
+        def _catch_up():
+            handed_ref[2] = b
+            handed_ref[3] = 0
+
+        base = handed_ref[1]
+        handed_ref[1] = base + n_iter
+        first = base % ring
+    elif handoff:
         handed = (b > 0) & (handed_ref[0] == 1)
         first = jnp.where(handed, handed_ref[1], 0)
         handed_ref[0] = 0
@@ -393,7 +434,33 @@ def _ragged_paged_kernel(
     m_s[...] = jnp.full_like(m_s, NEG_INF)
     l_s[...] = jnp.zeros_like(l_s)
 
-    for ahead in range(ring - 1):  # the first visits ride before any is scored
+    def top_up(g):
+        """Start the stream's visits up to number g + ring - 1, the ring's
+        whole depth ahead of visit g: this slot's later visits, then the
+        next live slots' first ones, as far as that reaches."""
+        def more(c):
+            issued, slot, _ = c
+            return (issued < g + ring) & (slot < pl.num_programs(0))
+
+        def one(c):
+            issued, slot, visit = c
+            live = live_pages(limits_ref[slot])
+            due = visit * pages < live  # or this slot has no visit left
+
+            @pl.when(due)
+            def _start():
+                each_copy(start, slot, visit, live=live, buf=issued % ring)
+
+            return (issued + due.astype(jnp.int32),
+                    jnp.where(due, slot, slot + 1),
+                    jnp.where(due, visit + 1, 0))
+
+        issued, slot, visit = jax.lax.while_loop(
+            more, one, (handed_ref[0], handed_ref[2], handed_ref[3]))
+        handed_ref[0], handed_ref[2], handed_ref[3] = issued, slot, visit
+
+    # the first visits ride before any is scored (a stream's at its top-ups)
+    for ahead in range(ring - 1) if not stream else ():
         mine_to_start = ahead < n_iter
         if handoff and ahead == 0:
             mine_to_start = mine_to_start & ~handed
@@ -477,21 +544,28 @@ def _ragged_paged_kernel(
                     jnp.zeros((part_rows, vbuf.shape[2]), vbuf.dtype))
         score(slot, first_row)
 
+    def tile(buf, slot, cols=None, lanes=0):
+        """Columns `cols` (a `pl.ds`; None: all) of a visit's buffer as the
+        MXU takes them, the leading `lanes` of each row if any are named (a
+        bfloat16 page goes as it is, the cast is none; fp8 -> bfloat16 is
+        exact)."""
+        if lanes:
+            at = (slot, slice(None) if cols is None else cols, pl.ds(0, lanes))
+        else:
+            at = slot if cols is None else (slot, cols)
+        return buf[at].astype(jnp.bfloat16)
+
     def score(slot, first_row, live=pages):
         """One visit's dots and its step of the online softmax, over the
         first `live` pages of the visit's buffer."""
         if live == pages:
-            colrow, own = colrow_ref[...], mine
-            tile = lambda buf: buf[slot]
+            cols, colrow, own = None, colrow_ref[...], mine
         else:
-            cols = live * part_rows
-            colrow, own = colrow_ref[:, pl.ds(0, cols)], mine[:, :cols]
-            tile = lambda buf: buf[slot, pl.ds(0, cols)]
+            cols = pl.ds(0, live * part_rows)
+            colrow, own = colrow_ref[:, cols], mine[:, :live * part_rows]
         ok = own & masked(first_row + colrow)  # [R, live·C]
-        # (a bfloat16 page goes as it is, the cast is none; fp8 -> bfloat16
-        # is exact)
         s = jax.lax.dot_general(
-            qb, tile(kbuf).astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+            qb, tile(kbuf, slot, cols), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [R, live·C]: every head's rows against every (n, h) row
         if softcap:
@@ -503,17 +577,21 @@ def _ragged_paged_kernel(
         p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
         l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-            p.astype(jnp.bfloat16), tile(vbuf).astype(jnp.bfloat16),
+            p.astype(jnp.bfloat16), tile(vbuf, slot, cols, values),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
         )  # p is zero off its own head: the long sum is the head's own
         m_s[...] = m_new
 
     def body(j, carry):
-        @pl.when(j + ring - 1 < n_iter)
-        def _prefetch():  # later visits ride the wire while this one computes
-            each_copy(start, b, j + ring - 1, np_live)
+        # later visits ride the wire while this one computes
+        if stream:
+            top_up(base + j)
+        else:
+            @pl.when(j + ring - 1 < n_iter)
+            def _prefetch():
+                each_copy(start, b, j + ring - 1, np_live)
 
-        if handoff:
+        if handoff and not stream:
             nxt = jnp.minimum(b + 1, pl.num_programs(0) - 1)
 
             @pl.when((j == n_iter - 1) & (b + 1 < pl.num_programs(0))
@@ -536,14 +614,28 @@ def _ragged_paged_kernel(
     l_ref[0] = jnp.broadcast_to(l_s[...], l_ref.shape[1:])
 
 
-def latent_paged_attention(q, pool, table, limits, interpret: bool = False):
+def value_lanes(values: int, width: int) -> int:
+    """The leading lanes of a `width`-lane latent row that the value dot
+    runs over when the caller reads `values` of them: whole 128-lane tiles
+    (a lane slice of a VMEM tile starts and ends on one), the whole row
+    where the round-up reaches it or no width is stated (0)."""
+    return min(width, -(-values // STAT_LANES) * STAT_LANES) if values else width
+
+
+def latent_paged_attention(q, pool, table, limits, interpret: bool = False,
+                           values: int = 0):
     """Decode partials over a LATENT pool (MLA's absorbed form: one row a
     token, key and value at once), for the caller that says its pool is one
     (`paged_decode_partials(latent=True)`): the as-stored walk of
     `_paged_partials_rows` over the one pool, every query head a row of the
     one pseudo-head. q [B, H, D] at the pool's row width; pool a
-    [P, page, 1, D] pool or its StackedLayer; a flat table.
-    Returns (acc [B, 1, H, D], m [B, 1, H, 1], l [B, 1, H, 1]) f32."""
+    [P, page, 1, D] pool or its StackedLayer; a flat table. `values`: the
+    leading lanes of a row the caller reads as values (MLA's kv_lora_rank;
+    0: all of them). The scores are over the whole row either way; the sum
+    of values is over Dv = `value_lanes(values, D)` lanes, and acc is that
+    wide: lane i < Dv of it is what the whole row's walk gives there, the
+    lanes past Dv do not exist.
+    Returns (acc [B, 1, H, Dv], m [B, 1, H, 1], l [B, 1, H, 1]) f32."""
     from localai_tpu.ops import ptable as _pt
 
     B, H, D = q.shape
@@ -555,7 +647,7 @@ def latent_paged_attention(q, pool, table, limits, interpret: bool = False):
     qr = (q.astype(jnp.float32) * (1.0 / D**0.5)).reshape(B, 1, H, D)
     return _paged_partials_rows(
         qr, jnp.broadcast_to(limits[:, None], (B, H)), pool, pool, table,
-        limits, 0.0, 0, None, interpret, latent=True)
+        limits, 0.0, 0, None, interpret, latent=True, values=values)
 
 
 def _paged_partials_rows(
@@ -574,17 +666,23 @@ def _paged_partials_rows(
     swin: int = 0,
     ring: int | None = None,  # visit buffers in the DMA ring (tests); None: `_ring_depth`
     latent: bool = False,  # v_pool IS k_pool, [.., page, 1, D]: MLA's latent rows
+    values: int = 0,  # ... of which the caller reads these leading lanes as values
 ):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     from localai_tpu.ops import ptable as _pt
-    from localai_tpu.ops.stacked import note_arith, note_visit, stacks_of
+    from localai_tpu.ops.stacked import (
+        note_arith, note_value_lanes, note_visit, stacks_of)
 
     B, K, QR, Dk = qr.shape
     k_pool, v_pool, layer = stacks_of(k_pool, v_pool, "layer_kv_pool")
     L, P, page = k_pool.shape[:3]
     Dv = v_pool.shape[4]
+    if latent:  # the value dot over the lanes that are values (`value_lanes`)
+        Dv = value_lanes(values, Dv)
+        note_value_lanes(Dv < Dk)
+    narrow = Dv if latent and Dv < Dk else 0  # 0: `p @ V` over V's whole rows
     # A latent page [page, 1, D] is the [C, D] matrix of the as-stored form
     # already (K = 1, every column the one head's), whatever `_flat_rows`
     # says of a pool WITH a head axis; a float32 one is rounded to bfloat16
@@ -614,7 +712,7 @@ def _paged_partials_rows(
         _ragged_paged_kernel, page=page, num_kv=K,
         softcap=float(softcap), window=int(window),
         sink=int(sink), swin=int(swin), l1_span=l1_span, ring=ring, flat=flat,
-        pages=pages, shared=latent,
+        pages=pages, shared=latent, values=narrow,
     )
     pools = (k_pool,) if latent else (k_pool, v_pool)
     qpos_rows = qpos_rows.astype(jnp.int32)
@@ -671,7 +769,8 @@ def _paged_partials_rows(
                 pltpu.VMEM((*lead, 1), jnp.float32),
                 pltpu.VMEM((*lead, 1), jnp.float32),
                 pltpu.SemaphoreType.DMA((ring, len(pools) * pages)),
-                *([] if swin else [pltpu.SMEM((2,), jnp.int32)]),  # handoff
+                # the handoff's state, a latent walk's stream's
+                *([] if swin else [pltpu.SMEM((4 if latent else 2,), jnp.int32)]),
             ],
         ),
         out_shape=[
@@ -711,18 +810,21 @@ def paged_decode_partials(
     sink: int = 0,  # windowed+sink decode (docs/LONG_CONTEXT.md)
     swin: int = 0,
     latent: bool = False,  # the caller's pool is MLA's latent one
+    values: int = 0,  # ... whose rows' leading lanes these are read as values
 ):
     """Drop-in for attention._paged_cache_partials: returns
     (acc [B, K, G, Dv], m [B, K, G, 1], l [B, K, G, 1]) f32, scale applied.
     `latent`: the one pool is key and value (`latent_paged_attention`, which
-    has none of the masks: asking for one with it is refused)."""
+    has none of the masks: asking for one with it is refused; with `values`
+    its acc holds the value lanes alone)."""
     B, H, D = q.shape
     if latent:
         if kv_scale is not None or softcap or swin or (
                 window and sliding is not None):
             raise ValueError("the latent paged kernel has no dequant scale, "
                              "softcap or window")
-        return latent_paged_attention(q, k_pool, table, limits, interpret)
+        return latent_paged_attention(q, k_pool, table, limits, interpret,
+                                      values)
     Kp, Dp = k_pool.shape[-2:]  # as stored: `pack` heads a row
     pack = Dp // D
     K = Kp * pack

@@ -126,6 +126,10 @@ _KEYS = {
     # (`note_visit`)
     "paged_attention_visit": ("paged_attention_multipage",
                               "paged_attention_onepage"),
+    # nor this: the lanes a latent paged-attention call's value dot runs
+    # over (`note_value_lanes`)
+    "paged_attention_values": ("paged_attention_value_lanes",
+                               "paged_attention_value_row"),
     # nor this: how a decode block's window reached one page pool
     # (`note_pool_write`)
     "pool_write": ("pool_write_inplace", "pool_write_scatter"),
@@ -147,7 +151,9 @@ class SiteCounts:
     arithmetic of each Pallas paged-attention call, `paged_attention_native`
     / `paged_attention_f32` (`note_arith`) and what a visit of its page walk
     holds, `paged_attention_multipage` / `paged_attention_onepage`
-    (`note_visit`), and the weight block of each Pallas dequant-matmul call,
+    (`note_visit`), and, a latent call alone, the lanes of its value dot,
+    `paged_attention_value_lanes` / `paged_attention_value_row`
+    (`note_value_lanes`), and the weight block of each Pallas dequant-matmul call,
     `wholerow` / `narrowed` (`note_blocks`); and how a decode block's window
     reached each page pool, `pool_write_inplace` / `pool_write_scatter`
     (`note_pool_write`); and the form each SSD layer's decode update took,
@@ -230,6 +236,17 @@ def note_visit(multipage: bool) -> None:
     (`paged_attention_onepage`: 8 KV heads and more, the per-head form, the
     cold-middle walk). The XLA walk counts under neither."""
     note_site(multipage, kernel="paged_attention_visit")
+
+
+def note_value_lanes(lanes: bool) -> None:
+    """Count one LATENT Pallas paged-attention call of the program being
+    traced (ops/paged_flash `latent_paged_attention`) by the lanes its value
+    dot `p @ V` runs over: `lanes`, the leading lanes the caller reads as
+    values (MLA's kv_lora_rank in whole lane tiles, 512 of a 640-lane row;
+    key `paged_attention_value_lanes`), or the whole row
+    (`paged_attention_value_row`: no width stated, or one whose round-up to
+    lane tiles is the row). A GQA call counts under neither."""
+    note_site(lanes, kernel="paged_attention_values")
 
 
 def note_pool_write(inplace: bool) -> None:

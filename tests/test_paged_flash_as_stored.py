@@ -277,3 +277,42 @@ def test_the_latent_walk_counts_what_it_does():
         assert tally["paged_attention_multipage"] == calls
         assert tally["paged_attention_onepage"] == 0
         assert tally["paged_attention_stacked"] == calls
+        # the value dot of each: kv_lora_rank 32 under a 64-wide row rounds
+        # up to the row (ISSUE 50), so no tiny preset's kernel is narrower
+        assert tally["paged_attention_value_row"] == calls
+        assert tally["paged_attention_value_lanes"] == 0
+
+
+@pytest.mark.parametrize("values,key", [
+    (512, "paged_attention_value_lanes"), (600, "paged_attention_value_row"),
+    (0, "paged_attention_value_row")])
+def test_a_latent_call_counts_its_value_dots_lanes(values, key):
+    """A latent call at the cells' row (640 lanes) counts under `value_lanes`
+    where the stated width leaves whole lane tiles unread (kv_lora_rank 512)
+    and under `value_row` where it does not or none is stated; a GQA call
+    and the XLA walk under neither (ISSUE 50)."""
+    from localai_tpu.ops.attention import paged_partials
+    from localai_tpu.ops.stacked import SiteCounts
+
+    pool = jnp.zeros((8, 128, 1, 640), jnp.bfloat16)
+    table, limits = _table(2, 3, 8, seed=17), jnp.array([5, 300], jnp.int32)
+    q = jnp.zeros((2, 20, 640), jnp.bfloat16)
+    other = ({"paged_attention_value_lanes", "paged_attention_value_row"}
+             - {key}).pop()
+    for impl, n in (("pallas", 1), ("xla", 0)):
+        sites = SiteCounts()
+        with sites.tracing("decode_block"):
+            acc, _, _ = jax.eval_shape(lambda q: paged_partials(
+                q, pool, pool, table, limits, impl=impl, latent=True,
+                values=values), q)
+        tally = sites.by_program["decode_block"]
+        assert (tally[key], tally[other]) == (n, 0)
+        assert acc.shape[-1] == (512 if n and values == 512 else 640)
+    k4, v4 = _pool(jax.random.key(66), 8, PAGE, 2, 32, jnp.bfloat16)
+    sites = SiteCounts()
+    with sites.tracing("decode_block"):
+        jax.eval_shape(lambda q: paged_partials(
+            q, k4, v4, table, limits, impl="pallas"), jnp.zeros((2, 4, 32)))
+    tally = sites.by_program["decode_block"]
+    assert tally["paged_attention_native"] == 1
+    assert (tally[key], tally[other]) == (0, 0)

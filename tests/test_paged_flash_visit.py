@@ -5,6 +5,8 @@ hold anything. The kernel in interpret mode against the float64 walk that
 takes the same visits (tests/paged_cases.py), and the rule as a table.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,11 +14,15 @@ import pytest
 
 from localai_tpu.ops.attention import _paged_cache_partials
 from localai_tpu.ops.paged_flash import (
+    latent_paged_attention,
     paged_decode_partials,
     paged_decode_partials_mq,
+    value_lanes,
 )
 from paged_cases import (
+    _assert_float32_grade,
     _check_against_float64_walk,
+    _f64_walk,
     _hier_of,
     _one_compile,
     _pool,
@@ -127,6 +133,90 @@ def test_multipage_visit_matches_xla_walk(n):
                   want[0] / jnp.maximum(want[2], 1e-30)), (got[1], want[1])):
         np.testing.assert_allclose(np.asarray(g)[live], np.asarray(w)[live],
                                    atol=1e-2, rtol=1e-2)
+
+
+# The latent visit at the two latent cells' page and row (128 rows of 640
+# bfloat16 lanes, six pages a visit by the byte bound; GLM-4.7-Flash's 20
+# query rows a slot and Kimi-Linear's 32) over a table of thirteen columns.
+_LATENT = dict(page=128, W=640, MP=13, visit=6)
+
+
+def _latent_case(heads, last):
+    """(q, pool, table, limits): slots whose LAST visit holds `last` live
+    pages, as a slot's only visit and behind one and two full ones, ending
+    inside a page, on a page's last row and on the next page's first; an
+    idle slot and a one-token slot between them (the handoff skips the one
+    and crosses the other)."""
+    page, W, MP, visit = (_LATENT[k] for k in ("page", "W", "MP", "visit"))
+    full = visit * page
+    limits = [(last - 1) * page + 5, 0, full + last * page, 1,
+              full + (last - 1) * page + 1,
+              min(2 * full + (last - 1) * page + 77, MP * page)]
+    B = len(limits)
+    pool = jax.random.normal(jax.random.key(150), (B * MP + 1, page, 1, W),
+                             jnp.bfloat16)
+    q = jax.random.normal(jax.random.key(151 + heads), (B, heads, W),
+                          jnp.bfloat16)
+    return q, pool, _table(B, MP, B * MP + 1, seed=30), jnp.array(
+        limits, jnp.int32)
+
+
+def _latent_call(key, values):
+    """One compile a (heads, values): the cases differ in their limits."""
+    return _one_compile(("latent", key, values), lambda q, k, v, t, l, **kw: (
+        latent_paged_attention(q, k, t, l, values=values, **kw)), {})
+
+
+@pytest.mark.parametrize("last", [1, 2, 3, 4, 5, 6])
+@pytest.mark.parametrize("heads", [20, 32])
+def test_latent_visit_matches_float64_walk(heads, last):
+    """The latent walk with the value dot on the value lanes (512 of 640,
+    ISSUE 50) against the float64 walk that takes the same visits: every
+    live size of a last visit, alone in its slot and behind one and two full
+    visits, every visit started by whichever slot is being scored when it
+    comes `ring - 1` ahead in the one stream of the slots' visits. acc holds
+    the value lanes alone."""
+    page, W, MP, visit = (_LATENT[k] for k in ("page", "W", "MP", "visit"))
+    q, pool, table, limits = _latent_case(heads, last)
+    B = q.shape[0]
+    got = _latent_call(heads, 512)(q, pool, pool, table, limits)
+    assert got[0].shape == (B, 1, heads, 512)
+    qr = (np.asarray(q, np.float32) * np.float32(1.0 / W**0.5))[:, None]
+    acc, m, l = _f64_walk(
+        qr, np.broadcast_to(np.asarray(limits)[:, None], (B, heads)), pool,
+        pool, table, limits, pages=visit)
+    _assert_float32_grade(got, (acc[..., :512], m, l), flips=0.05)
+    idle = np.asarray(limits) == 0
+    assert (np.asarray(got[0])[idle] == 0).all()
+    assert (np.asarray(got[2])[idle] == 0).all()
+
+
+@pytest.mark.parametrize("values,lanes", [(512, 512), (500, 512), (128, 128),
+                                          (513, 640), (600, 640), (0, 640)])
+def test_latent_value_lanes_are_the_whole_rows_lanes(values, lanes):
+    """(a) of ISSUE 50 moves no lane anyone reads: the value dot on
+    `value_lanes(values, W)` leading lanes gives, bit for bit, those lanes
+    of the walk whose value dot runs over the whole row, with the same
+    (m, l); a width whose round-up to lane tiles reaches the row is the
+    whole row's kernel, the parent's."""
+    W = _LATENT["W"]
+    assert value_lanes(values, W) == lanes
+    q, pool, table, limits = _latent_case(20, 3)
+    whole = _latent_call(20, 0)(q, pool, pool, table, limits)
+    got = _latent_call(20, values if lanes < W else 0)(
+        q, pool, pool, table, limits)
+    assert got[0].shape[-1] == lanes and whole[0].shape[-1] == W
+    # (the CPU's matmul sums a 128-wide output's products in another order
+    # than a 640-wide one's: float32's last bits there, nothing on the MXU)
+    same = (np.testing.assert_array_equal if lanes >= 512 else
+            functools.partial(np.testing.assert_allclose, rtol=0, atol=4e-6))
+    same(np.asarray(got[0]), np.asarray(whole[0])[..., :lanes])
+    for g, w in zip(got[1:], whole[1:]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    if lanes == W:  # no narrower kernel is traced: the same program text
+        text = lambda v: str(jax.make_jaxpr(lambda q: latent_paged_attention(
+            q, pool, table, limits, interpret=True, values=v))(q))
+        assert text(values) == text(0)
 
 
 @pytest.mark.parametrize("page,K,width,flat,swin,want", [

@@ -467,6 +467,8 @@ _NO_PAGED_SITES = {"paged_attention_stacked": 0, "paged_attention_sliced": 0,
                    "paged_attention_native": 0, "paged_attention_f32": 0,
                    "paged_attention_multipage": 0,
                    "paged_attention_onepage": 0,
+                   "paged_attention_value_lanes": 0,
+                   "paged_attention_value_row": 0,
                    "pool_write_inplace": 0, "pool_write_scatter": 0,
                    "ssd_decode_pallas": 0, "ssd_decode_xla": 0}
 # the seven Pallas dequant-matmul calls of a decode step, by the rule's block
@@ -590,6 +592,9 @@ def test_paged_decode_step_hands_the_kernel_the_pool_and_counts_it():
                      # 8-row pages: a visit is the table's three columns
                      "paged_attention_multipage": 1,
                      "paged_attention_onepage": 0,
+                     # a GQA pool: no latent call to state a value width
+                     "paged_attention_value_lanes": 0,
+                     "paged_attention_value_row": 0,
                      # a decode STEP: the block's pool write is not in it
                      "pool_write_inplace": 0, "pool_write_scatter": 0,
                    "ssd_decode_pallas": 0, "ssd_decode_xla": 0}
@@ -600,6 +605,8 @@ def test_paged_decode_step_hands_the_kernel_the_pool_and_counts_it():
                      "paged_attention_native": 0, "paged_attention_f32": 0,
                      "paged_attention_multipage": 0,
                      "paged_attention_onepage": 0,
+                     "paged_attention_value_lanes": 0,
+                     "paged_attention_value_row": 0,
                      "pool_write_inplace": 0, "pool_write_scatter": 0,
                    "ssd_decode_pallas": 0, "ssd_decode_xla": 0}
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
