@@ -61,7 +61,6 @@ from typing import Any, Iterator, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.profiler import TraceAnnotation
 import numpy as np
 
 from localai_tpu.models import llama
@@ -74,6 +73,7 @@ from localai_tpu.engine.runtime import (
     LoopPhases,
 )
 from localai_tpu.models.config import ArchConfig
+from localai_tpu.observe import gcwatch
 from localai_tpu.observe import postmortem as opostmortem
 from localai_tpu.observe import trace as otrace
 from localai_tpu.observe.journal import EventJournal
@@ -739,12 +739,6 @@ def _gather_pages(k, v, pages):
         return jnp.moveaxis(got, 0, 1)
 
     return per_page(k), per_page(v)
-
-
-def _host_copy_async(arr: Any) -> None:
-    """Start a device→host copy without blocking; np.asarray later is then a
-    cheap wait instead of a full round trip."""
-    arr.copy_to_host_async()
 
 
 @dataclasses.dataclass
@@ -1590,13 +1584,19 @@ class Engine:
             self._jstage("prefix_reuse_off",
                          a=float(self.ecfg.prefix_cache_entries))
         # Pipelined loop runtime (ISSUE 17, docs/ENGINE_RUNTIME.md).
-        # thread: single-writer engine-loop — the control stager's cache
-        # and counters are loop-thread state; bench/tests read the
-        # counters best-effort after generation settles.
-        self._ctrl = ControlStager()
         # thread: single-writer engine-loop — per-iteration host-phase
         # accumulator feeding the coalesced loop_iter journal emission.
         self._phases = LoopPhases()
+        # thread: single-writer engine-loop — the control stager's cache
+        # and counters are loop-thread state; bench/tests read the
+        # counters best-effort after generation settles.
+        self._ctrl = ControlStager(call=self._phases.call)
+        # thread: single-writer engine-loop — how far this loop has read
+        # gcwatch's ring of collector pauses (journalled as gc_pause).
+        self._gc_seen = 0
+        self.m_loop_call_ms = 0.0
+        self.m_loop_gc_ms = 0.0
+        self.m_loop_off_ms = 0.0
         # Deadline min-heap: submit-side threads push (internally locked),
         # the loop's housekeeping gate peeks — O(1) "anything due?" instead
         # of scanning every pending request every iteration.
@@ -1662,12 +1662,25 @@ class Engine:
         return self._postmortem_path
 
     def _jnote(self, event: str, rid: str = "", slot: int = -1,
-               a: float = 0.0, b: float = 0.0, phases=None) -> None:
-        """Loop-thread journal append (lock-free; no-op when disabled).
-        `phases` (loop_iter only) is the LOOP_PHASES-ordered ms vector."""
+               a: float = 0.0, b: float = 0.0) -> None:
+        """Loop-thread journal append (lock-free; no-op when disabled)."""
         j = self._journal
         if j is not None:
-            j.append(event, rid=rid, slot=slot, a=a, b=b, phases=phases)
+            j.append(event, rid=rid, slot=slot, a=a, b=b)
+
+    def _upload(self, host, dtype=None):
+        """`jnp.asarray(host)` through the loop's door (LoopPhases.call):
+        the time the loop thread sits in a transfer is the call's, not
+        Python's own. From another thread it is the span alone."""
+        with self._phases.call("call/upload",
+                               bytes=getattr(host, "nbytes", 0)):
+            return jnp.asarray(host, dtype)
+
+    def _host_copy_async(self, arr: Any) -> None:
+        """Start a device→host copy without blocking; np.asarray later is
+        then a cheap wait instead of a full round trip."""
+        with self._phases.call("call/host_copy"):
+            arr.copy_to_host_async()
 
     def _count_admit(self, rows: int, tokens: int) -> None:
         """One admission program went out: `rows` it was compiled for (group
@@ -1676,6 +1689,7 @@ class Engine:
         self.m_admit_programs += 1
         self.m_admit_rows_dispatched += rows
         self.m_admit_rows_prompt += tokens
+        self._phases.note(1, rows)  # what this stretch did (a loop_stall's)
         self._jnote("admit_rows", a=float(rows), b=float(tokens))
 
     def _jstage(self, event: str, rid: str = "", slot: int = -1,
@@ -1891,8 +1905,8 @@ class Engine:
         # Copies, as in _ptable_device: `row` may be a view of the host
         # table, which is rewritten while this dispatch is in flight.
         if self._hier:
-            return (jnp.asarray(row.copy()), jnp.asarray(self.h_l0.copy()))
-        return jnp.asarray(row.copy())
+            return (self._upload(row.copy()), self._upload(self.h_l0.copy()))
+        return self._upload(row.copy())
 
     def _pages_worst(self, request: GenRequest) -> int:
         """Worst-case pages for a request: the prefill writes a full bucket
@@ -2371,13 +2385,14 @@ class Engine:
         npgb = self._pow2_pages(npg)
         idx = np.full((npgb,), self._scratch_page, np.int32)
         idx[:npg] = pages
-        gk, gv = self._get_pages_gather(npgb)(
-            self.cache.k, self.cache.v, jnp.asarray(idx)
-        )
-        _host_copy_async(gk)
-        _host_copy_async(gv)
-        hk = np.ascontiguousarray(np.asarray(gk)[:, :npg])
-        hv = np.ascontiguousarray(np.asarray(gv)[:, :npg])
+        with self._phases.call("call/swap_out", pages=npg):
+            gk, gv = self._get_pages_gather(npgb)(
+                self.cache.k, self.cache.v, self._upload(idx)
+            )
+            self._host_copy_async(gk)
+            self._host_copy_async(gv)
+            hk = np.ascontiguousarray(np.asarray(gk)[:, :npg])
+            hv = np.ascontiguousarray(np.asarray(gv)[:, :npg])
         return hk, hv
 
     def _swap_in_pages(self, pages: list[int], hk: np.ndarray,
@@ -2392,9 +2407,11 @@ class Engine:
             pad = ((0, 0), (0, npgb - npg), (0, 0), (0, 0), (0, 0))
             hk = np.pad(hk, pad)
             hv = np.pad(hv, pad)
-        self.cache = self._get_swap_in(npgb)(
-            self.cache, jnp.asarray(idx), jnp.asarray(hk), jnp.asarray(hv)
-        )
+        with self._phases.call("call/swap_in", pages=npg):
+            self.cache = self._get_swap_in(npgb)(
+                self.cache, self._upload(idx), self._upload(hk),
+                self._upload(hv)
+            )
 
     def _host_make_room(self, need: int) -> bool:
         """Fit `need` bytes into the host tier by evicting LRU spilled
@@ -2616,15 +2633,16 @@ class Engine:
             np.asarray(request.prompt_ids, np.int64) % V, minlength=V
         )[:V].astype(np.int32)
         brow = self._host_bias_row(request)
-        (
-            self.counts, self.rngs, self.bias, self.d_tokens,
-            self.d_positions,
-        ) = self._get_resume_restore()(
-            self.counts, self.rngs, self.bias, self.d_tokens,
-            self.d_positions, jnp.int32(slot_idx), jnp.asarray(crow),
-            jnp.asarray(brow), jnp.asarray(rec["rng"]),
-            jnp.int32(rec["d_tok"]), jnp.int32(rec["d_pos"]),
-        )
+        with self._phases.call("call/resume_restore"):
+            (
+                self.counts, self.rngs, self.bias, self.d_tokens,
+                self.d_positions,
+            ) = self._get_resume_restore()(
+                self.counts, self.rngs, self.bias, self.d_tokens,
+                self.d_positions, jnp.int32(slot_idx), self._upload(crow),
+                self._upload(brow), self._upload(rec["rng"]),
+                jnp.int32(rec["d_tok"]), jnp.int32(rec["d_pos"]),
+            )
         for kf in _SAMPLING_FIELDS:
             self.h_sampling[kf][slot_idx] = getattr(request, kf)
         if self._mrope:
@@ -2690,11 +2708,12 @@ class Engine:
             # draw for token g+1, so advance the saved key one split —
             # every draw after the re-admission token then matches the
             # uncontended run (greedy is byte-exact regardless).
-            key = jax.random.wrap_key_data(jnp.asarray(rec["rng"]))
-            nxt = jax.random.key_data(jax.random.split(key, 2)[0])
-            self.rngs = self._get_rng_set()(
-                self.rngs, jnp.int32(slot_idx), nxt
-            )
+            with self._phases.call("call/rng_set"):
+                key = jax.random.wrap_key_data(self._upload(rec["rng"]))
+                nxt = jax.random.key_data(jax.random.split(key, 2)[0])
+                self.rngs = self._get_rng_set()(
+                    self.rngs, jnp.int32(slot_idx), nxt
+                )
         self.m_kv_preempt_recover_ms += (
             (time.monotonic() - rec["t_preempt"]) * 1e3
         )
@@ -2863,8 +2882,8 @@ class Engine:
                     a_np = np.pad(a_np, ((0, 0), (0, 0), (0, rank - r)))
                     b_np = np.pad(b_np, ((0, 0), (0, rank - r), (0, 0)))
             ent = self._lora_tree[key]
-            ent["a"] = ent["a"].at[:, row].set(jnp.asarray(a_np, dt))
-            ent["b"] = ent["b"].at[:, row].set(jnp.asarray(b_np, dt))
+            ent["a"] = ent["a"].at[:, row].set(self._upload(a_np, dt))
+            ent["b"] = ent["b"].at[:, row].set(self._upload(b_np, dt))
 
     def _adapter_acquire(self, name: str) -> int:
         """Pin `name` into a device adapter row and return the row id
@@ -4072,15 +4091,18 @@ class Engine:
             # _get_chunk_pin — blocks dispatched from here on must not stamp
             # stale-position rows into the slot). Paged idle writes resolve
             # through SCRATCH instead, no pin needed.
-            self.d_positions = self._get_chunk_pin()(
-                self.d_positions, jnp.int32(slot_idx)
-            )
+            with self._phases.call("call/chunk_pin"):
+                self.d_positions = self._get_chunk_pin()(
+                    self.d_positions, jnp.int32(slot_idx)
+                )
             if entry is not None:
                 # Seed the slot's rows [0, pb) from the stored span so the
                 # chunk programs read the prefix from the slot itself.
-                self.cache = self._get_span_copy(entry["pb"])(
-                    self.cache, entry["k"], entry["v"], jnp.int32(slot_idx)
-                )
+                with self._phases.call("call/span_copy"):
+                    self.cache = self._get_span_copy(entry["pb"])(
+                        self.cache, entry["k"], entry["v"],
+                        jnp.int32(slot_idx)
+                    )
         if entry is not None:
             for idx, e in enumerate(self._prefix_entries):
                 if e is entry:
@@ -4150,17 +4172,18 @@ class Engine:
         toks = np.zeros((1, n), np.int32)
         toks[0] = st["ids"][offset: offset + n]
         aux = np.asarray([n, slot_idx, offset], np.int32)
-        with TraceAnnotation("dispatch/prefill_chunk", m=1, bucket=n, tokens=n):
+        with self._phases.call("dispatch/prefill_chunk", m=1, bucket=n,
+                               tokens=n):
             if self._paged:
                 fn = self._get_chunk_mid(n, None)
                 out = fn(self.params, self.cache, self.d_positions,
-                         jnp.asarray(toks), jnp.asarray(aux),
+                         self._upload(toks), self._upload(aux),
                          self._ptable_device_row(st["table_row"]))
             else:
                 pwin = self._bucket_for(max(offset, 1))
                 fn = self._get_chunk_mid(n, pwin)
                 out = fn(self.params, self.cache, self.d_positions,
-                         jnp.asarray(toks), jnp.asarray(aux))
+                         self._upload(toks), self._upload(aux))
         self.cache, self.d_positions, marker = out
         self.m_prefill_chunks += 1
         self._count_admit(n, n)
@@ -4221,20 +4244,22 @@ class Engine:
             args = (self._ptable_device_row(st["table_row"]),)
         else:
             pb = self._bucket_for(max(offset, 1))
-            pk, pv = self._get_snapshot(pb)(self.cache, jnp.int32(slot_idx))
+            with self._phases.call("call/snapshot"):
+                pk, pv = self._get_snapshot(pb)(self.cache,
+                                                jnp.int32(slot_idx))
             fn = self._get_admit_cached(pb, tb, fbp, has_bias, with_topk,
                                         with_lp, with_dfa, draft)
             args = (pk, pv)
         args = args + (
-            jnp.asarray(tail_toks), jnp.asarray(full_toks), jnp.asarray(aux),
-            jnp.asarray(samp_pack),
+            self._upload(tail_toks), self._upload(full_toks), self._upload(aux),
+            self._upload(samp_pack),
         )
         if has_bias:
             bias_rows = np.zeros((1, V), np.float32)
             for tid, bval in request.logit_bias.items():
                 if 0 <= int(tid) < V:
                     bias_rows[0, int(tid)] = bval
-            args = args + (jnp.asarray(bias_rows),)
+            args = args + (self._upload(bias_rows),)
         if with_dfa:
             host = dfa_tables["host"]
             row = np.unpackbits(
@@ -4243,8 +4268,8 @@ class Engine:
             gmask0 = np.where(row, 0.0, -1e30).astype(np.float32)[None, :]
             ginit = np.full((1,), host.init_state, np.int32)
             args = args + (
-                jnp.asarray(gmask0), self._dfa_table(dfa_tables, with_dfa),
-                dfa_tables["tok_cls"], jnp.asarray(ginit),
+                self._upload(gmask0), self._dfa_table(dfa_tables, with_dfa),
+                dfa_tables["tok_cls"], self._upload(ginit),
             )
         state = (
             self.params, self.cache, self.counts, self.rngs, self.bias,
@@ -4254,7 +4279,7 @@ class Engine:
             state = state + (self.d_gstate,)
         if draft:
             state = state + (self.draft_params, self.d_cache)
-        with TraceAnnotation(
+        with self._phases.call(
                 "dispatch/prefill_chunk_final" if self._paged
                 else "dispatch/admit_cached", m=1, bucket=tb, tokens=len(tail)):
             out = fn(*state, *args)
@@ -4269,7 +4294,7 @@ class Engine:
             self.d_cache = out[9]
         if with_logits:
             self._fork_logits = out[-1]
-        _host_copy_async(toks)
+        self._host_copy_async(toks)
         for kf in _SAMPLING_FIELDS:
             self.h_sampling[kf][slot_idx] = getattr(request, kf)
         if self._mrope:
@@ -4725,9 +4750,10 @@ class Engine:
                 samp_pack[fi, j] = getattr(r, kf)
         if copies:
             cp = self._get_fork_page_copy()
-            for sp, dp in copies:
-                self.cache = cp(self.cache, jnp.int32(sp), jnp.int32(dp))
-        args = (logits, jnp.asarray(aux), jnp.asarray(samp_pack))
+            with self._phases.call("call/page_copy", pages=len(copies)):
+                for sp, dp in copies:
+                    self.cache = cp(self.cache, jnp.int32(sp), jnp.int32(dp))
+        args = (logits, self._upload(aux), self._upload(samp_pack))
         if with_dfa:
             host = dfa_tables["host"]
             V = self.cfg.vocab_size
@@ -4737,17 +4763,18 @@ class Engine:
             gmask0 = np.where(rowb, 0.0, -1e30).astype(np.float32)[None, :]
             ginit = np.full((1,), host.init_state, np.int32)
             args = args + (
-                jnp.asarray(gmask0), self._dfa_table(dfa_tables, with_dfa),
-                dfa_tables["tok_cls"], jnp.asarray(ginit), self.d_gstate,
+                self._upload(gmask0), self._dfa_table(dfa_tables, with_dfa),
+                dfa_tables["tok_cls"], self._upload(ginit), self.d_gstate,
             )
         fn = self._get_fork_sample(nb, with_topk, with_lp, with_dfa)
-        out = fn(self.counts, self.rngs, self.bias, self.d_tokens,
-                 self.d_positions, *args)
+        with self._phases.call("call/fork"):
+            out = fn(self.counts, self.rngs, self.bias, self.d_tokens,
+                     self.d_positions, *args)
         (self.counts, self.rngs, self.bias, self.d_tokens,
          self.d_positions, toks, tk, lp) = out[:8]
         if with_dfa:
             self.d_gstate = out[8]
-        _host_copy_async(toks)
+        self._host_copy_async(toks)
         t0 = time.monotonic()
         items = []
         for j, (dst, r, h, arow) in enumerate(forked):
@@ -4926,16 +4953,18 @@ class Engine:
             if partial:
                 sp, dp = src_pages[nfull], self._slot_pages[dst][nfull]
                 cp = self._get_fork_page_copy()
-                self.cache = cp(self.cache, jnp.int32(sp), jnp.int32(dp))
+                with self._phases.call("call/page_copy", pages=1):
+                    self.cache = cp(self.cache, jnp.int32(sp), jnp.int32(dp))
             fn = self._get_fork_ctrl_copy(bool(slot.dfa))
             aux = np.asarray([src, dst, salt], np.int32)
             state = (self.counts, self.rngs, self.bias, self.d_tokens,
                      self.d_positions)
-            if slot.dfa:
-                out = fn(*state, jnp.asarray(aux), self.d_gstate)
-                self.d_gstate = out[5]
-            else:
-                out = fn(*state, jnp.asarray(aux))
+            with self._phases.call("call/ctrl_copy"):
+                if slot.dfa:
+                    out = fn(*state, self._upload(aux), self.d_gstate)
+                    self.d_gstate = out[5]
+                else:
+                    out = fn(*state, self._upload(aux))
             (self.counts, self.rngs, self.bias, self.d_tokens,
              self.d_positions) = out[:5]
             r = dataclasses.replace(
@@ -5057,7 +5086,8 @@ class Engine:
         pb = self._bucket_for(rows)
         if self._prefix_span_bytes(pb) > self.ecfg.prefix_cache_bytes:
             return None
-        k, v = self._get_snapshot(pb)(self.cache, jnp.int32(slot_idx))
+        with self._phases.call("call/snapshot"):
+            k, v = self._get_snapshot(pb)(self.cache, jnp.int32(slot_idx))
         return pb, k, v
 
     def _prefix_save(self, slot_idx: int, key_tokens, valid_len: int,
@@ -5574,7 +5604,7 @@ class Engine:
             row = (self.h_l1[slot_idx] if self._hier
                    else self.h_ptable[slot_idx])
             args = (
-                jnp.asarray(pages_arr), self._ptable_device_row(row),
+                self._upload(pages_arr), self._ptable_device_row(row),
             )
         else:
             key = ("cached", entry["pb"], tb, fbp, has_bias, with_topk,
@@ -5582,15 +5612,15 @@ class Engine:
             getter = self._get_admit_cached
             args = (entry["k"], entry["v"])
         args = args + (
-            jnp.asarray(tail_toks), jnp.asarray(full_toks), jnp.asarray(aux),
-            jnp.asarray(samp_pack),
+            self._upload(tail_toks), self._upload(full_toks), self._upload(aux),
+            self._upload(samp_pack),
         )
         if has_bias:
             bias_rows = np.zeros((1, V), np.float32)
             for tid, bval in request.logit_bias.items():
                 if 0 <= int(tid) < V:
                     bias_rows[0, int(tid)] = bval
-            args = args + (jnp.asarray(bias_rows),)
+            args = args + (self._upload(bias_rows),)
         if with_dfa:
             host = dfa_tables["host"]
             row = np.unpackbits(
@@ -5599,8 +5629,8 @@ class Engine:
             gmask0 = np.where(row, 0.0, -1e30).astype(np.float32)[None, :]
             ginit = np.full((1,), host.init_state, np.int32)
             args = args + (
-                jnp.asarray(gmask0), self._dfa_table(dfa_tables, with_dfa),
-                dfa_tables["tok_cls"], jnp.asarray(ginit),
+                self._upload(gmask0), self._dfa_table(dfa_tables, with_dfa),
+                dfa_tables["tok_cls"], self._upload(ginit),
             )
         state = (
             self.params, self.cache, self.counts, self.rngs, self.bias,
@@ -5627,7 +5657,7 @@ class Engine:
         if fn is None:
             fn = getter(*key[1:])
         try:
-            with TraceAnnotation(
+            with self._phases.call(
                     "dispatch/admit_cached_paged" if key[0] == "cached-paged"
                     else "dispatch/admit_cached", m=1, bucket=tb,
                     tokens=len(tail)):
@@ -5660,7 +5690,7 @@ class Engine:
             self.d_cache = out[9]
         if with_logits:
             self._fork_logits = out[-1]
-        _host_copy_async(toks)
+        self._host_copy_async(toks)
         self._count_admit(tb, len(tail))
         # LRU bump + metrics. Identity scan, not `in`: dict == would compare
         # the numpy key arrays elementwise (and raises on length mismatch).
@@ -6381,7 +6411,6 @@ class Engine:
             out["recurrent_state_bytes"] = float(
                 self.ecfg.max_slots * rstate.row_bytes(
                     self.cfg, self.cache.conv.dtype))
-            out["state_snapshots"] = 0.0  # rows are dropped, never copied
             out["state_restores"] = float(self.m_state_restores)
             out["prefix_reuse_off"] = float(
                 self.ecfg.prefix_cache_entries > 0)
@@ -6451,9 +6480,19 @@ class Engine:
             out["loop_blocks"] = float(self.m_loop_blocks)
             out["loop_host_ms_total"] = float(self.m_loop_host_ms)
             out["loop_blocked_ms_total"] = float(self.m_loop_blocked_ms)
-            out["loop_host_overhead_per_block_ms"] = float(
-                self.m_loop_host_ms / self.m_loop_blocks
-            )
+            # Where the working phases' ms went (ISSUE 51; LoopPhases):
+            # inside jax calls, in the collector on the loop's thread, off
+            # the CPU; the rest of loop_host_ms_total less the blocked ms
+            # is Python. Maxima are since start.
+            ph = self._phases
+            out["loop_call_ms_total"] = float(self.m_loop_call_ms)
+            out["loop_gc_ms_total"] = float(self.m_loop_gc_ms)
+            out["loop_off_cpu_ms_total"] = float(max(self.m_loop_off_ms, 0.0))
+            out["loop_late_ms_max"] = float(ph.late_max_ever)
+            out["loop_stretch_ms_max"] = float(ph.stretch_max_ever)
+            out["loop_stalls"] = float(ph.stall_count)
+        # The collector's pauses anywhere in the process (observe/gcwatch.py).
+        out.update(gcwatch.WATCH.counters())
         if self._ctrl.commits:
             out["ctrl_commits"] = float(self._ctrl.commits)
             out["ctrl_transfers"] = float(self._ctrl.transfers())
@@ -6987,10 +7026,18 @@ class Engine:
     def _loop(self) -> None:
         self._charge_last = time.monotonic()
         self._charge_was_active = False
+        # From here on a collection that runs on this thread is booked to
+        # the phase it interrupted (observe/gcwatch.py; the first loop of
+        # the process installs the hook, the last to leave removes it).
+        self._phases.own()
+        gcwatch.WATCH.enter(self._phases.collector)
+        self._gc_seen = gcwatch.WATCH.n
         try:
             self._loop_body()
         finally:
             self._phases.end()  # closes the open loop/<phase> span
+            self._note_stalls()
+            gcwatch.WATCH.leave()
 
     def _loop_body(self) -> None:
         # Every moment of the loop lies in one phase (LoopPhases): a phase
@@ -7004,11 +7051,14 @@ class Engine:
             ph.iters += 1
             did = processed = False
             jr = self._journal
-            if jr is not None and jr.staged():
-                # Move cross-thread events (queued, span export) into the
-                # single-writer ring in order.
+            if jr is not None and (jr.staged()
+                                   or gcwatch.WATCH.n != self._gc_seen):
+                # Move cross-thread events (queued, span export) and the
+                # collector's pauses into the single-writer ring in order.
                 ph.begin("drain")
                 jr.drain_staged()
+                self._gc_seen = gcwatch.WATCH.drain(self._gc_seen,
+                                                    self._jnote_gc)
             # Budgeted sidecar (ISSUE 17): purge/deadline sweeps run on
             # a DUE tick — the deadline heap says something expired, or
             # the forced interval elapsed — instead of scanning every
@@ -7096,7 +7146,12 @@ class Engine:
                 if (front.ready() or nblocks >= depth
                         or not (active or self._parked)):
                     # begins the pull and process phases itself
+                    posted = self.m_generated_tokens
+                    finished = self.m_slots_released
                     self._process_entry(self._inflight.popleft())
+                    # what this stretch of `process` did (a loop_stall's)
+                    ph.note(self.m_generated_tokens - posted,
+                            self.m_slots_released - finished)
                     processed = True
                 else:
                     # The loop would otherwise wait on the in-flight block:
@@ -7121,7 +7176,7 @@ class Engine:
                         # mode waiting on an in-flight admit): don't
                         # busy-spin.
                         ph.begin("wait")
-                        self._wake.wait(timeout=0.001)
+                        ph.wait(self._wake, 0.001)
                         self._wake.clear()
             elif not active and not admitted:
                 now = time.monotonic()
@@ -7129,13 +7184,13 @@ class Engine:
                     ph.begin("housekeeping")
                     self._housekeeping(now)
                 ph.begin("wait")
-                self._wake.wait(timeout=0.05)
+                ph.wait(self._wake, 0.05)
                 self._wake.clear()
             elif hold and not did:
                 # Held dispatch with nothing in flight to process: brief
                 # pause (chunk progress and spill above already ran).
                 ph.begin("wait")
-                time.sleep(0.0005)
+                ph.sleep(0.0005)
             self._flush_loop_iter(did, processed)
 
     # thread: engine-loop-only
@@ -7240,6 +7295,8 @@ class Engine:
         lifecycle events a postmortem needs."""
         ph = self._phases
         ph.sync()
+        if ph.stalls:
+            self._note_stalls()
         host_ms = ph.total()  # excludes the wait phase
         if not (did or processed) and host_ms < 25.0:
             if ph.ms["wait"] >= 1000.0:
@@ -7250,13 +7307,39 @@ class Engine:
             return
         self.m_loop_host_ms += host_ms
         self.m_loop_blocked_ms += ph.ms["pull"]
+        self.m_loop_call_ms += ph.working(ph.call_ms)
+        self.m_loop_gc_ms += ph.working(ph.gc_ms)
+        self.m_loop_off_ms += ph.working(ph.off_ms)
         if did:
             self.m_loop_blocks += 1
-        self._jnote(
-            "loop_iter", slot=-1, a=float(int(self.h_active.sum())),
-            b=host_ms, phases=ph.vector(),
-        )
+        j = self._journal
+        if j is not None:
+            j.append("loop_iter", slot=-1, a=float(int(self.h_active.sum())),
+                     b=host_ms, phases=ph.vector(), causes=ph.causes(),
+                     extra=ph.extras())
         ph.reset()
+
+    # thread: engine-loop-only
+    def _note_stalls(self) -> None:
+        """Journal each stretch of runtime.STALL_MS or more as its own
+        `loop_stall`: it outlives the loop_iter windows around it in the
+        ring and reaches the postmortem."""
+        ph = self._phases
+        stalls, ph.stalls = ph.stalls, []
+        j = self._journal
+        if j is not None:
+            for st in stalls:
+                j.append("loop_stall", a=float(ph.names.index(st[0])),
+                         b=st[1], extra=ph.extras(stall=st))
+
+    # thread: engine-loop-only
+    def _jnote_gc(self, t: float, generation: int, ms: float,
+                  mine: bool) -> None:
+        """One collector pause from gcwatch's ring, as `gc_pause`."""
+        j = self._journal
+        if j is not None:
+            j.append_at(t, "gc_pause", slot=0 if mine else -1,
+                        a=float(generation), b=ms)
 
     # ------------------------------------------------------------------ #
     # Request-lifecycle enforcement (ISSUE 4, docs/ROBUSTNESS.md)
@@ -7736,14 +7819,14 @@ class Engine:
                              with_dfa=with_dfa, with_mrope=with_mrope,
                              with_lora=with_lora, with_logits=with_logits)
         args_in = (
-            jnp.asarray(prompt_toks), jnp.asarray(aux), jnp.asarray(samp_pack),
+            self._upload(prompt_toks), self._upload(aux), self._upload(samp_pack),
             # lint: ignore[trace-safety] admit programs are compiled per (m, bucket) by design and warmed (warmup()); m is the admission group size, already bucketed by the batching loop
-            jnp.asarray(bias_rows) if has_bias else jnp.zeros((m, V), jnp.float32),
+            self._upload(bias_rows) if has_bias else jnp.zeros((m, V), jnp.float32),
         )
         if n_img:
             embeds = np.asarray(chunk[0][0].image_embeds, np.float32)[None]  # [1, N, D]
             offsets = np.asarray([chunk[0][0].image_offset], np.int32)
-            args_in = args_in + (jnp.asarray(embeds), jnp.asarray(offsets))
+            args_in = args_in + (self._upload(embeds), self._upload(offsets))
         if with_mrope:
             # [1, 3, bucket]: the prompt's 3D streams, padding continued
             # sequentially (padded rows are masked out of attention anyway).
@@ -7756,7 +7839,7 @@ class Engine:
                 mrope_full[0, :, L3:] = (
                     last[:, None] + 1 + np.arange(bucket - L3)[None, :]
                 )
-            args_in = args_in + (jnp.asarray(mrope_full),)
+            args_in = args_in + (self._upload(mrope_full),)
         if with_dfa:
             host = dfa_tables["host"]
             row = np.unpackbits(
@@ -7765,8 +7848,8 @@ class Engine:
             gmask0 = np.where(row, 0.0, -1e30).astype(np.float32)[None, :]
             ginit = np.full((m,), host.init_state, np.int32)
             args_in = args_in + (
-                jnp.asarray(gmask0), self._dfa_table(dfa_tables, with_dfa),
-                dfa_tables["tok_cls"], jnp.asarray(ginit),
+                self._upload(gmask0), self._dfa_table(dfa_tables, with_dfa),
+                dfa_tables["tok_cls"], self._upload(ginit),
             )
         allocated_slots: list[int] = []
         if self._paged:
@@ -7793,20 +7876,20 @@ class Engine:
                 rows_tbl[j] = prow
             if self._hier:
                 args_in = args_in + (
-                    (jnp.asarray(rows_tbl), jnp.asarray(self.h_l0)),
+                    (self._upload(rows_tbl), self._upload(self.h_l0)),
                 )
             else:
-                args_in = args_in + (jnp.asarray(rows_tbl),)
+                args_in = args_in + (self._upload(rows_tbl),)
         if with_lora:
             args_in = args_in + (
-                self._lora_tree, jnp.asarray(adapter_rows, dtype=jnp.int32),
+                self._lora_tree, self._upload(adapter_rows, dtype=jnp.int32),
             )
         try:
             # The span a trace matches the admission's device execution to,
             # with the prompt tokens that execution carries.
             tokens = int(aux[0].sum())
-            with TraceAnnotation("dispatch/admit", m=m, bucket=bucket,
-                                 tokens=tokens):
+            with self._phases.call("dispatch/admit", m=m, bucket=bucket,
+                                   tokens=tokens):
                 if self.draft_cfg is None:
                     pre = (self.params, self.cache, self.counts, self.rngs,
                            self.bias, self.d_tokens, self.d_positions)
@@ -7845,7 +7928,7 @@ class Engine:
             self.d_cache = rest[0]
         if with_logits:
             self._fork_logits = out[-1]
-        _host_copy_async(toks)
+        self._host_copy_async(toks)
         self._count_admit(m * bucket, tokens)
         # Claim slots only after a successful dispatch so a failed admission
         # (e.g. compile error) never leaks slot state.
@@ -8172,8 +8255,8 @@ class Engine:
                            d["tok_cls"], self.d_gstate)
         # The span a trace matches the block's device execution to: its
         # steps and the rows that were live when it was dispatched.
-        with TraceAnnotation("dispatch/decode_block", n=n,
-                             live=int(active_snapshot.sum())):
+        with self._phases.call("dispatch/decode_block", n=n,
+                               live=int(active_snapshot.sum())):
             out = fn(*args, *lora_args)
         if p.with_dfa:
             (
@@ -8187,9 +8270,9 @@ class Engine:
                 self.cache, self.counts, self.rngs, self.d_tokens, self.d_positions,
                 toks_block, tk_block, lp_block, moe_block,
             ) = out
-        _host_copy_async(toks_block)
+        self._host_copy_async(toks_block)
         if tk_block is not None:
-            _host_copy_async(tk_block)
+            self._host_copy_async(tk_block)
         self.h_override_mask[:] = False
         held = 0  # pool rows the live slots hold as the block starts
         for i in range(self.ecfg.max_slots):
@@ -8391,14 +8474,16 @@ class Engine:
                 npgb = self._pow2_pages(max(1, len(pages)))
                 rows = np.full((npgb,), self.ecfg.kv_pages, np.int32)
                 rows[:len(pages)] = pages  # padding gathers SCRATCH rows
-                self.sd_cache = self._get_sd_sync_paged(npgb)(
-                    self.sd_cache, self.cache, jnp.asarray(rows),
-                    jnp.int32(i),
-                )
+                with self._phases.call("call/sd_sync"):
+                    self.sd_cache = self._get_sd_sync_paged(npgb)(
+                        self.sd_cache, self.cache, self._upload(rows),
+                        jnp.int32(i),
+                    )
             else:
-                self.sd_cache = self._get_sd_sync()(
-                    self.sd_cache, self.cache, jnp.int32(i)
-                )
+                with self._phases.call("call/sd_sync"):
+                    self.sd_cache = self._get_sd_sync()(
+                        self.sd_cache, self.cache, jnp.int32(i)
+                    )
             self._sd_gen[i] = self._slot_gen[i]
 
     def _get_sd_sync(self):
@@ -8482,10 +8567,10 @@ class Engine:
             args = (self.params, self.cache)
         args = args + (
             self.counts, self.rngs, self.bias, self.d_tokens,
-            self.d_positions, jnp.asarray(pack),
+            self.d_positions, self._upload(pack),
         )
         if mode == "prompt_lookup":
-            args = args + (jnp.asarray(drafts),)
+            args = args + (self._upload(drafts),)
         if self._paged:
             args = args + (self._ptable_device(),)
         if with_dfa:
@@ -8493,9 +8578,9 @@ class Engine:
             args = args + (d["mask_bits"], self._dfa_table(d, with_dfa),
                            d["tok_cls"], self.d_gstate)
         if with_lora:
-            args = args + (self._lora_tree, jnp.asarray(self.h_adapter))
-        with TraceAnnotation("dispatch/spec_block", n=kb + 1,
-                             live=int(active_snapshot.sum())):
+            args = args + (self._lora_tree, self._upload(self.h_adapter))
+        with self._phases.call("dispatch/spec_block", n=kb + 1,
+                               live=int(active_snapshot.sum())):
             out = fn(*args)
         if mode == "draft_model":
             self.cache, self.d_cache = out[0], out[1]
@@ -8513,8 +8598,8 @@ class Engine:
         if with_dfa:
             self.d_gstate = rest[6]
             self.m_dfa_tokens += int((self.h_gmask * active_snapshot).sum())
-        _host_copy_async(toks_out)
-        _host_copy_async(acc)
+        self._host_copy_async(toks_out)
+        self._host_copy_async(acc)
         nact = int(active_snapshot.sum())
         drafted = int(dlens[active_snapshot].sum())
         self.h_draft_len[active_snapshot] = dlens[active_snapshot]
@@ -8568,7 +8653,7 @@ class Engine:
             # pull inline. np.asarray is idempotent, so the drainer
             # finishing its own copy later is harmless.
             while not e.ready():
-                self._wake.wait(timeout=SPAN_SLICE_S)
+                self._phases.wait(self._wake, SPAN_SLICE_S)
                 self._wake.clear()
                 self._phases.begin("pull")
             # lint: ignore[trace-safety] deliberate sync point: the drainer thread usually completed the copy (this is a cheap wait, not a walk), and when it has not, the loop NEEDS these results to schedule the next block

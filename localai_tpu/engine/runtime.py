@@ -20,6 +20,9 @@ touching program semantics:
   whose vector rides the `loop_iter` journal event, so loop overhead per
   block is attributable from the journal alone; while a profiler capture
   runs, each phase is also a `loop/<phase>` span on the capture's clock.
+  Each phase's time is also accounted to a cause (ISSUE 51): inside a jax
+  call (`call`), in the collector on the loop's own thread, off the CPU,
+  or Python's own; and the longest single stretch of a window is kept.
 - `DeadlineIndex` — a lazy-deletion min-heap of absolute monotonic
   deadlines. Submit pushes each request's deadline / queue-timeout
   expiry; the loop's housekeeping tick asks "is anything due?" in O(1)
@@ -36,6 +39,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from localai_tpu.observe import gcwatch
 from localai_tpu.ops import ptable as pt
 
 # Host-phase names for one loop iteration, in emit order. journal.py's
@@ -54,6 +58,16 @@ LOOP_PHASES = (
     "wait",          # idle / waiting on an in-flight block
 )
 
+
+# The phases in which the loop has nothing to do but wait: for work (`wait`)
+# or for a device result (`pull`). The others are the working phases.
+IDLE_PHASES = ("wait", "pull")
+
+# A stretch of one working phase this long is journalled as its own
+# `loop_stall` event. The busiest cell's phases are 2-56 ms a block (PERF.md
+# section 6 "PR 43") and the pauses nobody could explain 290-680 ms (PR 46's
+# dumps): 100 ms is above anything a block's work takes and well below those.
+STALL_MS = 100.0
 
 # The longest a `loop/<phase>` span stays open before it is closed and opened
 # again (LoopPhases). It is also the slice in which the loop waits for a
@@ -80,11 +94,14 @@ class ControlStager:
     upload, so derived views are cached too.
     """
 
-    def __init__(self):
+    def __init__(self, call):
         # thread: instance-owned — each stager belongs to one engine and
         # is touched only by that engine's loop thread (bench/tests read
         # the counters best-effort after the fact).
         self._cache: dict[str, _CtrlEntry] = {}
+        # The door an upload goes through: the owning loop's
+        # `LoopPhases.call`, so that the time inside jax is the call's.
+        self._call = call
         self.uploads = 0        # full-array H2D transfers issued
         self.row_uploads = 0    # partial (row-diff) transfers issued
         self.skips = 0          # commits satisfied entirely from cache
@@ -111,14 +128,17 @@ class ControlStager:
                 # those rows. jnp's .at returns a NEW array — the old one
                 # was never donated, so in-flight dispatches that captured
                 # it keep reading consistent state.
-                dev = ent.dev.at[rows].set(jnp.asarray(host[rows]))
-                out = build(dev) if build is not None else dev
+                with self._call("call/ctrl_upload",
+                                bytes=int(rows.size) * host[0].nbytes):
+                    dev = ent.dev.at[rows].set(jnp.asarray(host[rows]))
+                    out = build(dev) if build is not None else dev
                 self._cache[key] = _CtrlEntry(host.copy(), dev, out)
                 self.row_uploads += 1
                 return out
         kept = host.copy()
-        dev = jnp.asarray(kept)
-        out = build(dev) if build is not None else dev
+        with self._call("call/ctrl_upload", bytes=kept.nbytes):
+            dev = jnp.asarray(kept)
+            out = build(dev) if build is not None else dev
         self._cache[key] = _CtrlEntry(kept, dev, out)
         self.uploads += 1
         return out
@@ -139,9 +159,51 @@ class ControlStager:
         return self.uploads + self.row_uploads
 
 
+class _Call:
+    """One `LoopPhases.call`: the time from entering to leaving it is the
+    call's, and while a capture runs it is the span `name` with `stats`."""
+
+    __slots__ = ("ph", "name", "stats", "span", "mine", "t", "cpu", "gc")
+
+    def __init__(self, ph, name, stats):
+        self.ph = ph
+        self.name = name
+        self.stats = stats
+        self.span = None
+
+    def __enter__(self):
+        ph = self.ph
+        if ph._enabled():
+            self.span = ph._annotate(self.name, **self.stats)
+            self.span.__enter__()
+        # Some of the engine's device calls are also reached from request
+        # threads (a span export's page gather): there the call is its span
+        # alone, the account is the loop thread's.
+        self.mine = ph._owner is None or ph._owner == threading.get_ident()
+        if self.mine:
+            ph._depth += 1
+            if ph._depth == 1:  # a call inside a call is the outer one's
+                self.gc = ph.collector.ms
+                self.cpu = ph._cpu()
+                self.t = ph._wall()
+        return self
+
+    def __exit__(self, *exc):
+        ph = self.ph
+        if self.mine:
+            if ph._depth == 1:
+                ph._c_ms += (ph._wall() - self.t) * 1000.0
+                ph._c_cpu += (ph._cpu() - self.cpu) * 1000.0
+                ph._c_gc += ph.collector.ms - self.gc
+            ph._depth -= 1
+        if self.span is not None:
+            self.span.__exit__(*exc)
+        return False
+
+
 class LoopPhases:
-    """Per-phase host milliseconds of the engine loop, and the same phases
-    as spans on the profiler's clock.
+    """Per-phase host milliseconds of the engine loop, each accounted to a
+    cause, and the same phases as spans on the profiler's clock.
 
     The loop calls `begin(name)` where a phase starts; the phase runs until
     the next `begin` (or `end()`). Each phase is accumulated in `ms` (the
@@ -154,30 +216,89 @@ class LoopPhases:
     closed and opened again, because the profiler records a span when it
     ENDS: one that is open when a capture stops is lost whole, and one that
     began before the capture has no start. Slices bound both losses. With no
-    capture running `begin` costs one clock read and `is_enabled()`; no
-    annotation object is made.
+    capture running `begin` costs a wall and a thread-CPU clock read and
+    `is_enabled()`; no annotation object is made.
+
+    Beside `ms[phase]`, a window keeps where the phase's time went
+    (ISSUE 51). `call_ms`: between entering and leaving `call(name, ...)`,
+    the one door for every call the loop thread makes into jax outside
+    `pull`. `gc_ms`: in the collector on this thread, outside calls
+    (`collector.ms`, written by `observe/gcwatch`; a pause that ended inside
+    a call is the call's). `off_ms`: wall time less this thread's CPU time,
+    outside calls: the loop had work and was not running, because another
+    thread held the interpreter or the machine ran something else (the idle
+    phases, which ask not to run, book none). It is a SIGNED sum: a kernel
+    that charges CPU time by the timer tick gives one interval a tick too
+    much and the next too little, and only their sum is right; a reader
+    sums first and holds the sum at 0 or above, and a single stretch's part
+    is good to a tick. The rest of a working phase is the loop thread
+    running Python. `late_ms` / `late_max`: how much later
+    than asked the loop's timed waits came back (`wait`, `sleep`), which
+    lies in `wait` and `pull` and in none of the above.
+
+    A stretch is the time spent in ONE working phase from a `begin` of it
+    to the next `begin` of another (or to `end()`, or to `sync()`, the end of
+    a loop iteration); beginning the running phase again does not end it.
+    The window's longest is kept with its parts and what it did (`note`),
+    and one of `STALL_MS` or more is also put on `stalls` for the loop to
+    journal as `loop_stall`.
 
     `sync()` settles the running phase's time into `ms` without closing
-    its span; `vector()`/`total()` read `ms`, `reset()` starts the next
-    window.
+    its span; `vector()`/`total()`/`causes()` read the window, `reset()`
+    starts the next one.
     """
 
-    __slots__ = ("names", "ms", "iters", "_mark", "_cur", "_span",
-                 "_span_t0", "_annotate", "_enabled")
+    __slots__ = ("names", "ms", "call_ms", "gc_ms", "off_ms", "late_ms",
+                 "late_max", "longest", "stalls", "late_max_ever",
+                 "stretch_max_ever", "stall_count", "iters", "collector",
+                 "_mark", "_cpu_mark", "_gc_mark", "_cur", "_span",
+                 "_span_t0", "_annotate", "_enabled", "_wall", "_cpu",
+                 "_owner", "_depth", "_c_ms", "_c_cpu", "_c_gc", "_did_a",
+                 "_did_b", "_long_ms", "_idle")
 
-    def __init__(self, names=LOOP_PHASES, annotate=None):
+    def __init__(self, names=LOOP_PHASES, annotate=None, wall=None, cpu=None):
         if annotate is None:
             from jax.profiler import TraceAnnotation as annotate
         # thread: instance-owned — loop-thread state, read best-effort by
         # metrics/bench after generation completes.
         self.names = tuple(names)
-        # thread: instance-owned — see above; the clock and counters below
+        # thread: instance-owned — see above; the clocks and counters below
         # are written only by the owning engine's loop thread.
         self.ms = {n: 0.0 for n in self.names}
         # thread: instance-owned — see above.
+        self.call_ms = {n: 0.0 for n in self.names}
+        # thread: instance-owned — see above.
+        self.gc_ms = {n: 0.0 for n in self.names}
+        # thread: instance-owned — see above.
+        self.off_ms = {n: 0.0 for n in self.names}
+        # thread: instance-owned — see above.
+        self.late_ms = 0.0
+        # thread: instance-owned — see above.
+        self.late_max = 0.0
+        # thread: instance-owned — the window's longest stretch, or None:
+        # [phase, ms, call ms, collector ms, off-CPU ms, did a, did b].
+        self.longest = None
+        # thread: instance-owned — stretches of STALL_MS and more that the
+        # loop has not journalled yet (same seven values).
+        self.stalls = []
+        # thread: instance-owned — since start, for Engine.metrics().
+        self.late_max_ever = 0.0
+        # thread: instance-owned — see above.
+        self.stretch_max_ever = 0.0
+        # thread: instance-owned — see above.
+        self.stall_count = 0
+        # thread: instance-owned — see above.
         self.iters = 0
+        # thread: instance-owned — the loop thread's collector total: the
+        # loop registers it with `gcwatch.WATCH.enter` when it starts, and
+        # the hook adds the pauses that ran on that thread.
+        self.collector = gcwatch.LoopTotal()
         # thread: instance-owned — see above.
         self._mark = 0.0
+        # thread: instance-owned — see above.
+        self._cpu_mark = 0.0
+        # thread: instance-owned — see above.
+        self._gc_mark = 0.0
         # thread: instance-owned — the running phase and its open span.
         self._cur = None
         # thread: instance-owned — see above.
@@ -186,13 +307,34 @@ class LoopPhases:
         self._span_t0 = 0.0
         self._annotate = annotate
         self._enabled = annotate.is_enabled
+        self._wall = wall if wall is not None else time.monotonic
+        self._cpu = cpu if cpu is not None else time.thread_time
+        # thread: instance-owned — the loop thread's ident once a loop owns
+        # this (`own`); None: whoever calls.
+        self._owner = None
+        # thread: instance-owned — calls open, and the wall, CPU and
+        # collector ms spent inside calls since the last settle.
+        self._depth = 0
+        # thread: instance-owned — see above.
+        self._c_ms = 0.0
+        # thread: instance-owned — see above.
+        self._c_cpu = 0.0
+        # thread: instance-owned — see above.
+        self._c_gc = 0.0
+        # thread: instance-owned — `longest`'s ms (0: none yet).
+        self._long_ms = 0.0
+        # thread: instance-owned — what the running stretch did (`note`).
+        self._did_a = 0.0
+        # thread: instance-owned — see above.
+        self._did_b = 0.0
+        self._idle = frozenset(IDLE_PHASES)
 
     def begin(self, name: str) -> None:
         if name == self._cur:
             # Keep the span, unless it is a slice old; open one if a capture
             # started in the middle of the phase.
             if (self._span is None
-                    or time.monotonic() - self._span_t0 >= SPAN_SLICE_S):
+                    or self._wall() - self._span_t0 >= SPAN_SLICE_S):
                 self._close_span()
                 self._open_span()
             return
@@ -200,11 +342,49 @@ class LoopPhases:
         self._cur = name
         self._open_span()
 
+    def own(self) -> None:
+        """The calling thread is the loop this belongs to."""
+        self._owner = threading.get_ident()
+
+    def call(self, name: str, **stats) -> _Call:
+        """Context manager around one call into jax: a dispatch, an upload,
+        the start of a copy. Its time is booked to the running phase's
+        `call_ms`; while a capture runs it is the span `name` with `stats`."""
+        return _Call(self, name, stats)
+
+    def note(self, a: float, b: float) -> None:
+        """What the running stretch did: `process` counts tokens posted and
+        requests finished, the other phases programs dispatched and rows."""
+        self._did_a += a
+        self._did_b += b
+
+    def wait(self, event, timeout: float) -> bool:
+        """`event.wait(timeout)`; a wait that came back by timeout books how
+        much later than asked it did."""
+        t0 = self._wall()
+        if event.wait(timeout=timeout):
+            return True
+        self._late((self._wall() - t0 - timeout) * 1000.0)
+        return False
+
+    def sleep(self, seconds: float) -> None:
+        t0 = self._wall()
+        time.sleep(seconds)
+        self._late((self._wall() - t0 - seconds) * 1000.0)
+
+    def _late(self, ms: float) -> None:
+        if ms > 0.0:
+            self.late_ms += ms
+            if ms > self.late_max:
+                self.late_max = ms
+                if ms > self.late_max_ever:
+                    self.late_max_ever = ms
+
     def _open_span(self) -> None:
         if self._enabled():
             self._span = self._annotate("loop/" + self._cur)
             self._span.__enter__()
-            self._span_t0 = time.monotonic()
+            self._span_t0 = self._wall()
 
     def _close_span(self) -> None:
         if self._span is not None:
@@ -212,10 +392,55 @@ class LoopPhases:
             self._span = None
 
     def _settle(self) -> None:
-        now = time.monotonic()
-        if self._cur is not None:
-            self.ms[self._cur] += (now - self._mark) * 1000.0
+        """Book the time since the last settle to the running phase and its
+        causes; that time is one stretch of the phase."""
+        now = self._wall()
+        cpu = self._cpu()
+        gc_all = self.collector.ms
+        cur = self._cur
+        if cur is not None:
+            dt = (now - self._mark) * 1000.0
+            self.ms[cur] += dt
+            call = self._c_ms
+            out = dt - call  # outside calls
+            if call:
+                self.call_ms[cur] += call
+                if out < 0.0:
+                    out = 0.0
+            pause = gc_all - self._gc_mark - self._c_gc
+            if pause > 0.0:
+                if pause > out:
+                    pause = out
+                self.gc_ms[cur] += pause
+            else:
+                pause = 0.0
+            if cur not in self._idle:
+                # Signed: where the kernel charges a thread's CPU time by
+                # the timer tick (10 ms on the chip's host) an interval
+                # reads a tick too much or too little; the sum is right.
+                off = out - ((cpu - self._cpu_mark) * 1000.0 - self._c_cpu)
+                self.off_ms[cur] += off
+                if off < 0.0:
+                    off = 0.0
+                elif off > out - pause:
+                    off = out - pause
+                if dt > self._long_ms or dt >= STALL_MS:
+                    stretch = [cur, dt, call, pause, off,
+                               self._did_a, self._did_b]
+                    if dt > self._long_ms:
+                        self._long_ms = dt
+                        self.longest = stretch
+                        if dt > self.stretch_max_ever:
+                            self.stretch_max_ever = dt
+                    if dt >= STALL_MS:
+                        self.stall_count += 1
+                        self.stalls.append(stretch)
+            self._did_a = self._did_b = 0.0
+        if self._c_ms:
+            self._c_ms = self._c_cpu = self._c_gc = 0.0
         self._mark = now
+        self._cpu_mark = cpu
+        self._gc_mark = gc_all
 
     def end(self) -> None:
         """Close the running phase (loop exit, or before a new one)."""
@@ -234,9 +459,40 @@ class LoopPhases:
     def vector(self) -> list:
         return [self.ms[n] for n in self.names]
 
+    def working(self, per_phase: dict) -> float:
+        """Sum of `call_ms`, `gc_ms` or `off_ms` over the working phases."""
+        return sum(v for n, v in per_phase.items() if n not in self._idle)
+
+    def extras(self, stall=None) -> list:
+        """What rides a `loop_iter` event beside the vectors
+        (`journal.LOOP_EXTRA`): the window's late wake-ups, sum and maximum,
+        then its longest stretch: the phase's index (-1: none), its ms, call,
+        collector and off-CPU ms and the two things it did. With `stall`, a
+        `loop_stall`'s: that stretch, and no late wake-ups (they are the
+        window's, not the stretch's)."""
+        late = [0.0, 0.0] if stall else [self.late_ms, self.late_max]
+        stretch = stall or self.longest
+        if stretch is None:
+            return late + [-1.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0]
+        return late + [float(self.names.index(stretch[0]))] + stretch[1:]
+
+    def causes(self) -> list:
+        """The window's account as the `loop_iter` event carries it: a row
+        each for call, collector and off-CPU ms, by phase."""
+        names = self.names
+        return [[self.call_ms[n] for n in names],
+                [self.gc_ms[n] for n in names],
+                [self.off_ms[n] for n in names]]
+
     def reset(self) -> None:
         for n in self.names:
             self.ms[n] = 0.0
+            self.call_ms[n] = 0.0
+            self.gc_ms[n] = 0.0
+            self.off_ms[n] = 0.0
+        self.late_ms = self.late_max = 0.0
+        self.longest = None
+        self._long_ms = 0.0
         self.iters = 0
 
 

@@ -40,7 +40,12 @@ BASE_EVENTS = (
     "decode_block",  # decode/spec block dispatched (a=block size, b=dispatch ms)
     "loop_iter",     # coalesced loop-iteration window (a=occupancy, b=host ms
     #                  spent this window outside the wait phase; the
-    #                  per-phase host-ms breakdown rides the `phases` vector)
+    #                  per-phase host-ms breakdown rides the `phases` vector,
+    #                  and where each phase's ms went `calls`, `gc`, `off`:
+    #                  inside a jax call, in the collector on the loop's
+    #                  thread, off the CPU; `late` is how late the loop's timed
+    #                  waits came back, `longest` the window's longest stretch
+    #                  of one working phase: engine/runtime.LoopPhases)
     "preempt",       # slot preempted for pool pressure (slot, a=ctx rows)
     "swap_out",      # preempt-swap image written to the host tier (a=bytes)
     "swap_in",       # swap resume restored pool pages (slot, a=bytes)
@@ -123,6 +128,15 @@ BASE_EVENTS = (
     #                  (a=(row, pick) pairs it was compiled for: rows x
     #                  top-k x MoE layers, b=of those, the rows in a held
     #                  expert's group, the ones the kernel visits)
+    "loop_stall",    # one stretch of a working phase took runtime.STALL_MS or
+    #                  more (a=index into LOOP_PHASES, b=its ms; `stretch`
+    #                  holds its call, collector and off-CPU ms and what it
+    #                  did: process tokens posted and requests finished, else
+    #                  programs dispatched and rows)
+    "gc_pause",      # a collection of 1 ms or more ended at `t`, anywhere in
+    #                  the process (a=generation, b=ms; slot 0 when it ran on
+    #                  this engine's loop thread, -1 elsewhere;
+    #                  observe/gcwatch.py)
 )
 
 # One journal event type per fault-injection site (faults.SITES), checked
@@ -150,6 +164,7 @@ FAULT_EVENTS = (
 
 EVENTS = BASE_EVENTS + FAULT_EVENTS
 CODES = {name: i for i, name in enumerate(EVENTS)}
+_WITH_EXTRA = (CODES["loop_iter"], CODES["loop_stall"])
 
 # Host-phase names for one loop_iter window (engine/runtime.LOOP_PHASES is
 # the writer-side source; this copy keeps the observe layer engine-free and
@@ -160,6 +175,15 @@ LOOP_PHASES = (
     "process", "housekeeping", "wait",
 )
 
+# What a `loop_iter` / `loop_stall` event carries beside its vectors, in the
+# order LoopPhases.extras() writes it: the window's late wake-ups (sum and
+# maximum; loop_iter only), then one stretch: its phase (index into
+# LOOP_PHASES, -1 = none), its ms, the call, collector and off-CPU ms inside
+# it, and the two things it did.
+LOOP_EXTRA = ("late_ms", "late_max", "phase", "ms", "call", "gc", "off",
+              "did_a", "did_b")
+_CAUSES = ("calls", "gc", "off")
+
 _DTYPE = np.dtype([
     ("t", np.float64),      # time.monotonic() at emit
     ("code", np.int16),     # index into EVENTS
@@ -168,9 +192,33 @@ _DTYPE = np.dtype([
     ("b", np.float64),      # second event-specific scalar
     ("rid", "U40"),         # request id (empty for engine-wide events)
     ("ph", np.float32, (len(LOOP_PHASES),)),  # loop_iter host-phase ms
+    # loop_iter: of those ms, per phase, in calls / collector / off the CPU.
+    # Written and read for loop_iter alone; another event's slot keeps
+    # whatever was there.
+    ("causes", np.float32, (len(_CAUSES), len(LOOP_PHASES))),
+    ("extra", np.float32, (len(LOOP_EXTRA),)),  # loop_iter, loop_stall
 ])
 
+
 _STAGED_CAP = 1024
+
+
+def _read_account(rec, d: dict) -> None:
+    """A loop_iter's `calls` / `gc` / `off` (ms by phase), `late` and
+    `longest`, or a loop_stall's `stretch`, into the snapshot's dict."""
+    x = dict(zip(LOOP_EXTRA, (float(v) for v in rec["extra"])))
+    stretch = None
+    if x["phase"] >= 0:
+        stretch = {"phase": LOOP_PHASES[int(x["phase"])], "ms": x["ms"],
+                   "call": x["call"], "gc": x["gc"], "off": x["off"],
+                   "did": [x["did_a"], x["did_b"]]}
+    if d["event"] == "loop_stall":
+        d["stretch"] = stretch
+        return
+    for name, row in zip(_CAUSES, rec["causes"]):
+        d[name] = {LOOP_PHASES[k]: float(v) for k, v in enumerate(row) if v}
+    d["late"] = {"ms": x["late_ms"], "max": x["late_max"]}
+    d["longest"] = stretch
 
 
 class EventJournal:
@@ -195,16 +243,28 @@ class EventJournal:
 
     # thread: engine-loop-only
     def append(self, event: str, rid: str = "", slot: int = -1,
-               a: float = 0.0, b: float = 0.0, phases=None) -> None:
+               a: float = 0.0, b: float = 0.0, phases=None, causes=None,
+               extra=None) -> None:
         """Writer-thread append: O(1), no allocation, no lock, no device.
         The `# thread:` declaration makes the single-writer convention
         machine-checked (thread-affinity lint pass): any call chain from a
         non-loop root is a finding — cross-thread emitters use stage().
-        `phases` (loop_iter only) is a LOOP_PHASES-ordered ms sequence."""
-        self._append_raw(time.monotonic(), event, rid, slot, a, b, phases)
+        `phases` (loop_iter only) is a LOOP_PHASES-ordered ms sequence,
+        `causes` (loop_iter only) LoopPhases.causes(), `extra` (loop_iter and
+        loop_stall) LoopPhases.extras()."""
+        self._append_raw(time.monotonic(), event, rid, slot, a, b, phases,
+                         causes, extra)
+
+    # thread: engine-loop-only
+    def append_at(self, t: float, event: str, slot: int = -1,
+                  a: float = 0.0, b: float = 0.0) -> None:
+        """Writer-thread append of something that ended at `t`, before now
+        (a collector pause the loop drained from gcwatch's ring)."""
+        self._append_raw(t, event, "", slot, a, b)
 
     def _append_raw(self, t: float, event: str, rid: str, slot: int,
-                    a: float, b: float, phases=None) -> None:
+                    a: float, b: float, phases=None, causes=None,
+                    extra=None) -> None:
         i = self.n % self.capacity
         buf = self._buf
         buf["t"][i] = t
@@ -214,6 +274,9 @@ class EventJournal:
         buf["b"][i] = b
         buf["rid"][i] = rid
         buf["ph"][i] = phases if phases is not None else 0.0
+        if extra is not None:
+            buf["extra"][i] = extra
+            buf["causes"][i] = causes if causes is not None else 0.0
         self.n += 1
 
     def stage(self, event: str, rid: str = "", slot: int = -1,
@@ -268,6 +331,8 @@ class EventJournal:
             if ph.any():
                 d["phases"] = {LOOP_PHASES[k]: float(v)
                                for k, v in enumerate(ph) if v}
+            if rec["code"] in _WITH_EXTRA:
+                _read_account(rec, d)
             out.append(d)
         with self._staged_lock:
             staged = list(self._staged)

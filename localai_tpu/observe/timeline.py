@@ -8,8 +8,9 @@ Mapping:
 - `tid` is the engine slot (engine-wide events ride tid 0 labeled
   "engine-loop");
 - events that carry a duration (`decode_block` dispatch wall, `loop_iter`
-  host time outside the wait phase, `chunk`) become complete ("X") events
-  ending at their journal timestamp; everything else is an instant ("i");
+  host time outside the wait phase, `chunk`, a `loop_stall`'s stretch, a
+  `gc_pause`) become complete ("X") events ending at their journal
+  timestamp; everything else is an instant ("i");
 - timestamps are microseconds relative to the earliest journal anchor, so
   multi-journal exports (cluster replicas) share one timeline.
 """
@@ -19,7 +20,10 @@ from __future__ import annotations
 from typing import Any
 
 # Journal events whose `b` field is a duration in milliseconds.
-_DUR_MS_EVENTS = {"decode_block", "loop_iter", "chunk"}
+_DUR_MS_EVENTS = {"decode_block", "loop_iter", "chunk", "loop_stall",
+                  "gc_pause"}
+# What a loop_iter / loop_stall carries beside a and b (journal.snapshot).
+_ACCOUNT_KEYS = ("phases", "calls", "gc", "off", "late", "longest", "stretch")
 
 
 def chrome_trace(journals: dict[str, Any]) -> dict:
@@ -43,10 +47,10 @@ def chrome_trace(journals: dict[str, Any]) -> dict:
             args = {"seq": rec["seq"], "a": rec["a"], "b": rec["b"]}
             if rec["rid"]:
                 args["rid"] = rec["rid"]
-            if "phases" in rec:
-                # loop_iter host-phase ms breakdown (ISSUE 17) — visible in
-                # the Perfetto args panel per window.
-                args["phases"] = rec["phases"]
+            # loop_iter host-phase ms breakdown (ISSUE 17) and where each
+            # phase's ms went (ISSUE 51) — visible in the Perfetto args
+            # panel per window.
+            args.update({k: rec[k] for k in _ACCOUNT_KEYS if k in rec})
             ev: dict = {
                 "name": rec["event"], "cat": "engine",
                 "pid": pid, "tid": tid, "args": args,

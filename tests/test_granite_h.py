@@ -93,7 +93,7 @@ def test_engine_agrees_with_the_plain_reference(served):
     assert eng.cache.conv.shape == (8, 2, 3, 128 + 2 * 32)
     assert eng.cache.k.shape == (2, 41, 16, 2, 16) == eng.cache.v.shape
     assert m["recurrent_state_bytes"] == 2 * 8 * (8 * 16 * 32 * 4 + 3 * 192 * 4)
-    assert m["state_snapshots"] == 0
+    assert "state_snapshots" not in m
     assert m["admit_rows_max"] == rstate.admit_rows(CFG)
     # off the TPU every SSD layer's update is the XLA step, and is counted
     assert m["ssd_decode_xla_sites"] > 0 and m["ssd_decode_pallas_sites"] == 0

@@ -80,7 +80,7 @@ def test_engine_agrees_with_the_plain_reference(served):
     kl = len(CFG.recurrent_layers)
     assert m["recurrent_state_bytes"] == 2 * kl * (
         4 * 16 * 16 * 4 + 3 * 3 * 64 * 4)
-    assert m["state_snapshots"] == 0 and m["prefix_reuse_off"] == 1
+    assert "state_snapshots" not in m and m["prefix_reuse_off"] == 1
     ev = [e for e in eng.journal.snapshot()]
     rows = [e for e in ev if e["event"] == "state_rows"]
     assert rows and all(e["a"] % (2 * kl) == 0 and e["b"] <= e["a"]

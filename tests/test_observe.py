@@ -16,6 +16,7 @@ The acceptance contract pinned here:
 - journal-on vs journal-off decode stays within noise.
 """
 
+import gc
 import json
 import threading
 import time
@@ -30,6 +31,7 @@ from localai_tpu.config import ApplicationConfig
 from localai_tpu.engine import ByteTokenizer, Engine, EngineConfig, GenRequest
 from localai_tpu.models import get_arch
 from localai_tpu.models.llama import init_params
+from localai_tpu.observe import gcwatch
 from localai_tpu.observe import journal as ojournal
 from localai_tpu.observe import timeline as otimeline
 from localai_tpu.observe import trace as otrace
@@ -125,6 +127,135 @@ def test_journal_staged_bounded():
     for _ in range(ojournal._STAGED_CAP + 10):
         j.stage("queued")
     assert j.dropped_staged == 10
+
+
+def _loop_account(j, t=None):
+    """One loop_iter window with every new field set, one loop_stall and one
+    gc_pause, as the engine loop writes them."""
+    n = len(ojournal.LOOP_PHASES)
+    phases = [0.0] * n
+    phases[ojournal.LOOP_PHASES.index("commit")] = 40.0
+    phases[ojournal.LOOP_PHASES.index("process")] = 150.0
+    causes = [[0.0] * n for _ in range(3)]
+    causes[0][ojournal.LOOP_PHASES.index("commit")] = 30.0   # calls
+    causes[1][ojournal.LOOP_PHASES.index("process")] = 120.0  # collector
+    causes[2][ojournal.LOOP_PHASES.index("commit")] = 4.0    # off the CPU
+    stretch = [float(ojournal.LOOP_PHASES.index("process")), 150.0, 0.0,
+               120.0, 0.0, 48.0, 2.0]
+    j.append("loop_stall", a=stretch[0], b=150.0, extra=[0.0, 0.0] + stretch)
+    j.append("loop_iter", a=3.0, b=190.0, phases=phases, causes=causes,
+             extra=[7.0, 5.0] + stretch)
+    j.append_at(j.t0_mono + 0.5, "gc_pause", slot=0, a=2.0, b=120.0)
+
+
+def test_loop_iter_carries_where_each_phases_ms_went():
+    j = EventJournal(16)
+    j.append("decode_block", a=16.0, b=1.0)
+    _loop_account(j)
+    snap = {e["event"]: e for e in j.snapshot()}
+    it = snap["loop_iter"]
+    assert it["phases"] == {"commit": 40.0, "process": 150.0}
+    assert it["calls"] == {"commit": 30.0}
+    assert it["gc"] == {"process": 120.0}
+    assert it["off"] == {"commit": 4.0}
+    assert it["late"] == {"ms": 7.0, "max": 5.0}
+    want = {"phase": "process", "ms": 150.0, "call": 0.0, "gc": 120.0,
+            "off": 0.0, "did": [48.0, 2.0]}
+    assert it["longest"] == want
+    assert snap["loop_stall"]["stretch"] == want
+    assert snap["loop_stall"]["b"] == 150.0
+    assert (snap["gc_pause"]["slot"], snap["gc_pause"]["a"]) == (0, 2.0)
+    # another event's slot of the ring says nothing about the loop
+    assert not {"calls", "longest", "stretch"} & set(snap["decode_block"])
+    # a window in which no stretch ended
+    j.append("loop_iter", phases=[0.0] * 9 + [30.0], causes=None,
+             extra=[0.0, 0.0, -1.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0])
+    quiet = max(j.snapshot(), key=lambda e: e["seq"])
+    assert quiet["longest"] is None and quiet["calls"] == {}
+    # the two events are the last of BASE_EVENTS: wire codes are append-only
+    assert ojournal.BASE_EVENTS[-2:] == ("loop_stall", "gc_pause")
+
+
+def test_timeline_draws_a_stall_and_a_pause_as_durations():
+    j = EventJournal(16)
+    _loop_account(j)
+    evs = {e["name"]: e for e in otimeline.chrome_trace({"e": j})["traceEvents"]
+           if e.get("cat") == "engine"}
+    pause = evs["gc_pause"]
+    assert pause["ph"] == "X" and pause["dur"] == pytest.approx(120e3)
+    assert pause["ts"] + pause["dur"] == pytest.approx(0.5e6)  # ends at its t
+    stall = evs["loop_stall"]
+    assert stall["ph"] == "X" and stall["dur"] == pytest.approx(150e3)
+    assert stall["args"]["stretch"]["did"] == [48.0, 2.0]
+    assert evs["loop_iter"]["args"]["gc"] == {"process": 120.0}
+    assert evs["loop_iter"]["args"]["longest"]["phase"] == "process"
+
+
+def test_a_collection_under_the_sidecar_lock_returns_and_is_journalled(
+        monkeypatch):
+    """The hook runs on whichever thread tripped the collector, under
+    whatever that thread holds: here the journal's sidecar lock, which
+    stage() would wait for forever."""
+    monkeypatch.setattr(gcwatch, "JOURNAL_MS", 0.0)
+    watch = gcwatch.WATCH
+    j = EventJournal(64)
+    before = watch.counters()
+    total = gcwatch.LoopTotal()
+    watch.enter(total)  # this thread stands in for an engine loop
+    try:
+        seen = watch.n
+
+        def collect_under_the_lock():
+            with j._staged_lock:
+                gc.collect()
+
+        t = threading.Thread(target=collect_under_the_lock)
+        t.start()
+        t.join(timeout=60.0)
+        assert not t.is_alive()
+        gc.collect()  # and one on the "loop" thread itself
+        after = watch.counters()
+        assert after["host_gc_pauses"] >= before["host_gc_pauses"] + 2
+        assert after["host_gc_gen2_pauses"] >= before["host_gc_gen2_pauses"] + 2
+        assert after["host_gc_pause_ms_total"] > before["host_gc_pause_ms_total"]
+        assert after["host_gc_pause_ms_max"] > 0.0
+        assert total.ms > 0.0  # only the second ran on this thread
+        ring = [p for p in watch.recent() if p["generation"] == 2][-2:]
+        assert ring[0]["thread"] == t.ident
+        assert ring[1]["thread"] == threading.get_ident()
+        assert total.ms == pytest.approx(
+            sum(p["ms"] for p in watch.recent()[seen - watch.n:]
+                if p["thread"] == threading.get_ident()))
+        seen = watch.drain(seen, lambda t_end, gen, ms, mine:
+                           j.append_at(t_end, "gc_pause",
+                                       slot=0 if mine else -1, a=gen, b=ms))
+        assert seen == watch.n
+        pauses = [e for e in j.snapshot() if e["event"] == "gc_pause"
+                  and e["a"] == 2.0]
+        assert [e["slot"] for e in pauses[-2:]] == [-1, 0]
+        assert all(e["b"] > 0.0 for e in pauses)
+    finally:
+        watch.leave()
+    assert watch._on_gc not in gc.callbacks
+
+
+def test_the_collectors_hook_lives_as_long_as_an_engine_loop(tiny):
+    watch = gcwatch.WATCH
+    assert watch._on_gc not in gc.callbacks
+    a = _mk_engine(tiny)
+    b = _mk_engine(tiny)
+    try:
+        a.generate([1, 2, 3], max_new_tokens=2, ignore_eos=True)
+        assert gc.callbacks.count(watch._on_gc) == 1
+        a.stop()
+        assert gc.callbacks.count(watch._on_gc) == 1  # b's loop still runs
+        n = watch.counters()["host_gc_pauses"]
+        gc.collect()
+        assert b.metrics()["host_gc_pauses"] > n
+    finally:
+        a.stop()
+        b.stop()
+    assert watch._on_gc not in gc.callbacks
 
 
 def test_journal_fault_events_mirror_sites():

@@ -2,8 +2,8 @@
 section 5): program and kernel names, loop phases as spans, the decode-row
 account, the `decode_first` event and the `join` phase."""
 
+import gc
 import time
-import types
 
 import jax
 import jax.numpy as jnp
@@ -150,7 +150,7 @@ class _Span:
     log: list = []
     enabled = True
 
-    def __init__(self, name):
+    def __init__(self, name, **stats):
         self.name = name
 
     def __enter__(self):
@@ -192,11 +192,21 @@ def test_loop_phases_make_no_span_without_a_capture():
     ph.begin("pull")
     _Span.enabled = False  # and stops in the middle of another
     ph.begin("process")
+    with ph.call("dispatch/decode_block", n=4, live=2):
+        pass               # a call is no object either without a capture
     ph.end()
     assert _Span.log == [("open", "loop/wait"), ("close", "loop/wait"),
                          ("open", "loop/pull"), ("close", "loop/pull")]
     assert set(k for k, v in ph.ms.items() if v > 0) <= {
         "admit", "wait", "pull", "process"}
+    _Span.log, _Span.enabled = [], True
+    ph.begin("dispatch")
+    with ph.call("dispatch/decode_block", n=4, live=2):
+        pass               # and under one it is the span it always was
+    ph.end()
+    assert _Span.log == [
+        ("open", "loop/dispatch"), ("open", "dispatch/decode_block"),
+        ("close", "dispatch/decode_block"), ("close", "loop/dispatch")]
 
 
 def test_a_long_phase_is_spans_of_one_slice(monkeypatch):
@@ -206,10 +216,9 @@ def test_a_long_phase_is_spans_of_one_slice(monkeypatch):
     wall-clock window from this thread): steps of 2**-12 s, slices of eight."""
     _Span.log, _Span.enabled = [], True
     now = [128.0]
-    monkeypatch.setattr(runtime, "time",
-                        types.SimpleNamespace(monotonic=lambda: now[0]))
     monkeypatch.setattr(runtime, "SPAN_SLICE_S", 2.0 ** -9)
-    ph = runtime.LoopPhases(annotate=_Span)
+    ph = runtime.LoopPhases(annotate=_Span, wall=lambda: now[0],
+                            cpu=lambda: now[0])
     for _ in range(80):
         ph.begin("pull")   # what the loop does every slice of its wait
         now[0] += 2.0 ** -12
@@ -233,12 +242,13 @@ def test_the_loops_spans_reach_a_real_capture(tiny, tmp_path):
         _run(eng, [2], "warm")
         jax.profiler.start_trace(str(tmp_path), **oprofile.trace_options(jax))
         _run(eng, [9, 9], "cap")
+        gc.collect()  # the engine's hook is in: a collection is a span too
         time.sleep(0.05)
         jax.profiler.stop_trace()
     finally:
         eng.stop()
     (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
-    names, blocks = set(), []
+    names, blocks, admits, pauses, uploads = set(), [], [], [], []
     for plane in ProfileData.from_file(str(path)).planes:
         if not plane.name.startswith("/host:"):
             continue
@@ -248,10 +258,235 @@ def test_the_loops_spans_reach_a_real_capture(tiny, tmp_path):
                     names.add(ev.name)
                 if ev.name == "dispatch/decode_block":
                     blocks.append(dict(ev.stats))
+                if ev.name == "dispatch/admit":
+                    admits.append(dict(ev.stats))
+                if ev.name == "host/gc":
+                    pauses.append(dict(ev.stats))
+                if ev.name == "call/ctrl_upload":
+                    uploads.append(dict(ev.stats))
     assert {"loop/wait", "loop/pull", "loop/process", "loop/dispatch"} <= names
     assert names <= {f"loop/{p}" for p in jmod.LOOP_PHASES}
     assert blocks and all(b["n"] in (4, 16) and 1 <= b["live"] <= 4
                           for b in blocks)
+    # the dispatch spans went through LoopPhases.call with the stats they had
+    assert admits and all(a["m"] >= 1 and a["bucket"] == 16
+                          and 4 <= a["tokens"] <= 4 * a["m"] for a in admits)
+    # the forced collection, and the first block's control upload
+    assert any(p["generation"] == 2 for p in pauses)
+    assert uploads and all(u["bytes"] > 0 for u in uploads)
+
+
+# --------------------------------------------------------------------- #
+# D: every stretch of the loop has a cause (ISSUE 51)
+# --------------------------------------------------------------------- #
+
+
+class _Clocks:
+    """A wall clock and the loop thread's CPU clock, turned by hand (ms)."""
+
+    def __init__(self):
+        self.wall = 64.0
+        self.cpu = 8.0
+
+    def run(self, ms):          # the thread runs Python
+        self.wall += ms / 1000.0
+        self.cpu += ms / 1000.0
+
+    def away(self, ms, cpu_ms=0.0):  # the thread is not on the CPU
+        self.wall += ms / 1000.0
+        self.cpu += cpu_ms / 1000.0
+
+    def phases(self):
+        return runtime.LoopPhases(annotate=_Span, wall=lambda: self.wall,
+                                  cpu=lambda: self.cpu)
+
+
+class _Never:
+    """An Event nobody sets: wait() comes back by timeout, `late` ms late."""
+
+    def __init__(self, clk, late):
+        self.clk, self.late = clk, late
+
+    def wait(self, timeout):
+        self.clk.away(timeout * 1000.0 + self.late)
+        return False
+
+
+def test_every_ms_of_a_working_phase_has_exactly_one_cause():
+    _Span.enabled = False
+    clk = _Clocks()
+    ph = clk.phases()
+    gcw = ph.collector  # the hook adds this thread's pauses to it
+    ph.begin("admit")
+    clk.run(10.0)                       # Python
+    with ph.call("dispatch/admit", m=2, bucket=16, tokens=20):
+        clk.away(30.0, cpu_ms=1.0)      # inside jax, mostly waiting
+        gcw.ms += 5.0                   # a pause in there is the call's
+    ph.note(1, 32)
+    clk.away(6.0)                       # another thread has the interpreter
+    clk.run(2.0)
+    clk.run(3.0)
+    gcw.ms += 3.0                       # the collector, on this thread
+    ph.begin("wait")
+    assert not ph.wait(_Never(clk, 300.0), 0.001)  # asked 1 ms, got 301
+    ph.begin("process")
+    clk.run(150.0)
+    ph.note(48, 2)
+    ph.sync()                           # the end of the loop's iteration
+    assert ph.ms["admit"] == pytest.approx(51.0)
+    assert ph.call_ms["admit"] == pytest.approx(30.0)
+    assert ph.gc_ms["admit"] == pytest.approx(3.0)
+    assert ph.off_ms["admit"] == pytest.approx(6.0)
+    assert ph.ms["process"] == pytest.approx(150.0)
+    work = [n for n in ph.names if n not in runtime.IDLE_PHASES]
+    python = {n: ph.ms[n] - ph.call_ms[n] - ph.gc_ms[n] - ph.off_ms[n]
+              for n in work}
+    assert python["admit"] == pytest.approx(12.0)
+    assert python["process"] == pytest.approx(150.0)
+    # the four causes add up to the working phases' ms, exactly
+    assert (sum(python.values()) + ph.working(ph.call_ms)
+            + ph.working(ph.gc_ms) + ph.working(ph.off_ms)
+            == pytest.approx(ph.total(exclude=runtime.IDLE_PHASES), abs=1e-9))
+    # the late wake-up is in `wait`, and in none of them
+    assert (ph.late_ms, ph.late_max) == (pytest.approx(300.0),) * 2
+    assert ph.ms["wait"] == pytest.approx(301.0)
+    assert ph.call_ms["wait"] == ph.off_ms["wait"] == ph.gc_ms["wait"] == 0.0
+    # the longest stretch, with its parts and what it did
+    assert ph.longest[0] == "process"
+    assert ph.longest[1:] == pytest.approx([150.0, 0.0, 0.0, 0.0, 48.0, 2.0],
+                                           abs=1e-6)
+    assert ph.stalls == [ph.longest] and ph.stall_count == 1
+    stretch = [float(ph.names.index("process")), 150.0, 0.0, 0.0, 0.0, 48.0,
+               2.0]
+    assert ph.extras() == pytest.approx([300.0, 300.0] + stretch, abs=1e-6)
+    # a stall's record carries the stretch and not the window's late pair
+    assert ph.extras(stall=ph.stalls[0]) == pytest.approx(
+        [0.0, 0.0] + stretch, abs=1e-6)
+    assert ph.causes()[0][ph.names.index("admit")] == pytest.approx(30.0)
+    ph.reset()
+    assert ph.longest is None and ph.late_max == 0.0
+    assert (ph.stretch_max_ever, ph.late_max_ever) == (
+        pytest.approx(150.0), pytest.approx(300.0))  # since start
+
+
+def test_a_thread_clock_that_ticks_in_steps_still_sums_to_the_truth():
+    """The chip's host charges a thread's CPU time by the 10 ms timer tick
+    (my chip run A, PR 51): a 4 ms stretch reads 0 or 10 ms of CPU. Held at 0
+    stretch by stretch, every short phase read as off the CPU whole."""
+    _Span.enabled = False
+    clk = _Clocks()
+    ph = runtime.LoopPhases(annotate=_Span, wall=lambda: clk.wall,
+                            cpu=lambda: int(clk.cpu * 100.0) / 100.0)
+    ph.begin("wait")
+    for _ in range(100):       # 400 ms of Python, 100 ms truly off the CPU
+        ph.begin("prep")
+        clk.run(4.0)
+        ph.begin("housekeeping")
+        clk.away(1.0)
+        ph.begin("wait")
+        clk.away(3.0)
+    ph.sync()
+    assert ph.ms["prep"] == pytest.approx(400.0)
+    off = ph.off_ms["prep"] + ph.off_ms["housekeeping"]
+    assert off == pytest.approx(100.0, abs=10.5)  # to a tick
+    assert ph.longest[4] >= 0.0  # one stretch's part is never negative
+
+
+def test_beginning_the_running_phase_again_does_not_end_its_stretch():
+    _Span.enabled = False
+    clk = _Clocks()
+    ph = clk.phases()
+    ph.begin("process")
+    clk.run(60.0)
+    ph.begin("process")   # what _process_entry does at every step
+    clk.run(60.0)
+    ph.begin("wait")
+    assert ph.longest[:2] == ["process", pytest.approx(120.0)]
+    assert len(ph.stalls) == 1
+    ph.reset()
+    ph.stalls.clear()
+    ph.begin("process")
+    clk.run(60.0)
+    ph.begin("prep")      # another phase between them: two stretches
+    clk.run(1.0)
+    ph.begin("process")
+    clk.run(60.0)
+    ph.end()
+    assert ph.longest[:2] == ["process", pytest.approx(60.0)]
+    assert ph.stalls == [] and ph.ms["process"] == pytest.approx(120.0)
+
+
+@pytest.fixture(scope="module")
+def stalled_run(tiny):
+    """A warm engine; a quiet run of it; then a run in which posting one
+    token takes 150 ms. Yields (events of the quiet run, of the slow one,
+    the engine's metrics after both)."""
+    eng = _engine(tiny)
+    try:
+        _run(eng, [9, 9], "warm")
+        t_quiet = time.monotonic()
+        _run(eng, [9, 9], "quiet")
+        t_slow = time.monotonic()
+        post, armed = eng._post_token, [True]
+
+        def slow_post(*a, **kw):
+            if armed[0]:
+                armed[0] = False
+                time.sleep(0.15)
+            return post(*a, **kw)
+
+        eng._post_token = slow_post
+        _run(eng, [9, 9], "slow")
+        time.sleep(0.1)  # a last iteration, so the window is flushed
+        evs = eng.journal.snapshot()
+        yield ([e for e in evs if t_quiet <= e["t"] < t_slow],
+               [e for e in evs if e["t"] >= t_slow], eng.metrics())
+    finally:
+        eng.stop()
+
+
+def test_a_stall_in_process_is_journalled_with_what_it_posted(stalled_run):
+    _quiet, slow, m = stalled_run
+    stalls = [e for e in slow if e["event"] == "loop_stall"]
+    mine = [e for e in stalls if e["stretch"]["phase"] == "process"]
+    assert len(mine) == 1, stalls
+    st = mine[0]["stretch"]
+    assert mine[0]["b"] == pytest.approx(st["ms"]) and st["ms"] >= 150.0
+    assert jmod.LOOP_PHASES[int(mine[0]["a"])] == "process"
+    # the thread slept: off the CPU, not in a call, not collecting
+    assert st["off"] >= 140.0 and st["call"] == 0.0
+    assert st["did"][0] >= 1  # tokens it posted
+    # the window that holds it names it as its longest stretch
+    longest = [e["longest"] for e in slow if e["event"] == "loop_iter"
+               and e["longest"] and e["longest"]["ms"] >= 150.0]
+    assert [x["phase"] for x in longest] == ["process"]
+    assert m["loop_stalls"] >= 1 and m["loop_stretch_ms_max"] >= 150.0
+
+
+def test_a_quiet_run_journals_no_stall_in_process(stalled_run):
+    quiet, _slow, _m = stalled_run
+    iters = [e for e in quiet if e["event"] == "loop_iter"]
+    assert iters and all({"calls", "gc", "off", "late", "longest"} <= set(e)
+                         for e in iters)
+    assert not [e for e in quiet if e["event"] == "loop_stall"
+                and e["stretch"]["phase"] == "process"]
+    for e in iters:  # no cause is larger than the phase it is a part of
+        for k in ("calls", "gc", "off"):
+            for phase, v in e[k].items():
+                assert v <= e["phases"].get(phase, 0.0) * 1.001 + 1e-3
+
+
+def test_the_loops_causes_are_counters_too(mixed_run):
+    eng, _rids, _lengths = mixed_run
+    m = eng.metrics()
+    parts = (m["loop_call_ms_total"] + m["loop_gc_ms_total"]
+             + m["loop_off_cpu_ms_total"])
+    assert 0.0 < m["loop_call_ms_total"] <= parts
+    assert parts <= m["loop_host_ms_total"] - m["loop_blocked_ms_total"] + 1e-6
+    assert m["loop_stretch_ms_max"] > 0.0 and m["loop_late_ms_max"] >= 0.0
+    assert m["host_gc_pauses"] >= m["host_gc_gen2_pauses"] >= 0
+    assert m["host_gc_pause_ms_total"] >= m["host_gc_pause_ms_max"] >= 0.0
+    assert "loop_host_overhead_per_block_ms" not in m
 
 
 # --------------------------------------------------------------------- #
