@@ -22,6 +22,19 @@ convention, as `kda_decode`: the index a scalar-prefetch operand, the output
 aliased onto the input), so no per-layer slice of the state is ever made:
 one layer's rows are 134 MB at 32 slots.
 
+The kernel is a stream of 2 MiB blocks in and out, and the update (a few VPU
+ops a vreg) hides under the copies. The read-out y = S C is done by the MXU,
+which the kernel otherwise leaves idle: C against the head's state contracted
+over d_state, a float32 dot at `Precision.HIGHEST` (six bfloat16 passes; 2e-7
+of the largest |y| against a float64 read-out, the XLA step's own error). As
+`jnp.sum(S * C, axis=1)` it was 1,024 lane reductions a slot and layer on the
+XLU and a one-lane store a head, and did not hide: 0.447 ms a layer's call at
+the published shape on a v5e where the MXU form takes 0.409, the same
+pipeline with NO read-out 0.409 and XLA's in-place fusion of the update
+alone 0.409 (PERF.md section 6 "PR 52"). Mosaic takes the dot at every shape
+the call's BlockSpecs admit (a state narrower than a lane tile too), so
+there is one read-out.
+
 Prefill runs the chunkwise form: within a chunk of `chunk` tokens the
 quadratic (attention-like) form, between chunks one sequential pass over the
 chunk states. A head's decay is a scalar, so every factored product is the
@@ -105,16 +118,31 @@ def _ssd_decode_kernel(layer_ref, dxT_ref, da_ref, b_ref, c_ref, s_ref,
     read by C. d_state lies on the lanes: B and C arrive as rows that
     broadcast along sublanes, dt x transposed ([P, heads]: a head's vector is
     a lane slice that broadcasts along lanes as a column), the decay as a row
-    of its own value."""
+    of its own value.
+
+    The read-out y[h] = S[h] C runs on the MXU: C, a sublane tile of its row,
+    against the head's state as it was just written, contracted over d_state
+    (the state is the transposed right-hand side, which the MXU loads as
+    stored), float32 in and out at `Precision.HIGHEST`. A head's y comes back
+    as a ROW (P on the lanes, the same on all 8 sublanes); eight heads' rows
+    make a tile by a select a head, and the block's [heads, P] tiles are
+    transposed once a grid step into the [P, heads] block the call stores."""
     del layer_ref  # consumed by the index maps
-    hb = s_ref.shape[2]
+    hb, P, N = s_ref.shape[2:]
     b_row = b_ref[0, 0]  # [1, N]
-    c_row = c_ref[0, 0]
+    c_rows = jnp.broadcast_to(c_ref[0, 0], (8, N))
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, P), 0)
+    tiles = [jnp.zeros((8, P), jnp.float32) for _ in range(-(-hb // 8))]
     for h in range(hb):  # static unroll: 8 vregs of state a head at 64 x 128
         S = s_ref[0, 0, h] * da_ref[0, h:h + 1, :]
         S = S + dxT_ref[0, 0, :, h:h + 1] * b_row
         s_out_ref[0, 0, h] = S
-        yT_ref[0, 0, :, h:h + 1] = jnp.sum(S * c_row, axis=1, keepdims=True)
+        y = jax.lax.dot_general(  # [8, N] x [P, N]^T: y[h] on every sublane
+            c_rows, S, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        tiles[h // 8] = jnp.where(sub == h % 8, y, tiles[h // 8])
+    yT_ref[0, 0] = jnp.transpose(jnp.concatenate(tiles, axis=0))[:, :hb]
 
 
 def head_block(H: int, G: int) -> int:

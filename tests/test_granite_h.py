@@ -306,13 +306,21 @@ def test_one_token_by_hand():
     np.testing.assert_allclose(y[0, 0], [3.5 + 10.0, 6.5 + 30.0], rtol=1e-6)
 
 
-@pytest.mark.parametrize("groups", [1, 2])
-def test_ssd_decode_kernel_updates_its_layer_of_the_stack_in_place(groups):
-    """The Pallas kernel (interpreted here) against the XLA step: layer 1 of
-    a three-layer stack, live rows and rows of garbage alike; the other
-    layers' rows are not touched."""
+# (Lm, B, H, P, N): the tiny preset's state, narrower than a lane tile, and
+# a state a lane tile wide whose head block holds 16 (one group) or 8 (two)
+# heads of a group: more than one tile of eight read-outs a grid step.
+KERNEL_SHAPES = {"n32": (3, 3, 8, 16, 32), "n128": (3, 3, 16, 8, 128)}
+# |y - float64 walk| over the largest |y|: float32 products summed over
+# d_state in another order, the dot at HIGHEST (read 1e-7 to 3e-7 here).
+READ_OUT_BOUND = 2e-6
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_case(shape, groups, layer=1):
+    """One draw a (shape, groups): the inputs, the kernel's (interpreted) and
+    the jitted XLA step's (y, state) after updating `layer` of the stack."""
     ks = jax.random.split(jax.random.key(5), 7)
-    Lm, B, H, P, N = 3, 3, 8, 16, 32
+    Lm, B, H, P, N = KERNEL_SHAPES[shape]
     state = jax.random.normal(ks[0], (Lm, B, H, P, N))
     x = jax.random.normal(ks[1], (B, H, P))
     dt = jax.nn.softplus(jax.random.normal(ks[2], (B, H)))
@@ -320,17 +328,80 @@ def test_ssd_decode_kernel_updates_its_layer_of_the_stack_in_place(groups):
     D = jax.random.normal(ks[4], (H,))
     Bm = jax.random.normal(ks[5], (B, groups, N))
     Cm = jax.random.normal(ks[6], (B, groups, N))
-    want_y, want = SSD.ssd_decode(state, jnp.int32(1), x, dt, A, Bm, Cm, D,
-                                  impl="xla")
-    got_y, got = jax.jit(lambda s: SSD.ssd_decode(
-        s, jnp.int32(1), x, dt, A, Bm, Cm, D, impl="pallas"))(state)
+    args = (state, x, dt, A, Bm, Cm, D)
+    got, want = (jax.jit(lambda s, i, impl=impl: SSD.ssd_decode(
+        s, i, x, dt, A, Bm, Cm, D, impl=impl))(state, jnp.int32(layer))
+        for impl in ("pallas", "xla"))
+    return args, got, want
+
+
+GROUPS = pytest.mark.parametrize("groups", [1, 2])
+SHAPES = pytest.mark.parametrize("shape", sorted(KERNEL_SHAPES))
+
+
+@SHAPES
+@GROUPS
+def test_ssd_decode_kernel_updates_its_layer_of_the_stack_in_place(
+        groups, shape):
+    """The Pallas kernel (interpreted here) against the XLA step: layer 1 of
+    a three-layer stack, live rows and rows of garbage alike; the other
+    layers' rows are not touched."""
+    (state, x, dt, A, Bm, Cm, D), (got_y, got), (want_y, want) = _kernel_case(
+        shape, groups)
     np.testing.assert_allclose(got_y, want_y, atol=2e-5)
     np.testing.assert_allclose(got[1], want[1], atol=1e-6)
     np.testing.assert_array_equal(got[0], state[0])
     np.testing.assert_array_equal(got[2], state[2])
     assert SSD.head_block(128, 1) == 64 and SSD.head_block(8, 2) == 4
+    assert SSD.head_block(16, 1) == 16 and SSD.head_block(16, 2) == 8
     with pytest.raises(ValueError, match="auto|pallas|xla"):
         SSD.ssd_decode(state, 1, x, dt, A, Bm, Cm, D, impl="mosaic")
+
+
+@SHAPES
+@GROUPS
+def test_ssd_decode_kernel_reads_the_state_out_at_float32_accuracy(
+        groups, shape):
+    """The kernel's y (the read-out a float32 dot at HIGHEST, on the MXU
+    where there is one) against a float64 `numpy` walk of `ssd_step` over
+    the same inputs, and beside the XLA step's own error."""
+    args, (got_y, _), (want_y, _) = _kernel_case(shape, groups)
+    S, x, dt, A, Bm, Cm, D = (np.asarray(a, np.float64) for a in args)
+    H = S.shape[2]
+    Bh, Ch = (np.repeat(a, H // groups, axis=1) for a in (Bm, Cm))
+    S = (S[1] * np.exp(dt * A)[..., None, None]
+         + (dt[..., None] * x)[..., None] * Bh[:, :, None, :])
+    y = np.einsum("bhpn,bhn->bhp", S, Ch) + D[:, None] * x
+    err, oracle = (float(np.abs(np.asarray(g, np.float64) - y).max()
+                         / np.abs(y).max()) for g in (got_y, want_y))
+    assert err <= READ_OUT_BOUND, (err, oracle)
+    assert err <= max(2.0 * oracle, 5e-7), (err, oracle)
+
+
+@SHAPES
+@GROUPS
+def test_ssd_decode_kernel_writes_the_oracles_state_bit_for_bit(groups, shape):
+    """The update is elementwise float32 in the same order in both forms:
+    the rows the kernel writes are the (jitted) XLA step's to the last bit,
+    whatever reads them out."""
+    _, (_, got), (_, want) = _kernel_case(shape, groups)
+    np.testing.assert_array_equal(got[1], want[1])
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@SHAPES
+def test_ssd_decode_kernel_touches_its_own_layer_alone(shape, layer):
+    """The first and the last layer of the stack as the target: every slot of
+    every OTHER layer keeps its bits, every slot of the layer is updated (a
+    dead slot's row as a live one's), and y is the layer's own."""
+    (state, *_), (got_y, got), (want_y, want) = _kernel_case(shape, 1, layer)
+    for other in range(state.shape[0]):
+        if other != layer:
+            np.testing.assert_array_equal(got[other], state[other])
+    np.testing.assert_array_equal(got[layer], want[layer])
+    assert (np.asarray(got[layer]) != np.asarray(state[layer])).any(
+        axis=(1, 2, 3)).all()  # every slot's row moved
+    np.testing.assert_allclose(got_y, want_y, atol=2e-5)
 
 
 def test_the_kernel_runs_inside_the_hybrid_scan():

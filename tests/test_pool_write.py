@@ -6,7 +6,9 @@ row of the pool is narrower than the native tile; everywhere else XLA's
 scatter stays. Here: the kernel (interpret mode, the code that compiles for
 the chip) against the scatter bit for bit; the rule and its two counters; a
 tp = 2 engine against tp = 1; and the four-chip cell's decode block compiled
-for a described TPU v5e, which is what shows the pool copies gone.
+for a described TPU v5e, which is what shows the pool copies gone. The one
+file that describes the topology also keeps the other kernels' compiles for
+the described chip: the swap gather and `ops/ssd.ssd_decode`'s read-out.
 """
 
 import dataclasses
@@ -335,6 +337,38 @@ def test_mosaic_takes_the_kernel(topo, shape, n, dtype):
     assert cell_program.kernels(text) == {"pool_write": 1}
     assert cell_program.pool_copies(text, shape) == []
     assert "output_to_operand_aliasing" in text
+
+
+@pytest.mark.parametrize("shape,groups", [
+    ((4, 32, 128, 64, 128), 1),  # granite-4.0-h-small's state, 4 layers of it
+    ((3, 3, 16, 8, 128), 2),  # a head block of one tile of read-outs
+    ((3, 4, 8, 16, 32), 1),  # tiny-granite-h: a state under one lane tile
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_mosaic_takes_the_ssd_read_out(topo, shape, groups):
+    """`ops/ssd.ssd_decode` (kept here, in the one file that describes the
+    topology): Mosaic takes the float32 dot at HIGHEST that reads the state
+    out (ISSUE 52) at the published shape and under one lane tile alike, the
+    stacked state aliased, nothing held beside it."""
+    from jax.sharding import SingleDeviceSharding
+
+    from localai_tpu.ops import ssd
+    from tools import cell_program
+
+    one = SingleDeviceSharding(topo.devices[0])
+    _, B, H, P, N = shape
+
+    def sds(*s, d="float32"):
+        return jax.ShapeDtypeStruct(s, jnp.dtype(d), sharding=one)
+
+    with cell_program.as_on_tpu():
+        compiled = jax.jit(ssd.ssd_decode, donate_argnums=(0,)).trace(
+            sds(*shape), sds(d="int32"), sds(B, H, P), sds(B, H), sds(H),
+            sds(B, groups, N), sds(B, groups, N), sds(H),
+        ).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert cell_program.kernels(text) == {"ssd_decode": 1}
+    assert "output_to_operand_aliasing" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 @pytest.mark.parametrize("k,v", [
