@@ -89,6 +89,12 @@ SIZES = {
         # (0.4 GB: the scatter it is held against relays the pool twice)
         mla=dict(layers=47, heads=20, head=256, latent=640, slots=8,
                  pages=20, write_slots=16, write_pages=49, prefill=(512, 2048)),
+        # laguna-xs.2 as published: window layers of 64 query heads over 8
+        # KV heads of 128 whose slots hold a 512-row ring (4 pages; 30 such
+        # layers of 16 slots here, 0.5 GB a pool), full layers of 48 query
+        # heads (6 rows a KV head) over 10 layers of pages
+        swa=dict(layers=30, slots=16, window=512, heads=64, full_heads=48,
+                 full_layers=10, pages=20, prefill=(1024, 2048)),
     ),
     "tiny": dict(
         arch="tiny", slots=4, context=512, page=16,
@@ -106,6 +112,8 @@ SIZES = {
         ssd=dict(layers=3, slots=4, heads=8, P=16, N=32),
         mla=dict(layers=3, heads=5, head=32, latent=128, slots=4,
                  pages=6, write_slots=4, write_pages=17, prefill=(32,)),
+        swa=dict(layers=3, slots=4, window=32, heads=8, full_heads=6,
+                 full_layers=2, pages=6, prefill=(64,)),
     ),
 }
 
@@ -817,6 +825,105 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
              lambda q, k, v, lens: valid_rows(A.causal_prefill_attention(
                  q, k, v, jnp.arange(q.shape[1])[None, :] < lens[:, None]),
                  lens),
+             (*qkv, lens), 2e-2)
+
+    # Laguna-XS.2's shapes (ISSUE 53). The window layers' reader: the paged
+    # walk over a per-slot RING (position p at row p mod 512 of the slot's
+    # four pages, `limits` the positions written so far, a row masked at the
+    # position it holds) at 8 query rows a KV head, the first and the last
+    # of 30 layers; contexts under a ring, exactly one, and some that have
+    # wrapped once and several times, each at a query 0, 7 and 15 steps into
+    # its block (the rows a block is about to replace are dead); an idle
+    # slot. Same arithmetic as paged_decode -> 5e-3.
+    sw = s["swa"]
+    Ls, Bs, Wn = sw["layers"], sw["slots"], sw["window"]
+    rp = -(-Wn // page)
+    ring_k, ring_v = (rnd16((Ls, Bs * rp, page, K, D)) for _ in range(2))
+    ring_tab = jnp.arange(Bs * rp, dtype=jnp.int32).reshape(Bs, rp)
+    ring_n0 = jnp.array(
+        [(0, 1, Wn // 2 + 3, Wn - 1, Wn, Wn + 1, Wn + page - 5, 2 * Wn,
+          2 * Wn + 17, 4 * Wn - 1, 700, 2000)[i % 12] + 3 * (i // 12)
+         for i in range(Bs)], jnp.int32)
+    ring_step = jnp.array([(0, 7, 15)[i % 3] for i in range(Bs)], jnp.int32)
+    ring_step = jnp.where(ring_n0 > 0, ring_step, 0)
+
+    def ring_read(impl):
+        def fn(q, kp, vp, t, n0, step, first, last):
+            return tuple(settled(A.paged_partials(
+                q, Q.StackedLayer(kp, i), Q.StackedLayer(vp, i), t, n0,
+                window=Wn, sliding=np.True_, q_pos=n0 + step, impl=impl,
+                ring=rp * page)) for i in (first, last))
+        return fn
+
+    case(f"window_attention_ring{Wn}_G{sw['heads'] // K}_l{Ls}",
+         ring_read("auto"), ring_read("xla"),
+         (rnd((Bs, sw["heads"], D)), ring_k, ring_v, ring_tab, ring_n0,
+          ring_step, jnp.int32(0), jnp.int32(Ls - 1)), 5e-3)
+    # The ring's write: a block's rows at their positions mod the ring (a
+    # block that wraps the ring's end among them), XLA's scatter over the
+    # donated rings as the engine runs it (8 rows of D a token: stored as
+    # scattered), against the rows placed by hand: the same rings, exactly.
+    rw_n = 16 if page == 128 else 4
+    rw_start = jnp.array(
+        [(0, Wn - rw_n, Wn - rw_n // 2, Wn, 700, 2000 - 3, page - 1,
+          3 * Wn + page)[i % 8] + i // 8 for i in range(Bs)], jnp.int32)
+
+    def ring_write(kp, t, wk, st):
+        return LL.write_block_to_pool(
+            LL.KVCache(kp, kp), t, wk, wk, st, paged_impl="auto", ring=True).k
+
+    def ring_by_hand(kp, t, wk, st):
+        row = (st[:, None] + jnp.arange(wk.shape[2])[None, :]) % (rp * page)
+        pid = jnp.take_along_axis(t, row // page, axis=1)
+        return kp.at[:, pid, row % page].set(wk)
+
+    case(f"ring_write_l{Ls}_n{rw_n}", ring_write, ring_by_hand,
+         (ring_k, ring_tab, rnd((Ls, Bs, rw_n, K, D)), rw_start), 0.0,
+         kernel=False)
+    del ring_k, ring_v
+    # The full layers' reader: the paged walk at 6 query rows a KV head (48
+    # heads over 8: every GQA cell so far has 1, 4 or 8), 48 query rows a
+    # slot, no multiple of 32; contexts of 150 to 2,560 tokens over the
+    # first and the last of 10 layers. Same arithmetic -> 5e-3.
+    Lf, fp = sw["full_layers"], sw["pages"]
+    f_k, f_v = (rnd16((Lf, Bs * fp + 1, page, K, D)) for _ in range(2))
+    f_tab = (jax.random.permutation(next(keys), Bs * fp) + 1).reshape(
+        Bs, fp).astype(jnp.int32)
+    f_lim = jnp.array([(i * 61 + 17) % (fp * page - 150) + 150
+                       for i in range(Bs)], jnp.int32).at[0].set(0)
+
+    def full_read(impl):
+        def fn(q, kp, vp, t, lim, first, last):
+            return tuple(settled(A.paged_partials(
+                q, Q.StackedLayer(kp, i), Q.StackedLayer(vp, i), t, lim,
+                impl=impl)) for i in (first, last))
+        return fn
+
+    case(f"paged_decode_G{sw['full_heads'] // K}_l{Lf}",
+         full_read("auto"), full_read("xla"),
+         (rnd((Bs, sw["full_heads"], D)), f_k, f_v, f_tab, f_lim,
+          jnp.int32(0), jnp.int32(Lf - 1)), 5e-3)
+    del f_k, f_v
+    # The prefill's flash kernel under the window, at the window layers' 64
+    # heads over 8: prompts two and four windows long (what the check and a
+    # preempted request admit), against the dense form under the same mask.
+    # Same rounding as flash_prefill_S* -> 2e-2.
+    for S in sw["prefill"]:
+        lens = jnp.array([S, max(1, S - 7)], jnp.int32)
+        qkv = (rnd((2, S, sw["heads"], D)), rnd((2, S, K, D)),
+               rnd((2, S, K, D)))
+
+        def valid_rows(out, lens, S=S):
+            return jnp.where((jnp.arange(S)[None, :] < lens[:, None])[
+                :, :, None, None], out, 0)
+
+        case(f"flash_prefill_window{Wn}_h{sw['heads']}_S{S}",
+             lambda q, k, v, lens: valid_rows(A.prefill_attention(
+                 q, k, v, None, lengths=lens, window=Wn, sliding=np.True_),
+                 lens),
+             lambda q, k, v, lens: valid_rows(A.causal_prefill_attention(
+                 q, k, v, jnp.arange(q.shape[1])[None, :] < lens[:, None],
+                 window=Wn, sliding=jnp.bool_(True)), lens),
              (*qkv, lens), 2e-2)
 
     # The held experts' stacks [26 x 32, 2304, 1024] at 64 rows through the

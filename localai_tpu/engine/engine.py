@@ -1583,6 +1583,14 @@ class Engine:
         if cfg.is_hybrid and self.ecfg.prefix_cache_entries > 0:
             self._jstage("prefix_reuse_off",
                          a=float(self.ecfg.prefix_cache_entries))
+        if cfg.recurrent_kind == "swa":
+            # The two lengths of this model's K/V, once: the window layers'
+            # rows a slot (and all slots' bytes), the full layers' pages.
+            self._jstage("window_state",
+                         a=float(cfg.ring_rows),
+                         b=float(self._slot_state_bytes()))
+            self._jstage("kv_pool", a=float(self.ecfg.kv_pages), b=float(
+                (self.ecfg.kv_pages + 1) * self._page_bytes()))
         # Pipelined loop runtime (ISSUE 17, docs/ENGINE_RUNTIME.md).
         # thread: single-writer engine-loop — per-iteration host-phase
         # accumulator feeding the coalesced loop_iter journal emission.
@@ -1634,6 +1642,8 @@ class Engine:
         self.m_moe_admit_rows = 0
         self.m_moe_admit_rows_held = 0
         self.m_state_restores = 0  # recurrent-state rows recomputed (preempt)
+        self.m_window_rows_read = 0  # rows a window layer's reader walked
+        self.m_window_rows_full = 0  # ... and would have at full length
         self.m_admit_splits = 0  # admission groups cut by state.admit_rows
         # Admission programs dispatched (full, cached tail, chunk), the rows
         # they were compiled for and the prompt tokens in them (_count_admit).
@@ -2046,8 +2056,17 @@ class Engine:
                 self._free_pages.append(p)
 
     def _page_bytes(self) -> int:
-        """Host/device bytes of one page's K+V rows across all layers."""
+        """Host/device bytes of one page's K+V rows across all layers that
+        hold pages (a hybrid model's cache layers: a window model's full
+        layers alone)."""
         return self._prefix_span_bytes(self.ecfg.kv_page_size)
+
+    def _slot_state_bytes(self) -> int:
+        """Bytes of a hybrid model's per-slot state over all slots (the
+        recurrent rows, or the window layers' rings): fixed, whatever the
+        contexts (engine/state.py)."""
+        return self.ecfg.max_slots * rstate.row_bytes(
+            self.cfg, self.cache.conv.dtype)
 
     def _pages_grow_slot(self, slot_idx: int, need_pages: int) -> bool:
         """Extend a live slot's table to `need_pages` total pages — a HOST
@@ -3098,8 +3117,10 @@ class Engine:
                      cfg.cache_v_dim), ldt_v,
                 )
             # A hybrid model's recurrent state is carried by the steps (each
-            # updates every row in place) while the pool stays read-only.
-            rec0 = (cache.state, cache.conv) if cfg.is_hybrid else None
+            # updates every row in place) while the pool stays read-only; a
+            # window kind's rings stay read-only too, beside the block's rows.
+            rec0 = (llama.block_recurrent(cfg, cache, B, n)
+                    if cfg.is_hybrid else None)
             if rec0 is not None:
                 cache = cache._replace(state=None, conv=None)
 
@@ -3200,7 +3221,9 @@ class Engine:
             else:
                 cache = llama.write_block_to_cache(cache, local_k, local_v, start_pos)
             if rec is not None:
-                cache = cache._replace(state=rec[0], conv=rec[1])
+                cache = llama.block_recurrent_done(
+                    cfg, cache, rec, start_pos,
+                    paged_impl=self.ecfg.paged_kernel)
             toks_block = outs[0]  # [n, B]
             tk_block = outs[1] if variant == "grammar" else None
             lp_block = tuple(outs[-3:]) if with_lp else None  # ([n,B],[n,B,LK],[n,B,LK])
@@ -4820,7 +4843,7 @@ class Engine:
             raise ValueError("fork n must be >= 1")
         if self.cfg.is_hybrid:
             raise ValueError(
-                f"{self.cfg.name} keeps a per-slot recurrent state "
+                f"{self.cfg.name} keeps a per-slot {rstate.what(self.cfg)} "
                 f"({self.cfg.recurrent_kind} layers): forking a live stream "
                 "would need a copy of the source's state row, which this "
                 "engine does not make")
@@ -6406,11 +6429,15 @@ class Engine:
                 out["moe_picks_here"] = float(self.m_moe_picks_here)
                 out["moe_admit_rows"] = float(self.m_moe_admit_rows)
                 out["moe_admit_rows_held"] = float(self.m_moe_admit_rows_held)
-        if self.cfg.is_hybrid:
+        if self.cfg.recurrent_kind == "swa":
+            # The window layers' rings beside the full layers' pages.
+            out["window_state_bytes"] = float(self._slot_state_bytes())
+            out["window_rows_read"] = float(self.m_window_rows_read)
+            out["window_rows_full"] = float(self.m_window_rows_full)
+        elif self.cfg.is_hybrid:
             # The second kind of per-slot state (engine/state.py).
-            out["recurrent_state_bytes"] = float(
-                self.ecfg.max_slots * rstate.row_bytes(
-                    self.cfg, self.cache.conv.dtype))
+            out["recurrent_state_bytes"] = float(self._slot_state_bytes())
+        if self.cfg.is_hybrid:
             out["state_restores"] = float(self.m_state_restores)
             out["prefix_reuse_off"] = float(
                 self.ecfg.prefix_cache_entries > 0)
@@ -8275,11 +8302,22 @@ class Engine:
             self._host_copy_async(tk_block)
         self.h_override_mask[:] = False
         held = 0  # pool rows the live slots hold as the block starts
+        ringed = 0  # ... and of those, the rows inside a window layer's ring
+        ring = self.cfg.ring_rows if self.cfg.recurrent_kind == "swa" else 0
         for i in range(self.ecfg.max_slots):
             if active_snapshot[i] and self.slots[i] is not None:
                 held += self.slots[i].sched_rows
+                ringed += min(self.slots[i].sched_rows, ring)
                 self.slots[i].scheduled += n
                 self.slots[i].sched_rows += n
+        if ring:
+            # What ONE window layer's reader walks in this block and what it
+            # would have walked at full length: a slot's rows at dispatch,
+            # cut to the ring, every step (the block's own ride in its
+            # window).
+            self.m_window_rows_read += n * ringed
+            self.m_window_rows_full += n * held
+            self._jnote("window_rows", a=float(n * ringed), b=float(n * held))
         if self.cfg.is_mla and self._paged:
             # What the latent walk reads: every step of the block walks the
             # rows its slots held at dispatch (the block's own rows ride in

@@ -16,6 +16,30 @@ chunked prefill holds a prompt token. It needs no allocator: row i of the
 arrays below belongs to slot index i,
 always.
 
+The fourth kind is no recurrence: a sliding-window attention layer ("swa",
+Laguna's `sliding_attention`) attends the last `sliding_window` positions and
+nothing before them, so what it keeps a slot is a RING of that many rows of
+keys and values, whatever the context: position p lives in row p mod the
+ring's rows, page-shaped (`state` the keys, `conv` the values, each
+[Ls, slots x ring_pages, ring_page, K, D] in the cache's dtype; slot i owns
+pages ring_pages·i .. of every window layer, a table that never changes:
+`llama.ring_table`). Keys are stored rotated, so a reader needs to know which
+rows are live and at which position each is masked, not their order: the
+paged reader walks a ring as it walks a slot's pages (`ops/paged_flash`,
+`ring_rows`). The KV manager's pages are the full layers' alone. Its
+lifecycle is the recurrent kinds', with one difference in `decode`:
+
+- claim:    the admission program writes the prompt's last min(n, ring) rows
+            of every window layer (`llama._ring_admit`).
+- decode:   a block READS the ring as it stood at its start beside its own
+            rows (`llama.block_recurrent`) and writes those rows at its end,
+            at their positions mod the ring (`llama.block_recurrent_done`):
+            the n rows they replace are the oldest, outside the window of
+            every query after the block. A block is no longer than the ring
+            (`refuse`). Idle and parked rows are written too, garbage into
+            a ring no tenant reads.
+- park, release, preempt: as below.
+
 The arrays ride in the cache pytree (`llama.KVCache.state`, `.conv`), so every
 program that carries the cache carries them, donated with it, and the
 device's order of programs is the order of their owners:
@@ -104,23 +128,50 @@ def _mla_token_bytes(cfg) -> int:
     return rows + qkv + moe
 
 
+def _swa_token_bytes(cfg) -> int:
+    """What an admission of a window / full attention MoE model holds a
+    prompt token: the FULL layers' K/V rows of every such layer until
+    `write_prefill_to_pool` has them (40 KB at 10 layers of 8 heads of 128;
+    the window layers' rows go into the rings layer by layer), q, k and v
+    of the layer at work at the wider kind's head count (20 KB at 64 + 16
+    heads), the grouped expert path's [rows, top-k, D] float32 rows in and
+    out and its two [rows, top-k, F] intermediates (160 KB at top-8 of 2048
+    and 512) and a dozen [rows, D] float32 rows of the layer at work (the
+    stream, its norms, the projections' outputs: 96 KB): 323 KB, 3,318 rows
+    a program, so 4 prompts of the 512 bucket (tools/cell_program.py
+    --program admit for a described v5e: 1.29 GB of temporaries at 8 x 512
+    rows, 315 KB a token, beside 14.9 GB held)."""
+    size = 2
+    rows = cfg.cache_layers * 2 * cfg.num_kv_heads * cfg.head_dim_ * size
+    qkv = (max(cfg.num_heads, cfg.swa_heads) + 2 * cfg.num_kv_heads
+           ) * cfg.head_dim_ * size
+    k = cfg.num_experts_per_token if cfg.is_moe else 0
+    moe = 2 * k * (cfg.hidden_size + cfg.moe_inter_size) * 4
+    return rows + qkv + moe + 12 * cfg.hidden_size * 4
+
+
 # Bytes of temporaries one prompt token costs an admission program, by
 # recurrent kind, or "mla" for a model of latent attention in every layer; a
 # kind that is not here is not bounded (a conv model's prefill holds a few
 # [T, D] rows a prompt, as any layer's).
 _ADMIT_TOKEN_BYTES = {"kda": _kda_token_bytes, "ssd": _ssd_token_bytes,
-                      "mla": _mla_token_bytes}
+                      "mla": _mla_token_bytes, "swa": _swa_token_bytes}
 
 
 def admit_rows(cfg) -> int | None:
     """Most prompt rows (requests x bucket) one admission program takes under
     `ADMIT_BYTES`, from the model's own widths (`_ADMIT_TOKEN_BYTES`): 2,048
     rows at KDA's 32 heads of 128, 1,024 at 64; 2,048 at SSD's 128 heads of
-    64 x 128; 6,864 at GLM-4.7-Flash's 47 latent layers. None for a kind
-    without a bound."""
+    64 x 128; 6,864 at GLM-4.7-Flash's 47 latent layers; 3,318 at Laguna's
+    window and full layers. None for a kind without a bound."""
     kind = cfg.recurrent_kind or ("mla" if cfg.is_mla else "")
     per_token = _ADMIT_TOKEN_BYTES.get(kind)
     return max(1, ADMIT_BYTES // per_token(cfg)) if per_token else None
+
+
+def what(cfg) -> str:
+    """What a slot of this model keeps beside its cache rows, in words."""
+    return "window rows" if cfg.recurrent_kind == "swa" else "recurrent state"
 
 
 def refuse(cfg, ecfg, plan, draft_cfg, spec_mode: str) -> None:
@@ -143,9 +194,22 @@ def refuse(cfg, ecfg, plan, draft_cfg, spec_mode: str) -> None:
         no.append("windowed+sink attention and page spill")
     if float(ecfg.kv_scale) != 1.0:
         no.append("a scaled fp8 pool (kv_scale != 1)")
+    if cfg.recurrent_kind == "swa":
+        ring = cfg.ring_rows
+        if ring & (ring - 1):
+            no.append(f"a ring of {ring} rows (sliding_window "
+                      f"{cfg.sliding_window}: the reader maps a row to its "
+                      "position by a power of two)")
+        if max(ecfg.block_sizes) > ring:
+            no.append(f"decode blocks of {max(ecfg.block_sizes)} steps (a "
+                      f"block's rows replace the ring's oldest: at most "
+                      f"{ring})")
+        if jnp.dtype(ecfg.cache_dtype(cfg.dtype)).itemsize < 2:
+            no.append("an 8-bit cache (the rings are held in the model's "
+                      "dtype)")
     if no:
         raise ValueError(
-            f"{cfg.name} keeps a per-slot recurrent state "
+            f"{cfg.name} keeps a per-slot {what(cfg)} "
             f"({cfg.recurrent_kind} layers, "
             f"{row_bytes(cfg, cfg.dtype)} bytes a slot) beside its "
             f"{'latent' if cfg.is_mla else 'K/V'} cache rows; this engine "
@@ -166,7 +230,14 @@ def _ssd_rows(cfg, Lk: int, slots: int):
             (Lk, slots, cfg.mamba_conv - 1, cfg.mamba_conv_dim))
 
 
-_ROWS = {"kda": _kda_rows, "conv": _conv_rows, "ssd": _ssd_rows}
+def _swa_rows(cfg, Ls: int, slots: int):
+    ring = (Ls, slots * cfg.ring_pages, cfg.ring_page, cfg.num_kv_heads,
+            cfg.head_dim_)
+    return ring, ring  # the keys' rings, the values'
+
+
+_ROWS = {"kda": _kda_rows, "conv": _conv_rows, "ssd": _ssd_rows,
+         "swa": _swa_rows}
 
 
 def _shapes(cfg, slots: int):
@@ -178,17 +249,25 @@ def allocate(cfg, slots: int, conv_dtype, sharding=None):
     """(state [Lk, slots, H, dk, dv] f32, conv [Lk, slots, c-1, 3·H·dk]) of a
     KDA model; (None, conv [Lc, slots, conv_cache-1, D]) of a conv model;
     (state [Lm, slots, H, P, N] f32, conv [Lm, slots, c-1, d_inner + 2·G·N])
-    of an SSD model."""
+    of an SSD model; a window model's rings, (keys, values)
+    [Ls, slots·ring_pages, ring_page, K, D] each, both in `conv_dtype`."""
     st, cv = _shapes(cfg, slots)
-    rows = (None if st is None else jnp.zeros(st, jnp.float32),
+    rows = (None if st is None else jnp.zeros(st, _state_dtype(cfg, conv_dtype)),
             jnp.zeros(cv, conv_dtype))
     if sharding is not None:  # None has no leaf to put
         rows = jax.device_put(rows, sharding)
     return rows
 
 
+def _state_dtype(cfg, conv_dtype):
+    """A recurrence's matrix is float32; a ring's keys are rows like its
+    values."""
+    return conv_dtype if cfg.recurrent_kind == "swa" else jnp.float32
+
+
 def row_bytes(cfg, conv_dtype) -> int:
     """Bytes of one slot's row over all recurrent layers."""
     st, cv = _shapes(cfg, 1)
-    return ((math.prod(st) * 4 if st else 0)
+    size = jnp.dtype(_state_dtype(cfg, conv_dtype)).itemsize
+    return ((math.prod(st) * size if st else 0)
             + math.prod(cv) * jnp.dtype(conv_dtype).itemsize)

@@ -200,6 +200,14 @@ def load_hf_checkpoint(
     stacked tensor ON THE HOST before placement/quantization — LoRA and the
     int8/int4 HBM envelope compose (merge first, then quantize, one pass).
     """
+    if cfg.recurrent_kind == "swa":
+        # The config keys are read (`_arch_from_laguna`); the tensors have no
+        # name list here yet, and a guessed one would load a wrong model.
+        raise ValueError(
+            f"{cfg.name}: a `laguna` checkpoint's tensors are not loaded yet "
+            "(no tensor-name list is in this repository: the per-kind "
+            "q_proj / o_proj / gate shapes, the router's bias); serve the "
+            "preset with synthetic weights")
     dt = jnp.dtype(cfg.dtype)
     reader = _ShardReader(ckpt_dir)
     if put is None:
@@ -1149,6 +1157,80 @@ def _save_deepseek(cfg: ArchConfig, params: Params, ckpt_dir: str,
         json.dump(hf_config, f, indent=1)
 
 
+def _arch_from_laguna(hf: dict) -> ArchConfig:
+    """poolside's `laguna` config keys: `layer_types` in periods that begin
+    with their `full_attention` layer, `num_attention_heads_per_layer` (one
+    count a kind), `rope_parameters` by layer type, `mlp_layer_types` (a
+    dense prefix, then sparse), `num_experts`, `moe_routed_scaling_factor`,
+    `shared_expert_intermediate_size`, `gating`. What the keys name and do
+    not define (the gate's operand, the router's scoring) is this
+    repository's reading: benchmark/configs/laguna-xs.2-int8-ep8.json,
+    `assumed`."""
+    lt = list(hf["layer_types"])
+    heads = list(hf["num_attention_heads_per_layer"])
+    kinds = tuple({"full_attention": "gqa", "sliding_attention": "swa"}[t]
+                  for t in lt)
+    by_kind = {k: {h for h, kk in zip(heads, kinds) if kk == k}
+               for k in ("gqa", "swa")}
+    if any(len(v) != 1 for v in by_kind.values()):
+        raise ValueError(f"laguna: one head count a layer type, got {by_kind}")
+    mlp = list(hf["mlp_layer_types"])
+    dense = mlp.index("sparse") if "sparse" in mlp else len(mlp)
+    if any(t != "sparse" for t in mlp[dense:]):
+        raise ValueError("laguna: dense MLPs after the first sparse one")
+    rp = hf["rope_parameters"]
+    full, local = rp["full_attention"], rp["sliding_attention"]
+    if (local.get("rope_type", "default") != "default"
+            or float(local.get("partial_rotary_factor", 1)) != 1.0):
+        raise ValueError("laguna: window layers rotate the whole head, unscaled")
+    if hf.get("moe_apply_router_weight_on_input"):
+        raise ValueError("laguna: router weights on the expert's input")
+    Fm = hf["moe_intermediate_size"]
+    shared = int(hf.get("shared_expert_intermediate_size") or 0)
+    if shared % Fm:
+        raise ValueError("laguna: a shared expert of another width")
+    yarn = full.get("rope_type") == "yarn"
+    return ArchConfig(
+        name=hf.get("_name_or_path", "laguna") or "laguna",
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=by_kind["gqa"].pop(),
+        swa_heads=by_kind["swa"].pop(),
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf.get("head_dim"),
+        max_position=hf.get("max_position_embeddings", 8192),
+        rms_eps=hf.get("rms_norm_eps", 1e-6),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        rope_theta=float(full["rope_theta"]),
+        rope_scaling="yarn" if yarn else None,
+        rope_scaling_factor=float(full.get("factor", 1.0)),
+        rope_original_max_position=int(
+            full.get("original_max_position_embeddings")
+            or rp.get("original_max_position_embeddings") or 4096),
+        rope_beta_fast=float(full.get("beta_fast", 32.0)),
+        rope_beta_slow=float(full.get("beta_slow", 1.0)),
+        rope_attn_factor=(float(full["attention_factor"])
+                          if full.get("attention_factor") is not None else None),
+        partial_rotary=float(full.get("partial_rotary_factor", 1.0)),
+        rope_local_theta=float(local["rope_theta"]),
+        sliding_window=int(hf["sliding_window"]),
+        attn_gate="head" if hf.get("gating") else False,
+        layer_kinds=kinds,
+        moe_family="deepseek",
+        num_experts=hf["num_experts"],
+        num_experts_per_token=hf["num_experts_per_tok"],
+        first_k_dense=dense,
+        n_shared_experts=shared // Fm,
+        moe_intermediate_size=Fm,
+        routed_scaling_factor=float(hf.get("moe_routed_scaling_factor", 1.0)),
+        scoring_func="sigmoid",
+        router_bias=True,
+        norm_topk_prob=True,
+    )
+
+
 def arch_from_hf_config(ckpt_dir: str) -> ArchConfig:
     """Build an ArchConfig from an HF config.json
     (llama/mistral/qwen2/mixtral/gemma/gemma-2/gemma-3/phi3), including every
@@ -1189,6 +1271,8 @@ def arch_from_hf_config(ckpt_dir: str) -> ArchConfig:
     if attn_factor is None:
         attn_factor = rope_scaling.get("mscale")
     model_type = hf.get("model_type", "llama")
+    if model_type == "laguna":
+        return _arch_from_laguna(hf)
     gemma3 = model_type in ("gemma3", "gemma3_text")
     gemma = model_type in ("gemma", "gemma2") or gemma3
     gemma2 = model_type == "gemma2"

@@ -14,8 +14,11 @@ import dataclasses
 from typing import Optional
 
 
-# The layer kinds that keep a per-slot recurrent state and write no cache row.
-RECURRENT_KINDS = ("kda", "conv", "ssd")
+# The layer kinds that keep a per-slot state of fixed size and write no row to
+# the paged cache: the three recurrent ones and "swa", a sliding-window
+# attention layer, whose state is a ring of its last `sliding_window` keys
+# and values (engine/state.py).
+RECURRENT_KINDS = ("kda", "conv", "ssd", "swa")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,9 +87,17 @@ class ArchConfig:
     final_softcap: float = 0.0
     query_scale: float = 0.0  # 0 = default head_dim^-0.5
     sliding_window: int = 0  # 0 = full attention on every layer
-    # Which layers slide: layer li is sliding iff li % pattern != pattern-1.
-    # Gemma-2 alternates (2); gemma-3 runs 5 local : 1 global (6).
+    # Which layers slide: layer li attends globally iff li % pattern ==
+    # `sliding_phase`, and slides otherwise. Gemma-2 alternates (2); gemma-3
+    # runs 5 local : 1 global (6); both END a period with its global layer,
+    # which is what a phase of None means (pattern - 1). A pattern of 1 with
+    # a phase no layer has (`kind_view("swa")`): every layer slides.
     sliding_pattern: int = 2
+    sliding_phase: Optional[int] = None
+    # The share of each head that is rotated: its leading
+    # head_dim x partial_rotary lanes, in half-split pairs over those lanes
+    # alone; the rest pass through (and carry no yarn amplitude).
+    partial_rotary: float = 1.0
     # Gemma-3: per-head RMS norms on q and k (after projection, before rope).
     qk_norm: bool = False
     # OLMoE: ONE RMS norm over the whole q projection (all heads, weight
@@ -183,10 +194,12 @@ class ArchConfig:
     # GQA whose q and k are never rotated (Solar-Open2's `use_rope` false):
     # `rope_theta` then has nothing to act on.
     attn_rope: bool = True
-    # GQA output gate (Solar-Open2's `use_gqa_gate`): the attention output is
-    # multiplied, element by element over heads x head width, by
-    # sigmoid(x W_g) of the layer's normed input before W_o ("wg" [D, H·Hd]).
-    attn_gate: bool = False
+    # GQA output gate: the attention output is multiplied by sigmoid(x W_g)
+    # of the layer's normed input before W_o. Two forms: True (or
+    # "element"; Solar-Open2's `use_gqa_gate`), element by element over heads
+    # x head width ("wg" [D, H·Hd]); "head" (Laguna's `gating`), one scalar
+    # a head ("wg_head" [D, H], held in the model's dtype).
+    attn_gate: "bool | str" = False
     # Hybrid linear attention (Kimi-Linear, arXiv:2510.26692): one kind per
     # layer, "kda" (Kimi Delta Attention: a per-slot recurrent state
     # [kda_heads, kda_head_dim, kda_head_dim] f32 plus the short conv's last
@@ -202,9 +215,17 @@ class ArchConfig:
     # "ssd" (Mamba-2's state-space duality layer, Granite-4.0-H) is the third:
     # its per-slot state a [mamba_heads, mamba_head_dim, mamba_d_state] f32
     # matrix a layer with a SCALAR decay a head, and the conv's last
-    # mamba_conv-1 inputs [mamba_conv-1, d_inner + 2·groups·d_state]. One
-    # model has one recurrent kind (`recurrent_kind`).
+    # mamba_conv-1 inputs [mamba_conv-1, d_inner + 2·groups·d_state].
+    # "swa" (Laguna's `sliding_attention`) is the fourth, and no recurrence:
+    # a GQA layer of `swa_heads` query heads over the model's KV heads that
+    # attends the last `sliding_window` positions, rotated at
+    # `rope_local_theta` unscaled over the whole head; its per-slot state is
+    # the ring of those positions' keys and values (`ring_pages` pages of
+    # `ring_page` rows), and its weights have their own stack because its
+    # head count is not the cache layers'. One model has one such kind
+    # (`recurrent_kind`).
     layer_kinds: tuple = ()
+    swa_heads: int = 0
     # LFM2's conv_L_cache: the taps of the short conv. Neither the taps nor
     # the two projections have a bias (the published `conv_bias` is false in
     # every LFM2 config; there is no field for a value nothing here computes)
@@ -264,6 +285,41 @@ class ArchConfig:
                 f"{sorted(kinds)}; one model has one (a stack of two "
                 "would need two per-slot states and two scans)")
         return next(iter(kinds), "")
+
+    def kind_view(self, kind: str) -> "ArchConfig":
+        """The config as the ONE decoder layer body reads it for the layers
+        of `kind` in a model whose attention layers differ by kind (a "swa"
+        model): the kind's head count, rope schedule and window, nothing
+        else changed. Any other model, and any other kind, reads itself."""
+        if self.recurrent_kind != "swa":
+            return self
+        if kind == "swa":  # every layer slides, rotated whole at the local base
+            return dataclasses.replace(
+                self, num_heads=self.swa_heads, sliding_pattern=1,
+                sliding_phase=1, rope_theta=self.rope_local_theta,
+                rope_local_theta=0.0, rope_scaling=None, partial_rotary=1.0)
+        return dataclasses.replace(  # the full layers: no window at all
+            self, sliding_window=0, rope_local_theta=0.0)
+
+    @property
+    def ring_page(self) -> int:
+        """Rows of one page of a "swa" layer's per-slot ring."""
+        return min(128, self.sliding_window)
+
+    @property
+    def ring_pages(self) -> int:
+        """Pages a slot's ring holds: `sliding_window` rows, rounded up."""
+        return -(-self.sliding_window // self.ring_page)
+
+    @property
+    def ring_rows(self) -> int:
+        """Rows a slot's ring holds in each window layer."""
+        return self.ring_pages * self.ring_page
+
+    @property
+    def rotary_dim(self) -> int:
+        """The leading lanes of a GQA head that rope rotates."""
+        return int(self.head_dim_ * self.partial_rotary)
 
     @property
     def recurrent_layers(self) -> tuple:
@@ -560,6 +616,50 @@ PRESETS: dict[str, ArchConfig] = {
         n_shared_experts=1,
         moe_intermediate_size=40,
         routed_scaling_factor=1.0,
+        scoring_func="sigmoid",
+        router_bias=True,
+        norm_topk_prob=True,
+    ),
+    "tiny-laguna-xs.2": ArchConfig(
+        # Laguna-XS.2-shaped tiny: two periods of 1 full : 3 window layers
+        # with the full layer LEADING its period, 6 query heads in the full
+        # layers and 8 in the window ones over 2 KV heads, a window of 16
+        # (one ring page a slot), the full layers rotating half a head under
+        # YaRN and the window layers the whole head at their own base, a
+        # per-head output gate, layer 0 dense AND a cache layer, 16 experts
+        # top-4 (sigmoid, selection bias, one group, renormalised, x2.5)
+        # beside a shared one, untied head.
+        name="tiny-laguna-xs.2",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=8,
+        num_heads=6,
+        swa_heads=8,
+        num_kv_heads=2,
+        head_dim=16,
+        max_position=512,
+        rms_eps=1e-6,
+        rope_theta=500000.0,
+        rope_scaling="yarn",
+        rope_scaling_factor=64.0,
+        rope_original_max_position=4096,
+        rope_beta_fast=64.0,
+        rope_beta_slow=1.0,
+        rope_attn_factor=1.4158883083359672,
+        partial_rotary=0.5,
+        rope_local_theta=10000.0,
+        sliding_window=16,
+        attn_gate="head",
+        routed_down_gain=0.1,
+        layer_kinds=("gqa", "swa", "swa", "swa") * 2,
+        moe_family="deepseek",
+        num_experts=16,
+        num_experts_per_token=4,
+        first_k_dense=1,
+        n_shared_experts=1,
+        moe_intermediate_size=32,
+        routed_scaling_factor=2.5,
         scoring_func="sigmoid",
         router_bias=True,
         norm_topk_prob=True,
@@ -916,6 +1016,55 @@ PRESETS: dict[str, ArchConfig] = {
         n_shared_experts=1,
         moe_intermediate_size=1280,
         routed_scaling_factor=1.0,
+        scoring_func="sigmoid",
+        router_bias=True,
+        norm_topk_prob=True,
+        n_group=1,
+        topk_group=1,
+    ),
+    "laguna-xs.2": ArchConfig(
+        # poolside/Laguna-XS.2 config.json (`laguna`, 33.4B-A3B): 40 layers
+        # in periods of one full-attention layer (48 query heads, half of
+        # each head rotated under YaRN: theta 5e5, factor 64 from 4,096,
+        # beta 64 / 1, amplitude 1.4158883) and three sliding-window layers
+        # (64 query heads, a window of 512, the whole head rotated at theta
+        # 1e4 unscaled), 8 KV heads of 128 everywhere, a per-head sigmoid
+        # output gate (`gating`); layer 0 a dense SwiGLU of 8192, layers
+        # 1-39 256 experts of 512 top-8 (sigmoid, selection bias, one group,
+        # renormalised, x2.5 on the output) plus one shared expert of 512;
+        # untied head. The window layers hold 512 rows a slot in a ring
+        # (engine/state.py), the full layers pages.
+        name="laguna-xs.2",
+        vocab_size=100352,
+        hidden_size=2048,
+        intermediate_size=8192,
+        num_layers=40,
+        num_heads=48,
+        swa_heads=64,
+        num_kv_heads=8,
+        head_dim=128,
+        max_position=262144,
+        rms_eps=1e-6,
+        rope_theta=500000.0,
+        rope_scaling="yarn",
+        rope_scaling_factor=64.0,
+        rope_original_max_position=4096,
+        rope_beta_fast=64.0,
+        rope_beta_slow=1.0,
+        rope_attn_factor=1.4158883083359672,
+        partial_rotary=0.5,
+        rope_local_theta=10000.0,
+        sliding_window=512,
+        attn_gate="head",
+        routed_down_gain=0.1,
+        layer_kinds=("gqa", "swa", "swa", "swa") * 10,
+        moe_family="deepseek",
+        num_experts=256,
+        num_experts_per_token=8,
+        first_k_dense=1,
+        n_shared_experts=1,
+        moe_intermediate_size=512,
+        routed_scaling_factor=2.5,
         scoring_func="sigmoid",
         router_bias=True,
         norm_topk_prob=True,

@@ -29,7 +29,8 @@ import jax.numpy as jnp
 from localai_tpu.models import quant
 from localai_tpu.models.config import ArchConfig
 from localai_tpu.models.quant import matmul, unembed_matmul
-from localai_tpu.observe.scopes import CONV_MIX, SSD_MIX, scope
+from localai_tpu.observe.scopes import (
+    CONV_MIX, RING_WRITE, SSD_MIX, WINDOW_MIX, scope)
 from localai_tpu.ops.attention import (
     _merge_partials_mq,
     decode_attention,  # noqa: F401 — public, used by tests/benchmarks
@@ -144,7 +145,9 @@ def _init_attn_layers(cfg: ArchConfig, rnd, keys, L: int,
     layers["wv"] = rnd(next(keys), (L, D, K * Hd))
     layers["wo"] = rnd(next(keys), (L, H * Hd, D),
                        init_gain(cfg, "wo", (L, H * Hd, D)))
-    if cfg.attn_gate:
+    if cfg.attn_gate == "head":  # a scalar a head: small, never quantized
+        layers["wg_head"] = rnd(next(keys), (L, D, H))
+    elif cfg.attn_gate:  # element by element
         layers["wg"] = rnd(next(keys), (L, D, H * Hd))
     if cfg.post_norms:
         layers["post_attn_norm"] = jnp.ones((L, D), dt)
@@ -878,11 +881,15 @@ def _attn_out(cfg: ArchConfig, lp: Params, attn_flat: jnp.ndarray,
 def _attn_gated(cfg: ArchConfig, lp: Params, x: jnp.ndarray,
                 attn_flat: jnp.ndarray, mesh=None) -> jnp.ndarray:
     """The output gate of gated attention (`cfg.attn_gate`): the attention
-    output times sigmoid(x W_g), element by element over heads x head width,
-    x the layer's normed input; what `_attn_out` then projects."""
-    g = matmul(x, lp["wg"], cfg.quant_kernel, mesh, "col")
-    return (attn_flat.astype(jnp.float32)
-            * jax.nn.sigmoid(g.astype(jnp.float32))).astype(attn_flat.dtype)
+    output times sigmoid(x W_g), element by element over heads x head width
+    or ("head") one scalar a head, x the layer's normed input; what
+    `_attn_out` then projects."""
+    head = cfg.attn_gate == "head"
+    g = jax.nn.sigmoid(matmul(x, lp["wg_head" if head else "wg"],
+                              cfg.quant_kernel, mesh, "col").astype(jnp.float32))
+    if head:  # [..., H] over [..., H·Hd]
+        g = jnp.repeat(g, cfg.head_dim_, axis=-1)
+    return (attn_flat.astype(jnp.float32) * g).astype(attn_flat.dtype)
 
 
 def _mlp_out(cfg: ArchConfig, lp: Params, x: jnp.ndarray, ep: int = 1,
@@ -896,14 +903,23 @@ def _mlp_out(cfg: ArchConfig, lp: Params, x: jnp.ndarray, ep: int = 1,
 
 
 def _layer_sliding(cfg: ArchConfig, li: jnp.ndarray):
-    """Which layers slide: li % pattern != pattern-1. Gemma-2 alternates
-    (pattern 2: even layers slide, odd attend globally); gemma-3 runs
-    5 local : 1 global (pattern 6). Returns a traced bool scalar (or None
-    when the arch has no sliding windows)."""
+    """Which layers slide: all but those with li % pattern == phase, the
+    config's `sliding_phase` (None: pattern - 1, the period ENDS with its
+    global layer. Gemma-2 alternates, pattern 2: even layers slide, odd
+    attend globally; gemma-3 runs 5 local : 1 global, pattern 6). Returns a
+    traced bool scalar, None when the arch has no sliding windows, and a
+    static `np.True_` where every layer slides (pattern 1: a window kind's
+    view of its model, `ArchConfig.kind_view`), which an op may choose its
+    kernel by."""
     if not cfg.sliding_window:
         return None
     p = cfg.sliding_pattern
-    return (li % p) != (p - 1)
+    phase = p - 1 if cfg.sliding_phase is None else cfg.sliding_phase
+    if p == 1 and phase != 0:
+        import numpy as np
+
+        return np.True_
+    return (li % p) != phase
 
 
 def _layer_inv_freq(cfg: ArchConfig, inv_global, inv_local, li):
@@ -948,7 +964,14 @@ def _attn_proj_qkv(cfg: ArchConfig, lp: Params, x: jnp.ndarray, mesh=None,
         # the ratio (commutes with RoPE — a rotation).
         q = q * float((cfg.head_dim_ / cfg.query_scale) ** 0.5)
     amp = rope_query_amp(cfg)
-    if amp != 1.0:
+    if amp != 1.0 and cfg.rotary_dim < Hd:
+        # ... and where part of a head is rotated the tables reach that part
+        # alone: m² on q's rotated lanes, 1 on those that pass through.
+        import numpy as np
+
+        q = q * jnp.asarray(np.where(
+            np.arange(Hd) < cfg.rotary_dim, amp, 1.0), q.dtype)
+    elif amp != 1.0:
         # yarn/longrope attention-amplitude correction (m on both cos/sin
         # tables ≡ m² on q alone; K stays unmodified in the cache).
         q = q * float(amp)
@@ -1530,11 +1553,35 @@ def _ssd_prefill_mix(cfg: ArchConfig, ap: Params, x, lengths, rec, j, slots):
 class RecurrentKind(NamedTuple):
     """What a recurrent kind brings to the hybrid scan: its weight stack's
     init, its one-token mixer `(cfg, ap, x, rec, j, impl)` and its
-    whole-prompt mixer `(cfg, ap, x, lengths, rec, j, slots)`."""
+    whole-prompt mixer `(cfg, ap, x, lengths, rec, j, slots)`. A window kind
+    ("swa") brings its stack alone: its layer is `_decoder_layer` itself, and
+    an entry point says where that layer's cache operands come from and
+    where its rows go (`SwaMix`)."""
 
     init: Any
-    decode_mix: Any
-    prefill_mix: Any
+    decode_mix: Any = None
+    prefill_mix: Any = None
+
+
+class SwaMix(NamedTuple):
+    """What an entry point brings for its window layers in the recurrent
+    mixer's place: `attend` as `_decoder_layer` takes it, `cache(rec, j)` the
+    cache operands of window layer j that `attend` is handed (a decode
+    step: its ring and its block window; an admission: none), and
+    `keep(rec, j, rows) -> (rec, out)` where the (k, v) rows the layer emits
+    go (a decode step writes them into the block's window, which it carries;
+    an admission writes the prompt's last ones into the slots' rings)."""
+
+    attend: Any
+    cache: Any
+    keep: Any
+
+
+def _init_swa_layers(cfg: ArchConfig, rnd, keys, L: int) -> Params:
+    """The window layers' attention stack: the GQA stack's leaves under its
+    names, at the window layers' own head count (`cfg.swa_heads`)."""
+    return _init_attn_layers(cfg.kind_view("swa"), rnd, keys, L,
+                             cache_stack=True)
 
 
 RECURRENT = {
@@ -1542,6 +1589,7 @@ RECURRENT = {
     "conv": RecurrentKind(_init_conv_layers, _conv_decode_mix,
                           _conv_prefill_mix),
     "ssd": RecurrentKind(_init_ssd_layers, _ssd_decode_mix, _ssd_prefill_mix),
+    "swa": RecurrentKind(_init_swa_layers),
 }
 
 
@@ -1549,9 +1597,12 @@ def _hybrid_tables(cfg: ArchConfig):
     """Static layout of a hybrid stack: the recurrent layers' model layer
     numbers, for each the index (among the cache layers) of the cache layer
     that stands beside it (or -1), how many recurrent layers carry the
-    dense-prefix MLPs, the dense prefix's length, and whether a cache layer
+    dense-prefix MLPs, the dense prefix's length, whether a cache layer
     stands IN FRONT of its recurrent layer (a period that begins with it:
-    Solar-Open2, LFM2) or behind it (one that ends with it: Kimi-Linear)."""
+    Solar-Open2, LFM2, Laguna) or behind it (one that ends with it:
+    Kimi-Linear). Cache layers that carry dense-prefix MLPs themselves
+    (Laguna's layer 0; then the whole prefix is such layers) run ahead of
+    the scan and stand beside no recurrent layer in it."""
     import numpy as np
 
     rk = cfg.recurrent_kind  # refuses a stack of two recurrent kinds
@@ -1559,13 +1610,16 @@ def _hybrid_tables(cfg: ArchConfig):
     ml = list(cfg.cache_layer_ids)
     kd = cfg.first_k_dense if cfg.is_moe else 0
     kind = "mla" if cfg.is_mla else "gqa"
+    ahead = [l for l in ml if l < kd]  # cache layers of the dense prefix
     ok = (len(cfg.layer_kinds) == cfg.num_layers and bool(kl)
           and all(k in (rk, kind) for k in cfg.layer_kinds)
-          and not any(l < kd for l in ml))
+          and ahead in ([], list(range(kd))))
     for lead in (False, True) if ok else ():
         step = -1 if lead else 1  # where a recurrent layer's cache layer stands
-        beside = [ml.index(l + step) if l + step in ml else -1 for l in kl]
-        covered = set(kl) | {l + step for l, m in zip(kl, beside) if m >= 0}
+        beside = [ml.index(l + step) if l + step in ml
+                  and l + step not in ahead else -1 for l in kl]
+        covered = (set(kl) | set(ahead)
+                   | {l + step for l, m in zip(kl, beside) if m >= 0})
         if (covered == set(range(cfg.num_layers))
                 and not any(m >= 0 and l < kd for l, m in zip(kl, beside))):
             nd = sum(1 for l in kl if l < kd)
@@ -1575,7 +1629,8 @@ def _hybrid_tables(cfg: ArchConfig):
         f"{cfg.name}: layer_kinds {cfg.layer_kinds} — every {kind!r} layer "
         f"has to stand beside a {rk or 'kda'!r} layer of its own, all of "
         "them behind theirs or all of them in front, and the dense-prefix "
-        f"layers have to be {rk or 'kda'!r} (models/llama._scan_hybrid)")
+        f"layers have to be all {rk or 'kda'!r} or all {kind!r} "
+        "(models/llama._scan_hybrid)")
 
 
 def _scan_hybrid(cfg: ArchConfig, params: Params, h, rec, rec_fn, cache_fn,
@@ -1599,6 +1654,7 @@ def _scan_hybrid(cfg: ArchConfig, params: Params, h, rec, rec_fn, cache_fn,
     cache-layer outs stacked over the cache layers)."""
     kl, beside, nd, kd, lead = _hybrid_tables(cfg)
     kl_t, beside_t = jnp.asarray(kl), jnp.asarray(beside)
+    ahead = sum(1 for l in cfg.cache_layer_ids if l < kd)  # run before the scan
 
     def run(h, rec, lo, hi, stack, off, with_cache):
         def cache_layer(h, j, li):
@@ -1638,7 +1694,17 @@ def _scan_hybrid(cfg: ArchConfig, params: Params, h, rec, rec_fn, cache_fn,
                 body, (h, rec, jnp.int32(lo)), None, length=hi - lo)
         return h, rec, outs
 
-    outs_k = []
+    outs_k, outs_m = [], []
+    for m in range(ahead):  # a cache layer with a dense-prefix MLP: its own trace
+        with scope("layer"):
+            mi = jnp.int32(m)
+            with jax.named_scope("layer_weights"):
+                lp = {**_take_layer(params[cfg.cache_stack], mi),
+                      **_take_layer(params["dense_layers"], mi)}
+            with jax.named_scope("layer_kv_pool"):
+                ex = _take_layer(tuple(extras), mi)
+            h, om = cache_fn(h, lp, mi, ex)
+        outs_m.append(jax.tree.map(lambda a: a[None], om))
     if nd:
         h, rec, (ok, _) = run(h, rec, 0, nd, params["dense_layers"], 0, False)
         outs_k.append(ok)
@@ -1647,7 +1713,8 @@ def _scan_hybrid(cfg: ArchConfig, params: Params, h, rec, rec_fn, cache_fn,
     with scope("attention/cache_write"):  # the rows the cache layers emitted
         out_k = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs_k)
         there = [j - nd for j in range(nd, len(kl)) if beside[j] >= 0]
-        out_m = jax.tree.map(lambda a: a[jnp.asarray(there)], om)
+        outs_m.append(jax.tree.map(lambda a: a[jnp.asarray(there)], om))
+        out_m = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs_m)
     return h, rec, out_k, out_m
 
 
@@ -1675,9 +1742,15 @@ def _hybrid_layer_fns(cfg: ArchConfig, rec_mix, *, pos, inv, attend,
     """(rec_fn, cache_fn, cache_zero) for `_scan_hybrid` from an entry point's
     `rec_mix(lp, x, rec, j) -> (y, rec)` (its recurrent kind's mixer, KDA's
     or the gated short conv's) and its cache layers' `attend`. The
-    cache layer, MLA or GQA, is `_decoder_layer` itself. With `count` each
-    layer's out ends with its rows per held expert or, from an admission
-    entry point (`admit`), with its grouped kernel's rows (`_walk_rows`)."""
+    cache layer, MLA or GQA, is `_decoder_layer` itself; so is a window
+    layer, for which `rec_mix` is the entry point's `SwaMix`: each kind of a
+    model whose attention layers differ by kind reads the config through its
+    own view (`ArchConfig.kind_view`: head count, rope schedule, window).
+    With `count` each layer's out ends with its rows per held expert or,
+    from an admission entry point (`admit`), with its grouped kernel's rows
+    (`_walk_rows`)."""
+    cview = cfg.kind_view("mla" if cfg.is_mla else "gqa")
+    cinv = inv if cview is cfg else _rope_inv(cview)
 
     def receiver():  # what `_mlp` fills, as `_mlp_out` takes it
         if admit:
@@ -1697,10 +1770,25 @@ def _hybrid_layer_fns(cfg: ArchConfig, rec_mix, *, pos, inv, attend,
         h = _residual(cfg, h, _mlp_out(cfg, lp, x, ep, mesh, **kw))
         return h, rec, (reading(kw) if count else None)
 
+    if cfg.recurrent_kind == "swa":
+        sview = cfg.kind_view("swa")
+        sinv = _rope_inv(sview)
+
+        def rec_fn(h, rec, lp, j):  # noqa: F811 — the window kind's
+            kw = receiver()
+            with jax.named_scope(WINDOW_MIX):
+                h, rows = _decoder_layer(
+                    sview, h, (lp, j) + tuple(rec_mix.cache(rec, j)), pos=pos,
+                    inv=sinv, attend=rec_mix.attend, ep=ep, mesh=mesh, **kw)
+                rec, out = rec_mix.keep(rec, j, rows)
+            if count:
+                out = tuple(out or ()) + (reading(kw),)
+            return h, rec, out
+
     def cache_fn(h, lp, m, ex):
         kw = receiver()
         h, rows = _decoder_layer(
-            cfg, h, (lp, m) + tuple(ex), pos=pos, inv=inv, attend=attend,
+            cview, h, (lp, m) + tuple(ex), pos=pos, inv=cinv, attend=attend,
             mla_full=mla_full, ep=ep, mesh=mesh, **kw)
         return h, rows + ((reading(kw),) if count else ())
 
@@ -1831,6 +1919,14 @@ def _forward_hidden(
 
         def rec_mix(lp, x, rec, j):
             return mix(cfg, lp, x, lengths, rec, j, slots)
+
+        if cfg.recurrent_kind == "swa":
+            # a window layer attends as any layer of a prompt does, under
+            # its window; the prompt's last rows go into its slot's ring
+            rec_mix = SwaMix(
+                attend, lambda rec, j: (),
+                lambda rec, j, rows: (
+                    _ring_admit(cfg, rec, j, rows, lengths, slots), None))
 
         h, rec, walked, kv = _scan_hybrid(
             cfg, params, h, rec, *_hybrid_layer_fns(
@@ -2030,9 +2126,11 @@ def decode_step_windowed(
     lora=None,  # (stacked adapter factors, ids [B]) — per-slot runtime
     # LoRA deltas applied unmerged beside the base matmuls (ISSUE 10)
     expert_rows: bool = False,  # also return the router's rows per expert
-    recurrent=None,  # hybrid models: (state, conv), the per-slot recurrent
-    # state of the KDA or SSD layers ((None, conv) of conv layers); updated
-    # in place, returned LAST
+    recurrent=None,  # hybrid models, as `block_recurrent` gives it: (state,
+    # conv), the per-slot recurrent state of the KDA or SSD layers ((None,
+    # conv) of conv layers), updated in place; window layers' (ring_k,
+    # ring_v, win_k, win_v), the rings read-only and this step's rows
+    # written into the block's windows; returned LAST
     kda_impl: str = "auto",  # the recurrent kind's decode kernel (KDA's or
     # SSD's): auto|pallas|xla
 ):
@@ -2065,11 +2163,14 @@ def decode_step_windowed(
         # row's lanes `_mla_unlatent` reads as values; the op checks it.
         latent = cfg.is_mla and bool(cfg.latent_pad)
 
-        def attend(q, k, v, sliding, kc, vc, lk, lv):
+        def attend(q, k, v, sliding, kc, vc, lk, lv, ring=False):
+            # `ring`: a window layer's, whose kc/vc are its slots' rings
             return decode_attention_windowed_paged(
-                q, kc, vc, ptable, lk, lv, k, v, positions, step,
-                impl=paged_impl, kv_scale=kv_scale, latent=latent,
-                values=cfg.kv_lora_rank if latent else 0,
+                q, kc, vc, ring_table(cfg, q.shape[0]) if ring else ptable,
+                lk, lv, k, v, positions, step,
+                impl=paged_impl, kv_scale=None if ring else kv_scale,
+                latent=latent, values=cfg.kv_lora_rank if latent else 0,
+                ring=cfg.ring_rows if ring else 0,
                 **_mask_opts(cfg, sliding, mesh=mesh, **sink))
     elif use_sp:
         def attend(q, k, v, sliding, kc, vc, lk, lv):
@@ -2113,11 +2214,42 @@ def decode_step_windowed(
         def rec_mix(lp, x, rec, j):
             return mix(cfg, lp, x, rec, j, impl=kda_impl)
 
+        swa = cfg.recurrent_kind == "swa"
+        if swa:
+            # The rings are read-only within a block, as the pool is: a
+            # window layer reads its ring as it stood at the block's start
+            # beside the block's own rows (`win_k`, `win_v`), which ARE
+            # carried through the layer scan: each window layer writes its
+            # new row into its own part of them, in place, as a recurrent
+            # kind updates its state.
+            if ptable is None:
+                raise NotImplementedError(
+                    f"{cfg.name}: window layers decode beside a paged pool")
+            ring_k, ring_v, *wins = recurrent
+            rings = (quant.StackedLayer(ring_k), quant.StackedLayer(ring_v))
+
+            def layer_rings(rec, j):
+                with jax.named_scope("layer_kv_pool"):
+                    return _take_layer(rings, j) + _take_layer(tuple(rec), j)
+
+            @scope("attention/cache_write")
+            def keep_row(rec, j, rows):
+                return tuple(jax.lax.dynamic_update_slice(
+                    win, new.astype(win.dtype)[None, :, None],
+                    (j, 0, step, 0, 0)) for win, new in zip(rec, rows)), None
+
+            rec_mix = SwaMix(functools.partial(attend, ring=True),
+                             layer_rings, keep_row)
+            recurrent = wins
+
         h, recurrent, rows_k, (new_k, new_v, *rows_e) = _scan_hybrid(
             cfg, params, h, tuple(recurrent), *_hybrid_layer_fns(
                 cfg, rec_mix, pos=rope_pos, inv=inv, attend=attend,
                 mla_full=False, ep=ep, mesh=mesh, count=expert_rows),
             extras=extras)
+        if swa:
+            rows_k = rows_k[0] if rows_k else None  # the routers' counts
+            recurrent = (ring_k, ring_v) + tuple(recurrent)
         if expert_rows:  # recurrent layers' MLPs, then the cache layers'
             with scope("mlp/router"):
                 rows_e = [jnp.concatenate([rows_k, rows_e[0]], axis=0)]
@@ -2349,6 +2481,8 @@ def write_block_to_pool(
     kv_scale=None,  # [2, K] f32 → pool rows store value/scale (fp8 KV)
     paged_impl: str = "auto",  # the engine's paged reader (EngineConfig.paged_kernel)
     mesh=None,  # Mesh with tp>1 → the pool is head-sharded
+    ring: bool = False,  # the pool is the slots' rings: position p at row
+    # p mod (MP·page) of its slot's pages, the block's rows wrap around
 ) -> KVCache:
     """Write a decode block's window into the page pool (once per block).
     Rows may straddle pages; each (slot, step) row lands at
@@ -2365,8 +2499,8 @@ def write_block_to_pool(
     L, B, n = local_k.shape[:3]
     page = pool.k.shape[2]
     MP = _pt.width(table)
-    row = jnp.minimum(start_positions[:, None] + jnp.arange(n)[None, :],
-                      MP * page - 1)  # [B, n]
+    row = start_positions[:, None] + jnp.arange(n)[None, :]  # [B, n]
+    row = row % (MP * page) if ring else jnp.minimum(row, MP * page - 1)
     pid = _pt.gather_cols(table, row // page)  # [B, n]
     off = row % page
     ks = None if kv_scale is None else kv_scale[0]
@@ -2376,6 +2510,71 @@ def write_block_to_pool(
     v = write_window(pool.v, _pool_store(local_v, pool.v.dtype, vs),
                      pid, off, impl=paged_impl, mesh=mesh)
     return pool._replace(k=k, v=v)
+
+
+def ring_table(cfg: ArchConfig, slots: int) -> jnp.ndarray:
+    """[slots, ring_pages] int32: the page table of the window layers' rings,
+    a constant: slot i owns pages ring_pages·i .. ring_pages·i + ring_pages-1
+    of every window layer (engine/state.py)."""
+    P = cfg.ring_pages
+    return jnp.arange(slots * P, dtype=jnp.int32).reshape(slots, P)
+
+
+@scope("attention/cache_write")
+def _ring_admit(cfg: ArchConfig, rec, j, rows, lengths, slots):
+    """A group of prompts' last rows into window layer j of their slots'
+    rings: rec = (ring_k, ring_v) [Ls, slots·ring_pages, ring_page, K, D],
+    rows = (k, v) [B, T, K, D] as the layer emitted them (k rotated), right-
+    padded to `lengths`. Ring row r takes the last position below the
+    prompt's length that is r mod the ring's rows; a row no position maps to
+    (a prompt shorter than the ring) holds whatever, past the slot's limit
+    where no reader looks. rec None (an entry point that keeps no state)
+    stays None."""
+    if rec is None:
+        return None
+    P, page, R = cfg.ring_pages, cfg.ring_page, cfg.ring_rows
+    last = lengths[:, None] - 1  # [B, 1]
+    t = last - (last - jnp.arange(R)[None, :]) % R  # [B, R] source positions
+    t = jnp.clip(t, 0, rows[0].shape[1] - 1)
+    pages = slots[:, None] * P + jnp.arange(P)[None, :]  # [B, P]
+    with jax.named_scope(RING_WRITE):
+        return tuple(
+            ring.at[j, pages].set(
+                jnp.take_along_axis(a, t[:, :, None, None], axis=1)
+                .reshape(a.shape[0], P, page, *a.shape[2:]).astype(ring.dtype))
+            for ring, a in zip(rec, rows))
+
+
+def block_recurrent(cfg: ArchConfig, cache: KVCache, slots: int, n: int):
+    """What the steps of an n-step decode block carry of a hybrid model's
+    per-slot state (`decode_step_windowed(recurrent=...)`): the recurrent
+    kinds' (state, conv), updated in place by every step; a window kind's
+    rings, read-only within the block, and the block's own rows of the
+    window layers [Ls, slots, n, K, D] beside them."""
+    rec = (cache.state, cache.conv)
+    if cfg.recurrent_kind == "swa":
+        with scope("attention/cache_write"):
+            win = jnp.zeros((len(cfg.recurrent_layers), slots, n,
+                             cfg.num_kv_heads, cfg.head_dim_), cache.state.dtype)
+        rec = rec + (win, win)
+    return rec
+
+
+def block_recurrent_done(cfg: ArchConfig, cache: KVCache, rec, start_positions,
+                         paged_impl: str = "auto") -> KVCache:
+    """`cache` with the per-slot state as a decode block leaves it: the
+    recurrent kinds' as the steps updated it; a window kind's rings with the
+    block's rows written at their positions mod the ring (the rows they
+    replace lie outside every later query's window)."""
+    if cfg.recurrent_kind == "swa":
+        ring_k, ring_v, win_k, win_v = rec
+        with jax.named_scope(RING_WRITE):
+            rings = write_block_to_pool(
+                KVCache(k=ring_k, v=ring_v),
+                ring_table(cfg, win_k.shape[1]), win_k, win_v,
+                start_positions, paged_impl=paged_impl, ring=True)
+        rec = (rings.k, rings.v)
+    return cache._replace(state=rec[0], conv=rec[1])
 
 
 @scope("attention/cache_write")

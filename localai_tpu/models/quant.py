@@ -55,10 +55,11 @@ QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "wg", "w_gate", "w_up", "w_down",
 
 # The per-layer stacks of a param tree: the model's layers, DeepSeek's dense
 # prefix, and a hybrid model's two attention kinds: its recurrent layers (KDA,
-# the gated short conv or SSD) and its cache layers, MLA or GQA (models/llama.py,
+# the gated short conv, SSD or window attention) and its cache layers, MLA or
+# GQA (models/llama.py,
 # `ArchConfig.recurrent_stack`, `.cache_stack`).
 LAYER_STACKS = ("layers", "dense_layers", "kda_layers", "conv_layers",
-                "ssd_layers", "mla_layers", "gqa_layers")
+                "ssd_layers", "swa_layers", "mla_layers", "gqa_layers")
 
 
 def quantize_tensor(w: jnp.ndarray) -> dict[str, jnp.ndarray]:

@@ -111,6 +111,16 @@ BASE_EVENTS = (
     #                  dispatch x its steps: what the block's page walks
     #                  read, its own rows ride in the window; b=the pool's
     #                  rows x its steps)
+    "window_rows",   # a decode block of a model with window layers was
+    #                  dispatched (a=rows one window layer's reader walks in
+    #                  it: each live slot's rows at dispatch cut to the ring,
+    #                  x its steps; b=the same at full length)
+    "window_state",  # once, at start, a model with window layers (a=rows a
+    #                  slot's ring holds in each window layer, b=bytes of
+    #                  all slots' rings over all window layers)
+    "kv_pool",       # once, at start, beside `window_state` (a=pages the KV
+    #                  manager hands out, b=the pool's bytes: the full
+    #                  layers' rows alone)
     "prefix_reuse_off",  # once, at start: prefix-span reuse was asked for
     #                  and is off (a hybrid model's prefix would need a
     #                  snapshot of its recurrent state; a=entries asked for)

@@ -85,6 +85,18 @@ SSD_MIX = "ssd_mix"
 # from a recurrent state's rows beside it.
 LATENT_WRITE = "latent_write"
 
+# A sliding-window attention layer (Laguna's `sliding_attention`), the layer
+# whole, by the same rule: written around the one decoder layer body where it
+# runs a window layer, so a reader tells the window layers' projections,
+# reader (`window_attention`) and MLP from the full layers' beside them.
+WINDOW_MIX = "window_mix"
+
+# The window layers' rows into their per-slot ring (a decode block's at its
+# end, a prompt's last `sliding_window` at admission): inside
+# `attention/cache_write`, which books it; the word tells it from the paged
+# pool's write beside it.
+RING_WRITE = "ring_write"
+
 
 def scope(leaf: str):
     """`jax.named_scope(leaf)` for a leaf of `SCOPES` (context manager or

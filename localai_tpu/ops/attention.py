@@ -70,16 +70,22 @@ def prefill_attention(
     mesh=None,  # Mesh with tp>1 → flash kernel head-sharded under shard_map
 ) -> jnp.ndarray:
     """Prefill attention dispatcher: Pallas flash kernel on TPU by default
-    (opt out with LOCALAI_FLASH=0), dense math otherwise. Softcapping /
-    sliding windows (gemma-2) force the dense path. With a tp>1 mesh the
+    (opt out with LOCALAI_FLASH=0), dense math otherwise. Softcapping and a
+    per-layer (traced) sliding flag (gemma-2) force the dense path; a window
+    every layer of the caller's kind slides under (`sliding` the static
+    `np.True_`, llama._layer_sliding) is the flash kernel's own. With a tp>1 mesh the
     flash kernel runs head-sharded under shard_map (each chip computes its
     own heads; the dense-math path needs nothing — GSPMD partitions plain
     einsums over the head axis by propagation)."""
     S = q.shape[1]
+    import numpy as np
+
+    static = isinstance(sliding, (bool, np.bool_))  # no traced per-layer flag
+    window = window if sliding is not None and (not static or sliding) else 0
     if (
         lengths is not None
         and not softcap
-        and not window
+        and (not window or static)
         and os.environ.get("LOCALAI_FLASH", "1") != "0"
         and jax.default_backend() == "tpu"
         and (S & (S - 1)) == 0  # power-of-two bucket, divisible by any block
@@ -92,7 +98,7 @@ def prefill_attention(
 
             fn = _head_shard_map(
                 lambda qs, ks, vs, ln: flash_prefill_attention(
-                    qs, ks, vs, ln, block_q=bq, block_k=bk
+                    qs, ks, vs, ln, block_q=bq, block_k=bk, window=window
                 ),
                 mesh,
                 in_specs=(P(None, None, "tp", None), P(None, None, "tp", None),
@@ -100,7 +106,8 @@ def prefill_attention(
                 out_specs=P(None, None, "tp", None),
             )
             return fn(q, k, v, lengths)
-        return flash_prefill_attention(q, k, v, lengths, block_q=bq, block_k=bk)
+        return flash_prefill_attention(q, k, v, lengths, block_q=bq,
+                                       block_k=bk, window=window)
     return causal_prefill_attention(q, k, v, length_mask, softcap=softcap,
                                     window=window, sliding=sliding)
 
@@ -500,7 +507,7 @@ def _sink_window_cols(limits, q_min, page, MP, sink, swin):
 def _paged_cache_partials(q, k_pool, v_pool, table, limits,
                           softcap: float = 0.0, window: int = 0, sliding=None,
                           q_pos=None, kv_scale=None, sink: int = 0,
-                          swin: int = 0):
+                          swin: int = 0, ring: int = 0):
     """Online-softmax partials over a paged cache — the static-shape TPU
     answer to ragged/paged KV (SURVEY §7; reference: llama.cpp's per-slot
     contiguous cache, vLLM's PagedAttention): HBM holds one shared page pool
@@ -526,6 +533,10 @@ def _paged_cache_partials(q, k_pool, v_pool, table, limits,
     convert, so XLA fuses cast+scale into the einsum's operand load and the
     dequantized copy never round-trips HBM (mirrors the in-register dequant
     the Pallas kernel does on its VMEM tile).
+    ring: the table's pages are a per-slot ring of this many rows (a window
+    layer's, engine/state.py): position p lives at row p mod ring, `limits`
+    counts the positions written so far, and a row is masked at the position
+    it holds (paged_flash._ragged_paged_kernel, ring_rows).
     Returns (acc [B, K, G, D], m [B, K, G, 1], l [B, K, G, 1]) f32, scale
     applied.
     """
@@ -579,6 +590,8 @@ def _paged_cache_partials(q, k_pool, v_pool, table, limits,
         gpos = (cols[:, :, None] * page
                 + jnp.arange(page)[None, None, :]).reshape(B, -1)
         valid = (gpos < limits[:, None]) & jnp.repeat(col_ok, page, axis=1)
+        if ring:  # a ring's row -> the position it holds
+            gpos = gpos + (limits[:, None] - 1 - gpos) // ring * ring
         if window and sliding is not None:
             dist = q_pos[:, None] - gpos
             valid = valid & (~sliding | (dist < window))
@@ -738,7 +751,7 @@ def paged_partials(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
                    window: int = 0, sliding=None, q_pos=None,
                    impl: str = "auto", mesh=None, kv_scale=None,
                    sink: int = 0, swin: int = 0, latent: bool = False,
-                   values: int = 0):
+                   values: int = 0, ring: int = 0):
     """Paged online-softmax partials, dispatched: the fused Pallas ragged
     paged-attention kernel (ops/paged_flash — pages stream HBM→VMEM once,
     walk bounded per slot) or the XLA gather walk below (reference path and
@@ -757,7 +770,9 @@ def paged_partials(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
     XLA walk reads such a pool as any other, whole rows. A pool whose rows
     are wider than q's heads holds several heads a row
     (`ArchConfig.cache_pack`: [P, page, K/p, p·D]): the kernel walks it as
-    stored (`paged_decode_partials`), the XLA walk a reshape of it."""
+    stored (`paged_decode_partials`), the XLA walk a reshape of it. `ring`:
+    the pool is a window layer's per-slot rings of that many rows and
+    `limits` the positions written so far (`_paged_cache_partials`), tp = 1."""
     import functools
 
     from localai_tpu.ops.paged_flash import paged_decode_partials, use_pallas
@@ -776,6 +791,8 @@ def paged_partials(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
         interp = jax.default_backend() != "tpu"
         if latent and _tp_degree(mesh) > 1:
             raise NotImplementedError("a latent pool has one head: tp = 1")
+        if ring and _tp_degree(mesh) > 1:
+            raise NotImplementedError("a window layer's ring is read at tp = 1")
         if _tp_degree(mesh) > 1:
             return _paged_pallas_sharded(
                 functools.partial(paged_decode_partials, softcap=softcap,
@@ -788,12 +805,12 @@ def paged_partials(q, k_pool, v_pool, table, limits, softcap: float = 0.0,
         return paged_decode_partials(
             q, k_pool, v_pool, table, limits, softcap=softcap, window=window,
             sliding=sliding, q_pos=q_pos, interpret=interp, kv_scale=kv_scale,
-            sink=sink, swin=swin, latent=latent, values=values,
+            sink=sink, swin=swin, latent=latent, values=values, ring=ring,
         )
     return _paged_cache_partials(
         q, k_pool, v_pool, table, limits,
         softcap=softcap, window=window, sliding=sliding, q_pos=q_pos,
-        kv_scale=kv_scale, sink=sink, swin=swin,
+        kv_scale=kv_scale, sink=sink, swin=swin, ring=ring,
     )
 
 
@@ -905,6 +922,8 @@ def decode_attention_windowed_paged(
     swin: int = 0,  # attended iff gpos < sink or q_pos - gpos < swin
     latent: bool = False,  # the pool is MLA's latent one (`paged_partials`)
     values: int = 0,  # ... and these leading lanes of a row are read as values
+    ring: int = 0,  # the pool is per-slot rings of this many rows (a window
+    # layer's): rows [0, block_start) are those still in the ring
 ) -> jnp.ndarray:
     """`decode_attention_windowed` over a paged pool: paged partials for
     rows [0, block_start), dense merge of the (tiny) local window + current
@@ -918,7 +937,7 @@ def decode_attention_windowed_paged(
         q, k_pool, v_pool, table, positions - step,
         softcap=softcap, window=window, sliding=sliding, q_pos=positions,
         impl=impl, mesh=mesh, kv_scale=kv_scale, sink=sink, swin=swin,
-        latent=latent, values=values,
+        latent=latent, values=values, ring=ring,
     )
     # f32 concat: the block-local window may live in the cache's storage
     # dtype (fp8 KV) while the current token is model-dtype.

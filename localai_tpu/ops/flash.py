@@ -52,6 +52,7 @@ def _flash_kernel(
     block_k: int,
     num_kv_blocks: int,
     scale: float,
+    window: int = 0,
 ):
     import jax.experimental.pallas as pl
 
@@ -69,8 +70,14 @@ def _flash_kernel(
     bq = q_ref.shape[2]
 
     # Causal: kv blocks entirely above this q block contribute nothing —
-    # skip their (masked-to-NEG_INF) compute.
-    @pl.when(ki * block_k < (qi + 1) * block_q)
+    # skip their (masked-to-NEG_INF) compute. Under a sliding `window` (a
+    # query attends the `window` positions up to its own) so do the blocks
+    # that end before the q block's first row's window begins.
+    live = ki * block_k < (qi + 1) * block_q
+    if window:
+        live = live & ((ki + 1) * block_k > qi * block_q - window + 1)
+
+    @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale  # [BQ, D]
         k_blk = k_ref[0, 0].astype(jnp.float32)  # [BK, D]
@@ -81,11 +88,15 @@ def _flash_kernel(
         q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
         kv_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
         mask = (kv_pos <= q_pos) & (kv_pos < length)
+        if window:
+            mask = mask & (q_pos - kv_pos < window)
         s = jnp.where(mask, s, NEG_INF)
 
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
+        if window:  # a row whose window lies past this block: all masked
+            p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
@@ -108,7 +119,7 @@ def _flash_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_q", "block_k", "interpret")
+    jax.jit, static_argnames=("block_q", "block_k", "interpret", "window")
 )
 def flash_prefill_attention(
     q: jnp.ndarray,  # [B, S, H, D]
@@ -118,6 +129,7 @@ def flash_prefill_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool = False,
+    window: int = 0,  # > 0: a query attends the last `window` positions only
 ) -> jnp.ndarray:
     """Causal GQA flash attention. Returns [B, S, H, D] in q.dtype."""
     import jax.experimental.pallas as pl
@@ -139,7 +151,7 @@ def flash_prefill_attention(
     grid = (B, H, S // block_q, num_kv_blocks)
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k,
-        num_kv_blocks=num_kv_blocks, scale=scale,
+        num_kv_blocks=num_kv_blocks, scale=scale, window=int(window),
     )
     out = pl.pallas_call(
         kernel,

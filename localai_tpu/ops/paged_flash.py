@@ -211,6 +211,7 @@ def _ragged_paged_kernel(
     pages: int = 1,
     shared: bool = False,
     values: int = 0,
+    ring_rows: int = 0,
 ):
     """Kernel body. Scalar-prefetch layout depends on the table layout:
 
@@ -278,6 +279,17 @@ def _ragged_paged_kernel(
     np_live) via an index remap, so a spilled slot streams only its sink
     pages + trailing window from HBM. Exact: skipped pages are fully masked
     either way.
+
+    ring_rows (a window layer's per-slot RING, engine/state.py): the slot's
+    table lists the pages of a ring of `ring_rows` rows in which position p
+    lives at row p mod ring_rows, and `limits` is the number of positions
+    written so far, however many. The walk is the table's (the live-page
+    count is clamped to its width as ever); what changes is the position a
+    row is masked AT: row r < limit holds the last position below the limit
+    that is r mod ring_rows (`masked`), so under `window` a row whose
+    position has left the query's window is dead although it is still in
+    the ring (the rows a decode block is about to overwrite). Keys are
+    stored rotated, so no reader needs the rows' order.
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -473,6 +485,8 @@ def _ragged_paged_kernel(
         """Which of a page's rows a query row attends: gpos [1 | QR, ·]
         global row indices against the slot's limit and the windows."""
         valid = gpos < lim
+        if ring_rows:  # a ring's row -> the position it holds (a power of two)
+            gpos = gpos + ((lim - 1 - gpos) & ~(ring_rows - 1))
         if window:
             sl = sliding_ref[0] > 0
             dist = qpos_ref[0] - gpos  # [rows, 1] - [·, columns]
@@ -667,6 +681,7 @@ def _paged_partials_rows(
     ring: int | None = None,  # visit buffers in the DMA ring (tests); None: `_ring_depth`
     latent: bool = False,  # v_pool IS k_pool, [.., page, 1, D]: MLA's latent rows
     values: int = 0,  # ... of which the caller reads these leading lanes as values
+    ring_rows: int = 0,  # the table's pages are a ring of this many rows
 ):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -712,7 +727,7 @@ def _paged_partials_rows(
         _ragged_paged_kernel, page=page, num_kv=K,
         softcap=float(softcap), window=int(window),
         sink=int(sink), swin=int(swin), l1_span=l1_span, ring=ring, flat=flat,
-        pages=pages, shared=latent, values=narrow,
+        pages=pages, shared=latent, values=narrow, ring_rows=int(ring_rows),
     )
     pools = (k_pool,) if latent else (k_pool, v_pool)
     qpos_rows = qpos_rows.astype(jnp.int32)
@@ -782,7 +797,8 @@ def _paged_partials_rows(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         # the name a capture's reader finds the kernel's self time by
-        name="latent_paged_attention" if latent else "paged_attention",
+        name=("latent_paged_attention" if latent else
+              "window_attention" if ring_rows else "paged_attention"),
     )(
         *tbl_args, limits.astype(jnp.int32), sl_arr,
         jnp.asarray(layer, jnp.int32).reshape(1), *operands,
@@ -811,13 +827,21 @@ def paged_decode_partials(
     swin: int = 0,
     latent: bool = False,  # the caller's pool is MLA's latent one
     values: int = 0,  # ... whose rows' leading lanes these are read as values
+    ring: int = 0,  # the table's pages are a per-slot ring of this many rows
 ):
     """Drop-in for attention._paged_cache_partials: returns
     (acc [B, K, G, Dv], m [B, K, G, 1], l [B, K, G, 1]) f32, scale applied.
     `latent`: the one pool is key and value (`latent_paged_attention`, which
     has none of the masks: asking for one with it is refused; with `values`
-    its acc holds the value lanes alone)."""
+    its acc holds the value lanes alone). `ring`: the pool is a window
+    layer's per-slot rings (`_ragged_paged_kernel`, ring_rows): a power of
+    two of rows, a flat table, a head a row."""
     B, H, D = q.shape
+    if ring and (latent or swin or ring & (ring - 1)
+                 or k_pool.shape[-1] != D):
+        raise ValueError(
+            f"a ring of {ring} rows: a power of two, read without the latent "
+            "form, the sink walk and packed heads")
     if latent:
         if kv_scale is not None or softcap or swin or (
                 window and sliding is not None):
@@ -840,7 +864,7 @@ def paged_decode_partials(
         return _paged_partials_rows(
             qr, qpos_rows, k_pool, v_pool, table, limits,
             softcap, window, sliding, interpret, kv_scale=kv_scale,
-            sink=sink, swin=swin,
+            sink=sink, swin=swin, ring_rows=ring,
         )
     # Narrow heads, `pack` of them a row of the pool (ArchConfig.cache_pack:
     # row i of a token holds heads pack·i .. pack·i + pack - 1 side by side,

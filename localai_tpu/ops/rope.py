@@ -25,8 +25,10 @@ def rope_frequencies(cfg: ArchConfig) -> jnp.ndarray:
     phi-3 longrope. The matching attention-amplitude factor (yarn mscale /
     longrope scaling) is served by `rope_query_amp`."""
     # Under MLA only the qk_rope_head_dim slice of q/k rotates (HF deepseek
-    # configs set head_dim to the same value, but don't rely on it).
-    hd = cfg.qk_rope_head_dim if cfg.is_mla else cfg.head_dim_
+    # configs set head_dim to the same value, but don't rely on it); under
+    # `partial_rotary` a GQA head's leading `rotary_dim` lanes, and every
+    # schedule below (yarn's ramp too) is computed over those alone.
+    hd = cfg.qk_rope_head_dim if cfg.is_mla else cfg.rotary_dim
     dims = jnp.arange(0, hd, 2, dtype=jnp.float32)
     inv_freq = 1.0 / (cfg.rope_theta ** (dims / hd))
     if cfg.rope_scaling == "linear":
@@ -101,7 +103,8 @@ def rope_query_amp(cfg: ArchConfig) -> float:
     """Static query pre-multiplier carrying the scaling family's attention-
     amplitude correction. HF scales BOTH cos/sin tables by `attention_factor`
     m (so scores gain m²); scaling q alone by m² is mathematically identical
-    and keeps the cached K unmodified."""
+    and keeps the cached K unmodified. Under `partial_rotary` the tables
+    reach the rotated lanes alone, and so does this (`_attn_proj_qkv`)."""
     if cfg.rope_scaling == "yarn":
         m = (
             cfg.rope_attn_factor
@@ -124,13 +127,20 @@ def rope_query_amp(cfg: ArchConfig) -> float:
 
 
 def rope_rotate(x: jnp.ndarray, angles: jnp.ndarray) -> jnp.ndarray:
-    """Split-half rotation from precomputed angles [..., seq, head_dim/2];
-    x: [..., seq, heads, head_dim]."""
-    cos = jnp.cos(angles)[..., None, :]  # [..., seq, 1, hd/2]
+    """Split-half rotation from precomputed angles [..., seq, rot/2];
+    x: [..., seq, heads, head_dim]. rot = head_dim rotates the whole head;
+    fewer angles (`ArchConfig.partial_rotary`) rotate the leading rot lanes
+    in half-split pairs over those lanes (i with i + rot/2) and pass the
+    rest through."""
+    cos = jnp.cos(angles)[..., None, :]  # [..., seq, 1, rot/2]
     sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
+    rot = 2 * angles.shape[-1]
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf[..., :rot], 2, axis=-1)
+    out = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if rot < x.shape[-1]:
+        out.append(xf[..., rot:])
+    return jnp.concatenate(out, axis=-1).astype(x.dtype)
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, inv_freq: jnp.ndarray) -> jnp.ndarray:

@@ -85,7 +85,9 @@ def _attn_specs(cfg: ArchConfig, cache_stack: bool = False) -> dict[str, P]:
         "wv": P(None, None, "tp"),
         "wo": P(None, "tp", None),
     })
-    if cfg.attn_gate:
+    if cfg.attn_gate == "head":
+        specs["wg_head"] = P(None, None, "tp")
+    elif cfg.attn_gate:
         specs["wg"] = P(None, None, "tp")
     if cfg.post_norms:  # gemma-2 sandwich norms — replicated like the rest
         specs["post_attn_norm"] = P(None, None)
@@ -138,6 +140,8 @@ _RECURRENT_LEAVES = {
     "conv": (("w_in", "conv_w", "wo"), ()),
     "ssd": (("w_z", "w_xbc", "w_dt", "conv_w", "wo"),
             ("conv_b", "dt_bias", "A_log", "ssm_D", "o_norm")),
+    # the window layers' attention stack: the GQA stack's leaves
+    "swa": (("wq", "wk", "wv", "wo", "wg_head"), ()),
 }
 
 

@@ -420,7 +420,7 @@ REFUSED_LAYOUTS = {
     "some_behind_some_in_front": ["gqa", "kda", "kda", "gqa"],
     "no_kda_layer": ["gqa", "gqa"],
     "the_other_models_kind": ["mla", "kda", "kda", "kda"],
-    "a_kind_nobody_knows": ["gqa", "kda", "swa", "kda"],
+    "a_kind_nobody_knows": ["gqa", "kda", "rwkv", "kda"],
 }
 
 
@@ -430,10 +430,14 @@ def test_hybrid_tables_refuse_what_the_scan_cannot_run(what):
         L._hybrid_tables(_kinds(CFG, REFUSED_LAYOUTS[what]))
 
 
-def test_hybrid_tables_refuse_a_cache_layer_in_the_dense_prefix():
+def test_hybrid_tables_take_a_dense_prefix_of_one_kind_only():
+    """A cache layer may carry a dense-prefix MLP where the whole prefix is
+    cache layers (they run ahead of the scan, beside no recurrent layer:
+    Laguna's layer 0); a prefix that mixes the kinds is refused."""
     kimi = get_arch("tiny-kimi-linear")
-    with pytest.raises(NotImplementedError, match="dense-prefix"):
-        L._hybrid_tables(_kinds(kimi, ["mla", "kda", "kda", "kda"]))
+    _, beside, nd, kd, lead = L._hybrid_tables(
+        _kinds(kimi, ["mla", "kda", "kda", "kda"]))
+    assert (beside.tolist(), nd, kd, lead) == ([-1, -1, -1], 0, 1, False)
     with pytest.raises(NotImplementedError, match="dense-prefix"):
         L._hybrid_tables(_kinds(kimi, ["kda", "mla", "kda"], first_k_dense=2))
 

@@ -203,6 +203,11 @@ def decode_block(y: dict, topo, *, layers: int | None = None,
         lk = jnp.zeros(win + (cfg.cache_k_dim,), dt)
         lv = jnp.zeros(win + (cfg.cache_v_dim,), dt)
         start = positions
+        # a tree with window layers carries their rings and the block's rows
+        carried = hasattr(llama, "block_recurrent") and rec is not None
+        if carried:
+            rec = llama.block_recurrent(
+                cfg, pool._replace(state=rec[0], conv=rec[1]), B, steps)
 
         def body(carry, step):
             tokens, positions, lk, lv, rec = carry
@@ -220,11 +225,15 @@ def decode_block(y: dict, topo, *, layers: int | None = None,
             body, (tokens, positions, lk, lv, rec), jnp.arange(steps))
         pool = llama.write_block_to_pool(pool, table, lk, lv, start,
                                          kv_scale=kv_scale, **write_kw)
+        if carried:
+            done = llama.block_recurrent_done(cfg, pool, rec, start)
+            rec = (done.state, done.conv)
         return pool, toks, rec
 
     args = (o.params, o.pool, o.table, sds((B,), jnp.int32),
             sds((B,), jnp.int32), o.rec, o.kv_scale)
-    return Program(jax.jit(block, donate_argnums=(1,)), args, o.pool_local)
+    # the engine donates the cache whole: the pool and a hybrid's rows
+    return Program(jax.jit(block, donate_argnums=(1, 5)), args, o.pool_local)
 
 
 def admit(y: dict, topo, *, layers: int | None = None, m: int = 4,
