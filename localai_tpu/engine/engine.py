@@ -6474,6 +6474,12 @@ class Engine:
                 sites["paged_attention_multipage"])
             out["paged_attention_onepage_sites"] = float(
                 sites["paged_attention_onepage"])
+            # and how its walk crosses a slot boundary: one stream of visits
+            # over all the slots, or a slot's own prefetch (stacked.note_walk)
+            out["paged_attention_stream_sites"] = float(
+                sites["paged_attention_stream"])
+            out["paged_attention_prefetch_sites"] = float(
+                sites["paged_attention_prefetch"])
         if (sites["paged_attention_value_lanes"]
                 or sites["paged_attention_value_row"]):
             # a latent pool's kernel calls alone, by the lanes their value

@@ -11,11 +11,16 @@ the gather itself cannot overlap the matmul. BENCH_r04 put paged decode at
 This kernel walks each slot's page table IN-KERNEL ("Ragged Paged
 Attention", PAPERS.md): the pool stays in HBM (memory_space=ANY), and the
 kernel streams the listed pages through a ring of VMEM page buffers with
-explicit async DMAs — pages j+1 .. j+ring-1 are in flight while page j is
-scored against the online-softmax running state (`_ring_depth`). Each live
-KV byte crosses HBM→VMEM exactly once, the walk stops at the slot's OWN
-live-prefix bound (ragged, not the batch max), and idle slots (limits == 0)
-cost nothing.
+explicit async DMAs: the visits of ALL the slots are ONE STREAM in the
+grid's order, and visits g+1 .. g+ring-1 of it are in flight while visit g
+is scored against the online-softmax running state (`_ring_depth`; `top_up`
+in the kernel body), whatever slots they belong to, so the wire does not
+wait for a slot's last dots, its write-back and the next program's
+prologue, which a walk of one or two visits a slot otherwise spends a fifth
+to a third of its time on (the dots themselves hide under the copies:
+PERF.md §6 PRs 50, 54). Each live KV byte crosses HBM→VMEM exactly once,
+the walk stops at the slot's OWN live-prefix bound (ragged, not the batch
+max), and idle slots (limits == 0) cost nothing.
 
 What a page visit computes (ISSUE 32; docs/PAGED_ATTENTION.md). A page of a
 16- or 8-bit pool goes to the MXU AS IT IS STORED: its [page, K, D] tile is
@@ -34,19 +39,13 @@ value; `latent_paged_attention`) is walked by the same visit (ISSUE 48): its
 page [page, W] is the [C, D] matrix of the as-stored form at K = 1 already,
 and the kernel body is told that the value pool IS the key pool (`shared`):
 one copy and one semaphore a page, the score dot and `p @ kbuf` on the one
-tile, and a last visit's dots over its live pages alone. A visit is bounded
+tile. A visit is bounded
 in bytes as well as in rows (`_visit_pages`), so six 128-row pages of 640
-bfloat16 lanes are one. Two things more are the latent walk's own (ISSUE
-50). The caller states how many leading lanes of a row are VALUES (MLA's
+bfloat16 lanes are one. One thing more is the latent walk's own (ISSUE
+50): the caller states how many leading lanes of a row are VALUES (MLA's
 kv_lora_rank, 512 of the 640: the rest is the rope part and the pad, which
 only the score reads), and `p @ kbuf` runs over those lanes alone, in whole
-lane tiles (`value_lanes`), into an accumulator and an output that wide. And
-the visits of ALL the slots are ONE STREAM in the grid's order: the ring
-holds the stream's next `ring` visits whatever slots they belong to
-(`top_up` in the kernel body), so the wire does not wait for a slot's last
-dots, its write-back and the next program's prologue, which is what a walk
-of one or two visits a slot otherwise spends a fifth of its time on (the
-dots themselves hide under the copies: PERF.md §6 PR 50).
+lane tiles (`value_lanes`), into an accumulator and an output that wide.
 
 Shapes (matching the XLA reference):
 - q rows     [B, K, QR, Dk] f32, 1/sqrt(D) pre-applied; QR = G query rows
@@ -164,8 +163,8 @@ def _visit_pages(page: int, num_kv: int, width: int, row_bytes: int, *,
     of 1,280 B rows gives 6 by its bytes. One page a visit too for the
     per-head form (not `flat`: a float32 pool, wide query tiles) and under
     `swin`, whose walk skips the cold middle, so consecutive visits are not
-    consecutive columns (no cell runs it; it stays without the handoff as
-    well)."""
+    consecutive columns (no cell runs it; it stays out of the slots' one
+    stream of visits as well, `_ragged_paged_kernel`)."""
     if not flat or swin:
         return 1
     rows = min(VISIT_ROWS, VISIT_BYTES // row_bytes)
@@ -251,34 +250,35 @@ def _ragged_paged_kernel(
     dot a pool over all of it, one max / exp / sum / rescale. Column c of
     a visit is row c // K of the visit's first page onwards, so the masks
     read as before. A slot's last visit holds 1..pages live pages: the rest
-    are not fetched, their columns lie past the slot's limit and are masked
-    like any dead row, and their part of the V buffer is ZEROED before the
-    dot (`p` is 0 there, but the buffer may hold anything, and 0 x NaN is
-    NaN; garbage in K only makes scores that the mask replaces).
+    are not fetched, and the visit's dots run over its live pages alone
+    (`visit_flat`: one of `pages` traced sizes of the same step), so no
+    unfetched part of a buffer is read, whatever it holds, and none is
+    zeroed. The columns left out are columns the mask would have killed
+    (`p` exactly 0), so every sum is the sum it was.
 
-    The DMAs run `ring - 1` visits ahead of the visit being scored, and a
-    slot's last visit starts the NEXT slot's first visit (`handoff`).
+    The slots' visits are one STREAM: every visit of every slot starts
+    `ring - 1` visits ahead of the one being scored, across slots, from
+    whichever program is running then (`top_up`; the scratch is [4]: visits
+    started, visits scored, and the slot and visit to start next). Only the
+    cold-middle walk (`swin`) is outside it: it warms up `ring - 1` visits
+    and prefetches inside its own program, and takes no scratch.
 
     `shared` (as stored only; MLA's latent pool, K = 1): the value pool IS
     the key pool. No v_hbm and no vbuf are passed: a page is ONE copy on
     one semaphore (sem [ring, pages]) into kbuf, which `p @ V` reads too.
-    A last visit's dots run over its live pages alone there (`visit_flat`),
-    so no unfetched part is read and none is zeroed. `values` (> 0: fewer
-    than the row has) are the leading lanes of a row that are read as VALUES:
+    `values` (> 0: fewer than the row has) are the leading lanes of a row
+    that are read as VALUES:
     `p @ V` runs over `kbuf[..., :values]` and acc is [R, values]; the lanes
-    past them (MLA's rope part and the pad) only ever entered the score. And
-    the slots' visits are one STREAM: in place of the warm-up, the prefetch
-    and the handoff of a slot's first visit, every visit of every slot
-    starts `ring - 1` visits ahead of the one being scored, across slots
-    (the scratch is [4]: visits started, visits scored, and the slot and
-    visit to start next).
+    past them (MLA's rope part and the pad) only ever entered the score.
 
     sink/swin (windowed+sink decode, docs/LONG_CONTEXT.md): a row is
     attended iff `gpos < sink` or `q_pos - gpos < swin`. The page walk then
     SKIPS the cold middle — it visits columns [0, sink_cols) ∪ [win_lo,
     np_live) via an index remap, so a spilled slot streams only its sink
     pages + trailing window from HBM. Exact: skipped pages are fully masked
-    either way.
+    either way. Which columns those are depends on the slot's own query
+    positions, which no program before it holds: this walk alone keeps its
+    copies inside its program (`_warmup`, `_prefetch`).
 
     ring_rows (a window layer's per-slot RING, engine/state.py): the slot's
     table lists the pages of a ring of `ring_rows` rows in which position p
@@ -302,11 +302,10 @@ def _ragged_paged_kernel(
         table_ref = refs[0]
         refs = refs[1:]
         table_width = table_ref.shape[1]
-    handoff = not swin
-    if handoff:
-        # SMEM [2] i32 scratch that outlives a program: did the slot before
-        # start this one's first page, and into which buffer of the ring
-        *refs, handed_ref = refs
+    stream = not swin
+    if stream:
+        # SMEM [4] i32 scratch that outlives a program: the stream's state
+        *refs, stream_ref = refs
     (
         limits_ref,  # scalar-prefetch [B] i32
         sliding_ref,  # scalar-prefetch [1] i32
@@ -381,18 +380,16 @@ def _ragged_paged_kernel(
         return (k_copy, pltpu.make_async_copy(
             v_hbm.at[layer, pid], into(vbuf), sem.at[buf, 2 * part + 1]))
 
-    def each_copy(act, row, j, live=None, buf=None):
-        """`act` (start or wait) on the copies of visit j of slot `row`, into
-        this slot's buffer for its visit j or into `buf`: the visit's first
-        page, which the caller knows to be live, and of the others those
-        below the slot's `live` pages."""
+    def each_copy(act, row, j, live, buf):
+        """`act` (start or wait) on the copies of visit j of slot `row` into
+        buffer `buf` of the ring: the visit's first page, which the caller
+        knows to be live, and of the others those below the slot's `live`
+        pages."""
         for part in range(pages):
             col = col_of(j) if pages == 1 else j * pages + part
 
             def go(col=col, part=part):
-                pid = page_of(row, col)
-                dst = (first + j) % ring if buf is None else buf
-                for dma in copies(pid, dst, part):
+                for dma in copies(page_of(row, col), buf, part):
                     act(dma)
 
             if part == 0:
@@ -406,41 +403,34 @@ def _ragged_paged_kernel(
     def wait(dma):
         dma.wait()
 
-    # A slot's first visit is the one DMA nothing hides (a program was some
-    # 1.4 us beside 0.68 us a visit, PERF.md §6 PR 32; where a visit is
-    # several pages it is most or all of a short slot's walk), so the slot
-    # before starts it from its own last visit: the grid's programs run in
-    # order and the scratch outlives them. Visit j of this slot then lives in
-    # buffer (first + j) mod ring. Not under swin: where that walk starts
-    # depends on the next slot's own query positions.
-    # A latent walk goes further (`shared`; ISSUE 50): the visits of ALL the
-    # slots are one stream, in the grid's order, and the ring holds its next
-    # `ring` visits whatever slots they belong to (`top_up`). The scratch is
-    # [issued, consumed, slot, visit]: how many visits of the stream were
-    # started and how many the slots before this one scored, and the first
-    # visit not started yet. Visit j of this slot is number consumed + j of
-    # the stream and lives in that buffer mod ring.
-    stream = shared and handoff
+    # The visits of ALL the slots are one STREAM, in the grid's order (its
+    # programs run in order on one core and the scratch outlives them): the
+    # ring holds the stream's next `ring` visits whatever slots they belong
+    # to (`top_up`), so no slot's first visit waits for the slot before to
+    # finish (a program was some 1.4 us beside 0.68 us a visit, PERF.md §6 PR
+    # 32; at one or two visits a slot that was most of the walk, PR 54). The
+    # scratch is [issued, consumed, slot, visit]: how many visits of the
+    # stream were started and how many the slots before this one scored, and
+    # the first visit not started yet. Visit j of this slot is number
+    # consumed + j of the stream and lives in that buffer mod ring. Not
+    # under swin: where that walk starts depends on the next slot's own
+    # query positions, so it warms up and prefetches inside its own program.
     if stream:
         @pl.when(b == 0)
         def _first_slot():
             for i in range(4):
-                handed_ref[i] = 0
+                stream_ref[i] = 0
 
-        @pl.when(handed_ref[2] < b)  # nothing left to start in the slots behind
+        @pl.when(stream_ref[2] < b)  # nothing left to start in the slots behind
         def _catch_up():
-            handed_ref[2] = b
-            handed_ref[3] = 0
+            stream_ref[2] = b
+            stream_ref[3] = 0
 
-        base = handed_ref[1]
-        handed_ref[1] = base + n_iter
+        base = stream_ref[1]
+        stream_ref[1] = base + n_iter
         first = base % ring
-    elif handoff:
-        handed = (b > 0) & (handed_ref[0] == 1)
-        first = jnp.where(handed, handed_ref[1], 0)
-        handed_ref[0] = 0
     else:
-        handed, first = False, 0
+        first = 0
 
     acc_s[...] = jnp.zeros_like(acc_s)
     m_s[...] = jnp.full_like(m_s, NEG_INF)
@@ -461,25 +451,21 @@ def _ragged_paged_kernel(
 
             @pl.when(due)
             def _start():
-                each_copy(start, slot, visit, live=live, buf=issued % ring)
+                each_copy(start, slot, visit, live, issued % ring)
 
             return (issued + due.astype(jnp.int32),
                     jnp.where(due, slot, slot + 1),
                     jnp.where(due, visit + 1, 0))
 
         issued, slot, visit = jax.lax.while_loop(
-            more, one, (handed_ref[0], handed_ref[2], handed_ref[3]))
-        handed_ref[0], handed_ref[2], handed_ref[3] = issued, slot, visit
+            more, one, (stream_ref[0], stream_ref[2], stream_ref[3]))
+        stream_ref[0], stream_ref[2], stream_ref[3] = issued, slot, visit
 
-    # the first visits ride before any is scored (a stream's at its top-ups)
-    for ahead in range(ring - 1) if not stream else ():
-        mine_to_start = ahead < n_iter
-        if handoff and ahead == 0:
-            mine_to_start = mine_to_start & ~handed
-
-        @pl.when(mine_to_start)
-        def _warmup(ahead=ahead):
-            each_copy(start, b, ahead, np_live)
+    if not stream:  # swin: the first visits ride before any is scored
+        for ahead in range(ring - 1):
+            @pl.when(ahead < n_iter)
+            def _warmup(ahead=ahead):
+                each_copy(start, b, ahead, np_live, ahead % ring)
 
     def masked(gpos):
         """Which of a page's rows a query row attends: gpos [1 | QR, ·]
@@ -538,25 +524,17 @@ def _ragged_paged_kernel(
     def visit_flat(slot, j):
         if pages == 1:
             return score(slot, col_of(j) * page)
-        first_row = j * (pages * page)
-        if shared:
-            # The dots of a last visit over its LIVE pages alone (one of
-            # `pages` traced sizes): with 32 query rows on a 128-row array a
-            # page's two dots take as long as its copy, so the columns of a
-            # page that was never fetched are not free to score and mask,
-            # and with none in the dots nothing is left to zero.
-            live = np_live - j * pages
-            for n in range(1, pages + 1):
-                pl.when((live == n) if n < pages else (live >= n))(
-                    functools.partial(score, slot, first_row, n))
-            return
-        for part in range(1, pages):  # a last visit's unfetched pages
-
-            @pl.when(j * pages + part >= np_live)
-            def _finite(part=part):
-                vbuf[slot, pl.ds(part * part_rows, part_rows)] = (
-                    jnp.zeros((part_rows, vbuf.shape[2]), vbuf.dtype))
-        score(slot, first_row)
+        # The dots of a last visit over its LIVE pages alone (one of `pages`
+        # traced sizes): the columns of a page that was never fetched are
+        # not free to score and mask (a six-page visit at K = 2 holds four
+        # live pages on average and its own chain, not the wire, sets its
+        # pace: PERF.md §6 PR 54; with 32 query rows a latent page's two
+        # dots take as long as its copy, PR 48), and with none in the dots
+        # nothing is left to zero.
+        live = np_live - j * pages
+        for n in range(1, pages + 1):
+            pl.when((live == n) if n < pages else (live >= n))(
+                functools.partial(score, slot, j * (pages * page), n))
 
     def tile(buf, slot, cols=None, lanes=0):
         """Columns `cols` (a `pl.ds`; None: all) of a visit's buffer as the
@@ -603,22 +581,11 @@ def _ragged_paged_kernel(
         else:
             @pl.when(j + ring - 1 < n_iter)
             def _prefetch():
-                each_copy(start, b, j + ring - 1, np_live)
+                each_copy(start, b, j + ring - 1, np_live, (j + ring - 1) % ring)
 
-        if handoff and not stream:
-            nxt = jnp.minimum(b + 1, pl.num_programs(0) - 1)
-
-            @pl.when((j == n_iter - 1) & (b + 1 < pl.num_programs(0))
-                     & (limits_ref[nxt] > 0))
-            def _hand_on():  # every later page of this slot is in: one is free
-                buf = (first + j + 1) % ring
-                each_copy(start, nxt, 0, buf=buf, live=(
-                    None if pages == 1 else live_pages(limits_ref[nxt])))
-                handed_ref[0] = 1
-                handed_ref[1] = buf
-
-        each_copy(wait, b, j, np_live)
-        (visit_flat if flat else visit_heads)((first + j) % ring, j)
+        buf = (first + j) % ring
+        each_copy(wait, b, j, np_live, buf)
+        (visit_flat if flat else visit_heads)(buf, j)
         return carry
 
     jax.lax.fori_loop(0, n_iter, body, 0)
@@ -688,7 +655,7 @@ def _paged_partials_rows(
 
     from localai_tpu.ops import ptable as _pt
     from localai_tpu.ops.stacked import (
-        note_arith, note_value_lanes, note_visit, stacks_of)
+        note_arith, note_value_lanes, note_visit, note_walk, stacks_of)
 
     B, K, QR, Dk = qr.shape
     k_pool, v_pool, layer = stacks_of(k_pool, v_pool, "layer_kv_pool")
@@ -721,6 +688,7 @@ def _paged_partials_rows(
         0 if latent else Dv * v_pool.dtype.itemsize)
     pages = _visit_pages(page, K, width, row_bytes, flat=flat, swin=int(swin))
     note_visit(multipage=pages > 1)
+    note_walk(stream=not swin)
     if ring is None:
         ring = _ring_depth(pages * page * K * row_bytes)
     kernel = functools.partial(
@@ -784,15 +752,15 @@ def _paged_partials_rows(
                 pltpu.VMEM((*lead, 1), jnp.float32),
                 pltpu.VMEM((*lead, 1), jnp.float32),
                 pltpu.SemaphoreType.DMA((ring, len(pools) * pages)),
-                # the handoff's state, a latent walk's stream's
-                *([] if swin else [pltpu.SMEM((4 if latent else 2,), jnp.int32)]),
+                # the stream's state
+                *([] if swin else [pltpu.SMEM((4,), jnp.int32)]),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B, *lead, n), jnp.float32)
             for n in (Dv, STAT_LANES, STAT_LANES)
         ],
-        # the programs hand a DMA on to the next one: in order, on one core
+        # a program starts later programs' copies: in order, on one core
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,

@@ -126,6 +126,10 @@ _KEYS = {
     # (`note_visit`)
     "paged_attention_visit": ("paged_attention_multipage",
                               "paged_attention_onepage"),
+    # nor this: how a paged-attention kernel's page walk keeps the wire busy
+    # across the grid's programs (`note_walk`)
+    "paged_attention_walk": ("paged_attention_stream",
+                             "paged_attention_prefetch"),
     # nor this: the lanes a latent paged-attention call's value dot runs
     # over (`note_value_lanes`)
     "paged_attention_values": ("paged_attention_value_lanes",
@@ -151,7 +155,9 @@ class SiteCounts:
     arithmetic of each Pallas paged-attention call, `paged_attention_native`
     / `paged_attention_f32` (`note_arith`) and what a visit of its page walk
     holds, `paged_attention_multipage` / `paged_attention_onepage`
-    (`note_visit`), and, a latent call alone, the lanes of its value dot,
+    (`note_visit`) and how its walk crosses a slot boundary,
+    `paged_attention_stream` / `paged_attention_prefetch` (`note_walk`),
+    and, a latent call alone, the lanes of its value dot,
     `paged_attention_value_lanes` / `paged_attention_value_row`
     (`note_value_lanes`), and the weight block of each Pallas dequant-matmul call,
     `wholerow` / `narrowed` (`note_blocks`); and how a decode block's window
@@ -236,6 +242,19 @@ def note_visit(multipage: bool) -> None:
     (`paged_attention_onepage`: 8 KV heads and more, the per-head form, the
     cold-middle walk). The XLA walk counts under neither."""
     note_site(multipage, kernel="paged_attention_visit")
+
+
+def note_walk(stream: bool) -> None:
+    """Count one Pallas paged-attention kernel call of the program being
+    traced by how its page walk keeps the wire busy across the grid's
+    programs (ops/paged_flash `_ragged_paged_kernel`): `stream`, the visits
+    of all the slots are one stream and the ring of visit buffers holds its
+    next visits whatever slots they belong to (every walk but one; key
+    `paged_attention_stream`), or each slot warms up and prefetches its own
+    visits inside its own program (`paged_attention_prefetch`: the
+    cold-middle walk under `swin`, whose start depends on the slot's own
+    query positions). The XLA walk counts under neither."""
+    note_site(stream, kernel="paged_attention_walk")
 
 
 def note_value_lanes(lanes: bool) -> None:

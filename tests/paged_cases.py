@@ -6,8 +6,8 @@ The modules, by what is under test: `test_paged_flash.py` (the kernel's walk
 against the XLA gather walk), `test_paged_flash_stacked.py` (the pool still
 stacked over layers), `test_paged_flash_as_stored.py` (a narrow pool's page
 handed on as stored, the ring of page buffers), `test_paged_flash_visit.py`
-(how many pages a visit holds), `test_paged_flash_engine.py` (engines and
-model entry points on the kernel).
+(how many pages a visit holds, the slots' visits as one stream),
+`test_paged_flash_engine.py` (engines and model entry points on the kernel).
 """
 
 import jax
@@ -64,18 +64,21 @@ def _bf16_round(x):
 
 
 def _f64_walk(qr, qpos_rows, k_pool, v_pool, table, limits, *, kv_scale=None,
-              softcap=0.0, window=0, sliding=False, sink=0, swin=0, pages=1):
+              softcap=0.0, window=0, sliding=False, sink=0, swin=0, pages=1,
+              ring_rows=0, mxu=_bf16_round):
     """The page walk in float64 numpy, a visit of `pages` consecutive table
     columns at a time (ISSUE 41; a slot's last visit holds what is left),
     rounding to bfloat16 exactly what the kernel hands the MXU in bfloat16:
     q (scale and k scale applied in float32 first, as the wrapper does) and
-    each visit's p against the running max. It reads the listed pages
-    only. qr [B, K, QR, D] float32 with 1/sqrt(D) in it; returns
-    (acc, m, l) as the kernel's [B, K, QR, ·]."""
+    each visit's p against the running max (`mxu`; the identity for the
+    per-head float32 form, whose dots are float32). It reads the listed pages
+    only. `ring_rows`: the table's pages are a ring of that many rows and a
+    row is masked at the position it holds. qr [B, K, QR, D] float32 with
+    1/sqrt(D) in it; returns (acc, m, l) as the kernel's [B, K, QR, ·]."""
     qr = np.asarray(qr, np.float32)
     if kv_scale is not None:
         qr = qr * np.asarray(kv_scale[0], np.float32)[None, :, None, None]
-    q = _bf16_round(qr)
+    q = mxu(qr)
     k = np.asarray(jnp.asarray(k_pool).astype(jnp.float32), np.float64)
     v = np.asarray(jnp.asarray(v_pool).astype(jnp.float32), np.float64)
     table, limits = np.asarray(table), np.asarray(limits)
@@ -93,6 +96,8 @@ def _f64_walk(qr, qpos_rows, k_pool, v_pool, table, limits, *, kv_scale=None,
             rows = len(pids) * page
             gpos = j * page + np.arange(rows)[None, :]  # [1, rows]
             ok = np.broadcast_to(gpos < limits[b], (QR, rows))
+            if ring_rows:  # the last position below the limit in that row
+                gpos = gpos + ((int(limits[b]) - 1 - gpos) & ~(ring_rows - 1))
             dist = qpos_rows[b][:, None] - gpos
             if window and sliding:
                 ok = ok & (dist < window)
@@ -108,8 +113,7 @@ def _f64_walk(qr, qpos_rows, k_pool, v_pool, table, limits, *, kv_scale=None,
             alpha = np.exp(np.maximum(m[b] - m_new, -80.0))
             p = np.where(ok[None], np.exp(s - m_new), 0.0)
             l[b] = l[b] * alpha + p.sum(-1, keepdims=True)
-            acc[b] = acc[b] * alpha + np.einsum(
-                "kqn,nkd->kqd", _bf16_round(p), vv)
+            acc[b] = acc[b] * alpha + np.einsum("kqn,nkd->kqd", mxu(p), vv)
             m[b] = m_new
     if kv_scale is not None:
         acc = acc * np.asarray(kv_scale[1], np.float64)[None, :, None, None]

@@ -433,11 +433,11 @@ _LATENT_CASES = {
     "last_visit_unfetched_nan": dict(
         limits=[128 + 5, _V + 1, _V + 3 * 128 - 3], nan=True),
     "one_token_nan": dict(limits=[1, 0, 11 * 128], nan=True),
-    # the handoff: a live slot after an idle one, after a one-page one, after
+    # the stream across slots: a live slot after an idle one, after a one-page one, after
     # two idle ones, and an idle last one
-    "handoff_after_idle_and_one_page": dict(
+    "stream_after_idle_and_one_page": dict(
         limits=[0, 300, 128, 700, 0, 0, _V + 1, 0]),
-    "handoff_from_a_second_visit": dict(limits=[_V + 128, 64, 13 * 128, 200]),
+    "stream_from_a_second_visit": dict(limits=[_V + 128, 64, 13 * 128, 200]),
     # the value dot over the value lanes alone (512 of 640: both latent
     # cells' kv_lora_rank; ISSUE 50), at GLM-4.7-Flash's 20 query rows a
     # slot and Kimi-Linear's 32: every live size of a last visit, as a
@@ -477,7 +477,7 @@ _LATENT_PROGRAMS = {}
 def test_latent_kernel_matches_the_xla_walk(case, layer):
     """A caller that says its [P, page, 1, W] pool is a latent one gets the
     latent walk (the as-stored visit over the one pool: several pages a
-    visit, the ring, the slot handoff), reading its layer out of the stacked
+    visit, the ring, the one stream of visits), reading its layer out of the stacked
     pool; against the XLA walk at the parent's tolerance. What that kernel
     lacks is refused, not dropped."""
     from localai_tpu.ops.paged_flash import _visit_pages

@@ -213,6 +213,8 @@ _NO_PAGED_SITES = {"paged_attention_stacked": 0, "paged_attention_sliced": 0,
                    "paged_attention_native": 0, "paged_attention_f32": 0,
                    "paged_attention_multipage": 0,
                    "paged_attention_onepage": 0,
+                   "paged_attention_stream": 0,
+                   "paged_attention_prefetch": 0,
                    "paged_attention_value_lanes": 0,
                    "paged_attention_value_row": 0,
                    "pool_write_inplace": 0, "pool_write_scatter": 0,

@@ -170,21 +170,23 @@ def _parent_rows(qr, k_pool, v_pool, table, limits):
 @pytest.mark.parametrize("H,K", [(4, 2), (4, 4), (4, 1)])
 def test_float32_pool_keeps_the_parents_numbers_bit_for_bit(H, K):
     """A float32 pool is not handed on as stored: same tiles, same float32
-    dots, same order as before the change, whatever the ring's depth."""
+    dots, same order as before the change, whatever the ring's depth and
+    whichever slot's program started a visit's copies (the stream)."""
     from localai_tpu.ops.paged_flash import _flat_rows, _paged_partials_rows
 
-    B, D, MP, P = 5, 32, 5, 26
+    B, D, MP, P = 9, 32, 5, 46
     k4, v4 = _pool(jax.random.key(60), P, PAGE, K, D)
     assert not _flat_rows(k4.dtype, v4.dtype, K, H // K)
     table = _table(B, MP, P, seed=14)
-    # a slot's first page is started by the slot before it: handed on, an
-    # idle slot in the way (hands nothing on, is handed nothing), a last one
-    limits = jnp.array([4 * PAGE + 5, PAGE, 0, 2 * PAGE + 1, 3], jnp.int32)
+    # a slot's visits are started from the programs of the slots before it:
+    # a run of idle slots in the way, a run of one-token ones, a last one
+    limits = jnp.array([4 * PAGE + 5, PAGE, 0, 0, 2 * PAGE + 1, 1, 1, 0, 3],
+                       jnp.int32)
     qr = (jax.random.normal(jax.random.key(61), (B, H, D))
           * (1.0 / D**0.5)).reshape(B, K, H // K, D)
     want = _parent_rows(qr, k4, v4, table, limits)
     qpos = jnp.broadcast_to(limits[:, None], (B, H // K))
-    for ring in (None, 2, 3):
+    for ring in (None, 2, 3, 4):
         got = _paged_partials_rows(qr, qpos, k4, v4, table, limits, 0.0, 0,
                                    None, True, ring=ring)
         for g, w in zip(got, want):
@@ -223,7 +225,9 @@ def test_site_counts_tell_the_kernels_arithmetic(dtype, key, K, page, visit):
     """What a traced kernel call fed its dots is counted with the site
     (ops/stacked.SiteCounts): a narrow pool native, a float32 pool f32, the
     XLA walk neither. Beside it what a visit held (ISSUE 41): K = 2 several
-    pages, K = 8 at 128-row pages and the per-head form one."""
+    pages, K = 8 at 128-row pages and the per-head form one; and how the
+    walk crosses a slot boundary (ISSUE 54): one stream of visits, but for
+    the cold-middle walk, which prefetches inside its own program."""
     from localai_tpu.ops.attention import paged_partials
     from localai_tpu.ops.stacked import SiteCounts
 
@@ -243,6 +247,16 @@ def test_site_counts_tell_the_kernels_arithmetic(dtype, key, K, page, visit):
         assert tally[f"paged_attention_{visit}"] == n
         assert tally["paged_attention_multipage"] + tally[
             "paged_attention_onepage"] == n
+        assert (tally["paged_attention_stream"],
+                tally["paged_attention_prefetch"]) == (n, 0)
+        with sites.tracing("cold_middle"):
+            jax.make_jaxpr(lambda q: paged_partials(
+                q, k4, v4, table, limits, impl=impl, sink=page // 2,
+                swin=page + 5))(q)
+        tally = sites.by_program["cold_middle"]
+        assert (tally["paged_attention_stream"],
+                tally["paged_attention_prefetch"]) == (0, n)
+        assert tally["paged_attention_onepage"] == n
 
 
 def test_the_latent_walk_counts_what_it_does():
@@ -276,6 +290,8 @@ def test_the_latent_walk_counts_what_it_does():
         assert tally["paged_attention_native"] == calls
         assert tally["paged_attention_multipage"] == calls
         assert tally["paged_attention_onepage"] == 0
+        assert (tally["paged_attention_stream"],
+                tally["paged_attention_prefetch"]) == (calls, 0)
         assert tally["paged_attention_stacked"] == calls
         # the value dot of each: kv_lora_rank 32 under a 64-wide row rounds
         # up to the row (ISSUE 50), so no tiny preset's kernel is narrower

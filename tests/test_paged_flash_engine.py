@@ -283,6 +283,13 @@ def test_engine_gauges_count_paged_attention_sites(impl, kv_heads, page):
     for key in (mine, other):
         assert metrics[f"paged_attention_{key}_sites"] == sum(
             p[f"paged_attention_{key}"] for p in by_program.values())
+    # and how the walk crosses a slot boundary (ISSUE 54): every kernel site
+    # of every program is one stream of visits, none prefetches on its own
+    assert block["paged_attention_stream"] == (
+        block["traces"] if impl == "pallas" else 0)
+    assert metrics["paged_attention_stream_sites"] == sum(
+        p["paged_attention_stream"] for p in by_program.values())
+    assert metrics["paged_attention_prefetch_sites"] == 0
     # and how the block's window reached the two pools (ISSUE 44): 16- and
     # 8-wide heads are no whole lane tile, which no DMA slices, so these
     # pools keep XLA's scatter under either reader (the kernel's engine

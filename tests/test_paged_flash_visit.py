@@ -1,8 +1,10 @@
 """A visit of the as-stored walk is as many consecutive pages of the slot as
 fit VISIT_ROWS (token, head) rows (ISSUE 41): one dot a pool over all of
 them, one rescale, a last visit of 1..n live pages whose unfetched part may
-hold anything. The kernel in interpret mode against the float64 walk that
-takes the same visits (tests/paged_cases.py), and the rule as a table.
+hold anything; the visits of all the slots are one stream through the ring
+of visit buffers (ISSUEs 50, 54). The kernel in interpret mode against the
+float64 walk that takes the same visits (tests/paged_cases.py), and the rule
+as a table.
 """
 
 import functools
@@ -35,8 +37,8 @@ def _multipage_case(n, wrapper, variant):
     pages at K = 8 / 4 / 2 give 1 / 3 / 6 (fp8 needs four heads a word:
     64-row pages for n = 6), 192-row pages at K = 4 / 2 give 2 / 4. Slots
     of 1, n - 1, n, n + 1 and 2n + 1 pages, ending inside a page and on a
-    page's last row, with idle slots between live ones (the handoff skips
-    them) and an idle first one."""
+    page's last row, with idle slots between live ones (the stream steps
+    over them) and an idle first one."""
     fp8 = variant == "fp8_scale"
     K, page = {1: (8, 128), 2: (4, 192), 3: (4, 128), 4: (2, 192),
                6: (4, 64) if fp8 else (2, 128)}[n]
@@ -145,8 +147,8 @@ def _latent_case(heads, last):
     """(q, pool, table, limits): slots whose LAST visit holds `last` live
     pages, as a slot's only visit and behind one and two full ones, ending
     inside a page, on a page's last row and on the next page's first; an
-    idle slot and a one-token slot between them (the handoff skips the one
-    and crosses the other)."""
+    idle slot and a one-token slot between them (the stream steps over the
+    one and crosses the other)."""
     page, W, MP, visit = (_LATENT[k] for k in ("page", "W", "MP", "visit"))
     full = visit * page
     limits = [(last - 1) * page + 5, 0, full + last * page, 1,
@@ -217,6 +219,125 @@ def test_latent_value_lanes_are_the_whole_rows_lanes(values, lanes):
         text = lambda v: str(jax.make_jaxpr(lambda q: latent_paged_attention(
             q, pool, table, limits, interpret=True, values=v))(q))
         assert text(values) == text(0)
+
+
+# The slots' visits are ONE STREAM (ISSUEs 50, 54): every walk but the
+# cold-middle one starts visit g + ring - 1 of the stream while visit g is
+# scored, whatever slot it belongs to. What a case holds: the walk's form
+# (K row-heads a token, the page, query rows a head, the pool's dtype) and
+# what rides on it.
+_STREAM = {
+    "K2_six_pages": dict(K=2, page=128, QR=4),  # one chip of tp = 4
+    "K4_three_pages": dict(K=4, page=128, QR=4),  # LFM2's packed rows
+    "K8_a_page": dict(K=8, page=128, QR=4),  # mistral int8
+    "K16_a_page": dict(K=16, page=128, QR=1),  # OLMoE
+    "ring_rows": dict(K=8, page=128, QR=8, ring_rows=512),  # Laguna's ring
+    "fp8_scale_window_softcap": dict(K=4, page=64, QR=2, fp8=True,
+                                     window=150, softcap=2.5),
+    "mq_16_rows": dict(K=2, page=128, QR=16, window=200, verify=4),
+    "f32_per_head": dict(K=2, page=16, QR=2, dtype=jnp.float32),
+    "hier": dict(K=2, page=128, QR=2, span=3),
+}
+
+
+def _stream_case(name):
+    """(call, walk, limits): `call(ring)` runs `_paged_partials_rows` at a
+    ring of `ring` visit buffers, `walk()` the float64 walk of the same
+    visits. The slots: a run of idle ones first, every live size 1..n
+    of a last visit (alone in its slot and behind a full visit, ending inside
+    a page and on a page's last row), runs of one-token slots and of idle
+    ones between live ones, a slot of more visits than the ring is deep, an
+    idle last but one. Every page no slot lists holds NaN, and so does a
+    ring buffer nobody wrote (the interpreter's scratch)."""
+    from localai_tpu.ops.paged_flash import (
+        _flat_rows, _paged_partials_rows, _visit_pages)
+
+    c = dict(_STREAM[name])
+    K, page, QR, D = c["K"], c["page"], c["QR"], 32
+    dtype = jnp.float8_e4m3fn if c.get("fp8") else c.get("dtype", jnp.bfloat16)
+    rr = c.get("ring_rows", 0)
+    flat = _flat_rows(dtype, dtype, K, QR)
+    assert flat == (name != "f32_per_head")
+    MP = rr // page if rr else 13
+    n = _visit_pages(page, K, MP, 2 * D * jnp.dtype(dtype).itemsize, flat=flat)
+    assert n == {"K2_six_pages": 6, "K4_three_pages": 3, "hier": 6,
+                 "fp8_scale_window_softcap": 6, "mq_16_rows": 6}.get(name, 1)
+    if rr:  # a ring's limit is the positions written, however many
+        limits = [0, 0, 5, 1, 1, rr, rr + 1, 0, 700, 2000, 0, 0, 3 * page, 1,
+                  rr - 1, 0, 300]
+    else:
+        spec = [(0, 0), (0, 0)]  # live pages, rows of the last one
+        for s in range(1, n + 1):
+            spec += [(n + s, page if s % 2 else 7), (s, 5 if s % 2 else page)]
+            if s == (n + 1) // 2:
+                spec += [(1, 1), (1, 1), (1, 1)]
+        spec += [(1, 1), (0, 0), (0, 0), (0, 0), (1, 1), (MP, 3), (0, 0),
+                 (2, page - 1)]
+        limits = [max(p - 1, 0) * page + e for p, e in spec]
+    B = len(limits)
+    P = B * MP + 1
+    limits = jnp.array(limits, jnp.int32)
+    k4, v4 = _pool(jax.random.key(170), P, page, K, D)
+    table = _table(B, MP, P, seed=40)
+    kw = {}
+    if c.get("fp8"):
+        kw["kv_scale"] = jnp.asarray(
+            [[2.0, 0.5, 1.25, 0.75], [1.5, 3.0, 0.5, 1.0]], jnp.float32)
+        k4, v4 = (k4 / kw["kv_scale"][0][:, None],
+                  v4 / kw["kv_scale"][1][:, None])
+    k4, v4 = k4.astype(dtype), v4.astype(dtype)
+    listed = np.zeros(P, bool)
+    for b, lim in enumerate(np.asarray(limits)):
+        listed[np.asarray(table)[b, :min(-(-int(lim) // page), MP)]] = True
+    poison = jnp.asarray(~listed)[:, None, None, None]
+    k4 = jnp.where(poison, jnp.nan, k4.astype(jnp.float32)).astype(dtype)
+    v4 = jnp.where(poison, jnp.nan, v4.astype(jnp.float32)).astype(dtype)
+    assert bool(jnp.isnan(k4.astype(jnp.float32)).any())
+    qr = jax.random.normal(jax.random.key(171), (B, K, QR, D)) * (1.0 / D**0.5)
+    T = c.get("verify", 1)  # a verify chunk's rows: r = t·G + g
+    qpos = (limits[:, None] + 2 + jnp.arange(QR)[None, :] // (QR // T))
+    window = rr or c.get("window", 0)
+    softcap = c.get("softcap", 0.0)
+    tbl = _hier_of(table, c["span"]) if "span" in c else table
+
+    def call(ring):
+        return jax.jit(lambda qr, k4, v4: _paged_partials_rows(
+            qr, qpos, k4, v4, tbl, limits, softcap, window,
+            jnp.asarray(True) if window else None, True, ring=ring,
+            ring_rows=rr, **kw))(qr, k4, v4)
+
+    def walk():
+        return _f64_walk(qr, qpos, k4, v4, table, limits, softcap=softcap,
+                         window=window, sliding=bool(window), pages=n,
+                         ring_rows=rr, **kw,
+                         **({} if flat else {"mxu": lambda x: np.asarray(
+                             x, np.float64)}))
+
+    return call, walk, limits
+
+
+_STREAM_GOT = {}  # (name, ring) -> the kernel's (acc, m, l): the depth-4 case reads depth 2's
+
+
+@pytest.mark.parametrize("ring", [2, 4])
+@pytest.mark.parametrize("name", list(_STREAM))
+def test_stream_of_visits_matches_float64_walk(name, ring):
+    """Every K/V walk that is not the cold-middle one, as one stream of
+    visits across slots (ISSUE 54), at a ring of two and of four visit
+    buffers against the float64 walk that takes the same visits: K 2 / 4 /
+    8 / 16, a ring's table, fp8 with scales under a window and a softcap,
+    a verify chunk's 16 query rows, the per-head float32 form, a two-level
+    table. No unlisted page is read (they hold NaN), an idle slot's rows are
+    zero, and the depth of the ring moves no bit."""
+    call, walk, limits = _stream_case(name)
+    got = _STREAM_GOT[name, ring] = tuple(np.asarray(g) for g in call(ring))
+    assert all(np.isfinite(g).all() for g in got)
+    _assert_float32_grade(got, walk(), flips=0.05)
+    idle = np.asarray(limits) == 0
+    assert (got[0][idle] == 0).all() and (got[2][idle] == 0).all()
+    if ring == 4:
+        for g, w in zip(got, _STREAM_GOT.get((name, 2)) or call(2)):
+            np.testing.assert_array_equal(g, np.asarray(w))
 
 
 @pytest.mark.parametrize("page,K,width,flat,swin,want", [

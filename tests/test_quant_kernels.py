@@ -467,6 +467,8 @@ _NO_PAGED_SITES = {"paged_attention_stacked": 0, "paged_attention_sliced": 0,
                    "paged_attention_native": 0, "paged_attention_f32": 0,
                    "paged_attention_multipage": 0,
                    "paged_attention_onepage": 0,
+                   "paged_attention_stream": 0,
+                   "paged_attention_prefetch": 0,
                    "paged_attention_value_lanes": 0,
                    "paged_attention_value_row": 0,
                    "pool_write_inplace": 0, "pool_write_scatter": 0,
@@ -592,6 +594,9 @@ def test_paged_decode_step_hands_the_kernel_the_pool_and_counts_it():
                      # 8-row pages: a visit is the table's three columns
                      "paged_attention_multipage": 1,
                      "paged_attention_onepage": 0,
+                     # one stream of visits over all the slots (no swin)
+                     "paged_attention_stream": 1,
+                     "paged_attention_prefetch": 0,
                      # a GQA pool: no latent call to state a value width
                      "paged_attention_value_lanes": 0,
                      "paged_attention_value_row": 0,
@@ -605,6 +610,8 @@ def test_paged_decode_step_hands_the_kernel_the_pool_and_counts_it():
                      "paged_attention_native": 0, "paged_attention_f32": 0,
                      "paged_attention_multipage": 0,
                      "paged_attention_onepage": 0,
+                     "paged_attention_stream": 0,
+                     "paged_attention_prefetch": 0,
                      "paged_attention_value_lanes": 0,
                      "paged_attention_value_row": 0,
                      "pool_write_inplace": 0, "pool_write_scatter": 0,
