@@ -95,6 +95,11 @@ SIZES = {
         # heads (6 rows a KV head) over 10 layers of pages
         swa=dict(layers=30, slots=16, window=512, heads=64, full_heads=48,
                  full_layers=10, pages=20, prefill=(1024, 2048)),
+        # ai21-jamba2-3b as published: the S6 state of 128 slots, 16 states
+        # x 5,120 channels float32 (4 of its 26 layers), and 20 query heads
+        # over ONE 128-wide K/V head in 2 layers of pages
+        s6=dict(layers=4, slots=128, N=16, E=5120, heads=20, kv_layers=2,
+                kv_slots=16, pages=20, prefill=(512, 2048)),
     ),
     "tiny": dict(
         arch="tiny", slots=4, context=512, page=16,
@@ -114,6 +119,8 @@ SIZES = {
                  pages=6, write_slots=4, write_pages=17, prefill=(32,)),
         swa=dict(layers=3, slots=4, window=32, heads=8, full_heads=6,
                  full_layers=2, pages=6, prefill=(64,)),
+        s6=dict(layers=3, slots=8, N=8, E=128, heads=4, kv_layers=2,
+                kv_slots=4, pages=6, prefill=(32,)),
     ),
 }
 
@@ -931,6 +938,70 @@ def child_kernels(size: str, rehearsal: bool, only: str = "") -> dict:
                  q, k, v, jnp.arange(q.shape[1])[None, :] < lens[:, None],
                  window=Wn, sliding=jnp.bool_(True)), lens),
              (*qkv, lens), 2e-2)
+
+    # AI21-Jamba2's S6 (Mamba-1) decode on the stacked float32 state at the
+    # published [128 slots, 16, 5120]: first and last layer, the layer a
+    # scalar-prefetch operand, the state aliased, against the XLA step. Odd
+    # slots hold a released tenant's garbage, 1e4 as large (the kernel
+    # updates every row alike); the layers between keep their rows.
+    # Elementwise float32 both sides, one exp an element -> 1e-4.
+    js = s["s6"]
+    from localai_tpu.ops import s6 as S6
+
+    Lj, Bj, Nj, Ej = js["layers"], js["slots"], js["N"], js["E"]
+
+    def s6_two(impl):
+        def fn(state, x, dt, At, Bm, Cm, Dk, first, last):
+            outs = []
+            for i in (first, last):
+                y, state = S6.s6_decode(state, i, x, dt, At, Bm, Cm, Dk,
+                                        impl=impl)
+                outs.append(y)
+            return tuple(outs) + (state[first], state[last], state[1])
+        return fn
+
+    case(f"s6_decode_stacked_b{Bj}_n{Nj}_e{Ej}", s6_two("auto"), s6_two("xla"),
+         (rnd((Lj, Bj, Nj, Ej), jnp.float32)
+          * jnp.where(jnp.arange(Bj) % 2 == 1, 1e4, 1.0)[None, :, None, None],
+          rnd((Bj, Ej)), jax.nn.softplus(rnd((Bj, Ej), jnp.float32) - 3.0),
+          -jnp.exp(jnp.broadcast_to(jnp.log(jnp.arange(
+              1, Nj + 1, dtype=jnp.float32))[:, None], (Nj, Ej))),
+          rnd((Bj, Nj)), rnd((Bj, Nj)), jnp.ones((Ej,), jnp.float32),
+          jnp.int32(0), jnp.int32(Lj - 1)), 1e-4)
+    # Its attention layers' reader: the paged walk at 20 query rows over ONE
+    # K/V head (a multi-query pool: the page as stored, `_flat_rows`; no cell
+    # so far has one head, and 20 rows are no multiple of the 8-row tile),
+    # contexts of 150 to 2,560 tokens over both layers. Same arithmetic as
+    # paged_decode -> 5e-3.
+    Lq, Bq, jp, Hj = js["kv_layers"], js["kv_slots"], js["pages"], js["heads"]
+    j_k, j_v = (rnd16((Lq, Bq * jp + 1, page, 1, D)) for _ in range(2))
+    j_tab = (jax.random.permutation(next(keys), Bq * jp) + 1).reshape(
+        Bq, jp).astype(jnp.int32)
+    j_lim = jnp.array([(i * 61 + 17) % (jp * page - 150) + 150
+                       for i in range(Bq)], jnp.int32).at[0].set(0)
+    case(f"paged_decode_G{Hj}_one_kv_head_l{Lq}",
+         full_read("auto"), full_read("xla"),
+         (rnd((Bq, Hj, D)), j_k, j_v, j_tab, j_lim,
+          jnp.int32(0), jnp.int32(Lq - 1)), 5e-3)
+    del j_k, j_v
+    # ... and the prefill's flash kernel at 20 : 1, the cell's bucket and the
+    # check's longest prompt, against the dense form. Same rounding as
+    # flash_prefill_S* -> 2e-2.
+    for S in js["prefill"]:
+        lens = jnp.array([S, max(1, S - 7)], jnp.int32)
+
+        def valid_rows(out, lens, S=S):
+            return jnp.where((jnp.arange(S)[None, :] < lens[:, None])[
+                :, :, None, None], out, 0)
+
+        case(f"flash_prefill_h{Hj}_one_kv_head_S{S}",
+             lambda q, k, v, lens: valid_rows(A.prefill_attention(
+                 q, k, v, None, lengths=lens), lens),
+             lambda q, k, v, lens: valid_rows(A.causal_prefill_attention(
+                 q, k, v, jnp.arange(q.shape[1])[None, :] < lens[:, None]),
+                 lens),
+             (rnd((2, S, Hj, D)), rnd((2, S, 1, D)), rnd((2, S, 1, D)), lens),
+             2e-2)
 
     # The held experts' stacks [26 x 32, 2304, 1024] at 64 rows through the
     # same kernel and block rule as olmoe's [16 x 64, 2048, 1024]: a whole

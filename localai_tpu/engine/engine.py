@@ -1589,6 +1589,13 @@ class Engine:
             self._jstage("window_state",
                          a=float(cfg.ring_rows),
                          b=float(self._slot_state_bytes()))
+        if cfg.recurrent_kind == "s6":
+            # The same for a slot's [N, E] matrix a layer and its row over
+            # all S6 layers, beside the few attention layers' one K/V head.
+            self._jstage("s6_state",
+                         a=float(cfg.mamba_d_state * cfg.mamba_d_inner),
+                         b=float(rstate.row_bytes(cfg, self.cache.conv.dtype)))
+        if cfg.recurrent_kind in ("swa", "s6"):
             self._jstage("kv_pool", a=float(self.ecfg.kv_pages), b=float(
                 (self.ecfg.kv_pages + 1) * self._page_bytes()))
         # Pipelined loop runtime (ISSUE 17, docs/ENGINE_RUNTIME.md).
@@ -2758,7 +2765,7 @@ class Engine:
                 "model-free speculation (spec_mode=prompt_lookup/"
                 "self_draft) serves adapter tenants"
             )
-        if self.cfg.is_mla or self.cfg.is_moe:
+        if self.cfg.is_mla or self.cfg.is_moe or self.cfg.is_hybrid:
             kind = (f"hybrid {self.cfg.recurrent_kind.upper()}/"
                     f"{'MLA' if self.cfg.is_mla else 'GQA'}"
                     if self.cfg.is_hybrid
@@ -6501,6 +6508,10 @@ class Engine:
             # stacked state in place, or the XLA step (stacked.note_ssd)
             out["ssd_decode_pallas_sites"] = float(sites["ssd_decode_pallas"])
             out["ssd_decode_xla_sites"] = float(sites["ssd_decode_xla"])
+        if sites["s6_decode_pallas"] or sites["s6_decode_xla"]:
+            # the same for each S6 layer's decode update (stacked.note_s6)
+            out["s6_decode_pallas_sites"] = float(sites["s6_decode_pallas"])
+            out["s6_decode_xla_sites"] = float(sites["s6_decode_xla"])
         if self.m_forks or self.m_fork_clone_fallbacks:
             # Tree-batched fork sampling (ISSUE 18): branches admitted by
             # slot fork vs degraded to the N-clone path (fault/pressure).

@@ -11,7 +11,10 @@ gated short convolution ("conv", LFM2) the operator's last conv_cache-1
 inputs [conv_cache-1, D] and no matrix at all (`state` is then None); per SSD
 layer ("ssd", Mamba-2: Granite-4.0-H) a [H, P, N] float32 matrix (4 MiB at
 128 heads of 64 x 128) and the conv's last mamba_conv-1 inputs over
-[x | B | C]. `_ROWS` holds the shape a kind, `_ADMIT_TOKEN_BYTES` what its
+[x | B | C]; per S6 layer ("s6", Mamba-1 as the `jamba` mixer runs it:
+AI21-Jamba2) a [N, E] float32 matrix, the states on the sublanes and the
+channels on the lanes (320 KiB at 16 x 5120), and the conv's last
+mamba_conv-1 inputs over x alone. `_ROWS` holds the shape a kind, `_ADMIT_TOKEN_BYTES` what its
 chunked prefill holds a prompt token. It needs no allocator: row i of the
 arrays below belongs to slot index i,
 always.
@@ -48,10 +51,11 @@ device's order of programs is the order of their owners:
             (`llama.prefill(recurrent=...)`: the state after the last prompt
             token, computed from zero). Nothing of an earlier tenant is read.
 - decode:   every block updates every row in place, live or not
-            (ops/kda.kda_decode, ops/ssd.ssd_decode, aliased; a conv row
-            shifts by one input). A row without a tenant decays garbage into
-            garbage; it stays bounded (KDA's update is a contraction, SSD's
-            a decay < 1 plus a bounded input, a conv row forgets after
+            (ops/kda.kda_decode, ops/ssd.ssd_decode, ops/s6.s6_decode,
+            aliased; a conv row shifts by one input). A row without a
+            tenant decays garbage into garbage; it stays bounded (KDA's
+            update is a contraction, SSD's and S6's a decay < 1 plus a
+            bounded input, a conv row forgets after
             conv_cache-1 steps) and is never read by a tenant.
 - park:     a tenant whose dispatched blocks cover its budget leaves the index
             (`Engine._park`); its blocks in flight still update the row, and
@@ -111,6 +115,17 @@ def _ssd_token_bytes(cfg) -> int:
     return 4 * (4 * H * C + 2 * H * P * N // C + 6 * H * P)
 
 
+def _s6_token_bytes(cfg) -> int:
+    """What an admission of an S6 (Mamba-1) model holds a prompt token: the
+    selective scan (ops/s6.s6_prefill) is a loop over positions whose [N, E]
+    state and decay exist once a PROMPT, so a token costs its float32 rows
+    alone: eight of [E] (the conv's output, x, dt, dt x, y, the gated y, z
+    and the scan's stacked output in the loop's own order) and the dense
+    MLP's three [F] rows beside them: 262 KB at 5,120 and 8,192, 4,096 rows a
+    program (tools/cell_program.py --program admit for a described v5e)."""
+    return 4 * (8 * cfg.mamba_d_inner + 3 * cfg.intermediate_size)
+
+
 def _mla_token_bytes(cfg) -> int:
     """What an admission of a plain-scan MLA model holds a prompt token: the
     latent rows of EVERY layer until `write_prefill_to_pool` has them (the
@@ -155,15 +170,18 @@ def _swa_token_bytes(cfg) -> int:
 # kind that is not here is not bounded (a conv model's prefill holds a few
 # [T, D] rows a prompt, as any layer's).
 _ADMIT_TOKEN_BYTES = {"kda": _kda_token_bytes, "ssd": _ssd_token_bytes,
-                      "mla": _mla_token_bytes, "swa": _swa_token_bytes}
+                      "mla": _mla_token_bytes, "swa": _swa_token_bytes,
+                      "s6": _s6_token_bytes}
 
 
 def admit_rows(cfg) -> int | None:
     """Most prompt rows (requests x bucket) one admission program takes under
     `ADMIT_BYTES`, from the model's own widths (`_ADMIT_TOKEN_BYTES`): 2,048
-    rows at KDA's 32 heads of 128, 1,024 at 64; 2,048 at SSD's 128 heads of
-    64 x 128; 6,864 at GLM-4.7-Flash's 47 latent layers; 3,318 at Laguna's
-    window and full layers. None for a kind without a bound."""
+    rows at KDA's 32 heads of 128 (Kimi-Linear), 1,024 at 64 (Solar-Open2);
+    2,048 at SSD's 128 heads of 64 x 128 (Granite-4.0-H); 6,864 at
+    GLM-4.7-Flash's 47 latent layers; 3,318 at Laguna's window and full
+    layers; 4,096 at S6's 5,120 channels beside a dense MLP of 8,192
+    (AI21-Jamba2). None for a kind without a bound (LFM2's conv)."""
     kind = cfg.recurrent_kind or ("mla" if cfg.is_mla else "")
     per_token = _ADMIT_TOKEN_BYTES.get(kind)
     return max(1, ADMIT_BYTES // per_token(cfg)) if per_token else None
@@ -230,6 +248,12 @@ def _ssd_rows(cfg, Lk: int, slots: int):
             (Lk, slots, cfg.mamba_conv - 1, cfg.mamba_conv_dim))
 
 
+def _s6_rows(cfg, Lk: int, slots: int):
+    E = cfg.mamba_d_inner
+    return ((Lk, slots, cfg.mamba_d_state, E),
+            (Lk, slots, cfg.mamba_conv - 1, E))
+
+
 def _swa_rows(cfg, Ls: int, slots: int):
     ring = (Ls, slots * cfg.ring_pages, cfg.ring_page, cfg.num_kv_heads,
             cfg.head_dim_)
@@ -237,7 +261,7 @@ def _swa_rows(cfg, Ls: int, slots: int):
 
 
 _ROWS = {"kda": _kda_rows, "conv": _conv_rows, "ssd": _ssd_rows,
-         "swa": _swa_rows}
+         "swa": _swa_rows, "s6": _s6_rows}
 
 
 def _shapes(cfg, slots: int):
@@ -249,7 +273,8 @@ def allocate(cfg, slots: int, conv_dtype, sharding=None):
     """(state [Lk, slots, H, dk, dv] f32, conv [Lk, slots, c-1, 3·H·dk]) of a
     KDA model; (None, conv [Lc, slots, conv_cache-1, D]) of a conv model;
     (state [Lm, slots, H, P, N] f32, conv [Lm, slots, c-1, d_inner + 2·G·N])
-    of an SSD model; a window model's rings, (keys, values)
+    of an SSD model; (state [Lm, slots, N, E] f32, conv [Lm, slots, c-1, E])
+    of an S6 model; a window model's rings, (keys, values)
     [Ls, slots·ring_pages, ring_page, K, D] each, both in `conv_dtype`."""
     st, cv = _shapes(cfg, slots)
     rows = (None if st is None else jnp.zeros(st, _state_dtype(cfg, conv_dtype)),

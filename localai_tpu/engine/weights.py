@@ -208,6 +208,14 @@ def load_hf_checkpoint(
             "(no tensor-name list is in this repository: the per-kind "
             "q_proj / o_proj / gate shapes, the router's bias); serve the "
             "preset with synthetic weights")
+    if cfg.recurrent_kind == "s6":
+        # As for `laguna`: the config keys are read (`_arch_from_jamba`), the
+        # tensors wait for a name list.
+        raise ValueError(
+            f"{cfg.name}: a `jamba` checkpoint's tensors are not loaded yet "
+            "(no tensor-name list is in this repository: the mixer's "
+            "in_proj / x_proj / dt_proj, its three inner norms, A_log [E, N] "
+            "to be transposed); serve the preset with synthetic weights")
     dt = jnp.dtype(cfg.dtype)
     reader = _ShardReader(ckpt_dir)
     if put is None:
@@ -1231,6 +1239,49 @@ def _arch_from_laguna(hf: dict) -> ArchConfig:
     )
 
 
+def _arch_from_jamba(hf: dict) -> ArchConfig:
+    """AI21's `jamba` config keys: layer l is an attention layer iff l mod
+    `attn_layer_period` == `attn_layer_offset`, every other a Mamba-1 layer
+    (`mamba_expand`, `mamba_d_state`, `mamba_d_conv`, `mamba_dt_rank`); layer
+    l has experts iff `num_experts` > 1 and l mod `expert_layer_period` ==
+    `expert_layer_offset` (Jamba2-3B: `num_experts` 1, every MLP dense). No
+    rope key: the attention layers apply no position term. What the keys do
+    not define (the head width, the inner norms) is this repository's
+    reading: benchmark/configs/ai21-jamba2-3b-int8.json, `assumed`."""
+    if int(hf.get("num_experts") or 1) > 1:
+        raise ValueError(
+            "jamba: num_experts > 1 (a dense MLP in some layers and experts "
+            "in others inside one recurrent stack) is not served yet")
+    if not hf.get("mamba_conv_bias", True) or hf.get("mamba_proj_bias"):
+        raise ValueError("jamba: the conv has a bias, the projections none")
+    if hf.get("sliding_window"):
+        raise ValueError("jamba: a sliding window on the attention layers")
+    n = hf["num_hidden_layers"]
+    period, offset = hf["attn_layer_period"], hf["attn_layer_offset"]
+    rank = hf.get("mamba_dt_rank", "auto")
+    return ArchConfig(
+        name=hf.get("_name_or_path", "jamba") or "jamba",
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=n,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf.get("head_dim"),
+        max_position=hf.get("max_position_embeddings", 262144),
+        rms_eps=hf.get("rms_norm_eps", 1e-6),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        attn_rope=False,
+        layer_kinds=tuple("gqa" if i % period == offset else "s6"
+                          for i in range(n)),
+        mamba_d_state=hf.get("mamba_d_state", 16),
+        mamba_conv=hf.get("mamba_d_conv", 4),
+        mamba_expand=hf.get("mamba_expand", 2),
+        mamba_dt_rank=(-(-hf["hidden_size"] // 16) if rank == "auto"
+                       else int(rank)),
+    )
+
+
 def arch_from_hf_config(ckpt_dir: str) -> ArchConfig:
     """Build an ArchConfig from an HF config.json
     (llama/mistral/qwen2/mixtral/gemma/gemma-2/gemma-3/phi3), including every
@@ -1273,6 +1324,8 @@ def arch_from_hf_config(ckpt_dir: str) -> ArchConfig:
     model_type = hf.get("model_type", "llama")
     if model_type == "laguna":
         return _arch_from_laguna(hf)
+    if model_type == "jamba":
+        return _arch_from_jamba(hf)
     gemma3 = model_type in ("gemma3", "gemma3_text")
     gemma = model_type in ("gemma", "gemma2") or gemma3
     gemma2 = model_type == "gemma2"

@@ -15,10 +15,10 @@ from typing import Optional
 
 
 # The layer kinds that keep a per-slot state of fixed size and write no row to
-# the paged cache: the three recurrent ones and "swa", a sliding-window
+# the paged cache: the four recurrent ones and "swa", a sliding-window
 # attention layer, whose state is a ring of its last `sliding_window` keys
 # and values (engine/state.py).
-RECURRENT_KINDS = ("kda", "conv", "ssd", "swa")
+RECURRENT_KINDS = ("kda", "conv", "ssd", "swa", "s6")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -222,27 +222,38 @@ class ArchConfig:
     # `rope_local_theta` unscaled over the whole head; its per-slot state is
     # the ring of those positions' keys and values (`ring_pages` pages of
     # `ring_page` rows), and its weights have their own stack because its
-    # head count is not the cache layers'. One model has one such kind
-    # (`recurrent_kind`).
+    # head count is not the cache layers'.
+    # "s6" (Mamba-1's selective scan, the `jamba` mixer: AI21-Jamba2) is the
+    # fifth: its per-slot state a [mamba_d_state, d_inner] f32 matrix a layer
+    # whose every element decays by its own exp(dt[c] A[c, n]), and the
+    # conv's last mamba_conv-1 inputs [mamba_conv-1, d_inner]; no heads and
+    # no groups. One model has one such kind (`recurrent_kind`).
     layer_kinds: tuple = ()
     swa_heads: int = 0
     # LFM2's conv_L_cache: the taps of the short conv. Neither the taps nor
     # the two projections have a bias (the published `conv_bias` is false in
     # every LFM2 config; there is no field for a value nothing here computes)
     conv_cache: int = 3
-    # Mamba-2 / SSD widths under their published names (`mamba_n_heads`,
-    # `mamba_d_head`, `mamba_d_state`, `mamba_n_groups`, `mamba_d_conv`,
-    # `mamba_chunk_size`: the chunk the model was trained in; the serving
-    # prefill blocks by at most ops/ssd.CHUNK, an exact sub-blocking);
-    # d_inner = heads x head_dim. The conv has a bias
-    # (`mamba_conv_bias` true in every published Granite-4.0-H config), the
-    # two projections none.
+    # The two Mamba forms' widths under their published names. Both read
+    # `mamba_d_state` and `mamba_conv` (`mamba_d_conv`), and both convs have a
+    # bias (`mamba_conv_bias` true in every published Granite-4.0-H and Jamba
+    # config) where the projections have none (`mamba_proj_bias` false).
+    # Mamba-2 / SSD ("ssd") alone: `mamba_heads` (`mamba_n_heads`),
+    # `mamba_head_dim` (`mamba_d_head`), `mamba_groups` (`mamba_n_groups`),
+    # `mamba_chunk` (`mamba_chunk_size`: the chunk the model was trained in;
+    # the serving prefill blocks by at most ops/ssd.CHUNK, an exact
+    # sub-blocking); d_inner = heads x head_dim.
+    # Mamba-1 / the selective scan ("s6") alone: `mamba_expand` (d_inner =
+    # expand x hidden_size; it has no heads) and `mamba_dt_rank`, the
+    # bottleneck the step comes through.
     mamba_heads: int = 0
     mamba_head_dim: int = 64
     mamba_d_state: int = 128
     mamba_groups: int = 1
     mamba_conv: int = 4
     mamba_chunk: int = 256
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
     kda_heads: int = 0
     kda_head_dim: int = 128
     kda_conv: int = 4  # short_conv_kernel_size
@@ -276,7 +287,7 @@ class ArchConfig:
 
     @property
     def recurrent_kind(self) -> str:
-        """A hybrid model's recurrent kind, "kda", "conv" or "ssd" ("" =
+        """A hybrid model's recurrent kind, one of `RECURRENT_KINDS` ("" =
         none). A stack that mixes two is refused here, by name."""
         kinds = {k for k in self.layer_kinds if k in RECURRENT_KINDS}
         if len(kinds) > 1:
@@ -323,7 +334,7 @@ class ArchConfig:
 
     @property
     def recurrent_layers(self) -> tuple:
-        """Model layer numbers of the recurrent (KDA, conv or SSD) layers."""
+        """Model layer numbers of the layers of the recurrent kind."""
         return tuple(i for i, k in enumerate(self.layer_kinds)
                      if k in RECURRENT_KINDS)
 
@@ -334,7 +345,10 @@ class ArchConfig:
 
     @property
     def mamba_d_inner(self) -> int:
-        return self.mamba_heads * self.mamba_head_dim
+        """Mamba-2 states it as heads x head width, Mamba-1 as expand x D."""
+        if self.mamba_heads:
+            return self.mamba_heads * self.mamba_head_dim
+        return self.mamba_expand * self.hidden_size
 
     @property
     def mamba_conv_dim(self) -> int:
@@ -732,6 +746,30 @@ PRESETS: dict[str, ArchConfig] = {
         num_experts_per_token=3,
         n_shared_experts=2,
         moe_intermediate_size=32,
+    ),
+    "tiny-jamba2": ArchConfig(
+        # AI21-Jamba2-shaped tiny: two periods of 3 with the NoPE multi-query
+        # layer at offset 1 (the published period is 14, offset 7), each
+        # BEHIND a Mamba-1 layer of its own; inner width 2 x hidden, 8 states,
+        # a step through a rank-8 bottleneck, 4 query heads over ONE K/V
+        # head, a dense SwiGLU in every layer, a tied head.
+        name="tiny-jamba2",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=6,
+        num_heads=4,
+        num_kv_heads=1,
+        head_dim=16,
+        max_position=512,
+        rms_eps=1e-6,
+        tie_embeddings=True,
+        attn_rope=False,
+        layer_kinds=("s6", "gqa", "s6") * 2,
+        mamba_d_state=8,
+        mamba_conv=4,
+        mamba_expand=2,
+        mamba_dt_rank=8,
     ),
     "llama-3.2-1b": ArchConfig(
         name="llama-3.2-1b",
@@ -1147,6 +1185,33 @@ PRESETS: dict[str, ArchConfig] = {
         num_experts_per_token=10,
         n_shared_experts=2,
         moe_intermediate_size=768,
+    ),
+    "ai21-jamba2-3b": ArchConfig(
+        # ai21labs/AI21-Jamba2-3B config.json (`jamba`, 3B dense): 28 layers,
+        # layer l a NoPE multi-query attention layer (20 query heads over ONE
+        # K/V head of 128) iff l mod 14 == 7, so layers 7 and 21; the other 26
+        # Mamba-1 layers (inner width 2 x 2560, 16 states, the step through a
+        # rank-160 bottleneck, conv 4 with a bias, dt / B / C normed);
+        # `num_experts` 1: every layer's MLP the dense SwiGLU of 8192; a
+        # tied head.
+        name="ai21-jamba2-3b",
+        vocab_size=65536,
+        hidden_size=2560,
+        intermediate_size=8192,
+        num_layers=28,
+        num_heads=20,
+        num_kv_heads=1,
+        head_dim=128,
+        max_position=262144,
+        rms_eps=1e-6,
+        tie_embeddings=True,
+        attn_rope=False,
+        layer_kinds=tuple(
+            "gqa" if i % 14 == 7 else "s6" for i in range(28)),
+        mamba_d_state=16,
+        mamba_conv=4,
+        mamba_expand=2,
+        mamba_dt_rank=160,
     ),
 }
 

@@ -30,7 +30,7 @@ from localai_tpu.models import quant
 from localai_tpu.models.config import ArchConfig
 from localai_tpu.models.quant import matmul, unembed_matmul
 from localai_tpu.observe.scopes import (
-    CONV_MIX, RING_WRITE, SSD_MIX, WINDOW_MIX, scope)
+    CONV_MIX, RING_WRITE, S6_MIX, SSD_MIX, WINDOW_MIX, scope)
 from localai_tpu.ops.attention import (
     _merge_partials_mq,
     decode_attention,  # noqa: F401 — public, used by tests/benchmarks
@@ -169,10 +169,15 @@ def init_special(name: str, key, shape, step=None):
     """The leaves a normal draw at 0.02 would make degenerate (KDA's decay:
     A as fla's KimiDeltaAttention draws it, the step from `step`, the model's
     `kda_init_dt`, `KDA_DT` by default; the short conv; SSD's A and step drawn
-    the same way, as Mamba-2 does, from `SSD_DT`, and its skip D at 1),
-    float32. None for any other leaf."""
+    the same way, as Mamba-2 does, from `SSD_DT`, and its skip D at 1; S6's
+    step from `SSD_DT` too, its skip at 1 and its A, held transposed [N, E],
+    at 1..N a channel, Mamba-1's published init), float32. None for any
+    other leaf."""
     if name == "ssm_D":  # the skip passes x on whole
         return jnp.ones(shape, jnp.float32)
+    if name == "A_logT":  # A[c, n] = n + 1, every channel alike
+        n = jnp.arange(1, shape[-2] + 1, dtype=jnp.float32)
+        return jnp.broadcast_to(jnp.log(n)[:, None], shape)
     if name == "conv_w":  # four taps that pass their input on at its size
         return jax.random.normal(key, shape, jnp.float32) * 0.5
     if name == "A_log":  # A in U(1, 16)
@@ -189,7 +194,9 @@ def init_special(name: str, key, shape, step=None):
 # a long-context model's slow channels do: the regime the float32 state is
 # kept for (held in bfloat16 it drifts by the root of the tokens remembered).
 KDA_DT = (1e-5, 1e-3)
-# The step of a synthetic SSD layer: Mamba-2's published dt_min and dt_max.
+# The step of a synthetic SSD ("ssd") or S6 ("s6") layer: dt_min and dt_max
+# as Mamba-2 and Mamba-1 both publish them. A KDA layer draws from `KDA_DT`
+# (or its preset's `kda_init_dt`), a conv layer has no step.
 SSD_DT = (1e-3, 1e-1)
 
 
@@ -296,6 +303,37 @@ def _init_ssd_layers(cfg: ArchConfig, rnd, keys, L: int) -> Params:
     }
 
 
+def _init_s6_layers(cfg: ArchConfig, rnd, keys, L: int) -> Params:
+    """The stack of a hybrid model's S6 (Mamba-1, `jamba`) layers: the
+    in-projection to [x | z], the projection to [dt | B | C], the step's
+    up-projection and the out-projection int8-able; the depthwise taps, their
+    bias and the three inner norms' weights in the model dtype; the step's
+    bias, A and the skip in float32. A is held as log(-A) TRANSPOSED, [N, E]:
+    the layout the state has and the decode kernel reads (a checkpoint's
+    `A_log` [E, N] is transposed once, at load). `w_in` is one leaf:
+    [D, 2 E] = 10,240 columns at the published widths take whole-row blocks
+    of the dequant-matmul, and the split at E is a lane-tile boundary."""
+    D, E, N = cfg.hidden_size, cfg.mamba_d_inner, cfg.mamba_d_state
+    R, dt = cfg.mamba_dt_rank, _dtype(cfg)
+    return {
+        "w_in": rnd(next(keys), (L, D, 2 * E)),
+        # depthwise causal conv over time of x, tap mamba_conv-1 on the
+        # current token, with a bias
+        "conv_w": init_special(
+            "conv_w", next(keys), (L, cfg.mamba_conv, E)).astype(dt),
+        "conv_b": rnd(next(keys), (L, E)),
+        "w_x": rnd(next(keys), (L, E, R + 2 * N)),
+        "dt_norm": jnp.ones((L, R), dt),
+        "b_norm": jnp.ones((L, N), dt),
+        "c_norm": jnp.ones((L, N), dt),
+        "w_dt": rnd(next(keys), (L, R, E)),
+        "dt_bias": init_special("dt_bias", next(keys), (L, E), SSD_DT),
+        "A_logT": init_special("A_logT", next(keys), (L, N, E)),
+        "ssm_D": init_special("ssm_D", next(keys), (L, E)),
+        "wo": rnd(next(keys), (L, E, D), init_gain(cfg, "wo", (L, E, D))),
+    }
+
+
 def init_params(cfg: ArchConfig, key: jnp.ndarray, scale: float = 0.02) -> Params:
     """Random init with HF-compatible tree structure (stacked layers).
 
@@ -352,7 +390,7 @@ def init_params(cfg: ArchConfig, key: jnp.ndarray, scale: float = 0.02) -> Param
     if cfg.is_hybrid:
         hk = iter(jax.random.split(jax.random.fold_in(key, 1), 32))
         Lr = len(cfg.recurrent_layers)
-        stack = cfg.recurrent_stack  # "kda_layers" | "conv_layers" | "ssd_layers"
+        stack = cfg.recurrent_stack  # "<kind>_layers"
         params[stack] = RECURRENT[cfg.recurrent_kind].init(cfg, rnd, hk, Lr)
         cache_stack = cfg.cache_stack  # "mla_layers" | "gqa_layers"
         params[cache_stack] = _init_attn_layers(
@@ -1263,7 +1301,7 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
 
 
 # --------------------------------------------------------------------------- #
-# Hybrid models (Kimi-Linear, Solar-Open2, LFM2, Granite-4.0-H): recurrent
+# Hybrid models (Kimi-Linear, Solar-Open2, LFM2, Granite-4.0-H, AI21-Jamba2): recurrent
 # layers with a per-slot state beside layers that write cache rows, MLA's
 # latent ones or GQA's ordinary keys and values (cfg.layer_kinds).
 #
@@ -1271,7 +1309,9 @@ def _decoder_layer(cfg: ArchConfig, h, xs, *, pos, inv, attend,
 # (`cfg.recurrent_kind`) a KDA layer keeps, per slot, a [H, dk, dv] float32
 # state and the short conv's last inputs; a gated short convolution ("conv")
 # its last conv_cache-1 inputs and nothing else; an SSD (Mamba-2) layer a
-# [H, P, N] float32 state and its conv's last inputs. What a kind brings
+# [H, P, N] float32 state and its conv's last inputs; an S6 (Mamba-1) layer a
+# [N, E] float32 state, every element with a decay of its own, and its conv's
+# last inputs. What a kind brings
 # (its stack's init, its decode and prefill mixers) is one entry of
 # `RECURRENT`. The two kinds' weights live
 # in their own stacks (`cfg.recurrent_stack`, `cfg.cache_stack`), the norms
@@ -1323,6 +1363,20 @@ def _kda_out(cfg: ArchConfig, ap: Params, o, gate, dtype, mesh=None):
     return matmul(o, ap["wo"], cfg.quant_kernel, mesh, "row")
 
 
+@scope("attention/cache_write")
+def _claim_rows(rec, j, slots, S, window, lengths, n: int):
+    """An admission's write of a recurrent kind's rows: `S` (the state after
+    each prompt's last token; None for a kind without one) and the conv's
+    inputs at each prompt's last n tokens (cut out of `window`, whose first n
+    rows are the zeros before token 0) into rows `slots` [B] of layer j."""
+    state, conv = rec
+    rows = jnp.take_along_axis(
+        window, (lengths[:, None] + jnp.arange(n)[None, :])[..., None], axis=1)
+    if S is not None:
+        state = state.at[j, slots].set(S)
+    return state, conv.at[j, slots].set(rows.astype(conv.dtype))
+
+
 def _kda_decode_mix(cfg: ArchConfig, ap: Params, x, rec, j, impl="auto"):
     """One token per slot: x [B, D], rec = (state, conv) stacked over the KDA
     layers, j this layer's index in them. Returns (y [B, D], rec)."""
@@ -1366,16 +1420,7 @@ def _kda_prefill_mix(cfg: ArchConfig, ap: Params, x, lengths, rec, j, slots):
         o = o[:, :T]
     y = _kda_out(cfg, ap, o, gate, x.dtype)
     if rec is not None:
-        state, conv = rec
-        with scope("attention/cache_write"):
-            # the conv's inputs at tokens len-c+1 .. len-1 (zeros before 0)
-            rows = jnp.take_along_axis(
-                window,
-                (lengths[:, None] + jnp.arange(c - 1)[None, :])[..., None],
-                axis=1)
-            state = state.at[j, slots].set(S)
-            conv = conv.at[j, slots].set(rows.astype(conv.dtype))
-        rec = (state, conv)
+        rec = _claim_rows(rec, j, slots, S, window, lengths, c - 1)
     return y, rec
 
 
@@ -1437,13 +1482,7 @@ def _conv_prefill_mix(cfg: ArchConfig, ap: Params, x, lengths, rec, j, slots):
         zeros = jnp.zeros((x.shape[0], n, cfg.hidden_size), x.dtype)
     c, window = _conv_inputs(cfg, ap, x, zeros)
     if rec is not None:
-        with scope("attention/cache_write"):
-            # the conv's inputs at tokens len-n .. len-1 (zeros before 0)
-            rows = jnp.take_along_axis(
-                window, (lengths[:, None] + jnp.arange(n)[None, :])[..., None],
-                axis=1)
-            conv = rec[1].at[j, slots].set(rows.astype(rec[1].dtype))
-        rec = (None, conv)
+        rec = _claim_rows(rec, j, slots, None, window, lengths, n)
     return _conv_out(cfg, ap, c, window), rec
 
 
@@ -1537,16 +1576,91 @@ def _ssd_prefill_mix(cfg: ArchConfig, ap: Params, x, lengths, rec, j, slots):
         y = y[:, :T]
     out = _ssd_out(cfg, ap, y, z, x.dtype)
     if rec is not None:
-        state, conv = rec
-        with scope("attention/cache_write"):
-            # the conv's inputs at tokens len-c+1 .. len-1 (zeros before 0)
-            rows = jnp.take_along_axis(
-                window,
-                (lengths[:, None] + jnp.arange(c - 1)[None, :])[..., None],
-                axis=1)
-            state = state.at[j, slots].set(S)
-            conv = conv.at[j, slots].set(rows.astype(conv.dtype))
-        rec = (state, conv)
+        rec = _claim_rows(rec, j, slots, S, window, lengths, c - 1)
+    return out, rec
+
+
+@scope("attention/proj")
+def _s6_inputs(cfg: ArchConfig, ap: Params, x: jnp.ndarray, conv_prev):
+    """x [B, T, D] (normed) -> the recurrence's operands and what the layer
+    needs after it: [x | z] = x W_in, x through the short conv (its inputs
+    before x's first token in `conv_prev` [B, c-1, E], zeros at a prompt's
+    start), its bias and silu; [r | B | C] = x W_x, each of the three under
+    an RMSNorm of its own (the `jamba` mixer's; plain Mamba-1 has none);
+    dt = softplus(r W_dt + b_dt).
+
+    Returns (xs [B, T, E] f32, dt [B, T, E] f32, Bm, Cm [B, T, N] f32, z
+    [B, T, E], window [B, c-1+T, E]: the conv's inputs, from which the caller
+    cuts the rows the next token will need)."""
+    f32 = jnp.float32
+    T = x.shape[1]
+    N, R, c = cfg.mamba_d_state, cfg.mamba_dt_rank, cfg.mamba_conv
+    qk, eps = cfg.quant_kernel, cfg.rms_eps
+    pre, z = jnp.split(matmul(x, ap["w_in"], qk), 2, axis=-1)
+    window = jnp.concatenate([conv_prev.astype(pre.dtype), pre], axis=1)
+    w = ap["conv_w"].astype(f32)  # [c, E]
+    y = sum(window[:, i:i + T].astype(f32) * w[i] for i in range(c))
+    xs = jax.nn.silu(y + ap["conv_b"].astype(f32))
+    rbc = matmul(xs.astype(x.dtype), ap["w_x"], qk)
+    r = rms_norm(rbc[..., :R], ap["dt_norm"], eps)
+    Bm = rms_norm(rbc[..., R:R + N].astype(f32), ap["b_norm"], eps)
+    Cm = rms_norm(rbc[..., R + N:].astype(f32), ap["c_norm"], eps)
+    dt = jax.nn.softplus(
+        matmul(r, ap["w_dt"], qk).astype(f32) + ap["dt_bias"].astype(f32))
+    return xs, dt, Bm, Cm, z, window
+
+
+@scope("attention/out")
+def _s6_out(cfg: ArchConfig, ap: Params, y, z, dtype, mesh=None):
+    """y [..., E] f32 -> the gate silu(z), W_out (no norm: Mamba-1's)."""
+    y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(dtype)
+    return matmul(y, ap["wo"], cfg.quant_kernel, mesh, "row")
+
+
+@jax.named_scope(S6_MIX)
+def _s6_decode_mix(cfg: ArchConfig, ap: Params, x, rec, j, impl="auto"):
+    """One token per slot: x [B, D], rec = (state, conv) stacked over the S6
+    layers, j this layer's index in them. Returns (y [B, D], rec). The
+    operator whole is written under `S6_MIX`, around its leaves, as
+    `CONV_MIX` is."""
+    from localai_tpu.ops.s6 import s6_decode
+
+    state, conv = rec
+    with scope("attention/proj"), jax.named_scope("layer_conv_rows"):
+        prev = jax.lax.dynamic_index_in_dim(conv, j, 0, keepdims=False)
+    xs, dt, Bm, Cm, z, window = _s6_inputs(cfg, ap, x[:, None], prev)
+    with scope("attention/cache_write"):
+        conv = jax.lax.dynamic_update_index_in_dim(
+            conv, window[:, 1:].astype(conv.dtype), j, 0)
+    with scope("attention/mix"):  # the kernel writes the state's rows in place
+        At = -jnp.exp(ap["A_logT"].astype(jnp.float32))
+        y, state = s6_decode(state, j, xs[:, 0], dt[:, 0], At, Bm[:, 0],
+                             Cm[:, 0], ap["ssm_D"], impl=impl)
+    return _s6_out(cfg, ap, y, z[:, 0], x.dtype), (state, conv)
+
+
+@jax.named_scope(S6_MIX)
+def _s6_prefill_mix(cfg: ArchConfig, ap: Params, x, lengths, rec, j, slots):
+    """Whole prompts from an empty state: x [B, T, D] right-padded to
+    `lengths`. With rec = (state, conv) the state after each prompt's last
+    token and the conv's last inputs are written to rows `slots` [B] of
+    layer j. Returns (y [B, T, D], rec). The recurrence is the decode step
+    over the prompt's positions (ops/s6.s6_prefill): Mamba-1 has no chunked
+    matmul form."""
+    from localai_tpu.ops.s6 import s6_prefill
+
+    B, T, _ = x.shape
+    c = cfg.mamba_conv
+    with scope("attention/proj"):
+        zeros = jnp.zeros((B, c - 1, cfg.mamba_d_inner), x.dtype)
+    xs, dt, Bm, Cm, z, window = _s6_inputs(cfg, ap, x, zeros)
+    with scope("attention/mix"):
+        valid = jnp.arange(T)[None, :] < lengths[:, None]
+        At = -jnp.exp(ap["A_logT"].astype(jnp.float32))
+        y, S = s6_prefill(xs, dt, At, Bm, Cm, ap["ssm_D"], valid)
+    out = _s6_out(cfg, ap, y, z, x.dtype)
+    if rec is not None:
+        rec = _claim_rows(rec, j, slots, S, window, lengths, c - 1)
     return out, rec
 
 
@@ -1590,6 +1704,7 @@ RECURRENT = {
                           _conv_prefill_mix),
     "ssd": RecurrentKind(_init_ssd_layers, _ssd_decode_mix, _ssd_prefill_mix),
     "swa": RecurrentKind(_init_swa_layers),
+    "s6": RecurrentKind(_init_s6_layers, _s6_decode_mix, _s6_prefill_mix),
 }
 
 
@@ -1636,7 +1751,7 @@ def _hybrid_tables(cfg: ArchConfig):
 def _scan_hybrid(cfg: ArchConfig, params: Params, h, rec, rec_fn, cache_fn,
                  cache_zero, extras=()):
     """The layer stack of a hybrid model: ONE scan over its recurrent layers
-    (KDA or conv, `cfg.recurrent_kind`), each with the cache layer that
+    (of `cfg.recurrent_kind`: one entry of `RECURRENT`), each with the cache layer that
     stands beside it (`lax.cond`) where there is one: behind it, or in front
     of it where the model's periods begin with their cache layer
     (`_hybrid_tables`).
@@ -1740,8 +1855,8 @@ def _hybrid_layer_fns(cfg: ArchConfig, rec_mix, *, pos, inv, attend,
                       mla_full: bool, ep: int, mesh, count: bool,
                       admit: bool = False):
     """(rec_fn, cache_fn, cache_zero) for `_scan_hybrid` from an entry point's
-    `rec_mix(lp, x, rec, j) -> (y, rec)` (its recurrent kind's mixer, KDA's
-    or the gated short conv's) and its cache layers' `attend`. The
+    `rec_mix(lp, x, rec, j) -> (y, rec)` (its recurrent kind's mixer, from
+    `RECURRENT`) and its cache layers' `attend`. The
     cache layer, MLA or GQA, is `_decoder_layer` itself; so is a window
     layer, for which `rec_mix` is the entry point's `SwaMix`: each kind of a
     model whose attention layers differ by kind reads the config through its

@@ -50,7 +50,7 @@ Params = dict[str, Any]
 # einsum paths with no grouped-int kernel and are small next to the MoE.
 QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "wg", "w_gate", "w_up", "w_down",
                     "wq_a", "wq_b", "wkv_a", "w_in", "w_z", "w_xbc", "w_dt",
-                    "shared_gate", "shared_up", "shared_down")
+                    "w_x", "shared_gate", "shared_up", "shared_down")
 
 
 # The per-layer stacks of a param tree: the model's layers, DeepSeek's dense
@@ -59,7 +59,8 @@ QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "wg", "w_gate", "w_up", "w_down",
 # GQA (models/llama.py,
 # `ArchConfig.recurrent_stack`, `.cache_stack`).
 LAYER_STACKS = ("layers", "dense_layers", "kda_layers", "conv_layers",
-                "ssd_layers", "swa_layers", "mla_layers", "gqa_layers")
+                "ssd_layers", "swa_layers", "s6_layers", "mla_layers",
+                "gqa_layers")
 
 
 def quantize_tensor(w: jnp.ndarray) -> dict[str, jnp.ndarray]:
@@ -289,7 +290,7 @@ def init_params_quantized(
         k = next(keys)
         special = init_special(
             name, k, sd.shape,
-            SSD_DT if cfg.recurrent_kind == "ssd" else cfg.kda_init_dt)
+            SSD_DT if cfg.recurrent_kind in ("ssd", "s6") else cfg.kda_init_dt)
         if special is not None:
             return special.astype(sd.dtype)
         if name in QUANT_LAYER_KEYS and len(sd.shape) == 4:

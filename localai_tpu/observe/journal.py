@@ -118,9 +118,14 @@ BASE_EVENTS = (
     "window_state",  # once, at start, a model with window layers (a=rows a
     #                  slot's ring holds in each window layer, b=bytes of
     #                  all slots' rings over all window layers)
-    "kv_pool",       # once, at start, beside `window_state` (a=pages the KV
-    #                  manager hands out, b=the pool's bytes: the full
-    #                  layers' rows alone)
+    "kv_pool",       # once, at start, beside `window_state` or `s6_state`
+    #                  (a=pages the KV manager hands out, b=the pool's bytes:
+    #                  the full / attention layers' rows alone; over
+    #                  (a + 1) x the page's rows, the bytes a token holds)
+    "s6_state",      # once, at start, a model with S6 (Mamba-1) layers
+    #                  (a=float32 values of a slot's state in ONE layer, its
+    #                  shape [d_state, d_inner] as a product; b=bytes of one
+    #                  slot's row over all S6 layers, state and conv inputs)
     "prefix_reuse_off",  # once, at start: prefix-span reuse was asked for
     #                  and is off (a hybrid model's prefix would need a
     #                  snapshot of its recurrent state; a=entries asked for)

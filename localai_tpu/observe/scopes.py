@@ -40,14 +40,16 @@ SCOPES = (
     "attention/proj",         # q/k/v, the MLA pair, KDA's q/k/v/g/beta/gate
     #                           projections and its short conv, q/k norms,
     #                           the gated short conv's [b | c | z] and b ⊙ z,
-    #                           SSD's [z | xBC | dt], its conv and softplus
+    #                           SSD's [z | xBC | dt], its conv and softplus,
+    #                           S6's [x | z], conv, [dt | B | C], their norms
     "attention/rope",         # the rotation (GQA; MLA rotates inside proj)
     "attention/mix",          # the token mixer: paged_attention,
     #                           latent_paged_attention, flash_prefill, the XLA
     #                           softmax and the block-window merge, kda_decode,
     #                           the chunkwise KDA prefill, the gated short
     #                           conv's taps and gate, ssd_decode and the
-    #                           chunkwise SSD prefill
+    #                           chunkwise SSD prefill, s6_decode and the
+    #                           selective scan over a prompt
     "attention/cache_write",  # K/V rows into pool, window or dense cache;
     #                           recurrent state and conv rows into their slots
     "attention/out",          # gate, un-latent, per-head norm, output projection
@@ -77,6 +79,12 @@ CONV_MIX = "conv_mix"
 # matmuls, the conv with its bias and silu, the rows read and written, the
 # `ssd_decode` kernel (or the chunkwise prefill) and the gated norm.
 SSD_MIX = "ssd_mix"
+
+# The S6 (Mamba-1, `jamba`) layer, the operator whole, by the same rule: its
+# four matmuls, the conv with its bias and silu, the three inner norms and
+# the softplus, the rows read and written, the `s6_decode` kernel (or the
+# prefill's scan, `s6_prefill`) and the gate.
+S6_MIX = "s6_mix"
 
 # A latent pool's block write (MLA's one 16-bit row a token, staged through
 # VMEM by `ops/pool_write.latent_pool_write`), by the same rule: written

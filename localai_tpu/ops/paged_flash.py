@@ -139,11 +139,17 @@ def _flat_rows(k_dtype, v_dtype, num_kv: int, qr: int) -> bool:
     n's head h, in HBM and in VMEM, as long as a token's K heads fill whole
     32-bit words of a sublane (XLA:TPU then takes the reshape as a bitcast;
     compiled for a v5e at K = 2, 8, 16 in bfloat16 and K = 8, 16 in fp8,
-    while fp8 at K = 2 was a copy of the pool). A float32 pool, an odd head
-    count and wide query tiles (prefill chunks) keep the per-head tiles."""
+    while fp8 at K = 2 was a copy of the pool). ONE 16-bit head a token (a
+    multi-query pool, as a latent one) has no head axis to interleave: its
+    `[page, 1, D]` page is the `[page, D]` matrix itself, and it is the
+    per-head form that Mosaic refuses there (a slice of one head out of a
+    tile of two: PR 55, ai21-jamba2-3b's pool, compiled for a v5e with no
+    copy of the pool). A float32 pool, any other odd head count and wide
+    query tiles (prefill chunks) keep the per-head tiles."""
     size = jnp.dtype(k_dtype).itemsize
     return (size < 4 and jnp.dtype(v_dtype).itemsize == size
-            and (num_kv * size) % 4 == 0 and num_kv * qr <= FLAT_MAX_ROWS)
+            and ((num_kv, size) == (1, 2) or (num_kv * size) % 4 == 0)
+            and num_kv * qr <= FLAT_MAX_ROWS)
 
 
 def _visit_pages(page: int, num_kv: int, width: int, row_bytes: int, *,

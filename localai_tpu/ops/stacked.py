@@ -139,6 +139,8 @@ _KEYS = {
     "pool_write": ("pool_write_inplace", "pool_write_scatter"),
     # nor this: which form an SSD layer's decode update took (`note_ssd`)
     "ssd_decode": ("ssd_decode_pallas", "ssd_decode_xla"),
+    # nor this: which form an S6 layer's decode update took (`note_s6`)
+    "s6_decode": ("s6_decode_pallas", "s6_decode_xla"),
 }
 _ALL_KEYS = tuple(k for ks in _KEYS.values() for k in ks)
 
@@ -163,7 +165,8 @@ class SiteCounts:
     `wholerow` / `narrowed` (`note_blocks`); and how a decode block's window
     reached each page pool, `pool_write_inplace` / `pool_write_scatter`
     (`note_pool_write`); and the form each SSD layer's decode update took,
-    `ssd_decode_pallas` / `ssd_decode_xla` (`note_ssd`).
+    `ssd_decode_pallas` / `ssd_decode_xla` (`note_ssd`), and an S6 layer's,
+    `s6_decode_pallas` / `s6_decode_xla` (`note_s6`).
     The choice is static, so it is counted where it is made, once per trace. An engine
     owns one and traces its programs under `tracing(<program>)`."""
 
@@ -288,3 +291,10 @@ def note_ssd(pallas: bool) -> None:
     or the XLA step sliced the layer out and put it back
     (`ssd_decode_xla`: off the TPU, or where a caller names it)."""
     note_site(pallas, kernel="ssd_decode")
+
+
+def note_s6(pallas: bool) -> None:
+    """Count one S6 (Mamba-1) decode update of the program being traced
+    (ops/s6 `s6_decode`) by its form, as `note_ssd` does: `s6_decode_pallas`
+    or `s6_decode_xla`."""
+    note_site(pallas, kernel="s6_decode")

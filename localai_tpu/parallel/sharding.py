@@ -140,6 +140,8 @@ _RECURRENT_LEAVES = {
     "conv": (("w_in", "conv_w", "wo"), ()),
     "ssd": (("w_z", "w_xbc", "w_dt", "conv_w", "wo"),
             ("conv_b", "dt_bias", "A_log", "ssm_D", "o_norm")),
+    "s6": (("w_in", "conv_w", "w_x", "w_dt", "A_logT", "wo"),
+           ("conv_b", "dt_norm", "b_norm", "c_norm", "dt_bias", "ssm_D")),
     # the window layers' attention stack: the GQA stack's leaves
     "swa": (("wq", "wk", "wv", "wo", "wg_head"), ()),
 }

@@ -623,9 +623,10 @@ def test_the_routed_down_projection_is_drawn_at_a_tenth():
 def test_one_field_says_what_the_routed_down_projection_is_drawn_at(name):
     """`routed_down_gain` alone decides it: every hybrid preset says the tenth
     that `is_hybrid` used to imply (their synthetic weights are the accepted
-    cells'), the two GLM presets ask for it, no other preset does."""
+    cells'), the two GLM presets ask for it, no other preset does (a DENSE
+    hybrid, AI21-Jamba2, has no routed experts to draw)."""
     arch = PRESETS[name]
-    tenth = arch.is_hybrid or name.endswith("glm-4.7-flash")
+    tenth = (arch.is_hybrid and arch.is_moe) or name.endswith("glm-4.7-flash")
     scalars = float(arch.embedding_multiplier * arch.logits_scaling
                     / arch.residual_multiplier)
     assert L.init_gain(arch, "w_down", (2, 8, 64, 32)) == pytest.approx(

@@ -218,7 +218,8 @@ _NO_PAGED_SITES = {"paged_attention_stacked": 0, "paged_attention_sliced": 0,
                    "paged_attention_value_lanes": 0,
                    "paged_attention_value_row": 0,
                    "pool_write_inplace": 0, "pool_write_scatter": 0,
-                   "ssd_decode_pallas": 0, "ssd_decode_xla": 0}
+                   "ssd_decode_pallas": 0, "ssd_decode_xla": 0,
+                   "s6_decode_pallas": 0, "s6_decode_xla": 0}
 
 
 def _pallas_calls(jaxpr, name):

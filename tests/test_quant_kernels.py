@@ -472,7 +472,8 @@ _NO_PAGED_SITES = {"paged_attention_stacked": 0, "paged_attention_sliced": 0,
                    "paged_attention_value_lanes": 0,
                    "paged_attention_value_row": 0,
                    "pool_write_inplace": 0, "pool_write_scatter": 0,
-                   "ssd_decode_pallas": 0, "ssd_decode_xla": 0}
+                   "ssd_decode_pallas": 0, "ssd_decode_xla": 0,
+                   "s6_decode_pallas": 0, "s6_decode_xla": 0}
 # the seven Pallas dequant-matmul calls of a decode step, by the rule's block
 # a dense model has no expert matmul to take the grouped kernel (`grouped`)
 _WHOLEROW_7 = {"wholerow": 7, "narrowed": 0, "grouped": 0}
@@ -602,7 +603,8 @@ def test_paged_decode_step_hands_the_kernel_the_pool_and_counts_it():
                      "paged_attention_value_row": 0,
                      # a decode STEP: the block's pool write is not in it
                      "pool_write_inplace": 0, "pool_write_scatter": 0,
-                   "ssd_decode_pallas": 0, "ssd_decode_xla": 0}
+                   "ssd_decode_pallas": 0, "ssd_decode_xla": 0,
+                   "s6_decode_pallas": 0, "s6_decode_xla": 0}
     calls, layer_pools, tally, want = seen["xla"]
     assert not calls and len(layer_pools) >= 2  # K and V, sliced at the walk
     assert tally == {"traces": 1, "stacked": 7, "sliced": 0, **_WHOLEROW_7,
@@ -615,7 +617,8 @@ def test_paged_decode_step_hands_the_kernel_the_pool_and_counts_it():
                      "paged_attention_value_lanes": 0,
                      "paged_attention_value_row": 0,
                      "pool_write_inplace": 0, "pool_write_scatter": 0,
-                   "ssd_decode_pallas": 0, "ssd_decode_xla": 0}
+                   "ssd_decode_pallas": 0, "ssd_decode_xla": 0,
+                   "s6_decode_pallas": 0, "s6_decode_xla": 0}
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-2, atol=2e-2)
 

@@ -25,7 +25,7 @@ from localai_tpu.observe import scopes
 from tools.same_program import tiny_engine_programs
 
 CONFIGS = ("tiny", "tiny-olmoe", "tiny-kimi-linear", "tiny-solar-open2",
-           "tiny-lfm2", "tiny-granite-h")
+           "tiny-lfm2", "tiny-granite-h", "tiny-jamba2")
 PROGRAMS = ("decode_block", "admit")
 # what does no work: the issue's list
 NO_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast"}
@@ -187,7 +187,7 @@ def test_the_mlp_scopes_tell_the_models_apart(compiled, config):
         config in ("tiny", "tiny-olmoe", "tiny-lfm2"))
 
 
-@pytest.mark.parametrize("config", [c for c in CONFIGS if c != "tiny"])
+@pytest.mark.parametrize("config", [c for c in CONFIGS if get_arch(c).is_moe])
 def test_ragged_dot_is_called_under_the_leaf_its_rewritten_name_is_read_as(config):
     """XLA:TPU rewrites `lax.ragged_dot` into a custom call it names
     `ragged-dot-none`, and `REWRITTEN` reads that bare name as `mlp/experts`:

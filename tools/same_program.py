@@ -23,7 +23,7 @@ import sys
 _INSTR = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = (\S+) (\w[\w\-]*)\(")
 
 TINY = ("tiny", "tiny-olmoe", "tiny-kimi-linear", "tiny-solar-open2",
-        "tiny-lfm2", "tiny-granite-h")
+        "tiny-lfm2", "tiny-granite-h", "tiny-jamba2")
 
 
 def digest(texts) -> str:
